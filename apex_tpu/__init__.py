@@ -22,21 +22,7 @@ mirrors the reference's public surface (``apex/__init__.py``) without copying
 its implementation.
 """
 
-import jax as _jax
-
-# jax-version compatibility: the repo targets current jax names; on older
-# releases alias the few renamed/moved APIs once here (every subpackage
-# imports apex_tpu first). jax.lax.axis_size(name) is statically
-# lax.psum(1, name) — psum of a python scalar constant folds to the axis
-# size at trace time, which is exactly axis_size's contract.
-if not hasattr(_jax.lax, "axis_size"):  # pragma: no cover - version dep
-
-    def _axis_size(axis_name):
-        return _jax.lax.psum(1, axis_name)
-
-    _jax.lax.axis_size = _axis_size
-
-from apex_tpu.utils.logging import get_logger, set_rank_info  # noqa: E402,F401
+from apex_tpu.utils.logging import get_logger, set_rank_info  # noqa: F401
 
 __version__ = "0.1.0"
 

@@ -27,7 +27,7 @@ Rule families (catalogue with bad/good snippets: docs/api/lint.md):
 
 Beyond the AST rules, ``python -m apex_tpu.lint --jaxpr`` checks **JXP
 contracts** over *traced programs* (``apex_tpu.lint.contracts`` /
-``jaxpr_check``): scan geometry (JXP1xx), donation honored at the pjit
+``jaxpr_check``): scan geometry (JXP1xx), donation honored at the jit
 level (JXP2xx), forbidden aval shapes (JXP3xx), collective inventory —
 ppermute present, no full-width all_gather, collective-free regions
 (JXP4xx), and fp32 accumulation (JXP5xx) — against the registered

@@ -15,7 +15,7 @@ Code families (catalogue with bad/good traces: ``docs/api/lint.md``):
   :func:`scan_length` (JXP102): the schedule-geometry witnesses (the zb
   dW sweep is "a third scan of exactly M·v ticks").
 * **JXP2xx** donation — :func:`donation_honored` (JXP201: a buffer
-  donated into a pjit eqn is dead; reading it afterwards is
+  donated into a jit eqn is dead; reading it afterwards is
   use-after-free at the XLA level), :func:`donation_rebound` (JXP202: a
   donated operand with no same-aval output cannot have its buffer
   reused — the donation silently buys nothing).
@@ -46,6 +46,7 @@ from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 from apex_tpu.lint.jaxpr_check import (
     EqnSite,
+    _is_jit_eqn,
     as_jaxpr,
     collective_axes,
     collective_kind,
@@ -71,7 +72,7 @@ JXP_CODES = {
                "does not) — the schedule-geometry witness"),
     "JXP201": ("donation-use-after-donate",
                "no value read (or returned) after its buffer was donated "
-               "into a pjit call"),
+               "into a jit call"),
     "JXP202": ("donated-not-rebound",
                "every donated operand has a same-aval output to rebind — "
                "a donation with no matching output buys nothing"),
@@ -217,7 +218,7 @@ def scan_length(length: int, *, min_count: int = 1,
 
 def donation_honored() -> Contract:
     """JXP201: no value read after its buffer was donated — a var passed
-    in a donated position of a pjit eqn must not feed any LATER eqn of
+    in a donated position of a jit eqn must not feed any LATER eqn of
     the same level, nor that level's outputs (XLA may have reused the
     buffer; the read is use-after-free). Literals are skipped — a
     literal has no buffer to donate."""
@@ -235,8 +236,8 @@ def donation_honored() -> Contract:
                                 "JXP201", label, path,
                                 f"donated buffer {var} is read by a later "
                                 f"`{eqn.primitive.name}` eqn after the "
-                                "pjit call that donated it"))
-                if eqn.primitive.name == "pjit":
+                                "jit call that donated it"))
+                if _is_jit_eqn(eqn):
                     donated = eqn.params.get("donated_invars") or ()
                     for var, is_donated in zip(eqn.invars, donated):
                         if is_donated and not hasattr(var, "val"):
@@ -254,7 +255,7 @@ def donation_honored() -> Contract:
 
 def donation_rebound() -> Contract:
     """JXP202: every donated operand has a same-aval output to rebind —
-    a pjit eqn donating an aval it produces fewer outputs of cannot
+    a jit eqn donating an aval it produces fewer outputs of cannot
     reuse the buffer (jax warns 'Some donated buffers were not usable'
     at run time; this is the same check at trace time, multiset-matched
     per (shape, dtype))."""
@@ -269,7 +270,7 @@ def donation_rebound() -> Contract:
         findings = []
         for path, jaxpr in walk.levels():
             for eqn in jaxpr.eqns:
-                if eqn.primitive.name != "pjit":
+                if not _is_jit_eqn(eqn):
                     continue
                 donated = eqn.params.get("donated_invars") or ()
                 if not any(donated):

@@ -32,14 +32,14 @@ Walk model
 ----------
 :func:`iter_sites` yields one :class:`EqnSite` per equation at every
 nesting level, descending into EVERY sub-jaxpr found in ``eqn.params``
-(pjit's ``jaxpr``, scan's ``jaxpr``, while's ``cond_jaxpr``/
+(jit's ``jaxpr``, scan's ``jaxpr``, while's ``cond_jaxpr``/
 ``body_jaxpr``, cond's ``branches``, custom_vjp/jvp's ``fun_jaxpr``/
 ``call_jaxpr``, shard_map's ``jaxpr``, remat, pallas_call — anything
 Jaxpr-shaped, listed or bare). Each site carries:
 
 * ``path`` — ``/``-joined segments of the higher-order eqns containing
-  it (``"pjit:step/scan:6"``); scan segments embed the static length,
-  pjit segments the wrapped function name, so contracts can target
+  it (``"jit:step/scan:6"``); scan segments embed the static length,
+  jit segments the wrapped function name, so contracts can target
   regions by regex (the zb dW sweep is ``scan:<M·v>``);
 * ``mult`` — the product of enclosing scan lengths: the number of times
   the eqn executes per call of the traced program (the unit
@@ -126,19 +126,27 @@ def sub_jaxprs(val) -> Iterator[Any]:
             yield from sub_jaxprs(item)
 
 
+def _is_jit_eqn(eqn) -> bool:
+    """True for the eqn a nested ``jax.jit`` call leaves in a jaxpr — the
+    one carrying ``donated_invars``. jax 0.9 names the primitive ``jit``;
+    the walker, the donation contracts and the liveness analysis all ask
+    here, so the name lives in one place."""
+    return eqn.primitive.name == "jit"
+
+
 def _segment(eqn) -> str:
     """Path segment for one higher-order eqn: scans embed their static
-    length (``scan:6`` — how contracts target the zb dW sweep), pjit its
-    wrapped-function name (``pjit:train_step``)."""
+    length (``scan:6`` — how contracts target the zb dW sweep), jit its
+    wrapped-function name (``jit:train_step``)."""
     name = eqn.primitive.name
     if name == "scan":
         length = eqn.params.get("length")
         if isinstance(length, int):
             return f"scan:{length}"
-    if name == "pjit":
+    if _is_jit_eqn(eqn):
         fn_name = eqn.params.get("name")
         if isinstance(fn_name, str) and fn_name:
-            return f"pjit:{fn_name}"
+            return f"jit:{fn_name}"
     return name
 
 

@@ -23,7 +23,7 @@ in bytes:
   still owns those buffers;
 * at each eqn the footprint is ``live-before + new output bytes +
   inner extra`` (outputs materialize while operands are still held);
-* **donation aliases input to output**: at a ``pjit`` eqn with
+* **donation aliases input to output**: at a ``jit`` eqn with
   ``donated_invars``, each donated operand at its last use is multiset-
   matched to a same-``(shape, dtype)`` output; the matched output takes
   over the donor's buffer (zero new bytes, family inherited) — a
@@ -48,7 +48,7 @@ in bytes:
 * **Pallas kernel bodies are skipped** (VMEM tiles, not HBM); the
   ``pallas_call`` eqn's HBM operands/outputs are counted like any
   other eqn's;
-* other sub-jaxpr eqns (pjit/remat/shard_map/custom_vjp) descend with
+* other sub-jaxpr eqns (jit/remat/shard_map/custom_vjp) descend with
   operand families and donation flags propagated; their contribution is
   the inner peak beyond the operand bytes already counted at this
   level (clamped family-wise at zero).
@@ -74,6 +74,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from apex_tpu.lint.jaxpr_check import (
     _KERNEL_PRIMS,
+    _is_jit_eqn,
     as_jaxpr,
     aval_bytes,
     sub_jaxprs,
@@ -98,7 +99,7 @@ def _akey(var) -> Tuple[Tuple[int, ...], str]:
 class _Stats:
     peak: int
     peak_fams: Dict[str, int]
-    aliased: int      #: bytes saved by pjit donation aliasing
+    aliased: int      #: bytes saved by jit donation aliasing
     stash: int        #: stacked scan-ys bytes (the length×stash term)
     whiles: int       #: while bodies seen (bound excludes trip count)
     eqns: int
@@ -140,7 +141,7 @@ class MemoryReport:
 def _map_operands(name: str, eqn, sub, fam_of: Dict[Any, str]
                   ) -> Tuple[List[str], List[bool]]:
     """(families, reusable) for one sub-jaxpr's invars, propagated from
-    the eqn operands they bind: pjit carries its donation flags down
+    the eqn operands they bind: jit carries its donation flags down
     (a donated inner input may die at its last inner use), a scan's
     carry slots are working buffers, everything else is pinned for the
     sub-level's duration. A layout we cannot map positionally (while's
@@ -155,7 +156,7 @@ def _map_operands(name: str, eqn, sub, fam_of: Dict[Any, str]
     fams = ["temps" if _is_lit(v) else fam_of.get(v, "temps")
             for v in ops]
     reuse = [False] * n
-    if name == "pjit":
+    if _is_jit_eqn(eqn):
         donated = eqn.params.get("donated_invars") or ()
         if len(donated) == n:
             reuse = [bool(d) for d in donated]
@@ -182,7 +183,7 @@ def _level(j, fams: Sequence[str], reusable: Sequence[bool]) -> _Stats:
             last_use[v] = n
     donated_at: Dict[Any, int] = {}
     for i, eqn in enumerate(eqns):
-        if eqn.primitive.name == "pjit":
+        if _is_jit_eqn(eqn):
             donated = eqn.params.get("donated_invars") or ()
             for v, d in zip(eqn.invars, donated):
                 if d and not _is_lit(v) and v not in donated_at:
@@ -251,7 +252,7 @@ def _level(j, fams: Sequence[str], reusable: Sequence[bool]) -> _Stats:
             whiles += 1
 
         # aliasing: which outputs take over a dying operand's buffer
-        # instead of allocating. Three sound cases: (1) pjit donation —
+        # instead of allocating. Three sound cases: (1) jit donation —
         # the caller handed the buffer over (tallied for JXP602);
         # (2) a scan's init carry dying at the scan — the running carry
         # slot reuses it (the carry is sequential, never coexistent);
@@ -264,7 +265,7 @@ def _level(j, fams: Sequence[str], reusable: Sequence[bool]) -> _Stats:
         nk = eqn.params.get("num_carry") if name == "scan" else None
         avail_don: Dict[Any, List[Any]] = {}
         avail_gen: Dict[Any, List[Any]] = {}
-        if name == "pjit":
+        if _is_jit_eqn(eqn):
             donated = eqn.params.get("donated_invars") or ()
             for v, d in zip(eqn.invars, donated):
                 if d and not _is_lit(v) and _release(v) == i:
@@ -315,7 +316,7 @@ def _level(j, fams: Sequence[str], reusable: Sequence[bool]) -> _Stats:
         # a call-like eqn's outputs either already exist at the inner
         # peak moment (then they are inside `extra`) or do not exist yet
         # (then `out_new` is the larger later moment) — take the max,
-        # not the sum, or every pjit output double-counts.
+        # not the sum, or every jit output double-counts.
         if subs and name not in _KERNEL_PRIMS and name not in (
                 "scan", "while"):
             if sum(extra_f.values()) >= sum(out_new_f.values()):

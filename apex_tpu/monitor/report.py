@@ -11,8 +11,8 @@ per request — chrome://tracing / Perfetto). A requested section whose
 records are absent from the stream prints an explicit ``SKIP(reason)``
 line, never a silent empty section.
 
-The MFU convention is the same spec-peak one the bench artifact uses
-(``BENCH_r05.json``): analytic model FLOPs per token (from the ``meta``
+The MFU convention is the same spec-peak one the bench artifact uses:
+analytic model FLOPs per token (from the ``meta``
 record) × achieved tokens/s ÷ the chip's public peak dense bf16 FLOP/s
 (:data:`PEAK_FLOPS_BY_DEVICE`, which ``bench.py`` imports — one table, one
 code path). The headline tokens/s uses the **best** (minimum-duration)
@@ -29,7 +29,7 @@ from typing import Any, Dict, Iterable, List, Optional
 
 # peak dense bf16 FLOP/s per chip by device kind (public spec sheets) —
 # THE spec-peak table: bench.py and the report both read it, so "mfu" means
-# the same thing in BENCH_*.json and in `monitor report` output.
+# the same thing in a bench result and in `monitor report` output.
 PEAK_FLOPS_BY_DEVICE = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,

@@ -58,15 +58,6 @@ def span_path() -> str:
     return "/".join(_STACK)
 
 
-def _trace_state_clean() -> bool:
-    from jax import core
-
-    try:
-        return bool(core.trace_state_clean())
-    except AttributeError:  # future jax: assume host context
-        return True
-
-
 @contextlib.contextmanager
 def span(name: str, **attrs):
     """Instrument a region: ``with span("fwd_bwd"): ...``.
@@ -88,7 +79,7 @@ def span(name: str, **attrs):
 
     _STACK.append(name)
     path = "/".join(_STACK)
-    traced = not _trace_state_clean()
+    traced = not jax.core.trace_ctx.is_top_level()
     t0 = monotonic_ns()
     try:
         with jax.named_scope(name):

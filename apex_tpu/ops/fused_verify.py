@@ -24,30 +24,34 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.ops import _backend
-from apex_tpu.ops.pallas.verify import (NO_DRAFT, VERIFY_LANES,
-                                        fused_verify_fwd,
+from apex_tpu.ops.pallas.sampling import whole_rows_fit
+from apex_tpu.ops.pallas.verify import (NO_DRAFT, fused_verify_fwd,
                                         fused_verify_tree_fwd,
                                         verify_greedy, verify_sampled,
                                         verify_tree_greedy,
                                         verify_tree_sampled)
 
 
-def verify_kernel_ok(vocab: int, dtype) -> bool:
+def verify_kernel_ok(rows: int, vocab: int, dtype) -> bool:
     """Mosaic eligibility: the vocab is the lane dim of every whole-row
-    reduction (same rule as the fused sampling tail); f16 has no Mosaic
-    support."""
-    return vocab % 128 == 0 and dtype != jnp.float16
+    reduction (same rule as the fused sampling tail), f16 has no Mosaic
+    support, and the ``rows`` whole vocab rows one grid step keeps
+    resident must fit the VMEM budget the kernel asks for (at vocab
+    32768 that admits k + 1 <= 32 rows; the 33 rows of the longest
+    draft the drafters allow take the XLA composition)."""
+    return (vocab % 128 == 0 and dtype != jnp.float16
+            and whole_rows_fit(rows, vocab))
 
 
-def _pad_lanes(x, fill):
-    """Pad the trailing dim of a (b, k+1) operand to ``VERIFY_LANES``
-    (one full lane tile — covers every k the drafters allow) for the
-    kernel's tiling; contents beyond k+1 are ignored."""
-    b, k1 = x.shape
-    if k1 >= VERIFY_LANES:
-        return x
-    return jnp.pad(x, ((0, 0), (0, VERIFY_LANES - k1)),
-                   constant_values=fill)
+def _col(x):
+    """(b, R) per-row operand → the (b, R, 1) column form the shared
+    verify math and the kernels take (see ``ops.pallas.verify``)."""
+    return None if x is None else x[..., None]
+
+
+def _cells(*xs):
+    """The (b, 1, 1) result cells of the XLA fallback → (b,) each."""
+    return tuple(x[:, 0, 0] for x in xs)
 
 
 def fused_verify(logits: jax.Array, drafted: jax.Array,
@@ -112,19 +116,18 @@ def fused_verify(logits: jax.Array, drafted: jax.Array,
                                    maxval=1.0)
         u_gum = jax.random.uniform(kg, (b, k1, V), jnp.float32,
                                    minval=tiny, maxval=1.0)
-    ok = verify_kernel_ok(V, logits.dtype)
+    ok = verify_kernel_ok(k1, V, logits.dtype)
     if _backend.choose_impl(impl, ok) == "pallas":
         return fused_verify_fwd(
-            logits,
-            _pad_lanes(drafted_pad, NO_DRAFT),
-            None if u_acc is None else _pad_lanes(u_acc, 1.0),
-            u_gum, temperature=float(temperature), top_k=top_k,
+            logits, _col(drafted_pad), _col(u_acc), u_gum,
+            temperature=float(temperature), top_k=top_k,
             top_p=float(top_p), interpret=_backend.interpret_mode())
     if sampled:
-        return verify_sampled(logits, drafted_pad, u_acc, u_gum,
-                              temperature=float(temperature), top_k=top_k,
-                              top_p=float(top_p))
-    return verify_greedy(logits, drafted_pad)
+        return _cells(*verify_sampled(
+            logits, _col(drafted_pad), _col(u_acc), u_gum,
+            temperature=float(temperature), top_k=top_k,
+            top_p=float(top_p)))
+    return _cells(*verify_greedy(logits, _col(drafted_pad)))
 
 
 def fused_verify_tree(logits: jax.Array, tokens: jax.Array,
@@ -206,18 +209,16 @@ def fused_verify_tree(logits: jax.Array, tokens: jax.Array,
                                    maxval=1.0)
         u_gum = jax.random.uniform(kg, (b, n1, V), jnp.float32,
                                    minval=tiny, maxval=1.0)
-    ok = verify_kernel_ok(V, logits.dtype) and n1 <= VERIFY_LANES
+    ok = verify_kernel_ok(n1, V, logits.dtype)
     if _backend.choose_impl(impl, ok) == "pallas":
-        anc_pad = anc if n1 >= VERIFY_LANES else jnp.pad(
-            anc, ((0, 0), (0, 0), (0, VERIFY_LANES - n1)))
         return fused_verify_tree_fwd(
-            logits, _pad_lanes(tokens, NO_DRAFT),
-            _pad_lanes(parents, 0), anc_pad,
-            None if u_acc is None else _pad_lanes(u_acc, 1.0),
-            u_gum, temperature=float(temperature), top_k=top_k,
+            logits, _col(tokens), _col(parents), anc, _col(u_acc), u_gum,
+            temperature=float(temperature), top_k=top_k,
             top_p=float(top_p), interpret=_backend.interpret_mode())
     if sampled:
-        return verify_tree_sampled(logits, tokens, parents, anc, u_acc,
-                                   u_gum, temperature=float(temperature),
-                                   top_k=top_k, top_p=float(top_p))
-    return verify_tree_greedy(logits, tokens, parents, anc)
+        return _cells(*verify_tree_sampled(
+            logits, _col(tokens), _col(parents), anc, _col(u_acc), u_gum,
+            temperature=float(temperature), top_k=top_k,
+            top_p=float(top_p)))
+    return _cells(*verify_tree_greedy(logits, _col(tokens), _col(parents),
+                                      anc))

@@ -4,14 +4,6 @@ Each module holds raw ``pallas_call`` kernels; the ``jax.custom_vjp`` wiring
 and eligibility checks live one level up in ``apex_tpu/ops/*.py``.
 """
 
-from jax.experimental.pallas import tpu as _pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams across releases; the
-# kernels use the new name, so on older jax alias it once here (every
-# kernel module imports this package first).
-if not hasattr(_pltpu, "CompilerParams"):  # pragma: no cover - jax-version dep
-    _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-
 
 def exact_block(n: int, pref: int, quantum: int) -> int:
     """Largest ``quantum``-multiple divisor of ``n`` that is <= ``pref``, or

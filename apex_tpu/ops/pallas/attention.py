@@ -116,6 +116,17 @@ def _mask_scale(seed, t, i, j, bq, bk, rate):
 _REL_LANES = 128  # table rows pad to one full lane row; num_buckets <= 128
 
 
+def _rel_table_operand(table, head_map):
+    """(spec, arg) for the (hb, 128) head-major bucket table, one head row
+    per grid step. The table rides as (hb, 1, 128) with (1, 1, 128) blocks:
+    Mosaic takes a block whose last two dims EQUAL the array's, but refuses
+    a (1, 128) block over (hb, 128) — a second-minor block of 1 is neither
+    a multiple of 8 nor the full dim. ``head_map``: grid -> head row."""
+    return (pl.BlockSpec((1, 1, _REL_LANES),
+                         lambda *g: (head_map(*g), 0, 0)),
+            table[:, None, :])
+
+
 def relative_position_bucket(rel_pos, *, bidirectional, num_buckets,
                              max_distance):
     """T5's relative-position bucketing (mesh-tf
@@ -148,8 +159,9 @@ def relative_position_bucket(rel_pos, *, bidirectional, num_buckets,
 def _rel_bias_block(tab_ref, off_ref, i, j, bq, bk, rel):
     """(bq, bk) fp32 bias tile recomputed from grid coordinates: global
     positions from the (2,) SMEM offsets, buckets from the closed form,
-    values by a ``num_buckets``-step select-sum over this head's (1, 128)
-    table row. ``rel = (num_buckets, bidirectional, max_distance)``."""
+    values by a ``num_buckets``-step select-sum over this head's
+    (1, 1, 128) table block. ``rel = (num_buckets, bidirectional,
+    max_distance)``."""
     nb, bidir, maxd = rel
     rows = off_ref[0] + i * bq + jax.lax.broadcasted_iota(
         jnp.int32, (bq, 1), 0)
@@ -159,7 +171,7 @@ def _rel_bias_block(tab_ref, off_ref, i, j, bq, bk, rel):
         cols - rows, bidirectional=bidir, num_buckets=nb, max_distance=maxd)
     bias = jnp.zeros((bq, bk), jnp.float32)
     for b in range(nb):
-        bias = bias + jnp.where(buckets == b, tab_ref[0, b],
+        bias = bias + jnp.where(buckets == b, tab_ref[0, 0, b],
                                 jnp.float32(0.0))
     return bias
 
@@ -338,8 +350,10 @@ _SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 # (bq, bk) fp32 blocks are bq·bk·4 bytes double-buffered — 4 MB at 1024²,
 # too much VMEM next to the q/k/v/do blocks and accumulators; 1 MB at 512²
 # fits. The BUCKETED path carries one (1, 128) table row + a (2,) scalar
-# pair instead, so it tiles at the normal (uncapped) block sizes — the r6
-# change that removed the cap from the production relative-bias path.
+# pair instead, so its forward and dq/dkv kernels tile at the normal
+# (uncapped) block sizes — the r6 change that removed the cap from the
+# production relative-bias path. (Its dtable kernel caps again, for its
+# own VMEM reason: see _dtable_blocks.)
 _BIAS_BLOCK_CAP = 512
 
 
@@ -362,7 +376,7 @@ def _tail_operands(kv_lens, rows, dropout_rate, dropout_seed, lens_map,
     additive-score array with ``bias_map`` its grid->(row, qblk, kblk) map
     and ``bias_block`` the (1, bq, bk) block shape; ``rel`` the bucketed
     pair (table (hb, 128) fp32 head-major, offsets (2,) int32) with
-    ``rel_map`` the grid->(head row, 0) map. One assembly point so a
+    ``rel_map`` the grid->head row map. One assembly point so a
     future operand cannot be appended in the wrong order at one of the
     call sites."""
     specs, args = [], []
@@ -370,8 +384,9 @@ def _tail_operands(kv_lens, rows, dropout_rate, dropout_seed, lens_map,
         specs.append(pl.BlockSpec(bias_block, bias_map))
         args.append(bias)
     if rel is not None:
-        specs.append(pl.BlockSpec((1, _REL_LANES), rel_map))
-        args.append(rel[0])
+        spec, arg = _rel_table_operand(rel[0], rel_map)
+        specs.append(spec)
+        args.append(arg)
         specs.append(_SMEM_SPEC)
         args.append(rel[1])
     if kv_lens is not None:
@@ -429,7 +444,7 @@ def flash_fwd(q, k, v, *, scale, causal, kv_lens=None, bias=None,
     tail_specs, tail_args = _tail_operands(
         kv_lens, bh, dropout_rate, dropout_seed, lambda b, i, j: (b, 0, 0),
         bias, lambda b, i, j, hb=hb: (b % hb, i, j), (1, bq, bk),
-        rel, lambda b, i, j, rhb=rhb: (b % rhb, 0))
+        rel, lambda b, i, j, rhb=rhb: b % rhb)
     in_specs += tail_specs
     args += tail_args
 
@@ -824,7 +839,7 @@ def flash_fwd_bshd(q, k, v, *, scale, causal, kv_lens=None, bias=None,
         kv_lens, b, dropout_rate, dropout_seed,
         lambda t, i, j, h=h: (t // h, 0, 0),
         bias, lambda t, i, j, hb=hb: (t % hb, i, j), (1, bq, bk),
-        rel, lambda t, i, j, rhb=rhb: (t % rhb, 0))
+        rel, lambda t, i, j, rhb=rhb: t % rhb)
     in_specs += tail_specs
     args += tail_args
 
@@ -1200,7 +1215,19 @@ def _bwd_dtable_kernel(*refs, scale, causal, bq, bk, nq, nk, nb, hb, off,
     @pl.when(jnp.logical_and(jnp.logical_and(i == nq - 1, j == nk - 1),
                              b == nb - 1))
     def _finish():
-        dtab_ref[:] = acc_scr[:]
+        dtab_ref[0] = acc_scr[:]
+
+
+def _dtable_blocks(sq, sk, bq, bk):
+    """(bq, bk, nq, nk) for the dtable kernel's own grid. It holds the
+    score, probability, dP, dS and bucket tiles of one (bq, bk) step live
+    across the ``num_buckets`` masked reductions — at 1024² that is 41 MB
+    of scoped VMEM against Mosaic's 16 MB default on a v5e ("Scoped
+    allocation with size 41.14M and limit 16.00M exceeded"), so it tiles
+    at the materialized-bias cap whatever the dq/dkv kernels chose."""
+    bq = _fit_block(sq, min(bq, _BIAS_BLOCK_CAP))
+    bk = _fit_block(sk, min(bk, _BIAS_BLOCK_CAP))
+    return bq, bk, _blocks(sq, bq), _blocks(sk, bk)
 
 
 def _dtable_pallas(args, in_specs, *, hb, nq, nk, nb, bq, bk, scale,
@@ -1209,6 +1236,7 @@ def _dtable_pallas(args, in_specs, *, hb, nq, nk, nb, bq, bk, scale,
     layouts (only ``in_specs``/``args`` differ). Returns (hb, 128) fp32
     head-major bucket-table grads (caller slices/transposes back to the
     (num_buckets, hb) table shape)."""
+    # (hb, 1, 128) output rows for the same reason as _rel_table_operand
     return pl.pallas_call(
         functools.partial(_bwd_dtable_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nq=nq, nk=nk, nb=nb, hb=hb,
@@ -1216,9 +1244,9 @@ def _dtable_pallas(args, in_specs, *, hb, nq, nk, nb, bq, bk, scale,
                           rel=rel),
         grid=(hb, nq, nk, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, _REL_LANES),
-                               lambda th, i, j, b: (th, 0)),
-        out_shape=jax.ShapeDtypeStruct((hb, _REL_LANES), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, _REL_LANES),
+                               lambda th, i, j, b: (th, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((hb, 1, _REL_LANES), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, _REL_LANES), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             # every inner dim accumulates into the one output row, so the
@@ -1226,7 +1254,7 @@ def _dtable_pallas(args, in_specs, *, hb, nq, nk, nb, bq, bk, scale,
             dimension_semantics=("parallel", "arbitrary", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
-    )(*args)
+    )(*args)[:, 0]
 
 
 def flash_bwd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
@@ -1289,7 +1317,7 @@ def flash_bwd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
             pl.BlockSpec((1, bq, _LSE_LANES), lambda b, i, j: (b, i, 0)),
         ] + tail_specs(lambda b, i, j: (b, 0, 0),
                        lambda b, i, j, hb=hb: (b % hb, i, j),
-                       lambda b, i, j, rhb=rhb: (b % rhb, 0)),
+                       lambda b, i, j, rhb=rhb: b % rhb),
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
@@ -1314,7 +1342,7 @@ def flash_bwd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
             pl.BlockSpec((1, bq, _LSE_LANES), lambda b, j, i: (b, i, 0)),
         ] + tail_specs(lambda b, j, i: (b, 0, 0),
                        lambda b, j, i, hb=hb: (b % hb, i, j),
-                       lambda b, j, i, rhb=rhb: (b % rhb, 0)),
+                       lambda b, j, i, rhb=rhb: b % rhb),
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
@@ -1343,9 +1371,12 @@ def flash_bwd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
         dv = dv.reshape(-1, group, sk, d).sum(1).astype(v.dtype)
     if rel is not None:
         nb = bh // rhb
+        bq, bk, nq, nk = _dtable_blocks(sq, sk, bq, bk)
         qmap = lambda th, i, j, b, rhb=rhb: (b * rhb + th, i, 0)  # noqa: E731
         kmap = lambda th, i, j, b, rhb=rhb, g=group: (  # noqa: E731
             (b * rhb + th) // g, j, 0)
+        tab_spec, tab_arg = _rel_table_operand(
+            rel[0], lambda th, i, j, b: th)
         dt_specs = [
             pl.BlockSpec((1, bq, d), qmap),
             pl.BlockSpec((1, bk, d), kmap),
@@ -1353,10 +1384,10 @@ def flash_bwd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
             pl.BlockSpec((1, bq, d), qmap),
             pl.BlockSpec((1, bq, _LSE_LANES), qmap),
             pl.BlockSpec((1, bq, _LSE_LANES), qmap),
-            pl.BlockSpec((1, _REL_LANES), lambda th, i, j, b: (th, 0)),
+            tab_spec,
             _SMEM_SPEC,
         ]
-        dt_args = [q, k, v, do, lse3, delta3, rel[0], rel[1]]
+        dt_args = [q, k, v, do, lse3, delta3, tab_arg, rel[1]]
         if varlen:
             dt_specs.append(pl.BlockSpec(
                 (1, 1, _LSE_LANES),
@@ -1450,7 +1481,7 @@ def flash_bwd_bshd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
         kv_lens, b, dropout_rate, dropout_seed,
         lambda t, i, j, h=h: (t // h, 0, 0),
         bias, lambda t, i, j, hb=hb: (t % hb, i, j), (1, bq, bk),
-        rel, lambda t, i, j, rhb=rhb: (t % rhb, 0))
+        rel, lambda t, i, j, rhb=rhb: t % rhb)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
@@ -1481,7 +1512,7 @@ def flash_bwd_bshd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
         kv_lens, b, dropout_rate, dropout_seed,
         lambda t, j, i, h=h: (t // h, 0, 0),
         bias, lambda t, j, i, hb=hb: (t % hb, i, j), (1, bq, bk),
-        rel, lambda t, j, i, rhb=rhb: (t % rhb, 0))
+        rel, lambda t, j, i, rhb=rhb: t % rhb)
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
@@ -1515,12 +1546,15 @@ def flash_bwd_bshd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
         # dtable: global q-head row r = b·rhb + th over the folded
         # (b, s, h·d) operands via (r // h, ·, r % h)
         nb = (b * h) // rhb
+        bq, bk, nq, nk = _dtable_blocks(sq, sk, bq, bk)
         qmap = lambda th, i, j, bi, rhb=rhb, h=h: (  # noqa: E731
             (bi * rhb + th) // h, i, (bi * rhb + th) % h)
         kmap = lambda th, i, j, bi, rhb=rhb, h=h, g=group: (  # noqa: E731
             (bi * rhb + th) // h, j, ((bi * rhb + th) % h) // g)
         rmap = lambda th, i, j, bi, rhb=rhb, h=h: (  # noqa: E731
             (bi * rhb + th) // h, (bi * rhb + th) % h, i, 0)
+        tab_spec, tab_arg = _rel_table_operand(
+            rel[0], lambda th, i, j, bi: th)
         dt_specs = [
             pl.BlockSpec((1, bq, d), qmap),
             pl.BlockSpec((1, bk, d), kmap),
@@ -1528,10 +1562,10 @@ def flash_bwd_bshd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
             pl.BlockSpec((1, bq, d), qmap),
             pl.BlockSpec((1, 1, bq, _LSE_LANES), rmap),
             pl.BlockSpec((1, 1, bq, _LSE_LANES), rmap),
-            pl.BlockSpec((1, _REL_LANES), lambda th, i, j, bi: (th, 0)),
+            tab_spec,
             _SMEM_SPEC,
         ]
-        dt_args = [q3, k3, v3, do3, lse4, delta4, rel[0], rel[1]]
+        dt_args = [q3, k3, v3, do3, lse4, delta4, tab_arg, rel[1]]
         if varlen:
             dt_specs.append(pl.BlockSpec(
                 (1, 1, _LSE_LANES),

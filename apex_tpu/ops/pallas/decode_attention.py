@@ -62,16 +62,22 @@ def _decode_kernel(*refs, scale, bk, nk, rel=None, quant=False):
     in VMEM or HBM.
 
     ``rel = (num_buckets, max_distance)`` (static) adds the T5 CAUSAL
-    bucketed relative bias recomputed in-kernel from a (group, 128)
+    bucketed relative bias recomputed in-kernel from a (1, group, 128)
     head-major table block: the query IS position ``kvlen - 1``, so
     rel_pos = col − (kvlen − 1) needs no extra operand beyond the table —
     the decode sibling of the flash kernels' ``rel_bias``.
 
-    ``quant`` (static): the k/v refs hold int8 rows and two extra
-    (1, bk) fp32 refs carry the per-row scales — the block dequantizes
-    IN VMEM right after its (halved) HBM→VMEM copy, so the decode
-    stream pays int8 bandwidth and fp32 math (the whole point of the
+    ``quant`` (static): the k/v refs hold 1-byte rows and two extra
+    (1, 1, bk) fp32 refs carry the per-row scales, so the decode stream
+    pays 1-byte bandwidth and fp32 math (the whole point of the
     quantized pool: the kernel is HBM-bound, the bytes are the cost).
+    A row's scale is shared by its d cells, so it factors out of both
+    contractions: ``q·(k_j s_j) = (q·k_j) s_j`` scales score COLUMN j and
+    ``Σ_j p_j (v_j s_j) = Σ_j (p_j s_j) v_j`` scales probability column
+    j. The scales therefore stay in the lane orientation they arrive in
+    (a (1, bk) row broadcast over the group's sublanes) — dequantizing
+    the (bk, d) rows themselves would need the row turned into a (bk, 1)
+    column, a lane→sublane relayout Mosaic does not do.
     """
     refs = list(refs)
     q_ref, k_ref, v_ref, len_ref = refs[:4]
@@ -100,14 +106,15 @@ def _decode_kernel(*refs, scale, bk, nk, rel=None, quant=False):
     def _step():
         q = q_ref[0]  # (group, d) — the kv group's query heads
         if quant:
-            # in-VMEM dequantize: int8 block × per-row fp32 scale
-            k = k_ref[0].astype(jnp.float32) * ks_ref[0][:, None]
+            k = k_ref[0].astype(jnp.float32)
             q = q.astype(jnp.float32)
         else:
             k = k_ref[0]  # (bk, d)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # (group, bk)
+        if quant:
+            s = s * ks_ref[0]
         cols = j * bk + jax.lax.broadcasted_iota(
             jnp.int32, (q.shape[0], bk), 1)
         if rel is not None:
@@ -118,7 +125,7 @@ def _decode_kernel(*refs, scale, bk, nk, rel=None, quant=False):
             bias = jnp.zeros(s.shape, jnp.float32)
             for b in range(nbk):
                 bias = bias + jnp.where(buckets == b,
-                                        rtab_ref[:, b:b + 1],
+                                        rtab_ref[0, :, b:b + 1],
                                         jnp.float32(0.0))
             s = s + bias
         s = jnp.where(cols < kvlen, s, NEG_INF)
@@ -128,9 +135,9 @@ def _decode_kernel(*refs, scale, bk, nk, rel=None, quant=False):
         alpha = jnp.exp(m_prev - m_new)
         l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
         if quant:
-            v = v_ref[0].astype(jnp.float32) * vs_ref[0][:, None]
             acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
+                p * vs_ref[0], v_ref[0].astype(jnp.float32),
+                (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
         else:
             acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
@@ -144,6 +151,20 @@ def _decode_kernel(*refs, scale, bk, nk, rel=None, quant=False):
         # kernels' dead-row convention)
         l = jnp.maximum(l_scr[:], 1e-30)
         o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
+
+
+def _rel_group_operand(table, group, row_map):
+    """(spec, arg) for the (h, 128) head-major bias table, one kv group's
+    q heads per grid row: rows iterate (batch, kv head), so row r reads
+    table rows [(r % h_kv)·group, +group). The table rides as
+    (h_kv, group, 128) with (1, group, 128) blocks — Mosaic takes a block
+    whose last two dims EQUAL the array's but refuses a (group, 128) block
+    over (h, 128) when group is neither a multiple of 8 nor h (MHA's
+    group of 1, GQA's 4). ``row_map``: grid -> row r."""
+    h_kv = table.shape[0] // group
+    return (pl.BlockSpec((1, group, _REL_LANES),
+                         lambda *g: (row_map(*g) % h_kv, 0, 0)),
+            table.reshape(h_kv, group, _REL_LANES))
 
 
 def decode_attn_fwd(q, k, v, lengths, *, scale, rel_bias=None, bk=512,
@@ -172,13 +193,9 @@ def decode_attn_fwd(q, k, v, lengths, *, scale, rel_bias=None, bk=512,
     ]
     args = [q, k, v, _kvlen_rows(lengths, rows)]
     if rel is not None:
-        # rows iterate (batch, kv head); table rows are q heads — row r's
-        # group block sits at head offset (r % h_kv)·group
-        h_kv = rel.shape[0] // group
-        in_specs.append(pl.BlockSpec(
-            (group, _REL_LANES),
-            lambda b, j, hk=h_kv: (b % hk, 0)))
-        args.append(rel)
+        spec, arg = _rel_group_operand(rel, group, lambda b, j: b)
+        in_specs.append(spec)
+        args.append(arg)
 
     return pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, bk=bk, nk=nk,
@@ -228,8 +245,8 @@ def decode_attn_paged_fwd(q, k_pool, v_pool, lengths, block_tables, *,
     ``k_scale``/``v_scale``: the int8-pool path — ``(num_blocks, bs)``
     fp32 per-row scales riding their own scalar-prefetched index maps
     (the SAME table lookup, minus the h_kv fold: scales are shared
-    across kv heads and head_dim); the kernel dequantizes each block in
-    VMEM, so the HBM stream is int8 (indirection-oblivious, like the
+    across kv heads and head_dim); the kernel applies them in VMEM, so
+    the HBM stream is 1 byte per cell (indirection-oblivious, like the
     bucketed bias).
     """
     rows, group, d = q.shape
@@ -254,16 +271,18 @@ def decode_attn_paged_fwd(q, k_pool, v_pool, lengths, block_tables, *,
     ]
     args = [q, k_pool, v_pool, _kvlen_rows(lengths, rows)]
     if quant:
-        in_specs.append(pl.BlockSpec(
-            (1, bs), lambda r, j, tbl, hk=h_kv: (tbl[r // hk, j], 0)))
-        in_specs.append(pl.BlockSpec(
-            (1, bs), lambda r, j, tbl, hk=h_kv: (tbl[r // hk, j], 0)))
-        args.extend([k_scale, v_scale])
+        # (num_blocks, 1, bs) with (1, 1, bs) blocks: the last two block
+        # dims must equal the array's (a (1, bs) block over
+        # (num_blocks, bs) is refused — second-minor 1 is neither a
+        # multiple of 8 nor the full dim)
+        scale_spec = pl.BlockSpec(
+            (1, 1, bs), lambda r, j, tbl, hk=h_kv: (tbl[r // hk, j], 0, 0))
+        in_specs.extend([scale_spec, scale_spec])
+        args.extend([k_scale[:, None, :], v_scale[:, None, :]])
     if rel is not None:
-        in_specs.append(pl.BlockSpec(
-            (group, _REL_LANES),
-            lambda r, j, tbl, hk=h_kv: (r % hk, 0)))
-        args.append(rel)
+        spec, arg = _rel_group_operand(rel, group, lambda r, j, tbl: r)
+        in_specs.append(spec)
+        args.append(arg)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,

@@ -44,6 +44,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.ops.pallas.attention import _LSE_LANES
 
@@ -113,16 +114,17 @@ def apply_top_p(s, top_p):
     return jnp.where(s >= t, s, FILTERED)
 
 
-def gumbel_argmax(s, u):
+def gumbel_argmax(s, u, keepdims=False):
     """One categorical draw over softmax(s) per row via the Gumbel trick;
     ties broken to the lowest index (argmax convention). ``u`` uniform in
-    (0, 1] — the caller clamps 0 away so log(u) is finite."""
+    (0, 1] — the caller clamps 0 away so log(u) is finite. The kernels
+    pass ``keepdims=True``: Mosaic has no layout for the rank-1 result."""
     g = -jnp.log(-jnp.log(u))
     x = s + g
     m = jnp.max(x, axis=-1, keepdims=True)
     V = x.shape[-1]
     idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
-    return jnp.min(jnp.where(x == m, idx, V), axis=-1)
+    return jnp.min(jnp.where(x == m, idx, V), axis=-1, keepdims=keepdims)
 
 
 def filtered_scaled(logits, *, temperature, top_k, top_p):
@@ -137,32 +139,73 @@ def filtered_scaled(logits, *, temperature, top_k, top_p):
     return s
 
 
+#: logits rows per grid step: one full sublane tile, so every whole-row
+#: reduction fills its vregs (a 1-row block would use 1 sublane in 8)
+_ROWS = 8
+
+
+_VMEM_FLOOR, _VMEM_CAP = 16 * 2 ** 20, 100 * 2 ** 20
+
+
+def _whole_row_bytes(rows, vocab):
+    """VMEM a kernel needs to keep ``rows`` whole fp32 vocab rows resident
+    (sublane-padded to 8) and run the bisection filters over them: the
+    double-buffered operand blocks plus the filter's live temporaries
+    (scaled row, exp row, Gumbel row, index iota, per-step where/compare)
+    come to under 24 row-blocks."""
+    return 24 * (-(-rows // 8) * 8) * vocab * 4
+
+
+def whole_rows_fit(rows, vocab) -> bool:
+    """Whether that many whole rows fit the VMEM a kernel may ask for
+    (100 of a v5e's 128 MiB) — part of the sampling and verify kernels'
+    eligibility gates."""
+    return _whole_row_bytes(rows, vocab) <= _VMEM_CAP
+
+
+def whole_row_vmem_limit(rows, vocab):
+    """``vmem_limit_bytes`` for such a kernel. Mosaic's default scoped
+    limit on a v5e is 16 MiB, which 8 rows of a 32768 vocab (1 MiB a
+    block) already crowd and a larger vocab exceeds."""
+    return min(_VMEM_CAP, max(_VMEM_FLOOR, _whole_row_bytes(rows, vocab)))
+
+
 def _sample_kernel(logits_ref, u_ref, o_ref, *, temperature, top_k, top_p):
-    """One grid row: the whole (1, V) logits row is VMEM-resident, every
-    reduction below runs on it in place — the only HBM traffic is the two
-    row reads and the 8-lane index write."""
+    """One grid step: ``_ROWS`` whole logits rows are VMEM-resident, every
+    reduction below runs on them in place — the only HBM traffic is the
+    two block reads and the 8-lane index write."""
     s = filtered_scaled(logits_ref[:], temperature=temperature,
                         top_k=top_k, top_p=top_p)
-    idx = gumbel_argmax(s, u_ref[:])
-    o_ref[:] = jnp.broadcast_to(idx[:, None], (1, _LSE_LANES))
+    idx = gumbel_argmax(s, u_ref[:], keepdims=True)
+    o_ref[:] = jnp.broadcast_to(idx, o_ref.shape)
 
 
 def fused_sample_fwd(logits, u, *, temperature, top_k, top_p,
                      interpret=False):
     """(b, V) logits + (b, V) uniform noise → (b,) int32 tokens; one
-    kernel invocation, grid over rows. V must be a 128-multiple (lane
-    tiling); the op-level wrapper gates on that."""
+    kernel invocation, grid over ``_ROWS``-row blocks (the batch is padded
+    up to a whole number of them — a (1, V) block over (b, V) is refused
+    by Mosaic: a second-minor block dim must be a multiple of 8 or the
+    full dim). V must be a 128-multiple (lane tiling); the op-level
+    wrapper gates on that."""
     b, V = logits.shape
+    pad = -b % _ROWS
+    if pad:  # pad rows sample garbage that is sliced away below
+        logits = jnp.pad(logits, ((0, pad), (0, 0)))
+        u = jnp.pad(u, ((0, pad), (0, 0)), constant_values=1.0)
     out = pl.pallas_call(
         functools.partial(_sample_kernel, temperature=temperature,
                           top_k=top_k, top_p=top_p),
-        grid=(b,),
+        grid=((b + pad) // _ROWS,),
         in_specs=[
-            pl.BlockSpec((1, V), lambda i: (i, 0)),
-            pl.BlockSpec((1, V), lambda i: (i, 0)),
+            pl.BlockSpec((_ROWS, V), lambda i: (i, 0)),
+            pl.BlockSpec((_ROWS, V), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, _LSE_LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, _LSE_LANES), jnp.int32),
+        out_specs=pl.BlockSpec((_ROWS, _LSE_LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((b + pad, _LSE_LANES), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=whole_row_vmem_limit(_ROWS, V)),
         interpret=interpret,
     )(logits, u)
-    return out[:, 0]
+    return out[:b, 0]

@@ -21,14 +21,17 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.ops import _backend
-from apex_tpu.ops.pallas.sampling import (filtered_scaled, fused_sample_fwd,
-                                          gumbel_argmax)
+from apex_tpu.ops.pallas.sampling import (_ROWS, filtered_scaled,
+                                          fused_sample_fwd, gumbel_argmax,
+                                          whole_rows_fit)
 
 
 def sample_kernel_ok(vocab: int, dtype) -> bool:
     """Mosaic eligibility: the vocab is the lane dim of every whole-row
-    reduction, so it must be a 128-multiple; f16 has no Mosaic support."""
-    return vocab % 128 == 0 and dtype != jnp.float16
+    reduction, so it must be a 128-multiple; f16 has no Mosaic support;
+    and one grid step's whole rows must fit the VMEM budget."""
+    return (vocab % 128 == 0 and dtype != jnp.float16
+            and whole_rows_fit(_ROWS, vocab))
 
 
 def fused_sample(logits: jax.Array, key: Optional[jax.Array] = None, *,

@@ -417,16 +417,6 @@ def get_rank_info() -> str:
 
 # --- in-shard_map rank helpers ----------------------------------------------
 
-# jax moved shard_map out of experimental and renamed its replication-check
-# kwarg (check_rep -> check_vma) across releases; resolve both once here so
-# the whole repo rides one entry point on any supported jax.
-if hasattr(jax, "shard_map"):
-    _jax_shard_map, _CHECK_KW = jax.shard_map, "check_vma"
-else:  # pragma: no cover - jax-version dependent
-    from jax.experimental.shard_map import shard_map as _jax_shard_map
-    _CHECK_KW = "check_rep"
-
-
 def shard_map(f, mesh=None, *, in_specs, out_specs, check_vma: bool = False):
     """``jax.shard_map`` bound to the global mesh, with the
     varying-manual-axes check off by default: Megatron-style TP code is full
@@ -435,29 +425,16 @@ def shard_map(f, mesh=None, *, in_specs, out_specs, check_vma: bool = False):
     invariants at runtime instead (e.g. ``distributed.py:340-348``).
 
     The global mesh is resolved at *call* time so wrappers may be built
-    before ``initialize_model_parallel()`` and survive re-initialization.
-
-    OLD-JAX HAZARD (the ``jax.experimental`` fallback, jax < 0.6):
-    that implementation transposes ``lax.psum`` to ``psum`` (with the
-    replication check on OR off), so ``jax.grad`` taken INSIDE the
-    wrapper of a loss that explicitly ``psum``s yields gradients scaled
-    by the axis size. The framework's own losses are unaffected (the
-    TP/pipeline grad-parity suites pass on 0.4.x — their collectives ride
-    custom VJPs with hand-written transposes, e.g.
-    ``vocab_parallel_cross_entropy``), but user code differentiating a
-    hand-psum'd scalar inside ``shard_map`` should either take the grad
-    OUTSIDE the wrapper or divide by ``lax.axis_size`` on old jax."""
+    before ``initialize_model_parallel()`` and survive re-initialization."""
     if mesh is not None:
-        return _jax_shard_map(
+        return jax.shard_map(
             f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            **{_CHECK_KW: check_vma}
-        )
+            check_vma=check_vma)
 
     def call(*args, **kwargs):
-        return _jax_shard_map(
+        return jax.shard_map(
             f, mesh=get_mesh(), in_specs=in_specs, out_specs=out_specs,
-            **{_CHECK_KW: check_vma},
-        )(*args, **kwargs)
+            check_vma=check_vma)(*args, **kwargs)
 
     return call
 

@@ -1387,6 +1387,15 @@ class ServingEngine:
         # allocator — one without the other would serve garbage rows)
         if pool is None:
             pool = self.init_pool()
+            if self.tp == 1:
+                # commit the fresh pool beside the params: every step
+                # returns a COMMITTED pool whenever the params are
+                # committed (a checkpoint restore, a trainer's mesh
+                # output, an explicit device_put), and a pool that went
+                # uncommitted -> committed between the first and second
+                # dispatch would be a second prefill_chunk executable
+                pool = jax.device_put(
+                    pool, jax.tree.leaves(params)[0].sharding)
         stats = ServeStats()
         # per-transition lifecycle records skip the per-line sink flush
         # inside the loop (one flush at the end) — the dominant cost of

@@ -298,5 +298,8 @@ def verify_greedy_tp(logits_local, drafted, *, axis=TENSOR_AXIS):
     drafted_pad = jnp.concatenate(
         [drafted.astype(jnp.int32),
          jnp.full((s, 1), NO_DRAFT, jnp.int32)], axis=1)
-    a = accepted_prefix_len(cand == drafted_pad)
-    return a, select_row(cand, a)
+    # the helpers take per-row operands as (S, k+1, 1) columns and
+    # return (S, 1, 1) cells
+    cand = cand[..., None]
+    a = accepted_prefix_len(cand == drafted_pad[..., None])
+    return a[:, 0, 0], select_row(cand, a)[:, 0, 0]

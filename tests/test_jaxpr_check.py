@@ -40,7 +40,7 @@ def _tp_mesh(n=4):
 class TestWalker:
     def _nested_program(self):
         """One program threading all five higher-order primitives the
-        ISSUE names: pjit, scan, while, cond, custom_vjp — inside a
+        ISSUE names: jit, scan, while, cond, custom_vjp — inside a
         shard_map."""
 
         @jax.custom_vjp
@@ -69,13 +69,13 @@ class TestWalker:
         closed = jax.make_jaxpr(fn)(*args)
         sites = list(jx.iter_sites(closed))
         prims = {s.prim for s in sites}
-        for prim in ("pjit", "scan", "while", "cond",
-                     "custom_vjp_call_jaxpr", "shard_map"):
+        for prim in ("jit", "scan", "while", "cond",
+                     "custom_vjp_call", "shard_map"):
             assert prim in prims, f"walker never saw {prim}"
         # eqns INSIDE each higher-order body were visited: their paths
         # carry the enclosing segment
         paths = {s.path for s in sites}
-        for seg in ("scan:5", "while", "cond", "custom_vjp_call_jaxpr",
+        for seg in ("scan:5", "while", "cond", "custom_vjp_call",
                     "shard_map"):
             assert any(seg in p for p in paths), (
                 f"no site under {seg}: {sorted(paths)}")

@@ -428,15 +428,22 @@ class TestValidateTool:
         bad_path.write_text(json.dumps(bad) + "\n")
         assert tool.main([str(bad_path)]) == 1
 
-    def test_repo_bench_artifacts_validate(self):
+    def test_driver_envelope_validates(self, tmp_path):
+        """A driver envelope (``{"parsed": {...bench result...}}``) is
+        unwrapped and its payload schema-checked."""
         tool = _load_validate_tool()
-        root = os.path.join(os.path.dirname(__file__), "..")
-        bench_files = sorted(
-            f for f in os.listdir(root)
-            if f.startswith("BENCH_") and f.endswith(".json"))
-        assert bench_files, "repo lost its bench artifacts"
-        for name in bench_files:
-            assert tool.validate_file(os.path.join(root, name)) == [], name
+        result = {"metric": "gpt_medium_train_step_throughput",
+                  "value": 1000.0, "unit": "tokens/s/chip",
+                  "vs_baseline": 2.0, "mfu": 0.5, "model_tflops": 100.0,
+                  "donated": True, "spread_pct": 0.1,
+                  "pass_times_ms": [1.0, 1.0, 1.0]}
+        good = tmp_path / "BENCH_r01.json"
+        good.write_text(json.dumps({"n": 1, "rc": 0, "parsed": result}))
+        assert tool.validate_file(str(good)) == []
+        bad = tmp_path / "BENCH_r02.json"
+        bad.write_text(json.dumps(
+            {"n": 2, "rc": 0, "parsed": dict(result, value="nan")}))
+        assert tool.validate_file(str(bad)) != []
 
 
 class TestLoggingSatellite:

@@ -111,10 +111,10 @@ class TestFusedVerify:
 
     @pytest.mark.parametrize("K", [8, 32])
     def test_kernel_handles_long_drafts(self, K):
-        """The drafted-id/noise operands ride a full 128-lane block —
-        every k validate_drafter allows must run the kernel path, not
-        crash at the old 8-lane carrier width (review finding): K=8 is
-        the first broken width, K=32 the MAX_DRAFT_K ceiling."""
+        """The drafted-id/noise operands ride (k+1, 1) columns — every k
+        validate_drafter allows must run the kernel path, not crash at
+        the old 8-lane carrier width (review finding): K=8 is the first
+        width that broke, K=32 the MAX_DRAFT_K ceiling."""
         logits = self._logits(b=2, K=K, seed=K)
         drafted = jnp.asarray(np.asarray(jnp.argmax(logits, -1))[:, :K])
         a1, t1 = fused_verify(logits, drafted, impl="xla")

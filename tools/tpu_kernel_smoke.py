@@ -1,92 +1,461 @@
+"""Compile every Pallas kernel family on the attached TPU and check each
+against its XLA composition at the flagship's shapes (hidden 1024, 8 heads
+of 128, seq 1024, vocab 32768, 8 serving slots, 128-row cache blocks).
+
+    python tools/tpu_kernel_smoke.py          # exits non-zero on any failure
+
+A family is ``(kernel_fn, reference_fn, args)``: both run under ``jax.jit``
+on the same operands and every output leaf (forward values AND gradients)
+must agree — floats to a relative-to-scale tolerance, integers (sampled
+tokens, accept lengths) exactly. ``main()`` returns the names of the families
+whose outputs disagree; a kernel Mosaic refuses to compile raises straight
+through (no handler here catches it), so no failure can leave exit code 0.
+``chip_smoke.py`` imports ``main`` and runs it in its own process — the chip
+belongs to one process at a time.
+
+``auto=True`` marks the families ``impl="auto"`` can select on a TPU; the
+rest are reachable only through an explicit ``impl="pallas"`` (``auto``
+resolves them to XLA on measured grounds, PERF.md).
+"""
+
+import dataclasses
+import os
 import sys
-sys.path.insert(0, __import__("os").path.dirname(__import__("os").path.dirname(__import__("os").path.abspath(__file__))))
-import numpy as np
-import jax, jax.numpy as jnp
+import time
+from typing import Callable, Tuple
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
 import jax.random as jr
+import numpy as np
 
-k = jr.PRNGKey(0)
-ok = []
+from apex_tpu.ops import (BucketedBias, decode_attention, flash_attention,
+                          fused_dense, fused_dense_gelu_dense,
+                          fused_layer_norm, fused_rms_norm, fused_sample,
+                          fused_verify, fused_verify_tree, mlp,
+                          scaled_masked_softmax,
+                          scaled_upper_triang_masked_softmax)
+from apex_tpu.ops import _backend
+from apex_tpu.ops.attention import (bshd_output_projection,
+                                    bshd_qkv_projection, fused_qkv_attention)
+from apex_tpu.transformer.tensor_parallel import vocab_parallel_cross_entropy
 
-def check(name, fn, *args):
-    try:
-        out = jax.jit(fn)(*args)
-        np.asarray(jax.tree.leaves(out)[0])  # host fetch = reliable sync
-        ok.append(name)
-        print(f"PASS {name}")
-    except Exception as e:
-        print(f"FAIL {name}: {str(e)[:300]}")
+# the flagship's widths (bench.py's on_tpu config); batch is cut to 2 —
+# kernels tile over batch·heads rows, so 2 covers the row index maps
+B, S, H, D, V = 2, 1024, 8, 128, 32768
+HID = H * D
+SLOTS, BLOCK = 8, 128          # the serving engine's slot array / page
+F32_TOL, BF16_TOL = 2e-3, 3e-2
 
-# layer norm fwd+bwd, bf16 weights (the GPT bench path)
-from apex_tpu.ops import fused_layer_norm, fused_rms_norm
-x = jr.normal(k, (512, 1024), jnp.bfloat16)
-w = jnp.ones((1024,), jnp.bfloat16); b = jnp.zeros((1024,), jnp.bfloat16)
-check("ln fwd", lambda x, w, b: fused_layer_norm(x, w, b, impl="pallas"), x, w, b)
-check("ln bwd", jax.grad(lambda x, w, b: fused_layer_norm(x, w, b, impl="pallas").astype(jnp.float32).sum(), argnums=(0, 1, 2)), x, w, b)
-check("rms bwd", jax.grad(lambda x, w: fused_rms_norm(x, w, impl="pallas").astype(jnp.float32).sum(), argnums=(0, 1)), x, w)
 
-# softmax
-from apex_tpu.ops import scaled_upper_triang_masked_softmax, scaled_masked_softmax
-s = jr.normal(k, (8, 256, 256), jnp.bfloat16)
-check("causal softmax fwd+bwd", jax.grad(lambda s: scaled_upper_triang_masked_softmax(s, 0.125, impl="pallas").astype(jnp.float32).sum()), s)
-mask = jnp.zeros((8, 256, 256), bool)
-check("masked softmax", lambda s: scaled_masked_softmax(s, mask, 0.125, impl="pallas"), s)
+@dataclasses.dataclass(frozen=True)
+class Family:
+    name: str
+    build: Callable[[], Tuple[Callable, Callable, tuple]]
+    auto: bool = True
+    tol: float = BF16_TOL
 
-# matmul bias act
-from apex_tpu.ops import fused_dense, fused_dense_gelu_dense, mlp
-xd = jr.normal(k, (1024, 1024), jnp.bfloat16)
-wd = jr.normal(k, (4096, 1024), jnp.bfloat16) * 0.02
-bd = jnp.zeros((4096,), jnp.bfloat16)
-check("fused_dense fwd", lambda x, w, b: fused_dense(x, w, b, impl="pallas"), xd, wd, bd)
-check("fused_dense bwd", jax.grad(lambda x, w, b: fused_dense(x, w, b, impl="pallas").astype(jnp.float32).sum(), argnums=(0, 1, 2)), xd, wd, bd)
-w2 = jr.normal(k, (1024, 4096), jnp.bfloat16) * 0.02
-b2 = jnp.zeros((1024,), jnp.bfloat16)
-check("dgd bwd", jax.grad(lambda x: fused_dense_gelu_dense(x, wd, bd, w2, b2, impl="pallas").astype(jnp.float32).sum()), xd)
-check("mlp bwd", jax.grad(lambda x: mlp(x, [wd], [bd], "relu", impl="pallas").astype(jnp.float32).sum()), xd)
 
-# flash attention
-from apex_tpu.ops.attention import flash_attention, fused_qkv_attention
-q = jr.normal(k, (8, 512, 64), jnp.bfloat16)
-check("flash fwd", lambda q: flash_attention(q, q, q, causal=True, impl="pallas"), q)
-check("flash bwd", jax.grad(lambda q: flash_attention(q, q, q, causal=True, impl="pallas").astype(jnp.float32).sum()), q)
+def _key(i):
+    return jr.fold_in(jr.PRNGKey(0), i)
 
-# seq-major (bshd) + fused attention block (the r3 zero-copy flagship path)
-qb = jr.normal(k, (2, 512, 4, 128), jnp.bfloat16)
-check("flash bshd fwd", lambda q: flash_attention(
-    q, q, q, causal=True, impl="pallas", layout="bshd"), qb)
-check("flash bshd bwd", jax.grad(lambda q: flash_attention(
-    q, q, q, causal=True, impl="pallas",
-    layout="bshd").astype(jnp.float32).sum()), qb)
-xf = jr.normal(k, (2, 512, 512), jnp.bfloat16)
-wqkv = jr.normal(k, (3 * 4 * 128, 512), jnp.bfloat16) * 0.02
-bqkv = jnp.zeros((3 * 4 * 128,), jnp.bfloat16)
-wout = jr.normal(k, (512, 4 * 128), jnp.bfloat16) * 0.02
-check("fused_qkv_attention fwd", lambda x: fused_qkv_attention(
-    x, wqkv, bqkv, wout, None, None, None, 4, 4, 128, 128 ** -0.5, True),
-    xf)
-check("fused_qkv_attention bwd", jax.grad(lambda x: fused_qkv_attention(
-    x, wqkv, bqkv, wout, None, None, None, 4, 4, 128, 128 ** -0.5,
-    True).astype(jnp.float32).sum()), xf)
-check("fused_qkv_attention dropout fwd", lambda x: fused_qkv_attention(
-    x, wqkv, bqkv, wout, None, jnp.int32(7), None, 4, 4, 128, 128 ** -0.5,
-    True, 0.1), xf)
-biash = jr.normal(k, (4, 512, 512), jnp.float32) * 0.5
-check("fused_qkv_attention bias bwd", jax.grad(lambda x: fused_qkv_attention(
-    x, wqkv, bqkv, wout, biash, None, None, 4, 4, 128, 128 ** -0.5,
-    True).astype(jnp.float32).sum()), xf)
-check("flash bias bwd", jax.grad(lambda q: flash_attention(
-    q, q, q, causal=True, impl="pallas",
-    bias=biash[:1, :, :]).astype(jnp.float32).sum()), q)
-check("flash dropout bwd", jax.grad(lambda q: flash_attention(
-    q, q, q, causal=True, impl="pallas", dropout_rate=0.1,
-    dropout_seed=jnp.int32(7)).astype(jnp.float32).sum()), q)
 
-# fused optimizers (multi-tensor engine)
-from apex_tpu.optimizers import fused_adam, fused_lamb, fused_sgd
-params = {"a": jr.normal(k, (1024, 1024)), "b": jr.normal(k, (333,))}
-grads = jax.tree.map(lambda p: p * 0.01, params)
-for name, ctor in [("adam", fused_adam), ("lamb", fused_lamb), ("sgd", fused_sgd)]:
-    opt = ctor(learning_rate=1e-3) if name != "sgd" else ctor(learning_rate=1e-3, momentum=0.9)
-    st = opt.init(params)
-    check(f"fused_{name}", lambda g, s, p: opt.update(g, s, p), grads, st, params)
+def _fwd_and_grads(fn, argnums):
+    """(output, grads wrt ``argnums``) under a fixed random cotangent — one
+    family then checks the forward and every backward kernel behind it."""
+    def run(*args):
+        def loss(*a):
+            out = fn(*a)
+            cot = jr.normal(_key(99), out.shape, jnp.float32)
+            return (out.astype(jnp.float32) * cot).sum(), out
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=argnums, has_aux=True)(*args)
+        return out, grads
+    return run
 
-print(f"{len(ok)} kernels pass on", jax.devices()[0].device_kind)
+
+# --- flash attention ----------------------------------------------------------
+
+def _qkv_bshd(h_kv=H):
+    q = jr.normal(_key(1), (B, S, H, D), jnp.bfloat16)
+    k = jr.normal(_key(2), (B, S, h_kv, D), jnp.bfloat16)
+    v = jr.normal(_key(3), (B, S, h_kv, D), jnp.bfloat16)
+    return q, k, v
+
+
+def _kv_lens():
+    return jnp.array([S, 300], jnp.int32)  # one full row, one padded
+
+
+def _flash_family(layout, h_kv=H, varlen=False, dropout=0.0):
+    def build():
+        q, k, v = _qkv_bshd(h_kv)
+        if layout == "bhsd":
+            q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+        kw = {}
+        if varlen:  # per batch (bshd) / per (batch, head) (flat)
+            kw["kv_lens"] = (_kv_lens() if layout == "bshd"
+                             else jnp.repeat(_kv_lens()[:, None], H, 1))
+        if dropout:
+            kw.update(dropout_rate=dropout, dropout_seed=jnp.int32(7))
+
+        def make(impl):
+            return _fwd_and_grads(
+                lambda q, k, v: flash_attention(
+                    q, k, v, causal=True, layout=layout, impl=impl, **kw),
+                (0, 1, 2))
+        return make("pallas"), make("xla"), (q, k, v)
+    return build
+
+
+def _flash_bias_family(layout, bucketed):
+    """Materialized (h, s, s) bias + dbias, or the in-kernel bucketed bias
+    + the dtable kernel — gradients flow to the bias operand too."""
+    def build():
+        q, k, v = _qkv_bshd()
+        if layout == "bhsd":
+            q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+        if bucketed:
+            operand = jr.normal(_key(4), (32, H), jnp.float32) * 0.5
+            wrap = lambda t: BucketedBias(t, bidirectional=False,  # noqa: E731
+                                          max_distance=128)
+        else:
+            operand = jr.normal(_key(4), (H, S, S), jnp.float32) * 0.5
+            wrap = lambda a: a  # noqa: E731
+
+        def make(impl):
+            return _fwd_and_grads(
+                lambda q, k, v, t: flash_attention(
+                    q, k, v, causal=True, layout=layout, impl=impl,
+                    bias=wrap(t)),
+                (0, 1, 2, 3))
+        return make("pallas"), make("xla"), (q, k, v, operand)
+    return build
+
+
+def _packed_family(varlen=False, dropout=0.0, biased=False):
+    """``fused_qkv_attention`` (the flagship train step's attention block:
+    packed projection → flash kernels reading head windows → output
+    projection) against separate projections around the XLA attention."""
+    def build():
+        x = jr.normal(_key(5), (B, S, HID), jnp.bfloat16)
+        w_qkv = jr.normal(_key(6), (3 * HID, HID), jnp.bfloat16) * 0.02
+        b_qkv = jr.normal(_key(7), (3 * HID,), jnp.bfloat16) * 0.02
+        w_out = jr.normal(_key(8), (HID, HID), jnp.bfloat16) * 0.02
+        scale = D ** -0.5
+        seed = jnp.int32(7) if dropout else None
+        lens = _kv_lens() if varlen else None
+        args = (x, w_qkv, b_qkv, w_out)
+        if biased:  # differentiated too: the packed dbias kernel
+            args += (jr.normal(_key(4), (H, S, S), jnp.float32) * 0.5,)
+
+        def kernel(x, w_qkv, b_qkv, w_out, bias=None):
+            return fused_qkv_attention(x, w_qkv, b_qkv, w_out, bias, seed,
+                                       lens, H, H, D, scale, True, dropout)
+
+        def reference(x, w_qkv, b_qkv, w_out, bias=None):
+            q, k, v = bshd_qkv_projection(x, w_qkv, b_qkv, H, H, D)
+            ctx = flash_attention(q, k, v, causal=True, layout="bshd",
+                                  impl="xla", kv_lens=lens, bias=bias,
+                                  dropout_rate=dropout, dropout_seed=seed)
+            return bshd_output_projection(ctx, w_out, H, D)
+
+        argnums = tuple(range(len(args)))
+        return (_fwd_and_grads(kernel, argnums),
+                _fwd_and_grads(reference, argnums), args)
+    return build
+
+
+# --- cross entropy ------------------------------------------------------------
+
+def _xent_family():
+    logits = jr.normal(_key(9), (B, S, V), jnp.bfloat16)
+    target = jr.randint(_key(10), (B, S), 0, V)
+
+    def make(impl):
+        return _fwd_and_grads(
+            lambda lg: vocab_parallel_cross_entropy(lg, target, 0.0, None,
+                                                    impl), (0,))
+    return make("pallas"), make("xla"), (logits,)
+
+
+# --- decode attention ---------------------------------------------------------
+
+def _lengths():
+    """One live length per slot, on and off the 128-row block edges."""
+    return jnp.array([1, 77, 128, 129, 500, 640, 1000, 1024], jnp.int32)
+
+
+def _decode_bias():
+    return BucketedBias(jr.normal(_key(14), (32, H), jnp.float32) * 0.5,
+                        bidirectional=False, max_distance=128)
+
+
+def _decode_family(h_kv, bias=False):
+    def build():
+        q = jr.normal(_key(11), (SLOTS, H, D), jnp.bfloat16)
+        k = jr.normal(_key(12), (SLOTS, h_kv, S, D), jnp.bfloat16)
+        v = jr.normal(_key(13), (SLOTS, h_kv, S, D), jnp.bfloat16)
+        rel = _decode_bias() if bias else None
+
+        def make(impl):
+            return lambda q, k, v: decode_attention(
+                q, k, v, _lengths(), impl=impl, bias=rel)
+        return make("pallas"), make("xla"), (q, k, v)
+    return build
+
+
+def _paged_family(h_kv, bias=False, kv_dtype=None):
+    """The serving engine's layout: a shared (num_blocks, h_kv, 128, d) pool
+    addressed through per-slot block tables (block 0 is the dead block)."""
+    def build():
+        nb = S // BLOCK
+        num_blocks = SLOTS * nb + 1
+        q = jr.normal(_key(11), (SLOTS, H, D), jnp.bfloat16)
+        k = jr.normal(_key(12), (num_blocks, h_kv, BLOCK, D), jnp.bfloat16)
+        v = jr.normal(_key(13), (num_blocks, h_kv, BLOCK, D), jnp.bfloat16)
+        tables = (1 + jr.permutation(_key(15), SLOTS * nb)
+                  ).reshape(SLOTS, nb).astype(jnp.int32)
+        scales = {}
+        if kv_dtype is not None:
+            from apex_tpu.serving.engine import KV_QUANT_SPECS, _quant_rows
+            qmax, qdtype = KV_QUANT_SPECS[kv_dtype]
+            k, ks = _quant_rows(k, (1, 3), qmax=qmax, qdtype=qdtype)
+            v, vs = _quant_rows(v, (1, 3), qmax=qmax, qdtype=qdtype)
+            scales = dict(k_scale=ks, v_scale=vs)
+        rel = _decode_bias() if bias else None
+
+        def make(impl):
+            return lambda q, k, v: decode_attention(
+                q, k, v, _lengths(), impl=impl, bias=rel, block_tables=tables,
+                **scales)
+        return make("pallas"), make("xla"), (q, k, v)
+    return build
+
+
+# --- sampling and speculative verification ------------------------------------
+
+def _logits(rows):
+    return jr.normal(_key(16), rows + (V,), jnp.float32) * 3.0
+
+
+def _sample_family(**knobs):
+    def build():
+        def make(impl):
+            return lambda lg: fused_sample(lg, _key(17), impl=impl, **knobs)
+        return make("pallas"), make("xla"), (_logits((SLOTS,)),)
+    return build
+
+
+def _verify_family(**knobs):
+    def build():
+        k = 4
+        logits = _logits((SLOTS, k + 1))
+        # drafts that agree with the target on a per-slot prefix, so accept
+        # lengths cover 0..k
+        greedy = jnp.argmax(logits[:, :k], -1).astype(jnp.int32)
+        keep = jnp.arange(k)[None, :] < (jnp.arange(SLOTS) % (k + 1))[:, None]
+        drafted = jnp.where(keep, greedy, (greedy + 1) % V)
+
+        def make(impl):
+            return lambda lg: fused_verify(lg, drafted, _key(18), impl=impl,
+                                           **knobs)
+        return make("pallas"), make("xla"), (logits,)
+    return build
+
+
+def _verify_tree_family(**knobs):
+    def build():
+        from apex_tpu.spec.tree import draft_tree
+        tree = draft_tree(2, 3)                   # branching 2, depth 3
+        parents, anc = tree.operands(SLOTS)
+        logits = _logits((SLOTS, tree.n1))
+        # each node carries its parent's greedy candidate on even slots
+        # (the whole first-child path accepts) and a miss on odd ones
+        cand = jnp.argmax(logits, -1).astype(jnp.int32)
+        par = jnp.asarray(parents)
+        hit = jnp.take_along_axis(cand, par, axis=1)
+        odd = (jnp.arange(SLOTS) % 2 == 1)[:, None]
+        tokens = jnp.where(odd, (hit + 1) % V, hit)
+
+        def make(impl):
+            return lambda lg: fused_verify_tree(
+                lg, tokens, par, jnp.asarray(anc), _key(19), impl=impl,
+                **knobs)
+        return make("pallas"), make("xla"), (logits,)
+    return build
+
+
+# --- the explicit-only families (auto resolves them to XLA) --------------------
+
+def _ln_family(rms):
+    def build():
+        x = jr.normal(_key(20), (512, HID), jnp.bfloat16)
+        w = 1.0 + 0.1 * jr.normal(_key(21), (HID,), jnp.bfloat16)
+        b = 0.1 * jr.normal(_key(22), (HID,), jnp.bfloat16)
+
+        def make(impl):
+            if rms:
+                return _fwd_and_grads(
+                    lambda x, w: fused_rms_norm(x, w, impl=impl), (0, 1))
+            return _fwd_and_grads(
+                lambda x, w, b: fused_layer_norm(x, w, b, impl=impl),
+                (0, 1, 2))
+        return make("pallas"), make("xla"), ((x, w) if rms else (x, w, b))
+    return build
+
+
+def _softmax_family(causal):
+    def build():
+        s = jr.normal(_key(23), (8, 256, 256), jnp.bfloat16)
+        mask = jr.bernoulli(_key(24), 0.2, (8, 256, 256))
+
+        def make(impl):
+            if causal:
+                return _fwd_and_grads(
+                    lambda s: scaled_upper_triang_masked_softmax(
+                        s, 0.125, impl=impl), (0,))
+            return _fwd_and_grads(
+                lambda s: scaled_masked_softmax(s, mask, 0.125, impl=impl),
+                (0,))
+        return make("pallas"), make("xla"), (s,)
+    return build
+
+
+def _dense_family(kind):
+    def build():
+        x = jr.normal(_key(25), (1024, HID), jnp.bfloat16)
+        w1 = jr.normal(_key(26), (4 * HID, HID), jnp.bfloat16) * 0.02
+        b1 = jr.normal(_key(27), (4 * HID,), jnp.bfloat16) * 0.02
+        w2 = jr.normal(_key(28), (HID, 4 * HID), jnp.bfloat16) * 0.02
+        b2 = jr.normal(_key(29), (HID,), jnp.bfloat16) * 0.02
+
+        def make(impl):
+            if kind == "dense":
+                return _fwd_and_grads(
+                    lambda x, w, b: fused_dense(x, w, b, impl=impl),
+                    (0, 1, 2))
+            if kind == "dense_gelu_dense":
+                return _fwd_and_grads(
+                    lambda x: fused_dense_gelu_dense(x, w1, b1, w2, b2,
+                                                     impl=impl), (0,))
+            return _fwd_and_grads(
+                lambda x: mlp(x, [w1], [b1], "relu", impl=impl), (0,))
+        args = (x, w1, b1) if kind == "dense" else (x,)
+        return make("pallas"), make("xla"), args
+    return build
+
+
+FAMILIES = (
+    Family("flash bshd fwd/dq/dkv", _flash_family("bshd")),
+    Family("flash flat fwd/dq/dkv", _flash_family("bhsd")),
+    Family("flash bshd GQA group 4", _flash_family("bshd", h_kv=2)),
+    Family("flash bshd kv_lens", _flash_family("bshd", varlen=True)),
+    Family("flash flat kv_lens", _flash_family("bhsd", varlen=True)),
+    Family("flash bshd dropout", _flash_family("bshd", dropout=0.1)),
+    Family("flash flat dropout", _flash_family("bhsd", dropout=0.1)),
+    Family("flash bshd bias + dbias", _flash_bias_family("bshd", False)),
+    Family("flash flat bias + dbias", _flash_bias_family("bhsd", False)),
+    Family("flash bshd bucketed bias + dtable",
+           _flash_bias_family("bshd", True)),
+    Family("flash flat bucketed bias + dtable",
+           _flash_bias_family("bhsd", True)),
+    Family("flash packed fwd/bwd", _packed_family()),
+    Family("flash packed kv_lens", _packed_family(varlen=True)),
+    Family("flash packed dropout", _packed_family(dropout=0.1)),
+    Family("flash packed bias + dbias", _packed_family(biased=True)),
+    Family("xentropy stats", _xent_family, tol=F32_TOL),
+    Family("decode contiguous MHA", _decode_family(H)),
+    Family("decode contiguous GQA group 4", _decode_family(2)),
+    Family("decode contiguous bucketed bias", _decode_family(H, bias=True)),
+    Family("decode paged MHA", _paged_family(H)),
+    Family("decode paged GQA group 4", _paged_family(2)),
+    Family("decode paged bucketed bias", _paged_family(H, bias=True)),
+    Family("decode paged int8 pool", _paged_family(H, kv_dtype="int8")),
+    Family("decode paged fp8 pool", _paged_family(H, kv_dtype="fp8_e4m3")),
+    Family("fused_sample greedy", _sample_family(temperature=0.0)),
+    Family("fused_sample top-k", _sample_family(temperature=0.8, top_k=50)),
+    Family("fused_sample top-p", _sample_family(temperature=0.8, top_p=0.9)),
+    Family("fused_sample top-k + top-p", _sample_family(
+        temperature=0.8, top_k=50, top_p=0.9)),
+    Family("fused_verify greedy", _verify_family(temperature=0.0)),
+    Family("fused_verify sampled", _verify_family(
+        temperature=0.8, top_k=50, top_p=0.9)),
+    Family("fused_verify_tree greedy", _verify_tree_family(temperature=0.0)),
+    Family("fused_verify_tree sampled", _verify_tree_family(
+        temperature=0.8, top_k=50, top_p=0.9)),
+    Family("layer norm fwd/bwd", _ln_family(False), auto=False),
+    Family("rms norm fwd/bwd", _ln_family(True), auto=False),
+    Family("causal softmax fwd/bwd", _softmax_family(True), auto=False),
+    Family("masked softmax fwd/bwd", _softmax_family(False), auto=False),
+    Family("fused_dense fwd/bwd", _dense_family("dense"), auto=False),
+    Family("dense_gelu_dense bwd", _dense_family("dense_gelu_dense"),
+           auto=False),
+    Family("mlp bwd", _dense_family("mlp"), auto=False),
+)
+
+
+def max_error(got, want) -> float:
+    """Largest disagreement over all output leaves: floats as max |a-b|
+    over max |b| (a scale-relative error — gradients span decades);
+    integer leaves count any mismatch as error 1."""
+    worst = 0.0
+    got_leaves, want_leaves = jax.tree.leaves(got), jax.tree.leaves(want)
+    if len(got_leaves) != len(want_leaves):
+        raise ValueError(f"output trees differ: {len(got_leaves)} vs "
+                         f"{len(want_leaves)} leaves")
+    for a, b in zip(got_leaves, want_leaves):
+        a, b = np.asarray(a), np.asarray(b)
+        if a.shape != b.shape:
+            raise ValueError(f"output shapes differ: {a.shape} vs {b.shape}")
+        if np.issubdtype(b.dtype, np.integer):
+            err = float(np.any(a != b))
+        else:
+            a, b = a.astype(np.float32), b.astype(np.float32)
+            if not (np.isfinite(a).all() and np.isfinite(b).all()):
+                return float("inf")
+            err = float(np.abs(a - b).max() / (np.abs(b).max() + 1e-6))
+        worst = max(worst, err)
+    return worst
+
+
+def check(family: Family):
+    """Compile, then run, one family's kernel and reference on the same
+    operands: ``(max error, compile seconds, run seconds)``."""
+    kernel, reference, args = family.build()
+    t0 = time.perf_counter()
+    compiled = [jax.jit(fn).lower(*args).compile()
+                for fn in (kernel, reference)]
+    t1 = time.perf_counter()
+    got, want = jax.block_until_ready([c(*args) for c in compiled])
+    return max_error(got, want), t1 - t0, time.perf_counter() - t1
+
+
+def main(families=FAMILIES) -> list:
+    """Run every family; print one line each; return the failing names."""
+    if _backend.interpret_mode():
+        raise RuntimeError(
+            f"kernel smoke needs a TPU: on {jax.default_backend()!r} every "
+            f"Pallas kernel would run interpreted, which checks nothing "
+            f"Mosaic compiles")
+    failed, compile_s, run_s = [], 0.0, 0.0
+    for fam in families:
+        err, c_s, r_s = check(fam)
+        compile_s, run_s = compile_s + c_s, run_s + r_s
+        ok = err <= fam.tol
+        if not ok:
+            failed.append(fam.name)
+        print(f"{'PASS' if ok else 'FAIL'} {fam.name}: max err {err:.2e} "
+              f"(tol {fam.tol:.0e}, {'auto' if fam.auto else 'explicit'}, "
+              f"compile {c_s:.1f} s, run {r_s:.2f} s)", flush=True)
+    print(f"{len(families) - len(failed)}/{len(families)} kernel families "
+          f"match their XLA composition on {jax.devices()[0].device_kind}")
+    print(f"kernels: compile {compile_s:.1f} s, run {run_s:.1f} s "
+          f"(operands are built outside both)", flush=True)
+    return failed
+
+
+if __name__ == "__main__":
+    sys.exit(1 if main() else 0)
