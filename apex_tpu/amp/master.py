@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from apex_tpu.amp.policy import Policy
 from apex_tpu.amp.scaler import apply_if_finite
+from apex_tpu.monitor import spans as monitor_spans
 from apex_tpu.utils.pytree import tree_cast
 
 PyTree = Any
@@ -59,10 +60,11 @@ def apply_updates_with_master(
     """Apply optax-style additive ``updates`` to the fp32 masters, skip when
     grads overflowed, and re-derive the model params. The full O2 step
     epilogue as one pure function."""
-    new_master = jax.tree.map(lambda p, u: p + jnp.asarray(u, p.dtype), weights.master, updates)
-    if grads_finite is not None:
-        new_master = apply_if_finite(weights.master, new_master, grads_finite)
-    return dataclasses.replace(weights, master=new_master).resync()
+    with monitor_spans.span("amp/apply_master"):
+        new_master = jax.tree.map(lambda p, u: p + jnp.asarray(u, p.dtype), weights.master, updates)
+        if grads_finite is not None:
+            new_master = apply_if_finite(weights.master, new_master, grads_finite)
+        return dataclasses.replace(weights, master=new_master).resync()
 
 
 def o2_state_dict_params(weights: MasterWeights) -> PyTree:

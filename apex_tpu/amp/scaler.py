@@ -27,6 +27,7 @@ from typing import Any, Callable, Tuple
 import jax
 import jax.numpy as jnp
 
+from apex_tpu.monitor import spans as monitor_spans
 from apex_tpu.utils.pytree import tree_all_finite
 
 PyTree = Any
@@ -154,14 +155,16 @@ def scaled_value_and_grad(
                 return scale_loss(scaler, loss), aux
             return scale_loss(scaler, out)
 
-        if has_aux:
-            (scaled, aux), grads = jax.value_and_grad(scaled_fn, has_aux=True)(*args, **kwargs)
-        else:
-            scaled, grads = jax.value_and_grad(scaled_fn)(*args, **kwargs)
-            aux = None
-        grads = unscale_grads(scaler, grads)
-        finite = all_finite(grads)
-        new_scaler = update_loss_scaler(scaler, finite)
+        with monitor_spans.span("amp/fwd_bwd"):
+            if has_aux:
+                (scaled, aux), grads = jax.value_and_grad(scaled_fn, has_aux=True)(*args, **kwargs)
+            else:
+                scaled, grads = jax.value_and_grad(scaled_fn)(*args, **kwargs)
+                aux = None
+        with monitor_spans.span("amp/unscale_check"):
+            grads = unscale_grads(scaler, grads)
+            finite = all_finite(grads)
+            new_scaler = update_loss_scaler(scaler, finite)
         loss = scaled / scaler.loss_scale
         if has_aux:
             return (loss, aux), (grads, finite, new_scaler)

@@ -252,8 +252,8 @@ class DecodeEngine:
         exactly once per (batch, cache shape)."""
         # trace-time step-anatomy span: every HLO of the decode step
         # carries the decode_step scope into device traces (the join key
-        # `monitor report --anatomy` correlates on); no-op when
-        # monitoring is off, and never touches the zero-recompile avals
+        # `monitor report --anatomy` correlates on), monitor on or off;
+        # entered once per trace, never touching the zero-recompile avals
         with monitor_spans.span("decode_step"):
             if self.tp > 1:
                 return self._tp_decode(params, cache, tokens, pos, key)

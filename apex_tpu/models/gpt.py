@@ -23,6 +23,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from apex_tpu.monitor import spans as monitor_spans
 from apex_tpu.ops import fused_layer_norm, scaled_upper_triang_masked_softmax
 from apex_tpu.ops.attention import flash_attention, seed_from_key
 from apex_tpu.transformer import tensor_parallel as tp_lib
@@ -621,7 +622,8 @@ class GPTModel:
     def _block(self, p, x, key):
         """Residual block. Dense: → new x. MoE: → (new x, router aux)."""
         c = self.config
-        a = self._attention(p, fused_layer_norm(x, p["ln1_w"], p["ln1_b"]), key)
+        with monitor_spans.span("gpt/attn"):
+            a = self._attention(p, fused_layer_norm(x, p["ln1_w"], p["ln1_b"]), key)
         if c.remat and c.remat_policy in ("save_attn", "save_attn_mlp"):
             from jax.ad_checkpoint import checkpoint_name
 
@@ -635,7 +637,8 @@ class GPTModel:
 
         if c.remat and c.remat_policy == "mlp_only":
             mlp_half = jax.checkpoint(mlp_half)
-        m = mlp_half(p, x)
+        with monitor_spans.span("gpt/mlp"):
+            m = mlp_half(p, x)
         aux = None
         if self.moe:
             m, aux = m
@@ -774,22 +777,23 @@ class GPTModel:
         per-layer means."""
         c = self.config
         s = tokens.shape[1]
-        x = self.embedding(params["embedding"], tokens)
-        if c.cp_axis is not None:
-            # tokens are a sequence shard: gather the shard's GLOBAL
-            # positions (zigzag stripes under ring)
-            x = x + params["pos_embedding"][self._cp_positions(s)]
-            if key is not None:
-                # decorrelate the residual-dropout streams per cp rank:
-                # each shard holds DIFFERENT global token positions, so an
-                # unfolded key would hand them identical local-coordinate
-                # keep masks (ADVICE r4). GPTPipeline folds its data-like
-                # axes (incl. cp) before its stage fns — which bypass this
-                # method — so the fold lives here for the direct path only.
-                key = jax.random.fold_in(
-                    key, jax.lax.axis_index(c.cp_axis))
-        else:
-            x = x + params["pos_embedding"][:s]
+        with monitor_spans.span("gpt/embed"):
+            x = self.embedding(params["embedding"], tokens)
+            if c.cp_axis is not None:
+                # tokens are a sequence shard: gather the shard's GLOBAL
+                # positions (zigzag stripes under ring)
+                x = x + params["pos_embedding"][self._cp_positions(s)]
+            else:
+                x = x + params["pos_embedding"][:s]
+        if c.cp_axis is not None and key is not None:
+            # decorrelate the residual-dropout streams per cp rank:
+            # each shard holds DIFFERENT global token positions, so an
+            # unfolded key would hand them identical local-coordinate
+            # keep masks (ADVICE r4). GPTPipeline folds its data-like
+            # axes (incl. cp) before its stage fns — which bypass this
+            # method — so the fold lives here for the direct path only.
+            key = jax.random.fold_in(
+                key, jax.lax.axis_index(c.cp_axis))
         if self.sp:
             x = self._sp_scatter(x)  # residual stream is seq-sharded
 
@@ -868,11 +872,12 @@ class GPTModel:
         aux dict (per-layer-mean load_balance_loss / router_z_loss /
         drop_fraction — the drop stat training loops should log)."""
         x, aux = self.hidden_states_with_aux(params, tokens, key)
-        logits = self.unembed(params, x)
-        losses = tp_lib.vocab_parallel_cross_entropy(
-            logits, targets, axis_name=self.axis
-        )
-        loss = tp_lib.masked_mean(losses, loss_mask)
+        with monitor_spans.span("gpt/unembed_xent"):
+            logits = self.unembed(params, x)
+            losses = tp_lib.vocab_parallel_cross_entropy(
+                logits, targets, axis_name=self.axis
+            )
+            loss = tp_lib.masked_mean(losses, loss_mask)
         if self.moe:
             c = self.config
             loss = (loss + c.moe_aux_coeff * aux["load_balance_loss"]

@@ -74,7 +74,7 @@ def _ring_span(op, payload, axis_name):
     ``<op>_ring_<axis>`` named scope into device traces, and the span
     record carries the per-hop chunk size (``bytes``) for CostDB
     calibration — the hop count rides the ``_count_ppermute`` counters.
-    No-op while monitoring is disabled."""
+    The scope is entered always; the record only while monitoring is on."""
     from apex_tpu.monitor import spans as monitor_spans
 
     return monitor_spans.collective_span(f"{op}_ring", payload, axis_name)

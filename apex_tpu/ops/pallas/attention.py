@@ -453,6 +453,7 @@ def flash_fwd(q, k, v, *, scale, causal, kv_lens=None, bias=None,
                           bq=bq, bk=bk, nk=nk, off=sk - sq, varlen=varlen,
                           rate=dropout_rate, has_bias=bias is not None,
                           rel=rel_static),
+        name="flash_fwd",
         grid=(bh, nq, nk),
         in_specs=in_specs,
         out_specs=[
@@ -523,6 +524,7 @@ def flash_fwd_packed(qkv, h, h_kv, d, *, scale, causal, kv_lens=None,
                           bq=bq, bk=bk, nk=nk, off=0, varlen=varlen,
                           bshd=True, rate=dropout_rate,
                           has_bias=bias is not None),
+        name="flash_fwd_packed",
         grid=(b * h, nq, nk),
         in_specs=in_specs,
         out_specs=[
@@ -657,6 +659,7 @@ def flash_bwd_packed(qkv, h, h_kv, d, o, lse, do, *, scale, causal,
         dq, dk, dv = pl.pallas_call(
             functools.partial(_bwd_single_block_kernel, scale=scale,
                               causal=causal, n=s, rate=dropout_rate),
+            name="flash_bwd_packed_fused",
             grid=(b * h,),
             in_specs=sb_specs,
             out_specs=[pl.BlockSpec((1, s, d), qm)] * 3,
@@ -693,6 +696,7 @@ def flash_bwd_packed(qkv, h, h_kv, d, o, lse, do, *, scale, causal,
                           bq=bq, bk=bk, nk=nk, off=0, varlen=varlen,
                           bshd=True, rate=dropout_rate,
                           has_bias=bias is not None),
+        name="flash_bwd_packed_dq",
         grid=(b * h, nq, nk),
         in_specs=[pl.BlockSpec((1, bq, d), qm),
                   pl.BlockSpec((1, bk, d), km),
@@ -727,6 +731,7 @@ def flash_bwd_packed(qkv, h, h_kv, d, o, lse, do, *, scale, causal,
                           bq=bq, bk=bk, nq=nq, off=0, varlen=varlen,
                           bshd=True, rate=dropout_rate,
                           has_bias=bias is not None),
+        name="flash_bwd_packed_dkv",
         grid=(b * h, nk, nq),
         in_specs=[pl.BlockSpec((1, bq, d), qm2),
                   pl.BlockSpec((1, bk, d), km2),
@@ -848,6 +853,7 @@ def flash_fwd_bshd(q, k, v, *, scale, causal, kv_lens=None, bias=None,
                           bq=bq, bk=bk, nk=nk, off=sk - sq, varlen=varlen,
                           bshd=True, rate=dropout_rate,
                           has_bias=bias is not None, rel=rel_static),
+        name="flash_fwd_bshd",
         grid=(b * h, nq, nk),
         in_specs=in_specs,
         out_specs=[
@@ -1111,6 +1117,7 @@ def _dbias_pallas(args, in_specs, *, hb, sq, sk, nq, nk, nb, bq, bk, scale,
         functools.partial(_bwd_dbias_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nb=nb, hb=hb, off=off,
                           varlen=varlen, bshd=bshd, rate=rate),
+        name="flash_bwd_dbias",
         grid=(hb, nq, nk, nb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bq, bk),
@@ -1242,6 +1249,7 @@ def _dtable_pallas(args, in_specs, *, hb, nq, nk, nb, bq, bk, scale,
                           bq=bq, bk=bk, nq=nq, nk=nk, nb=nb, hb=hb,
                           off=off, varlen=varlen, bshd=bshd, rate=rate,
                           rel=rel),
+        name="flash_bwd_dtable",
         grid=(hb, nq, nk, nb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, _REL_LANES),
@@ -1307,6 +1315,7 @@ def flash_bwd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
                           bq=bq, bk=bk, nk=nk, off=sk - sq, varlen=varlen,
                           rate=dropout_rate, has_bias=bias is not None,
                           rel=rel_static),
+        name="flash_bwd_dq",
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
@@ -1332,6 +1341,7 @@ def flash_bwd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
                           bq=bq, bk=bk, nq=nq, off=sk - sq, varlen=varlen,
                           rate=dropout_rate, has_bias=bias is not None,
                           rel=rel_static),
+        name="flash_bwd_dkv",
         grid=(bh, nk, nq),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),
@@ -1488,6 +1498,7 @@ def flash_bwd_bshd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
                           bq=bq, bk=bk, nk=nk, off=sk - sq, varlen=varlen,
                           bshd=True, rate=dropout_rate,
                           has_bias=bias is not None, rel=rel_static),
+        name="flash_bwd_bshd_dq",
         grid=(b * h, nq, nk),
         in_specs=[q_spec(qm), kv_spec(km), kv_spec(km), q_spec(qm),
                   row_spec(rm), row_spec(rm)] + extra_specs,
@@ -1519,6 +1530,7 @@ def flash_bwd_bshd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
                           bq=bq, bk=bk, nq=nq, off=sk - sq, varlen=varlen,
                           bshd=True, rate=dropout_rate,
                           has_bias=bias is not None, rel=rel_static),
+        name="flash_bwd_bshd_dkv",
         grid=(b * h, nk, nq),
         in_specs=[q_spec(qm2), kv_spec(km2), kv_spec(km2), q_spec(qm2),
                   row_spec(rm2), row_spec(rm2)] + extra_specs2,

@@ -200,6 +200,7 @@ def decode_attn_fwd(q, k, v, lengths, *, scale, rel_bias=None, bk=512,
     return pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, bk=bk, nk=nk,
                           rel=rel_static),
+        name="decode_attn",
         grid=(rows, nk),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, group, d), lambda b, j: (b, 0, 0)),
@@ -298,6 +299,7 @@ def decode_attn_paged_fwd(q, k_pool, v_pool, lengths, block_tables, *,
     return pl.pallas_call(
         functools.partial(_paged_kernel, scale=scale, bk=bs, nk=nb,
                           rel=rel_static, quant=quant),
+        name="decode_attn_paged",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, group, d), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -101,6 +101,7 @@ def ln_fwd(x2d, weight, bias, *, eps: float, rms: bool, interpret: bool):
 
     y, mean, rstd = pl.pallas_call(
         kernel,
+        name="ln_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -185,6 +186,7 @@ def ln_bwd(dy2d, x2d, mean, rstd, weight, *, rms: bool, interpret: bool):
 
     dx, dw, db = pl.pallas_call(
         kernel,
+        name="ln_bwd",
         grid=(nblocks,),
         in_specs=in_specs,
         out_specs=[

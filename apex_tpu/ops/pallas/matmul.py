@@ -99,6 +99,7 @@ def matmul_bias_act(
 
     out = pl.pallas_call(
         kernel,
+        name="matmul_bias_act",
         grid=(Mp // bm, Np // bn, k_steps),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),

@@ -196,6 +196,7 @@ def fused_sample_fwd(logits, u, *, temperature, top_k, top_p,
     out = pl.pallas_call(
         functools.partial(_sample_kernel, temperature=temperature,
                           top_k=top_k, top_p=top_p),
+        name="fused_sample",
         grid=((b + pad) // _ROWS,),
         in_specs=[
             pl.BlockSpec((_ROWS, V), lambda i: (i, 0)),

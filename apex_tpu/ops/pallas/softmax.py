@@ -82,6 +82,7 @@ def softmax_fwd(x2d, mask2d, *, scale: float, causal: bool, sq: int, interpret: 
         kernel = lambda x, y: base(x, None, y)  # noqa: E731
     y = pl.pallas_call(
         kernel,
+        name="softmax_fwd",
         grid=(rows_p // br,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((br, sk), lambda i: (i, 0)),
@@ -107,6 +108,7 @@ def softmax_bwd(dy2d, y2d, *, scale: float, interpret: bool):
     rows_p = y2d.shape[0]
     dx = pl.pallas_call(
         functools.partial(_softmax_bwd_kernel, scale=scale),
+        name="softmax_bwd",
         grid=(rows_p // br,),
         in_specs=[
             pl.BlockSpec((br, sk), lambda i: (i, 0)),

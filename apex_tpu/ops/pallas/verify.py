@@ -332,6 +332,7 @@ def fused_verify_fwd(logits, drafted, u_acc, u_gum, *, temperature,
     a, tok = pl.pallas_call(
         functools.partial(_verify_kernel, temperature=temperature,
                           top_k=top_k, top_p=top_p, sampled=sampled),
+        name="fused_verify",
         grid=(b,),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -382,6 +383,7 @@ def fused_verify_tree_fwd(logits, tokens, parents, anc, u_acc, u_gum, *,
     a, j_star, tok = pl.pallas_call(
         functools.partial(_verify_tree_kernel, temperature=temperature,
                           top_k=top_k, top_p=top_p, sampled=sampled),
+        name="fused_verify_tree",
         grid=(b,),
         in_specs=in_specs,
         out_specs=out_specs,

@@ -85,6 +85,7 @@ def xent_stats(logits2d, labels, *, interpret=False):
     stat = jax.ShapeDtypeStruct((n, _LANES), jnp.float32)
     m, l, t, s = pl.pallas_call(
         functools.partial(_stats_kernel, bv=bv, nv=nv),
+        name="xentropy_stats",
         grid=(n // bn, nv),
         in_specs=[
             pl.BlockSpec((bn, bv), lambda i, j: (i, j)),

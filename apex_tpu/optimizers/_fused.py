@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from apex_tpu.monitor.spans import span
 from apex_tpu.optimizers import multi_tensor as mt
 
 PyTree = Any
@@ -81,6 +82,7 @@ def resolve_layout(layout: str, chunk_size=None) -> str:
 
 def make_per_tensor_transform(
     *,
+    name: str,
     state_buffers: tuple,
     leaf_kernel: Callable[..., tuple],
     global_stats: Optional[Callable] = None,
@@ -91,7 +93,8 @@ def make_per_tensor_transform(
     ``leaf_kernel(g32, p32, bufs: dict, scal: dict, count, stats) ->
     (new_p32, new_bufs, new_scal)`` runs on each leaf; ``global_stats``
     (optional) maps the full fp32 grad pytree to a value passed to every
-    leaf (e.g. LAMB's global grad norm).
+    leaf (e.g. LAMB's global grad norm). The update is traced under the
+    scope ``<name>/update`` (``fused_adam/update`` in a device trace).
     """
 
     def init_fn(params):
@@ -110,41 +113,43 @@ def make_per_tensor_transform(
     def update_fn(grads, state, params=None):
         if params is None:
             raise ValueError("fused optimizers require params")
-        count = state.count + 1
-        g32 = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-        stats = global_stats(g32, count) if global_stats else None
+        with span(f"{name}/update"):
+            count = state.count + 1
+            g32 = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
+            stats = global_stats(g32, count) if global_stats else None
 
-        leaves_g, treedef = jax.tree.flatten(g32)
-        leaves_p = jax.tree.leaves(params)
-        bufs = {n: jax.tree.leaves(state.buffers[n]) for n in state_buffers}
-        scal = {n: jax.tree.leaves(state.scalars[n]) for n in state_scalars}
-        upd, new_bufs, new_scal = [], {n: [] for n in state_buffers}, \
-            {n: [] for n in state_scalars}
-        for i, (g, p) in enumerate(zip(leaves_g, leaves_p)):
-            p32 = p.astype(jnp.float32)
-            nb = {n: bufs[n][i] for n in state_buffers}
-            ns = {n: scal[n][i] for n in state_scalars}
-            new_p, nb, ns = leaf_kernel(g, p32, nb, ns, count, stats)
-            upd.append((new_p - p32).astype(p.dtype))
-            for n in state_buffers:
-                new_bufs[n].append(nb[n])
-            for n in state_scalars:
-                new_scal[n].append(ns[n])
+            leaves_g, treedef = jax.tree.flatten(g32)
+            leaves_p = jax.tree.leaves(params)
+            bufs = {n: jax.tree.leaves(state.buffers[n]) for n in state_buffers}
+            scal = {n: jax.tree.leaves(state.scalars[n]) for n in state_scalars}
+            upd, new_bufs, new_scal = [], {n: [] for n in state_buffers}, \
+                {n: [] for n in state_scalars}
+            for i, (g, p) in enumerate(zip(leaves_g, leaves_p)):
+                p32 = p.astype(jnp.float32)
+                nb = {n: bufs[n][i] for n in state_buffers}
+                ns = {n: scal[n][i] for n in state_scalars}
+                new_p, nb, ns = leaf_kernel(g, p32, nb, ns, count, stats)
+                upd.append((new_p - p32).astype(p.dtype))
+                for n in state_buffers:
+                    new_bufs[n].append(nb[n])
+                for n in state_scalars:
+                    new_scal[n].append(ns[n])
 
-        new_state = PerTensorState(
-            count=count,
-            buffers={n: jax.tree.unflatten(treedef, new_bufs[n])
-                     for n in state_buffers},
-            scalars={n: jax.tree.unflatten(treedef, new_scal[n])
-                     for n in state_scalars},
-        )
-        return jax.tree.unflatten(treedef, upd), new_state
+            new_state = PerTensorState(
+                count=count,
+                buffers={n: jax.tree.unflatten(treedef, new_bufs[n])
+                         for n in state_buffers},
+                scalars={n: jax.tree.unflatten(treedef, new_scal[n])
+                         for n in state_scalars},
+            )
+            return jax.tree.unflatten(treedef, upd), new_state
 
     return optax.GradientTransformation(init_fn, update_fn)
 
 
 def make_fused_transform(
     *,
+    name: str,
     state_buffers: tuple,
     kernel: Callable[..., tuple],
     state_scalars: tuple = (),
@@ -154,7 +159,8 @@ def make_fused_transform(
 
     ``kernel(g2d, p2d, buffers, scalars, count, layout) -> (new_p2d,
     new_buffers, new_scalars)``. The transformation's ``update`` returns
-    optax-style additive updates (``new_p - p``) in each param's dtype.
+    optax-style additive updates (``new_p - p``) in each param's dtype,
+    and is traced under the scope ``<name>/update``.
     """
 
     def init_fn(params):
@@ -174,17 +180,18 @@ def make_fused_transform(
     def update_fn(grads, state, params=None):
         if params is None:
             raise ValueError("fused optimizers require params")
-        layout = state.layout
-        g2d, _ = mt.flatten_to_chunks(grads, layout)
-        p2d, _ = mt.flatten_to_chunks(params, layout)
-        count = state.count + 1
-        new_p2d, new_buffers, new_scalars = kernel(
-            g2d, p2d, state.buffers, state.scalars, count, layout
-        )
-        updates = mt.unflatten_from_chunks(new_p2d - p2d, layout, like=params)
-        new_state = FusedState(
-            count=count, layout=layout, buffers=new_buffers, scalars=new_scalars
-        )
-        return updates, new_state
+        with span(f"{name}/update"):
+            layout = state.layout
+            g2d, _ = mt.flatten_to_chunks(grads, layout)
+            p2d, _ = mt.flatten_to_chunks(params, layout)
+            count = state.count + 1
+            new_p2d, new_buffers, new_scalars = kernel(
+                g2d, p2d, state.buffers, state.scalars, count, layout
+            )
+            updates = mt.unflatten_from_chunks(new_p2d - p2d, layout, like=params)
+            new_state = FusedState(
+                count=count, layout=layout, buffers=new_buffers, scalars=new_scalars
+            )
+            return updates, new_state
 
     return optax.GradientTransformation(init_fn, update_fn)

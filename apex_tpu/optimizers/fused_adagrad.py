@@ -40,11 +40,11 @@ def fused_adagrad(
 
     if resolve_layout(layout, chunk_size) == "per_tensor":
         return make_per_tensor_transform(
-            state_buffers=("h",),
+            name="fused_adagrad", state_buffers=("h",),
             leaf_kernel=lambda g, p, b, sc, c, stats: kernel(g, p, b, sc, c, None),
         )
 
-    return make_fused_transform(state_buffers=("h",), kernel=kernel, chunk_size=chunk_size or mt.DEFAULT_CHUNK)
+    return make_fused_transform(name="fused_adagrad", state_buffers=("h",), kernel=kernel, chunk_size=chunk_size or mt.DEFAULT_CHUNK)
 
 
 FusedAdagrad = fused_adagrad

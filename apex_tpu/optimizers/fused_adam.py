@@ -59,14 +59,14 @@ def fused_adam(
             return new_p, {"m": m, "v": v}, scal
 
         return make_per_tensor_transform(
-            state_buffers=("m", "v"), leaf_kernel=leaf_kernel)
+            name="fused_adam", state_buffers=("m", "v"), leaf_kernel=leaf_kernel)
 
     def kernel(g, p, buffers, scalars, count, layout_):
         new_p, m, v = adam_math(g, p, buffers["m"], buffers["v"], count)
         return new_p, {"m": m, "v": v}, scalars
 
     return make_fused_transform(
-        state_buffers=("m", "v"), kernel=kernel, chunk_size=chunk_size or mt.DEFAULT_CHUNK
+        name="fused_adam", state_buffers=("m", "v"), kernel=kernel, chunk_size=chunk_size or mt.DEFAULT_CHUNK
     )
 
 

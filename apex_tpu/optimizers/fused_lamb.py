@@ -125,7 +125,7 @@ def fused_lamb(
             return new_p, {"m": m, "v": v}, scal
 
         return make_per_tensor_transform(
-            state_buffers=("m", "v"), leaf_kernel=leaf_kernel,
+            name="fused_lamb", state_buffers=("m", "v"), leaf_kernel=leaf_kernel,
             global_stats=global_stats)
 
     def kernel(g, p, buffers, scalars, count, layout_):
@@ -139,7 +139,7 @@ def fused_lamb(
         return new_p, {"m": m, "v": v}, scalars
 
     return make_fused_transform(
-        state_buffers=("m", "v"), kernel=kernel, chunk_size=chunk_size or mt.DEFAULT_CHUNK
+        name="fused_lamb", state_buffers=("m", "v"), kernel=kernel, chunk_size=chunk_size or mt.DEFAULT_CHUNK
     )
 
 

@@ -83,7 +83,7 @@ def fused_novograd(
             return new_p, {"m": m}, {"v": v_new}
 
         return make_per_tensor_transform(
-            state_buffers=("m",), state_scalars=("v",),
+            name="fused_novograd", state_buffers=("m",), state_scalars=("v",),
             leaf_kernel=leaf_kernel)
 
     def kernel(g, p, buffers, scalars, count, layout):
@@ -100,7 +100,7 @@ def fused_novograd(
         return new_p, {"m": m}, {"v": v_new}
 
     return make_fused_transform(
-        state_buffers=("m",), state_scalars=("v",), kernel=kernel, chunk_size=chunk_size or mt.DEFAULT_CHUNK
+        name="fused_novograd", state_buffers=("m",), state_scalars=("v",), kernel=kernel, chunk_size=chunk_size or mt.DEFAULT_CHUNK
     )
 
 

@@ -56,12 +56,12 @@ def fused_sgd(
     if resolve_layout(layout, chunk_size) == "per_tensor":
         # the kernel is purely elementwise — reuse it per leaf
         return make_per_tensor_transform(
-            state_buffers=("momentum",) if momentum else (),
+            name="fused_sgd", state_buffers=("momentum",) if momentum else (),
             leaf_kernel=lambda g, p, b, sc, c, stats: kernel(g, p, b, sc, c, None),
         )
 
     return make_fused_transform(
-        state_buffers=("momentum",) if momentum else (),
+        name="fused_sgd", state_buffers=("momentum",) if momentum else (),
         kernel=kernel,
         chunk_size=chunk_size or mt.DEFAULT_CHUNK,
     )

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from apex_tpu.monitor import spans as monitor_spans
 from apex_tpu.parallel import mesh as mesh_lib
 
 PyTree = Any
@@ -94,7 +95,8 @@ def all_reduce_gradients(
             g = g.astype(orig_dtype)
         return g
 
-    return jax.tree.map(reduce_one, grads)
+    with monitor_spans.span("ddp/allreduce"):
+        return jax.tree.map(reduce_one, grads)
 
 
 # Alias with the reference's conceptual name.

@@ -44,9 +44,15 @@ def init(module, names: Optional[Iterable[str]] = None) -> None:
 
 @contextlib.contextmanager
 def trace(log_dir: str, *, host_tracer_level: int = 2):
-    """Capture a profiler trace to ``log_dir`` (viewable in
-    TensorBoard/XProf) — replaces running under nvprof/nsys."""
-    jax.profiler.start_trace(log_dir, create_perfetto_link=False)
+    """Capture a profiler trace to ``log_dir`` (an ``.xplane.pb`` viewable
+    in TensorBoard/XProf) — replaces running under nvprof/nsys. At
+    ``host_tracer_level`` 1 and above the host lines hold every
+    ``monitor.span`` entered outside a JAX trace, on the profiler's clock
+    beside the device's ``XLA Ops``."""
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = host_tracer_level
+    jax.profiler.start_trace(log_dir, create_perfetto_link=False,
+                             profiler_options=options)
     try:
         yield
     finally:

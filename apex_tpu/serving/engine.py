@@ -497,8 +497,8 @@ class ServingEngine:
                        key):
         # trace-time step-anatomy span (PR 6): every HLO of the chunk
         # program carries the serve_prefill scope in device traces — the
-        # join key request lifecycle records correlate on; no-op when
-        # monitoring is off, and never touches the stable avals
+        # join key request lifecycle records correlate on — monitor on or
+        # off; entered once per trace, never touching the stable avals
         with monitor_spans.span("serve_prefill"):
             if self.tp > 1:
                 return self._tp_prefill(params, pool, table_row, tokens,

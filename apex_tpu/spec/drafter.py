@@ -272,7 +272,7 @@ class ModelDrafter(Drafter):
         # one spec_draft span per round: its trace slice (and the
         # decode_step device scopes nested under it) joins the round's
         # spec lifecycle record through the ambient serve trace id —
-        # no-op while monitoring is off
+        # with monitoring off, a profiler annotation and no record
         with monitor_spans.span("spec_draft", stream=int(stream)):
             # teacher-force the unconsumed context rows (every token but
             # the last writes its k/v; its sampled candidate is discarded)

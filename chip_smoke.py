@@ -327,11 +327,13 @@ def witness_phase(train_text, decode_text):
     train, decode = kernels(train_text), kernels(decode_text)
     print(f"witness: train step Mosaic kernels {train}; decode step "
           f"{decode}", flush=True)
-    require("_fwd_kernel" in train,
+    # by part of the name, as the benchmark's readers match: the variant
+    # (flash_fwd_packed, flash_bwd_packed_fused, ...) follows the shapes
+    require(any("flash_fwd" in k for k in train),
             "flash forward kernel missing from the train step")
-    require(any(k.startswith("_bwd") for k in train),
+    require(any("flash_bwd" in k for k in train),
             "flash backward kernel missing from the train step")
-    require("_paged_kernel" in decode,
+    require("decode_attn_paged" in decode,
             "paged decode attention kernel missing from the decode step")
 
 

@@ -75,6 +75,65 @@ def test_phases_run_at_toy_size():
                                  served["lowered_text"])
 
 
+@pytest.fixture(scope="module")
+def lowered_for_tpu():
+    """The train and decode steps lowered FOR the TPU, here on the CPU: the
+    Mosaic custom calls carry the names the chip's programs will. Lowering
+    needs no device; the kernels are picked as on the chip because the
+    dispatch is steered here, in the test. Sizes: the smallest at which the
+    dispatch hands attention to the flash kernels (heads of 128, 1,024
+    positions)."""
+    import jax.numpy as jnp
+
+    from apex_tpu.models import GPTConfig, GPTModel
+    from apex_tpu.ops import _backend
+    from apex_tpu.serving import ServingEngine
+
+    cfg = dict(TOY, vocab_size=512, max_seq_len=1024, hidden_size=256)
+    patch = pytest.MonkeyPatch()
+    patch.setattr(_backend, "backend_platform", lambda: "tpu")
+    try:
+        model = GPTModel(GPTConfig(**cfg))
+        params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+        rows = jax.ShapeDtypeStruct((2, 1024), jnp.int32)
+
+        def lowered(fn, *args):
+            return fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+
+        engine = ServingEngine(model, num_slots=2, block_size=128,
+                               prefill_chunk=128, cache_dtype=jnp.bfloat16)
+        slots = jax.ShapeDtypeStruct((2,), jnp.int32)
+        return dict(
+            train=lowered(jax.jit(jax.value_and_grad(model.loss_fn)),
+                          params, rows, rows),
+            forward=lowered(jax.jit(model.loss_fn), params, rows, rows),
+            decode=lowered(
+                engine.decode_step, params, jax.eval_shape(engine.init_pool),
+                jax.ShapeDtypeStruct((2, engine.max_blocks_per_slot),
+                                     jnp.int32),
+                slots, slots, jax.random.PRNGKey(0)))
+    finally:
+        patch.undo()
+
+
+@pytest.mark.parametrize("train,decode,refusal", [
+    ("train", "decode", None),
+    ("decode", "decode", "flash forward kernel missing"),
+    ("forward", "decode", "flash backward kernel missing"),
+    ("train", "train", "paged decode attention kernel missing")])
+def test_witness_reads_the_names_the_kernels_carry(lowered_for_tpu, train,
+                                                   decode, refusal):
+    """The witness matches the names ``ops/pallas`` gives its kernels; a
+    rename there that the witness does not follow fails here, not on the
+    chip at phase 5."""
+    texts = lowered_for_tpu[train], lowered_for_tpu[decode]
+    if refusal is None:
+        chip_smoke.witness_phase(*texts)
+    else:
+        with pytest.raises(RuntimeError, match=refusal):
+            chip_smoke.witness_phase(*texts)
+
+
 def test_kernel_smoke_refuses_interpret_mode():
     import tpu_kernel_smoke
 

@@ -780,6 +780,89 @@ class TestSpans:
             pass
         assert monitor.span_path() == ""
 
+    def test_disabled_span_reads_no_clock_and_keeps_its_path(self, monkeypatch):
+        from apex_tpu.monitor import spans
+
+        def no_clock():
+            raise AssertionError("a span read the clock with no registry")
+
+        monkeypatch.setattr(spans, "monotonic_ns", no_clock)
+        with monitor.span("step"):
+            with monitor.span("fwd_bwd"):
+                assert monitor.span_path() == "step/fwd_bwd"
+        assert monitor.span_path() == ""
+
+    def test_each_thread_nests_its_own_spans(self):
+        import threading
+
+        inside, leave = threading.Event(), threading.Event()
+        seen = {}
+
+        def background():
+            with monitor.span("background"):
+                seen["background"] = monitor.span_path()
+                inside.set()
+                leave.wait(10)
+            seen["after"] = monitor.span_path()
+
+        t = threading.Thread(target=background)
+        t.start()
+        assert inside.wait(10)
+        # entered while the other thread's span is open, left after it
+        with monitor.span("serve_decode"):
+            seen["loop"] = monitor.span_path()
+            leave.set()
+            t.join(10)
+            assert monitor.span_path() == "serve_decode"
+        assert seen == {"background": "background", "loop": "serve_decode",
+                        "after": ""}
+        assert monitor.span_path() == ""
+
+    def test_disabled_traced_span_reaches_the_lowered_program(self):
+        import jax
+        import jax.numpy as jnp
+
+        assert not monitor.enabled()
+
+        def f(x):
+            with monitor.span("step"):
+                with monitor.span("gpt/attn"):
+                    return x * 2
+
+        text = jax.jit(f).lower(jnp.ones(4)).as_text(debug_info=True)
+        assert "step/gpt/attn/mul" in text
+
+    def test_host_phase_span_lands_in_the_profilers_own_trace(self, tmp_path):
+        import glob
+
+        import jax
+        import jax.numpy as jnp
+        from jax.profiler import ProfileData
+
+        from apex_tpu import prof
+
+        assert not monitor.enabled()
+        f = jax.jit(lambda x: x + 1)
+
+        @jax.jit
+        def g(x):
+            with monitor.span("traced_only"):
+                return x * 2
+
+        with prof.trace(str(tmp_path)):
+            with monitor.span("host_phase"):
+                with monitor.span("inner"):
+                    f(g(jnp.ones(4))).block_until_ready()
+        files = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+        assert len(files) == 1
+        on_host = {e.name: e.duration_ns
+                   for plane in ProfileData.from_file(files[0]).planes
+                   if plane.name == "/host:CPU"
+                   for line in plane.lines for e in line.events}
+        assert on_host["host_phase"] >= on_host["host_phase/inner"] > 0
+        # under a JAX trace a span is a scope only: its host time is tracing time
+        assert not any("traced_only" in name for name in on_host)
+
     def test_span_records_path_time_and_attrs(self, registry):
         reg, buf = registry
         with monitor.span("step", step=3):
