@@ -572,7 +572,15 @@ def fused_qkv_attention(x, w_qkv, b_qkv, w_out, bias, dropout_seed,
     None for full sequences). ``bias`` (hb, s, s) with hb | h: additive
     score bias read in-kernel (q-head row t reads bias row t % hb),
     differentiated (dbias = Σ_batch dS via the batch-innermost dbias
-    kernel); pass None for unbiased attention."""
+    kernel); pass None for unbiased attention.
+
+    Backward: without a bias the attention gradients come from ONE kernel
+    (``flash_bwd_packed_fused``: every score tile computed once; dk/dv
+    summed over the kv group in fp32 VMEM and handed back at kv width, so
+    the dk/dv GEMMs below contract (tokens, h_kv·d) operands); lengths and
+    dropout ride it. A bias takes the dq/dkv/dbias split. The choice is
+    made at trace time from shapes — see ``pallas.attention.
+    flash_bwd_packed``."""
     y, _ = _fused_attn_fwd(x, w_qkv, b_qkv, w_out, bias, dropout_seed,
                            kv_lens, h, h_kv, d, scale, causal, dropout_rate)
     return y

@@ -10,7 +10,9 @@ Shapes: q (bh, sq, d), k/v (bh, sk, d) with bh = batch*heads folded. Forward
 returns (o, lse) — lse is the softmax log-normalizer row vector that backward
 reuses (the same residual the CUTLASS fmha saves). Backward is the standard
 two-kernel split: dq accumulates over KV blocks, dk/dv over Q blocks, with
-D = rowsum(do·o) precomputed by the caller.
+D = rowsum(do·o) precomputed by the caller — except on the packed layout
+without a bias, where one kernel computes every score tile once and feeds
+dq, dk and dv from it (:func:`_bwd_packed_fused_kernel`).
 
 Block sizes default to 1024 (measured best on v5e at seq>=1024 — small
 blocks leave the head_dim-64 MXU contraction starved and grid overhead
@@ -86,11 +88,15 @@ def dropout_keep(seed, t, rows, cols, rate):
     return (_fmix32(row_key ^ cols.astype(_U32)) >> _U32(8)) >= thresh
 
 
-def _mask_scale(seed, t, i, j, bq, bk, rate):
+def _mask_scale(seed, t, i, j, bq, bk, rate, transposed=False):
     """(bq, bk) fp32 dropout multiplier (1/(1-rate) kept, 0 dropped) for
-    score block (i, j) — the shared fwd/bwd block recipe."""
-    rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
-    cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+    score block (i, j) — the shared fwd/bwd block recipe. ``transposed``:
+    the (bk, bq) multiplier of the block held as Sᵀ — the same hash of the
+    same coordinates, rows along lanes."""
+    rows = i * bq + jax.lax.broadcasted_iota(
+        jnp.int32, (1, bq) if transposed else (bq, 1), int(transposed))
+    cols = j * bk + jax.lax.broadcasted_iota(
+        jnp.int32, (bk, 1) if transposed else (1, bk), int(not transposed))
     keep = dropout_keep(seed, t, rows, cols, rate)
     return jnp.where(keep, jnp.float32(1.0 / (1.0 - rate)), 0.0)
 
@@ -329,8 +335,12 @@ def _kvlen_rows(kv_lens, bh):
 def _group_sum(x, h_kv, group, d, dtype):
     """Per-q-head fp32 dk/dv partials (b, s, h·d) → kv-head grads
     (b, s, h_kv·d): sum each kv group's q heads, THEN cast (fp32 before the
-    cross-head sum — the ADVICE r2 precision rule; XLA fuses the reduction
-    into the kernel's output write)."""
+    cross-head sum — the ADVICE r2 precision rule). Used by the dq/dkv
+    splits, whose grid rows are q heads: :func:`flash_bwd_bshd`, and
+    :func:`flash_bwd_packed` with a bias or a sequence too long for the
+    one-pass kernel (which sums the group in VMEM and needs none of this).
+    On the chip the partials are written and read back: 2 × 0.27 GB a layer
+    at 16 heads on 1 × 8,192 positions (PERF.md, PR 25)."""
     b, s, _ = x.shape
     return x.reshape(b, s, h_kv, group, d).sum(3).astype(
         dtype).reshape(b, s, h_kv * d)
@@ -550,60 +560,223 @@ def flash_fwd_packed(qkv, h, h_kv, d, *, scale, causal, kv_lens=None,
     return o, (lse if full_lse else lse[..., 0])
 
 
-def _bwd_single_block_kernel(*refs, scale, causal, n, rate=0.0):
-    """Single-block fused backward: when the whole (sq == sk == n) matrix
-    fits one block, dq/dk/dv come out of ONE kernel that computes the
-    score matrix once — the two-kernel split (which exists only because
-    dq accumulates over kv blocks and dkv over q blocks) recomputes QKᵀ,
-    the mask, and the exp twice. 5 GEMMs instead of 7; at the flagship
-    shape that is ~4 ms/step of attention backward removed (PERF.md r3).
+# VMEM a kernel of this package may ask for: Mosaic's default scoped limit on
+# a v5e is 16 MiB, the chip has 128 MiB; 100 leaves the compiler its own.
+_VMEM_FLOOR, _VMEM_CAP = 16 * 2 ** 20, 100 * 2 ** 20
 
-    D = rowsum(do·o) is computed HERE from the o block rather than taken
-    as an operand: the XLA prologue that produced it materialized the
-    fp32 do·o product (67 MB/layer), layout-copied it, reduced it, and
-    broadcast the result into the lane carrier — ~0.4 ms/layer of pure
-    HBM traffic for a VPU rowsum the kernel gets for free (PERF.md r3).
-    """
+
+def _vmem_limit(nbytes):
+    """``vmem_limit_bytes`` for a kernel that holds ``nbytes`` resident."""
+    return min(_VMEM_CAP, max(_VMEM_FLOOR, nbytes))
+
+
+def _fused_bwd_vmem_bytes(s, d, bq, bk, itemsize):
+    """VMEM the one-pass packed backward holds at once: the two whole-
+    sequence fp32 dk/dv accumulators, their (double-buffered) output blocks
+    at the kv dtype, the q/do/o/k/v/dq blocks, and the score-tile
+    temporaries of one step (S, P, dP, dS in fp32, their MXU-dtype copies,
+    a dropout multiplier: under eight (bq, bk) fp32 tiles)."""
+    accumulators = 2 * s * d * 4
+    outputs = 2 * 2 * s * d * itemsize
+    blocks = 2 * (4 * bq + 2 * bk) * d * itemsize + bq * d * 4
+    tiles = 8 * bq * bk * 4
+    return accumulators + outputs + blocks + tiles
+
+
+def _bwd_packed_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h,
+                             h_kv, varlen, rate=0.0):
+    """One-pass backward of the packed layout: grid (b·h_kv, group, nq, nk),
+    kv blocks innermost. Every (q block, kv block) score tile is computed
+    ONCE and feeds dq, dk and dv — five matmuls a tile where the dq/dkv
+    split pays seven and runs the mask/exp chain twice.
+
+    The tile is held TRANSPOSED, Sᵀ = K·Qᵀ (bk, bq): dV += Pᵀ·dO and
+    dK += dSᵀ·Q are then plain (bk, bq)·(bq, d) products with the big tile
+    as the streamed left operand, and only dQ contracts over the tile's
+    rows. Row statistics ride as (1, bq) lane rows: ``lse`` comes in that
+    form, D = rowsum(dO∘O) is computed here when a q block is first visited
+    (kv block 0) — no XLA prologue, no carrier.
+
+    Accumulators are fp32 VMEM scratch and each gradient leaves once: dq in
+    a (bq, d) scratch over the kv blocks of one visit; dk/dv in whole-
+    sequence (s, d) scratches that stay resident across the group's q heads
+    and all q blocks and are cast and written once a kv head, at kv width —
+    the cross-head sum happens in fp32 by construction (ADVICE r2), and no
+    per-q-head partial ever reaches HBM. ``scale`` multiplies dq and dk once
+    at their write instead of every dS tile.
+
+    A tile is skipped (above the causal diagonal / past the row's kv
+    length, as in the split), fully visible (no iota, compare or select),
+    or crossed by the diagonal or the length — branched on block indices
+    with ``pl.when``. Dropout regenerates the forward's mask: the same hash
+    on the same global coordinates, ``t`` the q-head row of the forward
+    grid."""
     refs = list(refs)
     q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref = refs[:6]
-    n_ = 6
+    n = 6
+    if varlen:
+        kvlen_ref = refs[n]
+        n += 1
     if rate > 0.0:
-        seed_ref = refs[n_]
-        n_ += 1
-    dq_ref, dk_ref, dv_ref = refs[n_:]
-    q = q_ref[0]
-    k = k_ref[0]
-    v = v_ref[0]
-    do = do_ref[0]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
-    if causal:
-        rows = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-        s = jnp.where(cols <= rows, s, NEG_INF)
-    p = jnp.exp(s - lse_ref[0, 0][:, 0:1])
-    if rate > 0.0:
-        ms = _mask_scale(seed_ref[0], pl.program_id(0), 0, 0, n, n, rate)
-        pd = p * ms
+        seed_ref = refs[n]
+        n += 1
+    dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, delta_scr = refs[n:]
+    r = pl.program_id(0)  # (batch, kv head) row
+    g = pl.program_id(1)  # q head within the kv group
+    i = pl.program_id(2)  # q block
+    j = pl.program_id(3)  # kv block (inner, dq accumulated)
+    first = jnp.logical_and(jnp.logical_and(g == 0, i == 0), j == 0)
+    last = jnp.logical_and(jnp.logical_and(g == group - 1, i == nq - 1),
+                           j == nk - 1)
+
+    @pl.when(first)
+    def _init_kv():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    @pl.when(j == 0)
+    def _visit():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+        prod = do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+        delta_scr[...] = jnp.sum(prod.T, axis=0, keepdims=True)  # (1, bq)
+
+    run = (not causal) or (j * bk <= (i + 1) * bq - 1)
+    # fully visible: the tile's last column at or left of its first row's
+    # diagonal / inside the kv length
+    inner = (not causal) or ((j + 1) * bk - 1 <= i * bq)
+    if varlen:
+        kvlen = kvlen_ref[0, 0, 0]
+        run = jnp.logical_and(run, j * bk < kvlen)
+        inner = jnp.logical_and(inner, (j + 1) * bk <= kvlen)
+
+    def _tile(masked):
+        # bf16 MXU operands, fp32 accumulation (see _fwd_kernel)
+        q = q_ref[0]
+        k = k_ref[0]
+        v = v_ref[0]
+        do = do_ref[0]
+        st = jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # (bk, bq) = Sᵀ
+        if masked:
+            cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+            if causal:
+                rows = i * bq + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, bq), 1)
+                st = jnp.where(cols <= rows, st, NEG_INF)
+            if varlen:
+                st = jnp.where(cols < kvlen, st, NEG_INF)
+        pt = jnp.exp(st - lse_ref[0, 0])
+        if rate > 0.0:
+            t = (r // h_kv) * h + (r % h_kv) * group + g  # the forward's row
+            ms = _mask_scale(seed_ref[0], t, i, j, bq, bk, rate,
+                             transposed=True)
+            pd = pt * ms  # dropped+rescaled probs: dV = Pdᵀ dO
+        else:
+            pd = pt
+        kv_rows = pl.ds(pl.multiple_of(j * bk, bk), bk)
+        dv_scr[kv_rows, :] += jax.lax.dot_general(
+            pd.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(
+            v, do, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        if rate > 0.0:
+            dpt = dpt * ms
+        dst = (pt * (dpt - delta_scr[...])).astype(q.dtype)  # dSᵀ / scale
+        dk_scr[kv_rows, :] += jax.lax.dot_general(
+            dst, q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dq_scr[...] += jax.lax.dot_general(
+            dst, k, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    if not causal and not varlen:
+        _tile(False)
     else:
-        pd = p
-    delta = jnp.sum(do.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
-                    axis=1, keepdims=True)
-    dv_ref[0] = jax.lax.dot_general(
-        pd.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dv_ref.dtype)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    if rate > 0.0:
-        dp = dp * ms
-    ds = (p * (dp - delta) * scale).astype(q.dtype)
-    dq_ref[0] = jax.lax.dot_general(
-        ds, k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dq_ref.dtype)
-    dk_ref[0] = jax.lax.dot_general(
-        ds, q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dk_ref.dtype)
+        pl.when(jnp.logical_and(run, inner))(lambda: _tile(False))
+        pl.when(jnp.logical_and(run, jnp.logical_not(inner)))(
+            lambda: _tile(True))
+
+    @pl.when(j == nk - 1)
+    def _write_dq():
+        dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
+
+    @pl.when(last)
+    def _write_dkv():
+        def block(jj, carry):
+            rows = pl.ds(pl.multiple_of(jj * bk, bk), bk)
+            dk_ref[0, rows, :] = (dk_scr[rows, :] * scale).astype(dk_ref.dtype)
+            dv_ref[0, rows, :] = dv_scr[rows, :].astype(dv_ref.dtype)
+            return carry
+        jax.lax.fori_loop(0, nk, block, 0)
+
+
+def _flash_bwd_packed_fused(qkv, h, h_kv, d, o, lse, do, *, scale, causal,
+                            kv_lens, bq, bk, interpret, dropout_rate,
+                            dropout_seed):
+    """Launch :func:`_bwd_packed_fused_kernel`; (dq (b, s, h·d), dk, dv
+    (b, s, h_kv·d)), all in ``qkv.dtype``."""
+    b, s, _ = qkv.shape
+    group = h // h_kv
+    nq, nk = _blocks(s, bq), _blocks(s, bk)
+    # (b, h, 1, s) lane rows: the transposed tile broadcasts lse along its
+    # sublanes (one strided slice of the forward's carrier a layer)
+    lse_rows = (lse[..., 0] if lse.ndim == 4 else lse)[:, :, None, :]
+
+    def head(r, g):  # q-head index within the batch row
+        return (r % h_kv) * group + g
+
+    def kv_block(i, j):
+        # causal: tiles above the diagonal are skipped — hold the last
+        # needed kv block so the skipped steps fetch nothing
+        return jnp.minimum(j, ((i + 1) * bq - 1) // bk) if causal else j
+
+    qm = lambda r, g, i, j: (r // h_kv, i, head(r, g))  # noqa: E731
+    km = lambda r, g, i, j: (r // h_kv, kv_block(i, j), h + r % h_kv)  # noqa: E731
+    vm = lambda r, g, i, j: (  # noqa: E731
+        r // h_kv, kv_block(i, j), h + h_kv + r % h_kv)
+    dkm = lambda r, g, i, j: (r // h_kv, 0, r % h_kv)  # noqa: E731
+    in_specs = [pl.BlockSpec((1, bq, d), qm),
+                pl.BlockSpec((1, bk, d), km),
+                pl.BlockSpec((1, bk, d), vm),
+                pl.BlockSpec((1, bq, d), qm),
+                pl.BlockSpec((1, bq, d), qm),
+                pl.BlockSpec((1, 1, 1, bq),
+                             lambda r, g, i, j: (r // h_kv, head(r, g), 0, i))]
+    tail_specs, tail_args = _tail_operands(
+        kv_lens, b, dropout_rate, dropout_seed,
+        lambda r, g, i, j: (r // h_kv, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_bwd_packed_fused_kernel, scale=scale,
+                          causal=causal, bq=bq, bk=bk, nq=nq, nk=nk,
+                          group=group, h=h, h_kv=h_kv,
+                          varlen=kv_lens is not None, rate=dropout_rate),
+        name="flash_bwd_packed_fused",
+        grid=(b * h_kv, group, nq, nk),
+        in_specs=in_specs + tail_specs,
+        out_specs=[pl.BlockSpec((1, bq, d), qm),
+                   pl.BlockSpec((1, s, d), dkm),
+                   pl.BlockSpec((1, s, d), dkm)],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, s, h * d), qkv.dtype),
+            jax.ShapeDtypeStruct((b, s, h_kv * d), qkv.dtype),
+            jax.ShapeDtypeStruct((b, s, h_kv * d), qkv.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((s, d), jnp.float32),
+            pltpu.VMEM((s, d), jnp.float32),
+            pltpu.VMEM((1, bq), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            # dk/dv accumulate across the group, the q blocks and the kv
+            # blocks of one (batch, kv head) row: all three stay sequential
+            dimension_semantics=("parallel", "arbitrary", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(_fused_bwd_vmem_bytes(
+                s, d, bq, bk, qkv.dtype.itemsize))),
+        interpret=interpret,
+    )(qkv, qkv, qkv, do, o, lse_rows, *tail_args)
 
 
 def flash_bwd_packed(qkv, h, h_kv, d, o, lse, do, *, scale, causal,
@@ -612,12 +785,22 @@ def flash_bwd_packed(qkv, h, h_kv, d, o, lse, do, *, scale, causal,
     """Backward of :func:`flash_fwd_packed`: returns SEPARATE folded grads
     (dq (b, s, h·d), dk/dv (b, s, h_kv·d)) — the caller contracts each
     against its weight window (plain 2D GEMMs), never materializing a
-    packed dqkv. When the sequence fits one block, a single fused kernel
-    replaces the dq/dkv pair (see :func:`_bwd_single_block_kernel`).
+    packed dqkv.
+
+    Unbiased attention takes ONE kernel, ``flash_bwd_packed_fused``, at any
+    number of blocks (see :func:`_bwd_packed_fused_kernel`): each score
+    tile computed once, dk/dv summed over the kv group in VMEM and written
+    at kv width. What picks it is what the shapes show: no bias, and the
+    two whole-sequence fp32 accumulators fit the VMEM a kernel may ask for
+    (:func:`_fused_bwd_vmem_bytes`; at d = 128 up to ~32 k positions). A
+    longer sequence, or a bias (the dbias kernel takes D as an operand),
+    rides the dq/dkv split: ``flash_bwd_packed_dq`` / ``_dkv`` with per-q-
+    head fp32 dk/dv partials and :func:`_group_sum`, then
+    ``flash_bwd_dbias``.
 
     ``lse`` may be the sliced (b, h, s) form or the (b, h, s, LANES)
     carrier exactly as :func:`flash_fwd_packed` ``full_lse=True`` returned
-    it — passing the carrier skips a per-layer re-broadcast.
+    it.
 
     ``bias`` (hb, s, s), hb | h: adds a fourth output dbias (hb, s, s)
     fp32 (see :func:`flash_bwd`)."""
@@ -626,56 +809,16 @@ def flash_bwd_packed(qkv, h, h_kv, d, o, lse, do, *, scale, causal,
     bq, bk = _bias_blocks(bias, bq, bk)
     bq, bk = _fit_block(s, bq), _fit_block(s, bk)
     nq, nk = _blocks(s, bq), _blocks(s, bk)
-    lse4 = lse if lse.ndim == 4 else _expand_rows(lse)
     varlen = kv_lens is not None
     hb = 0 if bias is None else bias.shape[0]
 
-    # varlen and bias ride the two-kernel split (the fused single-block
-    # kernel carries no length operand, and it computes delta internally —
-    # the dbias kernel needs delta as an operand; padded/biased batches pay
-    # one extra QK^T recompute, the same cost every multi-block sequence
-    # pays anyway)
-    if nq == 1 and nk == 1 and not varlen and bias is None:
-        qm = lambda t, h=h: (t // h, 0, t % h)  # noqa: E731
-        km = lambda t, h=h, g=group: (t // h, 0, h + (t % h) // g)  # noqa: E731
-        vm = lambda t, h=h, hk=h_kv, g=group: (  # noqa: E731
-            t // h, 0, h + hk + (t % h) // g)
-        rm = lambda t, h=h: (t // h, t % h, 0, 0)  # noqa: E731
-        # grouped kv: each grid point is one q head, so dk/dv come out as
-        # per-q-head fp32 partials (fp32 BEFORE the cross-head sum — the
-        # ADVICE r2 precision rule) and the group reduction happens outside,
-        # where XLA fuses it into the output write.
-        dkv_dt = jnp.float32 if group > 1 else qkv.dtype
-        sb_specs = [pl.BlockSpec((1, s, d), qm),
-                    pl.BlockSpec((1, s, d), km),
-                    pl.BlockSpec((1, s, d), vm),
-                    pl.BlockSpec((1, s, d), qm),
-                    pl.BlockSpec((1, s, d), qm),
-                    pl.BlockSpec((1, 1, s, _LSE_LANES), rm)]
-        sb_args = [qkv, qkv, qkv, do, o, lse4]
-        if dropout_rate > 0.0:
-            sb_specs.append(_SMEM_SPEC)
-            sb_args.append(_seed_operand(dropout_seed))
-        dq, dk, dv = pl.pallas_call(
-            functools.partial(_bwd_single_block_kernel, scale=scale,
-                              causal=causal, n=s, rate=dropout_rate),
-            name="flash_bwd_packed_fused",
-            grid=(b * h,),
-            in_specs=sb_specs,
-            out_specs=[pl.BlockSpec((1, s, d), qm)] * 3,
-            out_shape=[
-                jax.ShapeDtypeStruct((b, s, h * d), qkv.dtype),
-                jax.ShapeDtypeStruct((b, s, h * d), dkv_dt),
-                jax.ShapeDtypeStruct((b, s, h * d), dkv_dt),
-            ],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel",)),
-            interpret=interpret,
-        )(*sb_args)
-        if group > 1:
-            dk = _group_sum(dk, h_kv, group, d, qkv.dtype)
-            dv = _group_sum(dv, h_kv, group, d, qkv.dtype)
-        return dq, dk, dv
+    if bias is None and _fused_bwd_vmem_bytes(
+            s, d, bq, bk, qkv.dtype.itemsize) <= _VMEM_CAP:
+        return _flash_bwd_packed_fused(
+            qkv, h, h_kv, d, o, lse, do, scale=scale, causal=causal,
+            kv_lens=kv_lens, bq=bq, bk=bk, interpret=interpret,
+            dropout_rate=dropout_rate, dropout_seed=dropout_seed)
+    lse4 = lse if lse.ndim == 4 else _expand_rows(lse)
     delta = jnp.sum(
         do.astype(jnp.float32).reshape(b, s, h, d)
         * o.astype(jnp.float32).reshape(b, s, h, d), axis=-1)

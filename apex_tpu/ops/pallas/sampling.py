@@ -46,7 +46,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex_tpu.ops.pallas.attention import _LSE_LANES
+from apex_tpu.ops.pallas.attention import (_LSE_LANES, _VMEM_CAP,
+                                           _vmem_limit)
 
 # masked-out logit value (matches apex_tpu.inference.sampling._FILTERED):
 # finite so a pathologically over-filtered row degrades to near-uniform
@@ -144,9 +145,6 @@ def filtered_scaled(logits, *, temperature, top_k, top_p):
 _ROWS = 8
 
 
-_VMEM_FLOOR, _VMEM_CAP = 16 * 2 ** 20, 100 * 2 ** 20
-
-
 def _whole_row_bytes(rows, vocab):
     """VMEM a kernel needs to keep ``rows`` whole fp32 vocab rows resident
     (sublane-padded to 8) and run the bisection filters over them: the
@@ -167,7 +165,7 @@ def whole_row_vmem_limit(rows, vocab):
     """``vmem_limit_bytes`` for such a kernel. Mosaic's default scoped
     limit on a v5e is 16 MiB, which 8 rows of a 32768 vocab (1 MiB a
     block) already crowd and a larger vocab exceeds."""
-    return min(_VMEM_CAP, max(_VMEM_FLOOR, _whole_row_bytes(rows, vocab)))
+    return _vmem_limit(_whole_row_bytes(rows, vocab))
 
 
 def _sample_kernel(logits_ref, u_ref, o_ref, *, temperature, top_k, top_p):

@@ -1754,3 +1754,80 @@ class TestBucketedBias:
                 jnp.zeros((192,)), jnp.zeros((64, 64)),
                 BucketedBias(jnp.zeros((16, 1)), True, 64), None, None,
                 1, 1, 64, 0.125, True)
+
+
+class TestPackedOnePassBackward:
+    """``flash_bwd_packed`` without a bias is one kernel at any number of
+    blocks (``flash_bwd_packed_fused``): every score tile computed once,
+    dk/dv summed over the kv group in fp32 VMEM and written at kv width.
+    Checked against the gradients of the XLA composition (the same mask
+    hash, so dropout agrees bit for bit)."""
+
+    FULL, SHORT, DEAD = None, (512, 100), (0, 300)
+
+    @pytest.mark.pallas
+    @pytest.mark.parametrize(
+        "group,h_kv,causal,lens,rate,s,block,dtype",
+        [
+            # more than one block (4 x 4 tiles of 128), every group size
+            (1, 2, True, FULL, 0.0, 512, 128, jnp.float32),
+            (4, 2, True, FULL, 0.0, 512, 128, jnp.float32),
+            (16, 1, True, FULL, 0.0, 512, 128, jnp.float32),
+            (1, 2, False, FULL, 0.0, 512, 128, jnp.float32),
+            (4, 2, False, FULL, 0.0, 512, 128, jnp.float32),
+            (16, 1, False, FULL, 0.0, 512, 128, jnp.float32),
+            # kv_lens: one row shorter than a block, one of length 0
+            (4, 1, True, SHORT, 0.0, 512, 128, jnp.float32),
+            (4, 1, True, DEAD, 0.0, 512, 128, jnp.float32),
+            (4, 1, False, SHORT, 0.0, 512, 128, jnp.float32),
+            # dropout, same seed as the forward; with lengths too
+            (4, 1, True, FULL, 0.3, 512, 128, jnp.float32),
+            (2, 2, False, FULL, 0.3, 512, 128, jnp.float32),
+            (4, 1, True, DEAD, 0.3, 512, 128, jnp.float32),
+            # the sequence is one block, whatever block was asked for
+            (1, 2, True, FULL, 0.0, 128, 1024, jnp.float32),
+            (4, 1, True, FULL, 0.3, 128, 1024, jnp.float32),
+            (4, 1, False, (100, 128), 0.0, 128, 1024, jnp.float32),
+            # the training dtype: grads in bf16, the group summed in fp32
+            (16, 1, True, FULL, 0.0, 256, 128, jnp.bfloat16),
+        ])
+    def test_matches_xla_composition(self, group, h_kv, causal, lens, rate,
+                                     s, block, dtype):
+        from apex_tpu.ops.pallas import attention as pk
+
+        b, d = 2, 32
+        h = group * h_kv
+        key = jr.fold_in(K, 2500 + group)
+        qkv = jr.normal(key, (b, s, (h + 2 * h_kv) * d)).astype(dtype)
+        do = jr.normal(jr.fold_in(key, 1), (b, s, h * d)).astype(dtype)
+        kv_lens = None if lens is None else jnp.array(lens, jnp.int32)
+        seed = jnp.int32(77) if rate else None
+        scale = d ** -0.5
+        kw = dict(scale=scale, causal=causal, kv_lens=kv_lens, bq=block,
+                  bk=block, interpret=True, dropout_rate=rate,
+                  dropout_seed=seed)
+
+        def composition(q, k, v):
+            return flash_attention(
+                q, k, v, layout="bshd", impl="xla", causal=causal,
+                kv_lens=kv_lens, scale=scale, dropout_rate=rate,
+                dropout_seed=seed)
+
+        f32 = qkv.astype(jnp.float32)
+        q, k, v = (f32[..., :h * d].reshape(b, s, h, d),
+                   f32[..., h * d:(h + h_kv) * d].reshape(b, s, h_kv, d),
+                   f32[..., (h + h_kv) * d:].reshape(b, s, h_kv, d))
+        with jax.default_matmul_precision("highest"):
+            o, lse = pk.flash_fwd_packed(qkv, h, h_kv, d, full_lse=True, **kw)
+            got = pk.flash_bwd_packed(qkv, h, h_kv, d, o, lse, do, **kw)
+            _, vjp = jax.vjp(composition, q, k, v)
+            want = vjp(do.astype(jnp.float32).reshape(b, s, h, d))
+        assert len(got) == 3
+        tol = (dict(rtol=2e-4, atol=3e-5) if dtype == jnp.float32
+               else dict(rtol=3e-2, atol=6e-2))
+        for name, a, e, heads in zip(("dq", "dk", "dv"), got, want,
+                                     (h, h_kv, h_kv)):
+            assert a.shape == (b, s, heads * d) and a.dtype == dtype, name
+            np.testing.assert_allclose(
+                a.astype(jnp.float32), e.reshape(b, s, heads * d),
+                err_msg=name, **tol)

@@ -4,6 +4,7 @@ not ``jvp__.N``), and the trainer step's layer boundaries enter
 ``monitor.span`` scopes that reach the lowered program's metadata whether
 or not the monitor is on."""
 import ast
+import functools
 import glob
 import os
 import re
@@ -90,25 +91,56 @@ def test_flash_equations_carry_their_names(layout, shape, names):
     assert kernel_names(jaxpr.jaxpr) == names
 
 
-@pytest.mark.parametrize("seq,names", [
-    (128, ["flash_fwd_packed", "flash_bwd_packed_fused"]),     # one block: dq, dk, dv at once
-    (2048, ["flash_fwd_packed", "flash_bwd_packed_dq", "flash_bwd_packed_dkv"]),
+SPLIT = ["flash_bwd_packed_dq", "flash_bwd_packed_dkv"]
+
+
+@pytest.mark.parametrize("seq,biased,names", [
+    (128, False, ["flash_fwd_packed", "flash_bwd_packed_fused"]),   # one block
+    (2048, False, ["flash_fwd_packed", "flash_bwd_packed_fused"]),  # the same kernel at 2 x 2 blocks
+    (2048, True, ["flash_fwd_packed", *SPLIT, "flash_bwd_dbias"]),  # a bias keeps the split
 ])
-def test_packed_flash_equations_carry_their_names(seq, names):
+def test_packed_flash_equations_carry_their_names(seq, biased, names):
     """The fused projection + attention block that ``GPTModel`` takes at
     heads of 128 (the benchmark's ``sc1b-train-8k``)."""
     from apex_tpu.ops.attention import fused_qkv_attention
 
     h, h_kv, d, H = 2, 1, 128, 256
+    bias = jnp.zeros((1, seq, seq)) if biased else None
 
     def loss(x, w_qkv, b_qkv, w_out):
-        return fused_qkv_attention(x, w_qkv, b_qkv, w_out, None, None, None,
+        return fused_qkv_attention(x, w_qkv, b_qkv, w_out, bias, None, None,
                                    h, h_kv, d, d ** -0.5, True).sum()
 
     jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)))(
         jnp.ones((1, seq, H)), jnp.ones(((h + 2 * h_kv) * d, H)),
         jnp.ones(((h + 2 * h_kv) * d,)), jnp.ones((H, h * d)))
     assert kernel_names(jaxpr.jaxpr) == names
+
+
+@pytest.mark.parametrize("seq,names", [
+    (1024, ["flash_bwd_packed_fused"]),   # the flagship recipe: one block
+    (8192, ["flash_bwd_packed_fused"]),   # sc1b-train-8k: 2 x 4 MB of fp32 accumulators
+    (262144, SPLIT),                      # 2 x 128 MB do not fit a v5e's VMEM
+])
+def test_packed_backward_is_picked_by_the_vmem_its_accumulators_need(seq, names):
+    """No option picks the one-pass backward: the shapes do. Its whole-
+    sequence fp32 dk/dv accumulators must fit the VMEM a kernel may ask
+    for; a sequence too long for them keeps the dq/dkv split."""
+    from apex_tpu.ops.pallas import attention as pk
+
+    h, h_kv, d = 16, 1, 128
+
+    def backward(qkv, o, lse, do):
+        return pk.flash_bwd_packed(qkv, h, h_kv, d, o, lse, do, scale=d ** -0.5,
+                                   causal=True, interpret=True)
+
+    bf16 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(backward)(
+        bf16((1, seq, (h + 2 * h_kv) * d)), bf16((1, seq, h * d)),
+        jax.ShapeDtypeStruct((1, h, seq, 8), jnp.float32), bf16((1, seq, h * d)))
+    assert kernel_names(jaxpr.jaxpr) == names
+    fits = pk._fused_bwd_vmem_bytes(seq, d, 1024, 1024, 2) <= pk._VMEM_CAP
+    assert fits == (names != SPLIT)
 
 
 def test_cross_entropy_equation_carries_its_name():
