@@ -42,6 +42,9 @@ HALF_OPS = {
     # RNN cells are gate matmuls (cf. wrap.rnn_cast / rnn_compat,
     # apex/amp/wrap.py:157-265 — the reference casts weights+inputs half)
     "rnn", "lstm", "gru",
+    # the chunked gated delta rule is matmuls over chunk operands; its
+    # decays, norms and state are fp32 inside whatever the inputs
+    "gated_delta_rule",
 }
 
 FLOAT_OPS = {

@@ -36,19 +36,31 @@ class MasterWeights:
     master: PyTree                 # fp32, what the optimizer updates
     model: PyTree                  # param_dtype (bf16/fp16), what forward uses
     param_dtype: Any = dataclasses.field(metadata=dict(static=True), default=jnp.bfloat16)
+    # leaf names the model copy keeps in fp32 (the reference's
+    # ``keep_batchnorm_fp32`` by module type, here by name): parameters that
+    # enter an exponent, such as a recurrent layer's log decay
+    keep_float32: Tuple[str, ...] = dataclasses.field(metadata=dict(static=True), default=())
 
     @classmethod
-    def create(cls, params: PyTree, policy: Policy) -> "MasterWeights":
+    def create(cls, params: PyTree, policy: Policy,
+               keep_float32: Tuple[str, ...] = ()) -> "MasterWeights":
         """Initialize masters from (possibly half) params — the reference's
-        ``lazy_init_with_master_weights`` (``_process_optimizer.py:28-90``)."""
+        ``lazy_init_with_master_weights`` (``_process_optimizer.py:28-90``).
+        Leaves whose path holds one of ``keep_float32`` stay fp32 in the
+        model copy too."""
         master = tree_cast(params, jnp.float32)
-        return cls(master=master, model=tree_cast(master, policy.param_dtype),
-                   param_dtype=policy.param_dtype)
+        return cls(master=master, model=None, param_dtype=policy.param_dtype,
+                   keep_float32=tuple(keep_float32)).resync()
 
     def resync(self) -> "MasterWeights":
         """Re-derive model params from masters (master→model copy,
         ``_process_optimizer.py:354-364``)."""
-        return dataclasses.replace(self, model=tree_cast(self.master, self.param_dtype))
+        if not self.keep_float32:
+            return dataclasses.replace(self, model=tree_cast(self.master, self.param_dtype))
+        keep = lambda path: any(n in jax.tree_util.keystr(path) for n in self.keep_float32)  # noqa: E731
+        model = jax.tree_util.tree_map_with_path(
+            lambda path, a: a if keep(path) else tree_cast(a, self.param_dtype), self.master)
+        return dataclasses.replace(self, model=model)
 
 
 def apply_updates_with_master(

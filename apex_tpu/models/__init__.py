@@ -7,6 +7,10 @@ Analog of the reference's ``apex/transformer/testing/standalone_gpt.py`` /
 
 from apex_tpu.models.gpt import GPTConfig, GPTModel  # noqa: F401
 from apex_tpu.models.bert import BertConfig, BertModel  # noqa: F401
+from apex_tpu.models.hybrid_decoder import (  # noqa: F401
+    HybridDecoderConfig,
+    HybridDecoderModel,
+)
 from apex_tpu.models.resnet import ResNet50, ResNetConfig  # noqa: F401
 from apex_tpu.models.t5 import (  # noqa: F401
     EncDecPipeline,

@@ -1,0 +1,242 @@
+"""The gated delta rule (linear attention with a decaying, error-correcting
+state) and the small ops that stand around it in a decoder layer.
+
+Per value head, with float32 state ``S (d_k, d_v)``, decay ``g_t <= 0`` and
+write strength ``beta_t`` in (0, 1):
+
+    S <- exp(g_t) S;   u_t = beta_t (v_t - S^T k_t);   S <- S + k_t u_t^T;   o_t = S^T q_t
+
+:func:`gated_delta_rule` computes it in chunks of ``chunk`` tokens (the WY /
+UT form): inside a chunk the ``u`` solve a unit lower-triangular system
+``(I + A) U = beta V - (beta e^G K) S_0``, so ``T = (I + A)^-1`` turns each
+chunk into operands of a recurrence over *chunks* — matmuls instead of
+``chunk`` rank-one updates. All chunks are prepared at once in XLA (float32
+decays, cumulative inside the chunk only, so nothing is ever divided by a
+decay); the recurrence over chunks is the Pallas kernel pair
+``gdn_fwd`` / ``gdn_bwd`` (``ops/pallas/gated_delta_rule.py``) or, as
+``impl="xla"``, a ``lax.scan`` of the same mathematics — the oracle the
+kernels are tested against, as every kernel family here has one.
+
+Also here: :func:`causal_conv_silu` (the depthwise causal convolution that
+precedes the rule) and :func:`gated_rms_norm` (the head-wise RMSNorm times
+``silu(gate)`` that follows it).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from apex_tpu.amp.lists import apply_op_rules
+from apex_tpu.ops import _backend
+from apex_tpu.ops.pallas import gated_delta_rule as _k
+
+_HI = jax.lax.Precision.HIGHEST
+
+
+def causal_conv_silu(x, w):
+    """Depthwise causal convolution over time, then SiLU. ``x`` (b, t, c);
+    ``w`` (taps, c) with the last tap on the current token. Float32
+    accumulation, output in ``x``'s dtype."""
+    taps, t = w.shape[0], x.shape[1]
+    pad = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    y = sum(pad[:, j:j + t].astype(jnp.float32) * w[j].astype(jnp.float32)
+            for j in range(taps))
+    return jax.nn.silu(y).astype(x.dtype)
+
+
+def gated_rms_norm(x, gate, weight, eps=1e-6):
+    """``rmsnorm(x) * weight * silu(gate)`` over the last axis (one head),
+    statistics in float32."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+    y = y * weight.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
+    return y.astype(x.dtype)
+
+
+def l2_normalize(x, eps=1e-6):
+    x32 = x.astype(jnp.float32)
+    return x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, -1, keepdims=True) + eps)
+
+
+# --- inverse of a unit lower-triangular matrix --------------------------------
+
+def _substitute(a):
+    """``(I + a)^-1`` of strictly lower-triangular blocks ``a`` (s, s, N), the
+    batch in the minor axis: row after row, ``x_i = e_i - sum_j a_ij x_j`` —
+    elementwise float32 work in the recurrence's own order. (A loop, not
+    ``s^2 / 2`` unrolled updates: XLA reads ``a`` through the transpose that
+    made it for every one of those, four times the time on the chip.)"""
+    s = a.shape[0]
+    eye = jnp.eye(s, dtype=a.dtype)
+
+    def row(i, x):                         # rows from i on are still zero
+        a_i = jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+        r = eye[i][:, None] - jnp.sum(a_i[:, None, :] * x, axis=0)
+        return jax.lax.dynamic_update_index_in_dim(x, r, i, 0)
+
+    return jax.lax.fori_loop(0, s, row, jnp.zeros_like(a))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _unit_lower_inverse(a, precision=_HI):
+    """``(I + a)^-1`` for strictly lower-triangular ``a`` (..., C, C), float32,
+    by blocked forward substitution: the diagonal blocks of at least 32 rows
+    row after row, then pairs of blocks merged, ``[[P, 0], [Q, R]]^-1 =
+    [[P^-1, 0], [-R^-1 Q P^-1, R^-1]]``, until one block is left (two
+    float32 matmuls a doubling). Every intermediate is a block of the
+    inverse itself, so keys that point the same way (``a`` near 1
+    everywhere) lose no digits — the finite product ``(I - a)(I + a^2)
+    (I + a^4)...`` is the same matrix and cancels powers of ``a`` as large as
+    ``binom(C, C/2)`` to get it. ``precision`` is the cotangent's."""
+    C = a.shape[-1]
+    s = C
+    while s % 2 == 0 and s // 2 >= 32:
+        s //= 2
+    nb, lead = C // s, a.shape[:-2]
+    blocks = jnp.diagonal(a.reshape(lead + (nb, s, nb, s)), axis1=-4, axis2=-2)
+    x = _substitute(jnp.moveaxis(blocks, (-3, -2), (0, 1)).reshape(s, s, -1))
+    x = jnp.moveaxis(x.reshape((s, s) + lead + (nb,)), (0, 1), (-2, -1))   # (..., nb, s, s)
+    d = (x[..., :, :, None, :] * jnp.eye(nb, dtype=a.dtype)[:, None, :, None]
+         ).reshape(a.shape)                                             # block diagonal
+    while s < C:
+        row, col = (jnp.arange(C) // s)[:, None], (jnp.arange(C) // s)[None, :]
+        q = jnp.where((row % 2 == 1) & (col == row - 1), a, 0.0)
+        d = d - jnp.matmul(jnp.matmul(d, q, precision=_HI), d, precision=_HI)
+        s *= 2
+    return d
+
+
+def _inv_fwd(a, precision):
+    t = _unit_lower_inverse(a, precision)
+    return t, t
+
+
+def _inv_bwd(precision, t, dt):
+    tt = jnp.swapaxes(t, -1, -2)
+    return (-jnp.matmul(jnp.matmul(tt, dt, precision=precision), tt, precision=precision),)
+
+
+_unit_lower_inverse.defvjp(_inv_fwd, _inv_bwd)
+
+
+# --- chunk operands (XLA) and the recurrence over chunks ----------------------
+
+def _chunk_operands(q, k, v, g, beta, C, dtype):
+    """q, k (b, hk, n, C, dk) float32 (normalised, q scaled), v (b, hv, n, C,
+    dv), g, beta (b, hv, n, C) float32 -> w, u, qg, kg, p, gam of the module
+    docstring, the matmul operands cast to ``dtype``. The chunk-local
+    products run at the precision of what they feed: one bf16 pass where the
+    recurrence takes bf16 operands (the rounding of ``w`` and ``u`` themselves
+    is as large), float32 passes where it takes float32."""
+    hi = jax.lax.Precision.DEFAULT if dtype == jnp.bfloat16 else _HI
+    G = jnp.cumsum(g, axis=-1)
+    tri = jnp.tril(jnp.ones((C, C), bool))
+    diff = jnp.where(tri, G[..., :, None] - G[..., None, :], -jnp.inf)
+    decay = jnp.exp(diff)                                  # e^{G_i - G_j}, i >= j
+    # the scores are the key heads' own: computed once a key head, then
+    # given to the value heads it serves
+    serve = lambda x: jnp.repeat(x, v.shape[1] // k.shape[1], axis=1)  # noqa: E731
+    kk = serve(jnp.einsum("...ik,...jk->...ij", k, k, precision=hi))
+    qk = serve(jnp.einsum("...ik,...jk->...ij", q, k, precision=hi))
+    q, k = serve(q), serve(k)
+    a = jnp.where(jnp.tril(tri, -1), beta[..., None] * kk * decay, 0.0)
+    t = _unit_lower_inverse(a, hi)
+    eg = jnp.exp(G)[..., None]
+    w = jnp.einsum("...ij,...jk->...ik", t, beta[..., None] * eg * k, precision=hi)
+    u = jnp.einsum("...ij,...jk->...ik", t, beta[..., None] * v.astype(jnp.float32),
+                   precision=hi)
+    p = qk * decay
+    kg = k * jnp.exp(G[..., -1:, None] - G[..., None])
+    gam = jnp.exp(G[..., -1])
+    cast = lambda x: x.astype(dtype)  # noqa: E731
+    return cast(w), cast(u), cast(q * eg), cast(kg), cast(p), gam
+
+
+def _recurrence_xla(w, u, qg, kg, p, gam):
+    """(b, h, n, C, .) operands, gam (b, h, n): a scan over the chunks."""
+    f32 = jnp.float32
+    mm = functools.partial(jnp.einsum, preferred_element_type=f32, precision=_HI)
+
+    def chunk(state, x):
+        w, u, qg, kg, p, gam = x
+        s = state.astype(w.dtype)
+        v_new = u.astype(f32) - mm("bhck,bhkv->bhcv", w, s)
+        o = mm("bhck,bhkv->bhcv", qg, s) + mm("bhcj,bhjv->bhcv", p, v_new.astype(p.dtype))
+        state = state * gam[..., None, None] + mm("bhck,bhcv->bhkv", kg, v_new.astype(kg.dtype))
+        return state, o.astype(u.dtype)
+
+    b, h, n, C, dk = w.shape
+    xs = jax.tree.map(lambda a: jnp.moveaxis(a, 2, 0), (w, u, qg, kg, p, gam))
+    _, o = jax.lax.scan(chunk, jnp.zeros((b, h, dk, u.shape[-1]), f32), xs)
+    return jnp.moveaxis(o, 0, 2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _recurrence_pallas(w, u, qg, kg, p, gam, interpret):
+    """Operands (rows, T, .), gam (rows, n, 128)."""
+    return _k.gdn_fwd(w, u, qg, kg, p, gam, interpret=interpret)[0]
+
+
+def _rec_fwd(w, u, qg, kg, p, gam, interpret):
+    o, s0 = _k.gdn_fwd(w, u, qg, kg, p, gam, interpret=interpret)
+    return o, (w, u, qg, kg, p, gam, s0)
+
+
+def _rec_bwd(interpret, res, do):
+    return tuple(_k.gdn_bwd(*res, do, interpret=interpret))
+
+
+_recurrence_pallas.defvjp(_rec_fwd, _rec_bwd)
+
+
+def shapes_ok(dk: int, dv: int, chunk: int) -> bool:
+    """What the kernels' blocks need: features in whole lanes, chunks in
+    whole sublane tiles of either operand dtype."""
+    return dk % _k.LANES == 0 and dv % _k.LANES == 0 and chunk % 16 == 0
+
+
+def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, impl: str = "auto"):
+    """The rule of the module docstring over whole sequences from a zero state.
+
+    ``q``, ``k`` (b, t, hk, dk) as they leave the convolution — they are
+    L2-normalised here, ``q`` scaled by ``dk ** -0.5``; ``v`` (b, t, hv, dv)
+    with ``hv`` a multiple of ``hk`` (each key head serves ``hv // hk``
+    value heads); ``g`` (log decay) and ``beta`` (b, t, hv), float32 whatever
+    the inputs. Returns ``o`` (b, t, hv, dv) in ``v``'s dtype. ``t`` need not
+    be a multiple of ``chunk``: the tail is padded with tokens that neither
+    decay nor write. HALF-class under O1 (matmul-shaped; decays, norms and
+    the state are float32 inside regardless).
+
+    ``impl``: ``auto`` | ``pallas`` | ``xla`` — the recurrence over chunks as
+    the ``gdn_fwd`` / ``gdn_bwd`` kernels or as a ``lax.scan``.
+    """
+    q, k, v = apply_op_rules("gated_delta_rule", q, k, v)
+    b, t, hk, dk = q.shape
+    hv, dv = v.shape[2], v.shape[3]
+    use_kernel = _backend.choose_impl(impl, shapes_ok(dk, dv, chunk)) == "pallas"
+    n = -(-t // chunk)
+    if use_kernel and n > 8:
+        n = -(-n // 8) * 8                 # the kernel takes 8 chunks a step
+    pad = n * chunk - t
+    g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
+    qn = l2_normalize(q) * dk ** -0.5
+    kn = l2_normalize(k)
+
+    def chunks(x):                         # (b, t, heads, .) -> (b, heads, n, C, .)
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        x = jnp.moveaxis(x, 1, 2)
+        return x.reshape(x.shape[:2] + (n, chunk) + x.shape[3:])
+
+    ops = _chunk_operands(chunks(qn), chunks(kn), chunks(v), chunks(g[..., None])[..., 0],
+                          chunks(beta[..., None])[..., 0], chunk, v.dtype)
+    if use_kernel:
+        flat = [a.reshape((b * hv, n * chunk, a.shape[-1])) for a in ops[:5]]
+        gam = jnp.broadcast_to(ops[5].reshape(b * hv, n, 1), (b * hv, n, _k.LANES))
+        o = _recurrence_pallas(*flat, gam, _backend.interpret_mode())
+        o = o.reshape(b, hv, n * chunk, dv)
+    else:
+        o = _recurrence_xla(*ops).reshape(b, hv, n * chunk, dv)
+    return jnp.moveaxis(o, 1, 2)[:, :t]

@@ -583,6 +583,18 @@ def _fused_bwd_vmem_bytes(s, d, bq, bk, itemsize):
     return accumulators + outputs + blocks + tiles
 
 
+def _split_bwd_vmem_limit(d, bq, bk, itemsize, out_itemsize):
+    """``vmem_limit_bytes`` of a split (dq | dkv) backward call, or ``None``
+    (Mosaic's default) where the call fits the default: the double-buffered
+    q/do and k/v blocks, the output blocks and their fp32 accumulators, and
+    the score-tile temporaries of one step. Heads of 256 at 1,024-blocks
+    ask 17 MiB where heads of 128 and less stay inside the 16."""
+    blocks = 2 * (2 * bq + 2 * bk) * d * itemsize
+    outputs = 3 * 2 * max(bq, bk) * d * max(out_itemsize, 4)
+    need = blocks + outputs + 8 * bq * bk * 4
+    return _vmem_limit(need) if d > 128 else None
+
+
 def _bwd_packed_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h,
                              h_kv, varlen, rate=0.0):
     """One-pass backward of the packed layout: grid (b·h_kv, group, nq, nk),
@@ -1649,8 +1661,9 @@ def flash_bwd_bshd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
         out_shape=jax.ShapeDtypeStruct((b, sq, h * d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_split_bwd_vmem_limit(
+                d, bq, bk, q.dtype.itemsize, q.dtype.itemsize)),
         interpret=interpret,
     )(q3, k3, v3, do3, lse4, delta4, *extra_args)
 
@@ -1687,8 +1700,9 @@ def flash_bwd_bshd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_split_bwd_vmem_limit(
+                d, bq, bk, q.dtype.itemsize, jnp.dtype(dkv_dtypes[0]).itemsize)),
         interpret=interpret,
     )(q3, k3, v3, do3, lse4, delta4, *extra_args)
     dq = dq.reshape(b, sq, h, d)

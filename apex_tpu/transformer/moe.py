@@ -27,10 +27,16 @@ dispatched blocks to the experts' owners, the second routes results back.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from apex_tpu.monitor import spans as monitor_spans
+from apex_tpu.ops import _backend
+from apex_tpu.ops.pallas import grouped_matmul as gk
 
 
 # the router aux schema — THE definition every consumer zero-initializes
@@ -409,3 +415,286 @@ def moe_layer(
     y = _gather_combine(expert_out.reshape(E * capacity, d), gates,
                         slot_ids, inv, valid)
     return y.reshape(*lead, d).astype(x.dtype), aux
+
+
+# --- dropless routing onto the experts held here -----------------------------
+#
+# The capacity layer above pads or drops to a fixed (E, C) grid. The layer
+# below drops nothing: every (token, expert) assignment whose expert is held
+# here is computed, however uneven the routing. Static shapes come from
+# sorting the assignments by expert into a row buffer in which each expert's
+# rows start on a tile boundary (``ops/pallas/grouped_matmul``). The rows are
+# computed a block of as many rows as there are tokens at a time, as many
+# blocks as the routing fills: one where a rank holds a small share of the
+# experts, ``top_k`` and one more where it holds them all. What a block costs
+# does not depend on how many there are.
+
+def route_topk(x, router, k, *, normalize=True):
+    """Router at its full width: ``p = softmax_fp32(x @ router)``, the top
+    ``k`` (ids (T, k) int32, weights (T, k) float32, renormalised to sum 1
+    when ``normalize``), the Switch load-balance term ``E sum_e f_e P_e``
+    (``f``: share of the T k assignments, ``P``: mean probability) and the
+    assignments to every expert (E,) int32."""
+    logits = jnp.dot(x, router.astype(x.dtype), preferred_element_type=jnp.float32)
+    p = jax.nn.softmax(logits, axis=-1)
+    top_p, top_e = jax.lax.top_k(p, k)
+    if normalize:
+        top_p = top_p / jnp.sum(top_p, -1, keepdims=True)
+    E = router.shape[-1]
+    counts = jnp.sum(top_e[..., None] == jnp.arange(E, dtype=top_e.dtype),
+                     axis=(0, 1), dtype=jnp.int32)
+    share = counts.astype(jnp.float32) / (x.shape[0] * k)
+    aux = E * jnp.sum(share * jnp.mean(p, axis=0))
+    return top_e.astype(jnp.int32), top_p, aux, counts
+
+
+def dropless_plan(top_e, counts, experts_held, block_rows, tile):
+    """Where each local assignment's row lives, for the most rows a routing
+    can fill (every token's assignments local, every expert's last tile
+    nearly empty) in whole blocks of ``block_rows``. Returns a dict of int32
+    / bool arrays: ``tile_expert`` (tiles,) the held expert of every tile,
+    ``n_used`` () tiles in use, ``row_token``, ``row_assign``, ``row_valid``
+    (rows,), ``pos`` (T, k) the row of each assignment, ``local`` (T, k)."""
+    first, count = experts_held
+    T, k = top_e.shape
+    N = T * k
+    worst = T * min(k, count) + count * tile
+    rows = -(-worst // block_rows) * block_rows
+    local = (top_e >= first) & (top_e < first + count)
+    key = jnp.where(local, top_e - first, count).reshape(N)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)   # sorted place -> assignment
+    place = jnp.zeros((N,), jnp.int32).at[order].set(jnp.arange(N, dtype=jnp.int32))
+    held = jax.lax.dynamic_slice(counts, (first,), (count,))
+    start = jnp.cumsum(held) - held                           # first sorted place
+    tiles_of = -(-held // tile)
+    tile_end = jnp.cumsum(tiles_of)
+    tile_start = tile_end - tiles_of
+    n_used = tile_end[-1]
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(rows // tile, dtype=jnp.int32), side="right"),
+        count - 1).astype(jnp.int32)
+    r = jnp.arange(rows, dtype=jnp.int32)
+    e = tile_expert[r // tile]
+    within = r - tile_start[e] * tile
+    row_valid = (r // tile < n_used) & (within < held[e])
+    row_assign = order[jnp.clip(start[e] + within, 0, N - 1)]
+    e_of = jnp.minimum(key, count - 1)
+    pos = (tile_start[e_of] * tile + place - start[e_of]).reshape(T, k)
+    return {"tile_expert": tile_expert, "n_used": n_used.astype(jnp.int32),
+            "row_token": row_assign // k, "row_assign": row_assign,
+            "row_valid": row_valid, "pos": pos, "local": local}
+
+
+def _f0(a):
+    import numpy as np
+    return np.zeros(a.shape, jax.dtypes.float0)
+
+
+@jax.custom_vjp
+def _rows_from_tokens(x, row_token, row_valid, pos, sel):
+    """(T, H) tokens -> this block's (R, H) rows: a gather, and a gather
+    back (each token sums the rows its selected assignments own)."""
+    return jnp.where(row_valid[:, None], x[row_token], 0).astype(x.dtype)
+
+
+def _rows_fwd(x, row_token, row_valid, pos, sel):
+    return _rows_from_tokens(x, row_token, row_valid, pos, sel), (row_token, row_valid, pos, sel)
+
+
+def _rows_bwd(res, g):
+    row_token, row_valid, pos, sel = res
+    picked = jnp.where(sel[..., None], g[pos], 0)             # (T, k, H)
+    dx = jnp.sum(picked.astype(jnp.float32), axis=1).astype(g.dtype)
+    return dx, _f0(row_token), _f0(row_valid), _f0(pos), _f0(sel)
+
+
+_rows_from_tokens.defvjp(_rows_fwd, _rows_bwd)
+
+
+@jax.custom_vjp
+def _tokens_from_rows(y, weights, row_token, row_assign, row_valid, pos, sel):
+    """out[t] = sum_k sel[t, k] weights[t, k] y[pos[t, k]] (float32 sum);
+    its cotangent for ``y`` is again a gather, by ``row_token``."""
+    picked = jnp.where(sel[..., None], y[pos], 0).astype(jnp.float32)
+    return jnp.einsum("tkh,tk->th", picked, weights).astype(y.dtype)
+
+
+def _tokens_fwd(y, weights, row_token, row_assign, row_valid, pos, sel):
+    return (_tokens_from_rows(y, weights, row_token, row_assign, row_valid, pos, sel),
+            (y, weights, row_token, row_assign, row_valid, pos, sel))
+
+
+def _tokens_bwd(res, dout):
+    y, weights, row_token, row_assign, row_valid, pos, sel = res
+    d_row = jnp.where(row_valid[:, None], dout[row_token], 0)  # (R, H)
+    row_weight = weights.reshape(-1)[row_assign]
+    dy = (d_row.astype(jnp.float32) * row_weight[:, None]).astype(y.dtype)
+    dots = jnp.sum(d_row.astype(jnp.float32) * y.astype(jnp.float32), axis=-1)
+    dweights = jnp.where(sel, dots[pos], 0.0).astype(weights.dtype)
+    return (dy, dweights, _f0(row_token), _f0(row_assign), _f0(row_valid),
+            _f0(pos), _f0(sel))
+
+
+_tokens_from_rows.defvjp(_tokens_fwd, _tokens_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def grouped_matmul(x, w, tile_expert, n_used, impl="auto"):
+    """out[tile] = x[tile] @ w[tile_expert[tile]] over tiles of
+    ``grouped_matmul.TM`` rows; tiles from ``n_used`` on give zeros. As the
+    ``moe_gmm`` kernels or (``impl="xla"``) a batched einsum against the
+    gathered matrices."""
+    nu = n_used.reshape(1)
+    if _backend.choose_impl(impl, _gmm_shapes_ok(x, w)) == "pallas":
+        return gk.moe_gmm(x, w, tile_expert, nu, interpret=_backend.interpret_mode())
+    tiles = x.reshape(-1, gk.TM, x.shape[-1])
+    out = jnp.einsum("tmk,tkn->tmn", tiles, w[tile_expert],
+                     preferred_element_type=jnp.float32)
+    used = jnp.arange(tiles.shape[0]) < n_used
+    return jnp.where(used[:, None, None], out, 0).astype(x.dtype).reshape(x.shape[0], -1)
+
+
+def _gmm_shapes_ok(x, w):
+    return x.shape[-1] % 128 == 0 and w.shape[-1] % 128 == 0
+
+
+def _gmm_fwd(x, w, tile_expert, n_used, impl):
+    return grouped_matmul(x, w, tile_expert, n_used, impl), (x, w, tile_expert, n_used)
+
+
+def _gmm_bwd(impl, res, dy):
+    x, w, tile_expert, n_used = res
+    nu = n_used.reshape(1)
+    if _backend.choose_impl(impl, _gmm_shapes_ok(x, w)) == "pallas":
+        interpret = _backend.interpret_mode()
+        dx = gk.moe_gmm_dx(dy, w, tile_expert, nu, interpret=interpret)
+        dw = gk.moe_gmm_dw(x, dy, tile_expert, nu, w.shape[0], interpret=interpret)
+    else:
+        used = (jnp.arange(tile_expert.shape[0]) < n_used)[:, None, None]
+        xt = x.reshape(-1, gk.TM, x.shape[-1])
+        dyt = jnp.where(used, dy.reshape(-1, gk.TM, dy.shape[-1]), 0)
+        dx = jnp.einsum("tmn,tkn->tmk", dyt, w[tile_expert],
+                        preferred_element_type=jnp.float32).astype(x.dtype).reshape(x.shape)
+        per_tile = jnp.einsum("tmk,tmn->tkn", xt, dyt, preferred_element_type=jnp.float32)
+        dw = jax.ops.segment_sum(per_tile, tile_expert, num_segments=w.shape[0]).astype(w.dtype)
+    return dx, dw, _f0(tile_expert), _f0(n_used)
+
+
+grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def _held_experts_block(x, weights, w_gate_up, w_down, plan, block, rows, impl):
+    """What the rows ``[block * rows, (block + 1) * rows)`` of the plan add
+    to every token: gather, gate/up product, SiLU gate, down product,
+    weighted gather back."""
+    lo = block * rows
+    cut = lambda a: jax.lax.dynamic_slice_in_dim(a, lo, rows)  # noqa: E731
+    tile_expert = jax.lax.dynamic_slice_in_dim(plan["tile_expert"], lo // gk.TM, rows // gk.TM)
+    n_used = jnp.clip(plan["n_used"] - lo // gk.TM, 0, rows // gk.TM)
+    row_token, row_assign, row_valid = (cut(plan[n]) for n in
+                                        ("row_token", "row_assign", "row_valid"))
+    sel = plan["local"] & (plan["pos"] >= lo) & (plan["pos"] < lo + rows)
+    pos = jnp.clip(plan["pos"] - lo, 0, rows - 1)
+    xs = _rows_from_tokens(x, row_token, row_valid, pos, sel)
+    with monitor_spans.span("moe/experts"):
+        h = grouped_matmul(xs, w_gate_up, tile_expert, n_used, impl)
+        gate, up = jnp.split(h, 2, axis=-1)
+        act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(h.dtype)
+        y = grouped_matmul(act, w_down, tile_expert, n_used, impl)
+    return _tokens_from_rows(y, weights, row_token, row_assign, row_valid, pos, sel)
+
+
+def _add(a, b):
+    return jax.tree.map(
+        lambda a, b: (a.astype(jnp.float32) + b.astype(jnp.float32)).astype(a.dtype), a, b)
+
+
+def _blocks_used(plan, rows):
+    return -(-plan["n_used"] * gk.TM // rows)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _held_experts(x, weights, w_gate_up, w_down, plan, rows, impl):
+    """What the experts held add to every token: the plan's rows, block after
+    block of ``rows``, as many blocks as the routing fills — a loop whose
+    length the routing decides. The first block always runs and keeps for
+    the backward pass what differentiation would keep; a loop of unknown
+    length can keep nothing, so the backward pass computes every further
+    block again."""
+    return _held_experts_fwd(x, weights, w_gate_up, w_down, plan, rows, impl)[0]
+
+
+def _held_experts_fwd(x, weights, w_gate_up, w_down, plan, rows, impl):
+    args = (x, weights, w_gate_up, w_down)
+    one = lambda b: functools.partial(  # noqa: E731
+        _held_experts_block, plan=plan, block=b, rows=rows, impl=impl)
+    y, pull_first = jax.vjp(one(0), *args)
+    y = jax.lax.fori_loop(1, _blocks_used(plan, rows),
+                          lambda b, y: _add(y, one(b)(*args)), y)
+    return y, (pull_first, args, plan)
+
+
+def _held_experts_bwd(rows, impl, res, dy):
+    pull_first, args, plan = res
+    one = lambda b: functools.partial(  # noqa: E731
+        _held_experts_block, plan=plan, block=b, rows=rows, impl=impl)
+    grads = jax.lax.fori_loop(
+        1, _blocks_used(plan, rows),
+        lambda b, grads: _add(grads, jax.vjp(one(b), *args)[1](dy)), pull_first(dy))
+    return (*grads, jax.tree.map(_f0, plan))
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+def dropless_moe_layer(params, x, *, top_k, experts_held=None,
+                       normalize_weights=True, impl="auto"):
+    """Sparse SwiGLU experts without token dropping, plus a sigmoid-gated
+    shared expert, over ``x`` (..., hidden).
+
+    ``params``: ``router`` (hidden, E) at the router's FULL width;
+    ``w_gate_up`` (held, hidden, 2 F) and ``w_down`` (held, F, hidden) of the
+    experts held here; ``shared_gate_up`` (hidden, 2 Fs), ``shared_down``
+    (Fs, hidden), ``shared_mix`` (hidden,). ``experts_held = (first, count)``
+    says which of the router's experts these are (default: all). The layer
+    routes every token over all E experts, computes the part of the result
+    its own experts give and adds nothing for the absent ones — what one
+    member of an expert-parallel group computes before the exchange (on one
+    chip there is no exchange; the ragged exchange over ``ep`` is not here).
+
+    Returns ``(y, aux)``: ``aux["load_balance_loss"]`` (over the full
+    width), ``aux["expert_load"]`` (count,) int32 assignments to each expert
+    held, ``aux["dropped"]`` () int32 — local assignments that no row
+    computed, which this layer keeps at 0 by construction.
+    """
+    lead, H = x.shape[:-1], x.shape[-1]
+    xt = x.reshape(-1, H)
+    T = xt.shape[0]
+    E = params["router"].shape[-1]
+    held = (0, E) if experts_held is None else tuple(experts_held)
+    if params["w_gate_up"].shape[0] != held[1] or held[0] + held[1] > E:
+        raise ValueError(
+            f"experts_held={held} does not match the {params['w_gate_up'].shape[0]} "
+            f"expert matrices given and a router of width {E}")
+    with monitor_spans.span("moe/route"):
+        top_e, top_p, aux_loss, counts = route_topk(
+            xt, params["router"], top_k, normalize=normalize_weights)
+        rows = -(-T // gk.TM) * gk.TM
+        # under jax.checkpoint a policy may keep the plan by this name, so
+        # that the backward pass does not sort again
+        plan = jax.tree.map(
+            lambda a: checkpoint_name(jax.lax.stop_gradient(a), "moe_plan"),
+            dropless_plan(top_e, counts, held, rows, gk.TM))
+    y = _held_experts(xt, top_p, params["w_gate_up"], params["w_down"], plan, rows, impl)
+    with monitor_spans.span("moe/shared"):
+        gate, up = jnp.split(jnp.dot(xt, params["shared_gate_up"]), 2, axis=-1)
+        act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(xt.dtype)
+        mix = jax.nn.sigmoid(jnp.dot(xt, params["shared_mix"],
+                                     preferred_element_type=jnp.float32))
+        y = y + (mix[:, None] * jnp.dot(act, params["shared_down"]).astype(jnp.float32)
+                 ).astype(xt.dtype)
+    load = jax.lax.dynamic_slice(counts, (held[0],), (held[1],))
+    computed = jnp.sum(plan["row_valid"], dtype=jnp.int32)
+    aux = {"load_balance_loss": aux_loss, "expert_load": load,
+           "dropped": jnp.sum(load) - computed}
+    return y.reshape(*lead, H), aux

@@ -18,7 +18,8 @@ PALLAS_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file
                           "apex_tpu", "ops", "pallas")
 KERNEL_FILES = sorted(os.path.basename(p) for p in glob.glob(os.path.join(PALLAS_DIR, "*.py"))
                       if os.path.basename(p) != "__init__.py")
-# readers match by prefix: flash_fwd*, flash_bwd*, xentropy*; the rest by name
+# readers match by prefix: flash_fwd*, flash_bwd*, xentropy*, gdn_fwd*, gdn_bwd*,
+# moe_gmm*; the rest by name
 EXPECTED = {
     "attention.py": {
         "flash_fwd", "flash_fwd_packed", "flash_fwd_bshd",
@@ -32,6 +33,8 @@ EXPECTED = {
     "softmax.py": {"softmax_fwd", "softmax_bwd"},
     "sampling.py": {"fused_sample"},
     "verify.py": {"fused_verify", "fused_verify_tree"},
+    "gated_delta_rule.py": {"gdn_fwd", "gdn_bwd"},
+    "grouped_matmul.py": {"moe_gmm", "moe_gmm_dx", "moe_gmm_dw"},
 }
 SCOPES = ("amp/fwd_bwd", "amp/unscale_check", "amp/apply_master", "fused_adam/update",
           "gpt/embed", "gpt/attn", "gpt/mlp", "gpt/unembed_xent", "ddp/allreduce")
@@ -62,7 +65,7 @@ def test_every_pallas_call_has_a_literal_name(filename):
 def test_kernel_names_are_distinct_across_the_package():
     assert set(KERNEL_FILES) == set(EXPECTED)
     names = [n for f in KERNEL_FILES for n in literal_names(f)]
-    assert len(names) == 23 and len(set(names)) == 23
+    assert len(names) == 28 and len(set(names)) == 28
 
 
 def kernel_names(jaxpr):

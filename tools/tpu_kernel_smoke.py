@@ -3,6 +3,7 @@ against its XLA composition at the flagship's shapes (hidden 1024, 8 heads
 of 128, seq 1024, vocab 32768, 8 serving slots, 128-row cache blocks).
 
     python tools/tpu_kernel_smoke.py          # exits non-zero on any failure
+    python tools/tpu_kernel_smoke.py gdn moe  # only the families so named
 
 A family is ``(kernel_fn, reference_fn, args)``: both run under ``jax.jit``
 on the same operands and every output leaf (forward values AND gradients)
@@ -288,6 +289,96 @@ def _verify_tree_family(**knobs):
     return build
 
 
+# --- the hybrid decoder's kernels ------------------------------------------------
+
+def _flash_wide_head_family():
+    """Heads of 256 in a group of 8 (the split bshd backward's own VMEM
+    limit), the gated-attention layers' shape at the smoke's sequence."""
+    def build():
+        q = jr.normal(_key(41), (B, S, H, 256), jnp.bfloat16)
+        k, v = (jr.normal(_key(i), (B, S, 1, 256), jnp.bfloat16) for i in (42, 43))
+
+        def make(impl):
+            return _fwd_and_grads(
+                lambda q, k, v: flash_attention(q, k, v, causal=True, layout="bshd",
+                                                scale=256 ** -0.5, impl=impl), (0, 1, 2))
+        return make("pallas"), make("xla"), (q, k, v)
+    return build
+
+
+def _delta_rule_family():
+    """The recurrence over chunks as ``gdn_fwd`` / ``gdn_bwd`` against the
+    ``lax.scan`` of the same chunk operands: two key heads serve four value
+    heads, 16 chunks a row (two grid steps), decays from 0.2 to 0.999."""
+    def build():
+        from apex_tpu.ops.gated_delta_rule import gated_delta_rule
+        q, k = (jr.normal(_key(i), (B, S, 2, D), jnp.bfloat16) for i in (44, 45))
+        v = jr.normal(_key(46), (B, S, 4, D), jnp.bfloat16)
+        g = -jnp.exp(jr.uniform(_key(47), (B, S, 4), minval=-7.0, maxval=0.5))
+        beta = jax.nn.sigmoid(jr.normal(_key(48), (B, S, 4)))
+
+        def make(impl):
+            return _fwd_and_grads(lambda *a: gated_delta_rule(*a, impl=impl), (0, 1, 2, 3, 4))
+        return make("pallas"), make("xla"), (q, k, v, g, beta)
+    return build
+
+
+def _delta_rule_drifted_family():
+    """The whole chunked form (chunk operands, ``gdn_fwd`` / ``gdn_bwd``) in
+    bfloat16 against the token-by-token recurrence in float32, on keys as
+    training leaves them: 0.7 of every key one common direction, strong
+    writes, decays of 0.999 and slower — the chunk's triangular system far
+    from the identity, where an inverse that cancels powers of it is lost."""
+    def build():
+        from apex_tpu.ops.gated_delta_rule import gated_delta_rule, l2_normalize
+        q = jr.normal(_key(60), (B, S, 2, D), jnp.bfloat16)
+        k = (0.3 * jr.normal(_key(61), (B, S, 2, D))
+             + 0.7 * jr.normal(_key(62), (B, 1, 2, D))).astype(jnp.bfloat16)
+        v = jr.normal(_key(63), (B, S, 4, D), jnp.bfloat16)
+        g = -jnp.exp(jr.uniform(_key(64), (B, S, 4), minval=-12.0, maxval=-7.0))
+        beta = jax.nn.sigmoid(jr.normal(_key(65), (B, S, 4)) + 3.0)
+
+        def recurrence(q, k, v, g, beta):
+            f32 = jnp.float32
+            qn = jnp.repeat(l2_normalize(q) * D ** -0.5, 2, axis=2)
+            kn = jnp.repeat(l2_normalize(k), 2, axis=2)
+
+            def token(state, x):
+                q, k, v, g, beta = x              # (B, 4, D) and (B, 4)
+                state = state * jnp.exp(g)[..., None, None]
+                u = beta[..., None] * (v - jnp.einsum("bhkv,bhk->bhv", state, k, precision="highest"))
+                state = state + k[..., :, None] * u[..., None, :]
+                return state, jnp.einsum("bhkv,bhk->bhv", state, q, precision="highest")
+
+            xs = jax.tree.map(lambda a: jnp.moveaxis(a.astype(f32), 1, 0), (qn, kn, v, g, beta))
+            _, o = jax.lax.scan(token, jnp.zeros((B, 4, D, D), f32), xs)
+            return jnp.moveaxis(o, 0, 1)
+
+        args = (0, 1, 2, 3, 4)
+        return (_fwd_and_grads(lambda *a: gated_delta_rule(*a, impl="pallas"), args),
+                _fwd_and_grads(recurrence, args), (q, k, v, g, beta))
+    return build
+
+
+def _dropless_family():
+    """The dropless expert layer on the ``moe_gmm`` kernels against the
+    batched-einsum composition: 8 of 16 experts held, top 4, 2,048 tokens."""
+    def build():
+        from apex_tpu.transformer.moe import dropless_moe_layer
+        F, E, held = 512, 16, (4, 8)
+        n = lambda i, *shape: (0.05 * jr.normal(_key(i), shape)).astype(jnp.bfloat16)  # noqa: E731
+        p = {"router": n(50, HID, E), "w_gate_up": n(51, held[1], HID, 2 * F),
+             "w_down": n(52, held[1], F, HID), "shared_gate_up": n(53, HID, 2 * F),
+             "shared_down": n(54, F, HID), "shared_mix": n(55, HID)}
+        x = jr.normal(_key(56), (B, S, HID), jnp.bfloat16)
+
+        def make(impl):
+            return _fwd_and_grads(lambda p, x: dropless_moe_layer(
+                p, x, top_k=4, experts_held=held, impl=impl)[0], (0, 1))
+        return make("pallas"), make("xla"), (p, x)
+    return build
+
+
 # --- the explicit-only families (auto resolves them to XLA) --------------------
 
 def _ln_family(rms):
@@ -367,6 +458,11 @@ FAMILIES = (
     Family("flash packed dropout", _packed_family(dropout=0.1)),
     Family("flash packed bias + dbias", _packed_family(biased=True)),
     Family("xentropy stats", _xent_family, tol=F32_TOL),
+    Family("flash bshd heads of 256, group 8", _flash_wide_head_family()),
+    Family("gated delta rule gdn_fwd/gdn_bwd", _delta_rule_family()),
+    Family("gated delta rule on drifted keys, against the recurrence",
+           _delta_rule_drifted_family()),
+    Family("dropless experts moe_gmm/dx/dw", _dropless_family()),
     Family("decode contiguous MHA", _decode_family(H)),
     Family("decode contiguous GQA group 4", _decode_family(2)),
     Family("decode contiguous bucketed bias", _decode_family(H, bias=True)),
@@ -458,4 +554,7 @@ def main(families=FAMILIES) -> list:
 
 
 if __name__ == "__main__":
-    sys.exit(1 if main() else 0)
+    # any arguments choose the families whose name holds one of them
+    chosen = tuple(f for f in FAMILIES if not sys.argv[1:]
+                   or any(part in f.name for part in sys.argv[1:]))
+    sys.exit(1 if main(chosen) else 0)
