@@ -10,12 +10,14 @@ write strength ``beta_t`` in (0, 1):
 UT form): inside a chunk the ``u`` solve a unit lower-triangular system
 ``(I + A) U = beta V - (beta e^G K) S_0``, so ``T = (I + A)^-1`` turns each
 chunk into operands of a recurrence over *chunks* — matmuls instead of
-``chunk`` rank-one updates. All chunks are prepared at once in XLA (float32
-decays, cumulative inside the chunk only, so nothing is ever divided by a
-decay); the recurrence over chunks is the Pallas kernel pair
-``gdn_fwd`` / ``gdn_bwd`` (``ops/pallas/gated_delta_rule.py``) or, as
-``impl="xla"``, a ``lax.scan`` of the same mathematics — the oracle the
-kernels are tested against, as every kernel family here has one.
+``chunk`` rank-one updates (float32 decays, cumulative inside the chunk
+only, so nothing is ever divided by a decay). The Pallas kernel pair
+``gdn_fwd`` / ``gdn_bwd`` (``ops/pallas/gated_delta_rule.py``) builds every
+chunk's operands in VMEM and walks the chunks with the state there too;
+``impl="xla"`` prepares all chunks at once in XLA and runs a ``lax.scan``
+over them — the same mathematics, the oracle the kernels are tested
+against, as every kernel family here has one, and the path for shapes the
+kernels refuse.
 
 Also here: :func:`causal_conv_silu` (the depthwise causal convolution that
 precedes the rule) and :func:`gated_rms_norm` (the head-wise RMSNorm times
@@ -56,7 +58,7 @@ def gated_rms_norm(x, gate, weight, eps=1e-6):
     return y.astype(x.dtype)
 
 
-def l2_normalize(x, eps=1e-6):
+def l2_normalize(x, eps=_k.EPS):
     x32 = x.astype(jnp.float32)
     return x32 * jax.lax.rsqrt(jnp.sum(x32 * x32, -1, keepdims=True) + eps)
 
@@ -174,27 +176,36 @@ def _recurrence_xla(w, u, qg, kg, p, gam):
     return jnp.moveaxis(o, 0, 2)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _recurrence_pallas(w, u, qg, kg, p, gam, interpret):
-    """Operands (rows, T, .), gam (rows, n, 128)."""
-    return _k.gdn_fwd(w, u, qg, kg, p, gam, interpret=interpret)[0]
+# jitted: the layers of a model share one traced and lowered program a kernel
+_gdn_fwd = jax.jit(_k.gdn_fwd, static_argnames=("heads", "interpret"))
+_gdn_bwd = jax.jit(_k.gdn_bwd, static_argnames=("heads", "interpret"))
 
 
-def _rec_fwd(w, u, qg, kg, p, gam, interpret):
-    o, s0 = _k.gdn_fwd(w, u, qg, kg, p, gam, interpret=interpret)
-    return o, (w, u, qg, kg, p, gam, s0)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _rule_pallas(q, k, v, G, beta, gl, heads, interpret):
+    """The rule on the kernels: ``q, k`` (b, T, hk dk), ``v`` (b, T, hv dv) as
+    the convolution leaves them, ``G`` (the log decay cumulated inside each
+    chunk) and ``beta`` (b, hv, n, C) float32, ``gl`` (b, hv, n, dv) the
+    chunk's last ``G`` over the lanes. ``heads`` = hk."""
+    return _gdn_fwd(q, k, v, G, beta, gl, heads=heads, interpret=interpret)[0]
 
 
-def _rec_bwd(interpret, res, do):
-    return tuple(_k.gdn_bwd(*res, do, interpret=interpret))
+def _rule_fwd(q, k, v, G, beta, gl, heads, interpret):
+    o, s0 = _gdn_fwd(q, k, v, G, beta, gl, heads=heads, interpret=interpret)
+    return o, (q, k, v, G, beta, gl, s0)
 
 
-_recurrence_pallas.defvjp(_rec_fwd, _rec_bwd)
+def _rule_bwd(heads, interpret, res, do):
+    return tuple(_gdn_bwd(*res, do, heads=heads, interpret=interpret))
+
+
+_rule_pallas.defvjp(_rule_fwd, _rule_bwd)
 
 
 def shapes_ok(dk: int, dv: int, chunk: int) -> bool:
-    """What the kernels' blocks need: features in whole lanes, chunks in
-    whole sublane tiles of either operand dtype."""
+    """What the kernels' blocks need: features in whole lanes (a head is a
+    lane block of the projections' arrays), chunks in whole sublane tiles of
+    either operand dtype."""
     return dk % _k.LANES == 0 and dv % _k.LANES == 0 and chunk % 16 == 0
 
 
@@ -210,33 +221,40 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, impl: str = "auto"):
     decay nor write. HALF-class under O1 (matmul-shaped; decays, norms and
     the state are float32 inside regardless).
 
-    ``impl``: ``auto`` | ``pallas`` | ``xla`` — the recurrence over chunks as
-    the ``gdn_fwd`` / ``gdn_bwd`` kernels or as a ``lax.scan``.
+    ``impl``: ``auto`` | ``pallas`` | ``xla`` — the ``gdn_fwd`` / ``gdn_bwd``
+    kernels, which build every chunk's operands in VMEM, or the same
+    mathematics with the operands prepared by XLA and a ``lax.scan`` over
+    the chunks.
     """
     q, k, v = apply_op_rules("gated_delta_rule", q, k, v)
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
     use_kernel = _backend.choose_impl(impl, shapes_ok(dk, dv, chunk)) == "pallas"
     n = -(-t // chunk)
-    if use_kernel and n > 8:
-        n = -(-n // 8) * 8                 # the kernel takes 8 chunks a step
+    if use_kernel and n > _k.CHUNKS:
+        n = -(-n // _k.CHUNKS) * _k.CHUNKS         # whole grid steps
     pad = n * chunk - t
     g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
-    qn = l2_normalize(q) * dk ** -0.5
-    kn = l2_normalize(k)
 
-    def chunks(x):                         # (b, t, heads, .) -> (b, heads, n, C, .)
-        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-        x = jnp.moveaxis(x, 1, 2)
+    def padded(x):
+        return jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2)) if pad else x
+
+    def chunks(x):                         # (b, t, heads, ...) -> (b, heads, n, C, ...)
+        x = jnp.moveaxis(padded(x), 1, 2)
         return x.reshape(x.shape[:2] + (n, chunk) + x.shape[3:])
 
-    ops = _chunk_operands(chunks(qn), chunks(kn), chunks(v), chunks(g[..., None])[..., 0],
-                          chunks(beta[..., None])[..., 0], chunk, v.dtype)
     if use_kernel:
-        flat = [a.reshape((b * hv, n * chunk, a.shape[-1])) for a in ops[:5]]
-        gam = jnp.broadcast_to(ops[5].reshape(b * hv, n, 1), (b * hv, n, _k.LANES))
-        o = _recurrence_pallas(*flat, gam, _backend.interpret_mode())
-        o = o.reshape(b, hv, n * chunk, dv)
-    else:
-        o = _recurrence_xla(*ops).reshape(b, hv, n * chunk, dv)
+        # only the small float32 g and beta are re-laid (a chunk a row); q, k, v
+        # and o keep the layout of the projections, a head a lane block
+        G = jnp.cumsum(chunks(g), axis=-1)
+        gl = jnp.broadcast_to(G[..., -1:], G.shape[:-1] + (dv,))
+        flat = lambda x: padded(x).reshape(b, n * chunk, -1)  # noqa: E731
+        o = _rule_pallas(flat(q), flat(k), flat(v), G, chunks(beta), gl,
+                         hk, _backend.interpret_mode())
+        return o.reshape(b, n * chunk, hv, dv)[:, :t]
+    qn = l2_normalize(q) * dk ** -0.5
+    kn = l2_normalize(k)
+    ops = _chunk_operands(chunks(qn), chunks(kn), chunks(v), chunks(g), chunks(beta), chunk,
+                          v.dtype)
+    o = _recurrence_xla(*ops).reshape(b, hv, n * chunk, dv)
     return jnp.moveaxis(o, 1, 2)[:, :t]

@@ -1,25 +1,37 @@
-"""Pallas kernels of the chunked gated delta rule: the part that is
-sequential in time.
+"""Pallas kernels of the chunked gated delta rule: a chunk's operands are
+built where they are used, in VMEM, and only ``q, k, v, g, beta`` and ``o``
+cross HBM.
 
-``ops.gated_delta_rule`` turns every chunk of ``C`` tokens into its WY
-operands (all chunks at once, in XLA): ``w`` (C, dk), ``u`` (C, dv), the
-decayed queries ``qg`` and keys ``kg`` (C, dk), the masked, decayed scores
-``p`` (C, C) and the chunk's whole decay ``gam``. What is left walks the
-chunks in order with the state ``S (dk, dv)`` in float32:
+Per chunk of ``C`` tokens and value head, with ``G`` the in-chunk cumulative
+log decay, ``qn``/``kn`` the L2-normalised queries and keys of the key head
+that serves the value head, and float32 state ``S (dk, dv)``:
 
-    v' = u - w S;   o = qg S + p v';   S <- gam S + kg^T v'
+    decay_ij = e^{G_i - G_j} (i >= j);   a = tril(beta kk decay, -1);   T = (I + a)^-1
+    w = T (beta e^G kn);   u = T (beta v);   qg = qn e^G;   kg = kn e^{G_C - G}
+    p = tril(qk decay);    v' = u - w S;    o = qg S + p v';    S <- e^{G_C} S + kg^T v'
 
-``gdn_fwd`` keeps ``S`` in VMEM across the blocks of one (batch, head) row
-and handles ``chunks`` chunks a grid step (one DMA of ``chunks * C`` rows an
-operand); it writes the state each block started from. ``gdn_bwd`` walks the
-blocks backwards: it rebuilds the block's states and ``v'`` from that
-starting state, then carries ``dS`` back through the chunks. Matmul operands
-are in the operands' dtype (bf16 on the MXU), every accumulator is float32.
+``T`` comes from blocked forward substitution (:func:`_inverse`): the diagonal
+blocks of ``BASE`` rows column after column on the VPU, then pairs of blocks
+merged on the MXU — every intermediate a block of the inverse itself.
 
-Layout: operands (rows = batch x heads, T, feature), one row a grid row. The
-per-chunk decay rides as (rows, chunks, 128) float32, the scalar repeated
-over the lanes; its gradient comes back as lane-partial sums in the same
-shape (the caller's broadcast sums them).
+A grid step is ``CHUNKS`` chunks of one (batch, key head) and the value heads
+it serves. ``gdn_fwd`` first prepares the operands of all of them (nothing
+there waits for the state: ``UNROLL`` chunks side by side, their systems
+inverted as one batch), then walks the chunks in order with the states in
+VMEM; it writes the states each block started from. ``gdn_bwd`` walks the
+blocks backwards: it prepares the operands again, rebuilds the block's
+states and ``v'`` from that starting state, carries ``dS`` back through the
+chunks, and then turns the operands' cotangents into those of ``q, k, v, G,
+beta``; ``dq``, ``dk`` are summed over the value heads of the key head
+before they are written. Matmul operands are in ``v``'s dtype (bf16 on the MXU, float32 at
+``HIGHEST`` for float32 inputs); decays, ``beta``, the triangular system and
+its inverse, the state and every accumulator are float32.
+
+Layout: ``q, k`` (b, T, hk dk) and ``v, o`` (b, T, hv dv) as the projections
+leave them, one head a lane block picked by the index map; ``G``, ``beta``
+(b, hv, n, C) float32, a chunk a row; ``G_C`` (the chunk's whole log decay)
+rides as (b, hv, n, dv), the scalar repeated over the lanes, and its
+gradient comes back as lane-partial sums in the same shape.
 """
 
 from __future__ import annotations
@@ -32,148 +44,371 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
-
-
-def _mm(a, b, dims):
-    return jax.lax.dot_general(a, b, (dims, ((), ())),
-                               preferred_element_type=jnp.float32)
-
+CHUNKS = 8          # chunks a grid step
+BASE = 32           # rows of a diagonal block inverted by substitution
+UNROLL = 4          # chunks prepared side by side
+EPS = 1e-6          # of the L2 normalisation (ops.gated_delta_rule.l2_normalize takes it from here)
 
 NN, NT, TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
+_F32 = jnp.float32
 
 
-def _chunk_forward(state, w, u, qg, kg, p, gam_row):
-    """One chunk: (new state, v', o), all float32."""
-    s = state.astype(w.dtype)
-    v_new = u.astype(jnp.float32) - _mm(w, s, NN)
-    o = _mm(qg, s, NN) + _mm(p, v_new.astype(p.dtype), NN)
-    state = state * gam_row + _mm(kg, v_new.astype(kg.dtype), TN)
+def _mm(a, b, dims, precision=None):
+    return jax.lax.dot_general(a, b, (dims, ((), ())), precision=precision,
+                               preferred_element_type=_F32)
+
+
+def _precision(dtype):
+    """float32 operands take float32 passes; bf16 operands one pass."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+
+def _masks(C):
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    return row, col
+
+
+def _column(x_row, eye):
+    """(1, C) along the lanes -> (C, 1) along the sublanes."""
+    return jnp.sum(jnp.where(eye, x_row, 0.0), axis=1, keepdims=True)
+
+
+def _row(x_col, eye):
+    """(C, 1) -> (1, C)."""
+    return jnp.sum(jnp.where(eye, x_col, 0.0), axis=0, keepdims=True)
+
+
+def _inverse(a):
+    """``(I + a)^-1`` of strictly lower-triangular float32 ``a`` (N, C, C), N
+    matrices at once (one traced program for all of them). The diagonal
+    blocks of ``BASE`` rows by forward substitution, all blocks at once:
+    ``(I + a) = L_0 L_1 ...`` with ``L_r = I + a[:, r] e_r^T``, so the inverse
+    is ``I`` after the rank-one updates ``x -= a[:, r] x[r, :]`` in order —
+    the substitution's own sums, column after column, on the rows below ``r``
+    only (from the eight-row register that holds row ``r + 1``). Then pairs of
+    blocks merge, ``[[P, 0], [Q, R]]^-1 = [[P^-1, 0], [-R^-1 Q P^-1, R^-1]]``,
+    two float32 (``HIGHEST``) matmuls a doubling whatever the operands' dtype."""
+    N, C, _ = a.shape
+    base = min(BASE, C)
+    blocks = C // base
+
+    def within(rows):
+        """The column's place in its own diagonal block, per block (blocks,
+        rows, C): negative or past ``base`` outside the block. (Built at every
+        height used: Mosaic cannot slice an iota.)"""
+        col = jax.lax.broadcasted_iota(jnp.int32, (rows, C), 1)
+        return jnp.stack([col - blk * base for blk in range(blocks)])
+
+    a = a.reshape(N, blocks, base, C)
+    place = within(base)
+    ad = jnp.where((place >= 0) & (place < base), a, 0.0)
+    x = jnp.broadcast_to(jnp.where(
+        place == jax.lax.broadcasted_iota(jnp.int32, (base, C), 0), 1.0, 0.0).astype(_F32), a.shape)
+    for r in range(base - 1):
+        lo = (r + 1) // 8 * 8
+        a_r = jnp.sum(jnp.where(within(base - lo) == r, ad[:, :, lo:], 0.0), axis=3, keepdims=True)
+        below = x[:, :, lo:] - a_r * x[:, :, r:r + 1]
+        x = jnp.concatenate([x[:, :, :lo], below], axis=2) if lo else below
+    a, x = a.reshape(N, C, C), x.reshape(N, C, C)
+    row, col = _masks(C)
+    apart = row ^ col                        # in [s, 2s) and below the diagonal: the blocks Q
+    mm = functools.partial(jnp.einsum, "nij,njk->nik", precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=_F32)
+    s = base
+    while s < C:
+        q = jnp.where((apart >= s) & (apart < 2 * s), a, 0.0)
+        x = x - mm(mm(x, q), x)
+        s *= 2
+    return x
+
+
+def _normalized(x, scale):
+    """L2-normalised rows times ``scale`` (float32) and the factor applied."""
+    x32 = x.astype(_F32)
+    r = jax.lax.rsqrt(jnp.sum(x32 * x32, axis=1, keepdims=True) + EPS)
+    return x32 * (r * scale), r
+
+
+def _normalized_bwd(y, r, dy, scale):
+    """Cotangent of ``x`` for ``y = x r scale``, ``r = rsqrt(|x|^2 + eps)``."""
+    return r * (scale * dy - y * (jnp.sum(y * dy, axis=1, keepdims=True) / scale))
+
+
+def _key_head(q, k, dt):
+    """What the value heads of one key head share: normalised q, k (float32
+    and in ``dt``), their factors, and the scores ``kk``, ``qk`` (C, C)."""
+    C, dk = q.shape
+    qn, rq = _normalized(q, dk ** -0.5)
+    kn, rk = _normalized(k, 1.0)
+    qb, kb = qn.astype(dt), kn.astype(dt)
+    scores = _mm(jnp.concatenate([kb, qb], axis=0), kb, NT, _precision(dt))
+    return qn, kn, rq, rk, qb, kb, scores[:C], scores[C:]
+
+
+def _value_head(kn, qn, kk, qk, v, g_row, b_row, gl_row, row, col, dt):
+    """The chunk's operands for one value head but ``T``'s products:
+    everything elementwise, float32."""
+    eye = row == col
+    g_col, b_col = _column(g_row, eye), _column(b_row, eye)
+    decay = jnp.exp(jnp.where(row >= col, g_col - g_row, -jnp.inf))
+    a = jnp.where(row > col, b_col * kk * decay, 0.0)
+    p = qk * decay
+    eg = jnp.exp(g_col)
+    be = b_col * eg
+    tail = jnp.exp(jnp.broadcast_to(gl_row, (g_col.shape[0], gl_row.shape[1]))[:, :1] - g_col)
+    return dict(b_col=b_col, decay=decay, a=a, p=p, eg=eg, be=be, tail=tail,
+                bk=be * kn, bv=b_col * v.astype(_F32), qg=qn * eg, kg=kn * tail)
+
+
+def _prepare_group(chunks, refs, scr, *, C, G, dv, keep_t):
+    """The operands of every value head of the key head in the chunks
+    ``chunks``, written to the scratch. Nothing here waits for the state, so
+    the chunks are prepared side by side: one basic block, their triangular
+    systems inverted as one batch."""
+    q_ref, k_ref, v_ref, g_ref, b_ref, gl_ref = refs
+    dt = v_ref.dtype
+    pr = _precision(dt)
+    row, col = _masks(C)
+    heads = []
+    for c in chunks:
+        rows = pl.ds(pl.multiple_of(c * C, C), C)
+        one = pl.ds(c, 1)
+        qn, kn, _, _, _, _, kk, qk = _key_head(q_ref[rows, :], k_ref[rows, :], dt)
+        heads += [(h, rows, _value_head(kn, qn, kk, qk, v_ref[rows, h * dv:(h + 1) * dv],
+                                        g_ref[h, one, :], b_ref[h, one, :], gl_ref[h, one, :],
+                                        row, col, dt)) for h in range(G)]
+    inverses = _inverse(jnp.stack([x["a"] for _, _, x in heads])).astype(dt)
+    for (h, rows, x), t in zip(heads, inverses):
+        scr["w"][h, rows, :] = _mm(t, x["bk"].astype(dt), NN, pr).astype(dt)
+        scr["u"][h, rows, :] = _mm(t, x["bv"].astype(dt), NN, pr).astype(dt)
+        scr["qg"][h, rows, :] = x["qg"].astype(dt)
+        scr["kg"][h, rows, :] = x["kg"].astype(dt)
+        scr["p"][h, rows, :] = x["p"].astype(dt)
+        if keep_t:
+            scr["t"][h, rows, :] = t
+
+
+def _recur(state, scr, h, rows, gam_row, dt):
+    """One chunk of the recurrence on prepared operands: (new state, v', o),
+    all float32."""
+    pr = _precision(dt)
+    s = state.astype(dt)
+    v_new = scr["u"][h, rows, :].astype(_F32) - _mm(scr["w"][h, rows, :], s, NN, pr)
+    v_lo = v_new.astype(dt)
+    o = _mm(scr["qg"][h, rows, :], s, NN, pr) + _mm(scr["p"][h, rows, :], v_lo, NN, pr)
+    state = state * gam_row + _mm(scr["kg"][h, rows, :], v_lo, TN, pr)
     return state, v_new, o
 
 
-def _fwd_kernel(w_ref, u_ref, qg_ref, kg_ref, p_ref, gam_ref, o_ref, s0_ref,
-                s_scr, *, chunks, C):
-    @pl.when(pl.program_id(1) == 0)
+def _prepare(chunks, refs, scr, **kw):
+    """:func:`_prepare_group` for all chunks of the block, ``UNROLL`` of them
+    a group."""
+    group = UNROLL if chunks % UNROLL == 0 else 1
+
+    def step(i, carry):
+        _prepare_group([i * group + j for j in range(group)], refs, scr, **kw)
+        return carry
+
+    jax.lax.fori_loop(0, chunks // group, step, 0)
+
+
+_OPERANDS = ("w", "u", "qg", "kg", "p")
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, gl_ref, o_ref, s0_ref, s_scr, *operands,
+                chunks, C, G, dv):
+    @pl.when(pl.program_id(2) == 0)
     def _():
         s_scr[...] = jnp.zeros_like(s_scr)
 
     s0_ref[...] = s_scr[...]
+    dt = v_ref.dtype
+    scr = dict(zip(_OPERANDS, operands))
+    refs = (q_ref, k_ref, v_ref, g_ref, b_ref, gl_ref)
 
-    def body(c, carry):
+    _prepare(chunks, refs, scr, C=C, G=G, dv=dv, keep_t=False)
+
+    def walk(c, carry):
         rows = pl.ds(pl.multiple_of(c * C, C), C)
-        state, _, o = _chunk_forward(
-            s_scr[...], w_ref[rows, :], u_ref[rows, :], qg_ref[rows, :],
-            kg_ref[rows, :], p_ref[rows, :], gam_ref[pl.ds(c, 1), :])
-        s_scr[...] = state
-        o_ref[rows, :] = o.astype(o_ref.dtype)
+        for h in range(G):
+            state, _, o = _recur(s_scr[h], scr, h, rows, jnp.exp(gl_ref[h, pl.ds(c, 1), :]), dt)
+            s_scr[h] = state
+            o_ref[rows, h * dv:(h + 1) * dv] = o.astype(o_ref.dtype)
         return carry
 
-    jax.lax.fori_loop(0, chunks, body, 0)
+    jax.lax.fori_loop(0, chunks, walk, 0)
 
 
-def _bwd_kernel(w_ref, u_ref, qg_ref, kg_ref, p_ref, gam_ref, s0_ref, do_ref,
-                dw_ref, du_ref, dqg_ref, dkg_ref, dp_ref, dgam_ref,
-                ds_scr, states_scr, vnew_scr, *, chunks, C):
-    @pl.when(pl.program_id(1) == 0)
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, gl_ref, s0_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, dgl_ref,
+                ds_scr, states_scr, vnew_scr, du_scr, dkg_scr, *operands, chunks, C, G, dv):
+    @pl.when(pl.program_id(2) == 0)
     def _():
         ds_scr[...] = jnp.zeros_like(ds_scr)
 
-    def rebuild(c, state):
+    dt = v_ref.dtype
+    pr = _precision(dt)
+    dk_ = q_ref.shape[-1]
+    scr = dict(zip(_OPERANDS + ("t",), operands))
+    refs = (q_ref, k_ref, v_ref, g_ref, b_ref, gl_ref)
+    lo = lambda z: z.astype(dt)  # noqa: E731
+
+    _prepare(chunks, refs, scr, C=C, G=G, dv=dv, keep_t=True)
+
+    def rebuild(c, states):                # the block's states and v', in order
         rows = pl.ds(pl.multiple_of(c * C, C), C)
-        states_scr[c] = state
-        state, v_new, _ = _chunk_forward(
-            state, w_ref[rows, :], u_ref[rows, :], qg_ref[rows, :],
-            kg_ref[rows, :], p_ref[rows, :], gam_ref[pl.ds(c, 1), :])
-        vnew_scr[rows, :] = v_new
-        return state
+        out = []
+        for h in range(G):
+            states_scr[c, h] = states[h]
+            state, v_new, _ = _recur(states[h], scr, h, rows,
+                                     jnp.exp(gl_ref[h, pl.ds(c, 1), :]), dt)
+            vnew_scr[h, rows, :] = v_new.astype(dt)
+            out.append(state)
+        return tuple(out)
 
-    jax.lax.fori_loop(0, chunks, rebuild, s0_ref[...])
+    jax.lax.fori_loop(0, chunks, rebuild, tuple(s0_ref[h] for h in range(G)))
 
-    def back(i, carry):
+    def carry_back(i, carry):              # dS through the chunks, last to first
         c = chunks - 1 - i
         rows = pl.ds(pl.multiple_of(c * C, C), C)
-        w, qg, kg, p = w_ref[rows, :], qg_ref[rows, :], kg_ref[rows, :], p_ref[rows, :]
-        do = do_ref[rows, :]
-        dt = w.dtype
-        gam_row = gam_ref[pl.ds(c, 1), :]
-        state, ds = states_scr[c], ds_scr[...]
-        s, ds_lo = state.astype(dt), ds.astype(dt)
-        v_new = vnew_scr[rows, :]
-        dv_new = _mm(p, do, TN) + _mm(kg, ds_lo, NN)           # (C, dv)
-        dv_lo = dv_new.astype(dt)
-        dp_ref[rows, :] = _mm(do, v_new.astype(dt), NT).astype(dp_ref.dtype)
-        dqg_ref[rows, :] = _mm(do, s, NT).astype(dqg_ref.dtype)
-        dkg_ref[rows, :] = _mm(v_new.astype(dt), ds_lo, NT).astype(dkg_ref.dtype)
-        du_ref[rows, :] = dv_new.astype(du_ref.dtype)
-        dw_ref[rows, :] = (-_mm(dv_lo, s, NT)).astype(dw_ref.dtype)
-        dgam_ref[pl.ds(c, 1), :] = jnp.sum(state * ds, axis=0, keepdims=True)
-        ds_scr[...] = ds * gam_row + _mm(qg, do, TN) - _mm(w, dv_lo, TN)
+        one = pl.ds(c, 1)
+        for h in range(G):
+            state, ds = states_scr[c, h], ds_scr[h]
+            ds_lo = lo(ds)
+            do = do_ref[rows, h * dv:(h + 1) * dv]
+            gam = jnp.exp(gl_ref[h, one, :])
+            du = lo(_mm(scr["p"][h, rows, :], do, TN, pr)
+                    + _mm(scr["kg"][h, rows, :], ds_lo, NN, pr))               # = dv'
+            du_scr[h, rows, :] = du
+            dkg_scr[h, rows, :] = _mm(vnew_scr[h, rows, :], ds_lo, NT, pr)
+            dgl_ref[h, one, :] = jnp.sum(state * ds, axis=0, keepdims=True) * gam
+            ds_scr[h] = (ds * gam + _mm(scr["qg"][h, rows, :], do, TN, pr)
+                         - _mm(scr["w"][h, rows, :], du, TN, pr))
         return carry
 
-    jax.lax.fori_loop(0, chunks, back, 0)
+    jax.lax.fori_loop(0, chunks, carry_back, 0)
+
+    def inputs(c):                         # the operands' cotangents -> q, k, v, G, beta's
+        rows = pl.ds(pl.multiple_of(c * C, C), C)
+        one = pl.ds(c, 1)
+        row, col = _masks(C)
+        eye = row == col
+        lanes = lambda z: jnp.sum(z, axis=1, keepdims=True)  # noqa: E731
+        qn, kn, rq, rk, qb, kb, kk, qk = _key_head(q_ref[rows, :], k_ref[rows, :], dt)
+        dqn = jnp.zeros((C, dk_), _F32)
+        dkn = jnp.zeros((C, dk_), _F32)
+        dkk = jnp.zeros((C, C), _F32)
+        dqk = jnp.zeros((C, C), _F32)
+        for h in range(G):
+            v = v_ref[rows, h * dv:(h + 1) * dv]
+            x = _value_head(kn, qn, kk, qk, v, g_ref[h, one, :], b_ref[h, one, :],
+                            gl_ref[h, one, :], row, col, dt)
+            t, v_new, du = scr["t"][h, rows, :], vnew_scr[h, rows, :], du_scr[h, rows, :]
+            s = lo(states_scr[c, h])
+            do = do_ref[rows, h * dv:(h + 1) * dv]
+            dkg = dkg_scr[h, rows, :]
+            # the recurrence's operands
+            dp = jnp.where(row >= col, _mm(do, v_new, NT, pr), 0.0)
+            dqg = _mm(do, s, NT, pr)
+            dw = lo(-_mm(du, s, NT, pr))
+            # w = T bk, u = T bv, T = (I + a)^-1: da = -T^T dT T^T with dT = dw bk^T + du bv^T
+            dbk = _mm(t, dw, TN, pr)
+            dbv = _mm(t, du, TN, pr)
+            da = jnp.where(row > col, -(_mm(lo(dbk), scr["w"][h, rows, :], NT, pr)
+                                        + _mm(lo(dbv), scr["u"][h, rows, :], NT, pr)), 0.0)
+            # a = beta kk decay, p = qk decay, decay = e^{G_i - G_j}
+            e = da * x["a"] + dp * x["p"]
+            dkk = dkk + da * x["b_col"] * x["decay"]
+            dqk = dqk + dp * x["decay"]
+            tails = lanes(dkg * x["kg"])
+            db_col = lanes(da * kk * x["decay"]) + lanes(dbk * (kn * x["eg"]) + dbv * v.astype(_F32))
+            dg_col = lanes(e) + lanes(dbk * x["bk"] + dqg * x["qg"]) - tails
+            dg_ref[h, one, :] = _row(dg_col, eye) - jnp.sum(e, axis=0, keepdims=True)
+            db_ref[h, one, :] = _row(db_col, eye)
+            # G_C's: the state's decay came lane by lane (above); the keys' tails
+            # add their whole sum, a 1 / dv of it in every lane (the caller sums the lanes)
+            dgl_ref[h, one, :] += jnp.sum(jnp.broadcast_to(tails, (C, dv)), axis=0,
+                                          keepdims=True) * (1.0 / dv)
+            dkn = dkn + dbk * x["be"] + dkg * x["tail"]
+            dqn = dqn + dqg * x["eg"]
+            dv_ref[rows, h * dv:(h + 1) * dv] = (dbv * x["b_col"]).astype(dv_ref.dtype)
+        # kk = kn kn^T, qk = qn kn^T: once a key head, summed over its value heads
+        dkk_lo, dqk_lo = lo(dkk), lo(dqk)
+        dqn = dqn + _mm(dqk_lo, kb, NN, pr)
+        dkn = dkn + _mm(dqk_lo, qb, TN, pr) + _mm(dkk_lo, kb, NN, pr) + _mm(dkk_lo, kb, TN, pr)
+        dq_ref[rows, :] = _normalized_bwd(qn, rq, dqn, dk_ ** -0.5).astype(dq_ref.dtype)
+        dk_ref[rows, :] = _normalized_bwd(kn, rk, dkn, 1.0).astype(dk_ref.dtype)
+
+    jax.lax.fori_loop(0, chunks, lambda c, carry: (inputs(c), carry)[1], 0)
 
 
-def chunks_per_step(n_chunks, want=8):
-    """Chunks one grid step handles: the largest divisor of ``n_chunks`` up
-    to ``want`` whose decay block keeps to Mosaic's tiling (8 chunks, or all
-    of them)."""
-    if n_chunks <= want:
-        return n_chunks
-    return want if n_chunks % want == 0 else 0
+def _operand_scratch(G, rows, C, dk, dv, dtype, keep_t):
+    """w, u, qg, kg, p (and T) of a block's chunks, in the operands' dtype."""
+    widths = (dk, dv, dk, dk, C) + ((C,) if keep_t else ())
+    return [pltpu.VMEM((G, rows, width), dtype) for width in widths]
 
 
-def _specs(chunks, C, dk, dv, order):
-    block = lambda width: pl.BlockSpec((None, chunks * C, width),  # noqa: E731
-                                       lambda r, t: (r, order(t), 0))
-    gam = pl.BlockSpec((None, chunks, LANES), lambda r, t: (r, order(t), 0))
-    s0 = pl.BlockSpec((None, None, dk, dv), lambda r, t: (r, order(t), 0, 0))
-    return block, gam, s0
+def _specs(chunks, C, G, dk, dv, order):
+    """Block specs over the grid (batch, key head, block of chunks)."""
+    qk = pl.BlockSpec((None, chunks * C, dk), lambda b, j, t: (b, order(t), j))
+    v = pl.BlockSpec((None, chunks * C, G * dv), lambda b, j, t: (b, order(t), j))
+    per_chunk = lambda width: pl.BlockSpec(  # noqa: E731
+        (None, G, chunks, width), lambda b, j, t: (b, j, order(t), 0))
+    s0 = pl.BlockSpec((None, G, None, dk, dv), lambda b, j, t: (b, j, order(t), 0, 0))
+    return qk, v, per_chunk, s0
 
 
-def gdn_fwd(w, u, qg, kg, p, gam, *, interpret=False):
-    """o (rows, T, dv) and the state every block of ``chunks`` chunks started
-    from (rows, T / (chunks C), dk, dv) float32."""
-    R, T, dk = w.shape
-    dv, C = u.shape[-1], p.shape[-1]
-    chunks = chunks_per_step(T // C)
-    nt = T // (chunks * C)
-    block, gam_spec, s0_spec = _specs(chunks, C, dk, dv, lambda t: t)
+def _plan(q, v, g, heads):
+    """(b, hv, C, dk, dv, value heads a key head, chunks a grid step — all of
+    a short row's, the caller pads a longer one to whole steps —, steps)."""
+    hv, n, C = g.shape[1:]
+    chunks = min(n, CHUNKS)
+    return (q.shape[0], hv, C, q.shape[-1] // heads, v.shape[-1] // hv, hv // heads,
+            chunks, n // chunks)
+
+
+def gdn_fwd(q, k, v, g, beta, gl, *, heads, interpret=False):
+    """``o`` (b, T, hv dv) and the states every block of chunks started from
+    (b, hv, blocks, dk, dv) float32. ``heads`` is the number of key heads."""
+    b, hv, C, dk, dv, G, chunks, nt = _plan(q, v, g, heads)
+    qk, vs, per_chunk, s0 = _specs(chunks, C, G, dk, dv, lambda t: t)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, chunks=chunks, C=C),
+        functools.partial(_fwd_kernel, chunks=chunks, C=C, G=G, dv=dv),
         name="gdn_fwd",
-        grid=(R, nt),
-        in_specs=[block(dk), block(dv), block(dk), block(dk), block(C), gam_spec],
-        out_specs=[block(dv), s0_spec],
-        out_shape=[jax.ShapeDtypeStruct((R, T, dv), u.dtype),
-                   jax.ShapeDtypeStruct((R, nt, dk, dv), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        grid=(b, heads, nt),
+        in_specs=[qk, qk, vs, per_chunk(C), per_chunk(C), per_chunk(dv)],
+        out_specs=[vs, s0],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct((b, hv, nt, dk, dv), _F32)],
+        scratch_shapes=[pltpu.VMEM((G, dk, dv), _F32)] + _operand_scratch(G, chunks * C, C, dk, dv,
+                                                                        v.dtype, keep_t=False),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(w, u, qg, kg, p, gam)
+    )(q, k, v, g, beta, gl)
 
 
-def gdn_bwd(w, u, qg, kg, p, gam, s0, do, *, interpret=False):
-    """Cotangents of (w, u, qg, kg, p, gam) in their shapes and dtypes; the
-    decay's as lane-partial sums."""
-    R, T, dk = w.shape
-    dv, C = u.shape[-1], p.shape[-1]
-    chunks = chunks_per_step(T // C)
-    nt = T // (chunks * C)
-    block, gam_spec, s0_spec = _specs(chunks, C, dk, dv, lambda t: nt - 1 - t)
+def gdn_bwd(q, k, v, g, beta, gl, s0, do, *, heads, interpret=False):
+    """Cotangents of (q, k, v, g, beta, gl) in their shapes and dtypes; the
+    whole-chunk decay's as lane-partial sums."""
+    b, hv, C, dk, dv, G, chunks, nt = _plan(q, v, g, heads)
+    qk, vs, per_chunk, s0_spec = _specs(chunks, C, G, dk, dv, lambda t: nt - 1 - t)
     like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, chunks=chunks, C=C),
+        functools.partial(_bwd_kernel, chunks=chunks, C=C, G=G, dv=dv),
         name="gdn_bwd",
-        grid=(R, nt),
-        in_specs=[block(dk), block(dv), block(dk), block(dk), block(C), gam_spec,
-                  s0_spec, block(dv)],
-        out_specs=[block(dk), block(dv), block(dk), block(dk), block(C), gam_spec],
-        out_shape=[like(w), like(u), like(qg), like(kg), like(p), like(gam)],
-        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32),
-                        pltpu.VMEM((chunks, dk, dv), jnp.float32),
-                        pltpu.VMEM((chunks * C, dv), jnp.float32)],
+        grid=(b, heads, nt),
+        in_specs=[qk, qk, vs, per_chunk(C), per_chunk(C), per_chunk(dv), s0_spec, vs],
+        out_specs=[qk, qk, vs, per_chunk(C), per_chunk(C), per_chunk(dv)],
+        out_shape=[like(q), like(k), like(v), like(g), like(beta), like(gl)],
+        scratch_shapes=[pltpu.VMEM((G, dk, dv), _F32),                 # dS
+                        pltpu.VMEM((chunks, G, dk, dv), _F32),         # every chunk's state
+                        pltpu.VMEM((G, chunks * C, dv), v.dtype),      # v'
+                        pltpu.VMEM((G, chunks * C, dv), v.dtype),      # dv' = du
+                        pltpu.VMEM((G, chunks * C, dk), _F32)]         # dkg
+        + _operand_scratch(G, chunks * C, C, dk, dv, v.dtype, keep_t=True),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(w, u, qg, kg, p, gam, s0, do)
+    )(q, k, v, g, beta, gl, s0, do)

@@ -1,6 +1,7 @@
 """The chunked gated delta rule (XLA form and the ``gdn_fwd`` / ``gdn_bwd``
-kernels) against the token-by-token recurrence of the plain reference,
-forward and gradients; and the small ops around it."""
+kernels, which build every chunk's operands in VMEM) against the
+token-by-token recurrence of the plain reference and against each other,
+value and all five gradients; and the small ops around it."""
 import os
 import sys
 
@@ -43,14 +44,19 @@ def inputs(t, decay, b=2, hk=1, hv=2, d=128, seed=0, drift=0.0, sign=1.0):
             jax.random.normal(ks[5], (b, t, hv, d)))
 
 
-def check(impl, t, decay, tol=2e-5, **keys):
+def value_and_grads(f, x, do):
+    return jax.value_and_grad(lambda *a: jnp.sum(f(*a).astype(jnp.float32) * do),
+                              argnums=range(5))(*x)
+
+
+def check(impl, t, decay, tol=2e-5, chunk=64, **keys):
+    """Value and the gradients of q, k, v, g, beta against the recurrence."""
     *x, do = inputs(t, decay, **keys)
+    rule = lambda *a: gated_delta_rule(*a, chunk=chunk, impl=impl)  # noqa: E731
     with jax.default_matmul_precision("highest"):
-        want, want_g = jax.value_and_grad(
-            lambda *a: jnp.sum(recurrence(*a) * do), argnums=range(5))(*x)
-        got, got_g = jax.value_and_grad(
-            lambda *a: jnp.sum(gated_delta_rule(*a, impl=impl) * do), argnums=range(5))(*x)
-        o, o_ref = gated_delta_rule(*x, impl=impl), recurrence(*x)
+        want, want_g = value_and_grads(recurrence, x, do)
+        got, got_g = value_and_grads(rule, x, do)
+        o, o_ref = rule(*x), recurrence(*x)
     assert o.shape == o_ref.shape
     np.testing.assert_allclose(o, o_ref, atol=tol * float(jnp.max(jnp.abs(o_ref))))
     np.testing.assert_allclose(got, want, rtol=1e-4)
@@ -68,36 +74,60 @@ def test_chunked_xla_form_matches_the_recurrence(t, decay):
     check("xla", t, decay)
 
 
-@pytest.mark.parametrize("t,decay", [(150, "mixed")])
-def test_kernels_match_the_recurrence(t, decay):
-    check("pallas", t, decay)
+@pytest.mark.parametrize("t,decay,chunk,heads", [
+    (150, "mixed", 64, (1, 2)),       # three chunks, the last one padded; two value heads a key head
+    (96, "near_one", 64, (1, 2)),
+    (96, "near_zero", 64, (1, 2)),
+    (150, "mixed", 16, (1, 2)),       # ten chunks pad to sixteen: two grid steps, state and dS carried over
+    (300, "near_one", 16, (2, 4)),    # three grid steps, two key heads (a grid row each)
+    (130, "mixed", 32, (2, 2)),       # a value head a key head; the substitution alone (no merge)
+])
+def test_kernels_match_the_recurrence(t, decay, chunk, heads):
+    check("pallas", t, decay, chunk=chunk, hk=heads[0], hv=heads[1])
 
 
-@pytest.mark.parametrize("drift,sign", [(0.5, 1.0), (0.9, 1.0), (0.9, -1.0)])
-def test_chunked_form_holds_on_drifted_keys(drift, sign):
+@pytest.mark.parametrize("t,decay,chunk", [(150, "mixed", 64), (96, "near_one", 64),
+                                           (96, "near_zero", 64), (200, "mixed", 16)])
+def test_kernels_agree_with_the_xla_form(t, decay, chunk):
+    """Value and all five gradients, float32: the kernels build the operands
+    the XLA form builds."""
+    *x, do = inputs(t, decay, hk=2, hv=4)
+    with jax.default_matmul_precision("highest"):
+        (a, ga), (b, gb) = (value_and_grads(
+            lambda *y: gated_delta_rule(*y, chunk=chunk, impl=impl), x, do) for impl in ("pallas", "xla"))
+    np.testing.assert_allclose(a, b, rtol=1e-5)
+    for m, n in zip(ga, gb):
+        np.testing.assert_allclose(m, n, atol=1e-5 * float(jnp.max(jnp.abs(n))) + 1e-7)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("drift,sign", [(0.5, 1.0), (0.5, -1.0), (0.9, 1.0), (0.9, -1.0)])
+def test_chunked_form_holds_on_drifted_keys(drift, sign, impl):
     """Keys with a cosine of 0.5 to 0.99 between any two (of either sign),
     strong writes, slow decay: ``I + A`` has entries near +-1 everywhere
     below the diagonal. (The inverse as a product of powers of ``A`` was 1e7
     to 1e30 off here, and a training run at lr 3e-4 reached such keys within
-    26 steps.)"""
-    check("xla", 192, "near_one", tol=5e-5, drift=drift, sign=sign)
+    26 steps.) The kernels invert in VMEM by the same substitution and
+    merges."""
+    check(impl, 192, "near_one", tol=5e-5, drift=drift, sign=sign)
 
 
 @pytest.mark.slow
 @pytest.mark.parametrize("t,decay", [(640, "mixed"), (576, "near_one"), (128, "near_zero")])
 def test_kernels_match_the_recurrence_over_several_blocks(t, decay):
-    """640 tokens pad to 16 chunks: two grid steps of eight a row, the state
-    and its cotangent carried between them."""
+    """640 tokens pad to 16 chunks of 64: two grid steps of eight a row, the
+    state and its cotangent carried between them."""
     check("pallas", t, decay)
 
 
-def test_kernel_and_xla_forms_agree_in_bfloat16():
-    *x, do = inputs(192, "mixed")
-    q, k, v = (a.astype(jnp.bfloat16) for a in x[:3])
-    f = lambda impl: jax.value_and_grad(  # noqa: E731
-        lambda q, k, v: jnp.sum(gated_delta_rule(q, k, v, x[3], x[4], impl=impl)
-                                .astype(jnp.float32) * do), argnums=(0, 1, 2))(q, k, v)
-    (a, ga), (b, gb) = f("pallas"), f("xla")
+@pytest.mark.parametrize("decay,drift", [("mixed", 0.0), ("near_one", 0.7)])
+def test_kernel_and_xla_forms_agree_in_bfloat16(decay, drift):
+    """bf16 operands on both paths (the scores, ``T``'s products and the
+    recurrence round at the same places), all five gradients."""
+    *x, do = inputs(192, decay, drift=drift)
+    x = [a.astype(jnp.bfloat16) for a in x[:3]] + x[3:]
+    (a, ga), (b, gb) = (value_and_grads(lambda *y: gated_delta_rule(*y, impl=impl), x, do)
+                        for impl in ("pallas", "xla"))
     np.testing.assert_allclose(a, b, rtol=2e-2)
     for m, n in zip(ga, gb):
         gap = jnp.abs(m.astype(jnp.float32) - n.astype(jnp.float32))

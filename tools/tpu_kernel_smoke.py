@@ -307,8 +307,10 @@ def _flash_wide_head_family():
 
 
 def _delta_rule_family():
-    """The recurrence over chunks as ``gdn_fwd`` / ``gdn_bwd`` against the
-    ``lax.scan`` of the same chunk operands: two key heads serve four value
+    """``gdn_fwd`` / ``gdn_bwd`` (chunk operands and the 64 x 64 inverse built
+    in VMEM, then the recurrence over chunks) against the XLA form (operands
+    prepared by XLA, a ``lax.scan`` over the chunks), value and all five
+    gradients: heads of 128 as the cell's, two key heads serve four value
     heads, 16 chunks a row (two grid steps), decays from 0.2 to 0.999."""
     def build():
         from apex_tpu.ops.gated_delta_rule import gated_delta_rule
@@ -324,7 +326,8 @@ def _delta_rule_family():
 
 
 def _delta_rule_drifted_family():
-    """The whole chunked form (chunk operands, ``gdn_fwd`` / ``gdn_bwd``) in
+    """The kernels (``gdn_fwd`` / ``gdn_bwd``: operands, inverse and
+    recurrence in VMEM) in
     bfloat16 against the token-by-token recurrence in float32, on keys as
     training leaves them: 0.7 of every key one common direction, strong
     writes, decays of 0.999 and slower — the chunk's triangular system far
