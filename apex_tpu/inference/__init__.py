@@ -26,8 +26,9 @@ valid prefix — greedy output token-identical to ``draft=None``, with
 
 The fused decode-attention op lives in
 :func:`apex_tpu.ops.decode_attention` (Pallas kernel + XLA fallback);
-the cached model math in :class:`apex_tpu.models.GPTModel`'s
-``prefill_block``/``decode_qkv``/``decode_block`` branch. Serving
+the cached model math behind the layer-math seam both engines' step
+bodies are written against (:class:`apex_tpu.serving.engine.ModelMath`
+over :class:`apex_tpu.models.GPTModel`'s projections). Serving
 throughput is measured by ``python bench.py --decode`` (see
 ``docs/api/inference.md`` for the cache-layout and HBM-bound analysis).
 

@@ -41,14 +41,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from apex_tpu.inference.sampling import sample_logits
-from apex_tpu.models.gpt import GPTModel, shard_params_for_tp
+from apex_tpu.models.gpt import GPTModel
 from apex_tpu.monitor import spans as monitor_spans
 from apex_tpu.monitor import trace as monitor_trace
-from apex_tpu.ops import (decode_attention, fused_layer_norm, fused_verify,
-                          fused_verify_tree)
-from apex_tpu.ops.pallas.attention import NEG_INF
+from apex_tpu.ops import decode_attention
+from apex_tpu.ops.attention import flash_attention
 from apex_tpu.parallel import mesh as mesh_lib
 from apex_tpu.serving import tp as tp_serving
+from apex_tpu.serving.engine import (ModelMath, _pos_rows, cached_attention,
+                                     run_layers, winning_path_levels)
 
 
 @dataclass
@@ -128,6 +129,10 @@ class DecodeEngine:
         self.plan = plan
         self.tp = int(plan.tp) if plan is not None else 1
         self._mesh = None
+        # the layer-math seam the four bodies below are written against
+        self._math = ModelMath(model, sample_logits,
+                               temperature=self.temperature,
+                               top_k=self.top_k)
         if self.tp > 1:
             if self.temperature > 0:
                 raise ValueError(
@@ -143,26 +148,29 @@ class DecodeEngine:
                 has_rel_bias=getattr(model, "decode_rel_bias",
                                      None) is not None)
             self._mesh = tp_serving.tp_mesh(self.tp)
+            # replicated activations, plain dot + psum (overlap=False):
+            # batch and prompt lengths are not tp-divisible in general
+            # (the ring contract is witnessed on ServingEngine's programs)
+            self._math = tp_serving.ShardedMath(c, overlap=False)
             P = jax.sharding.PartitionSpec
             kv, rep = P(None, None, "tp"), P()
             cache_spec = {"k": kv, "v": kv}
-            self._cache_spec = cache_spec
-            # replicated-activation shard bodies (overlap=False helpers:
-            # batch and prompt lengths are not tp-divisible in general,
-            # so the boundary collectives are plain psums here; the
-            # ring-overlap contract is witnessed on the ServingEngine
-            # programs). Logits reassemble the vocab row via output
-            # sharding — never an all_gather inside the program.
-            self._tp_prefill = mesh_lib.shard_map(
-                self._prefill_body_tp, mesh=self._mesh,
-                in_specs=(P("tp"), rep, rep),
+            # the SAME bodies, shard_mapped in place: params arrive as
+            # per-rank shards, the cache's kv-head axis is this shard's
+            # contiguous slice, the greedy argmax / verify tails
+            # psum-compose, logits reassemble the vocab row via output
+            # sharding. The tree round stays unmapped: generate()
+            # refuses tree drafts under tp
+            smap = functools.partial(mesh_lib.shard_map, mesh=self._mesh)
+            self._prefill_body = smap(
+                self._prefill_body, in_specs=(P("tp"), rep, rep),
                 out_specs=(cache_spec, rep, P(None, "tp")))
-            self._tp_decode = mesh_lib.shard_map(
-                self._decode_step_body_tp, mesh=self._mesh,
+            self._decode_step_body = smap(
+                self._decode_step_body,
                 in_specs=(P("tp"), cache_spec, rep, rep, rep),
                 out_specs=(cache_spec, rep, P(None, "tp")))
-            self._tp_spec = mesh_lib.shard_map(
-                self._spec_verify_body_tp, mesh=self._mesh,
+            self._spec_verify_body = smap(
+                self._spec_verify_body,
                 in_specs=(P("tp"), cache_spec, rep, rep, rep, rep),
                 out_specs=(cache_spec, rep, rep))
         # one jitted executable each; decode additionally donates the cache
@@ -202,11 +210,23 @@ class DecodeEngine:
         return (2 * c.num_layers * batch * c.local_kv_heads * self.max_s
                 * c.head_dim * itemsize)
 
-    # --- prefill -------------------------------------------------------------
+    def _prepare_params(self, params):
+        """tp == 1: passthrough; under tp the per-rank shards, committed
+        to the mesh (:func:`apex_tpu.serving.tp.prepare_params`)."""
+        return tp_serving.prepare_params(params, self.tp, self.config,
+                                         self._mesh)
 
-    def _sample(self, logits, key):
-        return sample_logits(logits, key, temperature=self.temperature,
-                             top_k=self.top_k)
+    def _write_cache(self, cache, i, pos, k, v):
+        """``k``/``v`` (b, h_kv, rows, d) into layer ``i`` at rows
+        [pos, pos + rows) of the DONATED stacked buffers (layer index
+        static, position traced — one executable for all pos)."""
+        zero = jnp.int32(0)
+        at = (jnp.int32(i), zero, zero, pos, zero)
+        return {n: jax.lax.dynamic_update_slice(
+            cache[n], x[None].astype(cache[n].dtype), at)
+            for n, x in (("k", k), ("v", v))}
+
+    # --- prefill -------------------------------------------------------------
 
     def _prefill(self, params, tokens, key):
         """Prompt (b, s) → (cache populated at [0, s), next token (b,),
@@ -214,32 +234,29 @@ class DecodeEngine:
         structure (flash attention over the full prompt) with each layer's
         k/v exposed — cache contents ARE the training forward's k/v."""
         with monitor_spans.span("decode_prefill"):
-            if self.tp > 1:
-                return self._tp_prefill(params, tokens, key)
             return self._prefill_body(params, tokens, key)
 
     def _prefill_body(self, params, tokens, key):
-        model, c = self.model, self.config
-        b, s = tokens.shape
-        x = model.embedding(params["embedding"], tokens)
+        m, c = self._math, self.config
+        params = m.shard(params)
+        s = tokens.shape[1]
+        x = m.embed(params, tokens)
         x = x + params["pos_embedding"][:s]
         ks, vs = [], []
-        for i in range(c.num_layers):
-            layer = jax.tree.map(lambda a, i=i: a[i], params["layers"])
-            x, (k, v) = model.prefill_block(layer, x)
-            ks.append(k)
-            vs.append(v)
-        x = fused_layer_norm(x, params["lnf_w"], params["lnf_b"])
-        logits = model.unembed(params, x[:, -1:])[:, 0]
-        cache = self.init_cache(b)
+
+        def attend(i, q, k, v):
+            ks.append(k.transpose(0, 2, 1, 3))  # the cache layout
+            vs.append(v.transpose(0, 2, 1, 3))
+            return flash_attention(q, k, v, causal=True, layout="bshd")
+        x = run_layers(m, params, x, attend)
+        logits = m.unembed(params, x[:, -1:])[:, 0]
         # static-length write: s is a trace-time constant of this prompt
-        cache = {
-            "k": cache["k"].at[:, :, :, :s].set(
-                jnp.stack(ks).astype(self.cache_dtype)),
-            "v": cache["v"].at[:, :, :, :s].set(
-                jnp.stack(vs).astype(self.cache_dtype)),
-        }
-        return cache, self._sample(logits, key), logits
+        cache = {}
+        for n, rows in (("k", ks), ("v", vs)):
+            rows = jnp.stack(rows).astype(self.cache_dtype)
+            cache[n] = jnp.zeros((*rows.shape[:3], self.max_s, c.head_dim),
+                                 self.cache_dtype).at[:, :, :, :s].set(rows)
+        return cache, m.sample(logits, key), logits
 
     # --- decode --------------------------------------------------------------
 
@@ -251,24 +268,19 @@ class DecodeEngine:
         tokens, logits). Avals are independent of ``pos``: compiled
         exactly once per (batch, cache shape)."""
         # trace-time step-anatomy span: every HLO of the decode step
-        # carries the decode_step scope into device traces (the join key
-        # `monitor report --anatomy` correlates on), monitor on or off;
-        # entered once per trace, never touching the zero-recompile avals
+        # carries the decode_step scope into device traces, monitor on or
+        # off; entered once per trace, never touching the stable avals
         with monitor_spans.span("decode_step"):
-            if self.tp > 1:
-                return self._tp_decode(params, cache, tokens, pos, key)
             return self._decode_step_body(params, cache, tokens, pos, key)
 
     def _decode_step_body(self, params, cache, tokens, pos, key):
-        model, c = self.model, self.config
-        b = tokens.shape[0]
+        m, c = self._math, self.config
+        params = m.shard(params)
         pos = jnp.asarray(pos, jnp.int32)
-        x = model.embedding(params["embedding"], tokens[:, None])
+        x = m.embed(params, tokens[:, None])
         x = x + jax.lax.dynamic_slice(
             params["pos_embedding"], (pos, 0), (1, c.hidden_size))[None]
-        ck, cv = cache["k"], cache["v"]
-        lengths = jnp.full((b,), pos + 1, jnp.int32)
-        zero = jnp.int32(0)
+        lengths = jnp.full((tokens.shape[0],), pos + 1, jnp.int32)
         # T5-style relative bias at decode, for free: a model exposing
         # ``decode_rel_bias(params) -> BucketedBias`` (causal table) gets
         # it threaded into every block's fused decode attention — the
@@ -276,224 +288,26 @@ class DecodeEngine:
         # length, so the cache layout, avals, and the zero-recompile
         # contract are untouched. Models without the hook (stock GPT:
         # learned positions) pass None.
-        rel_hook = getattr(model, "decode_rel_bias", None)
+        rel_hook = getattr(self.model, "decode_rel_bias", None)
         rel_bias = None if rel_hook is None else rel_hook(params)
-        for i in range(c.num_layers):
-            layer = jax.tree.map(lambda a, i=i: a[i], params["layers"])
-            q, k_row, v_row = model.decode_qkv(layer, x)
-            # in-place row write into the DONATED stacked buffers (layer
-            # index static, position traced — one executable for all pos)
-            ck = jax.lax.dynamic_update_slice(
-                ck, k_row[None].astype(ck.dtype),
-                (jnp.int32(i), zero, zero, pos, zero))
-            cv = jax.lax.dynamic_update_slice(
-                cv, v_row[None].astype(cv.dtype),
-                (jnp.int32(i), zero, zero, pos, zero))
-            x = model.decode_block(layer, x, q, ck[i], cv[i], lengths,
-                                   rel_bias=rel_bias)
-        x = fused_layer_norm(x, params["lnf_w"], params["lnf_b"])
-        logits = model.unembed(params, x)[:, 0]
-        return {"k": ck, "v": cv}, self._sample(logits, key), logits
 
-    # --- tensor-parallel bodies (plan.tp >= 2) -------------------------------
-    #
-    # Per-shard twins run INSIDE shard_map: params arrive as
-    # shard_params_for_tp slices, the cache's kv-head axis is this
-    # shard's contiguous slice, activations stay replicated (batch and
-    # prompt lengths aren't tp-divisible in general, so projections use
-    # the plain dot+psum form), and the greedy argmax / verify tails
-    # psum-compose so every shard emits identical tokens.
-
-    def _prepare_params(self, params):
-        """tp == 1: passthrough. Under tp: split the replicated tree
-        into per-rank shards (leading ``(tp,)`` axis) committed to the
-        mesh under ``P('tp')``."""
-        if self.tp == 1:
-            return params
-        sharded = shard_params_for_tp(params, self.tp, self.config)
-        sh = jax.sharding.NamedSharding(self._mesh,
-                                        jax.sharding.PartitionSpec("tp"))
-        return jax.tree.map(lambda a: jax.device_put(a, sh), sharded)
-
-    def _prefill_body_tp(self, params, tokens, key):
-        c = self.config
-        axis, tp = tp_serving.TENSOR_AXIS, self.tp
-        h_loc, hkv_loc = c.num_heads // tp, c.kv_heads // tp
-        group, d = h_loc // hkv_loc, c.head_dim
-        params = tp_serving.take_shard(params)
-        b, s = tokens.shape
-        emb = params["embedding"]["weight"]
-        x = tp_serving.vocab_embed(emb, tokens, axis=axis)
-        x = x + params["pos_embedding"][:s]
-        scale = 1.0 / d ** 0.5
-        ii = jnp.arange(s, dtype=jnp.int32)
-        mask = ii[None, None, :, None] >= ii[None, None, None, :]
-        ks, vs = [], []
-        for i in range(c.num_layers):
-            layer = jax.tree.map(lambda a, i=i: a[i], params["layers"])
-            h_in = fused_layer_norm(x, layer["ln1_w"], layer["ln1_b"])
-            y = tp_serving.column_parallel(
-                h_in, layer["qkv"]["weight"], layer["qkv"].get("bias"),
-                axis=axis, overlap=False)
-            q = y[..., :h_loc * d].reshape(b, s, h_loc, d)
-            k = y[..., h_loc * d:(h_loc + hkv_loc) * d] \
-                .reshape(b, s, hkv_loc, d)
-            v = y[..., (h_loc + hkv_loc) * d:].reshape(b, s, hkv_loc, d)
-            kh = k.transpose(0, 2, 1, 3)  # (b, hkv_loc, s, d)
-            vh = v.transpose(0, 2, 1, 3)
-            ks.append(kh)
-            vs.append(vh)
-            qg = q.reshape(b, s, hkv_loc, group, d) \
-                .transpose(0, 2, 3, 1, 4)  # (b, hkv_loc, group, s, d)
-            sc = jnp.einsum("bhgqd,bhkd->bhgqk", qg,
-                            kh.astype(qg.dtype),
-                            preferred_element_type=jnp.float32) * scale
-            sc = jnp.where(mask[:, None], sc, NEG_INF)
-            p = jax.nn.softmax(sc, axis=-1)
-            ctx = jnp.einsum("bhgqk,bhkd->bhgqd", p.astype(vh.dtype), vh)
-            ctx = ctx.transpose(0, 3, 1, 2, 4).reshape(b, s, h_loc * d)
-            x = x + tp_serving.row_parallel(
-                ctx, layer["attn_out"]["weight"],
-                layer["attn_out"].get("bias"), axis=axis, overlap=False)
-            h2 = fused_layer_norm(x, layer["ln2_w"], layer["ln2_b"])
-            h = tp_serving.column_parallel(
-                h2, layer["mlp_up"]["weight"],
-                layer["mlp_up"].get("bias"), axis=axis, overlap=False)
-            h = jax.nn.gelu(h, approximate=True)
-            x = x + tp_serving.row_parallel(
-                h, layer["mlp_down"]["weight"],
-                layer["mlp_down"].get("bias"), axis=axis, overlap=False)
-        x = fused_layer_norm(x, params["lnf_w"], params["lnf_b"])
-        logits = jnp.dot(x[:, -1], emb.T)  # (b, V/tp)
-        shape = (c.num_layers, b, hkv_loc, self.max_s, d)
-        cache = {"k": jnp.zeros(shape, self.cache_dtype),
-                 "v": jnp.zeros(shape, self.cache_dtype)}
-        cache = {
-            "k": cache["k"].at[:, :, :, :s].set(
-                jnp.stack(ks).astype(self.cache_dtype)),
-            "v": cache["v"].at[:, :, :, :s].set(
-                jnp.stack(vs).astype(self.cache_dtype)),
-        }
-        tok = tp_serving.row_argmax_tp(logits, axis=axis)
-        return cache, tok, logits
-
-    def _decode_step_body_tp(self, params, cache, tokens, pos, key):
-        c = self.config
-        axis, tp = tp_serving.TENSOR_AXIS, self.tp
-        h_loc, hkv_loc = c.num_heads // tp, c.kv_heads // tp
-        d = c.head_dim
-        params = tp_serving.take_shard(params)
-        b = tokens.shape[0]
-        pos = jnp.asarray(pos, jnp.int32)
-        emb = params["embedding"]["weight"]
-        x = tp_serving.vocab_embed(emb, tokens[:, None], axis=axis)
-        x = x + jax.lax.dynamic_slice(
-            params["pos_embedding"], (pos, 0), (1, c.hidden_size))[None]
-        ck, cv = cache["k"], cache["v"]
-        lengths = jnp.full((b,), pos + 1, jnp.int32)
-        zero = jnp.int32(0)
-        for i in range(c.num_layers):
-            layer = jax.tree.map(lambda a, i=i: a[i], params["layers"])
-            h_in = fused_layer_norm(x, layer["ln1_w"], layer["ln1_b"])
-            y = tp_serving.column_parallel(
-                h_in[:, 0], layer["qkv"]["weight"],
-                layer["qkv"].get("bias"), axis=axis, overlap=False)
-            q = y[:, :h_loc * d].reshape(b, h_loc, d)
-            k_row = y[:, h_loc * d:(h_loc + hkv_loc) * d] \
-                .reshape(b, hkv_loc, d)
-            v_row = y[:, (h_loc + hkv_loc) * d:].reshape(b, hkv_loc, d)
-            ck = jax.lax.dynamic_update_slice(
-                ck, k_row[None, :, :, None].astype(ck.dtype),
-                (jnp.int32(i), zero, zero, pos, zero))
-            cv = jax.lax.dynamic_update_slice(
-                cv, v_row[None, :, :, None].astype(cv.dtype),
-                (jnp.int32(i), zero, zero, pos, zero))
-            # the fused decode-attention kernel, untouched: this shard
-            # owns a contiguous kv-head slice of the contiguous cache
-            ctx = decode_attention(q, ck[i], cv[i], lengths)
-            out = tp_serving.row_parallel(
-                ctx.reshape(b, h_loc * d), layer["attn_out"]["weight"],
-                layer["attn_out"].get("bias"), axis=axis, overlap=False)
-            x = x + out[:, None]
-            h2 = fused_layer_norm(x, layer["ln2_w"], layer["ln2_b"])
-            h = tp_serving.column_parallel(
-                h2[:, 0], layer["mlp_up"]["weight"],
-                layer["mlp_up"].get("bias"), axis=axis, overlap=False)
-            h = jax.nn.gelu(h, approximate=True)
-            m = tp_serving.row_parallel(
-                h, layer["mlp_down"]["weight"],
-                layer["mlp_down"].get("bias"), axis=axis, overlap=False)
-            x = x + m[:, None]
-        x = fused_layer_norm(x, params["lnf_w"], params["lnf_b"])
-        logits = jnp.dot(x[:, 0], emb.T)  # (b, V/tp)
-        tok = tp_serving.row_argmax_tp(logits, axis=axis)
-        return {"k": ck, "v": cv}, tok, logits
-
-    def _spec_verify_body_tp(self, params, cache, tokens, pos, drafted,
-                             key):
-        c = self.config
-        axis, tp = tp_serving.TENSOR_AXIS, self.tp
-        h_loc, hkv_loc = c.num_heads // tp, c.kv_heads // tp
-        group, d = h_loc // hkv_loc, c.head_dim
-        params = tp_serving.take_shard(params)
-        b, K1 = tokens.shape
-        pos = jnp.asarray(pos, jnp.int32)
-        positions = pos + jnp.arange(K1, dtype=jnp.int32)
-        emb = params["embedding"]["weight"]
-        x = tp_serving.vocab_embed(emb, tokens, axis=axis)  # (1, K1, H)
-        ptab = params["pos_embedding"]
-        x = x + jnp.take(ptab, jnp.minimum(positions, ptab.shape[0] - 1),
-                         axis=0)[None]
-        ck, cv = cache["k"], cache["v"]
-        scale = 1.0 / d ** 0.5
-        js = jnp.arange(self.max_s, dtype=jnp.int32)
-        mask = js[None, None, None, :] <= positions[None, None, :, None]
-        zero = jnp.int32(0)
-        for i in range(c.num_layers):
-            layer = jax.tree.map(lambda a, i=i: a[i], params["layers"])
-            h_in = fused_layer_norm(x, layer["ln1_w"], layer["ln1_b"])
-            y = tp_serving.column_parallel(
-                h_in, layer["qkv"]["weight"], layer["qkv"].get("bias"),
-                axis=axis, overlap=False)
-            q = y[..., :h_loc * d]
-            k = y[..., h_loc * d:(h_loc + hkv_loc) * d] \
-                .reshape(b, K1, hkv_loc, d)
-            v = y[..., (h_loc + hkv_loc) * d:].reshape(b, K1, hkv_loc, d)
-            ck = jax.lax.dynamic_update_slice(
-                ck, k.transpose(0, 2, 1, 3)[None].astype(ck.dtype),
-                (jnp.int32(i), zero, zero, pos, zero))
-            cv = jax.lax.dynamic_update_slice(
-                cv, v.transpose(0, 2, 1, 3)[None].astype(cv.dtype),
-                (jnp.int32(i), zero, zero, pos, zero))
-            k_all, v_all = ck[i][0], cv[i][0]  # (hkv_loc, max_s, d)
-            qg = q[0].reshape(K1, hkv_loc, group, d).transpose(1, 2, 0, 3)
-            s = jnp.einsum("hgcd,hsd->hgcs", qg, k_all.astype(qg.dtype),
-                           preferred_element_type=jnp.float32) * scale
-            s = jnp.where(mask[0], s, NEG_INF)
-            p = jax.nn.softmax(s, axis=-1)
-            ctx = jnp.einsum("hgcs,hsd->hgcd", p.astype(v_all.dtype),
-                             v_all)
-            ctx = ctx.transpose(2, 0, 1, 3).reshape(b, K1, h_loc * d)
-            x = x + tp_serving.row_parallel(
-                ctx, layer["attn_out"]["weight"],
-                layer["attn_out"].get("bias"), axis=axis, overlap=False)
-            h2 = fused_layer_norm(x, layer["ln2_w"], layer["ln2_b"])
-            h = tp_serving.column_parallel(
-                h2, layer["mlp_up"]["weight"],
-                layer["mlp_up"].get("bias"), axis=axis, overlap=False)
-            h = jax.nn.gelu(h, approximate=True)
-            x = x + tp_serving.row_parallel(
-                h, layer["mlp_down"]["weight"],
-                layer["mlp_down"].get("bias"), axis=axis, overlap=False)
-        x = fused_layer_norm(x, params["lnf_w"], params["lnf_b"])
-        logits = jnp.dot(x, emb.T)  # (1, K1, V/tp)
-        a, nxt = tp_serving.verify_greedy_tp(logits, drafted, axis=axis)
-        return {"k": ck, "v": cv}, a, nxt
+        def attend(i, q, k, v):  # (b, 1, heads, d)
+            nonlocal cache
+            # the row write comes BEFORE attention: the token attends to
+            # itself
+            cache = self._write_cache(cache, i, pos, k.transpose(0, 2, 1, 3),
+                                      v.transpose(0, 2, 1, 3))
+            return decode_attention(q[:, 0], cache["k"][i], cache["v"][i],
+                                    lengths, bias=rel_bias)[:, None]
+        x = run_layers(m, params, x, attend)
+        logits = m.unembed(params, x)[:, 0]
+        return cache, m.sample(logits, key), logits
 
     # --- speculative verification --------------------------------------------
 
-    def _spec_verify_step(self, params, cache, tokens, pos, drafted, key):
-        """One speculative round: score ``tokens`` (1, k+1) — the
+    def _spec_verify_step(self, *args):
+        """``(params, cache, tokens, pos, drafted, key)`` — one
+        speculative round: score ``tokens`` (1, k+1) — the
         pending sampled token followed by the k drafted continuations —
         in ONE multi-token step at cache rows [pos, pos+k], then run the
         fused verify-and-sample tail. Returns ``(cache, accept_len (1,),
@@ -503,66 +317,36 @@ class DecodeEngine:
         length masking IS the rewind on a contiguous cache. Avals depend
         only on the static k: one executable across every round."""
         with monitor_spans.span("spec_verify"):
-            if self.tp > 1:
-                return self._tp_spec(params, cache, tokens, pos,
-                                     drafted, key)
-            return self._spec_verify_body(params, cache, tokens, pos,
-                                          drafted, key)
+            return self._spec_verify_body(*args)
 
     def _spec_verify_body(self, params, cache, tokens, pos, drafted, key):
-        model, c = self.model, self.config
-        b, K1 = tokens.shape
-        d = c.head_dim
-        h_kv, group = c.local_kv_heads, c.local_heads // c.local_kv_heads
+        m = self._math
+        params = m.shard(params)
         pos = jnp.asarray(pos, jnp.int32)
-        positions = pos + jnp.arange(K1, dtype=jnp.int32)
-        x = model.embedding(params["embedding"], tokens)  # (1, K1, H)
-        ptab = params["pos_embedding"]
-        x = x + jnp.take(ptab, jnp.minimum(positions, ptab.shape[0] - 1),
-                         axis=0)[None]
-        ck, cv = cache["k"], cache["v"]
-        scale = 1.0 / d ** 0.5
+        positions = pos + jnp.arange(tokens.shape[1], dtype=jnp.int32)
+        x = m.embed(params, tokens)  # (1, K1, H)
+        x = x + _pos_rows(params, positions)[None]
         js = jnp.arange(self.max_s, dtype=jnp.int32)
         # prefix-causal per drafted row: row i sees keys [0, pos + i]
-        mask = js[None, None, None, :] <= positions[None, None, :, None]
-        zero = jnp.int32(0)
-        for i in range(c.num_layers):
-            layer = jax.tree.map(lambda a, i=i: a[i], params["layers"])
-            h_in = fused_layer_norm(x, layer["ln1_w"], layer["ln1_b"])
-            q, k, v = model._proj_qkv_bshd(layer, h_in)
-            # one contiguous K1-row write at the traced frontier (the
-            # multi-token sibling of the decode step's single-row write)
-            ck = jax.lax.dynamic_update_slice(
-                ck, k.transpose(0, 2, 1, 3)[None].astype(ck.dtype),
-                (jnp.int32(i), zero, zero, pos, zero))
-            cv = jax.lax.dynamic_update_slice(
-                cv, v.transpose(0, 2, 1, 3)[None].astype(cv.dtype),
-                (jnp.int32(i), zero, zero, pos, zero))
-            # K1 queries × the full cache — the flash multi-token
-            # scoring shape (the prefill-chunk attention at chunk=k+1)
-            k_all, v_all = ck[i][0], cv[i][0]  # (h_kv, max_s, d)
-            qg = q[0].reshape(K1, h_kv, group, d).transpose(1, 2, 0, 3)
-            s = jnp.einsum("hgcd,hsd->hgcs", qg, k_all.astype(qg.dtype),
-                           preferred_element_type=jnp.float32) * scale
-            s = jnp.where(mask[0], s, NEG_INF)
-            p = jax.nn.softmax(s, axis=-1)
-            ctx = jnp.einsum("hgcs,hsd->hgcd", p.astype(v_all.dtype),
-                             v_all)
-            ctx = ctx.transpose(2, 0, 1, 3).reshape(1, K1, c.local_heads,
-                                                    d)
-            x = x + model._proj_attn_out(layer, ctx)
-            x = x + model._mlp(layer, fused_layer_norm(
-                x, layer["ln2_w"], layer["ln2_b"]))
-        x = fused_layer_norm(x, params["lnf_w"], params["lnf_b"])
-        logits = model.unembed(params, x)  # (1, K1, V)
-        a, nxt = fused_verify(logits, drafted, key,
-                              temperature=self.temperature,
-                              top_k=self.top_k)
-        return {"k": ck, "v": cv}, a, nxt
+        mask = (js[None, None, None, :] <= positions[None, None, :, None])[0]
 
-    def _spec_tree_verify_step(self, params, cache, tokens, pos, parents,
-                               anc, levels, key):
-        """One TREE speculative round: score ``tokens`` (1, N+1) — the
+        def attend(i, q, k, v):  # (1, K1, heads, d)
+            nonlocal cache
+            # one contiguous K1-row write at the traced frontier (the
+            # multi-token sibling of the decode step's single-row write),
+            # then K1 queries × the full cache — the prefill-chunk
+            # attention at chunk = k+1
+            cache = self._write_cache(cache, i, pos, k.transpose(0, 2, 1, 3),
+                                      v.transpose(0, 2, 1, 3))
+            return cached_attention(q, cache["k"][i][0], cache["v"][i][0],
+                                    mask)
+        x = run_layers(m, params, x, attend)
+        a, nxt = m.verify(m.unembed(params, x), drafted, key)  # (1, K1, V)
+        return cache, a, nxt
+
+    def _spec_tree_verify_step(self, *args):
+        """``(params, cache, tokens, pos, parents, anc, levels, key)`` —
+        one TREE speculative round: score ``tokens`` (1, N+1) — the
         pending token (the root) plus N drafted tree nodes — in ONE
         forward, each node attending the committed cache rows plus its
         own root path via the ``anc`` tree-attention mask, then run the
@@ -576,89 +360,60 @@ class DecodeEngine:
         Rows past the accepted frontier hold zeros that next round's
         length masking hides — length masking IS the rewind."""
         with monitor_spans.span("spec_verify"):
-            return self._spec_tree_verify_body(params, cache, tokens,
-                                               pos, parents, anc, levels,
-                                               key)
+            return self._spec_tree_verify_body(*args)
 
     def _spec_tree_verify_body(self, params, cache, tokens, pos, parents,
                                anc, levels, key):
-        model, c = self.model, self.config
-        b, N1 = tokens.shape
-        d = c.head_dim
-        h_kv, group = c.local_kv_heads, c.local_heads // c.local_kv_heads
+        m = self._math
+        params = m.shard(params)
         pos = jnp.asarray(pos, jnp.int32)
         depth_vec = jnp.sum(anc.astype(jnp.int32), axis=-1) - 1  # (1, N1)
-        positions = pos + depth_vec[0]  # (N1,) — siblings SHARE positions
-        x = model.embedding(params["embedding"], tokens)  # (1, N1, H)
-        ptab = params["pos_embedding"]
-        x = x + jnp.take(ptab, jnp.minimum(positions, ptab.shape[0] - 1),
-                         axis=0)[None]
-        ck, cv = cache["k"], cache["v"]
-        scale = 1.0 / d ** 0.5
+        x = m.embed(params, tokens)  # (1, N1, H)
+        # siblings SHARE positions
+        x = x + _pos_rows(params, pos + depth_vec[0])[None]
         js = jnp.arange(self.max_s, dtype=jnp.int32)
         # committed rows only: the root's own k/v rides the TREE part
         # (index 0), not the cache, until the verdict commits it
-        cache_mask = js[None, None, :] < pos  # (1, 1, max_s)
-        tree_mask = anc[0] != 0  # (N1 queries, N1 nodes): the root path
+        cache_mask = (js[None, None, :] < pos)[None]  # (1, 1, 1, max_s)
+        tree_mask = (anc[0] != 0)[None, None]  # (1, 1, N1 queries, N1 nodes)
         tks, tvs = [], []
-        for i in range(c.num_layers):
-            layer = jax.tree.map(lambda a, i=i: a[i], params["layers"])
-            h_in = fused_layer_norm(x, layer["ln1_w"], layer["ln1_b"])
-            q, k, v = model._proj_qkv_bshd(layer, h_in)  # (1, N1, h, d)
+
+        def attend(i, q, k, v):  # (1, N1, heads, d)
             tks.append(k)
             tvs.append(v)
-            k_all, v_all = ck[i][0], cv[i][0]  # (h_kv, max_s, d)
-            qg = q[0].reshape(N1, h_kv, group, d).transpose(1, 2, 0, 3)
-            s_c = jnp.einsum("hgcd,hsd->hgcs", qg,
-                             k_all.astype(qg.dtype),
-                             preferred_element_type=jnp.float32) * scale
-            s_c = jnp.where(cache_mask[None], s_c, NEG_INF)
-            kt = k[0].transpose(1, 0, 2)  # (h_kv, N1, d)
-            vt = v[0].transpose(1, 0, 2)
-            s_t = jnp.einsum("hgcd,hnd->hgcn", qg, kt.astype(qg.dtype),
-                             preferred_element_type=jnp.float32) * scale
-            s_t = jnp.where(tree_mask[None, None], s_t, NEG_INF)
-            # ONE softmax across cache + tree keys — exactly the
-            # distribution the committed-path decode would compute
-            p = jax.nn.softmax(jnp.concatenate([s_c, s_t], axis=-1),
-                               axis=-1)
-            p_c, p_t = p[..., :self.max_s], p[..., self.max_s:]
-            ctx = jnp.einsum("hgcs,hsd->hgcd", p_c.astype(v_all.dtype),
-                             v_all) \
-                + jnp.einsum("hgcn,hnd->hgcd", p_t.astype(vt.dtype), vt)
-            ctx = ctx.transpose(2, 0, 1, 3).reshape(1, N1, c.local_heads,
-                                                    d)
-            x = x + model._proj_attn_out(layer, ctx)
-            x = x + model._mlp(layer, fused_layer_norm(
-                x, layer["ln2_w"], layer["ln2_b"]))
-        x = fused_layer_norm(x, params["lnf_w"], params["lnf_b"])
-        logits = model.unembed(params, x)  # (1, N1, V)
-        a, j_star, nxt = fused_verify_tree(
-            logits, tokens, parents, anc, key,
-            temperature=self.temperature, top_k=self.top_k)
+            return cached_attention(q, cache["k"][i][0], cache["v"][i][0],
+                                    cache_mask, tree=(k, v, tree_mask))
+        x = run_layers(m, params, x, attend)
+        a, j_star, nxt = m.verify_tree(m.unembed(params, x), tokens,
+                                       parents, anc, key)  # (1, N1, V)
         # commit the winning path: level l of j_star's root path (root =
         # level 0 = the pending token) lands at cache row pos + l; levels
         # past accept_len select nothing and write zeros (masked rows)
-        ii = jnp.arange(N1, dtype=jnp.int32)
-        onpath = jnp.einsum(
-            "si,sin->sn", (ii[None] == j_star[:, None]).astype(jnp.float32),
-            anc.astype(jnp.float32))  # (1, N1)
-        lvl = onpath[:, None, :] * (
-            depth_vec[:, None, :] == levels[None, :, None]
-        ).astype(jnp.float32)  # (1, depth+1, N1)
-        zero = jnp.int32(0)
-        for i in range(c.num_layers):
-            sel_k = jnp.einsum("bln,bnhd->bhld", lvl.astype(tks[i].dtype),
-                               tks[i])
-            sel_v = jnp.einsum("bln,bnhd->bhld", lvl.astype(tvs[i].dtype),
-                               tvs[i])
-            ck = jax.lax.dynamic_update_slice(
-                ck, sel_k[None].astype(ck.dtype),
-                (jnp.int32(i), zero, zero, pos, zero))
-            cv = jax.lax.dynamic_update_slice(
-                cv, sel_v[None].astype(cv.dtype),
-                (jnp.int32(i), zero, zero, pos, zero))
-        return {"k": ck, "v": cv}, a, j_star, nxt
+        lvl = winning_path_levels(anc, depth_vec, j_star, levels)
+        for i, kv in enumerate(zip(tks, tvs)):
+            sel_k, sel_v = (jnp.einsum("bln,bnhd->bhld", lvl.astype(t.dtype),
+                                       t) for t in kv)
+            cache = self._write_cache(cache, i, pos, sel_k, sel_v)
+        return cache, a, j_star, nxt
+
+    def _check_speculable(self, b):
+        if b != 1:
+            raise ValueError(
+                f"draft= speculative generation runs batch 1 (accepted "
+                f"lengths diverge across rows, and the contiguous cache "
+                f"carries one scalar position); got batch {b} — split "
+                f"the batch, or serve it through ServingEngine.serve("
+                f"draft=...) which speculates per slot")
+        if getattr(self.model, "decode_rel_bias", None) is not None:
+            # the k+1-row spec scoring does not thread the bucketed
+            # relative bias the plain decode step applies — verifying
+            # biased baseline logits against unbiased spec logits would
+            # silently break the token-identical contract
+            raise ValueError(
+                "draft= speculative decoding cannot run a model with a "
+                "decode relative-position bias (the spec verify step "
+                "does not carry the bucketed bias) — generate with "
+                "draft=None for this model")
 
     def _generate_spec_tree(self, params, prompt, max_new_tokens, key,
                             draft, adaptive):
@@ -674,24 +429,12 @@ class DecodeEngine:
         from apex_tpu.spec.tree import draft_tree
 
         b, s = prompt.shape
-        if b != 1:
-            raise ValueError(
-                f"draft= speculative generation runs batch 1 (accepted "
-                f"lengths diverge across rows, and the contiguous cache "
-                f"carries one scalar position); got batch {b} — split "
-                f"the batch, or serve it through ServingEngine.serve("
-                f"draft=...) which speculates per slot")
+        self._check_speculable(b)
         if self.tp > 1:
             raise ValueError(
                 "tree-speculative generation has no tensor-parallel "
                 "body — decode tree drafts at tp=1, or use a chain "
-                "drafter (which verifies through the tp twin)")
-        if getattr(self.model, "decode_rel_bias", None) is not None:
-            raise ValueError(
-                "draft= speculative decoding cannot run a model with a "
-                "decode relative-position bias (the spec verify step "
-                "does not carry the bucketed bias) — generate with "
-                "draft=None for this model")
+                "drafter (which verifies under tp)")
         shapes = (adaptive.choices if adaptive is not None
                   else ((draft.depth, draft.branching),))
         depth_max = max(dd for dd, _ in shapes)
@@ -760,23 +503,7 @@ class DecodeEngine:
         from apex_tpu.spec.drafter import validate_drafter
 
         b, s = prompt.shape
-        if b != 1:
-            raise ValueError(
-                f"draft= speculative generation runs batch 1 (accepted "
-                f"lengths diverge across rows, and the contiguous cache "
-                f"carries one scalar position); got batch {b} — split "
-                f"the batch, or serve it through ServingEngine.serve("
-                f"draft=...) which speculates per slot")
-        if getattr(self.model, "decode_rel_bias", None) is not None:
-            # the k+1-row spec scoring does not thread the bucketed
-            # relative bias the plain decode step applies — verifying
-            # biased baseline logits against unbiased spec logits would
-            # silently break the token-identical contract
-            raise ValueError(
-                "draft= speculative decoding cannot run a model with a "
-                "decode relative-position bias (the spec verify step "
-                "does not carry the bucketed bias) — generate with "
-                "draft=None for this model")
+        self._check_speculable(b)
         K = validate_drafter(draft, self.config,
                              needed_rows=s + max_new_tokens
                              + getattr(draft, "k", 1))

@@ -1,60 +1,45 @@
 """ServingEngine: continuous-batching generation over a paged KV cache.
 
-The device side of :mod:`apex_tpu.serving` — TWO compiled programs
-(plus a third, ``spec_step``, when a drafter is attached), each with
-one set of avals for the lifetime of the engine:
+The device side of :mod:`apex_tpu.serving` — compiled programs with one
+set of avals each for the lifetime of the engine (their contracts are
+the ``_*_body`` docstrings below):
 
 * ``prefill_chunk(params, pool, table_row, tokens, start, live, key)``
-  — one fixed-size chunk of ONE slot's prompt through the stack: the
-  chunk's k/v land in the slot's pool blocks (a scatter at traced block
-  ids — blocks fully past the live tokens are redirected to the dead
-  block so ragged final chunks never touch foreign memory), attention
-  runs chunk-queries × the slot's gathered padded cache under the
-  prefix-causal mask ``key_pos <= start + i``, and the LAST chunk's
-  final-row logits sample the request's first token. ``start``/``live``
-  are traced scalars, so every chunk of every prompt length is the same
-  executable.
+  — one fixed-size chunk of ONE slot's prompt: a block scatter at
+  traced ids, chunk queries × the slot's gathered padded cache, and the
+  LAST chunk's final row samples the request's first token.
 * ``decode_step(params, pool, tables, tokens, lengths, key)`` — one
-  token for EVERY slot at once: per-slot cache writes resolve
-  ``(block, row)`` through the table (dead slots' writes land in the
-  dead block), attention is the paged
-  :func:`apex_tpu.ops.decode_attention` (``lengths == 0`` rows are dead
-  by the kernel's convention), and the fused sampling tail
-  (:func:`apex_tpu.ops.fused_sample`) turns logits into tokens in one
-  dispatch.
+  token for EVERY slot: per-slot ``(block, row)`` writes, the paged
+  :func:`apex_tpu.ops.decode_attention`, the fused sampling tail.
+* ``spec_step(..., drafted, key)`` / ``spec_tree_step(..., parents,
+  anc, levels, key)`` — the speculative rounds (``serve(draft=...)``):
+  k+1 chain rows or a whole draft tree scored per slot in one dispatch,
+  the fused verify tails emit ``(accept_len, next_token)``.
 
-* ``spec_step(params, pool, tables, tokens, lengths, drafted, key)`` —
-  the speculative round (``serve(draft=...)``): every decoding slot
-  scores its pending token plus k drafts in one k+1-wide dispatch
-  (the prefill-chunk attention shape batched over the slot array) and
-  the fused verify tail (:func:`apex_tpu.ops.fused_verify`) emits
-  per-slot ``(accept_len, next_token)``; the scheduler rewinds tables/
-  lengths to the accepted frontier afterwards — contents-only, one
-  executable per static k.
+Each body is written ONCE, as positions + block ids + a loop over
+layers + a tail, against three seams: the layer math (:class:`ModelMath`
+here, :class:`apex_tpu.serving.tp.ShardedMath` under ``plan.tp >= 2`` —
+the same bodies run inside ``shard_map``), :func:`cached_attention`
+(the one dense attention over a gathered prefix) and the pool's
+``_write_blocks`` / ``_write_rows`` / ``_gather_slot``.
 
 All donate the pool: XLA updates the cache in place, so a step's HBM
 traffic is the live cache read plus one token's writes — never a pool
 copy. Under a quantized ``kv_dtype`` (``"int8"`` or ``"fp8_e4m3"``)
 the pool stores 1-byte k/v cells with per-block-row fp32 scales
-alongside (quantize on write at every write site; dequantize in-VMEM
-inside the paged decode kernel), halving the bytes the HBM-bound
-decode stream pays — the float pool stays the parity oracle, and the
-two quantized formats differ only in (qmax, storage dtype). Everything dynamic about traffic stays in
-:class:`~apex_tpu.serving.scheduler.Scheduler` on the host; churn
-reaches the device only as operand *contents*, which is why
+alongside (quantize on write; dequantize in-VMEM inside the paged
+decode kernel), halving the bytes the HBM-bound decode stream pays —
+the float pool stays the parity oracle. Everything dynamic about
+traffic stays in :class:`~apex_tpu.serving.scheduler.Scheduler` on the
+host; churn reaches the device only as operand *contents*, which is why
 ``decode_step._cache_size()`` stays 1 across arbitrary admit/evict
-(asserted by ``tests/test_serving.py`` and by ``bench.py --serve``).
-
-The chunk-attention gather materializes one ``(h_kv, max_s, d)`` view
-per layer per chunk — prefill is compute-bound and infrequent relative
-to decode, so this buys simplicity where it is cheap; fusing the
-chunk path into the flash family is future work (the decode hot path,
-where the HBM bound lives, is already fused end to end).
+(asserted by ``tests/test_serving.py``).
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
@@ -63,7 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from apex_tpu.models.gpt import GPTModel, shard_params_for_tp
+from apex_tpu.models.gpt import GPTModel
 from apex_tpu.monitor import registry as monitor_registry
 from apex_tpu.monitor import spans as monitor_spans
 from apex_tpu.monitor import trace as monitor_trace
@@ -106,6 +91,130 @@ def _quant_rows(x, axes, *, qmax=127.0, qdtype=jnp.int8):
     else:
         q = jnp.clip(y, -qmax, qmax).astype(qdtype)
     return q, jnp.squeeze(scale, axis=axes)
+
+
+class ModelMath:
+    """How one layer's linear algebra is done at tp = 1 — the seam every
+    step body of both engines is written against: ``embed`` / ``qkv`` /
+    ``attn_out`` / ``mlp`` / ``unembed`` and the tails ``sample`` /
+    ``verify`` / ``verify_tree`` / ``quant_rows``, here the model's own
+    methods at full head counts. :class:`apex_tpu.serving.tp.
+    ShardedMath` is the same surface on a tp mesh; an engine picks one
+    at construction and no body knows which. ``sample`` is the engine's
+    sampling program ``(logits, key, **sampling)``; ``sampling``
+    (temperature / top_k / top_p) also feeds the fused verify tails."""
+
+    def __init__(self, model: GPTModel, sample, *, qmax=127.0,
+                 qdtype=jnp.int8, **sampling):
+        self.model, self._sample, self._sampling = model, sample, sampling
+        self._quant = dict(qmax=qmax, qdtype=qdtype)
+
+    def shard(self, params):
+        return params
+
+    def embed(self, params, tokens):
+        return self.model.embedding(params["embedding"], tokens)
+
+    def qkv(self, layer, h_in):
+        """(…, s, H) → seq-major q (…, s, h, d), k/v (…, s, h_kv, d)."""
+        return self.model._proj_qkv_bshd(layer, h_in)
+
+    def attn_out(self, layer, ctx):
+        return self.model._proj_attn_out(layer, ctx)
+
+    def mlp(self, layer, h):
+        return self.model._mlp(layer, h)
+
+    def unembed(self, params, x):
+        return self.model.unembed(params, x)
+
+    def sample(self, logits, key):
+        return self._sample(logits, key, **self._sampling)
+
+    def verify(self, logits, drafted, key):
+        return fused_verify(logits, drafted, key, **self._sampling)
+
+    def verify_tree(self, logits, tokens, parents, anc, key):
+        return fused_verify_tree(logits, tokens, parents, anc, key,
+                                 **self._sampling)
+
+    def quant_rows(self, x, axes):
+        return _quant_rows(x, axes, **self._quant)
+
+
+def cached_attention(q, k_all, v_all, mask, tree=None):
+    """THE dense attention of every multi-token serving step (prefill
+    chunk, chain round, tree round — both engines): queries ``q``
+    (b, C, h, d), grouped by kv head, against each slot's whole cached
+    prefix ``k_all``/``v_all`` (b, h_kv, max_s, d) — or ONE slot's,
+    without the leading axis, for ``b == 1`` — under ``mask``
+    (broadcastable to the scores (…, h_kv, group, C, max_s)). ``tree``
+    adds a second key/value set ``(k_t, v_t, mask_t)`` — the tree
+    round's own nodes (b, N, h_kv, d) — sharing the ONE softmax, exactly
+    the distribution the committed-path decode would compute. Returns
+    the context (b, C, h, d).
+
+    It scores all ``max_s`` rows whatever the live length (PERF.md §6,
+    PR 23: 282 of a 312 ms chunk) — ROADMAP S4 lands here, once."""
+    one_slot = k_all.ndim < q.ndim
+    *lead, C, h, d = q.shape[one_slot:]
+    h_kv = k_all.shape[-3]
+    qg = jnp.moveaxis((q[0] if one_slot else q).reshape(
+        *lead, C, h_kv, h // h_kv, d), -4, -2)
+
+    def scores(k, mask):
+        s = jnp.einsum("...hgcd,...hsd->...hgcs", qg, k.astype(qg.dtype),
+                       preferred_element_type=jnp.float32) * (1.0 / d ** 0.5)
+        return jnp.where(mask, s, NEG_INF)
+
+    def mix(p, v):
+        return jnp.einsum("...hgcs,...hsd->...hgcd", p.astype(v.dtype), v)
+    s = scores(k_all, mask)
+    if tree is not None:
+        k_t, v_t, mask_t = tree
+        kt, vt = (jnp.swapaxes(a[0] if one_slot else a, -3, -2)
+                  for a in (k_t, v_t))
+        s = jnp.concatenate([s, scores(kt, mask_t)], axis=-1)
+    p = jax.nn.softmax(s, axis=-1)
+    n = k_all.shape[-2]
+    ctx = (mix(p, v_all) if tree is None
+           else mix(p[..., :n], v_all) + mix(p[..., n:], vt))
+    return jnp.moveaxis(ctx, -2, -4).reshape(q.shape)
+
+
+def winning_path_levels(anc, depth_vec, j_star, levels):
+    """The tree round's commit selector (b, depth+1, N1): one-hot over
+    the nodes of ``j_star``'s root path, one row per level (root =
+    level 0 = the pending token), from the ancestor-or-self matrix
+    ``anc`` (b, N1, N1) and its row sums ``depth_vec``."""
+    ii = jnp.arange(anc.shape[-1], dtype=jnp.int32)
+    onpath = jnp.einsum(
+        "si,sin->sn", (ii[None] == j_star[:, None]).astype(jnp.float32),
+        anc.astype(jnp.float32))  # (b, N1)
+    return onpath[:, None, :] * (
+        depth_vec[:, None, :] == levels[None, :, None]).astype(jnp.float32)
+
+
+def run_layers(m, params, x, attend):
+    """The stack, once for every step body of both engines: per layer
+    pre-LN → the seam's projections → ``attend(i, q, k, v)`` — the
+    step's own cache write and attention, returning the context
+    (…, s, heads, d) — → output projection → pre-LN MLP, a residual
+    around each half; then the final LN."""
+    for i in range(jax.tree.leaves(params["layers"])[0].shape[0]):
+        layer = jax.tree.map(lambda a: a[i], params["layers"])
+        q, k, v = m.qkv(layer, fused_layer_norm(
+            x, layer["ln1_w"], layer["ln1_b"]))
+        x = x + m.attn_out(layer, attend(i, q, k, v))
+        x = x + m.mlp(layer, fused_layer_norm(
+            x, layer["ln2_w"], layer["ln2_b"]))
+    return fused_layer_norm(x, params["lnf_w"], params["lnf_b"])
+
+
+def _pos_rows(params, pos):
+    """Learned position rows at traced ``pos`` (clamped to the table)."""
+    ptab = params["pos_embedding"]
+    return jnp.take(ptab, jnp.minimum(pos, ptab.shape[0] - 1), axis=0)
 
 
 @dataclass
@@ -279,6 +388,12 @@ class ServingEngine:
         self.tp = int(plan.tp) if plan is not None else 1
         self._mesh = None
         self._swap_ref = None
+        # the layer-math seam: WHICH linear algebra the step bodies run
+        # (local dots or the rings) is decided here and nowhere else
+        self._math = ModelMath(
+            model, fused_sample, qmax=self._qmax, qdtype=self._qdtype,
+            temperature=self.temperature, top_k=self.top_k,
+            top_p=self.top_p)
         if self.tp > 1:
             tp_serving.validate_tp(
                 plan, c, engine="ServingEngine",
@@ -291,29 +406,34 @@ class ServingEngine:
                 has_rel_bias=getattr(model, "decode_rel_bias",
                                      None) is not None)
             self._mesh = tp_serving.tp_mesh(self.tp)
+            # these programs ride the ring: slots, chunks are tp-divisible
+            self._math = tp_serving.ShardedMath(
+                c, overlap=True, temperature=self.temperature)
             P = jax.sharding.PartitionSpec
             kv, rep = P(None, None, "tp"), P()
             pool_spec = ({"k": kv, "v": kv, "k_scale": rep,
                           "v_scale": rep} if self.quantized
                          else {"k": kv, "v": kv})
             self._pool_spec = pool_spec
-            # the shard_mapped step bodies: params arrive P('tp') on the
-            # leading per-rank axis, pool k/v shard the kv-head axis,
-            # scales/tables/tokens/lengths/key replicate; sampled tokens
-            # come back replicated (the psum-composed tail computes the
-            # same ints on every shard) and logits reassemble the full
-            # vocab row from the shards — output assembly, never an
-            # all_gather inside the program (the jaxpr gate's witness)
-            self._tp_prefill = mesh_lib.shard_map(
-                self._prefill_chunk_body_tp, mesh=self._mesh,
+            # the SAME bodies, shard_mapped in place: params arrive
+            # P('tp') on the leading per-rank axis, pool k/v shard the
+            # kv-head axis (block ids/tables/free list stay GLOBAL — one
+            # logical pool), everything else replicates; sampled tokens
+            # come back replicated (the psum-composed tail) and logits
+            # reassemble the vocab row as output sharding — never an
+            # all_gather inside the program (the jaxpr gate's witness).
+            # The tree round stays unmapped: serve() refuses it under tp
+            smap = functools.partial(mesh_lib.shard_map, mesh=self._mesh)
+            self._prefill_chunk_body = smap(
+                self._prefill_chunk_body,
                 in_specs=(P("tp"), pool_spec, rep, rep, rep, rep, rep),
                 out_specs=(pool_spec, rep, P("tp")))
-            self._tp_decode = mesh_lib.shard_map(
-                self._decode_step_body_tp, mesh=self._mesh,
+            self._decode_step_body = smap(
+                self._decode_step_body,
                 in_specs=(P("tp"), pool_spec, rep, rep, rep, rep),
                 out_specs=(pool_spec, rep, P(None, "tp")))
-            self._tp_spec = mesh_lib.shard_map(
-                self._spec_step_body_tp, mesh=self._mesh,
+            self._spec_step_body = smap(
+                self._spec_step_body,
                 in_specs=(P("tp"), pool_spec, rep, rep, rep, rep, rep),
                 out_specs=(pool_spec, rep, rep))
         self.last_stats: Optional[ServeStats] = None
@@ -376,16 +496,10 @@ class ServingEngine:
         return pool
 
     def _prepare_params(self, params):
-        """tp == 1: passthrough. Under tp: split the replicated params
-        tree into per-rank shards (:func:`~apex_tpu.models.gpt.
-        shard_params_for_tp` — every leaf gains a leading ``(tp,)``
-        axis) and commit each leaf to the mesh under ``P('tp')``."""
-        if self.tp == 1:
-            return params
-        sharded = shard_params_for_tp(params, self.tp, self.config)
-        sh = jax.sharding.NamedSharding(self._mesh,
-                                        jax.sharding.PartitionSpec("tp"))
-        return jax.tree.map(lambda a: jax.device_put(a, sh), sharded)
+        """tp == 1: passthrough; under tp the per-rank shards, committed
+        to the mesh (:func:`apex_tpu.serving.tp.prepare_params`)."""
+        return tp_serving.prepare_params(params, self.tp, self.config,
+                                         self._mesh)
 
     def pool_bytes(self) -> int:
         """HBM footprint of the whole pool (both k and v, plus the
@@ -397,11 +511,6 @@ class ServingEngine:
             scales = c.num_layers * self.num_blocks * self.block_size
             return 2 * cells + 2 * scales * 4
         return 2 * cells * jnp.dtype(self.cache_dtype).itemsize
-
-    def _pool_out(self, ck, cv, ks, vs) -> Dict[str, jax.Array]:
-        if self.quantized:
-            return {"k": ck, "v": cv, "k_scale": ks, "v_scale": vs}
-        return {"k": ck, "v": cv}
 
     # --- weight hot-swap -----------------------------------------------------
 
@@ -485,26 +594,56 @@ class ServingEngine:
                         dur_ms=(time.perf_counter() - t0) * 1e3)
         return new_params
 
-    # --- sampling tail -------------------------------------------------------
+    # --- the pool's one write and one gather ----------------------------------
 
-    def _sample(self, logits, key):
-        return fused_sample(logits, key, temperature=self.temperature,
-                            top_k=self.top_k, top_p=self.top_p)
+    def _write(self, pool, at, at_scale, k, v, axes):
+        """k/v land at ``pool["k"/"v"][at]``; a quantized pool quantizes
+        on write — one scale per token row over ``axes`` (kv heads and
+        head_dim), at the SAME block coordinates, so the dead-block
+        redirect covers the scale planes too."""
+        out = {}
+        for n, x in (("k", k), ("v", v)):
+            if self.quantized:
+                x, scale = self._math.quant_rows(x, axes)
+                out[n + "_scale"] = pool[n + "_scale"].at[at_scale].set(scale)
+            out[n] = pool[n].at[at].set(x.astype(pool[n].dtype))
+        return out
+
+    def _write_blocks(self, pool, i, ids, kb, vb):
+        """Prefill's whole-block scatter: ``kb``/``vb``
+        (C/B, h_kv, B, d) into layer ``i`` at traced block ``ids``."""
+        return self._write(pool, (i, ids), (i, ids), kb, vb, (1, 3))
+
+    def _write_rows(self, pool, i, bid, row, k, v):
+        """One token row per traced ``(bid, row)`` coordinate — the
+        decode step, the chain round and the tree commit: ``k``/``v``
+        (…, h_kv, d) over the coordinates' leading shape."""
+        return self._write(pool, (i, bid, slice(None), row), (i, bid, row),
+                           k, v, (k.ndim - 2, k.ndim - 1))
+
+    def _gather_slot(self, pool, i, tables):
+        """Layer ``i``'s cached prefix of one slot (``tables`` (nb,)) or
+        of every slot ((S, nb)) as padded ``(…, h_kv, max_s, d)`` views;
+        quantized pools dequantize in the gather (the multi-token steps
+        are compute-bound; the HBM-bound decode step dequantizes
+        in-kernel instead)."""
+        def view(n):
+            a = pool[n][i][tables]  # (…, nb, h_kv, B, d)
+            if self.quantized:
+                a = a.astype(jnp.float32) \
+                    * pool[n + "_scale"][i][tables][..., None, :, None]
+            return jnp.swapaxes(a, -4, -3).reshape(
+                *a.shape[:-4], a.shape[-3], self.max_s, a.shape[-1])
+        return view("k"), view("v")
 
     # --- prefill chunk -------------------------------------------------------
 
-    def _prefill_chunk(self, params, pool, table_row, tokens, start, live,
-                       key):
-        # trace-time step-anatomy span (PR 6): every HLO of the chunk
-        # program carries the serve_prefill scope in device traces — the
-        # join key request lifecycle records correlate on — monitor on or
+    def _prefill_chunk(self, *args):
+        # trace-time step-anatomy span: every HLO of the chunk program
+        # carries the serve_prefill scope in device traces, monitor on or
         # off; entered once per trace, never touching the stable avals
         with monitor_spans.span("serve_prefill"):
-            if self.tp > 1:
-                return self._tp_prefill(params, pool, table_row, tokens,
-                                        start, live, key)
-            return self._prefill_chunk_body(params, pool, table_row,
-                                            tokens, start, live, key)
+            return self._prefill_chunk_body(*args)
 
     def _prefill_chunk_body(self, params, pool, table_row, tokens, start,
                             live, key):
@@ -517,19 +656,15 @@ class ServingEngine:
         ``live - 1`` is then the prompt's final token). ``start`` and
         ``live`` are traced: one executable for every chunk of every
         prompt."""
-        model, c = self.model, self.config
+        m, c = self._math, self.config
         C, B = self.prefill_chunk_size, self.block_size
-        nb, max_s = self.max_blocks_per_slot, self.max_s
-        h_kv, group = c.local_kv_heads, c.local_heads // c.local_kv_heads
-        d = c.head_dim
+        params = m.shard(params)
         start = jnp.asarray(start, jnp.int32)
         live = jnp.asarray(live, jnp.int32)
 
-        x = model.embedding(params["embedding"], tokens[None])  # (1, C, H)
+        x = m.embed(params, tokens[None])  # (1, C, H)
         pos = start + jnp.arange(C, dtype=jnp.int32)
-        ptab = params["pos_embedding"]
-        x = x + jnp.take(ptab, jnp.minimum(pos, ptab.shape[0] - 1),
-                         axis=0)[None]
+        x = x + _pos_rows(params, pos)[None]
 
         # the chunk's target blocks: C/B table entries from start/B on
         # (chunks are block-aligned: start is always a B-multiple — the
@@ -545,81 +680,33 @@ class ServingEngine:
         blk_live = (jnp.arange(nblk, dtype=jnp.int32) * B) < live
         ids = jnp.where(blk_live, ids, DEAD_BLOCK)
 
-        scale = 1.0 / d ** 0.5
-        js = jnp.arange(max_s, dtype=jnp.int32)
+        js = jnp.arange(self.max_s, dtype=jnp.int32)
         mask = js[None, None, None, :] <= pos[None, None, :, None]
-        ck, cv = pool["k"], pool["v"]
-        ks, vs = pool.get("k_scale"), pool.get("v_scale")
-        for i in range(c.num_layers):
-            layer = jax.tree.map(lambda a, i=i: a[i], params["layers"])
-            h_in = fused_layer_norm(x, layer["ln1_w"], layer["ln1_b"])
-            q, k, v = model._proj_qkv_bshd(layer, h_in)
+
+        def attend(i, q, k, v):  # (1, C, heads, d)
+            nonlocal pool
             # chunk k/v → (C/B, h_kv, B, d) block scatter at traced ids
-            kb = k[0].reshape(nblk, B, h_kv, d).transpose(0, 2, 1, 3)
-            vb = v[0].reshape(nblk, B, h_kv, d).transpose(0, 2, 1, 3)
-            if self.quantized:
-                # quantize on write: per (block, row) scales over
-                # (h_kv, d) — the same ids, so the dead-block redirect
-                # covers the scale planes too
-                kq, ksc = _quant_rows(kb, (1, 3), qmax=self._qmax,
-                                      qdtype=self._qdtype)
-                vq, vsc = _quant_rows(vb, (1, 3), qmax=self._qmax,
-                                      qdtype=self._qdtype)
-                ck = ck.at[i, ids].set(kq)
-                cv = cv.at[i, ids].set(vq)
-                ks = ks.at[i, ids].set(ksc)
-                vs = vs.at[i, ids].set(vsc)
-            else:
-                ck = ck.at[i, ids].set(kb.astype(ck.dtype))
-                cv = cv.at[i, ids].set(vb.astype(cv.dtype))
+            kb, vb = (a[0].reshape(nblk, B, *a.shape[2:])
+                      .transpose(0, 2, 1, 3) for a in (k, v))
+            pool = self._write_blocks(pool, i, ids, kb, vb)
             # prefix attention: chunk queries × the slot's gathered
             # padded cache (chunk rows included — causal within the
-            # chunk falls out of the same mask); int8 pools dequantize
-            # in the gather (prefill is compute-bound — simplicity is
-            # cheap here; the HBM-bound decode path dequantizes
-            # in-kernel instead)
-            if self.quantized:
-                k_all = (ck[i][table_row].astype(jnp.float32)
-                         * ks[i][table_row][:, None, :, None]) \
-                    .transpose(1, 0, 2, 3).reshape(h_kv, max_s, d)
-                v_all = (cv[i][table_row].astype(jnp.float32)
-                         * vs[i][table_row][:, None, :, None]) \
-                    .transpose(1, 0, 2, 3).reshape(h_kv, max_s, d)
-            else:
-                k_all = ck[i][table_row].transpose(1, 0, 2, 3) \
-                    .reshape(h_kv, max_s, d)
-                v_all = cv[i][table_row].transpose(1, 0, 2, 3) \
-                    .reshape(h_kv, max_s, d)
-            qg = q[0].reshape(C, h_kv, group, d).transpose(1, 2, 0, 3)
-            s = jnp.einsum("hgcd,hsd->hgcs", qg,
-                           k_all.astype(qg.dtype),
-                           preferred_element_type=jnp.float32) * scale
-            s = jnp.where(mask, s, NEG_INF)
-            p = jax.nn.softmax(s, axis=-1)
-            ctx = jnp.einsum("hgcs,hsd->hgcd", p.astype(v_all.dtype), v_all)
-            ctx = ctx.transpose(2, 0, 1, 3).reshape(1, C, c.local_heads, d)
-            x = x + model._proj_attn_out(layer, ctx)
-            x = x + model._mlp(layer, fused_layer_norm(
-                x, layer["ln2_w"], layer["ln2_b"]))
-        x = fused_layer_norm(x, params["lnf_w"], params["lnf_b"])
+            # chunk falls out of the same mask)
+            return cached_attention(
+                q, *self._gather_slot(pool, i, table_row), mask)
+        x = run_layers(m, params, x, attend)
         last = jax.lax.dynamic_slice(
             x, (jnp.int32(0), live - 1, jnp.int32(0)),
             (1, 1, c.hidden_size))
-        logits = model.unembed(params, last)[:, 0]  # (1, V)
-        return (self._pool_out(ck, cv, ks, vs),
-                self._sample(logits, key)[0], logits[0])
+        logits = m.unembed(params, last)[:, 0]  # (1, V)
+        return pool, m.sample(logits, key)[0], logits[0]
 
     # --- decode step ---------------------------------------------------------
 
-    def _decode_step(self, params, pool, tables, tokens, lengths, key):
-        # same trace-time scope as above: one span per TRACE (not per
-        # token), prefixing the whole decode step's HLOs in device traces
+    def _decode_step(self, *args):
+        # one span per TRACE (not per token), as above
         with monitor_spans.span("serve_decode"):
-            if self.tp > 1:
-                return self._tp_decode(params, pool, tables, tokens,
-                                       lengths, key)
-            return self._decode_step_body(params, pool, tables, tokens,
-                                          lengths, key)
+            return self._decode_step_body(*args)
 
     def _decode_step_body(self, params, pool, tables, tokens, lengths, key):
         """One token for EVERY slot: ``tokens`` (S,) are each slot's
@@ -628,14 +715,12 @@ class ServingEngine:
         output zeros, sampled value ignored by the host). Returns
         ``(pool, next_tokens, logits)``. Avals are churn-independent:
         compiled exactly once."""
-        model, c = self.model, self.config
-        B = self.block_size
+        m, B = self._math, self.block_size
+        params = m.shard(params)
         lengths = lengths.astype(jnp.int32)
         pos = jnp.maximum(lengths - 1, 0)  # the incoming token's position
-        x = model.embedding(params["embedding"], tokens[:, None])
-        ptab = params["pos_embedding"]
-        x = x + jnp.take(ptab, jnp.minimum(pos, ptab.shape[0] - 1),
-                         axis=0)[:, None]
+        x = m.embed(params, tokens[:, None])
+        x = x + _pos_rows(params, pos)[:, None]
         tables = tables.astype(jnp.int32)
         bid = jnp.take_along_axis(tables, (pos // B)[:, None], axis=1)[:, 0]
         # dead slots (lengths == 0) write to the dead block NO MATTER what
@@ -644,51 +729,34 @@ class ServingEngine:
         # would corrupt its own freshly prefilled cache
         bid = jnp.where(lengths > 0, bid, DEAD_BLOCK)
         row = pos % B
-        rel_hook = getattr(model, "decode_rel_bias", None)
+        rel_hook = getattr(self.model, "decode_rel_bias", None)
         rel_bias = None if rel_hook is None else rel_hook(params)
-        ck, cv = pool["k"], pool["v"]
-        ks, vs = pool.get("k_scale"), pool.get("v_scale")
-        for i in range(c.num_layers):
-            layer = jax.tree.map(lambda a, i=i: a[i], params["layers"])
-            q, k_row, v_row = model.decode_qkv(layer, x)
-            # per-slot (block, row) scatter into the DONATED pool; dead
-            # slots carry table rows of DEAD_BLOCK, so their writes are
-            # absorbed harmlessly
-            if self.quantized:
-                kq, ksc = _quant_rows(k_row[:, :, 0], (1, 2),  # (S,)
-                                      qmax=self._qmax, qdtype=self._qdtype)
-                vq, vsc = _quant_rows(v_row[:, :, 0], (1, 2),
-                                      qmax=self._qmax, qdtype=self._qdtype)
-                ck = ck.at[i, bid, :, row].set(kq)
-                cv = cv.at[i, bid, :, row].set(vq)
-                ks = ks.at[i, bid, row].set(ksc)
-                vs = vs.at[i, bid, row].set(vsc)
-                scales = (ks[i], vs[i])
-            else:
-                ck = ck.at[i, bid, :, row].set(
-                    k_row[:, :, 0].astype(ck.dtype))
-                cv = cv.at[i, bid, :, row].set(
-                    v_row[:, :, 0].astype(cv.dtype))
-                scales = None
-            x = model.decode_block(layer, x, q, ck[i], cv[i], lengths,
-                                   rel_bias=rel_bias, block_tables=tables,
-                                   kv_scales=scales)
-        x = fused_layer_norm(x, params["lnf_w"], params["lnf_b"])
-        logits = model.unembed(params, x)[:, 0]  # (S, V)
-        return self._pool_out(ck, cv, ks, vs), self._sample(logits, key), \
-            logits
+
+        def attend(i, q, k, v):  # (S, 1, heads, d)
+            nonlocal pool
+            # per-slot (block, row) scatter into the DONATED pool, BEFORE
+            # attention so the token attends to itself (through the
+            # contiguous cache's (S, h_kv, 1, d) row layout: the program
+            # a plain engine always traced)
+            pool = self._write_rows(pool, i, bid, row,
+                                    k.transpose(0, 2, 1, 3)[:, :, 0],
+                                    v.transpose(0, 2, 1, 3)[:, :, 0])
+            # the paged decode-attention kernel over the whole pool: block
+            # tables, length masking and the quantized pools' scale
+            # indirection (dequantized in-VMEM) live inside it
+            scales = {n: a[i] for n, a in pool.items() if n.endswith("scale")}
+            return decode_attention(q[:, 0], pool["k"][i], pool["v"][i],
+                                    lengths, bias=rel_bias,
+                                    block_tables=tables, **scales)[:, None]
+        x = run_layers(m, params, x, attend)
+        logits = m.unembed(params, x)[:, 0]  # (S, V)
+        return pool, m.sample(logits, key), logits
 
     # --- speculative round ---------------------------------------------------
 
-    def _spec_step(self, params, pool, tables, tokens, lengths, drafted,
-                   key):
-        # trace-time step-anatomy span, like serve_prefill/serve_decode
+    def _spec_step(self, *args):
         with monitor_spans.span("serve_spec"):
-            if self.tp > 1:
-                return self._tp_spec(params, pool, tables, tokens,
-                                     lengths, drafted, key)
-            return self._spec_step_body(params, pool, tables, tokens,
-                                        lengths, drafted, key)
+            return self._spec_step_body(*args)
 
     def _spec_step_body(self, params, pool, tables, tokens, lengths,
                         drafted, key):
@@ -706,91 +774,41 @@ class ServingEngine:
         rejected-draft k/v — the scheduler rewinds tables/lengths to the
         frontier (contents-only mutation; this program never retraces).
         Returns ``(pool, accept_lens (S,), next_tokens (S,))``."""
-        model, c = self.model, self.config
-        B = self.block_size
-        S, K1 = tokens.shape
-        h_kv, group = c.local_kv_heads, c.local_heads // c.local_kv_heads
-        d = c.head_dim
-        max_s = self.max_s
+        m, B = self._math, self.block_size
+        params = m.shard(params)
         lengths = lengths.astype(jnp.int32)
         base = jnp.maximum(lengths - 1, 0)
-        pos = base[:, None] + jnp.arange(K1, dtype=jnp.int32)[None, :]
-        x = model.embedding(params["embedding"], tokens)  # (S, K1, H)
-        ptab = params["pos_embedding"]
-        x = x + jnp.take(ptab, jnp.minimum(pos, ptab.shape[0] - 1),
-                         axis=0)
+        pos = base[:, None] + jnp.arange(tokens.shape[1],
+                                         dtype=jnp.int32)[None, :]
+        x = m.embed(params, tokens)  # (S, K1, H)
+        x = x + _pos_rows(params, pos)
         tables = tables.astype(jnp.int32)
         bid = jnp.take_along_axis(tables, pos // B, axis=1)  # (S, K1)
         # dead slots write to the dead block NO MATTER what their table
         # row says (same redirect as the decode step)
         bid = jnp.where(lengths[:, None] > 0, bid, DEAD_BLOCK)
         row = pos % B
-        scale = 1.0 / d ** 0.5
-        js = jnp.arange(max_s, dtype=jnp.int32)
+        js = jnp.arange(self.max_s, dtype=jnp.int32)
         # prefix-causal per drafted row: row j of slot i sees keys
         # [0, base_i + j] — broadcastable over (S, h_kv, group, K1, max_s)
         mask = js[None, None, None, None, :] <= pos[:, None, None, :, None]
-        ck, cv = pool["k"], pool["v"]
-        ks, vs = pool.get("k_scale"), pool.get("v_scale")
-        for i in range(c.num_layers):
-            layer = jax.tree.map(lambda a, i=i: a[i], params["layers"])
-            h_in = fused_layer_norm(x, layer["ln1_w"], layer["ln1_b"])
-            q, k, v = model._proj_qkv_bshd(layer, h_in)
-            # (S, K1) rows scattered at traced (block, row) coordinates
-            if self.quantized:
-                kq, ksc = _quant_rows(k, (2, 3),  # scales (S, K1)
-                                      qmax=self._qmax, qdtype=self._qdtype)
-                vq, vsc = _quant_rows(v, (2, 3),
-                                      qmax=self._qmax, qdtype=self._qdtype)
-                ck = ck.at[i, bid, :, row].set(kq)
-                cv = cv.at[i, bid, :, row].set(vq)
-                ks = ks.at[i, bid, row].set(ksc)
-                vs = vs.at[i, bid, row].set(vsc)
-            else:
-                ck = ck.at[i, bid, :, row].set(k.astype(ck.dtype))
-                cv = cv.at[i, bid, :, row].set(v.astype(cv.dtype))
-            # K1 queries per slot × the slot's gathered padded cache —
-            # the prefill-chunk attention at chunk = k+1, batched over
-            # the slot array (int8 pools dequantize in the gather)
-            if self.quantized:
-                k_all = (ck[i][tables].astype(jnp.float32)
-                         * ks[i][tables][:, :, None, :, None])
-                v_all = (cv[i][tables].astype(jnp.float32)
-                         * vs[i][tables][:, :, None, :, None])
-            else:
-                k_all, v_all = ck[i][tables], cv[i][tables]
-            k_all = k_all.transpose(0, 2, 1, 3, 4) \
-                .reshape(S, h_kv, max_s, d)
-            v_all = v_all.transpose(0, 2, 1, 3, 4) \
-                .reshape(S, h_kv, max_s, d)
-            qg = q.reshape(S, K1, h_kv, group, d).transpose(0, 2, 3, 1, 4)
-            s = jnp.einsum("bhgcd,bhsd->bhgcs", qg,
-                           k_all.astype(qg.dtype),
-                           preferred_element_type=jnp.float32) * scale
-            s = jnp.where(mask, s, NEG_INF)
-            p = jax.nn.softmax(s, axis=-1)
-            ctx = jnp.einsum("bhgcs,bhsd->bhgcd", p.astype(v_all.dtype),
-                             v_all)
-            ctx = ctx.transpose(0, 3, 1, 2, 4).reshape(S, K1,
-                                                       c.local_heads, d)
-            x = x + model._proj_attn_out(layer, ctx)
-            x = x + model._mlp(layer, fused_layer_norm(
-                x, layer["ln2_w"], layer["ln2_b"]))
-        x = fused_layer_norm(x, params["lnf_w"], params["lnf_b"])
-        logits = model.unembed(params, x)  # (S, K1, V)
-        a, nxt = fused_verify(logits, drafted, key,
-                              temperature=self.temperature,
-                              top_k=self.top_k, top_p=self.top_p)
-        return self._pool_out(ck, cv, ks, vs), a, nxt
+
+        def attend(i, q, k, v):  # (S, K1, heads, d)
+            nonlocal pool
+            # (S, K1) rows scattered at traced (block, row) coordinates,
+            # then K1 queries per slot × the slot's gathered padded cache
+            pool = self._write_rows(pool, i, bid, row, k, v)
+            return cached_attention(
+                q, *self._gather_slot(pool, i, tables), mask)
+        x = run_layers(m, params, x, attend)
+        a, nxt = m.verify(m.unembed(params, x), drafted, key)  # (S, K1, V)
+        return pool, a, nxt
 
     # --- tree speculative round ----------------------------------------------
 
-    def _tree_step(self, params, pool, tables, tokens, lengths, parents,
-                   anc, levels, key):
-        # trace-time step-anatomy span, like serve_spec
+    def _tree_step(self, *args):
         with monitor_spans.span("serve_spec_tree"):
-            return self._tree_step_body(params, pool, tables, tokens,
-                                        lengths, parents, anc, levels, key)
+            return self._tree_step_body(*args)
 
     def _tree_step_body(self, params, pool, tables, tokens, lengths,
                         parents, anc, levels, key):
@@ -812,375 +830,49 @@ class ServingEngine:
         rewind is pure host bookkeeping. Returns ``(pool, accept_lens
         (S,), j_star (S,), next_tokens (S,))`` — one executable per
         static ``(N+1, depth+1)``."""
-        model, c = self.model, self.config
-        B = self.block_size
-        S, N1 = tokens.shape
-        h_kv, group = c.local_kv_heads, c.local_heads // c.local_kv_heads
-        d = c.head_dim
-        max_s = self.max_s
+        m, B = self._math, self.block_size
+        params = m.shard(params)
         lengths = lengths.astype(jnp.int32)
         base = jnp.maximum(lengths - 1, 0)
         depth_vec = jnp.sum(anc.astype(jnp.int32), axis=-1) - 1  # (S, N1)
-        positions = base[:, None] + depth_vec  # siblings SHARE positions
-        x = model.embedding(params["embedding"], tokens)  # (S, N1, H)
-        ptab = params["pos_embedding"]
-        x = x + jnp.take(ptab, jnp.minimum(positions, ptab.shape[0] - 1),
-                         axis=0)
+        x = m.embed(params, tokens)  # (S, N1, H)
+        # siblings SHARE positions
+        x = x + _pos_rows(params, base[:, None] + depth_vec)
         tables = tables.astype(jnp.int32)
-        scale = 1.0 / d ** 0.5
-        js = jnp.arange(max_s, dtype=jnp.int32)
+        js = jnp.arange(self.max_s, dtype=jnp.int32)
         # committed rows only — the root's own k/v rides the TREE part
         # (node 0), not the cache, until the verdict commits it
         cache_mask = js[None, None, None, None, :] \
             < base[:, None, None, None, None]
         tree_mask = (anc != 0)[:, None, None]  # (S, 1, 1, N1, N1)
-        ck, cv = pool["k"], pool["v"]
-        ks, vs = pool.get("k_scale"), pool.get("v_scale")
         tks, tvs = [], []
-        for i in range(c.num_layers):
-            layer = jax.tree.map(lambda a_, i=i: a_[i], params["layers"])
-            h_in = fused_layer_norm(x, layer["ln1_w"], layer["ln1_b"])
-            q, k, v = model._proj_qkv_bshd(layer, h_in)  # (S, N1, h, d)
+
+        def attend(i, q, k, v):  # (S, N1, heads, d)
             tks.append(k)
             tvs.append(v)
             # N1 queries per slot × the slot's gathered padded cache —
-            # the chain round's gather, minus the pre-verdict scatter
-            if self.quantized:
-                k_all = (ck[i][tables].astype(jnp.float32)
-                         * ks[i][tables][:, :, None, :, None])
-                v_all = (cv[i][tables].astype(jnp.float32)
-                         * vs[i][tables][:, :, None, :, None])
-            else:
-                k_all, v_all = ck[i][tables], cv[i][tables]
-            k_all = k_all.transpose(0, 2, 1, 3, 4) \
-                .reshape(S, h_kv, max_s, d)
-            v_all = v_all.transpose(0, 2, 1, 3, 4) \
-                .reshape(S, h_kv, max_s, d)
-            qg = q.reshape(S, N1, h_kv, group, d).transpose(0, 2, 3, 1, 4)
-            s_c = jnp.einsum("bhgcd,bhsd->bhgcs", qg,
-                             k_all.astype(qg.dtype),
-                             preferred_element_type=jnp.float32) * scale
-            s_c = jnp.where(cache_mask, s_c, NEG_INF)
-            kt = k.transpose(0, 2, 1, 3)  # (S, h_kv, N1, d)
-            vt = v.transpose(0, 2, 1, 3)
-            s_t = jnp.einsum("bhgcd,bhnd->bhgcn", qg, kt.astype(qg.dtype),
-                             preferred_element_type=jnp.float32) * scale
-            s_t = jnp.where(tree_mask, s_t, NEG_INF)
-            # ONE softmax across cache + tree keys — exactly the
-            # distribution the committed-path decode would compute
-            p = jax.nn.softmax(jnp.concatenate([s_c, s_t], axis=-1),
-                               axis=-1)
-            p_c, p_t = p[..., :max_s], p[..., max_s:]
-            ctx = jnp.einsum("bhgcs,bhsd->bhgcd", p_c.astype(v_all.dtype),
-                             v_all) \
-                + jnp.einsum("bhgcn,bhnd->bhgcd", p_t.astype(vt.dtype), vt)
-            ctx = ctx.transpose(0, 3, 1, 2, 4).reshape(S, N1,
-                                                       c.local_heads, d)
-            x = x + model._proj_attn_out(layer, ctx)
-            x = x + model._mlp(layer, fused_layer_norm(
-                x, layer["ln2_w"], layer["ln2_b"]))
-        x = fused_layer_norm(x, params["lnf_w"], params["lnf_b"])
-        logits = model.unembed(params, x)  # (S, N1, V)
-        a, j_star, nxt = fused_verify_tree(
-            logits, tokens, parents, anc, key,
-            temperature=self.temperature, top_k=self.top_k,
-            top_p=self.top_p)
+            # the chain round's gather, minus the pre-verdict scatter —
+            # and × the round's own nodes, under ONE softmax
+            return cached_attention(
+                q, *self._gather_slot(pool, i, tables), cache_mask,
+                tree=(k, v, tree_mask))
+        x = run_layers(m, params, x, attend)
+        a, j_star, nxt = m.verify_tree(m.unembed(params, x), tokens,
+                                       parents, anc, key)  # (S, N1, V)
         # commit the winning path: level l of j_star's root path (root =
         # level 0 = the pending token) lands at pool row base + l; levels
         # past accept_len — and dead slots — redirect to the dead block
-        ii = jnp.arange(N1, dtype=jnp.int32)
-        onpath = jnp.einsum(
-            "si,sin->sn",
-            (ii[None] == j_star[:, None]).astype(jnp.float32),
-            anc.astype(jnp.float32))  # (S, N1)
-        lvl = onpath[:, None, :] * (
-            depth_vec[:, None, :] == levels[None, :, None]
-        ).astype(jnp.float32)  # (S, depth+1, N1)
+        lvl = winning_path_levels(anc, depth_vec, j_star, levels)
         wpos = base[:, None] + levels[None, :]  # (S, depth+1)
         valid = (levels[None, :] <= a[:, None]) & (lengths[:, None] > 0)
         bid = jnp.take_along_axis(tables, wpos // B, axis=1)
         bid = jnp.where(valid, bid, DEAD_BLOCK)
         row = wpos % B
-        for i in range(c.num_layers):
-            sel_k = jnp.einsum("bln,bnhd->blhd",
-                               lvl.astype(tks[i].dtype), tks[i])
-            sel_v = jnp.einsum("bln,bnhd->blhd",
-                               lvl.astype(tvs[i].dtype), tvs[i])
-            if self.quantized:
-                kq, ksc = _quant_rows(sel_k, (2, 3),  # scales (S, depth+1)
-                                      qmax=self._qmax, qdtype=self._qdtype)
-                vq, vsc = _quant_rows(sel_v, (2, 3),
-                                      qmax=self._qmax, qdtype=self._qdtype)
-                ck = ck.at[i, bid, :, row].set(kq)
-                cv = cv.at[i, bid, :, row].set(vq)
-                ks = ks.at[i, bid, row].set(ksc)
-                vs = vs.at[i, bid, row].set(vsc)
-            else:
-                ck = ck.at[i, bid, :, row].set(sel_k.astype(ck.dtype))
-                cv = cv.at[i, bid, :, row].set(sel_v.astype(cv.dtype))
-        return self._pool_out(ck, cv, ks, vs), a, j_star, nxt
-
-    # --- tensor-parallel step bodies (plan.tp >= 2) --------------------------
-    #
-    # Per-shard twins of the bodies above, run INSIDE shard_map: params
-    # arrive as shard_params_for_tp slices, the pool's kv-head axis is
-    # this shard's contiguous slice (block ids/tables/free list are
-    # GLOBAL — one logical pool), projections ride the ring-overlapped
-    # collective matmuls (apex_tpu.serving.tp helpers over
-    # ops/collective_matmul), attention math is unchanged at local head
-    # counts (GQA group size is tp-invariant since kv_heads % tp), the
-    # int8 scales pmax-compose to the tp=1 values, and the sampling/
-    # verify tails psum-compose so every shard emits the same tokens.
-
-    def _prefill_chunk_body_tp(self, params, pool, table_row, tokens,
-                               start, live, key):
-        c = self.config
-        axis, tp = tp_serving.TENSOR_AXIS, self.tp
-        C, B = self.prefill_chunk_size, self.block_size
-        max_s = self.max_s
-        h_loc, hkv_loc = c.num_heads // tp, c.kv_heads // tp
-        group, d = h_loc // hkv_loc, c.head_dim
-        params = tp_serving.take_shard(params)
-        start = jnp.asarray(start, jnp.int32)
-        live = jnp.asarray(live, jnp.int32)
-
-        emb = params["embedding"]["weight"]  # (V/tp, H)
-        x = tp_serving.vocab_embed(emb, tokens[None], axis=axis)
-        pos = start + jnp.arange(C, dtype=jnp.int32)
-        ptab = params["pos_embedding"]
-        x = x + jnp.take(ptab, jnp.minimum(pos, ptab.shape[0] - 1),
-                         axis=0)[None]
-
-        nblk = C // B
-        ids = jax.lax.dynamic_slice(table_row.astype(jnp.int32),
-                                    (start // B,), (nblk,))
-        blk_live = (jnp.arange(nblk, dtype=jnp.int32) * B) < live
-        ids = jnp.where(blk_live, ids, DEAD_BLOCK)
-
-        scale = 1.0 / d ** 0.5
-        js = jnp.arange(max_s, dtype=jnp.int32)
-        mask = js[None, None, None, :] <= pos[None, None, :, None]
-        ck, cv = pool["k"], pool["v"]
-        ks, vs = pool.get("k_scale"), pool.get("v_scale")
-        for i in range(c.num_layers):
-            layer = jax.tree.map(lambda a, i=i: a[i], params["layers"])
-            h_in = fused_layer_norm(x, layer["ln1_w"], layer["ln1_b"])
-            y = tp_serving.column_parallel(
-                h_in[0], layer["qkv"]["weight"],
-                layer["qkv"].get("bias"), axis=axis, seq_dim=0)
-            q = y[:, :h_loc * d].reshape(C, h_loc, d)
-            k = y[:, h_loc * d:(h_loc + hkv_loc) * d] \
-                .reshape(C, hkv_loc, d)
-            v = y[:, (h_loc + hkv_loc) * d:].reshape(C, hkv_loc, d)
-            kb = k.reshape(nblk, B, hkv_loc, d).transpose(0, 2, 1, 3)
-            vb = v.reshape(nblk, B, hkv_loc, d).transpose(0, 2, 1, 3)
-            if self.quantized:
-                kq, ksc = tp_serving.quant_rows_tp(kb, (1, 3), axis)
-                vq, vsc = tp_serving.quant_rows_tp(vb, (1, 3), axis)
-                ck = ck.at[i, ids].set(kq)
-                cv = cv.at[i, ids].set(vq)
-                ks = ks.at[i, ids].set(ksc)
-                vs = vs.at[i, ids].set(vsc)
-                k_all = (ck[i][table_row].astype(jnp.float32)
-                         * ks[i][table_row][:, None, :, None]) \
-                    .transpose(1, 0, 2, 3).reshape(hkv_loc, max_s, d)
-                v_all = (cv[i][table_row].astype(jnp.float32)
-                         * vs[i][table_row][:, None, :, None]) \
-                    .transpose(1, 0, 2, 3).reshape(hkv_loc, max_s, d)
-            else:
-                ck = ck.at[i, ids].set(kb.astype(ck.dtype))
-                cv = cv.at[i, ids].set(vb.astype(cv.dtype))
-                k_all = ck[i][table_row].transpose(1, 0, 2, 3) \
-                    .reshape(hkv_loc, max_s, d)
-                v_all = cv[i][table_row].transpose(1, 0, 2, 3) \
-                    .reshape(hkv_loc, max_s, d)
-            qg = q.reshape(C, hkv_loc, group, d).transpose(1, 2, 0, 3)
-            s = jnp.einsum("hgcd,hsd->hgcs", qg,
-                           k_all.astype(qg.dtype),
-                           preferred_element_type=jnp.float32) * scale
-            s = jnp.where(mask[0], s, NEG_INF)
-            p = jax.nn.softmax(s, axis=-1)
-            ctx = jnp.einsum("hgcs,hsd->hgcd", p.astype(v_all.dtype),
-                             v_all)
-            ctx = ctx.transpose(2, 0, 1, 3).reshape(C, h_loc * d)
-            out = tp_serving.row_parallel(
-                ctx, layer["attn_out"]["weight"],
-                layer["attn_out"].get("bias"), axis=axis, seq_dim=0)
-            x = x + out[None]
-            h2 = fused_layer_norm(x, layer["ln2_w"], layer["ln2_b"])
-            h = tp_serving.column_parallel(
-                h2[0], layer["mlp_up"]["weight"],
-                layer["mlp_up"].get("bias"), axis=axis, seq_dim=0)
-            h = jax.nn.gelu(h, approximate=True)
-            m = tp_serving.row_parallel(
-                h, layer["mlp_down"]["weight"],
-                layer["mlp_down"].get("bias"), axis=axis, seq_dim=0)
-            x = x + m[None]
-        x = fused_layer_norm(x, params["lnf_w"], params["lnf_b"])
-        last = jax.lax.dynamic_slice(
-            x, (jnp.int32(0), live - 1, jnp.int32(0)),
-            (1, 1, c.hidden_size))
-        logits = jnp.dot(last[0], emb.T)  # (1, V/tp)
-        tok = tp_serving.sample_tp(logits, key,
-                                   temperature=self.temperature,
-                                   axis=axis)[0]
-        return self._pool_out(ck, cv, ks, vs), tok, logits[0]
-
-    def _decode_step_body_tp(self, params, pool, tables, tokens, lengths,
-                             key):
-        c = self.config
-        axis, tp = tp_serving.TENSOR_AXIS, self.tp
-        B = self.block_size
-        h_loc, hkv_loc = c.num_heads // tp, c.kv_heads // tp
-        d = c.head_dim
-        params = tp_serving.take_shard(params)
-        lengths = lengths.astype(jnp.int32)
-        pos = jnp.maximum(lengths - 1, 0)
-        emb = params["embedding"]["weight"]
-        x = tp_serving.vocab_embed(emb, tokens[:, None], axis=axis)
-        ptab = params["pos_embedding"]
-        x = x + jnp.take(ptab, jnp.minimum(pos, ptab.shape[0] - 1),
-                         axis=0)[:, None]
-        tables = tables.astype(jnp.int32)
-        bid = jnp.take_along_axis(tables, (pos // B)[:, None],
-                                  axis=1)[:, 0]
-        bid = jnp.where(lengths > 0, bid, DEAD_BLOCK)
-        row = pos % B
-        ck, cv = pool["k"], pool["v"]
-        ks, vs = pool.get("k_scale"), pool.get("v_scale")
-        for i in range(c.num_layers):
-            layer = jax.tree.map(lambda a, i=i: a[i], params["layers"])
-            h_in = fused_layer_norm(x, layer["ln1_w"], layer["ln1_b"])
-            y = tp_serving.column_parallel(
-                h_in[:, 0], layer["qkv"]["weight"],
-                layer["qkv"].get("bias"), axis=axis, seq_dim=0)
-            q = y[:, :h_loc * d].reshape(-1, h_loc, d)
-            k_row = y[:, h_loc * d:(h_loc + hkv_loc) * d] \
-                .reshape(-1, hkv_loc, d)
-            v_row = y[:, (h_loc + hkv_loc) * d:].reshape(-1, hkv_loc, d)
-            if self.quantized:
-                kq, ksc = tp_serving.quant_rows_tp(k_row, (1, 2), axis)
-                vq, vsc = tp_serving.quant_rows_tp(v_row, (1, 2), axis)
-                ck = ck.at[i, bid, :, row].set(kq)
-                cv = cv.at[i, bid, :, row].set(vq)
-                ks = ks.at[i, bid, row].set(ksc)
-                vs = vs.at[i, bid, row].set(vsc)
-                k_scale, v_scale = ks[i], vs[i]
-            else:
-                ck = ck.at[i, bid, :, row].set(k_row.astype(ck.dtype))
-                cv = cv.at[i, bid, :, row].set(v_row.astype(cv.dtype))
-                k_scale = v_scale = None
-            # the paged decode-attention kernel, untouched: this shard
-            # owns a contiguous kv-head slice, so block tables, length
-            # masking, and the int8 scale indirection read identically
-            ctx = decode_attention(q, ck[i], cv[i], lengths,
-                                   block_tables=tables,
-                                   k_scale=k_scale, v_scale=v_scale)
-            out = tp_serving.row_parallel(
-                ctx.reshape(-1, h_loc * d), layer["attn_out"]["weight"],
-                layer["attn_out"].get("bias"), axis=axis, seq_dim=0)
-            x = x + out[:, None]
-            h2 = fused_layer_norm(x, layer["ln2_w"], layer["ln2_b"])
-            h = tp_serving.column_parallel(
-                h2[:, 0], layer["mlp_up"]["weight"],
-                layer["mlp_up"].get("bias"), axis=axis, seq_dim=0)
-            h = jax.nn.gelu(h, approximate=True)
-            m = tp_serving.row_parallel(
-                h, layer["mlp_down"]["weight"],
-                layer["mlp_down"].get("bias"), axis=axis, seq_dim=0)
-            x = x + m[:, None]
-        x = fused_layer_norm(x, params["lnf_w"], params["lnf_b"])
-        logits = jnp.dot(x[:, 0], emb.T)  # (S, V/tp)
-        toks = tp_serving.sample_tp(logits, key,
-                                    temperature=self.temperature,
-                                    axis=axis)
-        return self._pool_out(ck, cv, ks, vs), toks, logits
-
-    def _spec_step_body_tp(self, params, pool, tables, tokens, lengths,
-                           drafted, key):
-        c = self.config
-        axis, tp = tp_serving.TENSOR_AXIS, self.tp
-        B = self.block_size
-        S, K1 = tokens.shape
-        h_loc, hkv_loc = c.num_heads // tp, c.kv_heads // tp
-        group, d = h_loc // hkv_loc, c.head_dim
-        max_s = self.max_s
-        params = tp_serving.take_shard(params)
-        lengths = lengths.astype(jnp.int32)
-        base = jnp.maximum(lengths - 1, 0)
-        pos = base[:, None] + jnp.arange(K1, dtype=jnp.int32)[None, :]
-        emb = params["embedding"]["weight"]
-        x = tp_serving.vocab_embed(emb, tokens, axis=axis)  # (S, K1, H)
-        ptab = params["pos_embedding"]
-        x = x + jnp.take(ptab, jnp.minimum(pos, ptab.shape[0] - 1),
-                         axis=0)
-        tables = tables.astype(jnp.int32)
-        bid = jnp.take_along_axis(tables, pos // B, axis=1)
-        bid = jnp.where(lengths[:, None] > 0, bid, DEAD_BLOCK)
-        row = pos % B
-        scale = 1.0 / d ** 0.5
-        js = jnp.arange(max_s, dtype=jnp.int32)
-        mask = js[None, None, None, None, :] \
-            <= pos[:, None, None, :, None]
-        ck, cv = pool["k"], pool["v"]
-        ks, vs = pool.get("k_scale"), pool.get("v_scale")
-        for i in range(c.num_layers):
-            layer = jax.tree.map(lambda a, i=i: a[i], params["layers"])
-            h_in = fused_layer_norm(x, layer["ln1_w"], layer["ln1_b"])
-            y = tp_serving.column_parallel(
-                h_in, layer["qkv"]["weight"], layer["qkv"].get("bias"),
-                axis=axis, seq_dim=0)  # (S, K1, F/tp)
-            q = y[..., :h_loc * d]
-            k = y[..., h_loc * d:(h_loc + hkv_loc) * d] \
-                .reshape(S, K1, hkv_loc, d)
-            v = y[..., (h_loc + hkv_loc) * d:].reshape(S, K1, hkv_loc, d)
-            if self.quantized:
-                kq, ksc = tp_serving.quant_rows_tp(k, (2, 3), axis)
-                vq, vsc = tp_serving.quant_rows_tp(v, (2, 3), axis)
-                ck = ck.at[i, bid, :, row].set(kq)
-                cv = cv.at[i, bid, :, row].set(vq)
-                ks = ks.at[i, bid, row].set(ksc)
-                vs = vs.at[i, bid, row].set(vsc)
-                k_all = (ck[i][tables].astype(jnp.float32)
-                         * ks[i][tables][:, :, None, :, None])
-                v_all = (cv[i][tables].astype(jnp.float32)
-                         * vs[i][tables][:, :, None, :, None])
-            else:
-                ck = ck.at[i, bid, :, row].set(k.astype(ck.dtype))
-                cv = cv.at[i, bid, :, row].set(v.astype(cv.dtype))
-                k_all, v_all = ck[i][tables], cv[i][tables]
-            k_all = k_all.transpose(0, 2, 1, 3, 4) \
-                .reshape(S, hkv_loc, max_s, d)
-            v_all = v_all.transpose(0, 2, 1, 3, 4) \
-                .reshape(S, hkv_loc, max_s, d)
-            qg = q.reshape(S, K1, hkv_loc, group, d) \
-                .transpose(0, 2, 3, 1, 4)
-            s = jnp.einsum("bhgcd,bhsd->bhgcs", qg,
-                           k_all.astype(qg.dtype),
-                           preferred_element_type=jnp.float32) * scale
-            s = jnp.where(mask, s, NEG_INF)
-            p = jax.nn.softmax(s, axis=-1)
-            ctx = jnp.einsum("bhgcs,bhsd->bhgcd", p.astype(v_all.dtype),
-                             v_all)
-            ctx = ctx.transpose(0, 3, 1, 2, 4).reshape(S, K1,
-                                                       h_loc * d)
-            out = tp_serving.row_parallel(
-                ctx, layer["attn_out"]["weight"],
-                layer["attn_out"].get("bias"), axis=axis, seq_dim=0)
-            x = x + out
-            h2 = fused_layer_norm(x, layer["ln2_w"], layer["ln2_b"])
-            h = tp_serving.column_parallel(
-                h2, layer["mlp_up"]["weight"],
-                layer["mlp_up"].get("bias"), axis=axis, seq_dim=0)
-            h = jax.nn.gelu(h, approximate=True)
-            m = tp_serving.row_parallel(
-                h, layer["mlp_down"]["weight"],
-                layer["mlp_down"].get("bias"), axis=axis, seq_dim=0)
-            x = x + m
-        x = fused_layer_norm(x, params["lnf_w"], params["lnf_b"])
-        logits = jnp.dot(x, emb.T)  # (S, K1, V/tp)
-        a, nxt = tp_serving.verify_greedy_tp(logits, drafted, axis=axis)
-        return self._pool_out(ck, cv, ks, vs), a, nxt
+        for i, kv in enumerate(zip(tks, tvs)):
+            sel_k, sel_v = (jnp.einsum("bln,bnhd->blhd", lvl.astype(t.dtype),
+                                       t) for t in kv)
+            pool = self._write_rows(pool, i, bid, row, sel_k, sel_v)
+        return pool, a, j_star, nxt
 
     # --- the serving loop ----------------------------------------------------
 
@@ -1290,10 +982,9 @@ class ServingEngine:
             if is_tree_drafter(draft) and self.tp > 1:
                 raise ValueError(
                     f"serve(draft=<tree drafter>) is unsupported under "
-                    f"plan.tp={self.tp}: the tree-verify step has no "
-                    f"sharded twin — serve tree drafts at tp=1, or use "
-                    f"a chain drafter (which verifies through the tp "
-                    f"spec step)")
+                    f"plan.tp={self.tp}: the tree-verify tail has no "
+                    f"psum-composed form — serve tree drafts at tp=1, or "
+                    f"use a chain drafter (which verifies under tp)")
             # eager, knob-naming validation: vocab/block_size/k/cache
             # bounds fail HERE, not as an XLA error three rounds in.
             # max_s rows suffice for the drafter: spec rounds only run

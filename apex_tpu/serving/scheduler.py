@@ -413,6 +413,12 @@ class Scheduler:
             victim = self._admit_order[-1] if self._admit_order else None
             if victim is None or (victim == requester
                                   and len(self._admit_order) == 1):
+                if (self.draft_owner is not None
+                        and self.draft_owner.pool_blocks() > 0):
+                    # the last stream standing never yields to its own
+                    # drafter: draft KV is scratch, rebuilt by replay
+                    self.draft_owner.reset()
+                    continue
                 raise RuntimeError(
                     f"cannot make room for {need} block(s): nothing to "
                     f"reclaim or preempt with {alloc.num_free} free of "

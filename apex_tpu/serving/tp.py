@@ -31,6 +31,10 @@ this module holds only what changes under ``tp >= 2``:
   ``(b, V)`` uniforms from the replicated key and slices its columns —
   the fused-sampling-tail fusion argument of arXiv:2502.17728 carried
   across the shard boundary).
+* **The layer-math seam** (:class:`ShardedMath`): all a step body sees
+  of the above — ``embed`` / ``qkv`` / ``attn_out`` / ``mlp`` /
+  ``unembed`` and the tails at local head counts, the twin of
+  ``serving.engine.ModelMath`` (tp = 1): each body is written once.
 * **Cross-shard int8 scales** (:func:`quant_rows_tp`): local amax,
   ``pmax`` over tp, THEN the scale floor — scales come out bitwise
   identical to the tp=1 pool's (max composes through the floor), so
@@ -45,6 +49,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from apex_tpu.models.gpt import shard_params_for_tp
 from apex_tpu.ops import collective_matmul as cm
 from apex_tpu.parallel import mesh as mesh_lib
 from apex_tpu.plan.parallel_plan import ParallelPlan, PlanError
@@ -152,6 +157,17 @@ def take_shard(params):
     inside ``shard_map`` under ``P('tp', ...)`` every leaf arrives as
     ``(1, ...)`` — this rank's slice at index 0."""
     return jax.tree.map(lambda a: a[0], params)
+
+
+def prepare_params(params, tp: int, config, mesh):
+    """tp == 1: passthrough. Under tp: the per-rank shards of the
+    replicated tree (:func:`~apex_tpu.models.gpt.shard_params_for_tp`:
+    a leading ``(tp,)`` axis a leaf), committed to ``mesh`` as ``P('tp')``."""
+    if tp == 1:
+        return params
+    sh = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("tp"))
+    return jax.tree.map(lambda a: jax.device_put(a, sh),
+                        shard_params_for_tp(params, tp, config))
 
 
 # --- vocab-parallel embedding -------------------------------------------------
@@ -303,3 +319,68 @@ def verify_greedy_tp(logits_local, drafted, *, axis=TENSOR_AXIS):
     cand = cand[..., None]
     a = accepted_prefix_len(cand == drafted_pad[..., None])
     return a[:, 0, 0], select_row(cand, a)[:, 0, 0]
+
+
+# --- the layer-math seam ------------------------------------------------------
+
+class ShardedMath:
+    """How one layer's linear algebra is done on a tp mesh — the
+    ``plan.tp >= 2`` twin of ``serving.engine.ModelMath``, for a step
+    body running INSIDE ``shard_map``: same operations, same shapes up
+    to LOCAL head counts ((…, s, heads/tp, d)) and a vocabulary SHARD
+    of logits, so the bodies never name an axis. ``overlap`` picks the
+    ring-decomposed collective matmuls (the serving engine: slots and
+    chunks are tp-divisible) or plain dot + psum (``DecodeEngine``:
+    batch and prompt lengths are not). Tree verification and fp8 rows
+    have no sharded form yet: a psum-composed ``verify_tree`` here and
+    ``(qmax, qdtype)`` on :func:`quant_rows_tp` are all they need."""
+
+    def __init__(self, config, *, overlap: bool, temperature: float = 0.0,
+                 axis=TENSOR_AXIS):
+        self.config, self.overlap = config, overlap
+        self.temperature, self.axis = temperature, axis
+
+    def shard(self, params):
+        return take_shard(params)
+
+    def _ring(self, fn, p, x):
+        # the ring chunks the first axis tp divides: the slot array
+        # (S, …) or the prefill chunk (1, C, …) — validate_tp saw to it
+        seq_dim = 0 if x.shape[0] % jax.lax.axis_size(self.axis) == 0 else 1
+        return fn(x, p["weight"], p.get("bias"), axis=self.axis,
+                  seq_dim=seq_dim, overlap=self.overlap)
+
+    def embed(self, params, tokens):
+        return vocab_embed(params["embedding"]["weight"], tokens,
+                           axis=self.axis)
+
+    def qkv(self, layer, h_in):
+        """The packed projection's local columns, sliced once:
+        q (…, s, h/tp, d), k/v (…, s, h_kv/tp, d)."""
+        c, tp = self.config, jax.lax.axis_size(self.axis)
+        y = self._ring(column_parallel, layer["qkv"], h_in)
+        h, h_kv, d = c.num_heads // tp, c.kv_heads // tp, c.head_dim
+        return tuple(a.reshape(*a.shape[:-1], -1, d) for a in jnp.split(
+            y, [h * d, (h + h_kv) * d], axis=-1))
+
+    def attn_out(self, layer, ctx):
+        return self._ring(row_parallel, layer["attn_out"],
+                          ctx.reshape(*ctx.shape[:-2], -1))
+
+    def mlp(self, layer, h):
+        h = self._ring(column_parallel, layer["mlp_up"], h)
+        return self._ring(row_parallel, layer["mlp_down"],
+                          jax.nn.gelu(h, approximate=True))
+
+    def unembed(self, params, x):
+        return jnp.dot(x, params["embedding"]["weight"].T)
+
+    def sample(self, logits, key):
+        return sample_tp(logits, key, temperature=self.temperature,
+                         axis=self.axis)
+
+    def verify(self, logits, drafted, key):
+        return verify_greedy_tp(logits, drafted, axis=self.axis)
+
+    def quant_rows(self, x, axes):
+        return quant_rows_tp(x, axes, self.axis)

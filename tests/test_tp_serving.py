@@ -192,6 +192,57 @@ class TestTPServingParity:
         assert alloc.num_live == 0
         assert alloc.num_free == eng.num_blocks - 1
 
+    @pytest.mark.parametrize("kv_dtype", [None, "int8"])
+    @pytest.mark.parametrize("program", ["prefill_chunk", "decode_step",
+                                         "spec_step"])
+    def test_one_body_one_dispatch_vs_tp1(self, tiny_tp, program,
+                                          kv_dtype):
+        """The seam's pin: each program with a tp form exists as ONE
+        body (no ``*_body_tp`` attribute on either engine — the same
+        body runs inside ``shard_map`` over the tp layer math), and one
+        dispatch of it at tp = 2 returns the tokens of tp = 1, on the
+        float pool and the int8 pool."""
+        model, params = tiny_tp
+        engines = {tp: _engine(model, tp=tp, kv_dtype=kv_dtype)
+                   for tp in (1, 2)}
+        for eng in (*engines.values(), DecodeEngine(model),
+                    DecodeEngine(model, plan=ParallelPlan(tp=2))):
+            twins = [n for n in dir(eng) if n.endswith("_body_tp")]
+            assert not twins, f"{type(eng).__name__} grew twins: {twins}"
+        S, nb = 4, engines[1].max_blocks_per_slot
+        # slots 0..2 live on distinct blocks, slot 3 dead (all writes
+        # must land in the dead block under both layer maths)
+        tables = np.zeros((S, nb), np.int32)
+        tables[:3] = 1 + np.arange(3 * nb).reshape(3, nb)
+        lengths = np.asarray([5, 9, 1, 0], np.int32)
+        got = {}
+        for tp, eng in engines.items():
+            rng = np.random.default_rng(5)  # the same operands for both
+            p, pool = eng._prepare_params(params), eng.init_pool()
+            # a warm prefix under every program: one prompt chunk per
+            # live slot through the engine's own prefill
+            for i in range(3):
+                pool, tok, _ = eng.prefill_chunk(
+                    p, pool, jnp.asarray(tables[i]),
+                    jnp.asarray(rng.integers(0, 96, 16), jnp.int32),
+                    jnp.int32(0), jnp.int32(lengths[i]), K)
+            if program == "prefill_chunk":
+                got[tp] = [int(tok)]
+            elif program == "decode_step":
+                _, toks, _ = eng.decode_step(
+                    p, pool, jnp.asarray(tables),
+                    jnp.asarray([3, 7, 11, 0], jnp.int32),
+                    jnp.asarray(lengths + (lengths > 0)), K)
+                got[tp] = np.asarray(toks)[:3].tolist()
+            else:
+                tokens = jnp.asarray(rng.integers(0, 96, (S, 3)), jnp.int32)
+                _, acc, nxt = eng.spec_step(
+                    p, pool, jnp.asarray(tables), tokens,
+                    jnp.asarray(lengths + (lengths > 0)), tokens[:, 1:], K)
+                got[tp] = (np.asarray(acc)[:3].tolist(),
+                           np.asarray(nxt)[:3].tolist())
+        assert got[2] == got[1]
+
     def test_spec_rounds_bitwise_vs_plain(self, tiny_tp):
         """Speculative serving under tp: greedy output token-identical
         to the plain tp engine AND to tp=1, spec cache pinned at 1."""

@@ -335,21 +335,53 @@ class TestDrafterPoolAccounting:
         drafter blocks (the scheduler calls evict_stream from
         _preempt): accounting stays exact, the resumed streams match
         the equally-pressured non-speculative baseline, and the ladder
-        degraded at least one round rather than stalling."""
+        degraded at least one round rather than stalling. The drafter
+        only takes FREE blocks (the ladder degrades first), so a victim
+        holds drafter blocks only if it drafted while the pool had room
+        and an older stream's growth then took the room away: short
+        prompts, long generations."""
         model, params = _model()
         mk = lambda n: ServingEngine(model, num_slots=3, block_size=16,  # noqa: E731
                                      prefill_chunk=16, num_blocks=n)
-        base = mk(8).serve(params, _requests(8), telemetry=False)
+        reqs = lambda: _requests(8, prompt_rng=(4, 16), newtok=(24, 48))  # noqa: E731
+        base = mk(10).serve(params, reqs(), telemetry=False)
         want = {r.rid: list(r.tokens) for r in base}
-        eng = mk(8)
+        eng = mk(10)
         draft = self._drafter()
-        out = eng.serve(params, _requests(8), telemetry=False, draft=draft)
+        sched = eng.make_scheduler()
+        held, preempt = [], sched._preempt
+
+        def witness(i, *a, **kw):
+            st = draft._streams.get(sched.slot_rid(i))
+            held.append(0 if st is None else len(st["block_ids"]))
+            return preempt(i, *a, **kw)
+        sched._preempt = witness
+        out = eng.serve(params, reqs(), telemetry=False, draft=draft,
+                        scheduler=sched)
         assert all(list(r.tokens) == want[r.rid] for r in out)
         assert draft.pool_blocks() == 0
         assert any(r.evictions > 0 for r in out), \
             "pool pressure never preempted a stream"
         assert eng.last_stats.spec_degraded > 0, \
             "the headroom ladder never ran"
+        assert any(held), \
+            "no preempted stream held live drafter blocks"
+        sched.allocator.check_accounting()
+
+    def test_last_stream_reclaims_its_drafter_blocks(self):
+        """A pool that fits the last in-flight stream but not the
+        stream AND its drafter: the scheduler takes the drafter's
+        scratch blocks back (``_make_room``) instead of refusing."""
+        model, params = _model()
+        mk = lambda: ServingEngine(model, num_slots=3, block_size=16,  # noqa: E731
+                                   prefill_chunk=16, num_blocks=7)
+        reqs = lambda: _requests(8, newtok=(12, 30))  # noqa: E731
+        want = {r.rid: list(r.tokens)
+                for r in mk().serve(params, reqs(), telemetry=False)}
+        draft = self._drafter()
+        out = mk().serve(params, reqs(), telemetry=False, draft=draft)
+        assert all(list(r.tokens) == want[r.rid] for r in out)
+        assert draft.peak_blocks > 0 and draft.pool_blocks() == 0
 
     def test_unbound_drafter_names_the_fix(self):
         draft = self._drafter()
