@@ -159,15 +159,15 @@ class HybridDecoderModel:
                           c.linear_key_dim, c.linear_value_dim)
         qkvz = jnp.dot(x, p["w_qkvz"])
         ba = jnp.dot(x, p["w_ba"], preferred_element_type=jnp.float32)
-        qkv, z = jnp.split(qkvz, [2 * hk * dk + hv * dv], axis=-1)
-        qkv = causal_conv_silu(qkv, p["conv_w"])
-        q, k, v = jnp.split(qkv, [hk * dk, 2 * hk * dk], axis=-1)
+        # q|k|v and z are read where the projection left them: no slice of qkvz
+        q, k, v = causal_conv_silu(qkvz, p["conv_w"], widths=(hk * dk, hk * dk, hv * dv),
+                                   impl=c.delta_impl)
         beta = jax.nn.sigmoid(ba[..., :hv])
         g = (-jnp.exp(p["A_log"].astype(jnp.float32))
              * jax.nn.softplus(ba[..., hv:] + p["dt_bias"].astype(jnp.float32)))
         o = gated_delta_rule(q.reshape(b, s, hk, dk), k.reshape(b, s, hk, dk),
                              v.reshape(b, s, hv, dv), g, beta, impl=c.delta_impl)
-        o = gated_rms_norm(o, z.reshape(b, s, hv, dv), p["norm_w"], c.rms_eps)
+        o = gated_rms_norm(o, qkvz, p["norm_w"], c.rms_eps, impl=c.delta_impl)
         return jnp.dot(o.reshape(b, s, hv * dv), p["w_o"])
 
     def _attention_mixer(self, p, x):

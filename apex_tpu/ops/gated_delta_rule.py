@@ -21,7 +21,16 @@ kernels refuse.
 
 Also here: :func:`causal_conv_silu` (the depthwise causal convolution that
 precedes the rule) and :func:`gated_rms_norm` (the head-wise RMSNorm times
-``silu(gate)`` that follows it).
+``silu(gate)`` that follows it). Both take ``impl`` as the rule does and
+read a fused projection in place. On a TPU (and in interpret mode where the
+tests force it) the kernels of ``ops/pallas/delta_mixer.py`` run —
+``conv_silu_fwd`` / ``conv_silu_bwd``, ``gated_norm_fwd`` / ``gated_norm_bwd``:
+one pass over the arrays in their own dtype, float32 in VMEM only — wherever
+:func:`conv_shapes_ok` / :func:`norm_shapes_ok` hold: channels and heads in
+whole lane blocks, rows in whole sublane tiles. Every other shape, and
+``impl="xla"``, takes the float32 compositions (:func:`_conv_xla`,
+:func:`_norm_xla`), which XLA differentiates piece by piece: the oracle of
+the tests.
 """
 
 from __future__ import annotations
@@ -33,15 +42,13 @@ import jax.numpy as jnp
 
 from apex_tpu.amp.lists import apply_op_rules
 from apex_tpu.ops import _backend
+from apex_tpu.ops.pallas import delta_mixer as _m
 from apex_tpu.ops.pallas import gated_delta_rule as _k
 
 _HI = jax.lax.Precision.HIGHEST
 
 
-def causal_conv_silu(x, w):
-    """Depthwise causal convolution over time, then SiLU. ``x`` (b, t, c);
-    ``w`` (taps, c) with the last tap on the current token. Float32
-    accumulation, output in ``x``'s dtype."""
+def _conv_xla(x, w):
     taps, t = w.shape[0], x.shape[1]
     pad = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
     y = sum(pad[:, j:j + t].astype(jnp.float32) * w[j].astype(jnp.float32)
@@ -49,13 +56,140 @@ def causal_conv_silu(x, w):
     return jax.nn.silu(y).astype(x.dtype)
 
 
-def gated_rms_norm(x, gate, weight, eps=1e-6):
-    """``rmsnorm(x) * weight * silu(gate)`` over the last axis (one head),
-    statistics in float32."""
+def _norm_xla(x, gate, weight, eps):
     x32 = x.astype(jnp.float32)
     y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
     y = y * weight.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
     return y.astype(x.dtype)
+
+
+# jitted: the layers of a model share one traced and lowered program a kernel
+_conv_fwd = jax.jit(_m.conv_silu_fwd, static_argnames=("start", "interpret"))
+_conv_bwd = jax.jit(_m.conv_silu_bwd, static_argnames=("start", "interpret"))
+_norm_fwd = jax.jit(_m.gated_norm_fwd, static_argnames=("start", "dim", "eps", "interpret"))
+_norm_bwd = jax.jit(_m.gated_norm_bwd, static_argnames=("start", "dim", "eps", "interpret"))
+
+
+def _pieces(w, widths):
+    """(first channel, float32 taps) of every piece."""
+    starts = [sum(widths[:i]) for i in range(len(widths))]
+    return [(s, w[:, s:s + n].astype(jnp.float32)) for s, n in zip(starts, widths)]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _conv_pallas(x, w, widths, interpret):
+    """The convolution on the kernels, a call a piece: every piece reads its
+    channels of ``x`` in place and is an array of its own."""
+    return tuple(_conv_fwd(x, wp, start=s, interpret=interpret) for s, wp in _pieces(w, widths))
+
+
+def _conv_pallas_fwd(x, w, widths, interpret):
+    return _conv_pallas(x, w, widths, interpret), (x, w)
+
+
+def _conv_pallas_bwd(widths, interpret, res, dys):
+    x, w = res
+    dxs, dws = zip(*(_conv_bwd(x, wp, dy, start=s, interpret=interpret)
+                     for (s, wp), dy in zip(_pieces(w, widths), dys)))
+    rest = jnp.zeros(x.shape[:2] + (x.shape[2] - sum(widths),), x.dtype)
+    dw = jnp.concatenate([jnp.sum(d, axis=(0, 2)) for d in dws], axis=1)
+    return jnp.concatenate(dxs + (rest,), axis=2), dw.astype(w.dtype)
+
+
+_conv_pallas.defvjp(_conv_pallas_fwd, _conv_pallas_bwd)
+
+
+def conv_shapes_ok(x, w, widths) -> bool:
+    """What the convolution kernels' blocks need: every piece in whole lane
+    blocks, rows in whole sublane tiles of the dtype (a time block is a
+    divisor of the length, so no tail is left over), the taps before the
+    current token inside the halo."""
+    return (all(n % _m.LANES == 0 for n in widths) and x.shape[1] % _m.sublanes(x.dtype) == 0
+            and w.shape[0] - 1 <= _m.HALO)
+
+
+def causal_conv_silu(x, w, *, widths=None, impl: str = "auto"):
+    """Depthwise causal convolution over time, then SiLU. ``x`` (b, t, c');
+    ``w`` (taps, c) with the last tap on the current token and ``c <= c'``:
+    the first ``c`` channels of ``x`` are convolved (a fused projection is
+    read in place). Float32 accumulation, output (b, t, c) in ``x``'s dtype —
+    or, with ``widths`` (which sum to ``c``), the tuple of its pieces.
+
+    ``impl``: ``auto`` | ``pallas`` | ``xla`` — the ``conv_silu_fwd`` /
+    ``conv_silu_bwd`` kernels (``x``, ``dy`` and ``dx`` cross HBM once a pass,
+    float32 in VMEM only), or the composition XLA differentiates piece by
+    piece."""
+    c = w.shape[1]
+    parts = tuple(widths) if widths is not None else (c,)
+    if _backend.choose_impl(impl, conv_shapes_ok(x, w, parts)) == "pallas":
+        ys = _conv_pallas(x, w, parts, _backend.interpret_mode())
+    else:
+        y = _conv_xla(x[..., :c], w)
+        ys = jnp.split(y, [sum(parts[:i]) for i in range(1, len(parts))], axis=-1)
+    return tuple(ys) if widths is not None else ys[0]
+
+
+def _rows(x, gate):
+    """``x`` (..., heads, dim) and ``gate`` (..., c) as rows, and the gate's
+    first channel."""
+    c = x.shape[-2] * x.shape[-1]
+    return x.reshape(-1, c), gate.reshape(-1, gate.shape[-1]), gate.shape[-1] - c
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _norm_pallas(x, gate, w, eps, interpret):
+    """``x`` (..., heads, dim); ``gate`` (..., c): its last ``heads dim``
+    channels gate; ``w`` (dim,)."""
+    o, z, start = _rows(x, gate)
+    y = _norm_fwd(o, z, w.astype(jnp.float32)[None], start=start, dim=x.shape[-1], eps=eps,
+                  interpret=interpret)
+    return y.reshape(x.shape)
+
+
+def _norm_pallas_fwd(x, gate, w, eps, interpret):
+    return _norm_pallas(x, gate, w, eps, interpret), (x, gate, w)
+
+
+def _norm_pallas_bwd(eps, interpret, res, dy):
+    x, gate, w = res
+    o, z, start = _rows(x, gate)
+    do, dz, dw = _norm_bwd(o, z, w.astype(jnp.float32)[None], dy.reshape(o.shape), start=start,
+                           dim=x.shape[-1], eps=eps, interpret=interpret)
+    # the other channels' zeros in the gate's own shape: XLA folds them into
+    # whatever reads the cotangent
+    dz = jnp.pad(dz.reshape(gate.shape[:-1] + (-1,)), ((0, 0),) * (gate.ndim - 1) + ((start, 0),))
+    return do.reshape(x.shape), dz, jnp.sum(dw, axis=(0, 1)).astype(w.dtype)
+
+
+_norm_pallas.defvjp(_norm_pallas_fwd, _norm_pallas_bwd)
+
+
+def norm_shapes_ok(x, gate) -> bool:
+    """What the gated norm's kernels need: a head in whole lane blocks and
+    whole heads a channel block (so the gate's first channel is on a block's
+    edge), rows in whole sublane tiles of the dtypes."""
+    heads, dim = x.shape[-2:]
+    rows = x.size // (heads * dim)
+    return (dim % _m.LANES == 0 and _m.NORM_BLOCK[1] % dim == 0
+            and (gate.shape[-1] - heads * dim) % dim == 0
+            and rows % max(_m.sublanes(x.dtype), _m.sublanes(gate.dtype)) == 0)
+
+
+def gated_rms_norm(x, gate, weight, eps=1e-6, *, impl: str = "auto"):
+    """``rmsnorm(x) * weight * silu(gate)`` over the last axis (one head),
+    statistics in float32. ``x`` (..., heads, dim); ``gate`` in ``x``'s shape,
+    or (..., c) with ``c >= heads dim``: the last ``heads dim`` channels of a
+    fused projection, read in place.
+
+    ``impl``: ``auto`` | ``pallas`` | ``xla`` — the ``gated_norm_fwd`` /
+    ``gated_norm_bwd`` kernels (one pass each, the statistics recomputed in
+    the backward), or the float32 composition."""
+    heads, dim = x.shape[-2:]
+    if gate.ndim == x.ndim:
+        gate = gate.reshape(gate.shape[:-2] + (heads * dim,))
+    if _backend.choose_impl(impl, norm_shapes_ok(x, gate)) == "pallas":
+        return _norm_pallas(x, gate, weight, float(eps), _backend.interpret_mode())
+    return _norm_xla(x, gate[..., gate.shape[-1] - heads * dim:].reshape(x.shape), weight, eps)
 
 
 def l2_normalize(x, eps=_k.EPS):

@@ -19,7 +19,8 @@ PALLAS_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file
 KERNEL_FILES = sorted(os.path.basename(p) for p in glob.glob(os.path.join(PALLAS_DIR, "*.py"))
                       if os.path.basename(p) != "__init__.py")
 # readers match by prefix: flash_fwd*, flash_bwd*, xentropy*, gdn_fwd*, gdn_bwd*,
-# moe_gmm*; the rest by name
+# moe_gmm*; the rest by name (conv_silu_*, gated_norm_*: the stages around the
+# rule, which no reader's part may match)
 EXPECTED = {
     "attention.py": {
         "flash_fwd", "flash_fwd_packed", "flash_fwd_bshd",
@@ -34,6 +35,7 @@ EXPECTED = {
     "sampling.py": {"fused_sample"},
     "verify.py": {"fused_verify", "fused_verify_tree"},
     "gated_delta_rule.py": {"gdn_fwd", "gdn_bwd"},
+    "delta_mixer.py": {"conv_silu_fwd", "conv_silu_bwd", "gated_norm_fwd", "gated_norm_bwd"},
     "grouped_matmul.py": {"moe_gmm", "moe_gmm_dx", "moe_gmm_dw"},
 }
 SCOPES = ("amp/fwd_bwd", "amp/unscale_check", "amp/apply_master", "fused_adam/update",
@@ -65,7 +67,7 @@ def test_every_pallas_call_has_a_literal_name(filename):
 def test_kernel_names_are_distinct_across_the_package():
     assert set(KERNEL_FILES) == set(EXPECTED)
     names = [n for f in KERNEL_FILES for n in literal_names(f)]
-    assert len(names) == 28 and len(set(names)) == 28
+    assert len(names) == 32 and len(set(names)) == 32
 
 
 def kernel_names(jaxpr):
@@ -92,6 +94,26 @@ def test_flash_equations_carry_their_names(layout, shape, names):
     q = jnp.ones(shape, jnp.float32)
     jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, q, q)
     assert kernel_names(jaxpr.jaxpr) == names
+
+
+def test_delta_mixer_equations_carry_their_names():
+    """One delta-rule mixer, forward and gradient: the convolution a call a
+    piece (q, k, v), the rule, the gated norm — and no name of the two stages
+    around the rule holds a part a reader matches (``gdn_fwd_ms`` and
+    ``gdn_bwd_ms`` keep meaning the rule)."""
+    from apex_tpu.models import HybridDecoderConfig, HybridDecoderModel
+
+    model = HybridDecoderModel(HybridDecoderConfig(
+        vocab_size=64, hidden_size=128, layer_types=("linear",), linear_key_heads=1,
+        linear_value_heads=2, delta_impl="pallas"))
+    p = jax.tree.map(lambda a: a[0], model.init(jax.random.PRNGKey(0))["layers"]["gdn"])
+    x = jnp.ones((1, 64, 128), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, x: model._delta_mixer(p, x).sum(), argnums=(0, 1)))(p, x)
+    assert kernel_names(jaxpr.jaxpr) == (
+        ["conv_silu_fwd"] * 3 + ["gdn_fwd", "gated_norm_fwd", "gated_norm_bwd", "gdn_bwd"]
+        + ["conv_silu_bwd"] * 3)
+    for name in EXPECTED["delta_mixer.py"]:
+        assert not any(part in name for part in ("flash", "xentropy", "gdn", "moe_gmm"))
 
 
 SPLIT = ["flash_bwd_packed_dq", "flash_bwd_packed_dkv"]

@@ -325,6 +325,30 @@ def _delta_rule_family():
     return build
 
 
+def _delta_mixer_stages_family():
+    """The two stages around the rule at ``q3next-train-8k``'s shape:
+    ``conv_silu_fwd`` / ``conv_silu_bwd`` over ``q|k|v`` and ``gated_norm_fwd``
+    / ``gated_norm_bwd`` a head of 128, both reading the fused projection
+    (2, 8192, 12288) in place, against the XLA compositions — values and the
+    gradients of the projection, the taps, ``o`` and the norm's weight."""
+    def build():
+        from apex_tpu.ops.gated_delta_rule import causal_conv_silu, gated_rms_norm
+        t, qk, vv = 8192, 2048, 4096
+        qkvz = jr.normal(_key(70), (B, t, 2 * qk + 2 * vv), jnp.bfloat16)
+        taps = jr.uniform(_key(71), (4, 2 * qk + vv), minval=-0.5, maxval=0.5).astype(jnp.bfloat16)
+        o = jr.normal(_key(72), (B, t, vv // D, D), jnp.bfloat16)
+        weight = (1.0 + 0.1 * jr.normal(_key(73), (D,))).astype(jnp.bfloat16)
+
+        def make(impl):
+            def stages(qkvz, taps, o, weight):
+                qkv = causal_conv_silu(qkvz, taps, widths=(qk, qk, vv), impl=impl)
+                y = gated_rms_norm(o, qkvz, weight, impl=impl)
+                return jnp.concatenate(qkv + (y.reshape(B, t, vv),), axis=-1)
+            return _fwd_and_grads(stages, (0, 1, 2, 3))
+        return make("pallas"), make("xla"), (qkvz, taps, o, weight)
+    return build
+
+
 def _delta_rule_drifted_family():
     """The kernels (``gdn_fwd`` / ``gdn_bwd``: operands, inverse and
     recurrence in VMEM) in
@@ -465,6 +489,7 @@ FAMILIES = (
     Family("gated delta rule gdn_fwd/gdn_bwd", _delta_rule_family()),
     Family("gated delta rule on drifted keys, against the recurrence",
            _delta_rule_drifted_family()),
+    Family("delta mixer stages conv_silu/gated_norm fwd/bwd", _delta_mixer_stages_family()),
     Family("dropless experts moe_gmm/dx/dw", _dropless_family()),
     Family("decode contiguous MHA", _decode_family(H)),
     Family("decode contiguous GQA group 4", _decode_family(2)),
