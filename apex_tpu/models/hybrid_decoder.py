@@ -1,14 +1,23 @@
 """A decoder whose layers take their kind from a pattern: gated delta-rule
-(linear-attention) mixers and gated softmax-attention mixers in any order,
-each followed by a dropless sparse-expert layer with a shared expert.
+(linear-attention) mixers and gated softmax-attention mixers, over all keys
+or over a sliding window, in any order, each followed by a dropless
+sparse-expert layer with a shared expert or by a dense SwiGLU layer.
 
 Pre-norm residual blocks (``x += mixer(norm(x)); x += experts(norm(x))``)
 with zero-centred RMSNorm (``x / rms(x) * (1 + w)``), no position table
 (the attention layers carry partial rotary embeddings, the delta-rule layers
 need none), no biases, an untied output head. ``layer_types`` names each
-layer ``"linear"`` or ``"full"``; parameters of one kind are stacked on a
-leading axis under ``layers/gdn``, ``layers/attn`` and (every layer)
-``layers/moe``. All linears are stored (in, out).
+layer ``"linear"``, ``"full"`` or ``"window"``, ``ffn_types`` its second
+half ``"moe"`` (the default everywhere) or ``"dense"``; parameters of one
+kind are stacked on a leading axis under ``layers/gdn``, ``layers/attn``
+(both attention kinds, in the order they come), ``layers/moe`` and
+``layers/dense``; a kind no layer has has no group. All linears are stored
+(in, out). Switches for the blocks of other published decoders: plain
+RMSNorm (``zero_centered_norm=False``: ``x / rms(x) * w``), a second norm on
+each half's output before it is added (``sandwich_norms``:
+``layers/norm1_post``, ``norm2_post``), the embedding scaled
+(``embed_scale``), a sigmoid router with a selection bias, and a shared
+expert without its gate (``shared_gate=False``).
 
 * ``"linear"`` — fused ``q|k|v|z`` and ``b|a`` projections, causal depthwise
   convolution + SiLU on ``q|k|v``, :func:`ops.gated_delta_rule.gated_delta_rule`
@@ -18,8 +27,16 @@ leading axis under ``layers/gdn``, ``layers/attn`` and (every layer)
   the first ``rotary_dim`` features, causal flash attention in the (batch,
   seq, heads, head_dim) layout with grouped kv heads, ``sigmoid(gate)`` on
   the context, output projection.
+* ``"window"`` — the same mixer, a query seeing its last ``window`` keys
+  (``flash_attention(window=)``) and rotated over ``window_rotary_dim``
+  features (``rotary_dim`` is the ``"full"`` layers'; 0 rotates nothing).
 * experts — :func:`transformer.moe.dropless_moe_layer` over the experts held
-  here (``experts_held``), router at its full width.
+  here (``experts_held``), router at its full width. With
+  ``router_score="sigmoid"`` the step may carry a selection bias, state that
+  is no parameter: ``loss_fn(..., router_bias=b)`` routes with it, the aux
+  dict returns the step's ``router_counts`` and
+  :func:`transformer.moe.router_bias_update` moves it
+  (:meth:`HybridDecoderModel.init_router_bias` starts it).
 
 ``loss_fn`` has ``GPTModel.loss_fn``'s signature, so
 ``amp.scaled_value_and_grad`` and the trainers take either model.
@@ -41,7 +58,9 @@ from apex_tpu.ops.gated_delta_rule import (causal_conv_silu, gated_delta_rule,
                                            gated_rms_norm)
 from apex_tpu.ops.rotary import apply_partial_rotary
 from apex_tpu.transformer import tensor_parallel as tp_lib
-from apex_tpu.transformer.moe import dropless_moe_layer
+from apex_tpu.transformer.moe import dropless_moe_layer, silu_gate
+
+ATTENTION_KINDS = ("full", "window")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,6 +74,9 @@ class HybridDecoderConfig:
     head_dim: int = 256
     rotary_dim: int = 64
     rope_theta: float = 1e7
+    # "window" layers: keys a query sees, rotary features (None: rotary_dim)
+    window: Optional[int] = None
+    window_rotary_dim: Optional[int] = None
     # gated delta-rule layers
     linear_key_heads: int = 16
     linear_value_heads: int = 32
@@ -69,7 +91,16 @@ class HybridDecoderConfig:
     shared_ffn: int = 512
     normalize_topk: bool = True
     aux_coeff: float = 1e-3
+    router_score: str = "softmax"
+    route_scale: float = 1.0
+    shared_gate: bool = True
+    # second half of each layer, "moe" | "dense"; None: experts in every layer
+    ffn_types: Optional[Tuple[str, ...]] = None
+    dense_ffn: int = 0
     rms_eps: float = 1e-6
+    zero_centered_norm: bool = True
+    sandwich_norms: bool = False
+    embed_scale: float = 1.0
     # recompute every block's two halves (mixer, experts) in the backward pass
     remat: bool = False
     attention_impl: str = "auto"
@@ -78,9 +109,16 @@ class HybridDecoderConfig:
     dtype: Any = jnp.float32
 
     def __post_init__(self):
-        bad = set(self.layer_types) - {"linear", "full"}
+        bad = set(self.layer_types) - {"linear", *ATTENTION_KINDS}
         if bad or not self.layer_types:
-            raise ValueError(f"layer_types holds 'linear' and 'full', got {self.layer_types!r}")
+            raise ValueError("layer_types holds 'linear', 'full' and 'window', "
+                             f"got {self.layer_types!r}")
+        if "window" in self.layer_types and not self.window:
+            raise ValueError("a 'window' layer needs window=")
+        if set(self.ffn) - {"moe", "dense"} or len(self.ffn) != len(self.layer_types):
+            raise ValueError(f"ffn_types names every layer 'moe' or 'dense', got {self.ffn_types!r}")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_score is 'softmax' or 'sigmoid', got {self.router_score!r}")
         if self.num_heads % self.num_kv_heads or self.linear_value_heads % self.linear_key_heads:
             raise ValueError("query heads must be a multiple of kv heads, and value heads "
                              "of key heads")
@@ -89,11 +127,16 @@ class HybridDecoderConfig:
     def held(self) -> Tuple[int, int]:
         return self.experts_held or (0, self.router_experts)
 
+    @property
+    def ffn(self) -> Tuple[str, ...]:
+        return self.ffn_types or ("moe",) * len(self.layer_types)
 
-def _norm(x, w, eps):
+
+def _norm(x, w, eps, zero_centered=True):
     x32 = x.astype(jnp.float32)
     y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
-    return (y * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
+    w = w.astype(jnp.float32)
+    return (y * (1.0 + w if zero_centered else w)).astype(x.dtype)
 
 
 class HybridDecoderModel:
@@ -102,12 +145,18 @@ class HybridDecoderModel:
     def __init__(self, config: HybridDecoderConfig):
         self.config = config
 
+    def _norm(self, x, w):
+        c = self.config
+        return _norm(x, w, c.rms_eps, c.zero_centered_norm)
+
     def init(self, key):
         """Random parameters (normal 0.02; residual projections scaled by
         1/sqrt(2 L); decay ``A ~ U(1, 16)``, ``dt ~ logU(1e-3, 1e-1)``)."""
         c = self.config
         H, L = c.hidden_size, len(c.layer_types)
-        Lg, La = c.layer_types.count("linear"), c.layer_types.count("full")
+        Lg = c.layer_types.count("linear")
+        La = L - Lg
+        Lm, Ld = c.ffn.count("moe"), c.ffn.count("dense")
         qk, vv = c.linear_key_heads * c.linear_key_dim, c.linear_value_heads * c.linear_value_dim
         keys = iter(jax.random.split(key, 32))
         n = lambda shape, std=0.02: (std * jax.random.normal(  # noqa: E731
@@ -117,38 +166,58 @@ class HybridDecoderModel:
                                         jnp.log(1e-3), jnp.log(1e-1)))
         a = jax.random.uniform(next(keys), (Lg, c.linear_value_heads), jnp.float32, 1.0, 16.0)
         zeros = lambda shape: jnp.zeros(shape, c.dtype)  # noqa: E731
+        # a norm's weight at rest: 0 where it is added to 1, 1 where it is not
+        unit = zeros if c.zero_centered_norm else lambda shape: jnp.ones(shape, c.dtype)
         Eh = c.held[1]
+        layers = {
+            "norm1": unit((L, H)), "norm2": unit((L, H)),
+            "gdn": {
+                "w_qkvz": n((Lg, H, 2 * qk + 2 * vv)), "w_ba": n((Lg, H, 2 * c.linear_value_heads)),
+                "conv_w": jax.random.uniform(next(keys), (Lg, c.conv_kernel, 2 * qk + vv),
+                                             jnp.float32, -0.5, 0.5).astype(c.dtype),
+                "A_log": jnp.log(a), "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "norm_w": jnp.ones((Lg, c.linear_value_dim), c.dtype),
+                "w_o": n((Lg, vv, H), res),
+            },
+            "attn": {
+                "w_q": n((La, H, 2 * c.num_heads * c.head_dim)),
+                "w_k": n((La, H, c.num_kv_heads * c.head_dim)),
+                "w_v": n((La, H, c.num_kv_heads * c.head_dim)),
+                "q_norm": unit((La, c.head_dim)), "k_norm": unit((La, c.head_dim)),
+                "w_o": n((La, c.num_heads * c.head_dim, H), res),
+            },
+            "moe": {
+                "router": n((Lm, H, c.router_experts)),
+                "w_gate_up": n((Lm, Eh, H, 2 * c.expert_ffn)),
+                "w_down": n((Lm, Eh, c.expert_ffn, H), res),
+                "shared_gate_up": n((Lm, H, 2 * c.shared_ffn)),
+                "shared_down": n((Lm, c.shared_ffn, H), res),
+                "shared_mix": n((Lm, H)),
+            },
+            "dense": {
+                "w_gate_up": n((Ld, H, 2 * c.dense_ffn)),
+                "w_down": n((Ld, c.dense_ffn, H), res),
+            },
+        }
+        if not c.shared_gate:
+            del layers["moe"]["shared_mix"]
+        if c.sandwich_norms:
+            layers.update(norm1_post=unit((L, H)), norm2_post=unit((L, H)))
+        for group, count in (("gdn", Lg), ("attn", La), ("moe", Lm), ("dense", Ld)):
+            if not count:
+                del layers[group]
         return {
             "embedding": {"weight": n((c.vocab_size, H))},
             "head": {"weight": n((c.vocab_size, H))},
-            "norm_f": zeros((H,)),
-            "layers": {
-                "norm1": zeros((L, H)), "norm2": zeros((L, H)),
-                "gdn": {
-                    "w_qkvz": n((Lg, H, 2 * qk + 2 * vv)), "w_ba": n((Lg, H, 2 * c.linear_value_heads)),
-                    "conv_w": jax.random.uniform(next(keys), (Lg, c.conv_kernel, 2 * qk + vv),
-                                                 jnp.float32, -0.5, 0.5).astype(c.dtype),
-                    "A_log": jnp.log(a), "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
-                    "norm_w": jnp.ones((Lg, c.linear_value_dim), c.dtype),
-                    "w_o": n((Lg, vv, H), res),
-                },
-                "attn": {
-                    "w_q": n((La, H, 2 * c.num_heads * c.head_dim)),
-                    "w_k": n((La, H, c.num_kv_heads * c.head_dim)),
-                    "w_v": n((La, H, c.num_kv_heads * c.head_dim)),
-                    "q_norm": zeros((La, c.head_dim)), "k_norm": zeros((La, c.head_dim)),
-                    "w_o": n((La, c.num_heads * c.head_dim, H), res),
-                },
-                "moe": {
-                    "router": n((L, H, c.router_experts)),
-                    "w_gate_up": n((L, Eh, H, 2 * c.expert_ffn)),
-                    "w_down": n((L, Eh, c.expert_ffn, H), res),
-                    "shared_gate_up": n((L, H, 2 * c.shared_ffn)),
-                    "shared_down": n((L, c.shared_ffn, H), res),
-                    "shared_mix": n((L, H)),
-                },
-            },
+            "norm_f": unit((H,)),
+            "layers": layers,
         }
+
+    def init_router_bias(self):
+        """The selection bias of every expert layer's router at rest: state
+        of a training step, beside its parameters and not among them."""
+        c = self.config
+        return jnp.zeros((c.ffn.count("moe"), c.router_experts), jnp.float32)
 
     # --- mixers ---------------------------------------------------------------
 
@@ -170,66 +239,106 @@ class HybridDecoderModel:
         o = gated_rms_norm(o, qkvz, p["norm_w"], c.rms_eps, impl=c.delta_impl)
         return jnp.dot(o.reshape(b, s, hv * dv), p["w_o"])
 
-    def _attention_mixer(self, p, x):
+    def _attention_mixer(self, p, x, kind="full"):
         c = self.config
         b, s, _ = x.shape
         nh, nkv, dh = c.num_heads, c.num_kv_heads, c.head_dim
+        banded = kind == "window"
+        rot = c.rotary_dim if not banded or c.window_rotary_dim is None else c.window_rotary_dim
         qg = jnp.dot(x, p["w_q"]).reshape(b, s, nh, 2 * dh)
         q, gate = qg[..., :dh], qg[..., dh:]
         k = jnp.dot(x, p["w_k"]).reshape(b, s, nkv, dh)
         v = jnp.dot(x, p["w_v"]).reshape(b, s, nkv, dh)
-        q = apply_partial_rotary(_norm(q, p["q_norm"], c.rms_eps), c.rotary_dim, c.rope_theta)
-        k = apply_partial_rotary(_norm(k, p["k_norm"], c.rms_eps), c.rotary_dim, c.rope_theta)
+
+        def placed(x, w):                          # per-head norm, then its position
+            x = self._norm(x, w)
+            return apply_partial_rotary(x, rot, c.rope_theta) if rot else x
+
+        q, k = placed(q, p["q_norm"]), placed(k, p["k_norm"])
         ctx = flash_attention(q, k, v, causal=True, scale=dh ** -0.5, layout="bshd",
-                              impl=c.attention_impl)
+                              impl=c.attention_impl, window=c.window if banded else None)
         ctx = ctx * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(ctx.dtype)
         return jnp.dot(ctx.reshape(b, s, nh * dh), p["w_o"])
 
-    def _experts(self, p, x):
+    def _experts(self, p, x, router_bias=None):
         c = self.config
         return dropless_moe_layer(
             p, x, top_k=c.top_k, experts_held=c.held, normalize_weights=c.normalize_topk,
-            impl=c.experts_impl)
+            impl=c.experts_impl, score=c.router_score, route_scale=c.route_scale,
+            router_bias=router_bias, shared_gate=c.shared_gate)
+
+    @staticmethod
+    def _dense(p, x):
+        return jnp.dot(silu_gate(jnp.dot(x, p["w_gate_up"])), p["w_down"])
 
     # --- the stack ------------------------------------------------------------
 
-    def hidden_states_with_aux(self, params, tokens, key=None):
+    def hidden_states_with_aux(self, params, tokens, key=None, router_bias=None):
         """(final hidden states, aux): ``load_balance_loss`` (mean over the
-        layers), ``expert_load`` (layers, held) int32, ``dropped`` ()."""
+        expert layers), ``expert_load`` (expert layers, held) and
+        ``router_counts`` (expert layers, router width) int32, ``dropped`` ().
+        ``router_bias`` (expert layers, router width): the routers' selection
+        bias, where the step carries one."""
         del key                                    # no dropout in this block
         c = self.config
         layers = params["layers"]
         with monitor_spans.span("hybrid/embed"):
             x = params["embedding"]["weight"][tokens]
+            if c.embed_scale != 1.0:
+                x = x * jnp.asarray(c.embed_scale, x.dtype)
         keep_plan = jax.checkpoint_policies.save_only_these_names("moe_plan")
+        scopes = {"linear": "hybrid/gdn", "full": "hybrid/attn", "window": "hybrid/attn_win"}
 
-        def mixer_half(kind, p, w, x):
-            with monitor_spans.span("hybrid/gdn" if kind == "linear" else "hybrid/attn"):
-                mix = self._delta_mixer if kind == "linear" else self._attention_mixer
-                return x + mix(p, _norm(x, w, c.rms_eps))
+        def added(y, post):
+            """What a half adds to the stream: its output, normed again
+            where the block is a sandwich."""
+            return y if post is None else self._norm(y, post)
 
-        def expert_half(p, w, x):
+        def mixer_half(kind, p, w, post, x):
+            with monitor_spans.span(scopes[kind]):
+                h = self._norm(x, w)
+                y = (self._delta_mixer(p, h) if kind == "linear"
+                     else self._attention_mixer(p, h, kind))
+                return x + added(y, post)
+
+        def expert_half(p, w, post, bias, x):
             with monitor_spans.span("hybrid/moe"):
-                y, aux = self._experts(p, _norm(x, w, c.rms_eps))
-                return x + y, aux
+                y, aux = self._experts(p, self._norm(x, w), bias)
+                return x + added(y, post), aux
 
-        seen = {"linear": 0, "full": 0}
-        lb, loads, dropped = 0.0, [], 0
-        for i, kind in enumerate(c.layer_types):
-            group = "gdn" if kind == "linear" else "attn"
-            p_mix = jax.tree.map(lambda a, j=seen[kind]: a[j], layers[group])
-            p_moe = jax.tree.map(lambda a, i=i: a[i], layers["moe"])
-            seen[kind] += 1
-            f = lambda p, w, x, kind=kind: mixer_half(kind, p, w, x)  # noqa: E731
-            x = (jax.checkpoint(f) if c.remat else f)(p_mix, layers["norm1"][i], x)
-            g = jax.checkpoint(expert_half, policy=keep_plan) if c.remat else expert_half
-            x, aux = g(p_moe, layers["norm2"][i], x)
+        def dense_half(p, w, post, x):
+            with monitor_spans.span("hybrid/dense"):
+                return x + added(self._dense(p, self._norm(x, w)), post)
+
+        wrap = jax.checkpoint if c.remat else (lambda f, **kw: f)
+        post1, post2 = layers.get("norm1_post"), layers.get("norm2_post")
+        seen = {"gdn": 0, "attn": 0, "moe": 0, "dense": 0}
+        lb, loads, counts, dropped = 0.0, [], [], 0
+
+        def take(group):
+            j = seen[group]
+            seen[group] += 1
+            return j, jax.tree.map(lambda a: a[j], layers[group])
+
+        for i, (kind, ffn) in enumerate(zip(c.layer_types, c.ffn)):
+            _, p_mix = take("gdn" if kind == "linear" else "attn")
+            j, p_ffn = take(ffn)
+            f = lambda p, w, post, x, kind=kind: mixer_half(kind, p, w, post, x)  # noqa: E731
+            x = wrap(f)(p_mix, layers["norm1"][i], None if post1 is None else post1[i], x)
+            post = None if post2 is None else post2[i]
+            if ffn == "dense":
+                x = wrap(dense_half)(p_ffn, layers["norm2"][i], post, x)
+                continue
+            bias = None if router_bias is None else router_bias[j]
+            x, aux = wrap(expert_half, policy=keep_plan)(p_ffn, layers["norm2"][i], post, bias, x)
             lb = lb + aux["load_balance_loss"]
             loads.append(aux["expert_load"])
+            counts.append(aux["router_counts"])
             dropped = dropped + aux["dropped"]
-        aux = {"load_balance_loss": lb / len(c.layer_types),
-               "expert_load": jnp.stack(loads), "dropped": dropped}
-        return _norm(x, params["norm_f"], c.rms_eps), aux
+        aux = {"load_balance_loss": lb / max(len(loads), 1),
+               "expert_load": jnp.stack(loads), "router_counts": jnp.stack(counts),
+               "dropped": dropped}
+        return self._norm(x, params["norm_f"]), aux
 
     def hidden_states(self, params, tokens, key=None):
         return self.hidden_states_with_aux(params, tokens, key)[0]
@@ -241,11 +350,12 @@ class HybridDecoderModel:
         return self.unembed(params, self.hidden_states(params, tokens, key))
 
     def loss_fn(self, params, tokens, targets, key=None, loss_mask=None,
-                return_aux=False):
+                return_aux=False, router_bias=None):
         """Mean next-token cross-entropy plus the load-balance term at
         ``aux_coeff``; ``return_aux=True`` also returns the aux dict (the
-        load counters a training step hands back beside the loss)."""
-        x, aux = self.hidden_states_with_aux(params, tokens, key)
+        load counters a training step hands back beside the loss, and the
+        ``router_counts`` that move a selection bias)."""
+        x, aux = self.hidden_states_with_aux(params, tokens, key, router_bias)
         with monitor_spans.span("hybrid/unembed_xent"):
             losses = tp_lib.vocab_parallel_cross_entropy(
                 self.unembed(params, x), targets, axis_name=None)

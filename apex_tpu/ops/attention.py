@@ -175,14 +175,16 @@ def flash_auto_crossover(head_dim: int) -> int:
     lanes lower the kernel's break-even)."""
     return 512 if head_dim >= 128 else 1024
 
-def masked_scores(q, k, scale, causal, kv_lens=None, bias=None):
+def masked_scores(q, k, scale, causal, kv_lens=None, bias=None, window=None):
     """fp32 scaled scores over (..., seq, head_dim) with the bottom-right-
     aligned causal mask (last ``sq`` query rows of an ``sk``-long context)
     and optional per-row valid kv lengths (padding). ``kv_lens`` requires
     the flattened 3D layout (rows, seq, d) with one length per row.
     ``bias`` (hb, sq, sk): additive score bias, row ``r`` reading bias row
     ``r % hb`` (same contract as the Pallas kernels) — added to the scaled
-    scores BEFORE the masks; requires the 3D layout."""
+    scores BEFORE the masks; requires the 3D layout. ``window`` (with
+    ``causal``): a query sees its last ``window`` keys only, itself among
+    them."""
     s = jnp.einsum("...qd,...kd->...qk", q, k).astype(jnp.float32) * scale
     sq, sk = s.shape[-2], s.shape[-1]
     if bias is not None:
@@ -195,7 +197,10 @@ def masked_scores(q, k, scale, causal, kv_lens=None, bias=None):
         s = (s.reshape(-1, hb, sq, sk)
              + bias.astype(jnp.float32)).reshape(s.shape)
     if causal:
-        mask = jnp.arange(sk)[None, :] <= jnp.arange(sq)[:, None] + (sk - sq)
+        q_pos = jnp.arange(sq)[:, None] + (sk - sq)
+        mask = jnp.arange(sk)[None, :] <= q_pos
+        if window is not None:
+            mask = mask & (jnp.arange(sk)[None, :] > q_pos - window)
         s = jnp.where(mask, s, _k.NEG_INF)
     if kv_lens is not None:
         if s.ndim != 3:
@@ -228,8 +233,9 @@ def _dropout_apply_dense(x, keep, rate):
 
 
 def _xla_attention(q, k, v, scale, causal, kv_lens=None,
-                   dropout_rate=0.0, dropout_seed=None, bias=None):
-    s = masked_scores(q, k, scale, causal, kv_lens, bias)
+                   dropout_rate=0.0, dropout_seed=None, bias=None,
+                   window=None):
+    s = masked_scores(q, k, scale, causal, kv_lens, bias, window)
     lse = jax.nn.logsumexp(s, axis=-1)
     p = jnp.exp(s - lse[..., None])
     if dropout_rate > 0.0:
@@ -292,7 +298,8 @@ def _flash_fwd(q, k, v, bias, kv_lens, dropout_seed, scale, causal,
 
 
 def _flash_bwd_impl(q, k, v, o, lse, do, kv_lens, scale, causal, use_pallas,
-                    dropout_rate=0.0, dropout_seed=None, bias=None):
+                    dropout_rate=0.0, dropout_seed=None, bias=None,
+                    window=None):
     """(dq, dk, dv, dbias) from saved (o, lse) — dbias is None when no bias
     rode the forward. With a *global* lse this is also the per-shard
     backward of distributed (ring) attention: p = exp(s − lse) and
@@ -308,9 +315,13 @@ def _flash_bwd_impl(q, k, v, o, lse, do, kv_lens, scale, causal, use_pallas,
     dS (bias enters S additively after the 1/√d scale). With a
     :class:`BucketedBias` the fourth output is the (num_buckets, heads)
     TABLE cotangent instead (in-kernel dtable on the Pallas path; gather
-    VJP on the materialized fallback)."""
+    VJP on the materialized fallback).
+
+    ``window``: the XLA composition only (the seq-major layout's oracle;
+    the flat kernels take no window)."""
     bucketed = isinstance(bias, BucketedBias)
     if use_pallas:
+        assert window is None
         out = _k.flash_bwd(
             q, k, v, o, lse, do, scale=scale, causal=causal, kv_lens=kv_lens,
             bias=None if bucketed else bias,
@@ -329,7 +340,7 @@ def _flash_bwd_impl(q, k, v, o, lse, do, kv_lens, scale, causal, use_pallas,
     vf = jnp.repeat(v, group, 0) if group > 1 else v
     bias_arr = (bias.materialize(q.shape[1], k.shape[1]) if bucketed
                 else bias)
-    s = masked_scores(q, kf, scale, causal, kv_lens, bias_arr)
+    s = masked_scores(q, kf, scale, causal, kv_lens, bias_arr, window)
     p = jnp.exp(s - lse[..., None])
     dof = do.astype(jnp.float32)
     if dropout_rate > 0.0:
@@ -439,11 +450,11 @@ def _from_bh(x, b, h):  # (b*h, s, d) -> (b, s, h, d)
     return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
 def _flash_core_bshd(q, k, v, bias, kv_lens, dropout_seed, scale, causal,
-                     use_pallas, dropout_rate):
+                     use_pallas, dropout_rate, window=None):
     o, _ = _flash_fwd_res_bshd(q, k, v, bias, kv_lens, dropout_seed, scale,
-                               causal, use_pallas, dropout_rate)
+                               causal, use_pallas, dropout_rate, window)
     return o
 
 
@@ -454,7 +465,7 @@ def _expand_lens_bh(kv_lens, h):
 
 
 def _flash_fwd_res_bshd(q, k, v, bias, kv_lens, dropout_seed, scale, causal,
-                        use_pallas, dropout_rate):
+                        use_pallas, dropout_rate, window=None):
     bucketed = isinstance(bias, BucketedBias)
     if use_pallas:
         # carrier residual, same rationale as _flash_fwd_res
@@ -463,7 +474,8 @@ def _flash_fwd_res_bshd(q, k, v, bias, kv_lens, dropout_seed, scale, causal,
             bias=None if bucketed else bias,
             rel_bias=bias.kernel_operands() if bucketed else None,
             full_lse=True, interpret=_backend.interpret_mode(),
-            dropout_rate=dropout_rate, dropout_seed=dropout_seed)
+            dropout_rate=dropout_rate, dropout_seed=dropout_seed,
+            window=window)
     else:
         b, h = q.shape[0], q.shape[2]
         group = h // k.shape[2]
@@ -479,22 +491,24 @@ def _flash_fwd_res_bshd(q, k, v, bias, kv_lens, dropout_seed, scale, causal,
                     else bias)
         o3, lse3 = _xla_attention(_to_bh(q), kf, vf, scale, causal,
                                   _expand_lens_bh(kv_lens, h),
-                                  dropout_rate, dropout_seed, bias_arr)
+                                  dropout_rate, dropout_seed, bias_arr,
+                                  window)
         o = _from_bh(o3, b, h)
         lse = lse3.reshape(b, h, -1)
     return o, (q, k, v, o, lse)
 
 
 def _flash_fwd_bshd(q, k, v, bias, kv_lens, dropout_seed, scale, causal,
-                    use_pallas, dropout_rate):
+                    use_pallas, dropout_rate, window=None):
     o, res = _flash_fwd_res_bshd(q, k, v, bias, kv_lens, dropout_seed,
-                                 scale, causal, use_pallas, dropout_rate)
+                                 scale, causal, use_pallas, dropout_rate,
+                                 window)
     return o, (res, bias, kv_lens, dropout_seed)
 
 
 def _flash_bwd_bshd_impl(q, k, v, o, lse, do, kv_lens, scale, causal,
                          use_pallas, dropout_rate=0.0, dropout_seed=None,
-                         bias=None):
+                         bias=None, window=None):
     """(dq, dk, dv, dbias) for the seq-major layout — the bshd twin of
     :func:`_flash_bwd_impl`, same raw-cotangent contract: dbias is the
     UNcast fp32 bucket-table grad (BucketedBias) / fp32 dbias array /
@@ -508,7 +522,8 @@ def _flash_bwd_bshd_impl(q, k, v, o, lse, do, kv_lens, scale, causal,
             kv_lens=kv_lens, bias=None if bucketed else bias,
             rel_bias=bias.kernel_operands() if bucketed else None,
             interpret=_backend.interpret_mode(),
-            dropout_rate=dropout_rate, dropout_seed=dropout_seed)
+            dropout_rate=dropout_rate, dropout_seed=dropout_seed,
+            window=window)
         dq, dk, dv = out[:3]
         dbias = None
         if bias is not None:
@@ -521,17 +536,18 @@ def _flash_bwd_bshd_impl(q, k, v, o, lse, do, kv_lens, scale, causal,
         _to_bh(q), _to_bh(k), _to_bh(v), _to_bh(o),
         lse.reshape(b * h, -1), _to_bh(do), _expand_lens_bh(kv_lens, h),
         scale, causal, use_pallas=False, dropout_rate=dropout_rate,
-        dropout_seed=dropout_seed, bias=bias)
+        dropout_seed=dropout_seed, bias=bias, window=window)
     return (_from_bh(dq3, b, h), _from_bh(dk3, b, h_kv),
             _from_bh(dv3, b, h_kv), dbias)
 
 
-def _flash_bwd_bshd(scale, causal, use_pallas, dropout_rate, res_pack, do):
+def _flash_bwd_bshd(scale, causal, use_pallas, dropout_rate, window,
+                    res_pack, do):
     res, bias, kv_lens, dropout_seed = res_pack
     q, k, v, o, lse = res
     dq, dk, dv, dbias = _flash_bwd_bshd_impl(
         q, k, v, o, lse, do, kv_lens, scale, causal, use_pallas,
-        dropout_rate, dropout_seed, bias)
+        dropout_rate, dropout_seed, bias, window)
     return (dq, dk, dv, _bias_cotangent(bias, dbias),
             _float0_like(kv_lens), _float0_like(dropout_seed))
 
@@ -657,7 +673,7 @@ def flash_attention(
     *, causal: bool = False, scale: Optional[float] = None,
     kv_lens: Optional[jax.Array] = None, bias: Optional[jax.Array] = None,
     impl: str = "auto", layout: str = "bhsd", dropout_rate: float = 0.0,
-    dropout_seed: Optional[jax.Array] = None,
+    dropout_seed: Optional[jax.Array] = None, window: Optional[int] = None,
 ) -> jax.Array:
     """Blockwise attention over (..., seq, head_dim) with any number of
     leading batch/head dims. No sequence-length cap (cf. fmha's 512).
@@ -730,8 +746,21 @@ def flash_attention(
     the sharing rows of dS, computed by a third, batch-innermost backward
     kernel — ~2 extra GEMM passes, paid only when bias is given).
     Composes with causal, kv_lens, dropout, GQA, and both layouts (with
-    ``layout='bshd'`` hb must divide h)."""
+    ``layout='bshd'`` hb must divide h).
+
+    ``window`` (static int; needs ``causal=True``, ``layout='bshd'`` and no
+    ``bias``): sliding-window attention — query ``i`` sees keys
+    ``i - window < j <= i`` (positions bottom-right aligned as for causal).
+    The kernels (named ``flash_fwd_bshd_win``, ``flash_bwd_bshd_win_dq`` /
+    ``_dkv``) walk only the blocks a q block's band touches: a tile wholly
+    outside the band is neither fetched nor computed, tiles cut by either
+    edge are masked. ``impl='xla'`` masks the materialised scores."""
     q, k, v = apply_op_rules("attention", q, k, v)
+    if window is not None and (not causal or layout != "bshd" or bias is not None
+                               or int(window) < 1):
+        raise ValueError(
+            f"window={window!r} needs causal=True, layout='bshd', no bias "
+            f"and window >= 1")
     if layout not in ("bhsd", "bshd"):
         raise ValueError(f"layout must be bhsd|bshd, got {layout!r}")
     if not 0.0 <= dropout_rate < 1.0:
@@ -787,7 +816,8 @@ def flash_attention(
             impl_ = "xla"
         use_pallas = _backend.choose_impl(impl_, ok) == "pallas"
         return _flash_core_bshd(q, k, v, bias, kv_lens, dropout_seed,
-                                s_scale, causal, use_pallas, dropout_rate)
+                                s_scale, causal, use_pallas, dropout_rate,
+                                None if window is None else int(window))
     d = q.shape[-1]
     if causal and q.shape[-2] > k.shape[-2]:
         # bottom-right-aligned causal with sq > sk gives the first

@@ -197,10 +197,83 @@ def _fit_block(n, pref):
     return exact_block(n, pref, 128) or n
 
 
+# --- the band of a windowed causal layer --------------------------------------
+#
+# ``window`` (static int, causal only): query at global position ``q_pos``
+# sees key ``k_pos`` iff ``q_pos - window < k_pos <= q_pos``. A banded call
+# walks, for each q block, only the kv blocks its band touches: the grid's
+# reduction axis is as long as the widest such run (``_band_walk``), step
+# ``jj`` of q block ``i`` is kv block ``_band_first(i) + jj``, and the index
+# maps clamp at the band's last block, so a step past it fetches nothing
+# (an unchanged block index issues no DMA) and computes nothing. The dkv
+# kernel walks the q blocks of each kv block the same way. ``window=None``
+# leaves every kernel and index map as it was.
+
+def _visible(q_pos, k_pos, window):
+    """Whether key ``k_pos`` is visible to query ``q_pos`` (global
+    positions, broadcastable int32): causal, and inside the window."""
+    keep = k_pos <= q_pos
+    if window is not None:
+        keep = jnp.logical_and(keep, k_pos > q_pos - window)
+    return keep
+
+
+def _most(a, b):
+    """max of two block numbers: Python's on ints (a grid's static length),
+    ``jnp.maximum`` on traced ones (a kernel's or an index map's)."""
+    return max(a, b) if isinstance(a, int) and isinstance(b, int) else jnp.maximum(a, b)
+
+
+def _least(a, b):
+    return min(a, b) if isinstance(a, int) and isinstance(b, int) else jnp.minimum(a, b)
+
+
+def _band_first(i, b_own, b_other, shift, reach):
+    """First block (of ``b_other`` positions) that the band of block ``i``
+    (of ``b_own`` positions) touches: the block holding position
+    ``i * b_own + shift - reach``, not below 0. For a q block's kv blocks
+    ``shift = off``, ``reach = window - 1``; for a kv block's q blocks
+    ``shift = -off``, ``reach = 0``."""
+    return _most(i * b_own + shift - reach, 0) // b_other
+
+
+def _band_last(i, b_own, b_other, shift, reach, n_other):
+    """Last block that the band of block ``i`` touches: the one holding
+    position ``(i + 1) * b_own - 1 + shift + reach``, not above the last.
+    For a q block's kv blocks ``shift = off``, ``reach = 0``; for a kv
+    block's q blocks ``shift = -off``, ``reach = window - 1``."""
+    return _least(((i + 1) * b_own - 1 + shift + reach) // b_other, n_other - 1)
+
+
+def _check_window(window, causal, bias, rel_bias):
+    if window is None:
+        return
+    if not causal or window < 1:
+        raise ValueError(f"window={window!r} needs causal=True and window >= 1")
+    if bias is not None or rel_bias is not None:
+        raise ValueError("a windowed call takes no score bias")
+
+
+def _band_walk(banded, n_own, b_own, b_other, n_other, shift, lo_reach, hi_reach):
+    """``(steps, block)`` of a call's reduction axis: how many steps it
+    takes and which block of the other axis step ``s`` of own block ``i``
+    reads. Unbanded: every block in order. Banded: the widest run any own
+    block's band touches (static), counted from the band's first block and
+    held at its last."""
+    if not banded:
+        return n_other, lambda i, s: s
+    first = functools.partial(_band_first, b_own=b_own, b_other=b_other, shift=shift,
+                              reach=lo_reach)
+    last = functools.partial(_band_last, b_own=b_own, b_other=b_other, shift=shift,
+                             reach=hi_reach, n_other=n_other)
+    steps = max(last(i) - first(i) + 1 for i in range(n_own))
+    return steps, lambda i, s: _least(first(i) + s, last(i))
+
+
 # --- forward ------------------------------------------------------------------
 
 def _fwd_kernel(*refs, scale, causal, bq, bk, nk, off, varlen, bshd=False,
-                rate=0.0, has_bias=False, rel=None):
+                rate=0.0, has_bias=False, rel=None, window=None):
     """``varlen`` is a STATIC specialization flag: without kv lengths the
     kernel carries no length operand, no per-block length select, and no
     dynamic predicate conjunct — the common (non-padded) call pays nothing.
@@ -222,6 +295,8 @@ def _fwd_kernel(*refs, scale, causal, bq, bk, nk, off, varlen, bshd=False,
     bucketed relative bias per tile from a (1, 128) table row + (2,) SMEM
     global offsets (see :func:`_rel_bias_block`) — no O(s²) bias operand
     exists anywhere.
+    ``window`` (static): the banded walk of the section above — ``nk`` is
+    then the band's run of kv blocks, not the sequence's.
     """
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
@@ -241,9 +316,11 @@ def _fwd_kernel(*refs, scale, causal, bq, bk, nk, off, varlen, bshd=False,
     o_ref, lse_ref, m_scr, l_scr, acc_scr = refs[n:]
     t = pl.program_id(0)  # q-head row (dropout mask key)
     i = pl.program_id(1)  # q block
-    j = pl.program_id(2)  # k block
+    jj = pl.program_id(2)  # step along the kv blocks
+    # k block: the step itself, or (banded) counted from the band's first
+    j = jj if window is None else _band_first(i, bq, bk, off, window - 1) + jj
 
-    @pl.when(j == 0)
+    @pl.when(jj == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -277,7 +354,7 @@ def _fwd_kernel(*refs, scale, causal, bq, bk, nk, off, varlen, bshd=False,
             cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         if causal:
             rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            s = jnp.where(cols <= rows + off, s, NEG_INF)
+            s = jnp.where(_visible(rows + off, cols, window), s, NEG_INF)
         if varlen:
             s = jnp.where(cols < kvlen, s, NEG_INF)
         m_prev = m_scr[:]
@@ -295,7 +372,7 @@ def _fwd_kernel(*refs, scale, causal, bq, bk, nk, off, varlen, bshd=False,
         )
         m_scr[:] = m_new
 
-    @pl.when(j == nk - 1)
+    @pl.when(jj == nk - 1)
     def _finish():
         l = jnp.maximum(l_scr[:], 1e-30)
         o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
@@ -952,7 +1029,8 @@ def flash_bwd_packed(qkv, h, h_kv, d, o, lse, do, *, scale, causal,
 
 def flash_fwd_bshd(q, k, v, *, scale, causal, kv_lens=None, bias=None,
                    rel_bias=None, bq=1024, bk=1024, full_lse=False,
-                   interpret=False, dropout_rate=0.0, dropout_seed=None):
+                   interpret=False, dropout_rate=0.0, dropout_seed=None,
+                   window=None):
     """Seq-major flash forward: q (b, sq, h, d); k/v (b, sk, h_kv, d).
 
     The (s, h·d)-minor layout is exactly what the QKV projection GEMMs
@@ -970,13 +1048,21 @@ def flash_fwd_bshd(q, k, v, *, scale, causal, kv_lens=None, bias=None,
 
     ``bias`` (hb, sq, sk) with hb | h: additive score bias, q-head row
     ``t = b·h + h_i`` reading bias row ``t % hb``. ``rel_bias``: the
-    bucketed triple (see :func:`flash_fwd`), table row ``t % hb``."""
+    bucketed triple (see :func:`flash_fwd`), table row ``t % hb``.
+
+    ``window`` (static int; causal, no bias): a query sees its last
+    ``window`` keys, itself among them. The call is then named
+    ``flash_fwd_bshd_win`` and walks each q block's band only."""
     b, sq, h, d = q.shape
     sk, h_kv = k.shape[1], k.shape[2]
     group = h // h_kv
     bq, bk = _bias_blocks(bias, bq, bk)
     bq, bk = _fit_block(sq, bq), _fit_block(sk, bk)
     nq, nk = _blocks(sq, bq), _blocks(sk, bk)
+    off = sk - sq
+    _check_window(window, causal, bias, rel_bias)
+    steps, kv_block = _band_walk(window is not None, nq, bq, bk, nk, off,
+                                 (window or 1) - 1, 0)
     varlen = kv_lens is not None
     hb = 0 if bias is None else bias.shape[0]
     rel, rel_static = (None, None) if rel_bias is None else (
@@ -990,10 +1076,10 @@ def flash_fwd_bshd(q, k, v, *, scale, causal, kv_lens=None, bias=None,
                      lambda t, i, j, h=h: (t // h, i, t % h)),
         pl.BlockSpec((1, bk, d),
                      lambda t, i, j, h=h, g=group:
-                     (t // h, j, (t % h) // g)),
+                     (t // h, kv_block(i, j), (t % h) // g)),
         pl.BlockSpec((1, bk, d),
                      lambda t, i, j, h=h, g=group:
-                     (t // h, j, (t % h) // g)),
+                     (t // h, kv_block(i, j), (t % h) // g)),
     ]
     tail_specs, tail_args = _tail_operands(
         kv_lens, b, dropout_rate, dropout_seed,
@@ -1005,11 +1091,12 @@ def flash_fwd_bshd(q, k, v, *, scale, causal, kv_lens=None, bias=None,
 
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, nk=nk, off=sk - sq, varlen=varlen,
+                          bq=bq, bk=bk, nk=steps, off=off, varlen=varlen,
                           bshd=True, rate=dropout_rate,
-                          has_bias=bias is not None, rel=rel_static),
-        name="flash_fwd_bshd",
-        grid=(b * h, nq, nk),
+                          has_bias=bias is not None, rel=rel_static,
+                          window=window),
+        name="flash_fwd_bshd" if window is None else "flash_fwd_bshd_win",
+        grid=(b * h, nq, steps),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, bq, d),
@@ -1043,7 +1130,8 @@ def _rd_row(ref, bshd):
 
 
 def _bwd_dq_kernel(*refs, scale, causal, bq, bk, nk, off, varlen,
-                   bshd=False, rate=0.0, has_bias=False, rel=None):
+                   bshd=False, rate=0.0, has_bias=False, rel=None,
+                   window=None):
     refs = list(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     n = 6
@@ -1062,9 +1150,11 @@ def _bwd_dq_kernel(*refs, scale, causal, bq, bk, nk, off, varlen,
     dq_ref, acc_scr = refs[n:]
     t = pl.program_id(0)
     i = pl.program_id(1)
-    j = pl.program_id(2)
+    jj = pl.program_id(2)
+    # banded: the step counts kv blocks from the band's first (_fwd_kernel)
+    j = jj if window is None else _band_first(i, bq, bk, off, window - 1) + jj
 
-    @pl.when(j == 0)
+    @pl.when(jj == 0)
     def _init():
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
@@ -1091,7 +1181,7 @@ def _bwd_dq_kernel(*refs, scale, causal, bq, bk, nk, off, varlen,
             cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         if causal:
             rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            s = jnp.where(cols <= rows + off, s, NEG_INF)
+            s = jnp.where(_visible(rows + off, cols, window), s, NEG_INF)
         if varlen:
             s = jnp.where(cols < kvlen, s, NEG_INF)
         p = jnp.exp(s - _rd_row(lse_ref, bshd)[:, 0:1])
@@ -1109,13 +1199,17 @@ def _bwd_dq_kernel(*refs, scale, causal, bq, bk, nk, off, varlen,
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(j == nk - 1)
+    @pl.when(jj == nk - 1)
     def _finish():
         dq_ref[0] = acc_scr[:].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(*refs, scale, causal, bq, bk, nq, off, varlen,
-                    bshd=False, rate=0.0, has_bias=False, rel=None):
+                    bshd=False, rate=0.0, has_bias=False, rel=None,
+                    window=None, nq_all=None):
+    """``window`` (static): the grid's inner axis is the run of q blocks
+    whose band touches kv block ``j`` (``nq`` steps of the ``nq_all`` q
+    blocks), counted from the first of them."""
     refs = list(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs[:6]
     n = 6
@@ -1134,14 +1228,18 @@ def _bwd_dkv_kernel(*refs, scale, causal, bq, bk, nq, off, varlen,
     dk_ref, dv_ref, dk_scr, dv_scr = refs[n:]
     t = pl.program_id(0)
     j = pl.program_id(1)  # k block (outer)
-    i = pl.program_id(2)  # q block (inner, accumulated)
+    ii = pl.program_id(2)  # step along the q blocks (inner, accumulated)
+    i = ii if window is None else _band_first(j, bk, bq, -off, 0) + ii
 
-    @pl.when(i == 0)
+    @pl.when(ii == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     run = (not causal) or ((i + 1) * bq - 1 + off >= j * bk)
+    if window is not None:
+        # a step past the band's last q block (the index maps held its block)
+        run = i <= _band_last(j, bk, bq, -off, window - 1, nq_all)
     if varlen:
         kvlen = kvlen_ref[0, 0, 0]
         run = jnp.logical_and(run, j * bk < kvlen)
@@ -1164,7 +1262,7 @@ def _bwd_dkv_kernel(*refs, scale, causal, bq, bk, nq, off, varlen,
             cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         if causal:
             rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            s = jnp.where(cols <= rows + off, s, NEG_INF)
+            s = jnp.where(_visible(rows + off, cols, window), s, NEG_INF)
         if varlen:
             s = jnp.where(cols < kvlen, s, NEG_INF)
         p = jnp.exp(s - _rd_row(lse_ref, bshd)[:, 0:1])  # (bq, bk)
@@ -1188,7 +1286,7 @@ def _bwd_dkv_kernel(*refs, scale, causal, bq, bk, nq, off, varlen,
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    @pl.when(i == nq - 1)
+    @pl.when(ii == nq - 1)
     def _finish():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -1600,20 +1698,28 @@ def flash_bwd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
 
 def flash_bwd_bshd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
                    bias=None, rel_bias=None, bq=1024, bk=1024,
-                   interpret=False, dropout_rate=0.0, dropout_seed=None):
+                   interpret=False, dropout_rate=0.0, dropout_seed=None,
+                   window=None):
     """Seq-major backward (cf. :func:`flash_fwd_bshd`): q/o/do
     (b, sq, h, d), k/v (b, sk, h_kv, d), lse (b, h, sq) or the
     (b, h, sq, LANES) carrier from ``flash_fwd_bshd(full_lse=True)``.
     Returns (dq (b, sq, h, d), dk/dv (b, sk, h_kv, d)); with ``bias``
     (hb, sq, sk), hb | h, a fourth output dbias (hb, sq, sk) fp32 (see
     :func:`flash_bwd`); with ``rel_bias`` (the bucketed triple) a fourth
-    output dtable (hb, 128) fp32 head-major (see :func:`flash_bwd`)."""
+    output dtable (hb, 128) fp32 head-major (see :func:`flash_bwd`).
+    ``window``: as :func:`flash_fwd_bshd`; the calls are then named
+    ``flash_bwd_bshd_win_dq`` / ``flash_bwd_bshd_win_dkv``."""
     b, sq, h, d = q.shape
     sk, h_kv = k.shape[1], k.shape[2]
     group = h // h_kv
     bq, bk = _bias_blocks(bias, bq, bk)
     bq, bk = _fit_block(sq, bq), _fit_block(sk, bk)
     nq, nk = _blocks(sq, bq), _blocks(sk, bk)
+    off = sk - sq
+    _check_window(window, causal, bias, rel_bias)
+    banded, reach = window is not None, (window or 1) - 1
+    k_steps, kv_block = _band_walk(banded, nq, bq, bk, nk, off, reach, 0)
+    q_steps, q_block = _band_walk(banded, nk, bk, bq, nq, -off, 0, reach)
     hb = 0 if bias is None else bias.shape[0]
     rel, rel_static = (None, None) if rel_bias is None else (
         rel_bias[:2], rel_bias[2])
@@ -1639,7 +1745,8 @@ def flash_bwd_bshd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
         return pl.BlockSpec((1, 1, bq, _LSE_LANES), index_map)
 
     qm = lambda t, i, j, h=h: (t // h, i, t % h)  # noqa: E731
-    km = lambda t, i, j, h=h, g=group: (t // h, j, (t % h) // g)  # noqa: E731
+    km = lambda t, i, j, h=h, g=group: (  # noqa: E731
+        t // h, kv_block(i, j), (t % h) // g)
     rm = lambda t, i, j, h=h: (t // h, t % h, i, 0)  # noqa: E731
     varlen = kv_lens is not None
     extra_specs, extra_args = _tail_operands(
@@ -1650,11 +1757,12 @@ def flash_bwd_bshd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, nk=nk, off=sk - sq, varlen=varlen,
+                          bq=bq, bk=bk, nk=k_steps, off=off, varlen=varlen,
                           bshd=True, rate=dropout_rate,
-                          has_bias=bias is not None, rel=rel_static),
-        name="flash_bwd_bshd_dq",
-        grid=(b * h, nq, nk),
+                          has_bias=bias is not None, rel=rel_static,
+                          window=window),
+        name="flash_bwd_bshd_dq" if window is None else "flash_bwd_bshd_win_dq",
+        grid=(b * h, nq, k_steps),
         in_specs=[q_spec(qm), kv_spec(km), kv_spec(km), q_spec(qm),
                   row_spec(rm), row_spec(rm)] + extra_specs,
         out_specs=q_spec(qm),
@@ -1667,9 +1775,9 @@ def flash_bwd_bshd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
         interpret=interpret,
     )(q3, k3, v3, do3, lse4, delta4, *extra_args)
 
-    qm2 = lambda t, j, i, h=h: (t // h, i, t % h)  # noqa: E731
+    qm2 = lambda t, j, i, h=h: (t // h, q_block(j, i), t % h)  # noqa: E731
     km2 = lambda t, j, i, h=h, g=group: (t // h, j, (t % h) // g)  # noqa: E731
-    rm2 = lambda t, j, i, h=h: (t // h, t % h, i, 0)  # noqa: E731
+    rm2 = lambda t, j, i, h=h: (t // h, t % h, q_block(j, i), 0)  # noqa: E731
     # grouped kv: per-q-head fp32 partials at q-head positions, summed per
     # kv group outside (same rationale as flash_bwd)
     dkv_dtypes = (jnp.float32, jnp.float32) if group > 1 else (k.dtype,
@@ -1683,11 +1791,12 @@ def flash_bwd_bshd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, nq=nq, off=sk - sq, varlen=varlen,
+                          bq=bq, bk=bk, nq=q_steps, off=off, varlen=varlen,
                           bshd=True, rate=dropout_rate,
-                          has_bias=bias is not None, rel=rel_static),
-        name="flash_bwd_bshd_dkv",
-        grid=(b * h, nk, nq),
+                          has_bias=bias is not None, rel=rel_static,
+                          window=window, nq_all=nq),
+        name="flash_bwd_bshd_dkv" if window is None else "flash_bwd_bshd_win_dkv",
+        grid=(b * h, nk, q_steps),
         in_specs=[q_spec(qm2), kv_spec(km2), kv_spec(km2), q_spec(qm2),
                   row_spec(rm2), row_spec(rm2)] + extra_specs2,
         out_specs=[kv_spec(dkm), kv_spec(dkm)],

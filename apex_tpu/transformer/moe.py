@@ -424,28 +424,54 @@ def moe_layer(
 # here is computed, however uneven the routing. Static shapes come from
 # sorting the assignments by expert into a row buffer in which each expert's
 # rows start on a tile boundary (``ops/pallas/grouped_matmul``). The rows are
-# computed a block of as many rows as there are tokens at a time, as many
-# blocks as the routing fills: one where a rank holds a small share of the
-# experts, ``top_k`` and one more where it holds them all. What a block costs
-# does not depend on how many there are.
+# computed a block at a time (``dropless_block_rows``: as many rows as there
+# are tokens where a rank holds a small share of the experts), as many
+# blocks as the routing fills. What a block costs does not depend on how
+# many there are.
 
-def route_topk(x, router, k, *, normalize=True):
+def route_topk(x, router, k, *, normalize=True, score="softmax", bias=None, scale=1.0):
     """Router at its full width: ``p = softmax_fp32(x @ router)``, the top
     ``k`` (ids (T, k) int32, weights (T, k) float32, renormalised to sum 1
     when ``normalize``), the Switch load-balance term ``E sum_e f_e P_e``
     (``f``: share of the T k assignments, ``P``: mean probability) and the
-    assignments to every expert (E,) int32."""
+    assignments to every expert (E,) int32.
+
+    ``score="sigmoid"``: every expert is scored alone, ``p = sigmoid``; the
+    renormalisation guards an all-zero row (``+ 1e-20``) and the term is 0
+    (such a router is balanced by ``bias``, not by a loss). ``bias`` (E,)
+    float32 enters the choice of the top ``k`` and not their weights, and
+    carries no gradient (:func:`router_bias_update` moves it). ``scale``
+    multiplies the weights last."""
     logits = jnp.dot(x, router.astype(x.dtype), preferred_element_type=jnp.float32)
-    p = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(p, k)
+    p = jax.nn.softmax(logits, axis=-1) if score == "softmax" else jax.nn.sigmoid(logits)
+    if bias is None:
+        top_p, top_e = jax.lax.top_k(p, k)
+    else:
+        _, top_e = jax.lax.top_k(p + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
+        top_p = jnp.take_along_axis(p, top_e, axis=-1)
     if normalize:
-        top_p = top_p / jnp.sum(top_p, -1, keepdims=True)
+        total = jnp.sum(top_p, -1, keepdims=True)
+        top_p = top_p / (total if score == "softmax" else total + 1e-20)
+    if scale != 1.0:
+        top_p = top_p * scale
     E = router.shape[-1]
     counts = jnp.sum(top_e[..., None] == jnp.arange(E, dtype=top_e.dtype),
                      axis=(0, 1), dtype=jnp.int32)
-    share = counts.astype(jnp.float32) / (x.shape[0] * k)
-    aux = E * jnp.sum(share * jnp.mean(p, axis=0))
+    aux = jnp.float32(0.0)
+    if score == "softmax":
+        share = counts.astype(jnp.float32) / (x.shape[0] * k)
+        aux = E * jnp.sum(share * jnp.mean(p, axis=0))
     return top_e.astype(jnp.int32), top_p, aux, counts
+
+
+def router_bias_update(bias, counts, rate):
+    """The balancing step of a router that carries a selection bias and no
+    auxiliary loss: ``b + rate * sign(mean(n) - n)`` over the step's
+    assignments ``n`` (..., E) to every expert — an expert under the mean is
+    made likelier to be chosen, one over it less. Pure; the training step
+    keeps ``bias`` as state beside its parameters."""
+    n = counts.astype(jnp.float32)
+    return bias + rate * jnp.sign(jnp.mean(n, axis=-1, keepdims=True) - n)
 
 
 def dropless_plan(top_e, counts, experts_held, block_rows, tile):
@@ -583,6 +609,13 @@ def _gmm_bwd(impl, res, dy):
 grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd)
 
 
+def silu_gate(h):
+    """A SwiGLU's hidden from its fused ``gate|up`` product (..., 2 F):
+    ``silu(gate) * up`` in float32, in ``h``'s dtype."""
+    gate, up = jnp.split(h, 2, axis=-1)
+    return (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(h.dtype)
+
+
 def _held_experts_block(x, weights, w_gate_up, w_down, plan, block, rows, impl):
     """What the rows ``[block * rows, (block + 1) * rows)`` of the plan add
     to every token: gather, gate/up product, SiLU gate, down product,
@@ -598,9 +631,7 @@ def _held_experts_block(x, weights, w_gate_up, w_down, plan, block, rows, impl):
     xs = _rows_from_tokens(x, row_token, row_valid, pos, sel)
     with monitor_spans.span("moe/experts"):
         h = grouped_matmul(xs, w_gate_up, tile_expert, n_used, impl)
-        gate, up = jnp.split(h, 2, axis=-1)
-        act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(h.dtype)
-        y = grouped_matmul(act, w_down, tile_expert, n_used, impl)
+        y = grouped_matmul(silu_gate(h), w_down, tile_expert, n_used, impl)
     return _tokens_from_rows(y, weights, row_token, row_assign, row_valid, pos, sel)
 
 
@@ -647,15 +678,31 @@ def _held_experts_bwd(rows, impl, res, dy):
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
+def dropless_block_rows(tokens, top_k, held, width):
+    """Rows the held experts compute at a time: what an even routing sends
+    here (``tokens * top_k * held / width`` assignments) with every held
+    expert's last tile padded, in whole multiples of the tokens, so that the
+    place where a further block starts stays clear of the expected load. A
+    block costs its gathers whether it is full or nearly empty; a load that
+    sits AT a block's end pays for a second block in some layers and steps
+    and not in others."""
+    expected = tokens * top_k * held // width + held * gk.TM
+    return max(1, -(-expected // tokens)) * tokens
+
+
 def dropless_moe_layer(params, x, *, top_k, experts_held=None,
-                       normalize_weights=True, impl="auto"):
-    """Sparse SwiGLU experts without token dropping, plus a sigmoid-gated
-    shared expert, over ``x`` (..., hidden).
+                       normalize_weights=True, impl="auto", score="softmax",
+                       route_scale=1.0, router_bias=None, shared_gate=True):
+    """Sparse SwiGLU experts without token dropping, plus a shared expert,
+    over ``x`` (..., hidden).
 
     ``params``: ``router`` (hidden, E) at the router's FULL width;
     ``w_gate_up`` (held, hidden, 2 F) and ``w_down`` (held, F, hidden) of the
     experts held here; ``shared_gate_up`` (hidden, 2 Fs), ``shared_down``
-    (Fs, hidden), ``shared_mix`` (hidden,). ``experts_held = (first, count)``
+    (Fs, hidden) and ``shared_mix`` (hidden,): the shared expert is gated by
+    ``sigmoid(x . shared_mix)`` unless ``shared_gate`` is False, when it is
+    added as it is and the leaf is not read. ``score``, ``route_scale`` and
+    ``router_bias`` (E,) are :func:`route_topk`'s. ``experts_held = (first, count)``
     says which of the router's experts these are (default: all). The layer
     routes every token over all E experts, computes the part of the result
     its own experts give and adds nothing for the absent ones — what one
@@ -664,8 +711,10 @@ def dropless_moe_layer(params, x, *, top_k, experts_held=None,
 
     Returns ``(y, aux)``: ``aux["load_balance_loss"]`` (over the full
     width), ``aux["expert_load"]`` (count,) int32 assignments to each expert
-    held, ``aux["dropped"]`` () int32 — local assignments that no row
-    computed, which this layer keeps at 0 by construction.
+    held, ``aux["router_counts"]`` (E,) int32 assignments to every expert of
+    the router's width (what :func:`router_bias_update` balances),
+    ``aux["dropped"]`` () int32 — local assignments that no row computed,
+    which this layer keeps at 0 by construction.
     """
     lead, H = x.shape[:-1], x.shape[-1]
     xt = x.reshape(-1, H)
@@ -678,8 +727,9 @@ def dropless_moe_layer(params, x, *, top_k, experts_held=None,
             f"expert matrices given and a router of width {E}")
     with monitor_spans.span("moe/route"):
         top_e, top_p, aux_loss, counts = route_topk(
-            xt, params["router"], top_k, normalize=normalize_weights)
-        rows = -(-T // gk.TM) * gk.TM
+            xt, params["router"], top_k, normalize=normalize_weights, score=score,
+            bias=router_bias, scale=route_scale)
+        rows = -(-dropless_block_rows(T, top_k, held[1], E) // gk.TM) * gk.TM
         # under jax.checkpoint a policy may keep the plan by this name, so
         # that the backward pass does not sort again
         plan = jax.tree.map(
@@ -687,14 +737,16 @@ def dropless_moe_layer(params, x, *, top_k, experts_held=None,
             dropless_plan(top_e, counts, held, rows, gk.TM))
     y = _held_experts(xt, top_p, params["w_gate_up"], params["w_down"], plan, rows, impl)
     with monitor_spans.span("moe/shared"):
-        gate, up = jnp.split(jnp.dot(xt, params["shared_gate_up"]), 2, axis=-1)
-        act = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(xt.dtype)
-        mix = jax.nn.sigmoid(jnp.dot(xt, params["shared_mix"],
-                                     preferred_element_type=jnp.float32))
-        y = y + (mix[:, None] * jnp.dot(act, params["shared_down"]).astype(jnp.float32)
-                 ).astype(xt.dtype)
+        act = silu_gate(jnp.dot(xt, params["shared_gate_up"]))
+        if shared_gate:
+            mix = jax.nn.sigmoid(jnp.dot(xt, params["shared_mix"],
+                                         preferred_element_type=jnp.float32))
+            y = y + (mix[:, None] * jnp.dot(act, params["shared_down"]).astype(jnp.float32)
+                     ).astype(xt.dtype)
+        else:
+            y = y + jnp.dot(act, params["shared_down"])
     load = jax.lax.dynamic_slice(counts, (held[0],), (held[1],))
     computed = jnp.sum(plan["row_valid"], dtype=jnp.int32)
-    aux = {"load_balance_loss": aux_loss, "expert_load": load,
+    aux = {"load_balance_loss": aux_loss, "expert_load": load, "router_counts": counts,
            "dropped": jnp.sum(load) - computed}
     return y.reshape(*lead, H), aux

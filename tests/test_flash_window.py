@@ -1,0 +1,139 @@
+"""Sliding-window flash attention (seq-major layout): the banded kernels in
+interpret mode against the XLA composition with an explicit mask, forward
+and backward, at windows smaller than, equal to and larger than a tile and
+than the sequence; the band's block arithmetic by hand; and the unbanded
+call left as it was."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from apex_tpu.ops.attention import flash_attention
+from apex_tpu.ops.pallas import attention as fa
+
+pytestmark = pytest.mark.pallas
+
+
+def _qkv(key, b, sq, sk, h, h_kv, d, dtype=jnp.float32):
+    kq, kk, kv, kd = jax.random.split(key, 4)
+    return (jax.random.normal(kq, (b, sq, h, d), dtype),
+            jax.random.normal(kk, (b, sk, h_kv, d), dtype),
+            jax.random.normal(kv, (b, sk, h_kv, d), dtype),
+            jax.random.normal(kd, (b, sq, h, d), dtype))
+
+
+def _dense(q, k, v, window):
+    """Plain softmax attention under the mask ``i - window < j <= i``."""
+    b, sq, h, d = q.shape
+    sk, group = k.shape[1], h // k.shape[2]
+    k, v = jnp.repeat(k, group, 2), jnp.repeat(v, group, 2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / d ** 0.5
+    i = jnp.arange(sq)[:, None] + (sk - sq)
+    j = jnp.arange(sk)[None, :]
+    keep = (j <= i) & (j > i - window)
+    p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+# blocks of 128 at a sequence of 512: windows under a tile, of a tile, across
+# tiles (aligned and not), of the sequence and beyond it
+@pytest.mark.parametrize("window", [1, 5, 128, 129, 200, 256, 384, 512, 4096])
+def test_banded_kernels_match_the_masked_oracle(window):
+    q, k, v, do = _qkv(jax.random.PRNGKey(window), 2, 512, 512, 4, 2, 128)
+
+    def run(impl, bq=128, bk=128):
+        if impl == "xla":
+            f = lambda q, k, v: flash_attention(  # noqa: E731
+                q, k, v, causal=True, layout="bshd", impl="xla", window=window)
+            o, pull = jax.vjp(f, q, k, v)
+            return (o, *pull(do))
+        o, lse = fa.flash_fwd_bshd(q, k, v, scale=128 ** -0.5, causal=True, bq=bq, bk=bk,
+                                   interpret=True, window=window)
+        return (o, *fa.flash_bwd_bshd(q, k, v, o, lse, do, scale=128 ** -0.5, causal=True,
+                                      bq=bq, bk=bk, interpret=True, window=window))
+
+    want = run("xla")
+    np.testing.assert_allclose(want[0], _dense(q, k, v, window), atol=2e-5)
+    for blocks in ((128, 128), (256, 128), (128, 256)):
+        for got, ref, name in zip(run("pallas", *blocks), want, ("o", "dq", "dk", "dv")):
+            np.testing.assert_allclose(got, ref, atol=5e-5, err_msg=f"{name} at blocks {blocks}")
+
+
+def test_public_entry_differentiates_through_the_banded_kernels():
+    """``flash_attention(window=)`` under ``jax.grad`` in bf16, a longer key
+    sequence than query sequence (positions bottom-right aligned)."""
+    q, k, v, do = _qkv(jax.random.PRNGKey(0), 1, 256, 512, 2, 1, 128, jnp.bfloat16)
+
+    def loss(impl):
+        return lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, layout="bshd", impl=impl, window=200).astype(jnp.float32)
+            * do.astype(jnp.float32))
+
+    got = jax.grad(loss("pallas"), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss("xla"), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g.astype(jnp.float32), w.astype(jnp.float32),
+                                   atol=0.06, rtol=0.05)
+    o = flash_attention(q, k, v, causal=True, layout="bshd", impl="pallas", window=200)
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    np.testing.assert_allclose(f32(o), _dense(f32(q), f32(k), f32(v), 200), atol=0.03)
+
+
+def test_band_walks_only_the_blocks_it_touches():
+    """At the cell's shape (8,192 positions, blocks of 1,024, window 2,048) a
+    q block walks 3 kv blocks of 8 and a kv block 3 q blocks; the index maps
+    hold the band's last block on a step past it."""
+    steps = lambda *a: fa._band_walk(True, *a)[0]  # noqa: E731
+    assert steps(8, 1024, 1024, 8, 0, 2047, 0) == 3
+    assert steps(8, 1024, 1024, 8, 0, 0, 2047) == 3
+    assert steps(16, 512, 512, 16, 0, 2047, 0) == 5
+    # a window of one block and two keys reaches a third block
+    assert steps(8, 1024, 1024, 8, 0, 1025, 0) == 3
+    assert steps(8, 1024, 1024, 8, 0, 1024, 0) == 2
+    assert fa._band_walk(False, 8, 1024, 1024, 8, 0, 0, 0)[0] == 8
+    held = fa._band_walk(True, 8, 1024, 1024, 8, 0, 2047, 0)[1]
+    assert [int(held(5, s)) for s in range(4)] == [3, 4, 5, 5]   # a step past the band
+    first = [int(fa._band_first(i, 1024, 1024, 0, 2047)) for i in range(8)]
+    last = [int(fa._band_last(i, 1024, 1024, 0, 0, 8)) for i in range(8)]
+    assert first == [0, 0, 0, 1, 2, 3, 4, 5] and last == list(range(8))
+    q_first = [int(fa._band_first(j, 1024, 1024, 0, 0)) for j in range(8)]
+    q_last = [int(fa._band_last(j, 1024, 1024, 0, 2047, 8)) for j in range(8)]
+    assert q_first == list(range(8)) and q_last == [2, 3, 4, 5, 6, 7, 7, 7]
+
+
+def test_the_grid_is_the_bands_and_the_names_say_so():
+    q, k, v, do = _qkv(jax.random.PRNGKey(1), 1, 1024, 1024, 2, 1, 128)
+    f = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=True, layout="bshd", impl="pallas", window=256)
+    text = str(jax.make_jaxpr(lambda *a: jax.vjp(f, *a)[1](do))(q, k, v))
+    for name in ("flash_fwd_bshd_win", "flash_bwd_bshd_win_dq", "flash_bwd_bshd_win_dkv"):
+        assert name in text
+    fwd = str(jax.make_jaxpr(lambda q, k, v: fa.flash_fwd_bshd(
+        q, k, v, scale=1.0, causal=True, bq=128, bk=128, interpret=True, window=256))(q, k, v))
+    assert "grid=(2, 8, 3)" in fwd.replace("Grid", "grid") or "(2, 8, 3)" in fwd
+
+
+def test_no_window_is_the_call_it_was():
+    """``window=None`` builds the kernels from the arguments they always
+    had: the same jaxpr as a call that never names the argument, under the
+    unbanded names."""
+    q, k, v, do = _qkv(jax.random.PRNGKey(2), 1, 256, 256, 2, 1, 128)
+
+    def text(**kw):
+        f = lambda q, k, v: flash_attention(  # noqa: E731
+            q, k, v, causal=True, layout="bshd", impl="pallas", **kw)
+        return str(jax.make_jaxpr(lambda *a: jax.vjp(f, *a)[1](do))(q, k, v))
+
+    assert text(window=None) == text()
+    assert "_win" not in text()
+    assert "flash_fwd_bshd" in text() and "flash_bwd_bshd_dq" in text()
+
+
+@pytest.mark.parametrize("kw", [dict(causal=False), dict(layout="bhsd"), dict(window=0),
+                                dict(bias=jnp.zeros((1, 128, 128)))])
+def test_window_refuses_what_it_cannot_mask(kw):
+    q = jnp.zeros((1, 128, 1, 128))
+    args = dict(causal=True, layout="bshd", window=64)
+    args.update(kw)
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, q, q, **args)

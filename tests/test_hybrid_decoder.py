@@ -92,7 +92,11 @@ def test_loss_fn_has_the_trainers_signature_and_init_the_programs_tree():
     assert loss.shape == () and np.isfinite(float(loss)) and float(masked) != float(loss)
     assert model.logits(p, tokens).shape == (1, 64, 256)
     with pytest.raises(ValueError, match="layer_types"):
+        HybridDecoderConfig(layer_types=("linear", "sparse"))
+    with pytest.raises(ValueError, match="window="):
         HybridDecoderConfig(layer_types=("linear", "window"))
+    with pytest.raises(ValueError, match="ffn_types"):
+        HybridDecoderConfig(ffn_types=("dense",))
 
 
 def test_o2_keeps_the_named_leaves_in_float32():

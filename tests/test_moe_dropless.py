@@ -1,7 +1,8 @@
 """The dropless expert layer against the plain reference: uneven routing,
 nothing dropped, the block-after-block path, and the share test — the
 partial results of all shares, the shared expert counted once, add up to the
-uncut layer's result."""
+uncut layer's result; and the second router: sigmoid scores under a
+selection bias, its weights, its update and its own share test."""
 import os
 import sys
 
@@ -15,6 +16,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from apex_tpu.transformer import moe  # noqa: E402
+from benchmarks.reference import afmoe_ref as A  # noqa: E402
 from benchmarks.reference import hybrid_ref as R  # noqa: E402
 
 H, F, E, K = 128, 128, 16, 4
@@ -50,9 +52,11 @@ def reference(w, x, first=0, count=E):
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
 @pytest.mark.parametrize("tokens", [(2, 96), (3, 100)])   # 192 rows a block, or 300 in 384
-def test_uneven_routing_matches_the_reference_and_drops_nothing(impl, tokens):
-    """All 16 experts held, so the routing fills top_k blocks and part of
-    one more: the loop over blocks runs, forward and backward."""
+def test_uneven_routing_matches_the_reference_and_drops_nothing(impl, tokens, monkeypatch):
+    """All 16 experts held and blocks of as many rows as tokens, so the
+    routing fills top_k blocks and part of one more: the loop over blocks
+    runs, forward and backward."""
+    monkeypatch.setattr(moe, "dropless_block_rows", lambda tokens, *_: tokens)
     x = jax.random.normal(jax.random.PRNGKey(7), tokens + (H,))
     # positive features, so that a column of the router decides: expert 5
     # gets nearly every token, expert 3 none
@@ -108,6 +112,28 @@ def test_shares_add_up_to_the_uncut_layer():
     assert np.concatenate(loads).sum() == K * x.shape[0]
 
 
+def test_block_rows_keep_clear_of_the_expected_load(monkeypatch):
+    """A small share of the experts: a block of as many rows as tokens where
+    the expected load with its tile padding fits one (32 of 512 at top-10:
+    10,240 + 4,096 of 16,384), two where it sits at the block's end (16 of
+    128 at top-8: 16,384 + 2,048), all of them where every expert is held;
+    the blocks' size changes no result."""
+    assert moe.dropless_block_rows(16384, 10, 32, 512) == 16384
+    assert moe.dropless_block_rows(16384, 8, 16, 128) == 2 * 16384
+    assert moe.dropless_block_rows(16384, 8, 8, 128) == 16384
+    assert moe.dropless_block_rows(192, K, E, E) == (K + 11) * 192    # 768 + 2,048 rows of padding
+    x = jax.random.normal(jax.random.PRNGKey(21), (192, H))
+    w = weights(seed=4)
+    with jax.default_matmul_precision("highest"):
+        whole, aux = moe.dropless_moe_layer(program(w), x, top_k=K, impl="xla")
+        for rows in (128, 192, 1024):
+            monkeypatch.setattr(moe, "dropless_block_rows", lambda *_, rows=rows: rows)
+            y, a = moe.dropless_moe_layer(program(w), x, top_k=K, impl="xla")
+            np.testing.assert_allclose(y, whole, atol=1e-5 * float(jnp.max(jnp.abs(whole))))
+            np.testing.assert_array_equal(a["expert_load"], aux["expert_load"])
+            assert int(a["dropped"]) == 0
+
+
 def test_experts_held_must_match_the_matrices_given():
     w = weights()
     with pytest.raises(ValueError, match="experts_held"):
@@ -127,3 +153,109 @@ def test_no_local_assignment_at_all_gives_the_shared_expert_alone():
             p, x, top_k=K, experts_held=(0, 4), impl=impl)[0]))(program(w, 0, 4))
         assert float(jnp.max(jnp.abs(g["w_gate_up"]))) == 0.0
         assert float(jnp.max(jnp.abs(g["w_down"]))) == 0.0
+
+
+# --- the sigmoid router with a selection bias ---------------------------------
+
+SIG = {"router_num_experts": E, "num_experts_per_tok": K, "route_norm": True,
+       "route_scale": 2.826, "experts_held": (0, E)}
+
+
+def test_bias_moves_the_selection_and_not_the_weights():
+    x = jax.random.normal(jax.random.PRNGKey(2), (64, H))
+    router = weights()["router"]
+    bias = jnp.zeros((E,)).at[7].set(10.0).at[2].set(-10.0)   # always 7, never 2
+    plain_e, plain_w, aux, _ = moe.route_topk(x, router, K, score="sigmoid", scale=2.826)
+    top_e, top_w, _, counts = moe.route_topk(x, router, K, score="sigmoid", bias=bias, scale=2.826)
+    assert float(aux) == 0.0                               # balanced by the bias, not a loss
+    assert int(counts[7]) == 64 and int(counts[2]) == 0 and int(counts.sum()) == 64 * K
+    s = jax.nn.sigmoid(jnp.dot(x, router))
+    chosen = jnp.take_along_axis(s, top_e, -1)
+    # the weights are the scores themselves, renormalised and scaled: no bias in them
+    np.testing.assert_allclose(top_w, chosen / chosen.sum(-1, keepdims=True) * 2.826, rtol=1e-6)
+    np.testing.assert_allclose(top_w.sum(-1), 2.826, rtol=1e-6)
+    raw = moe.route_topk(x, router, K, score="sigmoid", bias=bias, normalize=False)[1]
+    np.testing.assert_allclose(raw, chosen, rtol=1e-6)     # route_scale 1, no renormalisation
+    # a zero bias is no bias; the reference agrees on ids, weights and counts
+    zero = moe.route_topk(x, router, K, score="sigmoid", bias=jnp.zeros((E,)), scale=2.826)
+    np.testing.assert_array_equal(zero[0], plain_e)
+    np.testing.assert_allclose(zero[1], plain_w, rtol=1e-6)
+    ref_e, ref_w, ref_counts = A.route(x, router, bias, SIG, "float32")
+    np.testing.assert_array_equal(top_e, ref_e)
+    np.testing.assert_allclose(top_w, ref_w, rtol=1e-5)
+    np.testing.assert_array_equal(counts, ref_counts)
+    # no gradient reaches the bias; the router's is the weights' alone
+    g = jax.grad(lambda b: jnp.sum(moe.route_topk(x, router, K, score="sigmoid", bias=b)[1]))(bias)
+    assert float(jnp.max(jnp.abs(g))) == 0.0
+
+
+def test_bias_update_is_a_signed_step_toward_the_mean_load():
+    counts = jnp.asarray([[0, 4, 8, 4], [5, 5, 5, 5]], jnp.int32)
+    bias = jnp.asarray([[0.0, 0.5, 0.0, -0.5], [0.1, 0.2, 0.3, 0.4]])
+    got = moe.router_bias_update(bias, counts, 0.001)
+    np.testing.assert_allclose(got, [[0.001, 0.5, -0.001, -0.5], [0.1, 0.2, 0.3, 0.4]], atol=1e-7)
+    np.testing.assert_allclose(got, A.bias_update(bias, counts.astype(jnp.float32),
+                                                  {"load_balance_coeff": 0.001}), atol=1e-7)
+
+
+def sigmoid_program(w, first=0, count=E):
+    p = program(w, first, count)
+    del p["shared_mix"]                                    # the shared expert ungated
+    return p
+
+
+def sigmoid_layer(w, x, bias, first=0, count=E, impl="xla"):
+    return moe.dropless_moe_layer(sigmoid_program(w, first, count), x, top_k=K,
+                                  experts_held=(first, count), impl=impl, score="sigmoid",
+                                  route_scale=2.826, router_bias=bias, shared_gate=False)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_sigmoid_layer_matches_the_reference(impl):
+    x = jax.random.normal(jax.random.PRNGKey(12), (2, 96, H))
+    w = weights(seed=2)
+    bias = 0.05 * jax.random.normal(jax.random.PRNGKey(13), (E,))
+    flat = x.reshape(-1, H)
+    ref = lambda w, x: (A.expert_layer(w, bias, SIG, x, "float32")[0]  # noqa: E731
+                        + A.shared_expert(w, x, "float32"))
+    with jax.default_matmul_precision("highest"):
+        y, aux = sigmoid_layer(w, x, bias, impl=impl)
+        want = ref(w, flat)
+        np.testing.assert_allclose(y.reshape(-1, H), want, atol=2e-5 * float(jnp.max(jnp.abs(want))))
+        np.testing.assert_array_equal(aux["router_counts"],
+                                      A.route(flat, w["router"], bias, SIG, "float32")[2])
+        assert int(aux["dropped"]) == 0 and int(aux["expert_load"].sum()) == K * flat.shape[0]
+        r = jax.random.normal(jax.random.PRNGKey(14), want.shape)
+        gp = jax.grad(lambda p: jnp.sum(moe.dropless_moe_layer(
+            p, x, top_k=K, impl=impl, score="sigmoid", route_scale=2.826,
+            router_bias=bias, shared_gate=False)[0].reshape(-1, H) * r))(sigmoid_program(w))
+        gw = sigmoid_program(jax.grad(lambda w: jnp.sum(ref(w, flat) * r))(w))
+    for name in gp:
+        np.testing.assert_allclose(gp[name], gw[name], err_msg=name,
+                                   atol=3e-5 * float(jnp.max(jnp.abs(gw[name]))) + 1e-8)
+
+
+def test_eight_sigmoid_shares_add_up_to_the_uncut_layer():
+    """The cell's cut at a small size: eight shares of two experts each, the
+    router at its full width in every one and the same bias; what every share
+    computes alike (the shared expert) counted once, the routed parts add up
+    to the uncut reference's layer."""
+    x = jax.random.normal(jax.random.PRNGKey(15), (192, H))
+    w = weights(seed=3)
+    bias = 0.05 * jax.random.normal(jax.random.PRNGKey(16), (E,))
+    with jax.default_matmul_precision("highest"):
+        shared = A.shared_expert(w, x, "float32")
+        uncut = A.expert_layer(w, bias, SIG, x, "float32")[0] + shared
+        parts, ref_parts, loads = [], [], []
+        for first in range(0, E, 2):
+            y, aux = sigmoid_layer(w, x, bias, first, 2)
+            parts.append(y - shared)
+            lw = dict(w, **{n: w[n][first:first + 2] for n in ("w_gate", "w_up", "w_down")})
+            ref_parts.append(A.expert_layer(lw, bias, SIG, x, "float32", held=(first, 2))[0])
+            loads.append(np.asarray(aux["expert_load"]))
+            assert int(aux["dropped"]) == 0
+    assert len(parts) == 8
+    scale = float(jnp.max(jnp.abs(uncut)))
+    np.testing.assert_allclose(sum(parts) + shared, uncut, atol=1e-5 * scale)
+    np.testing.assert_allclose(sum(ref_parts) + shared, uncut, atol=1e-5 * scale)
+    assert np.concatenate(loads).sum() == K * x.shape[0]

@@ -1,6 +1,6 @@
 """The names a device trace can read: every Pallas kernel carries a
 string-literal ``name=`` (so Mosaic custom-calls print as ``flash_fwd…``,
-not ``jvp__.N``), and the trainer step's layer boundaries enter
+not ``jvp__.N``; a banded call chooses between two literals), and the trainer step's layer boundaries enter
 ``monitor.span`` scopes that reach the lowered program's metadata whether
 or not the monitor is on."""
 import ast
@@ -26,7 +26,9 @@ EXPECTED = {
         "flash_fwd", "flash_fwd_packed", "flash_fwd_bshd",
         "flash_bwd_dq", "flash_bwd_dkv", "flash_bwd_bshd_dq", "flash_bwd_bshd_dkv",
         "flash_bwd_packed_fused", "flash_bwd_packed_dq", "flash_bwd_packed_dkv",
-        "flash_bwd_dbias", "flash_bwd_dtable"},
+        "flash_bwd_dbias", "flash_bwd_dtable",
+        # the seq-major kernels on a sliding window: still flash_fwd* / flash_bwd*
+        "flash_fwd_bshd_win", "flash_bwd_bshd_win_dq", "flash_bwd_bshd_win_dkv"},
     "xentropy.py": {"xentropy_stats"},
     "decode_attention.py": {"decode_attn", "decode_attn_paged"},
     "layer_norm.py": {"ln_fwd", "ln_bwd"},
@@ -43,16 +45,18 @@ SCOPES = ("amp/fwd_bwd", "amp/unscale_check", "amp/apply_master", "fused_adam/up
 
 
 def literal_names(filename):
-    """The ``name=`` of every ``pallas_call(...)`` in the file, ``None``
-    where it is missing or not a string literal."""
+    """The ``name=`` of every ``pallas_call(...)`` in the file (both where it
+    is ``"a" if ... else "b"``), ``None`` where it is missing or no string
+    literal."""
     with open(os.path.join(PALLAS_DIR, filename)) as f:
         tree = ast.parse(f.read())
     out = []
     for node in ast.walk(tree):
         if isinstance(node, ast.Call) and getattr(node.func, "attr", None) == "pallas_call":
             kw = {k.arg: k.value for k in node.keywords}.get("name")
-            ok = isinstance(kw, ast.Constant) and isinstance(kw.value, str)
-            out.append(kw.value if ok else None)
+            for one in ([kw.body, kw.orelse] if isinstance(kw, ast.IfExp) else [kw]):
+                ok = isinstance(one, ast.Constant) and isinstance(one.value, str)
+                out.append(one.value if ok else None)
     return out
 
 
@@ -67,7 +71,7 @@ def test_every_pallas_call_has_a_literal_name(filename):
 def test_kernel_names_are_distinct_across_the_package():
     assert set(KERNEL_FILES) == set(EXPECTED)
     names = [n for f in KERNEL_FILES for n in literal_names(f)]
-    assert len(names) == 32 and len(set(names)) == 32
+    assert len(names) == 35 and len(set(names)) == 35
 
 
 def kernel_names(jaxpr):
@@ -94,6 +98,21 @@ def test_flash_equations_carry_their_names(layout, shape, names):
     q = jnp.ones(shape, jnp.float32)
     jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, q, q)
     assert kernel_names(jaxpr.jaxpr) == names
+
+
+def test_banded_flash_equations_carry_their_own_names():
+    """A windowed call's kernels are told apart by name, and every accepted
+    flash reader's part (``flash_fwd`` / ``flash_bwd``) is still in them."""
+    from apex_tpu.ops.attention import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, impl="pallas", layout="bshd", window=64).sum()
+
+    q = jnp.ones((1, 256, 2, 128), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, q, q)
+    names = kernel_names(jaxpr.jaxpr)
+    assert names == ["flash_fwd_bshd_win", "flash_bwd_bshd_win_dq", "flash_bwd_bshd_win_dkv"]
+    assert "flash_fwd" in names[0] and all("flash_bwd" in n for n in names[1:])
 
 
 def test_delta_mixer_equations_carry_their_names():

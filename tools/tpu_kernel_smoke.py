@@ -306,6 +306,23 @@ def _flash_wide_head_family():
     return build
 
 
+def _flash_window_family():
+    """The banded kernels (``flash_fwd_bshd_win``, ``flash_bwd_bshd_win_dq`` /
+    ``_dkv``) at a windowed layer's shape: heads of 128 in a group of 4, a
+    window of 2,048 in rows of 8,192 (a q block walks 3 of the 8 kv blocks),
+    against XLA's masked scores (one row: 1 GB a score tensor)."""
+    def build():
+        q = jr.normal(_key(51), (1, 8192, 4, D), jnp.bfloat16)
+        k, v = (jr.normal(_key(i), (1, 8192, 1, D), jnp.bfloat16) for i in (52, 53))
+
+        def make(impl):
+            return _fwd_and_grads(
+                lambda q, k, v: flash_attention(q, k, v, causal=True, layout="bshd",
+                                                window=2048, impl=impl), (0, 1, 2))
+        return make("pallas"), make("xla"), (q, k, v)
+    return build
+
+
 def _delta_rule_family():
     """``gdn_fwd`` / ``gdn_bwd`` (chunk operands and the 64 x 64 inverse built
     in VMEM, then the recurrence over chunks) against the XLA form (operands
@@ -486,6 +503,7 @@ FAMILIES = (
     Family("flash packed bias + dbias", _packed_family(biased=True)),
     Family("xentropy stats", _xent_family, tol=F32_TOL),
     Family("flash bshd heads of 256, group 8", _flash_wide_head_family()),
+    Family("flash bshd window 2,048 of 8,192 fwd/dq/dkv", _flash_window_family()),
     Family("gated delta rule gdn_fwd/gdn_bwd", _delta_rule_family()),
     Family("gated delta rule on drifted keys, against the recurrence",
            _delta_rule_drifted_family()),
