@@ -751,10 +751,19 @@ def flash_attention(
     ``window`` (static int; needs ``causal=True``, ``layout='bshd'`` and no
     ``bias``): sliding-window attention — query ``i`` sees keys
     ``i - window < j <= i`` (positions bottom-right aligned as for causal).
-    The kernels (named ``flash_fwd_bshd_win``, ``flash_bwd_bshd_win_dq`` /
-    ``_dkv``) walk only the blocks a q block's band touches: a tile wholly
+    The kernels (named ``flash_fwd_bshd_win`` and, the backward in one
+    pass, ``flash_bwd_bshd_win_fused``; with a longer key sequence than
+    query sequence the dq/dkv split ``flash_bwd_bshd_win_dq`` / ``_dkv``)
+    walk only the blocks a q block's band touches: a tile wholly
     outside the band is neither fetched nor computed, tiles cut by either
-    edge are masked. ``impl='xla'`` masks the materialised scores."""
+    edge are masked. ``impl='xla'`` masks the materialised scores.
+
+    The backward of ``layout='bshd'`` is one kernel wherever the operands
+    allow it (``flash_bwd_bshd_fused``: no ``bias``, ``sq == sk``, and the
+    (s, d) fp32 dk/dv accumulators inside the VMEM a kernel may ask for —
+    the rule of :func:`apex_tpu.ops.pallas.attention.flash_bwd_bshd`):
+    every score tile computed once, dk/dv summed over the kv group in VMEM.
+    Nothing here chooses it."""
     q, k, v = apply_op_rules("attention", q, k, v)
     if window is not None and (not causal or layout != "bshd" or bias is not None
                                or int(window) < 1):

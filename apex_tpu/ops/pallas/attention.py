@@ -10,9 +10,9 @@ Shapes: q (bh, sq, d), k/v (bh, sk, d) with bh = batch*heads folded. Forward
 returns (o, lse) — lse is the softmax log-normalizer row vector that backward
 reuses (the same residual the CUTLASS fmha saves). Backward is the standard
 two-kernel split: dq accumulates over KV blocks, dk/dv over Q blocks, with
-D = rowsum(do·o) precomputed by the caller — except on the packed layout
-without a bias, where one kernel computes every score tile once and feeds
-dq, dk and dv from it (:func:`_bwd_packed_fused_kernel`).
+D = rowsum(do·o) precomputed by the caller — except on the seq-major layouts
+(packed and bshd) without a bias, where one kernel computes every score tile
+once and feeds dq, dk and dv from it (:func:`_bwd_fused_kernel`).
 
 Block sizes default to 1024 (measured best on v5e at seq>=1024 — small
 blocks leave the head_dim-64 MXU contraction starved and grid overhead
@@ -413,9 +413,10 @@ def _group_sum(x, h_kv, group, d, dtype):
     """Per-q-head fp32 dk/dv partials (b, s, h·d) → kv-head grads
     (b, s, h_kv·d): sum each kv group's q heads, THEN cast (fp32 before the
     cross-head sum — the ADVICE r2 precision rule). Used by the dq/dkv
-    splits, whose grid rows are q heads: :func:`flash_bwd_bshd`, and
-    :func:`flash_bwd_packed` with a bias or a sequence too long for the
-    one-pass kernel (which sums the group in VMEM and needs none of this).
+    splits, whose grid rows are q heads: :func:`flash_bwd_bshd` and
+    :func:`flash_bwd_packed` with a bias, unequal lengths or a sequence too
+    long for the one-pass kernel (which sums the group in VMEM and needs
+    none of this).
     On the chip the partials are written and read back: 2 × 0.27 GB a layer
     at 16 heads on 1 × 8,192 positions (PERF.md, PR 25)."""
     b, s, _ = x.shape
@@ -672,19 +673,21 @@ def _split_bwd_vmem_limit(d, bq, bk, itemsize, out_itemsize):
     return _vmem_limit(need) if d > 128 else None
 
 
-def _bwd_packed_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h,
-                             h_kv, varlen, rate=0.0):
-    """One-pass backward of the packed layout: grid (b·h_kv, group, nq, nk),
-    kv blocks innermost. Every (q block, kv block) score tile is computed
-    ONCE and feeds dq, dk and dv — five matmuls a tile where the dq/dkv
-    split pays seven and runs the mask/exp chain twice.
+def _bwd_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h, h_kv,
+                      varlen, rate=0.0, window=None):
+    """One-pass backward of the seq-major layouts (the packed q|k|v buffer
+    and separate bshd arrays: the two differ in index maps only): grid
+    (b·h_kv, group, nq, nk), kv blocks innermost. Every (q block, kv block)
+    score tile is computed ONCE and feeds dq, dk and dv — five matmuls a
+    tile where the dq/dkv split pays seven and runs the mask/exp chain
+    twice.
 
     The tile is held TRANSPOSED, Sᵀ = K·Qᵀ (bk, bq): dV += Pᵀ·dO and
     dK += dSᵀ·Q are then plain (bk, bq)·(bq, d) products with the big tile
     as the streamed left operand, and only dQ contracts over the tile's
     rows. Row statistics ride as (1, bq) lane rows: ``lse`` comes in that
     form, D = rowsum(dO∘O) is computed here when a q block is first visited
-    (kv block 0) — no XLA prologue, no carrier.
+    (its first kv step) — no XLA prologue, no carrier.
 
     Accumulators are fp32 VMEM scratch and each gradient leaves once: dq in
     a (bq, d) scratch over the kv blocks of one visit; dk/dv in whole-
@@ -696,8 +699,11 @@ def _bwd_packed_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h,
 
     A tile is skipped (above the causal diagonal / past the row's kv
     length, as in the split), fully visible (no iota, compare or select),
-    or crossed by the diagonal or the length — branched on block indices
-    with ``pl.when``. Dropout regenerates the forward's mask: the same hash
+    or crossed by the diagonal, the band's lower edge or the length —
+    branched on block indices with ``pl.when``. ``window`` (static): the kv
+    axis is the band's run of blocks (``nk`` steps, counted from the band's
+    first block; the section on the band above), the mask is
+    :func:`_visible`. Dropout regenerates the forward's mask: the same hash
     on the same global coordinates, ``t`` the q-head row of the forward
     grid."""
     refs = list(refs)
@@ -713,26 +719,33 @@ def _bwd_packed_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h,
     r = pl.program_id(0)  # (batch, kv head) row
     g = pl.program_id(1)  # q head within the kv group
     i = pl.program_id(2)  # q block
-    j = pl.program_id(3)  # kv block (inner, dq accumulated)
-    first = jnp.logical_and(jnp.logical_and(g == 0, i == 0), j == 0)
+    jj = pl.program_id(3)  # step along the kv blocks (inner, dq accumulated)
+    # kv block: the step itself, or (banded) counted from the band's first
+    j = jj if window is None else _band_first(i, bq, bk, 0, window - 1) + jj
+    first = jnp.logical_and(jnp.logical_and(g == 0, i == 0), jj == 0)
     last = jnp.logical_and(jnp.logical_and(g == group - 1, i == nq - 1),
-                           j == nk - 1)
+                           jj == nk - 1)
 
     @pl.when(first)
     def _init_kv():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    @pl.when(j == 0)
+    @pl.when(jj == 0)
     def _visit():
         dq_scr[...] = jnp.zeros_like(dq_scr)
         prod = do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32)
         delta_scr[...] = jnp.sum(prod.T, axis=0, keepdims=True)  # (1, bq)
 
+    # the band ends at the diagonal's block, so a step past it is a tile
+    # above the diagonal: one test skips both
     run = (not causal) or (j * bk <= (i + 1) * bq - 1)
     # fully visible: the tile's last column at or left of its first row's
-    # diagonal / inside the kv length
+    # diagonal / its first column inside its last row's window / inside the
+    # kv length
     inner = (not causal) or ((j + 1) * bk - 1 <= i * bq)
+    if window is not None:
+        inner = jnp.logical_and(inner, j * bk > (i + 1) * bq - 1 - window)
     if varlen:
         kvlen = kvlen_ref[0, 0, 0]
         run = jnp.logical_and(run, j * bk < kvlen)
@@ -752,7 +765,7 @@ def _bwd_packed_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h,
             if causal:
                 rows = i * bq + jax.lax.broadcasted_iota(
                     jnp.int32, (1, bq), 1)
-                st = jnp.where(cols <= rows, st, NEG_INF)
+                st = jnp.where(_visible(rows, cols, window), st, NEG_INF)
             if varlen:
                 st = jnp.where(cols < kvlen, st, NEG_INF)
         pt = jnp.exp(st - lse_ref[0, 0])
@@ -786,27 +799,41 @@ def _bwd_packed_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h,
         pl.when(jnp.logical_and(run, jnp.logical_not(inner)))(
             lambda: _tile(True))
 
-    @pl.when(j == nk - 1)
+    @pl.when(jj == nk - 1)
     def _write_dq():
         dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
     @pl.when(last)
     def _write_dkv():
-        def block(jj, carry):
-            rows = pl.ds(pl.multiple_of(jj * bk, bk), bk)
+        def block(blk, carry):
+            rows = pl.ds(pl.multiple_of(blk * bk, bk), bk)
             dk_ref[0, rows, :] = (dk_scr[rows, :] * scale).astype(dk_ref.dtype)
             dv_ref[0, rows, :] = dv_scr[rows, :].astype(dv_ref.dtype)
             return carry
-        jax.lax.fori_loop(0, nk, block, 0)
+        jax.lax.fori_loop(0, dk_scr.shape[0] // bk, block, 0)
 
 
-def _flash_bwd_packed_fused(qkv, h, h_kv, d, o, lse, do, *, scale, causal,
-                            kv_lens, bq, bk, interpret, dropout_rate,
-                            dropout_seed):
-    """Launch :func:`_bwd_packed_fused_kernel`; (dq (b, s, h·d), dk, dv
-    (b, s, h_kv·d)), all in ``qkv.dtype``."""
-    b, s, _ = qkv.shape
+def _fused_bwd_fits(bias, rel_bias, sq, sk, d, bq, bk, itemsize):
+    """The rule that picks the one-pass backward, read from the operands:
+    no score bias of either kind (the dbias / dtable kernels take D as an
+    operand), one sequence length (``sq == sk``: the tile walk assumes the
+    diagonal starts at the origin), and accumulators, blocks and tile
+    temporaries inside the VMEM a kernel may ask for."""
+    return (bias is None and rel_bias is None and sq == sk
+            and _fused_bwd_vmem_bytes(sq, d, bq, bk, itemsize) <= _VMEM_CAP)
+
+
+def _flash_bwd_fused(q3, k3, v3, o3, lse, do3, *, h, h_kv, d, packed, scale,
+                     causal, kv_lens, bq, bk, interpret, dropout_rate,
+                     dropout_seed, window=None):
+    """Launch :func:`_bwd_fused_kernel` over folded (b, s, heads·d) operands;
+    (dq (b, s, h·d), dk, dv (b, s, h_kv·d)) in the operands' dtypes.
+    ``packed``: the three operands are one q|k|v buffer, whose k and v
+    windows start at head columns ``h`` and ``h + h_kv``; separate bshd
+    arrays start at column 0. Nothing else tells the layouts apart."""
+    b, s, _ = q3.shape
     group = h // h_kv
+    k_col, v_col = (h, h + h_kv) if packed else (0, 0)
     nq, nk = _blocks(s, bq), _blocks(s, bk)
     # (b, h, 1, s) lane rows: the transposed tile broadcasts lse along its
     # sublanes (one strided slice of the forward's carrier a layer)
@@ -815,15 +842,20 @@ def _flash_bwd_packed_fused(qkv, h, h_kv, d, o, lse, do, *, scale, causal,
     def head(r, g):  # q-head index within the batch row
         return (r % h_kv) * group + g
 
-    def kv_block(i, j):
-        # causal: tiles above the diagonal are skipped — hold the last
-        # needed kv block so the skipped steps fetch nothing
-        return jnp.minimum(j, ((i + 1) * bq - 1) // bk) if causal else j
+    if window is None:
+        steps = nk
+
+        def kv_block(i, j):
+            # causal: tiles above the diagonal are skipped — hold the last
+            # needed kv block so the skipped steps fetch nothing
+            return jnp.minimum(j, ((i + 1) * bq - 1) // bk) if causal else j
+    else:
+        steps, kv_block = _band_walk(True, nq, bq, bk, nk, 0, window - 1, 0)
 
     qm = lambda r, g, i, j: (r // h_kv, i, head(r, g))  # noqa: E731
-    km = lambda r, g, i, j: (r // h_kv, kv_block(i, j), h + r % h_kv)  # noqa: E731
+    km = lambda r, g, i, j: (r // h_kv, kv_block(i, j), k_col + r % h_kv)  # noqa: E731
     vm = lambda r, g, i, j: (  # noqa: E731
-        r // h_kv, kv_block(i, j), h + h_kv + r % h_kv)
+        r // h_kv, kv_block(i, j), v_col + r % h_kv)
     dkm = lambda r, g, i, j: (r // h_kv, 0, r % h_kv)  # noqa: E731
     in_specs = [pl.BlockSpec((1, bq, d), qm),
                 pl.BlockSpec((1, bk, d), km),
@@ -836,20 +868,23 @@ def _flash_bwd_packed_fused(qkv, h, h_kv, d, o, lse, do, *, scale, causal,
         kv_lens, b, dropout_rate, dropout_seed,
         lambda r, g, i, j: (r // h_kv, 0, 0))
     return pl.pallas_call(
-        functools.partial(_bwd_packed_fused_kernel, scale=scale,
-                          causal=causal, bq=bq, bk=bk, nq=nq, nk=nk,
+        functools.partial(_bwd_fused_kernel, scale=scale,
+                          causal=causal, bq=bq, bk=bk, nq=nq, nk=steps,
                           group=group, h=h, h_kv=h_kv,
-                          varlen=kv_lens is not None, rate=dropout_rate),
-        name="flash_bwd_packed_fused",
-        grid=(b * h_kv, group, nq, nk),
+                          varlen=kv_lens is not None, rate=dropout_rate,
+                          window=window),
+        name="flash_bwd_packed_fused" if packed else (
+            "flash_bwd_bshd_fused" if window is None
+            else "flash_bwd_bshd_win_fused"),
+        grid=(b * h_kv, group, nq, steps),
         in_specs=in_specs + tail_specs,
         out_specs=[pl.BlockSpec((1, bq, d), qm),
                    pl.BlockSpec((1, s, d), dkm),
                    pl.BlockSpec((1, s, d), dkm)],
         out_shape=[
-            jax.ShapeDtypeStruct((b, s, h * d), qkv.dtype),
-            jax.ShapeDtypeStruct((b, s, h_kv * d), qkv.dtype),
-            jax.ShapeDtypeStruct((b, s, h_kv * d), qkv.dtype),
+            jax.ShapeDtypeStruct((b, s, h * d), q3.dtype),
+            jax.ShapeDtypeStruct((b, s, h_kv * d), k3.dtype),
+            jax.ShapeDtypeStruct((b, s, h_kv * d), v3.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
@@ -863,9 +898,9 @@ def _flash_bwd_packed_fused(qkv, h, h_kv, d, o, lse, do, *, scale, causal,
             dimension_semantics=("parallel", "arbitrary", "arbitrary",
                                  "arbitrary"),
             vmem_limit_bytes=_vmem_limit(_fused_bwd_vmem_bytes(
-                s, d, bq, bk, qkv.dtype.itemsize))),
+                s, d, bq, bk, q3.dtype.itemsize))),
         interpret=interpret,
-    )(qkv, qkv, qkv, do, o, lse_rows, *tail_args)
+    )(q3, k3, v3, do3, o3, lse_rows, *tail_args)
 
 
 def flash_bwd_packed(qkv, h, h_kv, d, o, lse, do, *, scale, causal,
@@ -877,11 +912,12 @@ def flash_bwd_packed(qkv, h, h_kv, d, o, lse, do, *, scale, causal,
     packed dqkv.
 
     Unbiased attention takes ONE kernel, ``flash_bwd_packed_fused``, at any
-    number of blocks (see :func:`_bwd_packed_fused_kernel`): each score
-    tile computed once, dk/dv summed over the kv group in VMEM and written
-    at kv width. What picks it is what the shapes show: no bias, and the
-    two whole-sequence fp32 accumulators fit the VMEM a kernel may ask for
-    (:func:`_fused_bwd_vmem_bytes`; at d = 128 up to ~32 k positions). A
+    number of blocks (see :func:`_bwd_fused_kernel`): each score tile
+    computed once, dk/dv summed over the kv group in VMEM and written at kv
+    width. What picks it is what the shapes show (:func:`_fused_bwd_fits`):
+    no bias, and the two whole-sequence fp32 accumulators fit the VMEM a
+    kernel may ask for (:func:`_fused_bwd_vmem_bytes`; at d = 128 up to
+    ~32 k positions). A
     longer sequence, or a bias (the dbias kernel takes D as an operand),
     rides the dq/dkv split: ``flash_bwd_packed_dq`` / ``_dkv`` with per-q-
     head fp32 dk/dv partials and :func:`_group_sum`, then
@@ -901,12 +937,12 @@ def flash_bwd_packed(qkv, h, h_kv, d, o, lse, do, *, scale, causal,
     varlen = kv_lens is not None
     hb = 0 if bias is None else bias.shape[0]
 
-    if bias is None and _fused_bwd_vmem_bytes(
-            s, d, bq, bk, qkv.dtype.itemsize) <= _VMEM_CAP:
-        return _flash_bwd_packed_fused(
-            qkv, h, h_kv, d, o, lse, do, scale=scale, causal=causal,
-            kv_lens=kv_lens, bq=bq, bk=bk, interpret=interpret,
-            dropout_rate=dropout_rate, dropout_seed=dropout_seed)
+    if _fused_bwd_fits(bias, None, s, s, d, bq, bk, qkv.dtype.itemsize):
+        return _flash_bwd_fused(
+            qkv, qkv, qkv, o, lse, do, h=h, h_kv=h_kv, d=d, packed=True,
+            scale=scale, causal=causal, kv_lens=kv_lens, bq=bq, bk=bk,
+            interpret=interpret, dropout_rate=dropout_rate,
+            dropout_seed=dropout_seed)
     lse4 = lse if lse.ndim == 4 else _expand_rows(lse)
     delta = jnp.sum(
         do.astype(jnp.float32).reshape(b, s, h, d)
@@ -1707,8 +1743,20 @@ def flash_bwd_bshd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
     (hb, sq, sk), hb | h, a fourth output dbias (hb, sq, sk) fp32 (see
     :func:`flash_bwd`); with ``rel_bias`` (the bucketed triple) a fourth
     output dtable (hb, 128) fp32 head-major (see :func:`flash_bwd`).
-    ``window``: as :func:`flash_fwd_bshd`; the calls are then named
-    ``flash_bwd_bshd_win_dq`` / ``flash_bwd_bshd_win_dkv``."""
+
+    Unbiased self-length attention takes ONE kernel, ``flash_bwd_bshd_fused``
+    (with ``window``: ``flash_bwd_bshd_win_fused``, its kv axis the band's
+    run of blocks) — the packed layout's one-pass kernel over three arrays
+    (:func:`_bwd_fused_kernel`), picked by the same rule from what the
+    operands show (:func:`_fused_bwd_fits`): no ``bias``, no ``rel_bias``,
+    ``sq == sk``, and the two (s, d) fp32 dk/dv accumulators with the blocks
+    and tile temporaries inside the VMEM a kernel may ask for (52 MiB of the
+    100 at d = 128, s = 8,192; 71 at d = 256). A bias of either kind,
+    ``sq != sk`` (cross attention, a longer key sequence) or accumulators
+    past the cap ride the dq/dkv split: ``flash_bwd_bshd_dq`` / ``_dkv``
+    (``flash_bwd_bshd_win_dq`` / ``_win_dkv`` with ``window``), with D and
+    its carrier built by XLA, per-q-head fp32 dk/dv partials and
+    :func:`_group_sum`."""
     b, sq, h, d = q.shape
     sk, h_kv = k.shape[1], k.shape[2]
     group = h // h_kv
@@ -1717,6 +1765,17 @@ def flash_bwd_bshd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
     nq, nk = _blocks(sq, bq), _blocks(sk, bk)
     off = sk - sq
     _check_window(window, causal, bias, rel_bias)
+    if _fused_bwd_fits(bias, rel_bias, sq, sk, d, bq, bk, q.dtype.itemsize):
+        # folded (b, s, h·d) views — free bitcasts (see flash_fwd_bshd)
+        dq, dk, dv = _flash_bwd_fused(
+            q.reshape(b, sq, h * d), k.reshape(b, sk, h_kv * d),
+            v.reshape(b, sk, h_kv * d), o.reshape(b, sq, h * d), lse,
+            do.reshape(b, sq, h * d), h=h, h_kv=h_kv, d=d, packed=False,
+            scale=scale, causal=causal, kv_lens=kv_lens, bq=bq, bk=bk,
+            interpret=interpret, dropout_rate=dropout_rate,
+            dropout_seed=dropout_seed, window=window)
+        return (dq.reshape(b, sq, h, d), dk.reshape(b, sk, h_kv, d),
+                dv.reshape(b, sk, h_kv, d))
     banded, reach = window is not None, (window or 1) - 1
     k_steps, kv_block = _band_walk(banded, nq, bq, bk, nk, off, reach, 0)
     q_steps, q_block = _band_walk(banded, nk, bk, bq, nq, -off, 0, reach)

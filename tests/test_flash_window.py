@@ -37,8 +37,13 @@ def _dense(q, k, v, window):
 
 # blocks of 128 at a sequence of 512: windows under a tile, of a tile, across
 # tiles (aligned and not), of the sequence and beyond it
+@pytest.mark.parametrize("backward", ["one_pass", "split"])
 @pytest.mark.parametrize("window", [1, 5, 128, 129, 200, 256, 384, 512, 4096])
-def test_banded_kernels_match_the_masked_oracle(window):
+def test_banded_kernels_match_the_masked_oracle(window, backward, monkeypatch):
+    """``split``: with no VMEM to ask for, the rule that picks the backward
+    keeps the dq/dkv pair (what a longer key sequence or a bias rides)."""
+    if backward == "split":
+        monkeypatch.setattr(fa, "_VMEM_CAP", 0)
     q, k, v, do = _qkv(jax.random.PRNGKey(window), 2, 512, 512, 4, 2, 128)
 
     def run(impl, bq=128, bk=128):
@@ -52,6 +57,9 @@ def test_banded_kernels_match_the_masked_oracle(window):
         return (o, *fa.flash_bwd_bshd(q, k, v, o, lse, do, scale=128 ** -0.5, causal=True,
                                       bq=bq, bk=bk, interpret=True, window=window))
 
+    names = str(jax.make_jaxpr(lambda: run("pallas"))())
+    assert ("flash_bwd_bshd_win_fused" in names) == (backward == "one_pass")
+    assert ("flash_bwd_bshd_win_dkv" in names) == (backward == "split")
     want = run("xla")
     np.testing.assert_allclose(want[0], _dense(q, k, v, window), atol=2e-5)
     for blocks in ((128, 128), (256, 128), (128, 256)):
@@ -106,8 +114,13 @@ def test_the_grid_is_the_bands_and_the_names_say_so():
     f = lambda q, k, v: flash_attention(  # noqa: E731
         q, k, v, causal=True, layout="bshd", impl="pallas", window=256)
     text = str(jax.make_jaxpr(lambda *a: jax.vjp(f, *a)[1](do))(q, k, v))
-    for name in ("flash_fwd_bshd_win", "flash_bwd_bshd_win_dq", "flash_bwd_bshd_win_dkv"):
+    for name in ("flash_fwd_bshd_win", "flash_bwd_bshd_win_fused"):
         assert name in text
+    # the one-pass backward walks the same band: 8 q blocks, 3 kv steps each
+    bwd = str(jax.make_jaxpr(lambda q, k, v, o, lse: fa.flash_bwd_bshd(
+        q, k, v, o, lse, do, scale=1.0, causal=True, bq=128, bk=128, interpret=True,
+        window=256))(q, k, v, q, jnp.zeros((1, 2, 1024), jnp.float32)))
+    assert "grid=(1, 2, 8, 3)" in bwd
     fwd = str(jax.make_jaxpr(lambda q, k, v: fa.flash_fwd_bshd(
         q, k, v, scale=1.0, causal=True, bq=128, bk=128, interpret=True, window=256))(q, k, v))
     assert "grid=(2, 8, 3)" in fwd.replace("Grid", "grid") or "(2, 8, 3)" in fwd
@@ -126,7 +139,7 @@ def test_no_window_is_the_call_it_was():
 
     assert text(window=None) == text()
     assert "_win" not in text()
-    assert "flash_fwd_bshd" in text() and "flash_bwd_bshd_dq" in text()
+    assert "flash_fwd_bshd" in text() and "flash_bwd_bshd_fused" in text()
 
 
 @pytest.mark.parametrize("kw", [dict(causal=False), dict(layout="bhsd"), dict(window=0),

@@ -1,11 +1,12 @@
 """The names a device trace can read: every Pallas kernel carries a
 string-literal ``name=`` (so Mosaic custom-calls print as ``flash_fwd…``,
-not ``jvp__.N``; a banded call chooses between two literals), and the trainer step's layer boundaries enter
+not ``jvp__.N``; a call that serves several layouts chooses between literals), and the trainer step's layer boundaries enter
 ``monitor.span`` scopes that reach the lowered program's metadata whether
 or not the monitor is on."""
 import ast
 import functools
 import glob
+import hashlib
 import os
 import re
 
@@ -27,8 +28,11 @@ EXPECTED = {
         "flash_bwd_dq", "flash_bwd_dkv", "flash_bwd_bshd_dq", "flash_bwd_bshd_dkv",
         "flash_bwd_packed_fused", "flash_bwd_packed_dq", "flash_bwd_packed_dkv",
         "flash_bwd_dbias", "flash_bwd_dtable",
+        # the one-pass backward over three arrays: the packed kernel's body
+        "flash_bwd_bshd_fused",
         # the seq-major kernels on a sliding window: still flash_fwd* / flash_bwd*
-        "flash_fwd_bshd_win", "flash_bwd_bshd_win_dq", "flash_bwd_bshd_win_dkv"},
+        "flash_fwd_bshd_win", "flash_bwd_bshd_win_fused",
+        "flash_bwd_bshd_win_dq", "flash_bwd_bshd_win_dkv"},
     "xentropy.py": {"xentropy_stats"},
     "decode_attention.py": {"decode_attn", "decode_attn_paged"},
     "layer_norm.py": {"ln_fwd", "ln_bwd"},
@@ -45,18 +49,22 @@ SCOPES = ("amp/fwd_bwd", "amp/unscale_check", "amp/apply_master", "fused_adam/up
 
 
 def literal_names(filename):
-    """The ``name=`` of every ``pallas_call(...)`` in the file (both where it
-    is ``"a" if ... else "b"``), ``None`` where it is missing or no string
-    literal."""
+    """The ``name=`` of every ``pallas_call(...)`` in the file (every branch
+    where it is ``"a" if ... else "b"``, nested or not), ``None`` where it is
+    missing or no string literal."""
     with open(os.path.join(PALLAS_DIR, filename)) as f:
         tree = ast.parse(f.read())
+
+    def branches(node):
+        if isinstance(node, ast.IfExp):
+            return branches(node.body) + branches(node.orelse)
+        ok = isinstance(node, ast.Constant) and isinstance(node.value, str)
+        return [node.value if ok else None]
+
     out = []
     for node in ast.walk(tree):
         if isinstance(node, ast.Call) and getattr(node.func, "attr", None) == "pallas_call":
-            kw = {k.arg: k.value for k in node.keywords}.get("name")
-            for one in ([kw.body, kw.orelse] if isinstance(kw, ast.IfExp) else [kw]):
-                ok = isinstance(one, ast.Constant) and isinstance(one.value, str)
-                out.append(one.value if ok else None)
+            out += branches({k.arg: k.value for k in node.keywords}.get("name"))
     return out
 
 
@@ -71,23 +79,25 @@ def test_every_pallas_call_has_a_literal_name(filename):
 def test_kernel_names_are_distinct_across_the_package():
     assert set(KERNEL_FILES) == set(EXPECTED)
     names = [n for f in KERNEL_FILES for n in literal_names(f)]
-    assert len(names) == 35 and len(set(names)) == 35
+    assert len(names) == 37 and len(set(names)) == 37
+
+
+def pallas_eqns(jaxpr):
+    """The ``pallas_call`` equations, through every sub-jaxpr."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from pallas_eqns(sub)
 
 
 def kernel_names(jaxpr):
-    """Names of the ``pallas_call`` equations, through every sub-jaxpr."""
-    out = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            out.append(eqn.params["name"])
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            out += kernel_names(sub)
-    return out
+    return [eqn.params["name"] for eqn in pallas_eqns(jaxpr)]
 
 
 @pytest.mark.parametrize("layout,shape,names", [
     ("bhsd", (1, 2, 128, 64), ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]),
-    ("bshd", (1, 128, 2, 128), ["flash_fwd_bshd", "flash_bwd_bshd_dq", "flash_bwd_bshd_dkv"]),
+    ("bshd", (1, 128, 2, 128), ["flash_fwd_bshd", "flash_bwd_bshd_fused"]),
 ])
 def test_flash_equations_carry_their_names(layout, shape, names):
     from apex_tpu.ops.attention import flash_attention
@@ -102,7 +112,8 @@ def test_flash_equations_carry_their_names(layout, shape, names):
 
 def test_banded_flash_equations_carry_their_own_names():
     """A windowed call's kernels are told apart by name, and every accepted
-    flash reader's part (``flash_fwd`` / ``flash_bwd``) is still in them."""
+    flash reader's part (``flash_fwd`` / ``flash_bwd``; the banded readers'
+    ``flash_fwd_bshd_win`` / ``flash_bwd_bshd_win``) is still in them."""
     from apex_tpu.ops.attention import flash_attention
 
     def loss(q, k, v):
@@ -111,8 +122,73 @@ def test_banded_flash_equations_carry_their_own_names():
     q = jnp.ones((1, 256, 2, 128), jnp.float32)
     jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, q, q)
     names = kernel_names(jaxpr.jaxpr)
-    assert names == ["flash_fwd_bshd_win", "flash_bwd_bshd_win_dq", "flash_bwd_bshd_win_dkv"]
-    assert "flash_fwd" in names[0] and all("flash_bwd" in n for n in names[1:])
+    assert names == ["flash_fwd_bshd_win", "flash_bwd_bshd_win_fused"]
+    assert "flash_fwd" in names[0] and "flash_bwd" in names[1]
+    assert "flash_fwd_bshd_win" in names[0] and "flash_bwd_bshd_win" in names[1]
+
+
+BSHD_SPLIT = ["flash_bwd_bshd_dq", "flash_bwd_bshd_dkv"]
+
+
+def _bshd_backward_names(sq, sk, h, h_kv, d, dtype=jnp.bfloat16, **kw):
+    """Names of the calls ``flash_bwd_bshd`` makes at these shapes (traced
+    from shapes alone: nothing runs)."""
+    from apex_tpu.ops.pallas import attention as pk
+
+    def backward(q, k, v, o, lse, do):
+        return pk.flash_bwd_bshd(q, k, v, o, lse, do, scale=d ** -0.5, interpret=True, **kw)
+
+    arr = functools.partial(jax.ShapeDtypeStruct, dtype=dtype)
+    jaxpr = jax.make_jaxpr(backward)(
+        arr((1, sq, h, d)), arr((1, sk, h_kv, d)), arr((1, sk, h_kv, d)), arr((1, sq, h, d)),
+        jax.ShapeDtypeStruct((1, h, sq, 8), jnp.float32), arr((1, sq, h, d)))
+    return kernel_names(jaxpr.jaxpr)
+
+
+@pytest.mark.parametrize("case,names", [
+    ("plain", ["flash_bwd_bshd_fused"]),
+    ("noncausal", ["flash_bwd_bshd_fused"]),
+    ("window", ["flash_bwd_bshd_win_fused"]),
+    ("bias", [*BSHD_SPLIT, "flash_bwd_dbias"]),          # dbias takes D as an operand
+    ("bucketed", [*BSHD_SPLIT, "flash_bwd_dtable"]),
+    ("longer_keys", BSHD_SPLIT),                          # sq != sk: cross attention, a ring's piece
+    ("longer_keys_window", ["flash_bwd_bshd_win_dq", "flash_bwd_bshd_win_dkv"]),
+])
+def test_bshd_backward_is_picked_by_what_its_operands_show(case, names):
+    """The seq-major backward is one pass unless the call carries a bias of
+    either kind or two sequence lengths; every name it can take still holds
+    the readers' parts."""
+    sq, sk = (256, 512) if case.startswith("longer_keys") else (256, 256)
+    kw = {"causal": case != "noncausal"}
+    if case.endswith("window"):
+        kw["window"] = 100
+    if case == "bias":
+        kw["bias"] = jnp.zeros((1, sq, sk))
+    if case == "bucketed":
+        kw["rel_bias"] = (jnp.zeros((2, 128)), jnp.zeros((2,), jnp.int32), (32, False, 128))
+    got = _bshd_backward_names(sq, sk, 2, 1, 128, jnp.float32, **kw)
+    assert got == names
+    assert all("flash_bwd" in n for n in got)
+    if "window" in kw:
+        assert all("flash_bwd_bshd_win" in n for n in got)
+
+
+@pytest.mark.parametrize("s,h,h_kv,d,window,names", [
+    (8192, 32, 4, 128, None, ["flash_bwd_bshd_fused"]),       # trinity-train-8k's full layer: 52 MiB
+    (8192, 32, 4, 128, 2048, ["flash_bwd_bshd_win_fused"]),   # its four banded layers
+    (8192, 16, 2, 256, None, ["flash_bwd_bshd_fused"]),       # q3next-train-8k: 71 MiB
+    (32768, 16, 2, 256, None, BSHD_SPLIT),                    # 2 x 32 MiB of accumulators and as much of outputs
+    (262144, 32, 4, 128, 2048, ["flash_bwd_bshd_win_dq", "flash_bwd_bshd_win_dkv"]),
+])
+def test_bshd_backward_is_picked_by_the_vmem_its_accumulators_need(s, h, h_kv, d, window, names):
+    """The packed layout's rule, from the same function: the whole-sequence
+    fp32 dk/dv accumulators with the blocks and tile temporaries against the
+    VMEM a kernel may ask for."""
+    from apex_tpu.ops.pallas import attention as pk
+
+    assert _bshd_backward_names(s, s, h, h_kv, d, causal=True, window=window) == names
+    fits = pk._fused_bwd_vmem_bytes(s, d, 1024, 1024, 2) <= pk._VMEM_CAP
+    assert fits == ("fused" in names[0])
 
 
 def test_delta_mixer_equations_carry_their_names():
@@ -185,6 +261,53 @@ def test_packed_backward_is_picked_by_the_vmem_its_accumulators_need(seq, names)
     assert kernel_names(jaxpr.jaxpr) == names
     fits = pk._fused_bwd_vmem_bytes(seq, d, 1024, 1024, 2) <= pk._VMEM_CAP
     assert fits == (names != SPLIT)
+
+
+def program_text(closed):
+    """A traced call as text: its equations (every kernel body among them)
+    and, what ``str`` leaves out, each block's index map."""
+    maps = [str(m.index_map_jaxpr) for eqn in pallas_eqns(closed.jaxpr)
+            for m in eqn.params["grid_mapping"].block_mappings]
+    return "\n".join([str(closed), *maps])
+
+
+# sha256 of ``program_text`` of the calls below, read on the commit before the
+# one-pass kernel's body took a window and a second layout (db094e1, jax 0.9.0).
+# A change of the packed kernel's arithmetic moves them, and is then a change of
+# ``sc1b-train-8k``'s program: read them again and say so.
+PACKED_BEFORE_THE_BAND = {
+    "causal": "7feb12415146cf8c",
+    "noncausal": "44c355e4db3d695d",
+    "kv_lens": "0caaa90fd00dc20a",
+    "dropout": "464c0b7a5af07d86",
+}
+
+
+@pytest.mark.parametrize("case", sorted(PACKED_BEFORE_THE_BAND))
+def test_packed_backward_without_a_window_is_the_program_it_was(case):
+    """The body the packed and bshd layouts now share builds, for the packed
+    layout (which has no window), the equations and index maps it built
+    before: kernel body, grid, blocks, VMEM limit."""
+    from apex_tpu.ops.pallas import attention as pk
+
+    h, h_kv, d, seq = 4, 2, 128, 512
+    kw = {"causal": case != "noncausal"}
+    if case == "kv_lens":
+        kw["kv_lens"] = jnp.array([5, 300], jnp.int32)
+    if case == "dropout":
+        kw.update(dropout_rate=0.1, dropout_seed=jnp.int32(3))
+
+    def backward(qkv, o, lse, do):
+        return pk.flash_bwd_packed(qkv, h, h_kv, d, o, lse, do, scale=d ** -0.5,
+                                   interpret=True, bq=128, bk=128, **kw)
+
+    bf16 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16)
+    closed = jax.make_jaxpr(backward)(
+        bf16((2, seq, (h + 2 * h_kv) * d)), bf16((2, seq, h * d)),
+        jax.ShapeDtypeStruct((2, h, seq, 8), jnp.float32), bf16((2, seq, h * d)))
+    assert kernel_names(closed.jaxpr) == ["flash_bwd_packed_fused"]
+    text = program_text(closed)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == PACKED_BEFORE_THE_BAND[case]
 
 
 def test_cross_entropy_equation_carries_its_name():

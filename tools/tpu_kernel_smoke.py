@@ -306,20 +306,53 @@ def _flash_wide_head_family():
     return build
 
 
-def _flash_window_family():
-    """The banded kernels (``flash_fwd_bshd_win``, ``flash_bwd_bshd_win_dq`` /
-    ``_dkv``) at a windowed layer's shape: heads of 128 in a group of 4, a
-    window of 2,048 in rows of 8,192 (a q block walks 3 of the 8 kv blocks),
-    against XLA's masked scores (one row: 1 GB a score tensor)."""
-    def build():
-        q = jr.normal(_key(51), (1, 8192, 4, D), jnp.bfloat16)
-        k, v = (jr.normal(_key(i), (1, 8192, 1, D), jnp.bfloat16) for i in (52, 53))
+def _sliced_attention_reference(fn):
+    """``fn``'s XLA form over one batch row and four query heads at a time
+    (a score tensor of 1 GB at 8,192 positions, where the whole call's
+    would be 17): the output and dq laid back in place, dk and dv summed in
+    float32 over the slices that share a kv head. Same cotangent as
+    :func:`_fwd_and_grads` draws, sliced."""
+    q_heads = 4
 
-        def make(impl):
-            return _fwd_and_grads(
-                lambda q, k, v: flash_attention(q, k, v, causal=True, layout="bshd",
-                                                window=2048, impl=impl), (0, 1, 2))
-        return make("pallas"), make("xla"), (q, k, v)
+    def run(q, k, v):
+        b, s, h, d = q.shape
+        h_kv, n = k.shape[2], h // q_heads
+        cot = jr.normal(_key(99), q.shape, jnp.float32)
+
+        def one(idx):
+            row, first = idx // n, (idx % n) * q_heads
+            cut = lambda x, head, heads: jax.lax.dynamic_slice(  # noqa: E731
+                x, (row, 0, head, 0), (1, s, heads, d))
+            kv_head = first // (h // h_kv)
+            out, pull = jax.vjp(fn, cut(q, first, q_heads), cut(k, kv_head, 1),
+                                cut(v, kv_head, 1))
+            dq, dk, dv = pull(cut(cot, first, q_heads).astype(out.dtype))
+            return out[0], dq[0], dk[0, :, 0].astype(jnp.float32), dv[0, :, 0].astype(jnp.float32)
+
+        out, dq, dk, dv = jax.lax.map(one, jnp.arange(b * n))
+        heads = lambda x: x.reshape(b, n, s, q_heads, d).transpose(  # noqa: E731
+            0, 2, 1, 3, 4).reshape(b, s, h, d)
+        kv = lambda x, like: x.reshape(b, h_kv, n // h_kv, s, d).sum(2).transpose(  # noqa: E731
+            0, 2, 1, 3).astype(like.dtype)
+        return heads(out), (heads(dq), kv(dk, k), kv(dv, v))
+    return run
+
+
+def _flash_cell_family(h, h_kv, d, window):
+    """The seq-major kernels at a training cell's attention shape, 2 rows of
+    8,192: the forward (``flash_fwd_bshd`` / ``_win``) and the one-pass
+    backward (``flash_bwd_bshd_fused`` / ``flash_bwd_bshd_win_fused``: dq, dk
+    and dv from one walk of the tiles, on a window 3 of the 8 kv blocks a q
+    block) against XLA's masked scores, a slice at a time."""
+    def build():
+        q = jr.normal(_key(51), (2, 8192, h, d), jnp.bfloat16)
+        k, v = (jr.normal(_key(i), (2, 8192, h_kv, d), jnp.bfloat16) for i in (52, 53))
+
+        def attention(impl):
+            return lambda q, k, v: flash_attention(
+                q, k, v, causal=True, layout="bshd", window=window, impl=impl)
+        return (_fwd_and_grads(attention("pallas"), (0, 1, 2)),
+                _sliced_attention_reference(attention("xla")), (q, k, v))
     return build
 
 
@@ -503,7 +536,10 @@ FAMILIES = (
     Family("flash packed bias + dbias", _packed_family(biased=True)),
     Family("xentropy stats", _xent_family, tol=F32_TOL),
     Family("flash bshd heads of 256, group 8", _flash_wide_head_family()),
-    Family("flash bshd window 2,048 of 8,192 fwd/dq/dkv", _flash_window_family()),
+    Family("flash bshd window 2,048 of 8,192, 32 / 4 heads of 128, fwd + one-pass bwd",
+           _flash_cell_family(32, 4, 128, 2048)),
+    Family("flash bshd 8,192, 16 / 2 heads of 256, fwd + one-pass bwd",
+           _flash_cell_family(16, 2, 256, None)),
     Family("gated delta rule gdn_fwd/gdn_bwd", _delta_rule_family()),
     Family("gated delta rule on drifted keys, against the recurrence",
            _delta_rule_drifted_family()),
@@ -565,14 +601,20 @@ def max_error(got, want) -> float:
 
 def check(family: Family):
     """Compile, then run, one family's kernel and reference on the same
-    operands: ``(max error, compile seconds, run seconds)``."""
+    operands: ``(max error, compile seconds, run seconds, halves)`` —
+    ``halves`` the forward's error and the gradients' apart where the family
+    returns ``(output, grads)``, else empty."""
     kernel, reference, args = family.build()
     t0 = time.perf_counter()
     compiled = [jax.jit(fn).lower(*args).compile()
                 for fn in (kernel, reference)]
     t1 = time.perf_counter()
     got, want = jax.block_until_ready([c(*args) for c in compiled])
-    return max_error(got, want), t1 - t0, time.perf_counter() - t1
+    halves = {}
+    if isinstance(got, tuple) and len(got) == 2 and isinstance(got[1], tuple):
+        halves = {"fwd": max_error(got[0], want[0]), "bwd": max_error(got[1], want[1])}
+    err = max(halves.values()) if halves else max_error(got, want)
+    return err, t1 - t0, time.perf_counter() - t1, halves
 
 
 def main(families=FAMILIES) -> list:
@@ -584,13 +626,14 @@ def main(families=FAMILIES) -> list:
             f"Mosaic compiles")
     failed, compile_s, run_s = [], 0.0, 0.0
     for fam in families:
-        err, c_s, r_s = check(fam)
+        err, c_s, r_s, halves = check(fam)
         compile_s, run_s = compile_s + c_s, run_s + r_s
         ok = err <= fam.tol
         if not ok:
             failed.append(fam.name)
+        apart = "".join(f"{half} {e:.2e}, " for half, e in halves.items())
         print(f"{'PASS' if ok else 'FAIL'} {fam.name}: max err {err:.2e} "
-              f"(tol {fam.tol:.0e}, {'auto' if fam.auto else 'explicit'}, "
+              f"({apart}tol {fam.tol:.0e}, {'auto' if fam.auto else 'explicit'}, "
               f"compile {c_s:.1f} s, run {r_s:.2f} s)", flush=True)
     print(f"{len(families) - len(failed)}/{len(families)} kernel families "
           f"match their XLA composition on {jax.devices()[0].device_kind}")
