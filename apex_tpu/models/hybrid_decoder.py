@@ -1,17 +1,19 @@
 """A decoder whose layers take their kind from a pattern: gated delta-rule
-(linear-attention) mixers and gated softmax-attention mixers, over all keys
-or over a sliding window, in any order, each followed by a dropless
+(linear-attention) mixers, gated softmax-attention mixers over all keys or
+over a sliding window, and latent-attention (MLA) mixers, in any order, each
+followed by a dropless
 sparse-expert layer with a shared expert or by a dense SwiGLU layer.
 
 Pre-norm residual blocks (``x += mixer(norm(x)); x += experts(norm(x))``)
 with zero-centred RMSNorm (``x / rms(x) * (1 + w)``), no position table
 (the attention layers carry partial rotary embeddings, the delta-rule layers
 need none), no biases, an untied output head. ``layer_types`` names each
-layer ``"linear"``, ``"full"`` or ``"window"``, ``ffn_types`` its second
-half ``"moe"`` (the default everywhere) or ``"dense"``; parameters of one
-kind are stacked on a leading axis under ``layers/gdn``, ``layers/attn``
-(both attention kinds, in the order they come), ``layers/moe`` and
-``layers/dense``; a kind no layer has has no group. All linears are stored
+layer ``"linear"``, ``"full"``, ``"window"`` or ``"latent"``, ``ffn_types``
+its second half ``"moe"`` (the default everywhere) or ``"dense"``; parameters
+of one kind are stacked on a leading axis under ``layers/gdn``,
+``layers/attn`` (both gated attention kinds, in the order they come),
+``layers/mla``, ``layers/moe`` and ``layers/dense``; a kind no layer has has
+no group. All linears are stored
 (in, out). Switches for the blocks of other published decoders: plain
 RMSNorm (``zero_centered_norm=False``: ``x / rms(x) * w``), a second norm on
 each half's output before it is added (``sandwich_norms``:
@@ -30,6 +32,14 @@ expert without its gate (``shared_gate=False``).
 * ``"window"`` — the same mixer, a query seeing its last ``window`` keys
   (``flash_attention(window=)``) and rotated over ``window_rotary_dim``
   features (``rotary_dim`` is the ``"full"`` layers'; 0 rotates nothing).
+* ``"latent"`` — multi-head latent attention without a gate or q/k norms:
+  ``w_q`` (hidden, heads x ``qk_nope_dim`` | heads x ``qk_rope_dim``),
+  ``w_kva`` (hidden, ``kv_lora_rank`` | ``qk_rope_dim``: the latent and ONE
+  rotary key for all heads), ``kv_norm`` on the latent, ``w_kvb`` (latent,
+  heads x ``qk_nope_dim`` | heads x ``v_head_dim``), rotary (``rope_scaling``:
+  a published ``yarn`` entry) on the rotary features only, scores
+  ``(q_nope . k_nope + q_pe . k_pe) * scale`` through
+  ``flash_attention(second=)`` — the shared key is never repeated —, ``w_o``.
 * experts — :func:`transformer.moe.dropless_moe_layer` over the experts held
   here (``experts_held``), router at its full width. With
   ``router_score="sigmoid"`` the step may carry a selection bias, state that
@@ -56,11 +66,12 @@ from apex_tpu.monitor import spans as monitor_spans
 from apex_tpu.ops.attention import flash_attention
 from apex_tpu.ops.gated_delta_rule import (causal_conv_silu, gated_delta_rule,
                                            gated_rms_norm)
-from apex_tpu.ops.rotary import apply_partial_rotary
+from apex_tpu.ops.rotary import apply_partial_rotary, yarn_mscale
 from apex_tpu.transformer import tensor_parallel as tp_lib
 from apex_tpu.transformer.moe import dropless_moe_layer, silu_gate
 
 ATTENTION_KINDS = ("full", "window")
+GROUP_OF_KIND = {"linear": "gdn", "full": "attn", "window": "attn", "latent": "mla"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,6 +88,14 @@ class HybridDecoderConfig:
     # "window" layers: keys a query sees, rotary features (None: rotary_dim)
     window: Optional[int] = None
     window_rotary_dim: Optional[int] = None
+    # "latent" layers (num_heads of them): per-head and shared-rotary score
+    # widths, the value head, the latent; a published ``rope_scaling`` entry
+    # of type yarn (a mapping; kept as its sorted items) or None
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_head_dim: int = 128
+    kv_lora_rank: int = 512
+    rope_scaling: Any = None
     # gated delta-rule layers
     linear_key_heads: int = 16
     linear_value_heads: int = 32
@@ -94,6 +113,8 @@ class HybridDecoderConfig:
     router_score: str = "softmax"
     route_scale: float = 1.0
     shared_gate: bool = True
+    # the balance term per sequence (mean over the rows) instead of batch-wise
+    seq_aux: bool = False
     # second half of each layer, "moe" | "dense"; None: experts in every layer
     ffn_types: Optional[Tuple[str, ...]] = None
     dense_ffn: int = 0
@@ -109,10 +130,12 @@ class HybridDecoderConfig:
     dtype: Any = jnp.float32
 
     def __post_init__(self):
-        bad = set(self.layer_types) - {"linear", *ATTENTION_KINDS}
+        bad = set(self.layer_types) - set(GROUP_OF_KIND)
         if bad or not self.layer_types:
-            raise ValueError("layer_types holds 'linear', 'full' and 'window', "
+            raise ValueError("layer_types holds 'linear', 'full', 'window' and 'latent', "
                              f"got {self.layer_types!r}")
+        if hasattr(self.rope_scaling, "items"):             # hashable, as the rest
+            object.__setattr__(self, "rope_scaling", tuple(sorted(self.rope_scaling.items())))
         if "window" in self.layer_types and not self.window:
             raise ValueError("a 'window' layer needs window=")
         if set(self.ffn) - {"moe", "dense"} or len(self.ffn) != len(self.layer_types):
@@ -154,8 +177,8 @@ class HybridDecoderModel:
         1/sqrt(2 L); decay ``A ~ U(1, 16)``, ``dt ~ logU(1e-3, 1e-1)``)."""
         c = self.config
         H, L = c.hidden_size, len(c.layer_types)
-        Lg = c.layer_types.count("linear")
-        La = L - Lg
+        Lg, Ll = c.layer_types.count("linear"), c.layer_types.count("latent")
+        La = L - Lg - Ll
         Lm, Ld = c.ffn.count("moe"), c.ffn.count("dense")
         qk, vv = c.linear_key_heads * c.linear_key_dim, c.linear_value_heads * c.linear_value_dim
         keys = iter(jax.random.split(key, 32))
@@ -186,6 +209,13 @@ class HybridDecoderModel:
                 "q_norm": unit((La, c.head_dim)), "k_norm": unit((La, c.head_dim)),
                 "w_o": n((La, c.num_heads * c.head_dim, H), res),
             },
+            "mla": {
+                "w_q": n((Ll, H, c.num_heads * (c.qk_nope_dim + c.qk_rope_dim))),
+                "w_kva": n((Ll, H, c.kv_lora_rank + c.qk_rope_dim)),
+                "kv_norm": unit((Ll, c.kv_lora_rank)),
+                "w_kvb": n((Ll, c.kv_lora_rank, c.num_heads * (c.qk_nope_dim + c.v_head_dim))),
+                "w_o": n((Ll, c.num_heads * c.v_head_dim, H), res),
+            },
             "moe": {
                 "router": n((Lm, H, c.router_experts)),
                 "w_gate_up": n((Lm, Eh, H, 2 * c.expert_ffn)),
@@ -203,7 +233,8 @@ class HybridDecoderModel:
             del layers["moe"]["shared_mix"]
         if c.sandwich_norms:
             layers.update(norm1_post=unit((L, H)), norm2_post=unit((L, H)))
-        for group, count in (("gdn", Lg), ("attn", La), ("moe", Lm), ("dense", Ld)):
+        for group, count in (("gdn", Lg), ("attn", La), ("mla", Ll), ("moe", Lm),
+                             ("dense", Ld)):
             if not count:
                 del layers[group]
         return {
@@ -260,12 +291,38 @@ class HybridDecoderModel:
         ctx = ctx * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(ctx.dtype)
         return jnp.dot(ctx.reshape(b, s, nh * dh), p["w_o"])
 
+    def _latent_mixer(self, p, x):
+        c = self.config
+        b, s, _ = x.shape
+        nh, dn, dr, dv, rank = (c.num_heads, c.qk_nope_dim, c.qk_rope_dim, c.v_head_dim,
+                                c.kv_lora_rank)
+        # the weights are cut, not the activations: each product lands where
+        # the kernel reads it (columns stored nope | rope and key | value)
+        with monitor_spans.span("mla/down"):
+            q_nope = jnp.dot(x, p["w_q"][:, :nh * dn]).reshape(b, s, nh, dn)
+            q_pe = jnp.dot(x, p["w_q"][:, nh * dn:]).reshape(b, s, nh, dr)
+            latent = self._norm(jnp.dot(x, p["w_kva"][:, :rank]), p["kv_norm"])
+            k_pe = jnp.dot(x, p["w_kva"][:, rank:]).reshape(b, s, 1, dr)
+        with monitor_spans.span("mla/up"):
+            k_nope = jnp.dot(latent, p["w_kvb"][:, :nh * dn]).reshape(b, s, nh, dn)
+            v = jnp.dot(latent, p["w_kvb"][:, nh * dn:]).reshape(b, s, nh, dv)
+        scale = (dn + dr) ** -0.5
+        if c.rope_scaling is not None:         # yarn's temperature, on the scores
+            entry = dict(c.rope_scaling)
+            scale *= yarn_mscale(entry["factor"], entry.get("mscale_all_dim", 0.0)) ** 2
+        q_pe, k_pe = (apply_partial_rotary(a, dr, c.rope_theta, scaling=c.rope_scaling)
+                      for a in (q_pe, k_pe))
+        ctx = flash_attention(q_nope, k_nope, v, causal=True, scale=scale, layout="bshd",
+                              impl=c.attention_impl, second=(q_pe, k_pe))
+        return jnp.dot(ctx.reshape(b, s, nh * dv), p["w_o"])
+
     def _experts(self, p, x, router_bias=None):
         c = self.config
         return dropless_moe_layer(
             p, x, top_k=c.top_k, experts_held=c.held, normalize_weights=c.normalize_topk,
             impl=c.experts_impl, score=c.router_score, route_scale=c.route_scale,
-            router_bias=router_bias, shared_gate=c.shared_gate)
+            router_bias=router_bias, shared_gate=c.shared_gate,
+            sequence_balance=c.seq_aux)
 
     @staticmethod
     def _dense(p, x):
@@ -287,7 +344,9 @@ class HybridDecoderModel:
             if c.embed_scale != 1.0:
                 x = x * jnp.asarray(c.embed_scale, x.dtype)
         keep_plan = jax.checkpoint_policies.save_only_these_names("moe_plan")
-        scopes = {"linear": "hybrid/gdn", "full": "hybrid/attn", "window": "hybrid/attn_win"}
+        scopes = {"linear": "hybrid/gdn", "full": "hybrid/attn", "window": "hybrid/attn_win",
+                  "latent": "hybrid/attn_mla"}
+        mixers = {"linear": self._delta_mixer, "latent": self._latent_mixer}
 
         def added(y, post):
             """What a half adds to the stream: its output, normed again
@@ -297,8 +356,7 @@ class HybridDecoderModel:
         def mixer_half(kind, p, w, post, x):
             with monitor_spans.span(scopes[kind]):
                 h = self._norm(x, w)
-                y = (self._delta_mixer(p, h) if kind == "linear"
-                     else self._attention_mixer(p, h, kind))
+                y = mixers[kind](p, h) if kind in mixers else self._attention_mixer(p, h, kind)
                 return x + added(y, post)
 
         def expert_half(p, w, post, bias, x):
@@ -312,7 +370,7 @@ class HybridDecoderModel:
 
         wrap = jax.checkpoint if c.remat else (lambda f, **kw: f)
         post1, post2 = layers.get("norm1_post"), layers.get("norm2_post")
-        seen = {"gdn": 0, "attn": 0, "moe": 0, "dense": 0}
+        seen = {"gdn": 0, "attn": 0, "mla": 0, "moe": 0, "dense": 0}
         lb, loads, counts, dropped = 0.0, [], [], 0
 
         def take(group):
@@ -321,7 +379,7 @@ class HybridDecoderModel:
             return j, jax.tree.map(lambda a: a[j], layers[group])
 
         for i, (kind, ffn) in enumerate(zip(c.layer_types, c.ffn)):
-            _, p_mix = take("gdn" if kind == "linear" else "attn")
+            _, p_mix = take(GROUP_OF_KIND[kind])
             j, p_ffn = take(ffn)
             f = lambda p, w, post, x, kind=kind: mixer_half(kind, p, w, post, x)  # noqa: E731
             x = wrap(f)(p_mix, layers["norm1"][i], None if post1 is None else post1[i], x)

@@ -555,6 +555,93 @@ def _flash_bwd_bshd(scale, causal, use_pallas, dropout_rate, window,
 _flash_core_bshd.defvjp(_flash_fwd_bshd, _flash_bwd_bshd)
 
 
+# --- seq-major core with two head widths / a second score term -----------------
+
+def _xla_two_width(q, k, v, q2, k2, scale, causal):
+    """The composition the two-width kernels are held to: materialised fp32
+    scores ``(q·kᵀ + q2·k2ᵀ) * scale`` over (b, s, h, d) operands, every
+    narrower head axis repeated to q's. Differentiated by JAX."""
+    h = q.shape[2]
+    wide = lambda x: jnp.repeat(x, h // x.shape[2], axis=2)  # noqa: E731
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, wide(k), preferred_element_type=jnp.float32)
+    if q2 is not None:
+        s = s + jnp.einsum("bqhd,bkhd->bhqk", q2, wide(k2),
+                           preferred_element_type=jnp.float32)
+    s = s * scale
+    if causal:
+        sq, sk = s.shape[-2:]
+        s = jnp.where(jnp.arange(sk)[None, :] <= jnp.arange(sq)[:, None] + (sk - sq),
+                      s, _k.NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), wide(v))
+
+
+_head_major = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731  (b, s, h, d) <-> (b, h, s, d)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _flash_core_two_width(q, k, v, q2, k2, scale, causal, use_pallas):
+    return _flash_fwd_two_width(q, k, v, q2, k2, scale, causal, use_pallas)[0]
+
+
+def _flash_fwd_two_width(q, k, v, q2, k2, scale, causal, use_pallas):
+    if not use_pallas:
+        return _xla_two_width(q, k, v, q2, k2, scale, causal), (q, k, v, q2, k2)
+    # the narrow second term rides head-major (see pallas.flash_fwd_bshd);
+    # XLA folds the transposes into what made q2 and k2 (a rotary embedding)
+    second = None if q2 is None else (_head_major(q2), _head_major(k2))
+    o, lse = _k.flash_fwd_bshd(q, k, v, scale=scale, causal=causal, full_lse=True,
+                               interpret=_backend.interpret_mode(), second=second)
+    return o, (q, k, v, second, o, lse)
+
+
+def _flash_bwd_two_width(scale, causal, use_pallas, res, do):
+    if not use_pallas:
+        q, k, v, q2, k2 = res
+        return jax.vjp(lambda *a: _xla_two_width(*a, scale, causal), q, k, v, q2, k2)[1](do)
+    q, k, v, second, o, lse = res
+    dq, dk, dv, *d_second = _k.flash_bwd_bshd(
+        q, k, v, o, lse, do, scale=scale, causal=causal,
+        interpret=_backend.interpret_mode(), second=second)
+    return (dq, dk, dv, *(map(_head_major, d_second) if second else (None, None)))
+
+
+_flash_core_two_width.defvjp(_flash_fwd_two_width, _flash_bwd_two_width)
+
+
+def _two_width_attention(q, k, v, second, scale, causal, impl):
+    """``flash_attention(layout='bshd')`` where v's head is not q's width or a
+    second score term is given: checks, the kernel rule, the core."""
+    if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
+        raise ValueError(f"layout='bshd' takes (b, s, h, d) operands; got "
+                         f"{q.shape} / {k.shape} / {v.shape}")
+    if q.shape[2] % k.shape[2] or k.shape[:3] != v.shape[:3]:
+        raise ValueError(f"kv heads ({k.shape[2]}) must divide q heads ({q.shape[2]}) "
+                         f"with matching batch/seq dims")
+    if causal and q.shape[1] > k.shape[1]:
+        raise ValueError(f"causal attention requires sq <= sk; got "
+                         f"sq={q.shape[1]} > sk={k.shape[1]}")
+    q2, k2 = second if second is not None else (None, None)
+    b, sq, h, d = q.shape
+    sk, dv = k.shape[1], v.shape[3]
+    d2 = 0 if q2 is None else q2.shape[3]
+    if q2 is not None and (
+            q2.ndim != 4 or k2.ndim != 4 or
+            q2.shape[:3] != (b, sq, h) or k2.shape[:2] != (b, sk) or k2.shape[3] != d2
+            or k.shape[2] % k2.shape[2]):
+        raise ValueError(
+            f"second = (q2 (b, sq, h, d2), k2 (b, sk, h2, d2)) with h2 dividing the "
+            f"kv heads ({k.shape[2]}); got {q2.shape} / {k2.shape}")
+    ok = (bshd_kernel_ok(sq, sk, h, d, q.dtype) and dv % 128 == 0 and d2 % 64 == 0
+          and _k.bshd_two_width_fits(sq, sk, d, dv, d2, q.dtype.itemsize))
+    if (impl == "auto" and sk < flash_auto_crossover(d)
+            and not _backend.interpret_forced()):
+        impl = "xla"
+    use_pallas = _backend.choose_impl(impl, ok) == "pallas"
+    s_scale = float(scale if scale is not None else 1.0 / (d + d2) ** 0.5)
+    return _flash_core_two_width(q, k, v, q2, k2, s_scale, causal, use_pallas)
+
+
 # --- fused projection + attention block ---------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11, 12))
@@ -674,6 +761,7 @@ def flash_attention(
     kv_lens: Optional[jax.Array] = None, bias: Optional[jax.Array] = None,
     impl: str = "auto", layout: str = "bhsd", dropout_rate: float = 0.0,
     dropout_seed: Optional[jax.Array] = None, window: Optional[int] = None,
+    second: Optional[tuple] = None,
 ) -> jax.Array:
     """Blockwise attention over (..., seq, head_dim) with any number of
     leading batch/head dims. No sequence-length cap (cf. fmha's 512).
@@ -763,8 +851,34 @@ def flash_attention(
     (s, d) fp32 dk/dv accumulators inside the VMEM a kernel may ask for —
     the rule of :func:`apex_tpu.ops.pallas.attention.flash_bwd_bshd`):
     every score tile computed once, dk/dv summed over the kv group in VMEM.
-    Nothing here chooses it."""
+    Nothing here chooses it.
+
+    Two head widths (``layout='bshd'``; no ``bias``, ``kv_lens``, dropout or
+    ``window``): ``v`` (b, sk, h_kv, dv) may be wider or narrower than q and
+    k, and the output is (b, sq, h, dv). ``second = (q2 (b, sq, h, d2),
+    k2 (b, sk, h2, d2))`` adds a second score term, ``(q·kᵀ + q2·k2ᵀ) *
+    scale`` (default scale ``(d + d2) ** -0.5``), whose key may have fewer
+    heads than k (h2 | h_kv; one in latent attention, where q2/k2 are the
+    rotary features and k2 is shared by every head): the kernels
+    (``flash_fwd_bshd_mla``, ``flash_bwd_bshd_mla_fused``) read k2 by index
+    map, never repeated in HBM, pad d2 = 64 to a lane tile in VMEM only, and
+    sum dk2 over all its q heads in VMEM. Such a call has the one-pass
+    backward only: shapes it does not fit (``sq != sk``, accumulators past
+    the VMEM cap — about 20 k positions at d = dv = 128, d2 = 64 —, d or dv
+    no multiple of 128, d2 no multiple of 64) take the XLA composition,
+    which materialises the scores. Plain calls keep their split backward
+    for a bias, ``sq != sk`` and sequences past the cap, as above."""
     q, k, v = apply_op_rules("attention", q, k, v)
+    if second is not None or (layout == "bshd" and v.ndim == 4
+                              and v.shape[-1] != q.shape[-1]):
+        if (layout != "bshd" or bias is not None or kv_lens is not None
+                or dropout_rate > 0.0 or window is not None):
+            raise ValueError(
+                "two head widths or a second score term need layout='bshd' "
+                "and no bias, kv_lens, dropout or window")
+        if second is not None:
+            second = apply_op_rules("attention", *second)
+        return _two_width_attention(q, k, v, second, scale, causal, impl)
     if window is not None and (not causal or layout != "bshd" or bias is not None
                                or int(window) < 1):
         raise ValueError(

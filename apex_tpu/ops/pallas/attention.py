@@ -254,6 +254,20 @@ def _check_window(window, causal, bias, rel_bias):
         raise ValueError("a windowed call takes no score bias")
 
 
+def _check_second(second, window, bias, rel_bias, kv_lens, dropout_rate):
+    """A second score term rides the plain causal / full call only."""
+    if second is None:
+        return
+    if (window is not None or bias is not None or rel_bias is not None
+            or kv_lens is not None or dropout_rate > 0.0):
+        raise ValueError("a call with a second score term takes no window, "
+                         "score bias, kv_lens or dropout")
+    q2, k2 = second
+    if q2.ndim != 4 or k2.ndim != 4 or q2.shape[1] % k2.shape[1]:
+        raise ValueError(f"second = (q2 (b, h, sq, d2), k2 (b, h2, sk, d2)), "
+                         f"h2 | h; got {q2.shape} / {k2.shape}")
+
+
 def _band_walk(banded, n_own, b_own, b_other, n_other, shift, lo_reach, hi_reach):
     """``(steps, block)`` of a call's reduction axis: how many steps it
     takes and which block of the other axis step ``s`` of own block ``i``
@@ -273,7 +287,7 @@ def _band_walk(banded, n_own, b_own, b_other, n_other, shift, lo_reach, hi_reach
 # --- forward ------------------------------------------------------------------
 
 def _fwd_kernel(*refs, scale, causal, bq, bk, nk, off, varlen, bshd=False,
-                rate=0.0, has_bias=False, rel=None, window=None):
+                rate=0.0, has_bias=False, rel=None, window=None, second=False):
     """``varlen`` is a STATIC specialization flag: without kv lengths the
     kernel carries no length operand, no per-block length select, and no
     dynamic predicate conjunct — the common (non-padded) call pays nothing.
@@ -297,10 +311,18 @@ def _fwd_kernel(*refs, scale, causal, bq, bk, nk, off, varlen, bshd=False,
     exists anywhere.
     ``window`` (static): the banded walk of the section above — ``nk`` is
     then the band's run of kv blocks, not the sequence's.
+    ``second`` (static): a second score term — two more operands after v,
+    a (bq, d2) block of head-major q2 (b, h, sq, d2) and a (bk, d2) block of
+    k2 (b, h2, sk, d2), whose product is added to ``q·kᵀ`` before the scale
+    (latent attention's rotary part: one key shared by all heads). The value
+    block's width is the accumulator's and the output's; it need not be q's.
     """
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
     n = 3
+    if second:
+        q2_ref, k2_ref = refs[n:n + 2]
+        n += 2
     if has_bias:
         bias_ref = refs[n]
         n += 1
@@ -345,7 +367,12 @@ def _fwd_kernel(*refs, scale, causal, bq, bk, nk, off, varlen, bshd=False,
         k = k_ref[0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (bq, bk)
+        )
+        if second:
+            s = s + jax.lax.dot_general(
+                q2_ref[0, 0], k2_ref[0, 0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        s = s * scale  # (bq, bk)
         if has_bias:
             s = s + bias_ref[0].astype(jnp.float32)
         if rel is not None:
@@ -648,15 +675,21 @@ def _vmem_limit(nbytes):
     return min(_VMEM_CAP, max(_VMEM_FLOOR, nbytes))
 
 
-def _fused_bwd_vmem_bytes(s, d, bq, bk, itemsize):
+def _fused_bwd_vmem_bytes(s, d, bq, bk, itemsize, dv=None, d2=0):
     """VMEM the one-pass packed backward holds at once: the two whole-
     sequence fp32 dk/dv accumulators, their (double-buffered) output blocks
     at the kv dtype, the q/do/o/k/v/dq blocks, and the score-tile
     temporaries of one step (S, P, dP, dS in fp32, their MXU-dtype copies,
-    a dropout multiplier: under eight (bq, bk) fp32 tiles)."""
-    accumulators = 2 * s * d * 4
-    outputs = 2 * 2 * s * d * itemsize
-    blocks = 2 * (4 * bq + 2 * bk) * d * itemsize + bq * d * 4
+    a dropout multiplier: under eight (bq, bk) fp32 tiles). ``dv``: the
+    value head's width where it is not ``d``; ``d2``: the second score
+    term's, whose q2/k2/dq2 blocks, dk2 accumulator and output ride at
+    whole 128-lane tiles."""
+    dv = d if dv is None else dv
+    w = d + dv + -(-d2 // 128) * 128             # lanes resident per position
+    accumulators = s * w * 4
+    outputs = 2 * s * w * itemsize
+    blocks = (2 * (2 * bq + bk) * w * itemsize
+              + bq * (w - dv) * 4)
     tiles = 8 * bq * bk * 4
     return accumulators + outputs + blocks + tiles
 
@@ -674,7 +707,7 @@ def _split_bwd_vmem_limit(d, bq, bk, itemsize, out_itemsize):
 
 
 def _bwd_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h, h_kv,
-                      varlen, rate=0.0, window=None):
+                      varlen, rate=0.0, window=None, second=None):
     """One-pass backward of the seq-major layouts (the packed q|k|v buffer
     and separate bshd arrays: the two differ in index maps only): grid
     (b·h_kv, group, nq, nk), kv blocks innermost. Every (q block, kv block)
@@ -705,17 +738,33 @@ def _bwd_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h, h_kv,
     first block; the section on the band above), the mask is
     :func:`_visible`. Dropout regenerates the forward's mask: the same hash
     on the same global coordinates, ``t`` the q-head row of the forward
-    grid."""
+    grid.
+
+    ``second`` (static; the number of q heads that share one head of k2): a
+    second score term ``q2·k2ᵀ`` (see :func:`_fwd_kernel`) — two more
+    operands after lse, two more gradients (dq2 a q block, dk2 a whole
+    sequence) and their two fp32 scratches. dk2 is summed in VMEM like dk,
+    over ALL the q heads of its k2 head: they are consecutive rows of the
+    grid's first axis, which such a call therefore walks in order
+    (``"arbitrary"``), and the accumulator is zeroed at the first and
+    written at the last of them."""
     refs = list(refs)
     q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref = refs[:6]
     n = 6
+    if second:
+        q2_ref, k2_ref = refs[n:n + 2]
+        n += 2
     if varlen:
         kvlen_ref = refs[n]
         n += 1
     if rate > 0.0:
         seed_ref = refs[n]
         n += 1
-    dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, delta_scr = refs[n:]
+    if second:
+        (dq_ref, dk_ref, dv_ref, dq2_ref, dk2_ref,
+         dq_scr, dk_scr, dv_scr, delta_scr, dq2_scr, dk2_scr) = refs[n:]
+    else:
+        dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, delta_scr = refs[n:]
     r = pl.program_id(0)  # (batch, kv head) row
     g = pl.program_id(1)  # q head within the kv group
     i = pl.program_id(2)  # q block
@@ -736,6 +785,19 @@ def _bwd_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h, h_kv,
         dq_scr[...] = jnp.zeros_like(dq_scr)
         prod = do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32)
         delta_scr[...] = jnp.sum(prod.T, axis=0, keepdims=True)  # (1, bq)
+
+    if second:
+        # this q head's place among the q heads of its k2 head
+        place = ((r % h_kv) * group + g) % second
+        ends = jnp.logical_and(i == nq - 1, jj == nk - 1)
+
+        @pl.when(jnp.logical_and(place == 0, jnp.logical_and(i == 0, jj == 0)))
+        def _init_k2():
+            dk2_scr[...] = jnp.zeros_like(dk2_scr)
+
+        @pl.when(jj == 0)
+        def _visit2():
+            dq2_scr[...] = jnp.zeros_like(dq2_scr)
 
     # the band ends at the diagonal's block, so a step past it is a tile
     # above the diagonal: one test skips both
@@ -759,7 +821,14 @@ def _bwd_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h, h_kv,
         do = do_ref[0]
         st = jax.lax.dot_general(
             k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (bk, bq) = Sᵀ
+        )
+        if second:
+            q2 = q2_ref[0, 0]
+            k2 = k2_ref[0, 0]
+            st = st + jax.lax.dot_general(
+                k2, q2, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        st = st * scale  # (bk, bq) = Sᵀ
         if masked:
             cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
             if causal:
@@ -791,6 +860,13 @@ def _bwd_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h, h_kv,
         dq_scr[...] += jax.lax.dot_general(
             dst, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if second:
+            dk2_scr[kv_rows, :] += jax.lax.dot_general(
+                dst, q2, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dq2_scr[...] += jax.lax.dot_general(
+                dst, k2, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     if not causal and not varlen:
         _tile(False)
@@ -812,27 +888,57 @@ def _bwd_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h, h_kv,
             return carry
         jax.lax.fori_loop(0, dk_scr.shape[0] // bk, block, 0)
 
+    if second:
+        @pl.when(jj == nk - 1)
+        def _write_dq2():
+            dq2_ref[0, 0] = (dq2_scr[...] * scale).astype(dq2_ref.dtype)
 
-def _fused_bwd_fits(bias, rel_bias, sq, sk, d, bq, bk, itemsize):
+        @pl.when(jnp.logical_and(place == second - 1, ends))
+        def _write_dk2():
+            def block(blk, carry):
+                rows = pl.ds(pl.multiple_of(blk * bk, bk), bk)
+                dk2_ref[0, 0, rows, :] = (dk2_scr[rows, :] * scale).astype(
+                    dk2_ref.dtype)
+                return carry
+            jax.lax.fori_loop(0, dk2_scr.shape[0] // bk, block, 0)
+
+
+def _fused_bwd_fits(bias, rel_bias, sq, sk, d, bq, bk, itemsize, dv=None, d2=0):
     """The rule that picks the one-pass backward, read from the operands:
     no score bias of either kind (the dbias / dtable kernels take D as an
     operand), one sequence length (``sq == sk``: the tile walk assumes the
     diagonal starts at the origin), and accumulators, blocks and tile
     temporaries inside the VMEM a kernel may ask for."""
     return (bias is None and rel_bias is None and sq == sk
-            and _fused_bwd_vmem_bytes(sq, d, bq, bk, itemsize) <= _VMEM_CAP)
+            and _fused_bwd_vmem_bytes(sq, d, bq, bk, itemsize, dv, d2) <= _VMEM_CAP)
+
+
+def bshd_two_width_fits(sq, sk, d, dv, d2, itemsize):
+    """Whether the seq-major kernels take a call whose value head is ``dv``
+    wide beside q/k heads of ``d``, with a second score term of width ``d2``
+    (0: none): such a call has the one-pass backward only, so its shapes
+    must pass :func:`_fused_bwd_fits` at the blocks the call takes."""
+    return _fused_bwd_fits(None, None, sq, sk, d, _fit_block(sq, 1024), _fit_block(sk, 1024),
+                           itemsize, dv, d2)
 
 
 def _flash_bwd_fused(q3, k3, v3, o3, lse, do3, *, h, h_kv, d, packed, scale,
                      causal, kv_lens, bq, bk, interpret, dropout_rate,
-                     dropout_seed, window=None):
+                     dropout_seed, window=None, second=None):
     """Launch :func:`_bwd_fused_kernel` over folded (b, s, heads·d) operands;
     (dq (b, s, h·d), dk, dv (b, s, h_kv·d)) in the operands' dtypes.
     ``packed``: the three operands are one q|k|v buffer, whose k and v
     windows start at head columns ``h`` and ``h + h_kv``; separate bshd
-    arrays start at column 0. Nothing else tells the layouts apart."""
+    arrays start at column 0. Nothing else tells the layouts apart.
+    ``second = (q2 (b, h, s, d2), k2 (b, h2, s, d2))``, head-major: the second
+    score term; the call is then ``flash_bwd_bshd_mla_fused`` and returns
+    dq2 and dk2 in those shapes after dv. v, o and do may be narrower or
+    wider than q and k (``dv`` from v3's width)."""
     b, s, _ = q3.shape
     group = h // h_kv
+    dv = d if packed else v3.shape[-1] // h_kv
+    d2 = 0 if second is None else second[0].shape[-1]
+    share = None if second is None else h // second[1].shape[1]
     k_col, v_col = (h, h + h_kv) if packed else (0, 0)
     nq, nk = _blocks(s, bq), _blocks(s, bk)
     # (b, h, 1, s) lane rows: the transposed tile broadcasts lse along its
@@ -859,11 +965,38 @@ def _flash_bwd_fused(q3, k3, v3, o3, lse, do3, *, h, h_kv, d, packed, scale,
     dkm = lambda r, g, i, j: (r // h_kv, 0, r % h_kv)  # noqa: E731
     in_specs = [pl.BlockSpec((1, bq, d), qm),
                 pl.BlockSpec((1, bk, d), km),
-                pl.BlockSpec((1, bk, d), vm),
-                pl.BlockSpec((1, bq, d), qm),
-                pl.BlockSpec((1, bq, d), qm),
+                pl.BlockSpec((1, bk, dv), vm),
+                pl.BlockSpec((1, bq, dv), qm),
+                pl.BlockSpec((1, bq, dv), qm),
                 pl.BlockSpec((1, 1, 1, bq),
                              lambda r, g, i, j: (r // h_kv, head(r, g), 0, i))]
+    out_specs = [pl.BlockSpec((1, bq, d), qm),
+                 pl.BlockSpec((1, s, d), dkm),
+                 pl.BlockSpec((1, s, dv), dkm)]
+    out_shape = [jax.ShapeDtypeStruct((b, s, h * d), q3.dtype),
+                 jax.ShapeDtypeStruct((b, s, h_kv * d), k3.dtype),
+                 jax.ShapeDtypeStruct((b, s, h_kv * dv), v3.dtype)]
+    scratch_shapes = [pltpu.VMEM((bq, d), jnp.float32),
+                      pltpu.VMEM((s, d), jnp.float32),
+                      pltpu.VMEM((s, dv), jnp.float32),
+                      pltpu.VMEM((1, bq), jnp.float32)]
+    # dk/dv accumulate across the group, the q blocks and the kv blocks of
+    # one (batch, kv head) row: all three stay sequential
+    semantics = ("parallel", "arbitrary", "arbitrary", "arbitrary")
+    if second is not None:
+        q2m = lambda r, g, i, j: (r // h_kv, head(r, g), i, 0)  # noqa: E731
+        k2m = lambda r, g, i, j: (  # noqa: E731
+            r // h_kv, head(r, g) // share, kv_block(i, j), 0)
+        dk2m = lambda r, g, i, j: (r // h_kv, head(r, g) // share, 0, 0)  # noqa: E731
+        in_specs += [pl.BlockSpec((1, 1, bq, d2), q2m),
+                     pl.BlockSpec((1, 1, bk, d2), k2m)]
+        out_specs += [pl.BlockSpec((1, 1, bq, d2), q2m),
+                      pl.BlockSpec((1, 1, s, d2), dk2m)]
+        out_shape += [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in second]
+        scratch_shapes += [pltpu.VMEM((bq, d2), jnp.float32),
+                           pltpu.VMEM((s, d2), jnp.float32)]
+        # dk2 accumulates across the kv heads of a batch row too
+        semantics = ("arbitrary",) * 4
     tail_specs, tail_args = _tail_operands(
         kv_lens, b, dropout_rate, dropout_seed,
         lambda r, g, i, j: (r // h_kv, 0, 0))
@@ -872,35 +1005,22 @@ def _flash_bwd_fused(q3, k3, v3, o3, lse, do3, *, h, h_kv, d, packed, scale,
                           causal=causal, bq=bq, bk=bk, nq=nq, nk=steps,
                           group=group, h=h, h_kv=h_kv,
                           varlen=kv_lens is not None, rate=dropout_rate,
-                          window=window),
+                          window=window, second=share),
         name="flash_bwd_packed_fused" if packed else (
-            "flash_bwd_bshd_fused" if window is None
-            else "flash_bwd_bshd_win_fused"),
+            "flash_bwd_bshd_mla_fused" if second is not None else (
+                "flash_bwd_bshd_fused" if window is None
+                else "flash_bwd_bshd_win_fused")),
         grid=(b * h_kv, group, nq, steps),
         in_specs=in_specs + tail_specs,
-        out_specs=[pl.BlockSpec((1, bq, d), qm),
-                   pl.BlockSpec((1, s, d), dkm),
-                   pl.BlockSpec((1, s, d), dkm)],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, s, h * d), q3.dtype),
-            jax.ShapeDtypeStruct((b, s, h_kv * d), k3.dtype),
-            jax.ShapeDtypeStruct((b, s, h_kv * d), v3.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((s, d), jnp.float32),
-            pltpu.VMEM((s, d), jnp.float32),
-            pltpu.VMEM((1, bq), jnp.float32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch_shapes,
         compiler_params=pltpu.CompilerParams(
-            # dk/dv accumulate across the group, the q blocks and the kv
-            # blocks of one (batch, kv head) row: all three stay sequential
-            dimension_semantics=("parallel", "arbitrary", "arbitrary",
-                                 "arbitrary"),
+            dimension_semantics=semantics,
             vmem_limit_bytes=_vmem_limit(_fused_bwd_vmem_bytes(
-                s, d, bq, bk, q3.dtype.itemsize))),
+                s, d, bq, bk, q3.dtype.itemsize, dv, d2))),
         interpret=interpret,
-    )(q3, k3, v3, do3, o3, lse_rows, *tail_args)
+    )(q3, k3, v3, do3, o3, lse_rows, *(second or ()), *tail_args)
 
 
 def flash_bwd_packed(qkv, h, h_kv, d, o, lse, do, *, scale, causal,
@@ -1066,8 +1186,9 @@ def flash_bwd_packed(qkv, h, h_kv, d, o, lse, do, *, scale, causal,
 def flash_fwd_bshd(q, k, v, *, scale, causal, kv_lens=None, bias=None,
                    rel_bias=None, bq=1024, bk=1024, full_lse=False,
                    interpret=False, dropout_rate=0.0, dropout_seed=None,
-                   window=None):
-    """Seq-major flash forward: q (b, sq, h, d); k/v (b, sk, h_kv, d).
+                   window=None, second=None):
+    """Seq-major flash forward: q (b, sq, h, d); k (b, sk, h_kv, d); v
+    (b, sk, h_kv, dv), whose width is the output's and need not be d.
 
     The (s, h·d)-minor layout is exactly what the QKV projection GEMMs
     emit, so no layout conversion feeds the kernel (removes the
@@ -1088,15 +1209,25 @@ def flash_fwd_bshd(q, k, v, *, scale, causal, kv_lens=None, bias=None,
 
     ``window`` (static int; causal, no bias): a query sees its last
     ``window`` keys, itself among them. The call is then named
-    ``flash_fwd_bshd_win`` and walks each q block's band only."""
+    ``flash_fwd_bshd_win`` and walks each q block's band only.
+
+    ``second = (q2 (b, h, sq, d2), k2 (b, h2, sk, d2))``, HEAD-major, h2 | h:
+    a second score term, ``(q·kᵀ + q2·k2ᵀ) * scale`` — latent attention's
+    rotary part, whose one key serves every head and is read by index map,
+    never repeated in HBM. Head-major because d2 is narrower than a lane
+    tile (64): a (bq, d2) block of a (s, d2) plane is legal where a d2-wide
+    column block of a folded (s, h·d2) view is not. The call is then named
+    ``flash_fwd_bshd_mla`` (no window, bias, lengths or dropout with it)."""
     b, sq, h, d = q.shape
     sk, h_kv = k.shape[1], k.shape[2]
+    dv = v.shape[3]
     group = h // h_kv
     bq, bk = _bias_blocks(bias, bq, bk)
     bq, bk = _fit_block(sq, bq), _fit_block(sk, bk)
     nq, nk = _blocks(sq, bq), _blocks(sk, bk)
     off = sk - sq
     _check_window(window, causal, bias, rel_bias)
+    _check_second(second, window, bias, rel_bias, kv_lens, dropout_rate)
     steps, kv_block = _band_walk(window is not None, nq, bq, bk, nk, off,
                                  (window or 1) - 1, 0)
     varlen = kv_lens is not None
@@ -1106,17 +1237,27 @@ def flash_fwd_bshd(q, k, v, *, scale, causal, kv_lens=None, bias=None,
     rhb = 0 if rel is None else rel[0].shape[0]
 
     args = [q.reshape(b, sq, h * d), k.reshape(b, sk, h_kv * d),
-            v.reshape(b, sk, h_kv * d)]
+            v.reshape(b, sk, h_kv * dv)]
     in_specs = [
         pl.BlockSpec((1, bq, d),
                      lambda t, i, j, h=h: (t // h, i, t % h)),
         pl.BlockSpec((1, bk, d),
                      lambda t, i, j, h=h, g=group:
                      (t // h, kv_block(i, j), (t % h) // g)),
-        pl.BlockSpec((1, bk, d),
+        pl.BlockSpec((1, bk, dv),
                      lambda t, i, j, h=h, g=group:
                      (t // h, kv_block(i, j), (t % h) // g)),
     ]
+    if second is not None:
+        d2, share = second[0].shape[-1], h // second[1].shape[1]
+        args += list(second)
+        in_specs += [
+            pl.BlockSpec((1, 1, bq, d2),
+                         lambda t, i, j, h=h: (t // h, t % h, i, 0)),
+            pl.BlockSpec((1, 1, bk, d2),
+                         lambda t, i, j, h=h, g=share:
+                         (t // h, (t % h) // g, kv_block(i, j), 0)),
+        ]
     tail_specs, tail_args = _tail_operands(
         kv_lens, b, dropout_rate, dropout_seed,
         lambda t, i, j, h=h: (t // h, 0, 0),
@@ -1130,31 +1271,32 @@ def flash_fwd_bshd(q, k, v, *, scale, causal, kv_lens=None, bias=None,
                           bq=bq, bk=bk, nk=steps, off=off, varlen=varlen,
                           bshd=True, rate=dropout_rate,
                           has_bias=bias is not None, rel=rel_static,
-                          window=window),
-        name="flash_fwd_bshd" if window is None else "flash_fwd_bshd_win",
+                          window=window, second=second is not None),
+        name="flash_fwd_bshd_mla" if second is not None else (
+            "flash_fwd_bshd" if window is None else "flash_fwd_bshd_win"),
         grid=(b * h, nq, steps),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, bq, d),
+            pl.BlockSpec((1, bq, dv),
                          lambda t, i, j, h=h: (t // h, i, t % h)),
             pl.BlockSpec((1, 1, bq, _LSE_LANES),
                          lambda t, i, j, h=h: (t // h, t % h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, sq, h * d), q.dtype),
+            jax.ShapeDtypeStruct((b, sq, h * dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, sq, _LSE_LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
     )(*args)
-    return o.reshape(b, sq, h, d), (lse if full_lse else lse[..., 0])
+    return o.reshape(b, sq, h, dv), (lse if full_lse else lse[..., 0])
 
 
 # --- backward -----------------------------------------------------------------
@@ -1735,7 +1877,7 @@ def flash_bwd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
 def flash_bwd_bshd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
                    bias=None, rel_bias=None, bq=1024, bk=1024,
                    interpret=False, dropout_rate=0.0, dropout_seed=None,
-                   window=None):
+                   window=None, second=None):
     """Seq-major backward (cf. :func:`flash_fwd_bshd`): q/o/do
     (b, sq, h, d), k/v (b, sk, h_kv, d), lse (b, h, sq) or the
     (b, h, sq, LANES) carrier from ``flash_fwd_bshd(full_lse=True)``.
@@ -1756,26 +1898,39 @@ def flash_bwd_bshd(q, k, v, o, lse, do, *, scale, causal, kv_lens=None,
     past the cap ride the dq/dkv split: ``flash_bwd_bshd_dq`` / ``_dkv``
     (``flash_bwd_bshd_win_dq`` / ``_win_dkv`` with ``window``), with D and
     its carrier built by XLA, per-q-head fp32 dk/dv partials and
-    :func:`_group_sum`."""
+    :func:`_group_sum`.
+
+    A value head of another width than q's (v, o, do (…, dv)) and a second
+    score term (``second``, as :func:`flash_fwd_bshd` takes it; the call is
+    ``flash_bwd_bshd_mla_fused`` and returns dq2, dk2 after dv) exist in the
+    one-pass kernel only: ask :func:`bshd_two_width_fits` first. dk2 is
+    summed over all the q heads of its key in VMEM, as dk over its group."""
     b, sq, h, d = q.shape
     sk, h_kv = k.shape[1], k.shape[2]
+    wv = v.shape[3]
     group = h // h_kv
     bq, bk = _bias_blocks(bias, bq, bk)
     bq, bk = _fit_block(sq, bq), _fit_block(sk, bk)
     nq, nk = _blocks(sq, bq), _blocks(sk, bk)
     off = sk - sq
     _check_window(window, causal, bias, rel_bias)
-    if _fused_bwd_fits(bias, rel_bias, sq, sk, d, bq, bk, q.dtype.itemsize):
+    _check_second(second, window, bias, rel_bias, kv_lens, dropout_rate)
+    d2 = 0 if second is None else second[0].shape[-1]
+    if _fused_bwd_fits(bias, rel_bias, sq, sk, d, bq, bk, q.dtype.itemsize, wv, d2):
         # folded (b, s, h·d) views — free bitcasts (see flash_fwd_bshd)
-        dq, dk, dv = _flash_bwd_fused(
+        dq, dk, dv, *d_second = _flash_bwd_fused(
             q.reshape(b, sq, h * d), k.reshape(b, sk, h_kv * d),
-            v.reshape(b, sk, h_kv * d), o.reshape(b, sq, h * d), lse,
-            do.reshape(b, sq, h * d), h=h, h_kv=h_kv, d=d, packed=False,
+            v.reshape(b, sk, h_kv * wv), o.reshape(b, sq, h * wv), lse,
+            do.reshape(b, sq, h * wv), h=h, h_kv=h_kv, d=d, packed=False,
             scale=scale, causal=causal, kv_lens=kv_lens, bq=bq, bk=bk,
             interpret=interpret, dropout_rate=dropout_rate,
-            dropout_seed=dropout_seed, window=window)
+            dropout_seed=dropout_seed, window=window, second=second)
         return (dq.reshape(b, sq, h, d), dk.reshape(b, sk, h_kv, d),
-                dv.reshape(b, sk, h_kv, d))
+                dv.reshape(b, sk, h_kv, wv), *d_second)
+    if second is not None or wv != d:
+        raise NotImplementedError(
+            "two head widths or a second score term: the one-pass backward "
+            "only (bshd_two_width_fits)")
     banded, reach = window is not None, (window or 1) - 1
     k_steps, kv_block = _band_walk(banded, nq, bq, bk, nk, off, reach, 0)
     q_steps, q_block = _band_walk(banded, nk, bk, bq, nq, -off, 0, reach)

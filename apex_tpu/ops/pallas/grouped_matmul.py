@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu.ops.pallas import exact_block
+
 TM = 128
 _VMEM = 64 * 2 ** 20   # of the chip's 128 MiB: a whole (K, N) expert matrix twice
 
@@ -110,7 +112,9 @@ def moe_gmm_dw(x, dy, tile_expert, n_used, num_experts, *, interpret=False):
     """x (M, K), dy (M, N) -> dw (E, K, N) in ``x``'s dtype."""
     M, K = x.shape
     N = dy.shape[1]
-    bn = min(N, 512)
+    # a column block that DIVIDES N (2,816 = 11 x 256): a last partial block
+    # would lie outside the grid and its columns would keep the zeros
+    bn = exact_block(N, 512, 128) or N
     tiles = M // TM
     # unused tiles keep the last used tile's block, so nothing is fetched or
     # written back for them

@@ -429,7 +429,8 @@ def moe_layer(
 # blocks as the routing fills. What a block costs does not depend on how
 # many there are.
 
-def route_topk(x, router, k, *, normalize=True, score="softmax", bias=None, scale=1.0):
+def route_topk(x, router, k, *, normalize=True, score="softmax", bias=None, scale=1.0,
+               sequences=None):
     """Router at its full width: ``p = softmax_fp32(x @ router)``, the top
     ``k`` (ids (T, k) int32, weights (T, k) float32, renormalised to sum 1
     when ``normalize``), the Switch load-balance term ``E sum_e f_e P_e``
@@ -441,7 +442,13 @@ def route_topk(x, router, k, *, normalize=True, score="softmax", bias=None, scal
     (such a router is balanced by ``bias``, not by a loss). ``bias`` (E,)
     float32 enters the choice of the top ``k`` and not their weights, and
     carries no gradient (:func:`router_bias_update` moves it). ``scale``
-    multiplies the weights last."""
+    multiplies the weights last.
+
+    ``sequences`` (int; the T tokens are that many rows of T / sequences):
+    the balance term taken per sequence and averaged over them — for each
+    row ``E sum_e f_e P_e`` with ``f`` the share of the row's assignments
+    and ``P`` the row's mean probability (DeepSeek's ``seq_aux``). ``None``:
+    the batch-wise term above."""
     logits = jnp.dot(x, router.astype(x.dtype), preferred_element_type=jnp.float32)
     p = jax.nn.softmax(logits, axis=-1) if score == "softmax" else jax.nn.sigmoid(logits)
     if bias is None:
@@ -455,12 +462,20 @@ def route_topk(x, router, k, *, normalize=True, score="softmax", bias=None, scal
     if scale != 1.0:
         top_p = top_p * scale
     E = router.shape[-1]
-    counts = jnp.sum(top_e[..., None] == jnp.arange(E, dtype=top_e.dtype),
-                     axis=(0, 1), dtype=jnp.int32)
+    chosen = top_e[..., None] == jnp.arange(E, dtype=top_e.dtype)
     aux = jnp.float32(0.0)
-    if score == "softmax":
-        share = counts.astype(jnp.float32) / (x.shape[0] * k)
-        aux = E * jnp.sum(share * jnp.mean(p, axis=0))
+    if sequences is None:
+        counts = jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32)
+        if score == "softmax":
+            share = counts.astype(jnp.float32) / (x.shape[0] * k)
+            aux = E * jnp.sum(share * jnp.mean(p, axis=0))
+    else:
+        by_row = lambda a: a.reshape(sequences, -1, *a.shape[1:])  # noqa: E731
+        row_counts = jnp.sum(by_row(chosen), axis=(1, 2), dtype=jnp.int32)
+        counts = jnp.sum(row_counts, axis=0)
+        if score == "softmax":
+            share = row_counts.astype(jnp.float32) / (x.shape[0] // sequences * k)
+            aux = E * jnp.mean(jnp.sum(share * jnp.mean(by_row(p), axis=1), axis=-1))
     return top_e.astype(jnp.int32), top_p, aux, counts
 
 
@@ -692,7 +707,8 @@ def dropless_block_rows(tokens, top_k, held, width):
 
 def dropless_moe_layer(params, x, *, top_k, experts_held=None,
                        normalize_weights=True, impl="auto", score="softmax",
-                       route_scale=1.0, router_bias=None, shared_gate=True):
+                       route_scale=1.0, router_bias=None, shared_gate=True,
+                       sequence_balance=False):
     """Sparse SwiGLU experts without token dropping, plus a shared expert,
     over ``x`` (..., hidden).
 
@@ -703,7 +719,10 @@ def dropless_moe_layer(params, x, *, top_k, experts_held=None,
     ``sigmoid(x . shared_mix)`` unless ``shared_gate`` is False, when it is
     added as it is and the leaf is not read. ``score``, ``route_scale`` and
     ``router_bias`` (E,) are :func:`route_topk`'s. ``experts_held = (first, count)``
-    says which of the router's experts these are (default: all). The layer
+    says which of the router's experts these are (default: all).
+    ``sequence_balance``: the balance term per sequence, ``x``'s leading dims
+    but the last being the sequences (:func:`route_topk`'s ``sequences``);
+    default the batch-wise term. The layer
     routes every token over all E experts, computes the part of the result
     its own experts give and adds nothing for the absent ones — what one
     member of an expert-parallel group computes before the exchange (on one
@@ -728,7 +747,8 @@ def dropless_moe_layer(params, x, *, top_k, experts_held=None,
     with monitor_spans.span("moe/route"):
         top_e, top_p, aux_loss, counts = route_topk(
             xt, params["router"], top_k, normalize=normalize_weights, score=score,
-            bias=router_bias, scale=route_scale)
+            bias=router_bias, scale=route_scale,
+            sequences=max(1, T // lead[-1]) if sequence_balance and lead else None)
         rows = -(-dropless_block_rows(T, top_k, held[1], E) // gk.TM) * gk.TM
         # under jax.checkpoint a policy may keep the plan by this name, so
         # that the backward pass does not sort again

@@ -259,3 +259,24 @@ def test_eight_sigmoid_shares_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(sum(parts) + shared, uncut, atol=1e-5 * scale)
     np.testing.assert_allclose(sum(ref_parts) + shared, uncut, atol=1e-5 * scale)
     assert np.concatenate(loads).sum() == K * x.shape[0]
+
+
+@pytest.mark.parametrize("N", [768, 2816, 1024])
+def test_grouped_dw_writes_every_column(N):
+    """``moe_gmm_dw`` takes column blocks that divide N: at 2 x 1,408 = 2,816
+    (= 5.5 x 512) and at 768 a 512-block left the last 256 columns of every
+    expert's gradient unwritten, silently zero."""
+    from apex_tpu.ops.pallas import grouped_matmul as gk
+    M, K, E = 4 * gk.TM, 128, 3
+    x = jax.random.normal(jax.random.PRNGKey(0), (M, K))
+    dy = jax.random.normal(jax.random.PRNGKey(1), (M, N))
+    tile_expert = jnp.array([0, 0, 2, 2], jnp.int32)
+    n_used = jnp.array([3], jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        got = gk.moe_gmm_dw(x, dy, tile_expert, n_used, E, interpret=True)
+        per_tile = jnp.einsum("tmk,tmn->tkn", x.reshape(4, gk.TM, K)[:3],
+                              dy.reshape(4, gk.TM, N)[:3])
+    want = jax.ops.segment_sum(per_tile, tile_expert[:3], num_segments=E)
+    np.testing.assert_allclose(got, want, atol=1e-3)
+    assert float(jnp.min(jnp.max(jnp.abs(got[0]), axis=0))) > 0.1     # no column left at zero
+    assert float(jnp.max(jnp.abs(got[1]))) == 0.0                     # an expert with no tile

@@ -32,7 +32,10 @@ EXPECTED = {
         "flash_bwd_bshd_fused",
         # the seq-major kernels on a sliding window: still flash_fwd* / flash_bwd*
         "flash_fwd_bshd_win", "flash_bwd_bshd_win_fused",
-        "flash_bwd_bshd_win_dq", "flash_bwd_bshd_win_dkv"},
+        "flash_bwd_bshd_win_dq", "flash_bwd_bshd_win_dkv",
+        # two head widths and a second score term (latent attention): the same
+        # two bodies, still flash_fwd* / flash_bwd*
+        "flash_fwd_bshd_mla", "flash_bwd_bshd_mla_fused"},
     "xentropy.py": {"xentropy_stats"},
     "decode_attention.py": {"decode_attn", "decode_attn_paged"},
     "layer_norm.py": {"ln_fwd", "ln_bwd"},
@@ -79,7 +82,7 @@ def test_every_pallas_call_has_a_literal_name(filename):
 def test_kernel_names_are_distinct_across_the_package():
     assert set(KERNEL_FILES) == set(EXPECTED)
     names = [n for f in KERNEL_FILES for n in literal_names(f)]
-    assert len(names) == 37 and len(set(names)) == 37
+    assert len(names) == 39 and len(set(names)) == 39
 
 
 def pallas_eqns(jaxpr):
@@ -125,6 +128,30 @@ def test_banded_flash_equations_carry_their_own_names():
     assert names == ["flash_fwd_bshd_win", "flash_bwd_bshd_win_fused"]
     assert "flash_fwd" in names[0] and "flash_bwd" in names[1]
     assert "flash_fwd_bshd_win" in names[0] and "flash_bwd_bshd_win" in names[1]
+
+
+def test_latent_flash_equations_carry_their_own_names():
+    """A call with a second score term is told apart by name, and every
+    accepted flash reader's part (``flash_fwd`` / ``flash_bwd``) is still in
+    it; the banded readers' parts are not."""
+    from apex_tpu.ops.attention import flash_attention
+
+    def loss(q, k, v, q2, k2):
+        return flash_attention(q, k, v, causal=True, impl="pallas", layout="bshd",
+                               second=(q2, k2)).sum()
+
+    q = jnp.ones((1, 256, 2, 128), jnp.float32)
+    q2 = jnp.ones((1, 256, 2, 64), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)))(
+        q, q, q, q2, q2[:, :, :1])
+    names = kernel_names(jaxpr.jaxpr)
+    assert names == ["flash_fwd_bshd_mla", "flash_bwd_bshd_mla_fused"]
+    assert "flash_fwd" in names[0] and "flash_bwd" in names[1]
+    assert not any("_win" in n or "packed" in n for n in names)
+    # the shared key rides at ONE head, head-major, and nothing is 192 or 256 wide
+    shapes = [v.aval.shape for eqn in pallas_eqns(jaxpr.jaxpr) for v in eqn.invars]
+    assert (1, 1, 256, 64) in shapes and (1, 2, 256, 64) in shapes
+    assert all(s[-1] in (64, 128, 256, 8) for s in shapes if len(s) >= 3)
 
 
 BSHD_SPLIT = ["flash_bwd_bshd_dq", "flash_bwd_bshd_dkv"]

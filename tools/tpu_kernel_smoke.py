@@ -356,6 +356,47 @@ def _flash_cell_family(h, h_kv, d, window):
     return build
 
 
+def _latent_cell_family(s=8192):
+    """The two-width kernels at ``dsv2lite-train-8k``'s attention shape, 2
+    rows of 8,192, 16 heads of 128 (+ 64 rotary features against ONE shared
+    key) with values of 128: ``flash_fwd_bshd_mla`` and the one-pass
+    ``flash_bwd_bshd_mla_fused`` (dq, dk, dv, dq2 and dk2 — the last summed
+    over all 16 heads in VMEM) against XLA's materialised scores, one batch
+    row and four heads at a time, dk2 summed in float32 over the slices."""
+    heads, q_heads, scale = 16, 4, 192 ** -0.5 * 1.5896
+
+    def build():
+        shapes = ((2, s, heads, 128),) * 3 + ((2, s, heads, 64), (2, s, 1, 64))
+        args = tuple(jr.normal(_key(61 + i), shape, jnp.bfloat16)
+                     for i, shape in enumerate(shapes))
+
+        def attention(impl):
+            return lambda q, k, v, q2, k2: flash_attention(
+                q, k, v, causal=True, layout="bshd", impl=impl, scale=scale, second=(q2, k2))
+
+        def sliced(q, k, v, q2, k2):
+            b, n = q.shape[0], heads // q_heads
+            cot = jr.normal(_key(99), v.shape[:2] + (heads, 128), jnp.float32)
+
+            def one(idx):
+                row, first = idx // n, (idx % n) * q_heads
+                cut = lambda x, hs=q_heads: jax.lax.dynamic_slice(  # noqa: E731
+                    x, (row, 0, first if hs > 1 else 0, 0), (1, s, hs, x.shape[3]))
+                out, pull = jax.vjp(attention("xla"), cut(q), cut(k), cut(v), cut(q2),
+                                    cut(k2, 1))
+                dq, dk, dv, dq2, dk2 = pull(cut(cot).astype(out.dtype))
+                return out[0], dq[0], dk[0], dv[0], dq2[0], dk2[0].astype(jnp.float32)
+
+            out, dq, dk, dv, dq2, dk2 = jax.lax.map(one, jnp.arange(b * n))
+            lay = lambda x: x.reshape(b, n, s, q_heads, x.shape[-1]).transpose(  # noqa: E731
+                0, 2, 1, 3, 4).reshape(b, s, heads, x.shape[-1])
+            dk2 = dk2.reshape(b, n, s, 1, 64).sum(1).astype(k2.dtype)
+            return lay(out), (lay(dq), lay(dk), lay(dv), lay(dq2), dk2)
+
+        return _fwd_and_grads(attention("pallas"), (0, 1, 2, 3, 4)), sliced, args
+    return build
+
+
 def _delta_rule_family():
     """``gdn_fwd`` / ``gdn_bwd`` (chunk operands and the 64 x 64 inverse built
     in VMEM, then the recurrence over chunks) against the XLA form (operands
@@ -540,6 +581,8 @@ FAMILIES = (
            _flash_cell_family(32, 4, 128, 2048)),
     Family("flash bshd 8,192, 16 / 2 heads of 256, fwd + one-pass bwd",
            _flash_cell_family(16, 2, 256, None)),
+    Family("flash bshd latent 8,192, 16 heads of 128 + 64 shared / 128, fwd + one-pass bwd",
+           _latent_cell_family()),
     Family("gated delta rule gdn_fwd/gdn_bwd", _delta_rule_family()),
     Family("gated delta rule on drifted keys, against the recurrence",
            _delta_rule_drifted_family()),
