@@ -36,6 +36,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from apex_tpu.monitor import spans as monitor_spans
 from apex_tpu.ops import _backend
+from apex_tpu.ops.pallas import expert_rows as rk
 from apex_tpu.ops.pallas import grouped_matmul as gk
 
 
@@ -426,8 +427,9 @@ def moe_layer(
 # rows start on a tile boundary (``ops/pallas/grouped_matmul``). The rows are
 # computed a block at a time (``dropless_block_rows``: as many rows as there
 # are tokens where a rank holds a small share of the experts), as many
-# blocks as the routing fills. What a block costs does not depend on how
-# many there are.
+# blocks as the routing fills. A block is a unit of memory, not of cost: the
+# movements between tokens and rows (``ops/pallas/expert_rows``) and the
+# grouped products stop at the tiles in use.
 
 def route_topk(x, router, k, *, normalize=True, score="softmax", bias=None, scale=1.0,
                sequences=None):
@@ -495,7 +497,12 @@ def dropless_plan(top_e, counts, experts_held, block_rows, tile):
     nearly empty) in whole blocks of ``block_rows``. Returns a dict of int32
     / bool arrays: ``tile_expert`` (tiles,) the held expert of every tile,
     ``n_used`` () tiles in use, ``row_token``, ``row_assign``, ``row_valid``
-    (rows,), ``pos`` (T, k) the row of each assignment, ``local`` (T, k)."""
+    (rows,), ``pos`` (T, k) the row of each assignment, ``local`` (T, k);
+    and, for the way back, the local assignments as they stand in token
+    order (one cumulative sum, no second sort): ``tile_rows`` (T / TT, L)
+    the rows that each tile of ``expert_rows.TT`` tokens sums, ``tile_count``
+    how many, ``rank`` (T, k) each assignment's place in its tile's list
+    (-1: not local; T rounded up to whole tiles in all three)."""
     first, count = experts_held
     T, k = top_e.shape
     N = T * k
@@ -521,9 +528,22 @@ def dropless_plan(top_e, counts, experts_held, block_rows, tile):
     row_assign = order[jnp.clip(start[e] + within, 0, N - 1)]
     e_of = jnp.minimum(key, count - 1)
     pos = (tile_start[e_of] * tile + place - start[e_of]).reshape(T, k)
+    token_tiles = -(-T // rk.TT)
+    by_tile = lambda a: jnp.pad(a, ((0, token_tiles * rk.TT - T), (0, 0))  # noqa: E731
+                                ).reshape(token_tiles, rk.TT * k)
+    listed = by_tile(local)
+    upto = jnp.cumsum(listed, axis=1, dtype=jnp.int32)
+    rank = jnp.where(listed, upto - 1, -1)
+    length = rk.list_length(min(k, count))
+    slot = jnp.where(listed, jnp.arange(token_tiles, dtype=jnp.int32)[:, None] * length + rank,
+                     token_tiles * length)
+    tile_rows = jnp.zeros((token_tiles * length,), jnp.int32).at[slot.reshape(-1)].set(
+        by_tile(pos).reshape(-1), mode="drop", unique_indices=True)
     return {"tile_expert": tile_expert, "n_used": n_used.astype(jnp.int32),
             "row_token": row_assign // k, "row_assign": row_assign,
-            "row_valid": row_valid, "pos": pos, "local": local}
+            "row_valid": row_valid, "pos": pos, "local": local,
+            "tile_rows": tile_rows.reshape(token_tiles, length), "tile_count": upto[:, -1],
+            "rank": rank.reshape(-1, k)}
 
 
 def _f0(a):
@@ -531,49 +551,86 @@ def _f0(a):
     return np.zeros(a.shape, jax.dtypes.float0)
 
 
-@jax.custom_vjp
-def _rows_from_tokens(x, row_token, row_valid, pos, sel):
+def _rows_impl(impl, a):
+    """The movements' implementation. A width the grouped products take and
+    the row DMA cannot (compiled: no multiple of 2,048 bf16) keeps XLA's
+    movements under ``impl="pallas"`` too, as it ran before the row kernels."""
+    ok = rk.shapes_ok(a.shape[-1], a.dtype, _backend.interpret_mode())
+    return _backend.choose_impl(impl if ok else "xla", ok)
+
+
+def _gather_rows(x, move, scale, dot_with=None):
+    """``moe_rows_gather`` over one block: row r takes ``scale[r]`` times the
+    token ``row_token[r]`` (rows that ``row_valid`` excludes: a scale of 0)."""
+    return rk.moe_rows_gather(
+        rk.as_groups(x), move["row_token"],
+        jnp.where(move["row_valid"], scale, 0.0), move["n_used"], dot_with,
+        width=x.shape[-1], dtype=x.dtype, interpret=_backend.interpret_mode())
+
+
+def _combine_rows(y, move, weights=None):
+    """``moe_rows_combine`` over one block: every token sums the rows its
+    selected assignments own, each times its weight (None: 1)."""
+    tokens = move["pos"].shape[0]
+    if weights is not None:
+        weights = jnp.pad(weights.astype(jnp.float32),
+                          ((0, move["rank"].shape[0] - tokens), (0, 0)))
+    interpret = _backend.interpret_mode()
+    return rk.moe_rows_combine(
+        rk.moe_rows_pack(y, move["n_used"], interpret=interpret), move["tile_rows"],
+        move["tile_count"], move["rank"], weights,
+        tokens=tokens, width=y.shape[-1], dtype=y.dtype, interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rows_from_tokens(x, move, impl):
     """(T, H) tokens -> this block's (R, H) rows: a gather, and a gather
     back (each token sums the rows its selected assignments own)."""
-    return jnp.where(row_valid[:, None], x[row_token], 0).astype(x.dtype)
+    if _rows_impl(impl, x) == "pallas":
+        return _gather_rows(x, move, 1.0)
+    return jnp.where(move["row_valid"][:, None], x[move["row_token"]], 0).astype(x.dtype)
 
 
-def _rows_fwd(x, row_token, row_valid, pos, sel):
-    return _rows_from_tokens(x, row_token, row_valid, pos, sel), (row_token, row_valid, pos, sel)
+def _rows_fwd(x, move, impl):
+    return _rows_from_tokens(x, move, impl), move
 
 
-def _rows_bwd(res, g):
-    row_token, row_valid, pos, sel = res
-    picked = jnp.where(sel[..., None], g[pos], 0)             # (T, k, H)
+def _rows_bwd(impl, move, g):
+    if _rows_impl(impl, g) == "pallas":
+        return _combine_rows(g, move), jax.tree.map(_f0, move)
+    picked = jnp.where(move["sel"][..., None], g[move["pos"]], 0)             # (T, k, H)
     dx = jnp.sum(picked.astype(jnp.float32), axis=1).astype(g.dtype)
-    return dx, _f0(row_token), _f0(row_valid), _f0(pos), _f0(sel)
+    return dx, jax.tree.map(_f0, move)
 
 
 _rows_from_tokens.defvjp(_rows_fwd, _rows_bwd)
 
 
-@jax.custom_vjp
-def _tokens_from_rows(y, weights, row_token, row_assign, row_valid, pos, sel):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _tokens_from_rows(y, weights, move, impl):
     """out[t] = sum_k sel[t, k] weights[t, k] y[pos[t, k]] (float32 sum);
     its cotangent for ``y`` is again a gather, by ``row_token``."""
-    picked = jnp.where(sel[..., None], y[pos], 0).astype(jnp.float32)
+    if _rows_impl(impl, y) == "pallas":
+        return _combine_rows(y, move, weights)
+    picked = jnp.where(move["sel"][..., None], y[move["pos"]], 0).astype(jnp.float32)
     return jnp.einsum("tkh,tk->th", picked, weights).astype(y.dtype)
 
 
-def _tokens_fwd(y, weights, row_token, row_assign, row_valid, pos, sel):
-    return (_tokens_from_rows(y, weights, row_token, row_assign, row_valid, pos, sel),
-            (y, weights, row_token, row_assign, row_valid, pos, sel))
+def _tokens_fwd(y, weights, move, impl):
+    return _tokens_from_rows(y, weights, move, impl), (y, weights, move)
 
 
-def _tokens_bwd(res, dout):
-    y, weights, row_token, row_assign, row_valid, pos, sel = res
-    d_row = jnp.where(row_valid[:, None], dout[row_token], 0)  # (R, H)
-    row_weight = weights.reshape(-1)[row_assign]
-    dy = (d_row.astype(jnp.float32) * row_weight[:, None]).astype(y.dtype)
-    dots = jnp.sum(d_row.astype(jnp.float32) * y.astype(jnp.float32), axis=-1)
-    dweights = jnp.where(sel, dots[pos], 0.0).astype(weights.dtype)
-    return (dy, dweights, _f0(row_token), _f0(row_assign), _f0(row_valid),
-            _f0(pos), _f0(sel))
+def _tokens_bwd(impl, res, dout):
+    y, weights, move = res
+    row_weight = weights.reshape(-1)[move["row_assign"]]
+    if _rows_impl(impl, y) == "pallas":
+        dy, dots = _gather_rows(dout.astype(y.dtype), move, row_weight, dot_with=y)
+    else:
+        d_row = jnp.where(move["row_valid"][:, None], dout[move["row_token"]], 0)  # (R, H)
+        dy = (d_row.astype(jnp.float32) * row_weight[:, None]).astype(y.dtype)
+        dots = jnp.sum(d_row.astype(jnp.float32) * y.astype(jnp.float32), axis=-1)
+    dweights = jnp.where(move["sel"], dots[move["pos"]], 0.0).astype(weights.dtype)
+    return dy, dweights, jax.tree.map(_f0, move)
 
 
 _tokens_from_rows.defvjp(_tokens_fwd, _tokens_bwd)
@@ -631,23 +688,37 @@ def silu_gate(h):
     return (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(h.dtype)
 
 
+def _block_move(plan, block, rows):
+    """The plan cut to the rows ``[block * rows, (block + 1) * rows)``: what
+    the two movements and the grouped products of one block read."""
+    lo = block * rows
+    cut = lambda a: jax.lax.dynamic_slice_in_dim(a, lo, rows)  # noqa: E731
+    sel = plan["local"] & (plan["pos"] >= lo) & (plan["pos"] < lo + rows)
+    listed = jnp.pad(sel, ((0, plan["rank"].shape[0] - sel.shape[0]), (0, 0)))
+    return {"tile_expert": jax.lax.dynamic_slice_in_dim(plan["tile_expert"], lo // gk.TM,
+                                                        rows // gk.TM),
+            "n_used": jnp.clip(plan["n_used"] - lo // gk.TM, 0, rows // gk.TM).reshape(1),
+            "row_token": cut(plan["row_token"]), "row_assign": cut(plan["row_assign"]),
+            "row_valid": cut(plan["row_valid"]), "sel": sel,
+            "pos": jnp.clip(plan["pos"] - lo, 0, rows - 1),
+            # the token-ordered lists hold every block's rows: for another block's,
+            # this block's first row is fetched and weighs nothing
+            "tile_rows": jnp.where((plan["tile_rows"] >= lo) & (plan["tile_rows"] < lo + rows),
+                                   plan["tile_rows"] - lo, 0),
+            "tile_count": plan["tile_count"], "rank": jnp.where(listed, plan["rank"], -1)}
+
+
 def _held_experts_block(x, weights, w_gate_up, w_down, plan, block, rows, impl):
     """What the rows ``[block * rows, (block + 1) * rows)`` of the plan add
     to every token: gather, gate/up product, SiLU gate, down product,
     weighted gather back."""
-    lo = block * rows
-    cut = lambda a: jax.lax.dynamic_slice_in_dim(a, lo, rows)  # noqa: E731
-    tile_expert = jax.lax.dynamic_slice_in_dim(plan["tile_expert"], lo // gk.TM, rows // gk.TM)
-    n_used = jnp.clip(plan["n_used"] - lo // gk.TM, 0, rows // gk.TM)
-    row_token, row_assign, row_valid = (cut(plan[n]) for n in
-                                        ("row_token", "row_assign", "row_valid"))
-    sel = plan["local"] & (plan["pos"] >= lo) & (plan["pos"] < lo + rows)
-    pos = jnp.clip(plan["pos"] - lo, 0, rows - 1)
-    xs = _rows_from_tokens(x, row_token, row_valid, pos, sel)
+    move = _block_move(plan, block, rows)
+    tile_expert, n_used = move["tile_expert"], move["n_used"][0]
+    xs = _rows_from_tokens(x, move, impl)
     with monitor_spans.span("moe/experts"):
         h = grouped_matmul(xs, w_gate_up, tile_expert, n_used, impl)
         y = grouped_matmul(silu_gate(h), w_down, tile_expert, n_used, impl)
-    return _tokens_from_rows(y, weights, row_token, row_assign, row_valid, pos, sel)
+    return _tokens_from_rows(y, weights, move, impl)
 
 
 def _add(a, b):
@@ -698,9 +769,9 @@ def dropless_block_rows(tokens, top_k, held, width):
     here (``tokens * top_k * held / width`` assignments) with every held
     expert's last tile padded, in whole multiples of the tokens, so that the
     place where a further block starts stays clear of the expected load. A
-    block costs its gathers whether it is full or nearly empty; a load that
-    sits AT a block's end pays for a second block in some layers and steps
-    and not in others."""
+    block costs what its rows in use cost, full or nearly empty (its buffers
+    are sized for all of them); a load that sits AT a block's end runs a
+    second block in some layers and steps and not in others."""
     expected = tokens * top_k * held // width + held * gk.TM
     return max(1, -(-expected // tokens)) * tokens
 

@@ -20,7 +20,7 @@ PALLAS_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file
 KERNEL_FILES = sorted(os.path.basename(p) for p in glob.glob(os.path.join(PALLAS_DIR, "*.py"))
                       if os.path.basename(p) != "__init__.py")
 # readers match by prefix: flash_fwd*, flash_bwd*, xentropy*, gdn_fwd*, gdn_bwd*,
-# moe_gmm*; the rest by name (conv_silu_*, gated_norm_*: the stages around the
+# moe_gmm*, moe_rows*; the rest by name (conv_silu_*, gated_norm_*: the stages around the
 # rule, which no reader's part may match)
 EXPECTED = {
     "attention.py": {
@@ -46,6 +46,9 @@ EXPECTED = {
     "gated_delta_rule.py": {"gdn_fwd", "gdn_bwd"},
     "delta_mixer.py": {"conv_silu_fwd", "conv_silu_bwd", "gated_norm_fwd", "gated_norm_bwd"},
     "grouped_matmul.py": {"moe_gmm", "moe_gmm_dx", "moe_gmm_dw"},
+    # the movements between tokens and expert rows: moe_rows*, never moe_gmm*
+    "expert_rows.py": {"moe_rows_gather", "moe_rows_gather_dots", "moe_rows_pack",
+                       "moe_rows_combine", "moe_rows_combine_weighted"},
 }
 SCOPES = ("amp/fwd_bwd", "amp/unscale_check", "amp/apply_master", "fused_adam/update",
           "gpt/embed", "gpt/attn", "gpt/mlp", "gpt/unembed_xent", "ddp/allreduce")
@@ -82,16 +85,19 @@ def test_every_pallas_call_has_a_literal_name(filename):
 def test_kernel_names_are_distinct_across_the_package():
     assert set(KERNEL_FILES) == set(EXPECTED)
     names = [n for f in KERNEL_FILES for n in literal_names(f)]
-    assert len(names) == 39 and len(set(names)) == 39
+    assert len(names) == 44 and len(set(names)) == 44
+
+
+def all_eqns(jaxpr):
+    """Every equation, through every sub-jaxpr."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from all_eqns(sub)
 
 
 def pallas_eqns(jaxpr):
-    """The ``pallas_call`` equations, through every sub-jaxpr."""
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            yield eqn
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from pallas_eqns(sub)
+    return (eqn for eqn in all_eqns(jaxpr) if eqn.primitive.name == "pallas_call")
 
 
 def kernel_names(jaxpr):
@@ -431,3 +437,99 @@ def test_every_fused_optimizer_names_its_update(name, layout):
     text = jax.jit(opt.update).lower(params, opt.init(params), params).as_text(
         debug_info=True)
     assert f"{name}/update/" in text
+
+
+# --- the dropless expert layer's row movements ---------------------------------
+
+def _expert_layer_operands(tokens=256, hidden=128, top_k=4, width=16, held=8, F=128):
+    k = iter(jax.random.split(jax.random.PRNGKey(3), 8))
+    n = lambda *s: 0.05 * jax.random.normal(next(k), s)  # noqa: E731
+    p = {"router": n(hidden, width), "w_gate_up": n(held, hidden, 2 * F), "w_down": n(held, F, hidden),
+         "shared_gate_up": n(hidden, 2 * F), "shared_down": n(F, hidden), "shared_mix": n(hidden)}
+    return p, jax.random.normal(next(k), (tokens, hidden)), top_k, (0, held)
+
+
+def _expert_layer_grads(impl):
+    from apex_tpu.transformer import moe
+    p, x, top_k, held = _expert_layer_operands()
+
+    def loss(p, x):
+        y = moe.dropless_moe_layer(p, x, top_k=top_k, experts_held=held, impl=impl)[0]
+        return jnp.sum(y * jnp.cos(jnp.arange(y.size, dtype=y.dtype).reshape(y.shape))), y
+    return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True), (p, x)
+
+
+def test_expert_rows_move_by_kernels_that_no_moe_gmm_reader_matches():
+    """With the kernels the layer's program holds the four ``moe_rows*`` calls
+    beside the ``moe_gmm*`` ones and no gather whose result is (T, k, H) or a
+    block's (R, H); the XLA composition holds both. ``moe_gmm_ms`` reads the
+    part ``moe_gmm``, which no movement's name holds."""
+    from apex_tpu.transformer import moe
+    fn, args = _expert_layer_grads("pallas")
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    names = kernel_names(jaxpr)
+    # the first block, then the loop over further blocks; backward the same
+    assert names[:5] == ["moe_rows_gather", "moe_gmm", "moe_gmm", "moe_rows_pack",
+                         "moe_rows_combine_weighted"]
+    assert set(names) == EXPECTED["expert_rows.py"] | EXPECTED["grouped_matmul.py"]
+    for name in EXPECTED["expert_rows.py"]:
+        assert "moe_rows" in name and "moe_gmm" not in name
+    (_, x, top_k, held), hidden = _expert_layer_operands(), 128
+    rows = moe.dropless_block_rows(x.shape[0], top_k, held[1], 16)
+    wide = {x.shape[0] * top_k * hidden, rows * hidden}
+
+    def wide_gathers(jaxpr):
+        return [e for e in all_eqns(jaxpr) if e.primitive.name in ("gather", "scatter", "scatter-add")
+                and e.outvars[0].aval.size in wide]
+    assert not wide_gathers(jaxpr)
+    fn, args = _expert_layer_grads("xla")
+    assert len(wide_gathers(jax.make_jaxpr(fn)(*args).jaxpr)) >= 4     # both movements, both ways
+
+
+def test_the_xla_composition_is_the_parents_bit_for_bit(monkeypatch):
+    """``impl="xla"`` is the reference the kernels are held to, so it stays what
+    the parent computed: the layer with the parent's two movements (copied here
+    as they stood before the kernels) in place of today's gives the same output
+    and the same gradients, bit for bit."""
+    from apex_tpu.transformer import moe
+
+    @jax.custom_vjp
+    def rows_from_tokens(x, row_token, row_valid, pos, sel):
+        return jnp.where(row_valid[:, None], x[row_token], 0).astype(x.dtype)
+
+    def rows_bwd(res, g):
+        row_token, row_valid, pos, sel = res
+        picked = jnp.where(sel[..., None], g[pos], 0)
+        dx = jnp.sum(picked.astype(jnp.float32), axis=1).astype(g.dtype)
+        return dx, moe._f0(row_token), moe._f0(row_valid), moe._f0(pos), moe._f0(sel)
+
+    rows_from_tokens.defvjp(lambda x, *r: (rows_from_tokens(x, *r), r), rows_bwd)
+
+    @jax.custom_vjp
+    def tokens_from_rows(y, weights, row_token, row_assign, row_valid, pos, sel):
+        picked = jnp.where(sel[..., None], y[pos], 0).astype(jnp.float32)
+        return jnp.einsum("tkh,tk->th", picked, weights).astype(y.dtype)
+
+    def tokens_bwd(res, dout):
+        y, weights, row_token, row_assign, row_valid, pos, sel = res
+        d_row = jnp.where(row_valid[:, None], dout[row_token], 0)
+        row_weight = weights.reshape(-1)[row_assign]
+        dy = (d_row.astype(jnp.float32) * row_weight[:, None]).astype(y.dtype)
+        dots = jnp.sum(d_row.astype(jnp.float32) * y.astype(jnp.float32), axis=-1)
+        dweights = jnp.where(sel, dots[pos], 0.0).astype(weights.dtype)
+        return (dy, dweights, moe._f0(row_token), moe._f0(row_assign), moe._f0(row_valid),
+                moe._f0(pos), moe._f0(sel))
+
+    tokens_from_rows.defvjp(lambda *a: (tokens_from_rows(*a), a), tokens_bwd)
+
+    fn, args = _expert_layer_grads("xla")
+    (_, y), (gp, gx) = jax.jit(fn)(*args)
+    monkeypatch.setattr(moe, "_rows_from_tokens", lambda x, m, impl: rows_from_tokens(
+        x, m["row_token"], m["row_valid"], m["pos"], m["sel"]))
+    monkeypatch.setattr(moe, "_tokens_from_rows", lambda y, w, m, impl: tokens_from_rows(
+        y, w, m["row_token"], m["row_assign"], m["row_valid"], m["pos"], m["sel"]))
+    fn, args = _expert_layer_grads("xla")
+    (_, y0), (gp0, gx0) = jax.jit(fn)(*args)
+    for got, want in zip(jax.tree.leaves((y, gp, gx)), jax.tree.leaves((y0, gp0, gx0))):
+        assert got.dtype == want.dtype and bool(jnp.all(got == want))
+    assert float(jnp.max(jnp.abs(gp0["w_down"]))) > 0 and float(jnp.max(jnp.abs(gp0["router"]))) > 0

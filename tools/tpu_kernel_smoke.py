@@ -57,6 +57,7 @@ class Family:
     build: Callable[[], Tuple[Callable, Callable, tuple]]
     auto: bool = True
     tol: float = BF16_TOL
+    timings: Callable[[], list] = None   # lines of times this family reports beside its check
 
 
 def _key(i):
@@ -497,6 +498,91 @@ def _dropless_family():
     return build
 
 
+CELL_TOKENS, CELL_HIDDEN = 16384, 2048   # 2 rows of 8,192; every expert cell's width
+# top-k, experts held, router width, F: trinity-, dsv2lite- and q3next-train-8k
+EXPERT_CELLS = {"trinity": (8, 16, 128, 1024), "dsv2lite": (6, 8, 64, 1408),
+                "q3next": (10, 32, 512, 512)}
+
+
+def _expert_cell_operands(cell):
+    k, held, width, F = EXPERT_CELLS[cell]
+    n = lambda i, *shape: (0.05 * jr.normal(_key(i), shape)).astype(jnp.bfloat16)  # noqa: E731
+    p = {"router": n(60, CELL_HIDDEN, width), "w_gate_up": n(61, held, CELL_HIDDEN, 2 * F),
+         "w_down": n(62, held, F, CELL_HIDDEN), "shared_gate_up": n(63, CELL_HIDDEN, 2 * F),
+         "shared_down": n(64, F, CELL_HIDDEN), "shared_mix": n(65, CELL_HIDDEN)}
+    return p, jr.normal(_key(66), (CELL_TOKENS, CELL_HIDDEN), jnp.bfloat16)
+
+
+def _dropless_cell_family(cell):
+    """The dropless expert layer at a cell's (k, held, width, F), 16,384
+    tokens of 2,048: the row movements (``moe_rows_*``) and the grouped
+    products against the XLA composition, forward and every gradient."""
+    def build():
+        from apex_tpu.transformer.moe import dropless_moe_layer
+        k, held = EXPERT_CELLS[cell][:2]
+
+        def make(impl):
+            return _fwd_and_grads(lambda p, x: dropless_moe_layer(
+                p, x, top_k=k, experts_held=(0, held), impl=impl)[0], (0, 1))
+        return make("pallas"), make("xla"), _expert_cell_operands(cell)
+    return build
+
+
+def _dropless_movement_times(cell, calls=20):
+    """Each of the four movements between tokens and rows alone, at a cell's
+    shapes and a routing of its router at initialisation: XLA's gathers
+    against the ``moe_rows_*`` kernels (host clock, ``calls`` calls), and the
+    plan that feeds them."""
+    def report():
+        from apex_tpu.ops.pallas import grouped_matmul as gk
+        from apex_tpu.transformer import moe
+        k, held, width, _ = EXPERT_CELLS[cell]
+        p, x = _expert_cell_operands(cell)
+        rows = moe.dropless_block_rows(CELL_TOKENS, k, held, width)
+
+        def planned(x, router):
+            top_e, top_p, _, counts = moe.route_topk(x, router, k)
+            return top_p, moe.dropless_plan(top_e, counts, (0, held), rows, gk.TM)
+        planned = jax.jit(planned)
+        top_p, plan = planned(x, p["router"])
+        move = jax.jit(lambda plan: moe._block_move(plan, 0, rows))(plan)
+        y = jr.normal(_key(67), (rows, CELL_HIDDEN), jnp.bfloat16)
+        g = jr.normal(_key(68), (CELL_TOKENS, CELL_HIDDEN), jnp.bfloat16)
+
+        def ms(fn, *args):
+            fn = jax.jit(fn)
+            out = jax.block_until_ready(fn(*args))
+            t0 = time.perf_counter()
+            for _ in range(calls):
+                out = fn(*args)
+            jax.block_until_ready(out)
+            return 1e3 * (time.perf_counter() - t0) / calls, out
+
+        lines = [f"{cell}: block of {rows} rows, {int(plan['n_used'])} tiles in use, "
+                 f"{int(plan['row_valid'].sum())} assignments; route + plan "
+                 f"{ms(planned, x, p['router'])[0]:.3f} ms"]
+        movements = {
+            "rows <- tokens": lambda impl: (lambda x: moe._rows_from_tokens(x, move, impl), (x,)),
+            "its cotangent (tokens <- rows, weight 1)": lambda impl: (
+                lambda y: jax.vjp(lambda x: moe._rows_from_tokens(x, move, impl), x)[1](y)[0], (y,)),
+            "tokens <- rows, weighted": lambda impl: (
+                lambda y, w: moe._tokens_from_rows(y, w, move, impl), (y, top_p)),
+            "its cotangents (rows <- tokens scaled, dweights)": lambda impl: (
+                lambda y, w, g: jax.vjp(lambda y, w: moe._tokens_from_rows(y, w, move, impl),
+                                        y, w)[1](g), (y, top_p, g)),
+        }
+        for name, make in movements.items():
+            took = {}
+            for impl in ("xla", "pallas"):
+                fn, args = make(impl)
+                took[impl] = ms(fn, *args)
+            err = max_error(took["pallas"][1], took["xla"][1])
+            lines.append(f"{cell}: {name}: xla {took['xla'][0]:.3f} ms, "
+                         f"moe_rows {took['pallas'][0]:.3f} ms, max err {err:.2e}")
+        return lines
+    return report
+
+
 # --- the explicit-only families (auto resolves them to XLA) --------------------
 
 def _ln_family(rms):
@@ -588,6 +674,10 @@ FAMILIES = (
            _delta_rule_drifted_family()),
     Family("delta mixer stages conv_silu/gated_norm fwd/bwd", _delta_mixer_stages_family()),
     Family("dropless experts moe_gmm/dx/dw", _dropless_family()),
+    *(Family(f"dropless experts at {cell}-train-8k's top {k} onto {held} of {width}, F {F}: "
+             f"moe_rows_gather/combine + moe_gmm", _dropless_cell_family(cell),
+             timings=_dropless_movement_times(cell))
+      for cell, (k, held, width, F) in EXPERT_CELLS.items()),
     Family("decode contiguous MHA", _decode_family(H)),
     Family("decode contiguous GQA group 4", _decode_family(2)),
     Family("decode contiguous bucketed bias", _decode_family(H, bias=True)),
@@ -678,6 +768,8 @@ def main(families=FAMILIES) -> list:
         print(f"{'PASS' if ok else 'FAIL'} {fam.name}: max err {err:.2e} "
               f"({apart}tol {fam.tol:.0e}, {'auto' if fam.auto else 'explicit'}, "
               f"compile {c_s:.1f} s, run {r_s:.2f} s)", flush=True)
+        for line in (fam.timings() if fam.timings else ()):
+            print(f"     {line}", flush=True)
     print(f"{len(families) - len(failed)}/{len(families)} kernel families "
           f"match their XLA composition on {jax.devices()[0].device_kind}")
     print(f"kernels: compile {compile_s:.1f} s, run {run_s:.1f} s "
