@@ -257,18 +257,21 @@ class HybridDecoderModel:
         b, s, _ = x.shape
         hk, hv, dk, dv = (c.linear_key_heads, c.linear_value_heads,
                           c.linear_key_dim, c.linear_value_dim)
-        qkvz = jnp.dot(x, p["w_qkvz"])
-        ba = jnp.dot(x, p["w_ba"], preferred_element_type=jnp.float32)
+        with monitor_spans.span("mix/proj_in"):
+            qkvz = jnp.dot(x, p["w_qkvz"])
+            ba = jnp.dot(x, p["w_ba"], preferred_element_type=jnp.float32)
         # q|k|v and z are read where the projection left them: no slice of qkvz
         q, k, v = causal_conv_silu(qkvz, p["conv_w"], widths=(hk * dk, hk * dk, hv * dv),
                                    impl=c.delta_impl)
-        beta = jax.nn.sigmoid(ba[..., :hv])
-        g = (-jnp.exp(p["A_log"].astype(jnp.float32))
-             * jax.nn.softplus(ba[..., hv:] + p["dt_bias"].astype(jnp.float32)))
+        with monitor_spans.span("mix/place"):
+            beta = jax.nn.sigmoid(ba[..., :hv])
+            g = (-jnp.exp(p["A_log"].astype(jnp.float32))
+                 * jax.nn.softplus(ba[..., hv:] + p["dt_bias"].astype(jnp.float32)))
         o = gated_delta_rule(q.reshape(b, s, hk, dk), k.reshape(b, s, hk, dk),
                              v.reshape(b, s, hv, dv), g, beta, impl=c.delta_impl)
         o = gated_rms_norm(o, qkvz, p["norm_w"], c.rms_eps, impl=c.delta_impl)
-        return jnp.dot(o.reshape(b, s, hv * dv), p["w_o"])
+        with monitor_spans.span("mix/proj_out"):
+            return jnp.dot(o.reshape(b, s, hv * dv), p["w_o"])
 
     def _attention_mixer(self, p, x, kind="full"):
         c = self.config
@@ -276,20 +279,24 @@ class HybridDecoderModel:
         nh, nkv, dh = c.num_heads, c.num_kv_heads, c.head_dim
         banded = kind == "window"
         rot = c.rotary_dim if not banded or c.window_rotary_dim is None else c.window_rotary_dim
-        qg = jnp.dot(x, p["w_q"]).reshape(b, s, nh, 2 * dh)
-        q, gate = qg[..., :dh], qg[..., dh:]
-        k = jnp.dot(x, p["w_k"]).reshape(b, s, nkv, dh)
-        v = jnp.dot(x, p["w_v"]).reshape(b, s, nkv, dh)
+        with monitor_spans.span("mix/proj_in"):
+            qg = jnp.dot(x, p["w_q"]).reshape(b, s, nh, 2 * dh)
+            q, gate = qg[..., :dh], qg[..., dh:]
+            k = jnp.dot(x, p["w_k"]).reshape(b, s, nkv, dh)
+            v = jnp.dot(x, p["w_v"]).reshape(b, s, nkv, dh)
 
         def placed(x, w):                          # per-head norm, then its position
             x = self._norm(x, w)
             return apply_partial_rotary(x, rot, c.rope_theta) if rot else x
 
-        q, k = placed(q, p["q_norm"]), placed(k, p["k_norm"])
+        with monitor_spans.span("mix/place"):
+            q, k = placed(q, p["q_norm"]), placed(k, p["k_norm"])
         ctx = flash_attention(q, k, v, causal=True, scale=dh ** -0.5, layout="bshd",
                               impl=c.attention_impl, window=c.window if banded else None)
-        ctx = ctx * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(ctx.dtype)
-        return jnp.dot(ctx.reshape(b, s, nh * dh), p["w_o"])
+        with monitor_spans.span("mix/place"):
+            ctx = ctx * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(ctx.dtype)
+        with monitor_spans.span("mix/proj_out"):
+            return jnp.dot(ctx.reshape(b, s, nh * dh), p["w_o"])
 
     def _latent_mixer(self, p, x):
         c = self.config
@@ -297,7 +304,8 @@ class HybridDecoderModel:
         nh, dn, dr, dv, rank = (c.num_heads, c.qk_nope_dim, c.qk_rope_dim, c.v_head_dim,
                                 c.kv_lora_rank)
         # the weights are cut, not the activations: each product lands where
-        # the kernel reads it (columns stored nope | rope and key | value)
+        # the kernel reads it (columns stored nope | rope and key | value);
+        # ``mla/down`` and ``mla/up`` are this mixer's ``mix/proj_in``
         with monitor_spans.span("mla/down"):
             q_nope = jnp.dot(x, p["w_q"][:, :nh * dn]).reshape(b, s, nh, dn)
             q_pe = jnp.dot(x, p["w_q"][:, nh * dn:]).reshape(b, s, nh, dr)
@@ -310,11 +318,13 @@ class HybridDecoderModel:
         if c.rope_scaling is not None:         # yarn's temperature, on the scores
             entry = dict(c.rope_scaling)
             scale *= yarn_mscale(entry["factor"], entry.get("mscale_all_dim", 0.0)) ** 2
-        q_pe, k_pe = (apply_partial_rotary(a, dr, c.rope_theta, scaling=c.rope_scaling)
-                      for a in (q_pe, k_pe))
+        with monitor_spans.span("mix/place"):
+            q_pe, k_pe = (apply_partial_rotary(a, dr, c.rope_theta, scaling=c.rope_scaling)
+                          for a in (q_pe, k_pe))
         ctx = flash_attention(q_nope, k_nope, v, causal=True, scale=scale, layout="bshd",
                               impl=c.attention_impl, second=(q_pe, k_pe))
-        return jnp.dot(ctx.reshape(b, s, nh * dv), p["w_o"])
+        with monitor_spans.span("mix/proj_out"):
+            return jnp.dot(ctx.reshape(b, s, nh * dv), p["w_o"])
 
     def _experts(self, p, x, router_bias=None):
         c = self.config

@@ -67,6 +67,17 @@ def _trace_file(run_dir: str) -> str:
     return files[0]
 
 
+def has_chrome_trace(log_dir: str) -> bool:
+    """Whether the newest run under ``log_dir`` holds a ``*.trace.json.gz``
+    (jax 0.9 writes the ``.xplane.pb`` alone; ``apex_tpu.prof.scopes`` reads
+    that)."""
+    try:
+        _trace_file(_latest_run_dir(log_dir))
+    except FileNotFoundError:
+        return False
+    return True
+
+
 def read_trace(log_dir: str) -> List[TraceEvent]:
     """Parse the newest run's chrome trace into device events.
 
