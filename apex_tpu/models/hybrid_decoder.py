@@ -48,6 +48,17 @@ expert without its gate (``shared_gate=False``).
   :func:`transformer.moe.router_bias_update` moves it
   (:meth:`HybridDecoderModel.init_router_bias` starts it).
 
+``remat`` recomputes every block in the backward pass, a half at a time,
+except what the half's policy keeps by name (``MIXER_SAVED``,
+``EXPERTS_SAVED``): a mixer half keeps the results of its kernels — the
+flash call's output and its log-sum-exp rows (``ops.attention.FLASH_SAVED``),
+the delta rule's output and its chunks' entry states
+(``ops.gated_delta_rule.RULE_SAVED``), which their backward rules read, so
+no forward kernel runs twice — and the outputs of its input projections
+(``"mix_proj"``: q, gate, k, v of an attention layer, ``q|k|v|z`` and ``b|a``
+of a delta-rule layer); an expert half keeps its routing plan. Norms,
+rotary, the convolution, the gates and ``w_o``'s operand are computed again.
+
 ``loss_fn`` has ``GPTModel.loss_fn``'s signature, so
 ``amp.scaled_value_and_grad`` and the trainers take either model.
 ``float32_params`` names the leaves a mixed-precision policy should leave
@@ -61,10 +72,11 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from apex_tpu.monitor import spans as monitor_spans
-from apex_tpu.ops.attention import flash_attention
-from apex_tpu.ops.gated_delta_rule import (causal_conv_silu, gated_delta_rule,
+from apex_tpu.ops.attention import FLASH_SAVED, flash_attention
+from apex_tpu.ops.gated_delta_rule import (RULE_SAVED, causal_conv_silu, gated_delta_rule,
                                            gated_rms_norm)
 from apex_tpu.ops.rotary import apply_partial_rotary, yarn_mscale
 from apex_tpu.transformer import tensor_parallel as tp_lib
@@ -72,6 +84,15 @@ from apex_tpu.transformer.moe import dropless_moe_layer, silu_gate
 
 ATTENTION_KINDS = ("full", "window")
 GROUP_OF_KIND = {"linear": "gdn", "full": "attn", "window": "attn", "latent": "mla"}
+MIXER_SCOPES = {"linear": "hybrid/gdn", "full": "hybrid/attn", "window": "hybrid/attn_win",
+                "latent": "hybrid/attn_mla"}
+# What ``remat`` keeps of a half beside its arguments, by name. A mixer half:
+# the results of its kernels (the names their forward rules give them: which
+# of them exist in a half follows from the kernels its layer kind runs) and
+# the outputs of its input projections. An expert half: the routing plan
+# (``transformer.moe``).
+MIXER_SAVED = FLASH_SAVED + RULE_SAVED + ("mix_proj",)
+EXPERTS_SAVED = ("moe_plan",)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -122,7 +143,9 @@ class HybridDecoderConfig:
     zero_centered_norm: bool = True
     sandwich_norms: bool = False
     embed_scale: float = 1.0
-    # recompute every block's two halves (mixer, experts) in the backward pass
+    # recompute every block's two halves (mixer, experts) in the backward
+    # pass, all but the results of their kernels, the mixers' input
+    # projections and the routing plan (MIXER_SAVED, EXPERTS_SAVED)
     remat: bool = False
     attention_impl: str = "auto"
     delta_impl: str = "auto"
@@ -258,8 +281,9 @@ class HybridDecoderModel:
         hk, hv, dk, dv = (c.linear_key_heads, c.linear_value_heads,
                           c.linear_key_dim, c.linear_value_dim)
         with monitor_spans.span("mix/proj_in"):
-            qkvz = jnp.dot(x, p["w_qkvz"])
-            ba = jnp.dot(x, p["w_ba"], preferred_element_type=jnp.float32)
+            qkvz = checkpoint_name(jnp.dot(x, p["w_qkvz"]), "mix_proj")
+            ba = checkpoint_name(jnp.dot(x, p["w_ba"], preferred_element_type=jnp.float32),
+                                 "mix_proj")
         # q|k|v and z are read where the projection left them: no slice of qkvz
         q, k, v = causal_conv_silu(qkvz, p["conv_w"], widths=(hk * dk, hk * dk, hv * dv),
                                    impl=c.delta_impl)
@@ -281,9 +305,9 @@ class HybridDecoderModel:
         rot = c.rotary_dim if not banded or c.window_rotary_dim is None else c.window_rotary_dim
         with monitor_spans.span("mix/proj_in"):
             qg = jnp.dot(x, p["w_q"]).reshape(b, s, nh, 2 * dh)
-            q, gate = qg[..., :dh], qg[..., dh:]
-            k = jnp.dot(x, p["w_k"]).reshape(b, s, nkv, dh)
-            v = jnp.dot(x, p["w_v"]).reshape(b, s, nkv, dh)
+            q, gate, k, v = (checkpoint_name(a, "mix_proj") for a in (
+                qg[..., :dh], qg[..., dh:], jnp.dot(x, p["w_k"]).reshape(b, s, nkv, dh),
+                jnp.dot(x, p["w_v"]).reshape(b, s, nkv, dh)))
 
         def placed(x, w):                          # per-head norm, then its position
             x = self._norm(x, w)
@@ -338,6 +362,43 @@ class HybridDecoderModel:
     def _dense(p, x):
         return jnp.dot(silu_gate(jnp.dot(x, p["w_gate_up"])), p["w_down"])
 
+    # --- the halves of a block ------------------------------------------------
+
+    def _added(self, y, post):
+        """What a half adds to the stream: its output, normed again where
+        the block is a sandwich."""
+        return y if post is None else self._norm(y, post)
+
+    def _mixer_half(self, kind):
+        """``(p, w, post, x) -> x + mixer(norm(x))`` of a ``kind`` layer."""
+        mixers = {"linear": self._delta_mixer, "latent": self._latent_mixer}
+
+        def half(p, w, post, x):
+            with monitor_spans.span(MIXER_SCOPES[kind]):
+                h = self._norm(x, w)
+                y = mixers[kind](p, h) if kind in mixers else self._attention_mixer(p, h, kind)
+                return x + self._added(y, post)
+
+        return self._recomputed(half, MIXER_SAVED)
+
+    def _expert_half(self, p, w, post, bias, x):
+        with monitor_spans.span("hybrid/moe"):
+            y, aux = self._experts(p, self._norm(x, w), bias)
+            return x + self._added(y, post), aux
+
+    def _dense_half(self, p, w, post, x):
+        with monitor_spans.span("hybrid/dense"):
+            return x + self._added(self._dense(p, self._norm(x, w)), post)
+
+    def _recomputed(self, half, saved=()):
+        """``half`` as the stack runs it. Under ``remat`` the backward pass
+        holds the half's arguments and the values named in ``saved`` and
+        computes the rest of it again."""
+        if not self.config.remat:
+            return half
+        return jax.checkpoint(
+            half, policy=jax.checkpoint_policies.save_only_these_names(*saved))
+
     # --- the stack ------------------------------------------------------------
 
     def hidden_states_with_aux(self, params, tokens, key=None, router_bias=None):
@@ -353,32 +414,8 @@ class HybridDecoderModel:
             x = params["embedding"]["weight"][tokens]
             if c.embed_scale != 1.0:
                 x = x * jnp.asarray(c.embed_scale, x.dtype)
-        keep_plan = jax.checkpoint_policies.save_only_these_names("moe_plan")
-        scopes = {"linear": "hybrid/gdn", "full": "hybrid/attn", "window": "hybrid/attn_win",
-                  "latent": "hybrid/attn_mla"}
-        mixers = {"linear": self._delta_mixer, "latent": self._latent_mixer}
-
-        def added(y, post):
-            """What a half adds to the stream: its output, normed again
-            where the block is a sandwich."""
-            return y if post is None else self._norm(y, post)
-
-        def mixer_half(kind, p, w, post, x):
-            with monitor_spans.span(scopes[kind]):
-                h = self._norm(x, w)
-                y = mixers[kind](p, h) if kind in mixers else self._attention_mixer(p, h, kind)
-                return x + added(y, post)
-
-        def expert_half(p, w, post, bias, x):
-            with monitor_spans.span("hybrid/moe"):
-                y, aux = self._experts(p, self._norm(x, w), bias)
-                return x + added(y, post), aux
-
-        def dense_half(p, w, post, x):
-            with monitor_spans.span("hybrid/dense"):
-                return x + added(self._dense(p, self._norm(x, w)), post)
-
-        wrap = jax.checkpoint if c.remat else (lambda f, **kw: f)
+        expert_half = self._recomputed(self._expert_half, EXPERTS_SAVED)
+        dense_half = self._recomputed(self._dense_half)
         post1, post2 = layers.get("norm1_post"), layers.get("norm2_post")
         seen = {"gdn": 0, "attn": 0, "mla": 0, "moe": 0, "dense": 0}
         lb, loads, counts, dropped = 0.0, [], [], 0
@@ -391,14 +428,14 @@ class HybridDecoderModel:
         for i, (kind, ffn) in enumerate(zip(c.layer_types, c.ffn)):
             _, p_mix = take(GROUP_OF_KIND[kind])
             j, p_ffn = take(ffn)
-            f = lambda p, w, post, x, kind=kind: mixer_half(kind, p, w, post, x)  # noqa: E731
-            x = wrap(f)(p_mix, layers["norm1"][i], None if post1 is None else post1[i], x)
+            x = self._mixer_half(kind)(p_mix, layers["norm1"][i],
+                                       None if post1 is None else post1[i], x)
             post = None if post2 is None else post2[i]
             if ffn == "dense":
-                x = wrap(dense_half)(p_ffn, layers["norm2"][i], post, x)
+                x = dense_half(p_ffn, layers["norm2"][i], post, x)
                 continue
             bias = None if router_bias is None else router_bias[j]
-            x, aux = wrap(expert_half, policy=keep_plan)(p_ffn, layers["norm2"][i], post, bias, x)
+            x, aux = expert_half(p_ffn, layers["norm2"][i], post, bias, x)
             lb = lb + aux["load_balance_loss"]
             loads.append(aux["expert_load"])
             counts.append(aux["router_counts"])

@@ -26,6 +26,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 import numpy as _np
 
@@ -498,12 +499,27 @@ def _flash_fwd_res_bshd(q, k, v, bias, kv_lens, dropout_seed, scale, causal,
     return o, (q, k, v, o, lse)
 
 
+# The forward kernel's results under the seq-major rules below, by the names
+# a ``jax.checkpoint`` policy keeps them under (``save_only_these_names``):
+# the backward rule reads both, so with both saved a recomputed block does
+# not launch the forward kernel again. Outside ``jax.checkpoint`` a name
+# lowers to nothing. The flat and packed rules name nothing.
+FLASH_SAVED = ("flash_o", "flash_lse")
+
+
+def _flash_saved(o, lse):
+    return tuple(map(checkpoint_name, (o, lse), FLASH_SAVED))
+
+
 def _flash_fwd_bshd(q, k, v, bias, kv_lens, dropout_seed, scale, causal,
                     use_pallas, dropout_rate, window=None):
-    o, res = _flash_fwd_res_bshd(q, k, v, bias, kv_lens, dropout_seed,
-                                 scale, causal, use_pallas, dropout_rate,
-                                 window)
-    return o, (res, bias, kv_lens, dropout_seed)
+    o, (*_, lse) = _flash_fwd_res_bshd(
+        q, k, v, bias, kv_lens, dropout_seed, scale, causal, use_pallas,
+        dropout_rate, window)
+    # the rows: lane 0 of the kernel's (b, h, s, 8) carrier, which HBM pads
+    # to 128 lanes; the one-pass backward reads rows, the split re-expands
+    o, lse = _flash_saved(o, lse[..., 0] if lse.ndim == 4 else lse)
+    return o, ((q, k, v, o, lse), bias, kv_lens, dropout_seed)
 
 
 def _flash_bwd_bshd_impl(q, k, v, o, lse, do, kv_lens, scale, causal,
@@ -590,8 +606,9 @@ def _flash_fwd_two_width(q, k, v, q2, k2, scale, causal, use_pallas):
     # the narrow second term rides head-major (see pallas.flash_fwd_bshd);
     # XLA folds the transposes into what made q2 and k2 (a rotary embedding)
     second = None if q2 is None else (_head_major(q2), _head_major(k2))
-    o, lse = _k.flash_fwd_bshd(q, k, v, scale=scale, causal=causal, full_lse=True,
-                               interpret=_backend.interpret_mode(), second=second)
+    o, lse = _flash_saved(*_k.flash_fwd_bshd(
+        q, k, v, scale=scale, causal=causal, full_lse=True,
+        interpret=_backend.interpret_mode(), second=second))
     return o, (q, k, v, second, o, lse)
 
 

@@ -39,6 +39,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from apex_tpu.amp.lists import apply_op_rules
 from apex_tpu.ops import _backend
@@ -324,8 +325,17 @@ def _rule_pallas(q, k, v, G, beta, gl, heads, interpret):
     return _gdn_fwd(q, k, v, G, beta, gl, heads=heads, interpret=interpret)[0]
 
 
+# ``gdn_fwd``'s results by the names a ``jax.checkpoint`` policy keeps them
+# under (``save_only_these_names``): the output and the chunks' entry states,
+# which the backward rule reads; with both saved a recomputed block does not
+# launch the forward kernel again. Outside ``jax.checkpoint`` a name lowers
+# to nothing.
+RULE_SAVED = ("gdn_o", "gdn_s0")
+
+
 def _rule_fwd(q, k, v, G, beta, gl, heads, interpret):
-    o, s0 = _gdn_fwd(q, k, v, G, beta, gl, heads=heads, interpret=interpret)
+    o, s0 = map(checkpoint_name,
+                _gdn_fwd(q, k, v, G, beta, gl, heads=heads, interpret=interpret), RULE_SAVED)
     return o, (q, k, v, G, beta, gl, s0)
 
 
