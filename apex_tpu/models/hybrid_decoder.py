@@ -1,16 +1,18 @@
 """A decoder whose layers take their kind from a pattern: gated delta-rule
-(linear-attention) mixers, gated softmax-attention mixers over all keys or
-over a sliding window, and latent-attention (MLA) mixers, in any order, each
-followed by a dropless
-sparse-expert layer with a shared expert or by a dense SwiGLU layer.
+(linear-attention) mixers, state-space (Mamba-2) mixers, gated softmax-attention
+mixers over all keys or over a sliding window, and latent-attention (MLA)
+mixers, in any order, each followed by a dropless
+sparse-expert layer with a shared expert, by a dense SwiGLU layer, or by
+nothing (a block of one half).
 
 Pre-norm residual blocks (``x += mixer(norm(x)); x += experts(norm(x))``)
 with zero-centred RMSNorm (``x / rms(x) * (1 + w)``), no position table
 (the attention layers carry partial rotary embeddings, the delta-rule layers
 need none), no biases, an untied output head. ``layer_types`` names each
-layer ``"linear"``, ``"full"``, ``"window"`` or ``"latent"``, ``ffn_types``
-its second half ``"moe"`` (the default everywhere) or ``"dense"``; parameters
-of one kind are stacked on a leading axis under ``layers/gdn``,
+layer ``"linear"``, ``"ssm"``, ``"full"``, ``"window"`` or ``"latent"``, ``ffn_types``
+its second half ``"moe"`` (the default everywhere), ``"dense"`` or ``"none"``
+(no second half: ``norm2`` has a row for each layer that has one); parameters
+of one kind are stacked on a leading axis under ``layers/gdn``, ``layers/ssm``,
 ``layers/attn`` (both gated attention kinds, in the order they come),
 ``layers/mla``, ``layers/moe`` and ``layers/dense``; a kind no layer has has
 no group. All linears are stored
@@ -18,13 +20,25 @@ no group. All linears are stored
 RMSNorm (``zero_centered_norm=False``: ``x / rms(x) * w``), a second norm on
 each half's output before it is added (``sandwich_norms``:
 ``layers/norm1_post``, ``norm2_post``), the embedding scaled
-(``embed_scale``), a sigmoid router with a selection bias, and a shared
-expert without its gate (``shared_gate=False``).
+(``embed_scale``), a sigmoid router with a selection bias, a shared
+expert without its gate (``shared_gate=False``), ungated ``relu(x)^2`` experts
+with one ``up`` matrix (``expert_activation="relu2"``: leaves ``w_up``,
+``shared_up``), and an attention mixer without its gate (``attn_gate=False``),
+its per-head norms (``qk_norm=False``) or rotary (``rotary_dim=0``).
 
 * ``"linear"`` — fused ``q|k|v|z`` and ``b|a`` projections, causal depthwise
   convolution + SiLU on ``q|k|v``, :func:`ops.gated_delta_rule.gated_delta_rule`
   (float32 decay, write strength, norms and state whatever the policy),
   head-wise RMSNorm gated by ``silu(z)``, output projection.
+* ``"ssm"`` — one fused projection ``xBC | z | dt`` (``ssm_heads x
+  ssm_head_dim`` | 2 ``ssm_groups x ssm_state`` || the same inner width ||
+  ``ssm_heads``; Mamba-2 publishes the columns as ``z | xBC | dt``: a
+  relabelling that puts the convolved channels first, where the convolution
+  kernel reads them in place), causal depthwise convolution + bias + SiLU on
+  ``xBC``, ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)``,
+  :func:`ops.ssd.ssd_scan` (float32 decay and state whatever the policy; ``D``
+  the skip), ``rmsnorm(y * silu(z)) * w`` over groups of ``inner /
+  ssm_groups`` (gate before norm, a weight a channel), output projection.
 * ``"full"`` — ``q|gate`` per head, per-head RMSNorm of q and k, rotary on
   the first ``rotary_dim`` features, causal flash attention in the (batch,
   seq, heads, head_dim) layout with grouped kv heads, ``sigmoid(gate)`` on
@@ -53,11 +67,13 @@ except what the half's policy keeps by name (``MIXER_SAVED``,
 ``EXPERTS_SAVED``): a mixer half keeps the results of its kernels — the
 flash call's output and its log-sum-exp rows (``ops.attention.FLASH_SAVED``),
 the delta rule's output and its chunks' entry states
-(``ops.gated_delta_rule.RULE_SAVED``), which their backward rules read, so
+(``ops.gated_delta_rule.RULE_SAVED``), the state-space scan's likewise
+(``ops.ssd.SSD_SAVED``), which their backward rules read, so
 no forward kernel runs twice — and the outputs of its input projections
 (``"mix_proj"``: q, gate, k, v of an attention layer, ``q|k|v|z`` and ``b|a``
-of a delta-rule layer); an expert half keeps its routing plan. Norms,
-rotary, the convolution, the gates and ``w_o``'s operand are computed again.
+of a delta-rule layer, ``xBC|z|dt`` of a state-space layer); an expert half
+keeps its routing plan. Norms, rotary, the convolution, the gates and
+``w_o``'s operand are computed again.
 
 ``loss_fn`` has ``GPTModel.loss_fn``'s signature, so
 ``amp.scaled_value_and_grad`` and the trainers take either model.
@@ -79,19 +95,22 @@ from apex_tpu.ops.attention import FLASH_SAVED, flash_attention
 from apex_tpu.ops.gated_delta_rule import (RULE_SAVED, causal_conv_silu, gated_delta_rule,
                                            gated_rms_norm)
 from apex_tpu.ops.rotary import apply_partial_rotary, yarn_mscale
+from apex_tpu.ops.ssd import SSD_SAVED, ssd_scan
 from apex_tpu.transformer import tensor_parallel as tp_lib
-from apex_tpu.transformer.moe import dropless_moe_layer, silu_gate
+from apex_tpu.transformer.moe import (ACTIVATIONS, FIRST_LEAVES, dropless_moe_layer,
+                                      silu_gate)
 
 ATTENTION_KINDS = ("full", "window")
-GROUP_OF_KIND = {"linear": "gdn", "full": "attn", "window": "attn", "latent": "mla"}
+GROUP_OF_KIND = {"linear": "gdn", "full": "attn", "window": "attn", "latent": "mla",
+                 "ssm": "ssm"}
 MIXER_SCOPES = {"linear": "hybrid/gdn", "full": "hybrid/attn", "window": "hybrid/attn_win",
-                "latent": "hybrid/attn_mla"}
+                "latent": "hybrid/attn_mla", "ssm": "hybrid/ssm"}
 # What ``remat`` keeps of a half beside its arguments, by name. A mixer half:
 # the results of its kernels (the names their forward rules give them: which
 # of them exist in a half follows from the kernels its layer kind runs) and
 # the outputs of its input projections. An expert half: the routing plan
 # (``transformer.moe``).
-MIXER_SAVED = FLASH_SAVED + RULE_SAVED + ("mix_proj",)
+MIXER_SAVED = FLASH_SAVED + RULE_SAVED + SSD_SAVED + ("mix_proj",)
 EXPERTS_SAVED = ("moe_plan",)
 
 
@@ -106,6 +125,10 @@ class HybridDecoderConfig:
     head_dim: int = 256
     rotary_dim: int = 64
     rope_theta: float = 1e7
+    # the attention mixer's output gate (``w_q`` holds q|gate a head) and its
+    # per-head RMSNorms of q and k; rotary_dim 0 rotates nothing
+    attn_gate: bool = True
+    qk_norm: bool = True
     # "window" layers: keys a query sees, rotary features (None: rotary_dim)
     window: Optional[int] = None
     window_rotary_dim: Optional[int] = None
@@ -123,6 +146,13 @@ class HybridDecoderConfig:
     linear_key_dim: int = 128
     linear_value_dim: int = 128
     conv_kernel: int = 4
+    # state-space (Mamba-2) layers: heads of ssm_head_dim, the state's rows,
+    # groups that share B and C, tokens a chunk (their convolution has a bias)
+    ssm_heads: int = 64
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_groups: int = 8
+    ssm_chunk: int = 128
     # experts: the router's width, which of them are held, experts a token
     router_experts: int = 512
     experts_held: Optional[Tuple[int, int]] = None      # (first, count); None = all
@@ -134,9 +164,12 @@ class HybridDecoderConfig:
     router_score: str = "softmax"
     route_scale: float = 1.0
     shared_gate: bool = True
+    # "silu_gate": SwiGLU experts over a fused gate|up; "relu2": ungated
+    # relu(x W_up)^2 W_down (leaves w_up, shared_up)
+    expert_activation: str = "silu_gate"
     # the balance term per sequence (mean over the rows) instead of batch-wise
     seq_aux: bool = False
-    # second half of each layer, "moe" | "dense"; None: experts in every layer
+    # second half of each layer, "moe" | "dense" | "none"; None: experts in every layer
     ffn_types: Optional[Tuple[str, ...]] = None
     dense_ffn: int = 0
     rms_eps: float = 1e-6
@@ -148,6 +181,8 @@ class HybridDecoderConfig:
     # projections and the routing plan (MIXER_SAVED, EXPERTS_SAVED)
     remat: bool = False
     attention_impl: str = "auto"
+    # the recurrent mixers' kernels: the delta rule, the state-space scan and
+    # the convolution and gated norm around either
     delta_impl: str = "auto"
     experts_impl: str = "auto"
     dtype: Any = jnp.float32
@@ -155,14 +190,20 @@ class HybridDecoderConfig:
     def __post_init__(self):
         bad = set(self.layer_types) - set(GROUP_OF_KIND)
         if bad or not self.layer_types:
-            raise ValueError("layer_types holds 'linear', 'full', 'window' and 'latent', "
-                             f"got {self.layer_types!r}")
+            raise ValueError("layer_types holds 'linear', 'ssm', 'full', 'window' and "
+                             f"'latent', got {self.layer_types!r}")
         if hasattr(self.rope_scaling, "items"):             # hashable, as the rest
             object.__setattr__(self, "rope_scaling", tuple(sorted(self.rope_scaling.items())))
         if "window" in self.layer_types and not self.window:
             raise ValueError("a 'window' layer needs window=")
-        if set(self.ffn) - {"moe", "dense"} or len(self.ffn) != len(self.layer_types):
-            raise ValueError(f"ffn_types names every layer 'moe' or 'dense', got {self.ffn_types!r}")
+        if set(self.ffn) - {"moe", "dense", "none"} or len(self.ffn) != len(self.layer_types):
+            raise ValueError("ffn_types names every layer 'moe', 'dense' or 'none', "
+                             f"got {self.ffn_types!r}")
+        if self.expert_activation not in ACTIVATIONS:
+            raise ValueError(f"expert_activation is one of {sorted(ACTIVATIONS)}, "
+                             f"got {self.expert_activation!r}")
+        if "ssm" in self.layer_types and self.ssm_heads % self.ssm_groups:
+            raise ValueError("state-space heads must be a multiple of their groups")
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError(f"router_score is 'softmax' or 'sigmoid', got {self.router_score!r}")
         if self.num_heads % self.num_kv_heads or self.linear_value_heads % self.linear_key_heads:
@@ -186,7 +227,7 @@ def _norm(x, w, eps, zero_centered=True):
 
 
 class HybridDecoderModel:
-    float32_params = ("A_log", "dt_bias")
+    float32_params = ("A_log", "dt_bias", "D")
 
     def __init__(self, config: HybridDecoderConfig):
         self.config = config
@@ -200,8 +241,8 @@ class HybridDecoderModel:
         1/sqrt(2 L); decay ``A ~ U(1, 16)``, ``dt ~ logU(1e-3, 1e-1)``)."""
         c = self.config
         H, L = c.hidden_size, len(c.layer_types)
-        Lg, Ll = c.layer_types.count("linear"), c.layer_types.count("latent")
-        La = L - Lg - Ll
+        Lg, Ll, Ls = (c.layer_types.count(kind) for kind in ("linear", "latent", "ssm"))
+        La = L - Lg - Ll - Ls
         Lm, Ld = c.ffn.count("moe"), c.ffn.count("dense")
         qk, vv = c.linear_key_heads * c.linear_key_dim, c.linear_value_heads * c.linear_value_dim
         keys = iter(jax.random.split(key, 32))
@@ -215,8 +256,9 @@ class HybridDecoderModel:
         # a norm's weight at rest: 0 where it is added to 1, 1 where it is not
         unit = zeros if c.zero_centered_norm else lambda shape: jnp.ones(shape, c.dtype)
         Eh = c.held[1]
+        up, shared_up, gated = FIRST_LEAVES[c.expert_activation]
         layers = {
-            "norm1": unit((L, H)), "norm2": unit((L, H)),
+            "norm1": unit((L, H)), "norm2": unit((Lm + Ld, H)),
             "gdn": {
                 "w_qkvz": n((Lg, H, 2 * qk + 2 * vv)), "w_ba": n((Lg, H, 2 * c.linear_value_heads)),
                 "conv_w": jax.random.uniform(next(keys), (Lg, c.conv_kernel, 2 * qk + vv),
@@ -226,7 +268,7 @@ class HybridDecoderModel:
                 "w_o": n((Lg, vv, H), res),
             },
             "attn": {
-                "w_q": n((La, H, 2 * c.num_heads * c.head_dim)),
+                "w_q": n((La, H, (2 if c.attn_gate else 1) * c.num_heads * c.head_dim)),
                 "w_k": n((La, H, c.num_kv_heads * c.head_dim)),
                 "w_v": n((La, H, c.num_kv_heads * c.head_dim)),
                 "q_norm": unit((La, c.head_dim)), "k_norm": unit((La, c.head_dim)),
@@ -241,9 +283,9 @@ class HybridDecoderModel:
             },
             "moe": {
                 "router": n((Lm, H, c.router_experts)),
-                "w_gate_up": n((Lm, Eh, H, 2 * c.expert_ffn)),
+                up: n((Lm, Eh, H, gated * c.expert_ffn)),
                 "w_down": n((Lm, Eh, c.expert_ffn, H), res),
-                "shared_gate_up": n((Lm, H, 2 * c.shared_ffn)),
+                shared_up: n((Lm, H, gated * c.shared_ffn)),
                 "shared_down": n((Lm, c.shared_ffn, H), res),
                 "shared_mix": n((Lm, H)),
             },
@@ -254,17 +296,43 @@ class HybridDecoderModel:
         }
         if not c.shared_gate:
             del layers["moe"]["shared_mix"]
+        if not c.qk_norm:
+            del layers["attn"]["q_norm"], layers["attn"]["k_norm"]
         if c.sandwich_norms:
-            layers.update(norm1_post=unit((L, H)), norm2_post=unit((L, H)))
+            layers.update(norm1_post=unit((L, H)), norm2_post=unit((Lm + Ld, H)))
         for group, count in (("gdn", Lg), ("attn", La), ("mla", Ll), ("moe", Lm),
                              ("dense", Ld)):
             if not count:
                 del layers[group]
-        return {
+        params = {
             "embedding": {"weight": n((c.vocab_size, H))},
             "head": {"weight": n((c.vocab_size, H))},
             "norm_f": unit((H,)),
             "layers": layers,
+        }
+        if Ls:                 # drawn last: the other groups keep the keys they had
+            layers["ssm"] = self._init_ssm(Ls, n, keys, res)
+        return params
+
+    def _init_ssm(self, Ls, n, keys, res):
+        """The state-space group (Mamba-2's start values: ``dt ~ logU(1e-3,
+        1e-1)`` floored at 1e-4 through the inverse softplus, ``A ~ U(1, 16)``,
+        ``D = 1``, the gated norm's weight 1)."""
+        c = self.config
+        H, nh = c.hidden_size, c.ssm_heads
+        inner, bc = nh * c.ssm_head_dim, c.ssm_groups * c.ssm_state
+        dt = jnp.maximum(jnp.exp(jax.random.uniform(
+            next(keys), (Ls, nh), jnp.float32, jnp.log(1e-3), jnp.log(1e-1))), 1e-4)
+        a = jax.random.uniform(next(keys), (Ls, nh), jnp.float32, 1.0, 16.0)
+        return {
+            "w_in": n((Ls, H, 2 * inner + 2 * bc + nh)),
+            "conv_w": jax.random.uniform(next(keys), (Ls, c.conv_kernel, inner + 2 * bc),
+                                         jnp.float32, -0.5, 0.5).astype(c.dtype),
+            "conv_b": n((Ls, inner + 2 * bc)),
+            "A_log": jnp.log(a), "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "D": jnp.ones((Ls, nh), jnp.float32),
+            "norm_w": jnp.ones((Ls, inner), c.dtype),
+            "w_o": n((Ls, inner, H), res),
         }
 
     def init_router_bias(self):
@@ -297,6 +365,29 @@ class HybridDecoderModel:
         with monitor_spans.span("mix/proj_out"):
             return jnp.dot(o.reshape(b, s, hv * dv), p["w_o"])
 
+    def _ssm_mixer(self, p, x):
+        c = self.config
+        b, s, _ = x.shape
+        nh, dh, groups, state = c.ssm_heads, c.ssm_head_dim, c.ssm_groups, c.ssm_state
+        inner, bc = nh * dh, groups * state
+        with monitor_spans.span("mix/proj_in"):
+            proj = checkpoint_name(jnp.dot(x, p["w_in"]), "mix_proj")
+        # xBC, z and dt are read where the projection left them
+        xs, B, C = causal_conv_silu(proj, p["conv_w"], p["conv_b"],
+                                    widths=(inner, bc, bc), impl=c.delta_impl)
+        with monitor_spans.span("mix/place"):
+            dt = jax.nn.softplus(proj[..., 2 * inner + 2 * bc:].astype(jnp.float32)
+                                 + p["dt_bias"].astype(jnp.float32))
+            A = -jnp.exp(p["A_log"].astype(jnp.float32))
+        y = ssd_scan(xs.reshape(b, s, nh, dh), dt, A, B.reshape(b, s, groups, state),
+                     C.reshape(b, s, groups, state), p["D"], chunk=c.ssm_chunk,
+                     impl=c.delta_impl)
+        y = gated_rms_norm(y.reshape(b, s, groups, inner // groups), proj,
+                           p["norm_w"].reshape(groups, inner // groups), c.rms_eps,
+                           gate_first=True, gate_start=inner + 2 * bc, impl=c.delta_impl)
+        with monitor_spans.span("mix/proj_out"):
+            return jnp.dot(y.reshape(b, s, inner), p["w_o"])
+
     def _attention_mixer(self, p, x, kind="full"):
         c = self.config
         b, s, _ = x.shape
@@ -304,21 +395,27 @@ class HybridDecoderModel:
         banded = kind == "window"
         rot = c.rotary_dim if not banded or c.window_rotary_dim is None else c.window_rotary_dim
         with monitor_spans.span("mix/proj_in"):
-            qg = jnp.dot(x, p["w_q"]).reshape(b, s, nh, 2 * dh)
-            q, gate, k, v = (checkpoint_name(a, "mix_proj") for a in (
-                qg[..., :dh], qg[..., dh:], jnp.dot(x, p["w_k"]).reshape(b, s, nkv, dh),
-                jnp.dot(x, p["w_v"]).reshape(b, s, nkv, dh)))
+            kv = lambda w: jnp.dot(x, w).reshape(b, s, nkv, dh)  # noqa: E731
+            if c.attn_gate:
+                qg = jnp.dot(x, p["w_q"]).reshape(b, s, nh, 2 * dh)
+                q, gate, k, v = (checkpoint_name(a, "mix_proj") for a in (
+                    qg[..., :dh], qg[..., dh:], kv(p["w_k"]), kv(p["w_v"])))
+            else:
+                q, k, v = (checkpoint_name(a, "mix_proj") for a in (
+                    jnp.dot(x, p["w_q"]).reshape(b, s, nh, dh), kv(p["w_k"]), kv(p["w_v"])))
 
         def placed(x, w):                          # per-head norm, then its position
-            x = self._norm(x, w)
+            x = self._norm(x, w) if c.qk_norm else x
             return apply_partial_rotary(x, rot, c.rope_theta) if rot else x
 
-        with monitor_spans.span("mix/place"):
-            q, k = placed(q, p["q_norm"]), placed(k, p["k_norm"])
+        if c.qk_norm or rot:
+            with monitor_spans.span("mix/place"):
+                q, k = placed(q, p.get("q_norm")), placed(k, p.get("k_norm"))
         ctx = flash_attention(q, k, v, causal=True, scale=dh ** -0.5, layout="bshd",
                               impl=c.attention_impl, window=c.window if banded else None)
-        with monitor_spans.span("mix/place"):
-            ctx = ctx * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(ctx.dtype)
+        if c.attn_gate:
+            with monitor_spans.span("mix/place"):
+                ctx = ctx * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(ctx.dtype)
         with monitor_spans.span("mix/proj_out"):
             return jnp.dot(ctx.reshape(b, s, nh * dh), p["w_o"])
 
@@ -356,7 +453,7 @@ class HybridDecoderModel:
             p, x, top_k=c.top_k, experts_held=c.held, normalize_weights=c.normalize_topk,
             impl=c.experts_impl, score=c.router_score, route_scale=c.route_scale,
             router_bias=router_bias, shared_gate=c.shared_gate,
-            sequence_balance=c.seq_aux)
+            sequence_balance=c.seq_aux, activation=c.expert_activation)
 
     @staticmethod
     def _dense(p, x):
@@ -371,7 +468,8 @@ class HybridDecoderModel:
 
     def _mixer_half(self, kind):
         """``(p, w, post, x) -> x + mixer(norm(x))`` of a ``kind`` layer."""
-        mixers = {"linear": self._delta_mixer, "latent": self._latent_mixer}
+        mixers = {"linear": self._delta_mixer, "latent": self._latent_mixer,
+                  "ssm": self._ssm_mixer}
 
         def half(p, w, post, x):
             with monitor_spans.span(MIXER_SCOPES[kind]):
@@ -417,7 +515,7 @@ class HybridDecoderModel:
         expert_half = self._recomputed(self._expert_half, EXPERTS_SAVED)
         dense_half = self._recomputed(self._dense_half)
         post1, post2 = layers.get("norm1_post"), layers.get("norm2_post")
-        seen = {"gdn": 0, "attn": 0, "mla": 0, "moe": 0, "dense": 0}
+        seen = {"gdn": 0, "ssm": 0, "attn": 0, "mla": 0, "moe": 0, "dense": 0}
         lb, loads, counts, dropped = 0.0, [], [], 0
 
         def take(group):
@@ -425,17 +523,21 @@ class HybridDecoderModel:
             seen[group] += 1
             return j, jax.tree.map(lambda a: a[j], layers[group])
 
+        second = 0             # second halves so far: the row of norm2 (and norm2_post)
         for i, (kind, ffn) in enumerate(zip(c.layer_types, c.ffn)):
             _, p_mix = take(GROUP_OF_KIND[kind])
-            j, p_ffn = take(ffn)
+            j, p_ffn = take(ffn) if ffn != "none" else (None, None)
             x = self._mixer_half(kind)(p_mix, layers["norm1"][i],
                                        None if post1 is None else post1[i], x)
-            post = None if post2 is None else post2[i]
+            if ffn == "none":
+                continue
+            row, second = second, second + 1
+            post = None if post2 is None else post2[row]
             if ffn == "dense":
-                x = dense_half(p_ffn, layers["norm2"][i], post, x)
+                x = dense_half(p_ffn, layers["norm2"][row], post, x)
                 continue
             bias = None if router_bias is None else router_bias[j]
-            x, aux = expert_half(p_ffn, layers["norm2"][i], post, bias, x)
+            x, aux = expert_half(p_ffn, layers["norm2"][row], post, bias, x)
             lb = lb + aux["load_balance_loss"]
             loads.append(aux["expert_load"])
             counts.append(aux["router_counts"])

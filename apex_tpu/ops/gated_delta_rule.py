@@ -49,52 +49,65 @@ from apex_tpu.ops.pallas import gated_delta_rule as _k
 _HI = jax.lax.Precision.HIGHEST
 
 
-def _conv_xla(x, w):
+def _conv_xla(x, w, bias=None):
     taps, t = w.shape[0], x.shape[1]
     pad = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
     y = sum(pad[:, j:j + t].astype(jnp.float32) * w[j].astype(jnp.float32)
             for j in range(taps))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return jax.nn.silu(y).astype(x.dtype)
 
 
-def _norm_xla(x, gate, weight, eps):
+def _norm_xla(x, gate, weight, eps, gate_first=False):
     x32 = x.astype(jnp.float32)
+    silu = jax.nn.silu(gate.astype(jnp.float32))
+    if gate_first:
+        x32 = x32 * silu
     y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
-    y = y * weight.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
-    return y.astype(x.dtype)
+    y = y * weight.astype(jnp.float32)
+    return (y if gate_first else y * silu).astype(x.dtype)
 
 
 # jitted: the layers of a model share one traced and lowered program a kernel
 _conv_fwd = jax.jit(_m.conv_silu_fwd, static_argnames=("start", "interpret"))
 _conv_bwd = jax.jit(_m.conv_silu_bwd, static_argnames=("start", "interpret"))
-_norm_fwd = jax.jit(_m.gated_norm_fwd, static_argnames=("start", "dim", "eps", "interpret"))
-_norm_bwd = jax.jit(_m.gated_norm_bwd, static_argnames=("start", "dim", "eps", "interpret"))
+_NORM_STATIC = ("start", "dim", "eps", "gate_first", "interpret")
+_norm_fwd = jax.jit(_m.gated_norm_fwd, static_argnames=_NORM_STATIC)
+_norm_bwd = jax.jit(_m.gated_norm_bwd, static_argnames=_NORM_STATIC)
 
 
-def _pieces(w, widths):
-    """(first channel, float32 taps) of every piece."""
+def _pieces(w, bias, widths):
+    """(first channel, float32 taps, float32 bias (1, n) or None) of every piece."""
     starts = [sum(widths[:i]) for i in range(len(widths))]
-    return [(s, w[:, s:s + n].astype(jnp.float32)) for s, n in zip(starts, widths)]
+    cut = lambda a, s, n: a[..., s:s + n].astype(jnp.float32)  # noqa: E731
+    return [(s, cut(w, s, n), None if bias is None else cut(bias[None], s, n))
+            for s, n in zip(starts, widths)]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _conv_pallas(x, w, widths, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _conv_pallas(x, w, bias, widths, interpret):
     """The convolution on the kernels, a call a piece: every piece reads its
-    channels of ``x`` in place and is an array of its own."""
-    return tuple(_conv_fwd(x, wp, start=s, interpret=interpret) for s, wp in _pieces(w, widths))
+    channels of ``x`` in place and is an array of its own. ``bias`` (c,) or
+    None."""
+    return tuple(_conv_fwd(x, wp, bp, start=s, interpret=interpret)
+                 for s, wp, bp in _pieces(w, bias, widths))
 
 
-def _conv_pallas_fwd(x, w, widths, interpret):
-    return _conv_pallas(x, w, widths, interpret), (x, w)
+def _conv_pallas_fwd(x, w, bias, widths, interpret):
+    return _conv_pallas(x, w, bias, widths, interpret), (x, w, bias)
 
 
 def _conv_pallas_bwd(widths, interpret, res, dys):
-    x, w = res
-    dxs, dws = zip(*(_conv_bwd(x, wp, dy, start=s, interpret=interpret)
-                     for (s, wp), dy in zip(_pieces(w, widths), dys)))
+    x, w, bias = res
+    dxs, dws = zip(*(_conv_bwd(x, wp, dy, bp, start=s, interpret=interpret)
+                     for (s, wp, bp), dy in zip(_pieces(w, bias, widths), dys)))
     rest = jnp.zeros(x.shape[:2] + (x.shape[2] - sum(widths),), x.dtype)
-    dw = jnp.concatenate([jnp.sum(d, axis=(0, 2)) for d in dws], axis=1)
-    return jnp.concatenate(dxs + (rest,), axis=2), dw.astype(w.dtype)
+    # the taps' rows and, under them, the bias's where there is one
+    d = jnp.concatenate([jnp.sum(d, axis=(0, 2)) for d in dws], axis=1)
+    taps = w.shape[0]
+    return (jnp.concatenate(dxs + (rest,), axis=2), d[:taps].astype(w.dtype),
+            None if bias is None else d[taps].astype(bias.dtype))
 
 
 _conv_pallas.defvjp(_conv_pallas_fwd, _conv_pallas_bwd)
@@ -109,11 +122,12 @@ def conv_shapes_ok(x, w, widths) -> bool:
             and w.shape[0] - 1 <= _m.HALO)
 
 
-def causal_conv_silu(x, w, *, widths=None, impl: str = "auto"):
+def causal_conv_silu(x, w, bias=None, *, widths=None, impl: str = "auto"):
     """Depthwise causal convolution over time, then SiLU. ``x`` (b, t, c');
     ``w`` (taps, c) with the last tap on the current token and ``c <= c'``:
     the first ``c`` channels of ``x`` are convolved (a fused projection is
-    read in place). Float32 accumulation, output (b, t, c) in ``x``'s dtype —
+    read in place); ``bias`` (c,) is added before the SiLU. Float32
+    accumulation, output (b, t, c) in ``x``'s dtype —
     or, with ``widths`` (which sum to ``c``), the tuple of its pieces.
 
     ``impl``: ``auto`` | ``pallas`` | ``xla`` — the ``conv_silu_fwd`` /
@@ -123,64 +137,78 @@ def causal_conv_silu(x, w, *, widths=None, impl: str = "auto"):
     c = w.shape[1]
     parts = tuple(widths) if widths is not None else (c,)
     if _backend.choose_impl(impl, conv_shapes_ok(x, w, parts)) == "pallas":
-        ys = _conv_pallas(x, w, parts, _backend.interpret_mode())
+        ys = _conv_pallas(x, w, bias, parts, _backend.interpret_mode())
     else:
-        y = _conv_xla(x[..., :c], w)
+        y = _conv_xla(x[..., :c], w, bias)
         ys = jnp.split(y, [sum(parts[:i]) for i in range(1, len(parts))], axis=-1)
     return tuple(ys) if widths is not None else ys[0]
 
 
+def _weight_row(w):
+    """The norm's weight as the kernels take it: (1, dim) for every head, or
+    (1, heads dim) a channel its own."""
+    w = w.astype(jnp.float32)
+    return w[None] if w.ndim == 1 else w.reshape(1, -1)
+
+
 def _rows(x, gate):
-    """``x`` (..., heads, dim) and ``gate`` (..., c) as rows, and the gate's
-    first channel."""
-    c = x.shape[-2] * x.shape[-1]
-    return x.reshape(-1, c), gate.reshape(-1, gate.shape[-1]), gate.shape[-1] - c
+    """``x`` (..., heads, dim) and ``gate`` (..., c) as rows."""
+    return x.reshape(-1, x.shape[-2] * x.shape[-1]), gate.reshape(-1, gate.shape[-1])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _norm_pallas(x, gate, w, eps, interpret):
-    """``x`` (..., heads, dim); ``gate`` (..., c): its last ``heads dim``
-    channels gate; ``w`` (dim,)."""
-    o, z, start = _rows(x, gate)
-    y = _norm_fwd(o, z, w.astype(jnp.float32)[None], start=start, dim=x.shape[-1], eps=eps,
-                  interpret=interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _norm_pallas(x, gate, w, eps, start, gate_first, interpret):
+    """``x`` (..., heads, dim); ``gate`` (..., c): its ``heads dim`` channels
+    from ``start`` on gate; ``w`` (dim,), or (heads, dim) a channel its own."""
+    o, z = _rows(x, gate)
+    y = _norm_fwd(o, z, _weight_row(w), start=start, dim=x.shape[-1],
+                  eps=eps, gate_first=gate_first, interpret=interpret)
     return y.reshape(x.shape)
 
 
-def _norm_pallas_fwd(x, gate, w, eps, interpret):
-    return _norm_pallas(x, gate, w, eps, interpret), (x, gate, w)
+def _norm_pallas_fwd(x, gate, w, eps, start, gate_first, interpret):
+    return _norm_pallas(x, gate, w, eps, start, gate_first, interpret), (x, gate, w)
 
 
-def _norm_pallas_bwd(eps, interpret, res, dy):
+def _norm_pallas_bwd(eps, start, gate_first, interpret, res, dy):
     x, gate, w = res
-    o, z, start = _rows(x, gate)
-    do, dz, dw = _norm_bwd(o, z, w.astype(jnp.float32)[None], dy.reshape(o.shape), start=start,
-                           dim=x.shape[-1], eps=eps, interpret=interpret)
+    o, z = _rows(x, gate)
+    do, dz, dw = _norm_bwd(o, z, _weight_row(w), dy.reshape(o.shape),
+                           start=start, dim=x.shape[-1], eps=eps, gate_first=gate_first,
+                           interpret=interpret)
     # the other channels' zeros in the gate's own shape: XLA folds them into
     # whatever reads the cotangent
-    dz = jnp.pad(dz.reshape(gate.shape[:-1] + (-1,)), ((0, 0),) * (gate.ndim - 1) + ((start, 0),))
-    return do.reshape(x.shape), dz, jnp.sum(dw, axis=(0, 1)).astype(w.dtype)
+    after = gate.shape[-1] - start - o.shape[-1]
+    dz = jnp.pad(dz.reshape(gate.shape[:-1] + (-1,)),
+                 ((0, 0),) * (gate.ndim - 1) + ((start, after),))
+    if w.ndim == 1:
+        return do.reshape(x.shape), dz, jnp.sum(dw, axis=(0, 1)).astype(w.dtype)
+    return do.reshape(x.shape), dz, jnp.sum(dw, axis=1).reshape(w.shape).astype(w.dtype)
 
 
 _norm_pallas.defvjp(_norm_pallas_fwd, _norm_pallas_bwd)
 
 
-def norm_shapes_ok(x, gate) -> bool:
+def norm_shapes_ok(x, gate, start=None) -> bool:
     """What the gated norm's kernels need: a head in whole lane blocks and
     whole heads a channel block (so the gate's first channel is on a block's
     edge), rows in whole sublane tiles of the dtypes."""
     heads, dim = x.shape[-2:]
     rows = x.size // (heads * dim)
-    return (dim % _m.LANES == 0 and _m.NORM_BLOCK[1] % dim == 0
-            and (gate.shape[-1] - heads * dim) % dim == 0
+    start = gate.shape[-1] - heads * dim if start is None else start
+    return (dim % _m.LANES == 0 and _m.NORM_BLOCK[1] % dim == 0 and start % dim == 0
             and rows % max(_m.sublanes(x.dtype), _m.sublanes(gate.dtype)) == 0)
 
 
-def gated_rms_norm(x, gate, weight, eps=1e-6, *, impl: str = "auto"):
-    """``rmsnorm(x) * weight * silu(gate)`` over the last axis (one head),
-    statistics in float32. ``x`` (..., heads, dim); ``gate`` in ``x``'s shape,
-    or (..., c) with ``c >= heads dim``: the last ``heads dim`` channels of a
-    fused projection, read in place.
+def gated_rms_norm(x, gate, weight, eps=1e-6, *, gate_first: bool = False, gate_start=None,
+                   impl: str = "auto"):
+    """``rmsnorm(x) * weight * silu(gate)`` over the last axis (one head, or
+    one group of a wider layer), statistics in float32 — with ``gate_first``
+    ``rmsnorm(x * silu(gate)) * weight``, the gate inside the statistics.
+    ``x`` (..., heads, dim); ``weight`` (dim,) for every head, or (heads, dim)
+    a channel its own; ``gate`` in ``x``'s shape, or (..., c) with ``c >=
+    heads dim``: ``heads dim`` channels of a fused projection, read in place
+    from channel ``gate_start`` on (default: the last ones).
 
     ``impl``: ``auto`` | ``pallas`` | ``xla`` — the ``gated_norm_fwd`` /
     ``gated_norm_bwd`` kernels (one pass each, the statistics recomputed in
@@ -188,9 +216,12 @@ def gated_rms_norm(x, gate, weight, eps=1e-6, *, impl: str = "auto"):
     heads, dim = x.shape[-2:]
     if gate.ndim == x.ndim:
         gate = gate.reshape(gate.shape[:-2] + (heads * dim,))
-    if _backend.choose_impl(impl, norm_shapes_ok(x, gate)) == "pallas":
-        return _norm_pallas(x, gate, weight, float(eps), _backend.interpret_mode())
-    return _norm_xla(x, gate[..., gate.shape[-1] - heads * dim:].reshape(x.shape), weight, eps)
+    start = gate.shape[-1] - heads * dim if gate_start is None else gate_start
+    if _backend.choose_impl(impl, norm_shapes_ok(x, gate, start)) == "pallas":
+        return _norm_pallas(x, gate, weight, float(eps), start, bool(gate_first),
+                            _backend.interpret_mode())
+    return _norm_xla(x, gate[..., start:start + heads * dim].reshape(x.shape), weight, eps,
+                     gate_first)
 
 
 def l2_normalize(x, eps=_k.EPS):

@@ -127,12 +127,17 @@ def moe_rows_pack(a, n_used, *, interpret=False):
     )(n_used, a)
 
 
+def padded_width(width, dtype, interpret):
+    """The least width the kernels take that holds ``width``: the strided
+    read-back wants a row's group to be whole tiles of eight lines (compiled:
+    multiples of 1,024 float32, 2,048 bf16), any whole lines interpreted."""
+    quantum = (1 if interpret else 8) * (2 * LANES if _packed(dtype) else LANES)
+    return -(-width // quantum) * quantum
+
+
 def shapes_ok(width, dtype, interpret):
-    """Widths the kernels take: the strided read-back wants a row's group to
-    be whole tiles of eight lines (compiled: 1,024 float32, 2,048 bf16), any
-    whole lines interpreted."""
-    lines = 1 if interpret else 8
-    return width % (lines * (2 * LANES if _packed(dtype) else LANES)) == 0
+    """Widths the kernels take as they are."""
+    return padded_width(width, dtype, interpret) == width
 
 
 def list_length(per_token):

@@ -50,13 +50,14 @@ SPANS: Dict[str, str] = {
     "hybrid/gdn": "a delta-rule mixer half", "hybrid/attn": "a full-attention mixer half",
     "hybrid/attn_win": "a windowed-attention mixer half",
     "hybrid/attn_mla": "a latent-attention mixer half",
+    "hybrid/ssm": "a state-space (Mamba-2) mixer half",
     "hybrid/dense": "a dense feed-forward half", "hybrid/moe": "an expert half",
     "hybrid/unembed_xent": "models/hybrid_decoder.py loss_fn",
     "moe/route": "transformer/moe.py dropless_moe_layer: scores, top-k, the plan",
     "moe/experts": "the grouped products", "moe/shared": "the shared experts",
     "mla/down": "_latent_mixer's proj_in: the query and down projections, the latent's norm",
     "mla/up": "_latent_mixer's proj_in: W_kvb",
-    "mix/proj_in": "a mixer's input projections (w_q, w_k, w_v; w_qkvz, w_ba)",
+    "mix/proj_in": "a mixer's input projections (w_q, w_k, w_v; w_qkvz, w_ba; w_in)",
     "mix/place": "what stands between the projections and the kernel and after it: "
                  "per-head norms, rotary, the gates, beta / g",
     "mix/proj_out": "a mixer's output projection (w_o)",

@@ -551,21 +551,39 @@ def _f0(a):
     return np.zeros(a.shape, jax.dtypes.float0)
 
 
+def _rows_width(a):
+    """The width the row kernels move ``a``'s rows at: its own where the row
+    DMA takes it (compiled: a multiple of 2,048 bf16), else the next it takes,
+    zeros in the columns added (2,688 moves at 4,096: the kernels' cost
+    follows the rows in use, XLA's gathers run over every row of a block, so
+    a row half as wide again is still the cheaper)."""
+    return rk.padded_width(a.shape[-1], a.dtype, _backend.interpret_mode())
+
+
 def _rows_impl(impl, a):
-    """The movements' implementation. A width the grouped products take and
-    the row DMA cannot (compiled: no multiple of 2,048 bf16) keeps XLA's
-    movements under ``impl="pallas"`` too, as it ran before the row kernels."""
-    ok = rk.shapes_ok(a.shape[-1], a.dtype, _backend.interpret_mode())
+    """The movements' implementation. A width that would move at twice its
+    own or more keeps XLA's movements under ``impl="pallas"`` too, as it ran
+    before the row kernels."""
+    ok = _rows_width(a) < 2 * a.shape[-1]
     return _backend.choose_impl(impl if ok else "xla", ok)
+
+
+def _widened(a, wide):
+    return a if a.shape[-1] == wide else jnp.pad(a, ((0, 0), (0, wide - a.shape[-1])))
 
 
 def _gather_rows(x, move, scale, dot_with=None):
     """``moe_rows_gather`` over one block: row r takes ``scale[r]`` times the
     token ``row_token[r]`` (rows that ``row_valid`` excludes: a scale of 0)."""
-    return rk.moe_rows_gather(
-        rk.as_groups(x), move["row_token"],
-        jnp.where(move["row_valid"], scale, 0.0), move["n_used"], dot_with,
-        width=x.shape[-1], dtype=x.dtype, interpret=_backend.interpret_mode())
+    H, wide = x.shape[-1], _rows_width(x)
+    out = rk.moe_rows_gather(
+        rk.as_groups(_widened(x, wide)), move["row_token"],
+        jnp.where(move["row_valid"], scale, 0.0), move["n_used"],
+        None if dot_with is None else _widened(dot_with, wide),
+        width=wide, dtype=x.dtype, interpret=_backend.interpret_mode())
+    if wide == H:
+        return out
+    return out[:, :H] if dot_with is None else (out[0][:, :H], out[1])
 
 
 def _combine_rows(y, move, weights=None):
@@ -576,10 +594,12 @@ def _combine_rows(y, move, weights=None):
         weights = jnp.pad(weights.astype(jnp.float32),
                           ((0, move["rank"].shape[0] - tokens), (0, 0)))
     interpret = _backend.interpret_mode()
-    return rk.moe_rows_combine(
-        rk.moe_rows_pack(y, move["n_used"], interpret=interpret), move["tile_rows"],
-        move["tile_count"], move["rank"], weights,
-        tokens=tokens, width=y.shape[-1], dtype=y.dtype, interpret=interpret)
+    H, wide = y.shape[-1], _rows_width(y)
+    out = rk.moe_rows_combine(
+        rk.moe_rows_pack(_widened(y, wide), move["n_used"], interpret=interpret),
+        move["tile_rows"], move["tile_count"], move["rank"], weights,
+        tokens=tokens, width=wide, dtype=y.dtype, interpret=interpret)
+    return out if wide == H else out[:, :H]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -653,7 +673,11 @@ def grouped_matmul(x, w, tile_expert, n_used, impl="auto"):
 
 
 def _gmm_shapes_ok(x, w):
-    return x.shape[-1] % 128 == 0 and w.shape[-1] % 128 == 0
+    """Widths the grouped products take: every block is a whole matrix or a
+    tile of whole rows, so a width need not be whole lane tiles (1,856 = 14.5
+    of them runs on the kernels at its own width, nothing padded in HBM) —
+    whole half tiles, as compiled and checked on the chip."""
+    return x.shape[-1] % 64 == 0 and w.shape[-1] % 64 == 0
 
 
 def _gmm_fwd(x, w, tile_expert, n_used, impl):
@@ -688,6 +712,22 @@ def silu_gate(h):
     return (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)).astype(h.dtype)
 
 
+def relu2(h):
+    """An ungated feed-forward's hidden from its ``up`` product: ``relu(h)^2``
+    in float32, in ``h``'s dtype."""
+    r = jax.nn.relu(h.astype(jnp.float32))
+    return (r * r).astype(h.dtype)
+
+
+# an expert's hidden from its first product, by the activation's name:
+# ``silu_gate`` reads a fused ``gate|up`` (..., 2 F), ``relu2`` one ``up`` (..., F)
+ACTIVATIONS = {"silu_gate": silu_gate, "relu2": relu2}
+# the leaves of that first product (the routed experts', the shared expert's)
+# and how many times F they are wide
+FIRST_LEAVES = {"silu_gate": ("w_gate_up", "shared_gate_up", 2),
+                "relu2": ("w_up", "shared_up", 1)}
+
+
 def _block_move(plan, block, rows):
     """The plan cut to the rows ``[block * rows, (block + 1) * rows)``: what
     the two movements and the grouped products of one block read."""
@@ -708,16 +748,17 @@ def _block_move(plan, block, rows):
             "tile_count": plan["tile_count"], "rank": jnp.where(listed, plan["rank"], -1)}
 
 
-def _held_experts_block(x, weights, w_gate_up, w_down, plan, block, rows, impl):
+def _held_experts_block(x, weights, w_gate_up, w_down, plan, block, rows, impl,
+                        activation="silu_gate"):
     """What the rows ``[block * rows, (block + 1) * rows)`` of the plan add
-    to every token: gather, gate/up product, SiLU gate, down product,
-    weighted gather back."""
+    to every token: gather, gate/up (or up) product, the activation, down
+    product, weighted gather back."""
     move = _block_move(plan, block, rows)
     tile_expert, n_used = move["tile_expert"], move["n_used"][0]
     xs = _rows_from_tokens(x, move, impl)
     with monitor_spans.span("moe/experts"):
         h = grouped_matmul(xs, w_gate_up, tile_expert, n_used, impl)
-        y = grouped_matmul(silu_gate(h), w_down, tile_expert, n_used, impl)
+        y = grouped_matmul(ACTIVATIONS[activation](h), w_down, tile_expert, n_used, impl)
     return _tokens_from_rows(y, weights, move, impl)
 
 
@@ -730,31 +771,31 @@ def _blocks_used(plan, rows):
     return -(-plan["n_used"] * gk.TM // rows)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _held_experts(x, weights, w_gate_up, w_down, plan, rows, impl):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _held_experts(x, weights, w_gate_up, w_down, plan, rows, impl, activation="silu_gate"):
     """What the experts held add to every token: the plan's rows, block after
     block of ``rows``, as many blocks as the routing fills — a loop whose
     length the routing decides. The first block always runs and keeps for
     the backward pass what differentiation would keep; a loop of unknown
     length can keep nothing, so the backward pass computes every further
     block again."""
-    return _held_experts_fwd(x, weights, w_gate_up, w_down, plan, rows, impl)[0]
+    return _held_experts_fwd(x, weights, w_gate_up, w_down, plan, rows, impl, activation)[0]
 
 
-def _held_experts_fwd(x, weights, w_gate_up, w_down, plan, rows, impl):
+def _held_experts_fwd(x, weights, w_gate_up, w_down, plan, rows, impl, activation):
     args = (x, weights, w_gate_up, w_down)
     one = lambda b: functools.partial(  # noqa: E731
-        _held_experts_block, plan=plan, block=b, rows=rows, impl=impl)
+        _held_experts_block, plan=plan, block=b, rows=rows, impl=impl, activation=activation)
     y, pull_first = jax.vjp(one(0), *args)
     y = jax.lax.fori_loop(1, _blocks_used(plan, rows),
                           lambda b, y: _add(y, one(b)(*args)), y)
     return y, (pull_first, args, plan)
 
 
-def _held_experts_bwd(rows, impl, res, dy):
+def _held_experts_bwd(rows, impl, activation, res, dy):
     pull_first, args, plan = res
     one = lambda b: functools.partial(  # noqa: E731
-        _held_experts_block, plan=plan, block=b, rows=rows, impl=impl)
+        _held_experts_block, plan=plan, block=b, rows=rows, impl=impl, activation=activation)
     grads = jax.lax.fori_loop(
         1, _blocks_used(plan, rows),
         lambda b, grads: _add(grads, jax.vjp(one(b), *args)[1](dy)), pull_first(dy))
@@ -779,9 +820,13 @@ def dropless_block_rows(tokens, top_k, held, width):
 def dropless_moe_layer(params, x, *, top_k, experts_held=None,
                        normalize_weights=True, impl="auto", score="softmax",
                        route_scale=1.0, router_bias=None, shared_gate=True,
-                       sequence_balance=False):
+                       sequence_balance=False, activation="silu_gate"):
     """Sparse SwiGLU experts without token dropping, plus a shared expert,
     over ``x`` (..., hidden).
+
+    ``activation="relu2"``: ungated experts, ``relu(x W_up)^2 W_down`` — the
+    leaves ``w_up`` (held, hidden, F) and ``shared_up`` (hidden, Fs) stand
+    where ``w_gate_up`` and ``shared_gate_up`` do below.
 
     ``params``: ``router`` (hidden, E) at the router's FULL width;
     ``w_gate_up`` (held, hidden, 2 F) and ``w_down`` (held, F, hidden) of the
@@ -811,9 +856,12 @@ def dropless_moe_layer(params, x, *, top_k, experts_held=None,
     T = xt.shape[0]
     E = params["router"].shape[-1]
     held = (0, E) if experts_held is None else tuple(experts_held)
-    if params["w_gate_up"].shape[0] != held[1] or held[0] + held[1] > E:
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"activation is one of {sorted(ACTIVATIONS)}, got {activation!r}")
+    first, shared_first, _ = FIRST_LEAVES[activation]
+    if params[first].shape[0] != held[1] or held[0] + held[1] > E:
         raise ValueError(
-            f"experts_held={held} does not match the {params['w_gate_up'].shape[0]} "
+            f"experts_held={held} does not match the {params[first].shape[0]} "
             f"expert matrices given and a router of width {E}")
     with monitor_spans.span("moe/route"):
         top_e, top_p, aux_loss, counts = route_topk(
@@ -826,9 +874,9 @@ def dropless_moe_layer(params, x, *, top_k, experts_held=None,
         plan = jax.tree.map(
             lambda a: checkpoint_name(jax.lax.stop_gradient(a), "moe_plan"),
             dropless_plan(top_e, counts, held, rows, gk.TM))
-    y = _held_experts(xt, top_p, params["w_gate_up"], params["w_down"], plan, rows, impl)
+    y = _held_experts(xt, top_p, params[first], params["w_down"], plan, rows, impl, activation)
     with monitor_spans.span("moe/shared"):
-        act = silu_gate(jnp.dot(xt, params["shared_gate_up"]))
+        act = ACTIVATIONS[activation](jnp.dot(xt, params[shared_first]))
         if shared_gate:
             mix = jax.nn.sigmoid(jnp.dot(xt, params["shared_mix"],
                                          preferred_element_type=jnp.float32))

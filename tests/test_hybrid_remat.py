@@ -23,14 +23,17 @@ if ROOT not in sys.path:
 from apex_tpu.models import HybridDecoderConfig, HybridDecoderModel, hybrid_decoder  # noqa: E402
 from apex_tpu.ops.attention import FLASH_SAVED  # noqa: E402
 from apex_tpu.ops.gated_delta_rule import RULE_SAVED  # noqa: E402
+from apex_tpu.ops.ssd import SSD_SAVED  # noqa: E402
 
 LAYERS, ROWS, SEQ, HIDDEN = 2, 2, 256, 128
 # layer kind -> its forward kernel, the names its half holds, the values
-# under them (an attention layer's q, gate, k, v; ``q|k|v|z`` and ``b|a``)
+# under them (an attention layer's q, gate, k, v; ``q|k|v|z`` and ``b|a``;
+# ``xBC|z|dt``)
 KINDS = {"full": ("flash_fwd_bshd", FLASH_SAVED + ("mix_proj",), 2 + 4),
          "window": ("flash_fwd_bshd_win", FLASH_SAVED + ("mix_proj",), 2 + 4),
          "latent": ("flash_fwd_bshd_mla", FLASH_SAVED, 2),
-         "linear": ("gdn_fwd", RULE_SAVED + ("mix_proj",), 2 + 2)}
+         "linear": ("gdn_fwd", RULE_SAVED + ("mix_proj",), 2 + 2),
+         "ssm": ("ssd_fwd", SSD_SAVED + ("mix_proj",), 2 + 1)}
 
 
 def build(kind, remat):
@@ -39,7 +42,8 @@ def build(kind, remat):
         vocab_size=256, hidden_size=HIDDEN, layer_types=(kind,) * LAYERS, num_heads=2,
         num_kv_heads=1, head_dim=128, rotary_dim=32, window=128 if kind == "window" else None,
         qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128, kv_lora_rank=64, linear_key_heads=1,
-        linear_value_heads=2, linear_key_dim=128, linear_value_dim=128, router_experts=8,
+        linear_value_heads=2, linear_key_dim=128, linear_value_dim=128, ssm_heads=4,
+        ssm_head_dim=64, ssm_groups=2, router_experts=8,
         top_k=2, expert_ffn=128, shared_ffn=128, ffn_types=("dense", "moe"), dense_ffn=128,
         remat=remat, attention_impl="pallas", delta_impl="pallas", experts_impl="xla"))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (ROWS, SEQ), 0, 256)

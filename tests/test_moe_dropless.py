@@ -442,15 +442,124 @@ def test_bf16_layer_on_the_kernels_matches_the_xla_composition(top_k=6, held=(0,
         close(got[2][name], want[2][name], name)
 
 
-@pytest.mark.parametrize("width, dtype, takes", [
-    (2048, jnp.bfloat16, "pallas"), (1024, jnp.float32, "pallas"),
-    (1024, jnp.bfloat16, "xla"), (512, jnp.float32, "xla")])
+@pytest.mark.parametrize("width, dtype, takes, moves_at", [
+    (2048, jnp.bfloat16, "pallas", 2048), (1024, jnp.float32, "pallas", 1024),
+    (1024, jnp.bfloat16, "xla", 2048), (512, jnp.float32, "xla", 1024),
+    # nemotron3-train-8k's rows: 10.5 lines of 128 words move as 16
+    (2688, jnp.bfloat16, "pallas", 4096), (2688, jnp.float32, "pallas", 3072)])
 def test_compiled_movements_keep_xla_at_widths_the_row_dma_cannot_take(
-        width, dtype, takes, monkeypatch):
-    """Compiled, a row's group is whole tiles of eight lines: narrower widths
-    keep XLA's movements under ``impl="pallas"`` too (the grouped products
-    take any multiple of 128) instead of raising."""
+        width, dtype, takes, moves_at, monkeypatch):
+    """Compiled, a row's group is whole tiles of eight lines: a width between
+    two such moves at the next one, zeros in the columns added, where that is
+    under twice its own; narrower widths keep XLA's movements under
+    ``impl="pallas"`` too (the grouped products take them) instead of
+    raising."""
     monkeypatch.setattr(moe._backend, "interpret_mode", lambda: False)
     a = jax.ShapeDtypeStruct((256, width), dtype)
+    assert moe._rows_width(a) == moves_at
     assert moe._rows_impl("pallas", a) == takes
     assert moe._rows_impl("xla", a) == "xla"
+
+
+def test_rows_of_a_width_between_two_the_kernels_take_move_widened(top_k=6, held=(0, 8)):
+    """bf16 rows of 384 (one and a half lines of packed words, as 2,688 is
+    10.5 compiled): the movements run on the kernels at 512 with zeros in the
+    added columns, forward and every gradient, and nothing of the padding
+    reaches a result."""
+    p, x = _movement_operands(256, 384, held, 64, jnp.bfloat16)
+    assert moe._rows_width(x) == 512 and moe._rows_impl("pallas", x) == "pallas"
+    names = lambda impl: str(jax.make_jaxpr(  # noqa: E731
+        lambda p, x: _layer_and_grads(impl, p, x, top_k, held)[0])(p, x))
+    assert "moe_rows_gather" in names("pallas") and "moe_rows_combine" in names("pallas")
+    assert "moe_rows" not in names("xla")
+    want = _layer_and_grads("xla", p, x, top_k, held)
+    got = _layer_and_grads("pallas", p, x, top_k, held)
+    np.testing.assert_array_equal(got[1]["expert_load"], want[1]["expert_load"])
+    assert got[0].shape == want[0].shape == (256, 384) and got[3].shape == (256, 384)
+    f32 = lambda a: np.asarray(a, np.float32)  # noqa: E731
+    close = lambda a, b, name: np.testing.assert_allclose(  # noqa: E731
+        f32(a), f32(b), err_msg=name, atol=2e-2 * float(np.max(np.abs(f32(b)))) + 1e-9)
+    close(got[0], want[0], "y")
+    close(got[3], want[3], "dx")
+    for name in want[2]:
+        close(got[2][name], want[2][name], name)
+
+
+@pytest.mark.parametrize("K,N", [(256, 1856), (1856, 256)])
+def test_grouped_products_take_a_width_of_fourteen_and_a_half_lane_tiles(K, N):
+    """1,856 = 14.5 x 128 as the output width and as the contracted one: all
+    three ``moe_gmm*`` kernels at the width itself (whole-matrix blocks, no
+    padding in HBM), no result column lost, none of ``dw`` left at zero."""
+    from apex_tpu.ops.pallas import grouped_matmul as gk
+    M, E = 4 * gk.TM, 3
+    k = jax.random.split(jax.random.PRNGKey(2), 3)
+    x, dy = jax.random.normal(k[0], (M, K)), jax.random.normal(k[1], (M, N))
+    w = jax.random.normal(k[2], (E, K, N))
+    tile_expert, n_used = jnp.array([0, 0, 2, 2], jnp.int32), jnp.array([3], jnp.int32)
+    assert moe._gmm_shapes_ok(x, w) and moe._gmm_shapes_ok(dy, jnp.swapaxes(w, 1, 2))
+    assert not moe._gmm_shapes_ok(x[:, :200], w[:, :200])
+    used = (jnp.arange(4) < 3)[:, None, None]
+    with jax.default_matmul_precision("highest"):
+        out = gk.moe_gmm(x, w, tile_expert, n_used, interpret=True)
+        dx = gk.moe_gmm_dx(dy, w, tile_expert, n_used, interpret=True)
+        dw = gk.moe_gmm_dw(x, dy, tile_expert, n_used, E, interpret=True)
+        xt, dyt = x.reshape(4, gk.TM, K), jnp.where(used, dy.reshape(4, gk.TM, N), 0)
+        want = jnp.where(used, jnp.einsum("tmk,tkn->tmn", xt, w[tile_expert]), 0)
+        want_dx = jnp.einsum("tmn,tkn->tmk", dyt, w[tile_expert])
+        want_dw = jax.ops.segment_sum(jnp.einsum("tmk,tmn->tkn", xt, dyt), tile_expert,
+                                      num_segments=E)
+    assert out.shape == (M, N) and dx.shape == (M, K) and dw.shape == (E, K, N)
+    np.testing.assert_allclose(out, want.reshape(M, N), atol=1e-3)
+    np.testing.assert_allclose(dx, want_dx.reshape(M, K), atol=1e-3)
+    np.testing.assert_allclose(dw, want_dw, atol=1e-3)
+    assert float(jnp.min(jnp.max(jnp.abs(out[:gk.TM]), axis=0))) > 0.1   # every result column
+    assert float(jnp.min(jnp.max(jnp.abs(dw[0]), axis=0))) > 0.1         # every column of dw
+    assert float(jnp.max(jnp.abs(dw[1]))) == 0.0                         # an expert with no tile
+
+
+RELU2 = {"router_num_experts": E, "num_experts_per_tok": K, "route_norm": True,
+         "route_scale": 2.5, "experts_held": (0, E)}
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("F2", [128, 192])       # whole lane tiles, and one and a half
+def test_relu2_experts_match_the_reference_and_every_gradient(impl, F2):
+    """Ungated experts with ONE up matrix, ``relu(x W_up)^2 W_down``, the
+    shared expert the same form: value, loads and the gradient of every leaf
+    (the up matrices' every column among them) against ``ssm_ref``."""
+    from benchmarks.reference import ssm_ref as S
+    k = iter(jax.random.split(jax.random.PRNGKey(21), 8))
+    n = lambda *s: 0.05 * jax.random.normal(next(k), s)  # noqa: E731
+    w = {"router": n(H, E), "w_up": n(E, H, F2), "w_down": n(E, F2, H), "shared_up": n(H, F),
+         "shared_down": n(F, H)}
+    x, bias = jax.random.normal(next(k), (2, 96, H)), 0.05 * jax.random.normal(next(k), (E,))
+    ct = jax.random.normal(next(k), (192, H))
+    held = (4, 8)
+    cut = lambda w: dict(w, w_up=w["w_up"][4:12], w_down=w["w_down"][4:12])  # noqa: E731
+
+    def layer(w, x):
+        y, aux = moe.dropless_moe_layer(
+            cut(w), x, top_k=K, experts_held=held, impl=impl, score="sigmoid", route_scale=2.5,
+            router_bias=bias, shared_gate=False, activation="relu2")
+        return jnp.sum(y.reshape(-1, H) * ct), aux
+
+    def ref(w, x):
+        m = x.reshape(-1, H)
+        y, counts = S.expert_layer(cut(w), bias, RELU2, m, "float32", held=held)
+        return jnp.sum((y + S.shared_expert(w, m, "float32")) * ct), counts
+
+    with jax.default_matmul_precision("highest"):
+        (got, aux), g = jax.value_and_grad(layer, argnums=(0, 1), has_aux=True)(w, x)
+        (want, counts), g_want = jax.value_and_grad(ref, argnums=(0, 1), has_aux=True)(w, x)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    np.testing.assert_array_equal(aux["router_counts"], counts)
+    assert int(aux["dropped"]) == 0
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(g)[0], jax.tree.leaves(g_want)):
+        np.testing.assert_allclose(a, b, atol=2e-5 * float(jnp.max(jnp.abs(b))),
+                                   err_msg=jax.tree_util.keystr(path))
+    dw_up = g[0]["w_up"][4:12]
+    assert float(jnp.min(jnp.max(jnp.abs(dw_up), axis=(0, 1)))) > 0       # every column written
+    with pytest.raises(ValueError, match="activation"):
+        moe.dropless_moe_layer(cut(w), x, top_k=K, experts_held=held, activation="gelu")
+    with pytest.raises(KeyError):            # a SwiGLU layer's leaves are not these
+        moe.dropless_moe_layer(cut(w), x, top_k=K, experts_held=held)

@@ -22,8 +22,8 @@ PALLAS_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file
 KERNEL_FILES = sorted(os.path.basename(p) for p in glob.glob(os.path.join(PALLAS_DIR, "*.py"))
                       if os.path.basename(p) != "__init__.py")
 # readers match by prefix: flash_fwd*, flash_bwd*, xentropy*, gdn_fwd*, gdn_bwd*,
-# moe_gmm*, moe_rows*; the rest by name (conv_silu_*, gated_norm_*: the stages around the
-# rule, which no reader's part may match)
+# ssd_fwd*, ssd_bwd*, moe_gmm*, moe_rows*; the rest by name (conv_silu_*, gated_norm_*: the
+# stages around the rule and the scan, which no reader's part may match)
 EXPECTED = {
     "attention.py": {
         "flash_fwd", "flash_fwd_packed", "flash_fwd_bshd",
@@ -46,6 +46,8 @@ EXPECTED = {
     "sampling.py": {"fused_sample"},
     "verify.py": {"fused_verify", "fused_verify_tree"},
     "gated_delta_rule.py": {"gdn_fwd", "gdn_bwd"},
+    # the Mamba-2 state-space scan: ssd_*, which no older reader's part matches
+    "ssd.py": {"ssd_fwd", "ssd_bwd"},
     "delta_mixer.py": {"conv_silu_fwd", "conv_silu_bwd", "gated_norm_fwd", "gated_norm_bwd"},
     "grouped_matmul.py": {"moe_gmm", "moe_gmm_dx", "moe_gmm_dw"},
     # the movements between tokens and expert rows: moe_rows*, never moe_gmm*
@@ -90,7 +92,7 @@ def test_every_pallas_call_has_a_literal_name(filename):
 def test_kernel_names_are_distinct_across_the_package():
     assert set(KERNEL_FILES) == set(EXPECTED)
     names = [n for f in KERNEL_FILES for n in literal_names(f)]
-    assert len(names) == 44 and len(set(names)) == 44
+    assert len(names) == 46 and len(set(names)) == 46
 
 
 def all_eqns(jaxpr):
@@ -247,6 +249,29 @@ def test_delta_mixer_equations_carry_their_names():
         + ["conv_silu_bwd"] * 3)
     for name in EXPECTED["delta_mixer.py"]:
         assert not any(part in name for part in ("flash", "xentropy", "gdn", "moe_gmm"))
+
+
+def test_state_space_mixer_equations_carry_their_names():
+    """One state-space mixer, forward and gradient: the convolution (with its
+    bias) a call a piece (x, B, C), the scan, the gated norm — and the scan's
+    names hold no part an older reader matches, nor do the older kernels'
+    names hold ``ssd_`` (``ssd_fwd_ms`` / ``ssd_bwd_ms`` mean the scan alone)."""
+    from apex_tpu.models import HybridDecoderConfig, HybridDecoderModel
+
+    model = HybridDecoderModel(HybridDecoderConfig(
+        vocab_size=64, hidden_size=128, layer_types=("ssm",), ssm_heads=4, ssm_head_dim=64,
+        ssm_state=128, ssm_groups=2, delta_impl="pallas"))
+    p = jax.tree.map(lambda a: a[0], model.init(jax.random.PRNGKey(0))["layers"]["ssm"])
+    x = jnp.ones((1, 128, 128), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, x: model._ssm_mixer(p, x).sum(), argnums=(0, 1)))(p, x)
+    assert kernel_names(jaxpr.jaxpr) == (
+        ["conv_silu_fwd"] * 3 + ["ssd_fwd", "gated_norm_fwd", "gated_norm_bwd", "ssd_bwd"]
+        + ["conv_silu_bwd"] * 3)
+    for name in EXPECTED["ssd.py"]:
+        assert not any(part in name for part in ("flash", "xentropy", "gdn", "moe_gmm", "moe_rows",
+                                                 "conv_silu", "gated_norm"))
+    others = set().union(*(names for f, names in EXPECTED.items() if f != "ssd.py"))
+    assert not any("ssd_" in name for name in others)
 
 
 SPLIT = ["flash_bwd_packed_dq", "flash_bwd_packed_dkv"]

@@ -20,6 +20,7 @@ resolves them to XLA on measured grounds, PERF.md).
 """
 
 import dataclasses
+import functools
 import os
 import sys
 import time
@@ -441,6 +442,87 @@ def _delta_mixer_stages_family():
     return build
 
 
+def _timed(fn, *args, calls=5):
+    """(milliseconds a call of jitted ``fn`` by the host clock over ``calls``
+    calls, its result)."""
+    fn = jax.jit(fn)
+    out = jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return 1e3 * (time.perf_counter() - t0) / calls, out
+
+
+def _ssd_operands(t=8192):
+    """``nemotron3-train-8k``'s scan: 2 rows of 8,192, 64 heads of 64 in 8
+    groups, a state of 128 rows, ``dt`` and ``A`` as the model starts them."""
+    h, p, g, n = 64, 64, 8, 128
+    x = jr.normal(_key(80), (B, t, h, p), jnp.bfloat16)
+    dt = jax.nn.softplus(jr.normal(_key(81), (B, t, h)) + jnp.log(jnp.expm1(
+        jnp.exp(jr.uniform(_key(82), (h,), minval=jnp.log(1e-3), maxval=jnp.log(1e-1))))))
+    A = -jr.uniform(_key(83), (h,), minval=1.0, maxval=16.0)
+    Bm, Cm = (jr.normal(_key(i), (B, t, g, n), jnp.bfloat16) for i in (84, 85))
+    D = 1.0 + 0.1 * jr.normal(_key(86), (h,))
+    return x, dt, A, Bm, Cm, D
+
+
+def _ssd_family():
+    """``ssd_fwd`` / ``ssd_bwd`` (the chunked Mamba-2 scan, states in VMEM)
+    against the XLA form (the same chunked mathematics as einsums and a
+    ``lax.scan`` over the chunks) at the cell's shape, value and all six
+    gradients (``dA``, ``dD`` and ``ddt`` among them)."""
+    def build():
+        from apex_tpu.ops.ssd import ssd_scan
+
+        def make(impl):
+            return _fwd_and_grads(lambda *a: ssd_scan(*a, impl=impl), (0, 1, 2, 3, 4, 5))
+        return make("pallas"), make("xla"), _ssd_operands()
+    return build
+
+
+def _ssd_times():
+    def report():
+        from apex_tpu.ops.ssd import ssd_scan
+        args = _ssd_operands()
+        lines = []
+        for impl in ("pallas", "xla"):
+            fwd = lambda *a: ssd_scan(*a, impl=impl)  # noqa: E731
+            both = _fwd_and_grads(fwd, (0, 1, 2, 3, 4, 5))
+            lines.append(f"ssd {impl}: forward {_timed(fwd, *args)[0]:.2f} ms, forward + backward "
+                         f"{_timed(both, *args)[0]:.2f} ms a layer (2 x 8,192 tokens)")
+        return lines
+    return report
+
+
+def _ssm_mixer_stages_family():
+    """The two stages around the scan at ``nemotron3-train-8k``'s shape:
+    ``conv_silu_*`` WITH a bias over ``x|B|C`` and ``gated_norm_*`` gating
+    first over groups of 512 with a weight a channel, both reading the fused
+    projection (2, 8192, 10304: ``xBC | z | dt``) in place, against the XLA
+    compositions — values and the gradients of the projection, the taps, the
+    bias, ``y`` and the norm's weight."""
+    def build():
+        from apex_tpu.ops.gated_delta_rule import causal_conv_silu, gated_rms_norm
+        t, inner, bc = 8192, 4096, 1024
+        conv = inner + 2 * bc
+        proj = jr.normal(_key(90), (B, t, conv + inner + 64), jnp.bfloat16)
+        taps = jr.uniform(_key(91), (4, conv), minval=-0.5, maxval=0.5).astype(jnp.bfloat16)
+        bias = (0.02 * jr.normal(_key(92), (conv,))).astype(jnp.bfloat16)
+        y = jr.normal(_key(93), (B, t, 8, inner // 8), jnp.bfloat16)
+        weight = (1.0 + 0.1 * jr.normal(_key(94), (8, inner // 8))).astype(jnp.bfloat16)
+
+        def make(impl):
+            def stages(proj, taps, bias, y, weight):
+                xbc = causal_conv_silu(proj, taps, bias, widths=(inner, bc, bc), impl=impl)
+                n = gated_rms_norm(y, proj, weight, 1e-5, gate_first=True, gate_start=conv,
+                                   impl=impl)
+                return jnp.concatenate(xbc + (n.reshape(B, t, inner),), axis=-1)
+            return _fwd_and_grads(stages, (0, 1, 2, 3, 4))
+        return make("pallas"), make("xla"), (proj, taps, bias, y, weight)
+    return build
+
+
 def _delta_rule_drifted_family():
     """The kernels (``gdn_fwd`` / ``gdn_bwd``: operands, inverse and
     recurrence in VMEM) in
@@ -498,32 +580,49 @@ def _dropless_family():
     return build
 
 
-CELL_TOKENS, CELL_HIDDEN = 16384, 2048   # 2 rows of 8,192; every expert cell's width
-# top-k, experts held, router width, F: trinity-, dsv2lite- and q3next-train-8k
+CELL_TOKENS, CELL_HIDDEN = 16384, 2048   # 2 rows of 8,192; the first three expert cells' width
+# top-k, experts held, router width, F: trinity-, dsv2lite-, q3next- and nemotron3-train-8k
 EXPERT_CELLS = {"trinity": (8, 16, 128, 1024), "dsv2lite": (6, 8, 64, 1408),
-                "q3next": (10, 32, 512, 512)}
+                "q3next": (10, 32, 512, 512), "nemotron3": (6, 8, 128, 1856),
+                # no cell's: a second width of whole HALF lane tiles (7.5) and a second
+                # row the DMA cannot take (3,072 moves at 4,096), which the rules let through
+                "halftile": (6, 8, 128, 960)}
+# experts that are not SwiGLU at 2,048: (hidden, activation)
+EXPERT_FORMS = {"nemotron3": (2688, "relu2"), "halftile": (3072, "relu2")}
+NO_CELL = {"halftile"}
+
+
+def _expert_form(cell):
+    return EXPERT_FORMS.get(cell, (CELL_HIDDEN, "silu_gate"))
 
 
 def _expert_cell_operands(cell):
     k, held, width, F = EXPERT_CELLS[cell]
+    hidden, activation = _expert_form(cell)
     n = lambda i, *shape: (0.05 * jr.normal(_key(i), shape)).astype(jnp.bfloat16)  # noqa: E731
-    p = {"router": n(60, CELL_HIDDEN, width), "w_gate_up": n(61, held, CELL_HIDDEN, 2 * F),
-         "w_down": n(62, held, F, CELL_HIDDEN), "shared_gate_up": n(63, CELL_HIDDEN, 2 * F),
-         "shared_down": n(64, F, CELL_HIDDEN), "shared_mix": n(65, CELL_HIDDEN)}
-    return p, jr.normal(_key(66), (CELL_TOKENS, CELL_HIDDEN), jnp.bfloat16)
+    p = {"router": n(60, hidden, width), "w_down": n(62, held, F, hidden),
+         "shared_down": n(64, F, hidden), "shared_mix": n(65, hidden)}
+    if activation == "relu2":                   # one up matrix, no gate
+        p.update(w_up=n(61, held, hidden, F), shared_up=n(63, hidden, F))
+    else:
+        p.update(w_gate_up=n(61, held, hidden, 2 * F), shared_gate_up=n(63, hidden, 2 * F))
+    return p, jr.normal(_key(66), (CELL_TOKENS, hidden), jnp.bfloat16)
 
 
 def _dropless_cell_family(cell):
     """The dropless expert layer at a cell's (k, held, width, F), 16,384
-    tokens of 2,048: the row movements (``moe_rows_*``) and the grouped
-    products against the XLA composition, forward and every gradient."""
+    tokens of its hidden size: the row movements (``moe_rows_*`` where the
+    width lets them) and the grouped products against the XLA composition,
+    forward and every gradient."""
     def build():
         from apex_tpu.transformer.moe import dropless_moe_layer
         k, held = EXPERT_CELLS[cell][:2]
+        activation = _expert_form(cell)[1]
 
         def make(impl):
             return _fwd_and_grads(lambda p, x: dropless_moe_layer(
-                p, x, top_k=k, experts_held=(0, held), impl=impl)[0], (0, 1))
+                p, x, top_k=k, experts_held=(0, held), impl=impl,
+                activation=activation)[0], (0, 1))
         return make("pallas"), make("xla"), _expert_cell_operands(cell)
     return build
 
@@ -546,18 +645,10 @@ def _dropless_movement_times(cell, calls=20):
         planned = jax.jit(planned)
         top_p, plan = planned(x, p["router"])
         move = jax.jit(lambda plan: moe._block_move(plan, 0, rows))(plan)
-        y = jr.normal(_key(67), (rows, CELL_HIDDEN), jnp.bfloat16)
-        g = jr.normal(_key(68), (CELL_TOKENS, CELL_HIDDEN), jnp.bfloat16)
+        y = jr.normal(_key(67), (rows, x.shape[-1]), jnp.bfloat16)
+        g = jr.normal(_key(68), x.shape, jnp.bfloat16)
 
-        def ms(fn, *args):
-            fn = jax.jit(fn)
-            out = jax.block_until_ready(fn(*args))
-            t0 = time.perf_counter()
-            for _ in range(calls):
-                out = fn(*args)
-            jax.block_until_ready(out)
-            return 1e3 * (time.perf_counter() - t0) / calls, out
-
+        ms = functools.partial(_timed, calls=calls)
         lines = [f"{cell}: block of {rows} rows, {int(plan['n_used'])} tiles in use, "
                  f"{int(plan['row_valid'].sum())} assignments; route + plan "
                  f"{ms(planned, x, p['router'])[0]:.3f} ms"]
@@ -673,8 +764,13 @@ FAMILIES = (
     Family("gated delta rule on drifted keys, against the recurrence",
            _delta_rule_drifted_family()),
     Family("delta mixer stages conv_silu/gated_norm fwd/bwd", _delta_mixer_stages_family()),
+    Family("ssd scan ssd_fwd/ssd_bwd at nemotron3-train-8k's 64 heads of 64, N 128, 8 groups",
+           _ssd_family(), timings=_ssd_times()),
+    Family("ssm mixer stages conv_silu + bias / gated_norm gate-first 512 fwd/bwd",
+           _ssm_mixer_stages_family()),
     Family("dropless experts moe_gmm/dx/dw", _dropless_family()),
-    *(Family(f"dropless experts at {cell}-train-8k's top {k} onto {held} of {width}, F {F}: "
+    *(Family(f"dropless experts at {cell}{'' if cell in NO_CELL else '-train-8k'}'s "
+             f"top {k} onto {held} of {width}, F {F}: "
              f"moe_rows_gather/combine + moe_gmm", _dropless_cell_family(cell),
              timings=_dropless_movement_times(cell))
       for cell, (k, held, width, F) in EXPERT_CELLS.items()),
