@@ -38,6 +38,7 @@ from apex_tpu.monitor import spans as monitor_spans
 from apex_tpu.ops import _backend
 from apex_tpu.ops.pallas import expert_rows as rk
 from apex_tpu.ops.pallas import grouped_matmul as gk
+from apex_tpu.ops.pallas import top_rounds as tr
 
 
 # the router aux schema — THE definition every consumer zero-initializes
@@ -423,21 +424,52 @@ def moe_layer(
 # The capacity layer above pads or drops to a fixed (E, C) grid. The layer
 # below drops nothing: every (token, expert) assignment whose expert is held
 # here is computed, however uneven the routing. Static shapes come from
-# sorting the assignments by expert into a row buffer in which each expert's
-# rows start on a tile boundary (``ops/pallas/grouped_matmul``). The rows are
-# computed a block at a time (``dropless_block_rows``: as many rows as there
-# are tokens where a rank holds a small share of the experts), as many
+# counting the assignments by expert into a row buffer in which each expert's
+# rows start on a tile boundary (``ops/pallas/grouped_matmul``): a row's place
+# is its expert's first row plus the earlier assignments to that expert, so
+# nothing is sorted (this compiler lowers ``top_k`` and ``argsort`` alike to
+# full stable sorts, and a gather or a scatter costs it some 5 ns an element:
+# the plan keeps two scatters of the T k assignments and no gather). The rows
+# are computed a block at a time (``dropless_block_rows``: as many rows as
+# there are tokens where a rank holds a small share of the experts), as many
 # blocks as the routing fills. A block is a unit of memory, not of cost: the
 # movements between tokens and rows (``ops/pallas/expert_rows``) and the
 # grouped products stop at the tiles in use.
 
+@jax.custom_vjp
+def _scores_at(p, ids, lane):
+    """``p`` (T, E) at ``ids`` (T, k), ``lane = arange(E)``: a select over
+    (T, k, E) and its sum, forward and (onto (T, E)) backward, from the ids
+    alone. XLA's gather of T k scalars takes many times as long here, and the
+    scatter-add that transposes it compiles to a sort of the T k indices."""
+    return jnp.sum(jnp.where(ids[..., None] == lane, p[:, None, :], 0), axis=-1)
+
+
+def _scores_at_fwd(p, ids, lane):
+    return _scores_at(p, ids, lane), (ids, lane)
+
+
+def _scores_at_bwd(res, g):
+    ids, lane = res
+    return jnp.sum(jnp.where(ids[..., None] == lane, g[..., None], 0), axis=1), _f0(ids), _f0(lane)
+
+
+_scores_at.defvjp(_scores_at_fwd, _scores_at_bwd)
+
+
 def route_topk(x, router, k, *, normalize=True, score="softmax", bias=None, scale=1.0,
-               sequences=None):
+               sequences=None, impl="auto"):
     """Router at its full width: ``p = softmax_fp32(x @ router)``, the top
     ``k`` (ids (T, k) int32, weights (T, k) float32, renormalised to sum 1
     when ``normalize``), the Switch load-balance term ``E sum_e f_e P_e``
     (``f``: share of the T k assignments, ``P``: mean probability) and the
     assignments to every expert (E,) int32.
+
+    The top ``k`` are chosen by k rounds of a row maximum, the lowest index
+    among equals (``ops/pallas/top_rounds``: ``jax.lax.top_k``'s ids without
+    its sort; the kernel ``moe_top_rounds`` or, ``impl="xla"`` and off the
+    chip, the same rounds as XLA operations). The ids carry no gradient; the
+    weights are ``p`` at them (:func:`_scores_at`).
 
     ``score="sigmoid"``: every expert is scored alone, ``p = sigmoid``; the
     renormalisation guards an all-zero row (``+ 1e-20``) and the term is 0
@@ -453,18 +485,25 @@ def route_topk(x, router, k, *, normalize=True, score="softmax", bias=None, scal
     the batch-wise term above."""
     logits = jnp.dot(x, router.astype(x.dtype), preferred_element_type=jnp.float32)
     p = jax.nn.softmax(logits, axis=-1) if score == "softmax" else jax.nn.sigmoid(logits)
-    if bias is None:
-        top_p, top_e = jax.lax.top_k(p, k)
+    E = router.shape[-1]
+    lane = jnp.arange(E, dtype=jnp.int32)
+    chosen_by = jax.lax.stop_gradient(p)
+    ok = tr.shapes_ok(x.shape[0], E)
+    offset = jnp.zeros((E,), jnp.float32) if bias is None else bias.astype(jnp.float32)
+    if _backend.choose_impl(impl if ok else "xla", ok) == "pallas":
+        top_e = tr.moe_top_rounds(chosen_by.T, offset, k=k, interpret=_backend.interpret_mode()).T
     else:
-        _, top_e = jax.lax.top_k(p + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
-        top_p = jnp.take_along_axis(p, top_e, axis=-1)
+        top_e = tr.top_rounds(chosen_by + offset, k)
+    # part of the plan a jax.checkpoint policy may keep (``moe_plan``): the
+    # backward pass then reads the weights again and runs no round twice
+    top_e = checkpoint_name(top_e, "moe_plan")
+    top_p = _scores_at(p, top_e, lane)
     if normalize:
         total = jnp.sum(top_p, -1, keepdims=True)
         top_p = top_p / (total if score == "softmax" else total + 1e-20)
     if scale != 1.0:
         top_p = top_p * scale
-    E = router.shape[-1]
-    chosen = top_e[..., None] == jnp.arange(E, dtype=top_e.dtype)
+    chosen = top_e[..., None] == lane
     aux = jnp.float32(0.0)
     if sequences is None:
         counts = jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32)
@@ -478,7 +517,7 @@ def route_topk(x, router, k, *, normalize=True, score="softmax", bias=None, scal
         if score == "softmax":
             share = row_counts.astype(jnp.float32) / (x.shape[0] // sequences * k)
             aux = E * jnp.mean(jnp.sum(share * jnp.mean(by_row(p), axis=1), axis=-1))
-    return top_e.astype(jnp.int32), top_p, aux, counts
+    return top_e, top_p, aux, counts
 
 
 def router_bias_update(bias, counts, rate):
@@ -499,51 +538,63 @@ def dropless_plan(top_e, counts, experts_held, block_rows, tile):
     ``n_used`` () tiles in use, ``row_token``, ``row_assign``, ``row_valid``
     (rows,), ``pos`` (T, k) the row of each assignment, ``local`` (T, k);
     and, for the way back, the local assignments as they stand in token
-    order (one cumulative sum, no second sort): ``tile_rows`` (T / TT, L)
-    the rows that each tile of ``expert_rows.TT`` tokens sums, ``tile_count``
-    how many, ``rank`` (T, k) each assignment's place in its tile's list
-    (-1: not local; T rounded up to whole tiles in all three)."""
+    order: ``tile_rows`` (T / TT, L) the rows that each tile of
+    ``expert_rows.TT`` tokens sums, ``tile_count`` how many, ``rank`` (T, k)
+    each assignment's place in its tile's list (-1: not local; T rounded up
+    to whole tiles in all three).
+
+    The rows are those of a stable sort of the assignments by expert, made by
+    counting: an assignment's place among its expert's rows is how many
+    earlier assignments (in the order t k + j) chose that expert — the
+    earlier tokens' (a histogram a token, summed along the tokens) and the
+    same token's. Assignments to experts held elsewhere are never ordered:
+    their ``pos`` reads 0 under a ``local`` that is false, and a row that
+    ``row_valid`` excludes reads assignment 0."""
     first, count = experts_held
     T, k = top_e.shape
     N = T * k
     worst = T * min(k, count) + count * tile
     rows = -(-worst // block_rows) * block_rows
     local = (top_e >= first) & (top_e < first + count)
-    key = jnp.where(local, top_e - first, count).reshape(N)
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)   # sorted place -> assignment
-    place = jnp.zeros((N,), jnp.int32).at[order].set(jnp.arange(N, dtype=jnp.int32))
     held = jax.lax.dynamic_slice(counts, (first,), (count,))
-    start = jnp.cumsum(held) - held                           # first sorted place
     tiles_of = -(-held // tile)
     tile_end = jnp.cumsum(tiles_of)
     tile_start = tile_end - tiles_of
     n_used = tile_end[-1]
-    tile_expert = jnp.minimum(
-        jnp.searchsorted(tile_end, jnp.arange(rows // tile, dtype=jnp.int32), side="right"),
-        count - 1).astype(jnp.int32)
-    r = jnp.arange(rows, dtype=jnp.int32)
-    e = tile_expert[r // tile]
-    within = r - tile_start[e] * tile
-    row_valid = (r // tile < n_used) & (within < held[e])
-    row_assign = order[jnp.clip(start[e] + within, 0, N - 1)]
-    e_of = jnp.minimum(key, count - 1)
-    pos = (tile_start[e_of] * tile + place - start[e_of]).reshape(T, k)
+    # by tile, then spread over the tile's rows: a gather a row costs as much as the rows do
+    i = jnp.arange(-(-rows // tile), dtype=jnp.int32)      # a last tile cut short is never in use
+    tile_expert = jnp.minimum(jnp.sum(i[:, None] >= tile_end, axis=1, dtype=jnp.int32), count - 1)
+    filled = jnp.where(i < n_used,
+                       held[tile_expert] - (i - tile_start[tile_expert]) * tile, 0)
+    row_valid = (jnp.arange(tile, dtype=jnp.int32) < filled[:, None]).reshape(-1)[:rows]
+    tile_expert = tile_expert[:rows // tile]
+    key = jnp.where(local, top_e - first, count)              # count: held elsewhere
+    chose = key[..., None] == jnp.arange(count, dtype=key.dtype)            # (T, k, count)
+    per_token = jnp.sum(chose, axis=1, dtype=jnp.int32)
+    earlier = jnp.cumsum(per_token, axis=0) - per_token + tile_start * tile   # (T, count)
+    lower = jnp.tril(jnp.ones((k, k), bool), -1)               # [j, j']: j' before j
+    twice = jnp.sum((key[:, :, None] == key[:, None, :]) & lower, axis=-1, dtype=jnp.int32)
+    pos = jnp.where(local, jnp.sum(jnp.where(chose, earlier[:, None, :], 0), axis=-1) + twice, 0)
+    row_assign = jnp.zeros((rows,), jnp.int32).at[jnp.where(local, pos, rows).reshape(N)].set(
+        jnp.arange(N, dtype=jnp.int32), mode="drop", unique_indices=True)
+    # the token-ordered lists, counted the same way: the tile's earlier tokens, then the token's own
     token_tiles = -(-T // rk.TT)
-    by_tile = lambda a: jnp.pad(a, ((0, token_tiles * rk.TT - T), (0, 0))  # noqa: E731
-                                ).reshape(token_tiles, rk.TT * k)
-    listed = by_tile(local)
-    upto = jnp.cumsum(listed, axis=1, dtype=jnp.int32)
-    rank = jnp.where(listed, upto - 1, -1)
+    padded = lambda a: jnp.pad(a, ((0, token_tiles * rk.TT - T), (0, 0)))  # noqa: E731
+    listed = padded(local)
+    mine = jnp.sum(listed, axis=1, dtype=jnp.int32).reshape(token_tiles, rk.TT)
+    upto = jnp.cumsum(mine, axis=1)
+    rank = jnp.where(listed, (upto - mine).reshape(-1, 1)
+                     + jnp.sum(listed[:, None, :] & lower, axis=-1, dtype=jnp.int32), -1)
     length = rk.list_length(min(k, count))
-    slot = jnp.where(listed, jnp.arange(token_tiles, dtype=jnp.int32)[:, None] * length + rank,
-                     token_tiles * length)
+    tile_of = jnp.arange(token_tiles * rk.TT, dtype=jnp.int32)[:, None] // rk.TT
+    slot = jnp.where(listed, tile_of * length + rank, token_tiles * length)
     tile_rows = jnp.zeros((token_tiles * length,), jnp.int32).at[slot.reshape(-1)].set(
-        by_tile(pos).reshape(-1), mode="drop", unique_indices=True)
+        padded(pos).reshape(-1), mode="drop", unique_indices=True)
     return {"tile_expert": tile_expert, "n_used": n_used.astype(jnp.int32),
             "row_token": row_assign // k, "row_assign": row_assign,
             "row_valid": row_valid, "pos": pos, "local": local,
             "tile_rows": tile_rows.reshape(token_tiles, length), "tile_count": upto[:, -1],
-            "rank": rank.reshape(-1, k)}
+            "rank": rank}
 
 
 def _f0(a):
@@ -866,11 +917,11 @@ def dropless_moe_layer(params, x, *, top_k, experts_held=None,
     with monitor_spans.span("moe/route"):
         top_e, top_p, aux_loss, counts = route_topk(
             xt, params["router"], top_k, normalize=normalize_weights, score=score,
-            bias=router_bias, scale=route_scale,
+            bias=router_bias, scale=route_scale, impl=impl,
             sequences=max(1, T // lead[-1]) if sequence_balance and lead else None)
         rows = -(-dropless_block_rows(T, top_k, held[1], E) // gk.TM) * gk.TM
         # under jax.checkpoint a policy may keep the plan by this name, so
-        # that the backward pass does not sort again
+        # that the backward pass does not make it again
         plan = jax.tree.map(
             lambda a: checkpoint_name(jax.lax.stop_gradient(a), "moe_plan"),
             dropless_plan(top_e, counts, held, rows, gk.TM))

@@ -24,6 +24,8 @@ KERNEL_FILES = sorted(os.path.basename(p) for p in glob.glob(os.path.join(PALLAS
 # readers match by prefix: flash_fwd*, flash_bwd*, xentropy*, gdn_fwd*, gdn_bwd*,
 # ssd_fwd*, ssd_bwd*, moe_gmm*, moe_rows*; the rest by name (conv_silu_*, gated_norm_*: the
 # stages around the rule and the scan, which no reader's part may match)
+READER_PARTS = ("flash_fwd", "flash_bwd", "xentropy", "gdn_", "moe_gmm", "moe_rows", "conv_silu",
+                "gated_norm", "ssd_")
 EXPECTED = {
     "attention.py": {
         "flash_fwd", "flash_fwd_packed", "flash_fwd_bshd",
@@ -53,6 +55,8 @@ EXPECTED = {
     # the movements between tokens and expert rows: moe_rows*, never moe_gmm*
     "expert_rows.py": {"moe_rows_gather", "moe_rows_gather_dots", "moe_rows_pack",
                        "moe_rows_combine", "moe_rows_combine_weighted"},
+    # the router's top k by rounds: inside ``moe/route``, under no kernel reader's part
+    "top_rounds.py": {"moe_top_rounds"},
 }
 # the nine of the GPT step's: the trainer's own and the model's, out of the
 # program's one table of spans
@@ -92,7 +96,7 @@ def test_every_pallas_call_has_a_literal_name(filename):
 def test_kernel_names_are_distinct_across_the_package():
     assert set(KERNEL_FILES) == set(EXPECTED)
     names = [n for f in KERNEL_FILES for n in literal_names(f)]
-    assert len(names) == 46 and len(set(names)) == 46
+    assert len(names) == 47 and len(set(names)) == 47
 
 
 def all_eqns(jaxpr):
@@ -499,9 +503,10 @@ def test_expert_rows_move_by_kernels_that_no_moe_gmm_reader_matches():
     jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
     names = kernel_names(jaxpr)
     # the first block, then the loop over further blocks; backward the same
-    assert names[:5] == ["moe_rows_gather", "moe_gmm", "moe_gmm", "moe_rows_pack",
+    assert names[:6] == ["moe_top_rounds", "moe_rows_gather", "moe_gmm", "moe_gmm", "moe_rows_pack",
                          "moe_rows_combine_weighted"]
-    assert set(names) == EXPECTED["expert_rows.py"] | EXPECTED["grouped_matmul.py"]
+    assert set(names) == (EXPECTED["expert_rows.py"] | EXPECTED["grouped_matmul.py"]
+                          | EXPECTED["top_rounds.py"])
     for name in EXPECTED["expert_rows.py"]:
         assert "moe_rows" in name and "moe_gmm" not in name
     (_, x, top_k, held), hidden = _expert_layer_operands(), 128
@@ -563,3 +568,45 @@ def test_the_xla_composition_is_the_parents_bit_for_bit(monkeypatch):
     for got, want in zip(jax.tree.leaves((y, gp, gx)), jax.tree.leaves((y0, gp0, gx0))):
         assert got.dtype == want.dtype and bool(jnp.all(got == want))
     assert float(jnp.max(jnp.abs(gp0["w_down"]))) > 0 and float(jnp.max(jnp.abs(gp0["router"]))) > 0
+
+
+# --- the dropless expert layer's routing: no sort ------------------------------
+
+@pytest.mark.parametrize("recomputed", [False, True])
+@pytest.mark.parametrize("router", ["softmax", "sigmoid under a bias"])
+def test_expert_layer_lowers_without_a_sort(router, recomputed):
+    """The layer's forward and backward hold no ``sort`` and no ``top_k``
+    operation (this compiler makes a full stable sort of either), as the
+    stack runs it: plain, and under ``jax.checkpoint`` keeping ``EXPERTS_SAVED``."""
+    from apex_tpu.models.hybrid_decoder import EXPERTS_SAVED
+    from apex_tpu.transformer import moe
+    p, x, top_k, held = _expert_layer_operands()
+    kw = {} if router == "softmax" else dict(
+        score="sigmoid", router_bias=jnp.linspace(-0.1, 0.1, p["router"].shape[-1]))
+
+    def layer(p, x):
+        y, aux = moe.dropless_moe_layer(p, x, top_k=top_k, experts_held=held, **kw)
+        return jnp.sum(y) + aux["load_balance_loss"]
+    if recomputed:
+        layer = jax.checkpoint(
+            layer, policy=jax.checkpoint_policies.save_only_these_names(*EXPERTS_SAVED))
+    text = jax.jit(jax.value_and_grad(layer, argnums=(0, 1))).lower(p, x).as_text()
+    operations = set(re.findall(r"\b(?:stablehlo|chlo|mhlo)\.(\w+)", text))
+    assert {"reduce", "scatter", "while"} <= operations          # the text names its operations so
+    assert not {o for o in operations if "sort" in o or "top" in o}
+    assert "TopK" not in text and "top_k" not in text
+
+
+def test_the_rounds_kernel_stands_in_the_route_span_under_no_readers_part():
+    """``moe_top_rounds`` is traced inside ``moe/route`` (``moe_route_ms``
+    counts it) and once for the layers that share its shapes; its name holds
+    no reader's part and is held by none."""
+    from apex_tpu.transformer import moe
+    (name,) = EXPECTED["top_rounds.py"]
+    assert not any(part in name or name in part for part in READER_PARTS)
+    fn, args = _expert_layer_grads("pallas")
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    calls = [line for line in text.splitlines() if "moe_top_rounds" in line and "moe/route" in line]
+    assert calls
+    names = kernel_names(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert names.count(name) == 1                  # the backward pass runs no round

@@ -627,31 +627,40 @@ def _dropless_cell_family(cell):
     return build
 
 
+def _route_and_plan(cell, calls=20):
+    """The routing of a cell's router at initialisation over 16,384 tokens of
+    its hidden size, and what each half takes alone (host clock, ``calls``
+    calls): ``route`` the scores, the top k and the counts
+    (``moe.route_topk``), ``plan`` the rows from them (``moe.dropless_plan``).
+    Returns ``(the tokens, rows a block, top_p, plan, the two times' text)``."""
+    from apex_tpu.ops.pallas import grouped_matmul as gk
+    from apex_tpu.transformer import moe
+    k, held, width, _ = EXPERT_CELLS[cell]
+    p, x = _expert_cell_operands(cell)
+    rows = moe.dropless_block_rows(CELL_TOKENS, k, held, width)
+    route_ms, (top_e, top_p, _, counts) = _timed(
+        lambda x, router: moe.route_topk(x, router, k), x, p["router"], calls=calls)
+    plan_ms, plan = _timed(
+        lambda top_e, counts: moe.dropless_plan(top_e, counts, (0, held), rows, gk.TM),
+        top_e, counts, calls=calls)
+    return x, rows, top_p, plan, f"route {route_ms:.3f} ms, plan {plan_ms:.3f} ms"
+
+
 def _dropless_movement_times(cell, calls=20):
     """Each of the four movements between tokens and rows alone, at a cell's
     shapes and a routing of its router at initialisation: XLA's gathers
     against the ``moe_rows_*`` kernels (host clock, ``calls`` calls), and the
-    plan that feeds them."""
+    routing and the plan that feed them, each alone."""
     def report():
-        from apex_tpu.ops.pallas import grouped_matmul as gk
         from apex_tpu.transformer import moe
-        k, held, width, _ = EXPERT_CELLS[cell]
-        p, x = _expert_cell_operands(cell)
-        rows = moe.dropless_block_rows(CELL_TOKENS, k, held, width)
-
-        def planned(x, router):
-            top_e, top_p, _, counts = moe.route_topk(x, router, k)
-            return top_p, moe.dropless_plan(top_e, counts, (0, held), rows, gk.TM)
-        planned = jax.jit(planned)
-        top_p, plan = planned(x, p["router"])
+        x, rows, top_p, plan, took = _route_and_plan(cell, calls)
         move = jax.jit(lambda plan: moe._block_move(plan, 0, rows))(plan)
         y = jr.normal(_key(67), (rows, x.shape[-1]), jnp.bfloat16)
         g = jr.normal(_key(68), x.shape, jnp.bfloat16)
 
         ms = functools.partial(_timed, calls=calls)
         lines = [f"{cell}: block of {rows} rows, {int(plan['n_used'])} tiles in use, "
-                 f"{int(plan['row_valid'].sum())} assignments; route + plan "
-                 f"{ms(planned, x, p['router'])[0]:.3f} ms"]
+                 f"{int(plan['row_valid'].sum())} assignments; {took}"]
         movements = {
             "rows <- tokens": lambda impl: (lambda x: moe._rows_from_tokens(x, move, impl), (x,)),
             "its cotangent (tokens <- rows, weight 1)": lambda impl: (
