@@ -62,6 +62,21 @@ its per-head norms (``qk_norm=False``) or rotary (``rotary_dim=0``).
   :func:`transformer.moe.router_bias_update` moves it
   (:meth:`HybridDecoderModel.init_router_bias` starts it).
 
+``loop_trips`` > 1 makes the stack a looped one: the same layers are walked
+that many times on the same weights (:meth:`HybridDecoderModel.walk`), the
+final norm closes every walk and its output opens the next, and every walk's
+output leaves through the one head. ``params["exit_gate"]`` (a Linear(hidden,
+1) with bias, shared by the walks) gives every token an exit distribution
+``p^t = g^t prod_{j<t} (1 - g^j)``, the last walk taking what is left, and
+``loss_fn`` is the mean over tokens of ``sum_t p^t l^t + exit_entropy_coeff
+sum_t p^t log p^t`` (the gate, ``p`` and the weighting in float32, span
+``hybrid/exit``; each exit's head and loss under ``hybrid/unembed_xent``, in a
+checkpoint of their own and ``EXIT_BLOCK`` tokens at a time, so no exit's
+logits are alive beside another's); the aux dict gains ``exit_mass``,
+``exit_losses`` (both (``loop_trips``,)) and ``exit_entropy``. With
+``loop_trips`` 1 (the default) there is no gate and the loss is the one
+cross-entropy it was.
+
 ``remat`` recomputes every block in the backward pass, a half at a time,
 except what the half's policy keeps by name (``MIXER_SAVED``,
 ``EXPERTS_SAVED``): a mixer half keeps the results of its kernels — the
@@ -73,7 +88,12 @@ no forward kernel runs twice — and the outputs of its input projections
 (``"mix_proj"``: q, gate, k, v of an attention layer, ``q|k|v|z`` and ``b|a``
 of a delta-rule layer, ``xBC|z|dt`` of a state-space layer); an expert half
 keeps its routing plan. Norms, rotary, the convolution, the gates and
-``w_o``'s operand are computed again.
+``w_o``'s operand are computed again. A looped stack holds ``loop_trips``
+passes of activations for every layer of state, so under ``remat`` it keeps
+the least of each (``LOOP_SAVED``): a block is recomputed whole — both halves,
+the kernels too — from its input and the routing plan, and every walk's
+closing norm from its input; which of the two rules holds follows from
+``loop_trips``, not from a setting.
 
 ``loss_fn`` has ``GPTModel.loss_fn``'s signature, so
 ``amp.scaled_value_and_grad`` and the trainers take either model.
@@ -112,6 +132,12 @@ MIXER_SCOPES = {"linear": "hybrid/gdn", "full": "hybrid/attn", "window": "hybrid
 # (``transformer.moe``).
 MIXER_SAVED = FLASH_SAVED + RULE_SAVED + SSD_SAVED + ("mix_proj",)
 EXPERTS_SAVED = ("moe_plan",)
+# A looped stack (``loop_trips`` > 1) holds ``loop_trips`` passes of activations
+# for every layer of state, so it keeps the least of each: a block is
+# recomputed whole, its kernels too, from its input alone and the routing plan.
+LOOP_SAVED = EXPERTS_SAVED
+# tokens whose logits a looped stack's exit computes at a time
+EXIT_BLOCK = 8192
 
 
 @dataclasses.dataclass(frozen=True)
@@ -176,6 +202,12 @@ class HybridDecoderConfig:
     zero_centered_norm: bool = True
     sandwich_norms: bool = False
     embed_scale: float = 1.0
+    # a looped stack: the same layers walked ``loop_trips`` times on the same
+    # weights, the final norm closing every walk and opening the next; every
+    # walk's output leaves through the head, weighted by a learned exit
+    # distribution whose entropy joins the loss at ``exit_entropy_coeff``
+    loop_trips: int = 1
+    exit_entropy_coeff: float = 0.0
     # recompute every block's two halves (mixer, experts) in the backward
     # pass, all but the results of their kernels, the mixers' input
     # projections and the routing plan (MIXER_SAVED, EXPERTS_SAVED)
@@ -204,6 +236,8 @@ class HybridDecoderConfig:
                              f"got {self.expert_activation!r}")
         if "ssm" in self.layer_types and self.ssm_heads % self.ssm_groups:
             raise ValueError("state-space heads must be a multiple of their groups")
+        if self.loop_trips < 1:
+            raise ValueError(f"loop_trips counts the walks of the stack, got {self.loop_trips}")
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError(f"router_score is 'softmax' or 'sigmoid', got {self.router_score!r}")
         if self.num_heads % self.num_kv_heads or self.linear_value_heads % self.linear_key_heads:
@@ -238,7 +272,7 @@ class HybridDecoderModel:
 
     def init(self, key):
         """Random parameters (normal 0.02; residual projections scaled by
-        1/sqrt(2 L); decay ``A ~ U(1, 16)``, ``dt ~ logU(1e-3, 1e-1)``)."""
+        1/sqrt(2 L ``loop_trips``); decay ``A ~ U(1, 16)``, ``dt ~ logU(1e-3, 1e-1)``)."""
         c = self.config
         H, L = c.hidden_size, len(c.layer_types)
         Lg, Ll, Ls = (c.layer_types.count(kind) for kind in ("linear", "latent", "ssm"))
@@ -248,7 +282,7 @@ class HybridDecoderModel:
         keys = iter(jax.random.split(key, 32))
         n = lambda shape, std=0.02: (std * jax.random.normal(  # noqa: E731
             next(keys), shape, jnp.float32)).astype(c.dtype)
-        res = 0.02 / (2 * L) ** 0.5
+        res = 0.02 / (2 * L * c.loop_trips) ** 0.5    # a layer adds to the stream every walk
         dt = jnp.exp(jax.random.uniform(next(keys), (Lg, c.linear_value_heads), jnp.float32,
                                         jnp.log(1e-3), jnp.log(1e-1)))
         a = jax.random.uniform(next(keys), (Lg, c.linear_value_heads), jnp.float32, 1.0, 16.0)
@@ -312,6 +346,8 @@ class HybridDecoderModel:
         }
         if Ls:                 # drawn last: the other groups keep the keys they had
             layers["ssm"] = self._init_ssm(Ls, n, keys, res)
+        if c.loop_trips > 1:   # one Linear(hidden, 1) with bias, shared by the walks
+            params["exit_gate"] = {"weight": n((H, 1)), "bias": zeros((1,))}
         return params
 
     def _init_ssm(self, Ls, n, keys, res):
@@ -488,30 +524,31 @@ class HybridDecoderModel:
         with monitor_spans.span("hybrid/dense"):
             return x + self._added(self._dense(p, self._norm(x, w)), post)
 
-    def _recomputed(self, half, saved=()):
-        """``half`` as the stack runs it. Under ``remat`` the backward pass
-        holds the half's arguments and the values named in ``saved`` and
-        computes the rest of it again."""
-        if not self.config.remat:
-            return half
+    def _recomputed(self, f, saved=(), block=False):
+        """A half of a block (or, ``block``, a whole block or a walk's closing
+        norm) as the stack runs it. Under ``remat`` the backward pass holds
+        its arguments and the values named in ``saved`` and computes the rest
+        of it again: a stack walked once a half at a time, a looped stack a
+        block at a time, so of the two the other is returned as it is."""
+        if not self.config.remat or block != (self.config.loop_trips > 1):
+            return f
         return jax.checkpoint(
-            half, policy=jax.checkpoint_policies.save_only_these_names(*saved))
+            f, policy=jax.checkpoint_policies.save_only_these_names(*saved))
 
     # --- the stack ------------------------------------------------------------
 
-    def hidden_states_with_aux(self, params, tokens, key=None, router_bias=None):
-        """(final hidden states, aux): ``load_balance_loss`` (mean over the
-        expert layers), ``expert_load`` (expert layers, held) and
-        ``router_counts`` (expert layers, router width) int32, ``dropped`` ().
-        ``router_bias`` (expert layers, router width): the routers' selection
-        bias, where the step carries one."""
-        del key                                    # no dropout in this block
+    def walk(self, params, x, router_bias=None):
+        """One walk of the stack from the stream ``x`` through the final norm:
+        (the normed output, aux): ``load_balance_loss`` (mean over the expert
+        layers), ``expert_load`` (expert layers, held) and ``router_counts``
+        (expert layers, router width) int32, ``dropped`` (). ``router_bias``
+        (expert layers, router width): the routers' selection bias, where the
+        step carries one. Under ``remat`` each half of a block is recomputed
+        by itself (``MIXER_SAVED``, ``EXPERTS_SAVED``); in a looped stack the
+        block is recomputed whole, from its input (``LOOP_SAVED``), and the
+        final norm from its."""
         c = self.config
         layers = params["layers"]
-        with monitor_spans.span("hybrid/embed"):
-            x = params["embedding"]["weight"][tokens]
-            if c.embed_scale != 1.0:
-                x = x * jnp.asarray(c.embed_scale, x.dtype)
         expert_half = self._recomputed(self._expert_half, EXPERTS_SAVED)
         dense_half = self._recomputed(self._dense_half)
         post1, post2 = layers.get("norm1_post"), layers.get("norm2_post")
@@ -527,25 +564,58 @@ class HybridDecoderModel:
         for i, (kind, ffn) in enumerate(zip(c.layer_types, c.ffn)):
             _, p_mix = take(GROUP_OF_KIND[kind])
             j, p_ffn = take(ffn) if ffn != "none" else (None, None)
-            x = self._mixer_half(kind)(p_mix, layers["norm1"][i],
-                                       None if post1 is None else post1[i], x)
-            if ffn == "none":
+
+            def block(p_mix, p_ffn, x):            # layer i, run before the loop moves on
+                x = self._mixer_half(kind)(p_mix, layers["norm1"][i],
+                                           None if post1 is None else post1[i], x)
+                if ffn == "none":
+                    return x, None
+                post = None if post2 is None else post2[second]
+                if ffn == "dense":
+                    return dense_half(p_ffn, layers["norm2"][second], post, x), None
+                bias = None if router_bias is None else router_bias[j]
+                return expert_half(p_ffn, layers["norm2"][second], post, bias, x)
+
+            x, aux = self._recomputed(block, LOOP_SAVED, block=True)(p_mix, p_ffn, x)
+            second += ffn != "none"
+            if aux is None:
                 continue
-            row, second = second, second + 1
-            post = None if post2 is None else post2[row]
-            if ffn == "dense":
-                x = dense_half(p_ffn, layers["norm2"][row], post, x)
-                continue
-            bias = None if router_bias is None else router_bias[j]
-            x, aux = expert_half(p_ffn, layers["norm2"][row], post, bias, x)
             lb = lb + aux["load_balance_loss"]
             loads.append(aux["expert_load"])
             counts.append(aux["router_counts"])
             dropped = dropped + aux["dropped"]
+        stacked = lambda rows, width: (jnp.stack(rows) if rows  # noqa: E731
+                                       else jnp.zeros((0, width), jnp.int32))
         aux = {"load_balance_loss": lb / max(len(loads), 1),
-               "expert_load": jnp.stack(loads), "router_counts": jnp.stack(counts),
-               "dropped": dropped}
-        return self._norm(x, params["norm_f"]), aux
+               "expert_load": stacked(loads, c.held[1]),
+               "router_counts": stacked(counts, c.router_experts), "dropped": dropped}
+        return self._recomputed(self._norm, block=True)(x, params["norm_f"]), aux
+
+    def trip_states(self, params, tokens, router_bias=None):
+        """(the normed output of each of the ``loop_trips`` walks, the output
+        of one opening the next: one entry where the stack is walked once;
+        :meth:`walk`'s aux, the balance term its mean over the walks and the
+        counters their sum)."""
+        c = self.config
+        with monitor_spans.span("hybrid/embed"):
+            x = params["embedding"]["weight"][tokens]
+            if c.embed_scale != 1.0:
+                x = x * jnp.asarray(c.embed_scale, x.dtype)
+        states, auxes = [], []
+        for _ in range(c.loop_trips):
+            x, aux = self.walk(params, x, router_bias)
+            states.append(x)
+            auxes.append(aux)
+        if len(auxes) > 1:
+            aux = jax.tree.map(lambda *a: sum(a), *auxes)
+            aux["load_balance_loss"] = aux["load_balance_loss"] / c.loop_trips
+        return states, aux
+
+    def hidden_states_with_aux(self, params, tokens, key=None, router_bias=None):
+        """(final hidden states, aux): the last of :meth:`trip_states`."""
+        del key                                    # no dropout in this block
+        states, aux = self.trip_states(params, tokens, router_bias)
+        return states[-1], aux
 
     def hidden_states(self, params, tokens, key=None):
         return self.hidden_states_with_aux(params, tokens, key)[0]
@@ -561,11 +631,67 @@ class HybridDecoderModel:
         """Mean next-token cross-entropy plus the load-balance term at
         ``aux_coeff``; ``return_aux=True`` also returns the aux dict (the
         load counters a training step hands back beside the loss, and the
-        ``router_counts`` that move a selection bias)."""
-        x, aux = self.hidden_states_with_aux(params, tokens, key, router_bias)
-        with monitor_spans.span("hybrid/unembed_xent"):
-            losses = tp_lib.vocab_parallel_cross_entropy(
-                self.unembed(params, x), targets, axis_name=None)
-            loss = tp_lib.masked_mean(losses, loss_mask)
+        ``router_counts`` that move a selection bias). A looped stack's loss
+        is :meth:`_exit_loss` over every walk's output."""
+        del key                                    # no dropout in this block
+        states, aux = self.trip_states(params, tokens, router_bias)
+        if len(states) > 1:
+            loss, exits = self._exit_loss(params, states, targets, loss_mask)
+            aux = dict(aux, **exits)
+        else:
+            with monitor_spans.span("hybrid/unembed_xent"):
+                losses = tp_lib.vocab_parallel_cross_entropy(
+                    self.unembed(params, states[0]), targets, axis_name=None)
+                loss = tp_lib.masked_mean(losses, loss_mask)
         loss = loss + self.config.aux_coeff * aux["load_balance_loss"]
         return (loss, aux) if return_aux else loss
+
+    @staticmethod
+    def exit_log_probs(gate, states):
+        """``log p^t`` (trips, ...) per token, float32, from the walks' outputs:
+        ``g^t = sigmoid(h^t . w + b)`` with the one ``gate`` (``weight``
+        (hidden, 1), ``bias`` (1,)), ``p^t = g^t prod_{j<t} (1 - g^j)``, the
+        last walk taking what is left (its own gate is never asked):
+        ``log p^t = log g^t + sum_{j<t} log (1 - g^j)``, by the log-sigmoids."""
+        z = jnp.stack([jnp.dot(x, gate["weight"], preferred_element_type=jnp.float32)[..., 0]
+                       for x in states[:-1]]) + gate["bias"].astype(jnp.float32)
+        stays = jnp.cumsum(jax.nn.log_sigmoid(-z), axis=0)
+        return jnp.concatenate([jax.nn.log_sigmoid(z[:1]),
+                                jax.nn.log_sigmoid(z[1:]) + stays[:-1], stays[-1:]])
+
+    def _exit_loss(self, params, states, targets, loss_mask=None):
+        """The looped stack's objective over the walks' outputs ``h^t``: every
+        one leaves through the one head (``l^t``, per token),
+        :meth:`exit_log_probs` gives the exit distribution ``p``, and the loss
+        is the mean over tokens of ``sum_t p^t l^t + exit_entropy_coeff sum_t
+        p^t log p^t``: the expected loss less the coefficient times the
+        distribution's entropy. The gate, ``p`` and the weighting in float32;
+        gradients flow through ``p``. Returns (loss, {``exit_mass`` (trips,)
+        the mean of ``p^t``, ``exit_losses`` (trips,) the mean of ``l^t``,
+        ``exit_entropy`` ()})."""
+        @jax.checkpoint
+        def block_losses(head, x, targets):
+            with monitor_spans.span("hybrid/unembed_xent"):
+                return tp_lib.vocab_parallel_cross_entropy(
+                    jnp.dot(x, head.T), targets, axis_name=None)
+
+        # each exit's logits are made again in the backward pass, from h^t, a
+        # block of EXIT_BLOCK tokens at a time: no exit's (tokens, vocabulary)
+        # logits are alive when another's are computed, nor one exit's whole
+        tokens = targets.size
+        blocks = tokens // EXIT_BLOCK if tokens % EXIT_BLOCK == 0 else 1
+        by_block = lambda a: jnp.split(a.reshape(tokens, *a.shape[targets.ndim:]), blocks)  # noqa: E731
+        losses = jnp.stack([
+            jnp.concatenate([block_losses(params["head"]["weight"], x, t) for x, t in zip(
+                by_block(state), by_block(targets))]).reshape(targets.shape)
+            for state in states])
+        with monitor_spans.span("hybrid/exit"):
+            log_p = self.exit_log_probs(params["exit_gate"], states)
+            p = jnp.exp(log_p)
+            plogp = jnp.sum(p * log_p, axis=0)
+            per_token = jnp.sum(p * losses, axis=0) + self.config.exit_entropy_coeff * plogp
+            mean = lambda a: tp_lib.masked_mean(a, loss_mask)  # noqa: E731
+            aux = {"exit_mass": jnp.stack([mean(a) for a in p]),
+                   "exit_losses": jnp.stack([mean(a) for a in losses]),
+                   "exit_entropy": -mean(plogp)}
+            return mean(per_token), aux

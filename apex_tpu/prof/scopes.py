@@ -52,7 +52,8 @@ SPANS: Dict[str, str] = {
     "hybrid/attn_mla": "a latent-attention mixer half",
     "hybrid/ssm": "a state-space (Mamba-2) mixer half",
     "hybrid/dense": "a dense feed-forward half", "hybrid/moe": "an expert half",
-    "hybrid/unembed_xent": "models/hybrid_decoder.py loss_fn",
+    "hybrid/unembed_xent": "models/hybrid_decoder.py loss_fn (a looped stack: every exit's)",
+    "hybrid/exit": "a looped stack's exit gate, the exit distribution and the weighting",
     "moe/route": "transformer/moe.py dropless_moe_layer: scores, top-k, the plan",
     "moe/experts": "the grouped products", "moe/shared": "the shared experts",
     "mla/down": "_latent_mixer's proj_in: the query and down projections, the latent's norm",
