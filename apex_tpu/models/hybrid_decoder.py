@@ -70,12 +70,13 @@ output leaves through the one head. ``params["exit_gate"]`` (a Linear(hidden,
 ``p^t = g^t prod_{j<t} (1 - g^j)``, the last walk taking what is left, and
 ``loss_fn`` is the mean over tokens of ``sum_t p^t l^t + exit_entropy_coeff
 sum_t p^t log p^t`` (the gate, ``p`` and the weighting in float32, span
-``hybrid/exit``; each exit's head and loss under ``hybrid/unembed_xent``, in a
-checkpoint of their own and ``EXIT_BLOCK`` tokens at a time, so no exit's
-logits are alive beside another's); the aux dict gains ``exit_mass``,
-``exit_losses`` (both (``loop_trips``,)) and ``exit_entropy``. With
-``loop_trips`` 1 (the default) there is no gate and the loss is the one
-cross-entropy it was.
+``hybrid/exit``; the exits' heads and losses under ``hybrid/unembed_xent``:
+:func:`weighted_exit_losses`, which makes an exit's gradient where it makes
+its loss, ``EXIT_BLOCK`` tokens at a time, so a block's logits are made once
+a step, none stand between the passes and none beside another block's); the
+aux dict gains ``exit_mass``, ``exit_losses`` (both (``loop_trips``,)) and
+``exit_entropy``. With ``loop_trips`` 1 (the default) there is no gate and the
+loss is the one cross-entropy it was.
 
 ``remat`` recomputes every block in the backward pass, a half at a time,
 except what the half's policy keeps by name (``MIXER_SAVED``,
@@ -90,8 +91,9 @@ of a delta-rule layer, ``xBC|z|dt`` of a state-space layer); an expert half
 keeps its routing plan. Norms, rotary, the convolution, the gates and
 ``w_o``'s operand are computed again. A looped stack holds ``loop_trips``
 passes of activations for every layer of state, so under ``remat`` it keeps
-the least of each (``LOOP_SAVED``): a block is recomputed whole — both halves,
-the kernels too — from its input and the routing plan, and every walk's
+the least that spares a kernel its second run (``LOOP_SAVED``): a block is
+recomputed as one — its projections, norms, rotary and second half — from
+its input, the routing plan and the flash call's results, and every walk's
 closing norm from its input; which of the two rules holds follows from
 ``loop_trips``, not from a setting.
 
@@ -133,10 +135,11 @@ MIXER_SCOPES = {"linear": "hybrid/gdn", "full": "hybrid/attn", "window": "hybrid
 MIXER_SAVED = FLASH_SAVED + RULE_SAVED + SSD_SAVED + ("mix_proj",)
 EXPERTS_SAVED = ("moe_plan",)
 # A looped stack (``loop_trips`` > 1) holds ``loop_trips`` passes of activations
-# for every layer of state, so it keeps the least of each: a block is
-# recomputed whole, its kernels too, from its input alone and the routing plan.
-LOOP_SAVED = EXPERTS_SAVED
-# tokens whose logits a looped stack's exit computes at a time
+# for every layer of state, so it keeps the least that spares a kernel its second
+# run: of a block-pass its input, the routing plan and the flash call's results.
+# The projections, norms, rotary and the second half are computed again.
+LOOP_SAVED = EXPERTS_SAVED + FLASH_SAVED
+# tokens whose logits a looped stack's exit makes, and turns into their gradient, at a time
 EXIT_BLOCK = 8192
 
 
@@ -251,6 +254,71 @@ class HybridDecoderConfig:
     @property
     def ffn(self) -> Tuple[str, ...]:
         return self.ffn_types or ("moe",) * len(self.layer_types)
+
+
+def _exit_blocks(a):
+    """``a`` (tokens, ...) cut into an exit's blocks of ``EXIT_BLOCK`` tokens;
+    whole where that does not divide the tokens."""
+    tokens = a.shape[0]
+    return jnp.split(a, tokens // EXIT_BLOCK if tokens % EXIT_BLOCK == 0 else 1)
+
+
+@jax.custom_vjp
+def weighted_exit_losses(head, states, targets, weights):
+    """A looped stack's exits through the one head: ``(sum over exits and
+    tokens of weights^t l^t, l)`` with ``l^t`` the per-token cross-entropy of
+    ``states[t] @ head.T`` (``head`` (vocabulary, hidden), ``states`` a tuple
+    of (tokens, hidden), ``targets`` (tokens,), ``weights`` and ``l``
+    (exits, tokens) float32). The cotangent of ``l`` is ``weights`` times one
+    scalar, so an exit makes its gradient where it makes its loss:
+    differentiated, the forward pass takes ``EXIT_BLOCK`` tokens at a time,
+    makes their logits ONCE (in the states' dtype), from them the statistics
+    of :func:`vocab_parallel_cross_entropy` and ``g = weights (softmax -
+    onehot)`` in the logits' dtype, ``g @ head`` and ``g^T @ x`` — the latter
+    summed over every block and exit in one float32 accumulator, rounded to
+    the head's dtype once the last has added to it — and keeps those, not the
+    logits; the backward rule multiplies them by the incoming scalar and runs
+    no product. The gradient with respect to ``weights`` is ``l``; ``l``
+    itself is handed out to be read and carries no gradient."""
+    with monitor_spans.span("hybrid/unembed_xent"):
+        losses = jnp.stack([jnp.concatenate([
+            tp_lib.vocab_parallel_cross_entropy(jnp.dot(xb, head.T), tb, axis_name=None)
+            for xb, tb in zip(_exit_blocks(x), _exit_blocks(targets))]) for x in states])
+        return jnp.sum(weights * losses), losses
+
+
+def _exits_fwd(head, states, targets, weights):
+    d_head = jnp.zeros(head.shape, jnp.float32)
+    losses, d_states = [], []
+    with monitor_spans.span("hybrid/unembed_xent"):
+        for x, w in zip(states, weights):
+            rows, d_rows = [], []
+            for xb, tb, wb in zip(_exit_blocks(x), _exit_blocks(targets), _exit_blocks(w)):
+                # a block starts when the one before it has handed in its gradients:
+                # the compiler would else make every block's logits first and hold them
+                xb, d_head, d_rows = jax.lax.optimization_barrier((xb, d_head, d_rows))
+                # the loss and wb (softmax - onehot) from the one set of logits
+                loss, g = tp_lib.cross_entropy_with_grad(
+                    jnp.dot(xb, head.T), tb, wb, axis_name=None)
+                rows.append(loss)
+                d_rows.append(jnp.dot(g, head))
+                d_head = d_head + jax.lax.dot_general(
+                    g, xb, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            losses.append(jnp.concatenate(rows))
+            d_states.append(jnp.concatenate(d_rows))
+        losses = jnp.stack(losses)
+        return ((jnp.sum(weights * losses), losses),
+                (d_head.astype(head.dtype), tuple(d_states), losses))
+
+
+def _exits_bwd(res, cts):
+    d_head, d_states, losses = res
+    ct = cts[0]                                   # the losses' own is not read
+    scaled = lambda d: (ct * d).astype(d.dtype)   # noqa: E731
+    return scaled(d_head), tuple(scaled(d) for d in d_states), None, ct * losses
+
+
+weighted_exit_losses.defvjp(_exits_fwd, _exits_bwd)
 
 
 def _norm(x, w, eps, zero_centered=True):
@@ -545,8 +613,8 @@ class HybridDecoderModel:
         (expert layers, router width): the routers' selection bias, where the
         step carries one. Under ``remat`` each half of a block is recomputed
         by itself (``MIXER_SAVED``, ``EXPERTS_SAVED``); in a looped stack the
-        block is recomputed whole, from its input (``LOOP_SAVED``), and the
-        final norm from its."""
+        block is recomputed as one, from its input and its flash call's
+        results (``LOOP_SAVED``), and the final norm from its input."""
         c = self.config
         layers = params["layers"]
         expert_half = self._recomputed(self._expert_half, EXPERTS_SAVED)
@@ -666,32 +734,32 @@ class HybridDecoderModel:
         is the mean over tokens of ``sum_t p^t l^t + exit_entropy_coeff sum_t
         p^t log p^t``: the expected loss less the coefficient times the
         distribution's entropy. The gate, ``p`` and the weighting in float32;
-        gradients flow through ``p``. Returns (loss, {``exit_mass`` (trips,)
-        the mean of ``p^t``, ``exit_losses`` (trips,) the mean of ``l^t``,
-        ``exit_entropy`` ()})."""
-        @jax.checkpoint
-        def block_losses(head, x, targets):
-            with monitor_spans.span("hybrid/unembed_xent"):
-                return tp_lib.vocab_parallel_cross_entropy(
-                    jnp.dot(x, head.T), targets, axis_name=None)
-
-        # each exit's logits are made again in the backward pass, from h^t, a
-        # block of EXIT_BLOCK tokens at a time: no exit's (tokens, vocabulary)
-        # logits are alive when another's are computed, nor one exit's whole
+        gradients flow through ``p``, which the walks' outputs give before any
+        exit is taken: ``p^t`` times a token's share of the mean is the
+        cotangent of ``l^t`` up to the loss's own, and
+        :func:`weighted_exit_losses` takes it as its weights. Returns (loss,
+        {``exit_mass`` (trips,) the mean of ``p^t``, ``exit_losses`` (trips,)
+        the mean of ``l^t``, read without a gradient, ``exit_entropy`` ()})."""
         tokens = targets.size
-        blocks = tokens // EXIT_BLOCK if tokens % EXIT_BLOCK == 0 else 1
-        by_block = lambda a: jnp.split(a.reshape(tokens, *a.shape[targets.ndim:]), blocks)  # noqa: E731
-        losses = jnp.stack([
-            jnp.concatenate([block_losses(params["head"]["weight"], x, t) for x, t in zip(
-                by_block(state), by_block(targets))]).reshape(targets.shape)
-            for state in states])
+        mean = lambda a: tp_lib.masked_mean(a, loss_mask)  # noqa: E731
+        # the gate and the exits read the walks' outputs where they stand: the compiler
+        # would else make them again, inside the gate's backward, from every half's output
+        # of every block-pass before them, and hold those until then
+        states = jax.lax.optimization_barrier(tuple(states))
         with monitor_spans.span("hybrid/exit"):
             log_p = self.exit_log_probs(params["exit_gate"], states)
             p = jnp.exp(log_p)
             plogp = jnp.sum(p * log_p, axis=0)
-            per_token = jnp.sum(p * losses, axis=0) + self.config.exit_entropy_coeff * plogp
-            mean = lambda a: tp_lib.masked_mean(a, loss_mask)  # noqa: E731
+            # a token's share of the mean: the cotangent of every l^t is p^t times it
+            counted = (jnp.ones(targets.shape, jnp.float32) if loss_mask is None
+                       else loss_mask.astype(jnp.float32))
+            share = counted / jnp.maximum(jnp.sum(counted), 1.0)
+        expected, losses = weighted_exit_losses(
+            params["head"]["weight"], tuple(x.reshape(tokens, -1) for x in states),
+            targets.reshape(tokens), (p * share).reshape(len(states), tokens))
+        losses = jax.lax.stop_gradient(losses).reshape(p.shape)
+        with monitor_spans.span("hybrid/exit"):
             aux = {"exit_mass": jnp.stack([mean(a) for a in p]),
                    "exit_losses": jnp.stack([mean(a) for a in losses]),
                    "exit_entropy": -mean(plogp)}
-            return mean(per_token), aux
+            return expected + self.config.exit_entropy_coeff * mean(plogp), aux

@@ -17,6 +17,7 @@ from apex_tpu.transformer.tensor_parallel.layers import (  # noqa: F401
     VocabParallelEmbedding,
 )
 from apex_tpu.transformer.tensor_parallel.cross_entropy import (  # noqa: F401
+    cross_entropy_with_grad,
     masked_mean,
     vocab_parallel_cross_entropy,
 )

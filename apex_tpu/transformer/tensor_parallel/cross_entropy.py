@@ -140,6 +140,18 @@ def _vce_bwd(label_smoothing, axis_name, impl, res, dloss):
 vocab_parallel_cross_entropy.defvjp(_vce_fwd, _vce_bwd)
 
 
+def cross_entropy_with_grad(logits, target, dloss, label_smoothing=0.0,
+                            axis_name=mesh_lib.TENSOR_AXIS, impl="auto"):
+    """(per-token loss, ``dloss`` times its gradient with respect to
+    ``logits``, in their dtype) from one set of logits:
+    :func:`vocab_parallel_cross_entropy`'s two rules run back to back, for a
+    caller that knows the loss's cotangent ``dloss`` (logits.shape[:-1]) where
+    it makes the logits and so need not keep them, or make them again, for a
+    backward pass. Nothing here is differentiated."""
+    loss, res = _vce_fwd(logits, target, label_smoothing, axis_name, impl)
+    return loss, _vce_bwd(label_smoothing, axis_name, impl, res, dloss)[0]
+
+
 def masked_mean(losses: jax.Array, loss_mask=None) -> jax.Array:
     """Mean per-token loss, optionally weighted by a 0/1 ``loss_mask``
     (1 = count) — the reduction every loss head shares (reference

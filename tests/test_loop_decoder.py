@@ -5,7 +5,8 @@ reference ``benchmarks/reference/loop_ref.py`` on seeded weights at a small
 size: the loss, every gradient leaf and the exits' readings with and without
 ``remat``; the walks against an unshared stack built from copies; the exit
 distribution; what the backward pass keeps of a looped stack and of its
-exits; and a stack walked once, which is the program it was before."""
+exits, whose hand-written rule is held to jax's own; and a stack walked once,
+which is the program it was before."""
 import collections
 import contextlib
 import dataclasses
@@ -232,11 +233,13 @@ def kernel_calls(jaxpr):
 KERNEL_SIZE = dict(SMALL, num_attention_heads=1, num_key_value_heads=1, head_dim=128)
 
 
-def test_a_looped_stack_keeps_its_blocks_inputs_alone(monkeypatch):
-    """Under ``remat`` a looped stack recomputes every block whole, its flash
-    call too: of a block-pass the backward holds the block's input, of a walk
-    its closing norm's, and not the projections and kernel results a stack
-    walked once keeps (``MIXER_SAVED``) nor the second half's input."""
+def test_a_looped_stack_keeps_its_blocks_inputs_and_flash_results(monkeypatch):
+    """Under ``remat`` a looped stack recomputes a block from its input and
+    its flash call's results (``LOOP_SAVED``): of a block-pass the backward
+    holds the block's input, the attention's output and its log-sum-exp rows,
+    so no flash forward runs twice; of a walk its closing norm's input; and
+    not the projections a stack walked once keeps (``MIXER_SAVED``), nor the
+    second half's input or a SwiGLU value."""
     d, model, w = build(KERNEL_SIZE, remat=True, attention_impl="pallas")
     tokens, targets = batch()
     p = loop_tree.to_program(w)
@@ -244,19 +247,26 @@ def test_a_looped_stack_keeps_its_blocks_inputs_alone(monkeypatch):
     grad = lambda: kernel_calls(jax.make_jaxpr(jax.grad(model.loss_fn))(  # noqa: E731
         p, tokens, targets).jaxpr)
     calls = grad()
-    assert calls["flash_fwd_bshd"] == 2 * passes == 16
+    assert calls["flash_fwd_bshd"] == passes == 8
     assert sum(c for n, c in calls.items() if n.startswith("flash_bwd")) == passes
     saved = saved_shapes(model.loss_fn, p, tokens, targets)
     # a block's input a pass (the embedding's rows and three walks' outputs
-    # among them), a closing norm's input a walk, and the last walk's output
-    assert saved.count((ROWS, SEQ, 128)) == passes + TRIPS, saved
-    assert saved.count((ROWS * SEQ, 128)) == TRIPS          # as the exits read them
-    wide = (ROWS, SEQ, 2 * KERNEL_SIZE["intermediate_size"])
-    assert wide not in saved and (ROWS, SEQ, 1, 128) not in saved
-    # the witness: a stack that keeps its kernels' results runs none of them twice
-    monkeypatch.setattr(hybrid_decoder, "LOOP_SAVED", hybrid_decoder.FLASH_SAVED)
-    assert grad()["flash_fwd_bshd"] == passes
-    assert saved_shapes(model.loss_fn, p, tokens, targets).count((ROWS, SEQ, 1, 128)) == passes
+    # among them), a closing norm's input a walk, and the walks' outputs as the
+    # gate read them (three of the four that ``_exit_loss``'s barrier hands on,
+    # the buffers of the next walks' inputs under another name): no projection's
+    # output, which has a stream's shape here, is among them
+    assert saved.count((ROWS, SEQ, 128)) == passes + TRIPS + TRIPS - 1, saved
+    # of the values in the kernel's layout (q, k, v and the context) the context alone
+    assert saved.count((ROWS, SEQ, 1, 128)) == passes
+    assert saved.count((ROWS, 1, SEQ)) == passes            # its log-sum-exp rows
+    assert saved.count((ROWS * SEQ, 128)) == TRIPS          # the exits' gradients to h^t
+    wide = KERNEL_SIZE["intermediate_size"]
+    assert not [shape for shape in saved
+                if shape[:2] == (ROWS, SEQ) and shape[-1] in (wide, 2 * wide)]
+    # the witness: a stack that keeps its blocks' inputs alone runs every flash forward twice
+    monkeypatch.setattr(hybrid_decoder, "LOOP_SAVED", hybrid_decoder.EXPERTS_SAVED)
+    assert grad()["flash_fwd_bshd"] == 2 * passes
+    assert (ROWS, SEQ, 1, 128) not in saved_shapes(model.loss_fn, p, tokens, targets)
 
 
 def most_logits_alive(jaxpr, tokens, vocab):
@@ -279,30 +289,145 @@ def most_logits_alive(jaxpr, tokens, vocab):
     return most
 
 
+def onto_vocabulary(jaxpr, vocab):
+    """Contractions of a jaxpr, and of the jaxprs it holds, whose result's
+    last axis is the vocabulary: the products that make logits."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    return sum((eqn.primitive.name == "dot_general"
+                and eqn.outvars[0].aval.shape[-1:] == (vocab,))
+               + sum(onto_vocabulary(sub, vocab) for sub in jax.core.jaxprs_in_params(eqn.params))
+               for eqn in jaxpr.eqns)
+
+
+def plain_exits(head, states, targets, weights):
+    """``weighted_exit_losses`` as the plain composition jax differentiates."""
+    losses = jnp.stack([jax.nn.logsumexp(logits, axis=-1)
+                        - jnp.take_along_axis(logits, targets[:, None], axis=-1)[:, 0]
+                        for logits in (x @ head.T for x in states)])
+    return jnp.sum(weights * losses), jax.lax.stop_gradient(losses)
+
+
 @pytest.mark.parametrize("remat", [False, True])
 def test_no_exits_logits_stand_between_the_passes_or_beside_anothers(remat, monkeypatch):
-    """Each exit's head and loss are recomputed from ``h^t`` in the backward
-    pass: nothing of (tokens, vocabulary) is kept from the forward pass, and
-    in the gradient's jaxpr no more such values are alive at a point with
-    four exits than with two. Without the checkpoint around an exit every
-    exit's stand until the backward pass."""
+    """An exit makes its gradient where it makes its loss: nothing of (tokens,
+    vocabulary) is kept from the forward pass, in the gradient's jaxpr no more
+    such values are alive at a point with four exits than with two, and the
+    product onto the vocabulary runs once an exit's block. The plain
+    composition keeps every exit's logits until the backward pass, and in a
+    checkpoint of its own it makes them twice."""
     wide = dict(SMALL, vocab_size=8192)
     tokens, targets = batch(8192)
+    monkeypatch.setattr(hybrid_decoder, "EXIT_BLOCK", SEQ)
 
     def alive(trips):
         d, model, w = build(dict(wide, total_ut_steps=trips), remat=remat)
         p = loop_tree.to_program(w)
         kept = [shape for shape in saved_shapes(model.loss_fn, p, tokens, targets)
-                if int(np.prod(shape)) >= ROWS * SEQ * 8192]
+                if shape[-1:] == (8192,) and int(np.prod(shape)) >= SEQ * 8192]
         jaxpr = jax.make_jaxpr(jax.grad(model.loss_fn))(p, tokens, targets)
-        return kept, most_logits_alive(jaxpr, ROWS * SEQ, 8192)
+        return kept, most_logits_alive(jaxpr, SEQ, 8192), onto_vocabulary(jaxpr, 8192)
 
-    kept, four = alive(4)
+    kept, four, products = alive(4)
     assert not kept
     assert four == alive(2)[1]
-    monkeypatch.setattr(jax, "checkpoint", lambda f, **_: f)
-    kept, unkept = alive(4)
+    assert products == 4 * ROWS                  # an exit's block of SEQ tokens a row
+    unruled = hybrid_decoder.weighted_exit_losses.fun      # the function without its rule
+    monkeypatch.setattr(hybrid_decoder, "weighted_exit_losses", plain_exits)
+    kept, unkept, _ = alive(4)
     assert kept and unkept > four
+    monkeypatch.setattr(hybrid_decoder, "weighted_exit_losses", jax.checkpoint(unruled))
+    kept, _, twice = alive(4)
+    assert not kept and twice == 2 * products
+
+
+# the exits' hand-written rule against jax's own of the plain composition:
+# (a loss mask?, EXIT_BLOCK, the sum's cotangent)
+EXIT_CASES = {
+    "one block": (False, ROWS * SEQ, 1.0),
+    "blocks, a mask": (True, SEQ // 2, 1.0),
+    "a block that does not divide the tokens": (False, SEQ - 32, 1.0),
+    "a loss scale, a mask": (True, SEQ, 1024.0),
+    "a cotangent below one": (False, SEQ // 2, -0.37),
+}
+
+
+def exit_inputs(masked):
+    """A head, four walks' states, targets and the weights ``p^t share`` as
+    ``_exit_loss`` makes them, float32."""
+    keys = jax.random.split(jax.random.PRNGKey(7), 5)
+    tokens, hidden, vocab = ROWS * SEQ, 64, 384
+    head = 0.3 * jax.random.normal(keys[0], (vocab, hidden))
+    states = tuple(jax.random.normal(k, (tokens, hidden)) for k in jax.random.split(keys[1], TRIPS))
+    targets = jax.random.randint(keys[2], (tokens,), 0, vocab)
+    p = jax.nn.softmax(jax.random.normal(keys[3], (TRIPS, tokens)), axis=0)
+    mask = (jax.random.uniform(keys[4], (tokens,)) < 0.7) if masked else jnp.ones((tokens,))
+    return head, states, targets, p * mask / jnp.sum(mask)
+
+
+def exit_gaps(f, case):
+    """The largest gaps of ``f``'s values and gradients to the plain
+    composition's, each against the plain one's largest entry."""
+    masked, block, ct = EXIT_CASES[case]
+    args = exit_inputs(masked)
+    with jax.default_matmul_precision("highest"):
+        (total, losses), pull = jax.vjp(f, *args)
+        (want_total, want_losses), want_pull = jax.vjp(plain_exits, *args)
+        cts = (jnp.float32(ct), jnp.zeros_like(losses))
+        (d_head, d_states, _, d_w), (w_head, w_states, _, w_w) = pull(cts), want_pull(cts)
+    assert d_head.dtype == jnp.float32 and d_states[0].shape == args[1][0].shape
+    return {"sum": gap(total, want_total), "losses": gap(losses, want_losses),
+            "d head": gap(d_head, w_head), "d w": gap(d_w, w_w),
+            "d states": max(gap(a, b) for a, b in zip(d_states, w_states))}
+
+
+@pytest.mark.parametrize("case", EXIT_CASES)
+def test_the_exits_rule_is_the_gradient_of_the_plain_composition(case, monkeypatch):
+    monkeypatch.setattr(hybrid_decoder, "EXIT_BLOCK", EXIT_CASES[case][1])
+    gaps = exit_gaps(hybrid_decoder.weighted_exit_losses, case)
+    assert max(gaps.values()) <= GRAD_TOL, gaps
+    assert gaps["sum"] <= LOSS_TOL and gaps["losses"] <= LOSS_TOL, gaps
+
+    # a cheaper rule, the gradient through the weights dropped, is refused
+    @jax.custom_vjp
+    def cheaper(*args):
+        return hybrid_decoder.weighted_exit_losses(*args)
+
+    def bwd(res, cts):
+        d_head, d_states, _, d_w = hybrid_decoder._exits_bwd(res, cts)
+        return d_head, d_states, None, jnp.zeros_like(d_w)
+
+    cheaper.defvjp(hybrid_decoder._exits_fwd, bwd)
+    gaps = exit_gaps(cheaper, case)
+    assert gaps.pop("d w") > 0.5 and max(gaps.values()) <= GRAD_TOL, gaps
+
+
+def test_a_masked_looped_loss_is_the_masked_mean_of_the_plain_objective():
+    """``loss_fn(loss_mask=)`` of a looped stack against the objective written
+    out: the masked mean over tokens of ``sum_t p^t l^t + coeff sum_t p^t log
+    p^t``, the loss and every gradient leaf."""
+    d, model, w = build()
+    tokens, targets = batch()
+    p = loop_tree.to_program(w)
+    mask = jax.random.uniform(jax.random.PRNGKey(5), (ROWS, SEQ)) < 0.6
+
+    def plain(p):
+        states, _ = model.trip_states(p, tokens)
+        log_p = model.exit_log_probs(p["exit_gate"], states)
+        losses = jnp.stack([loop_ref.exit_losses(p["head"]["weight"], x.reshape(ROWS * SEQ, -1),
+                                                 targets.reshape(-1), "float32")
+                            for x in states]).reshape(log_p.shape)
+        per_token = jnp.sum(jnp.exp(log_p) * (losses + d["entropy_beta"] * log_p), axis=0)
+        return jnp.sum(per_token * mask) / jnp.sum(mask)
+
+    with jax.default_matmul_precision("highest"):
+        loss, g = jax.jit(jax.value_and_grad(
+            lambda p: model.loss_fn(p, tokens, targets, loss_mask=mask)))(p)
+        want, g_want = jax.jit(jax.value_and_grad(plain))(p)
+        unmasked = jax.jit(model.loss_fn)(p, tokens, targets)
+    assert abs(float(loss) - float(want)) <= LOSS_TOL * float(want)
+    assert abs(float(unmasked) - float(want)) > 10 * LOSS_TOL * float(want)
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(g)[0], jax.tree.leaves(g_want)):
+        assert gap(a, b) <= GRAD_TOL, jax.tree_util.keystr(path)
 
 
 def test_an_exit_takes_its_tokens_a_block_at_a_time(monkeypatch):
