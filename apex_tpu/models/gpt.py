@@ -377,7 +377,8 @@ class GPTModel:
             from apex_tpu.ops import _backend
             from apex_tpu.ops.attention import (bshd_kernel_ok,
                                                 flash_auto_crossover,
-                                                fused_qkv_attention)
+                                                fused_qkv_attention,
+                                                packed_kernel_ok)
             # the O1 per-op cast applies before the kernel-eligibility
             # gate — an fp16-casting policy must land on the XLA path
             # (Mosaic has no f16), so the gate sees the POST-cast dtype
@@ -387,7 +388,7 @@ class GPTModel:
             fused_ok = (
                 c.cp_axis is None  # cp: attention is distributed below
                 and "bias" in p["qkv"]
-                and bshd_kernel_ok(s_len, s_len, h, d, xc.dtype)
+                and packed_kernel_ok(s_len, h, hkv, d, xc.dtype)
                 and (s_len >= flash_auto_crossover(d)
                      or _backend.interpret_forced())
                 and _backend.choose_impl("auto", True) == "pallas"
@@ -398,7 +399,9 @@ class GPTModel:
                 # buffer → output GEMM, all plain 2D contractions with a
                 # hand-written VJP (see ops.attention.fused_qkv_attention
                 # — kills the ~4.5 GB/step of XLA layout-conversion copies
-                # the composed formulation paid, PERF.md r3).
+                # the composed formulation paid, PERF.md r3). Heads of 128,
+                # and heads of 64 two to a lane tile (an even local count,
+                # no grouped kv: gpt2-medium's 16).
                 y = fused_qkv_attention(
                     xc, w_qkv, b_qkv, w_out, None, seed, None, h, hkv, d,
                     1.0 / float(d) ** 0.5, True, drop)
@@ -413,12 +416,13 @@ class GPTModel:
                     and (s_len >= flash_auto_crossover(d)
                          or _backend.interpret_forced())
                     and _backend.choose_impl("auto", True) == "pallas"):
-                # d=64 multi-head can't ride the folded bshd layout (its
-                # 64-wide blocks break the 128-lane tile rule) but the
-                # bh-flat kernel handles d=64 fine — keep the pre-r3
-                # head-batched route so those configs don't silently lose
-                # the kernel (the layout copies it pays are the r2 cost
-                # model; head_dim 128 is the recommended config anyway)
+                # what is left of d=64 after the pair rule above — a qkv
+                # projection without bias, an odd local head count (tp),
+                # grouped kv: the folded bshd layout cannot take 64-wide
+                # blocks, the bh-flat kernels can, so these keep the
+                # head-batched route (its layout copies and the dq | dkv
+                # split backward: 13.4 + 13.5 ms a step at gpt2-medium's
+                # shape, PERF.md §6, PR 42) and do not lose the kernel
                 qkv4 = self.qkv.headwise(p["qkv"], x, h + 2 * hkv)
                 q4 = qkv4[:, :h]
                 k4 = qkv4[:, h:h + hkv]

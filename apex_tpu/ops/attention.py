@@ -405,14 +405,31 @@ _flash_core.defvjp(_flash_fwd, _flash_bwd)
 
 def bshd_kernel_ok(sq: int, sk: int, h: int, d: int, dtype) -> bool:
     """Mosaic eligibility for the seq-major (folded) kernels — shared by
-    ``flash_attention(layout='bshd')``, ``fused_qkv_attention`` callers,
-    and the GPT fused-path gate so the rule lives in ONE place. The folded
-    (b, s, h·d) views take d-wide column blocks, so d must tile the
-    128-lane rule itself (d == 64 only passes when it IS the folded dim,
+    ``flash_attention(layout='bshd')``, the ring, Ulysses and BERT, and the
+    first half of :func:`packed_kernel_ok`, so the rule lives in ONE place.
+    The folded (b, s, h·d) views take d-wide column blocks, so d must tile
+    the 128-lane rule itself (d == 64 only passes when it IS the folded dim,
     i.e. a single head); f16 has no Mosaic support at all."""
     return (sq % 128 == 0 and sk % 128 == 0
             and (d % 128 == 0 or (h == 1 and d == 64))
             and dtype != jnp.float16)
+
+
+def packed_kernel_ok(s: int, h: int, h_kv: int, d: int, dtype) -> bool:
+    """Mosaic eligibility for :func:`fused_qkv_attention` (the packed q|k|v
+    buffer, self attention of ``s`` positions) — the GPT fused-path gate.
+    Either :func:`bshd_kernel_ok`'s rule, or the PAIR rule: heads of 64 ride
+    two to a 128-lane block when there is an even number of them and
+    ``h == h_kv`` (``pallas.attention.packed_pair``: under a group of q heads
+    the two heads of a q pair would share one kv head on the wrong lanes —
+    grouped heads of 64 keep the flat kernels), ``s`` tiles by 128 and fits
+    the one-pass backward (``packed_pair_fits``: about 16 k positions), and
+    the dtype is not f16. A pair call takes no score bias. Shapes and dtype
+    only: nothing else chooses the path."""
+    return bshd_kernel_ok(s, s, h, d, dtype) or (
+        _k.packed_pair(h, h_kv, d) and s % 128 == 0
+        and dtype != jnp.float16
+        and _k.packed_pair_fits(s, jnp.dtype(dtype).itemsize))
 
 
 def bshd_qkv_projection(x, weight, bias, h, h_kv, d):
@@ -700,10 +717,35 @@ def fused_qkv_attention(x, w_qkv, b_qkv, w_out, bias, dropout_seed,
     the dk/dv GEMMs below contract (tokens, h_kv·d) operands); lengths and
     dropout ride it. A bias takes the dq/dkv/dbias split. The choice is
     made at trace time from shapes — see ``pallas.attention.
-    flash_bwd_packed``."""
+    flash_bwd_packed``.
+
+    Heads of 64 (:func:`packed_kernel_ok`'s pair rule: an even number,
+    ``h == h_kv``) ride the same block two heads to a 128-lane tile —
+    ``flash_fwd_packed_pair`` / ``flash_bwd_packed_pair_fused``, the same
+    mathematics once a head of the pair; lengths and dropout ride them, a
+    ``bias`` does not (ValueError)."""
     y, _ = _fused_attn_fwd(x, w_qkv, b_qkv, w_out, bias, dropout_seed,
                            kv_lens, h, h_kv, d, scale, causal, dropout_rate)
     return y
+
+
+# Heads of 64 in pairs: jitted, so the layers of a model share one traced and
+# lowered program a kernel (the pair bodies hold a tile once a head, and a
+# kernel body is traced at every call site). The calls at heads of 128 stay
+# the plain functions: their programs lower to the text they always did. The
+# wrappers' names hold no part a trace reader matches (``flash_``): XLA names
+# what it derives from a call after the jitted function.
+_PACKED_STATIC = ("scale", "causal", "full_lse", "interpret", "dropout_rate")
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3), static_argnames=_PACKED_STATIC)
+def _pair_forward(qkv, h, h_kv, d, **kw):
+    return _k.flash_fwd_packed(qkv, h, h_kv, d, **kw)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3), static_argnames=_PACKED_STATIC)
+def _pair_backward(qkv, h, h_kv, d, o, lse, do, **kw):
+    return _k.flash_bwd_packed(qkv, h, h_kv, d, o, lse, do, **kw)
 
 
 def _fused_attn_fwd(x, w_qkv, b_qkv, w_out, bias, dropout_seed, kv_lens, h,
@@ -727,7 +769,8 @@ def _fused_attn_fwd(x, w_qkv, b_qkv, w_out, bias, dropout_seed, kv_lens, h,
     # full_lse: keep the (b, h, s, LANES) lane carrier as the residual —
     # backward hands it straight back to the kernel (slicing lane 0 here
     # would force a re-broadcast there, one slice+broadcast pair per layer)
-    o, lse = _k.flash_fwd_packed(
+    fwd = _pair_forward if _k.packed_pair(h, h_kv, d) else _k.flash_fwd_packed
+    o, lse = fwd(
         qkv, h, h_kv, d, scale=scale, causal=causal, kv_lens=kv_lens,
         bias=bias, full_lse=True, interpret=_backend.interpret_mode(),
         dropout_rate=dropout_rate, dropout_seed=dropout_seed)
@@ -745,7 +788,8 @@ def _fused_attn_bwd(h, h_kv, d, scale, causal, dropout_rate, res, dy):
     o2 = o.reshape(T, h * d)
     dw_out = jnp.dot(dy2.T, o2)
     do = jnp.dot(dy2, w_out).reshape(b, s, h * d)
-    out = _k.flash_bwd_packed(
+    bwd = _pair_backward if _k.packed_pair(h, h_kv, d) else _k.flash_bwd_packed
+    out = bwd(
         qkv, h, h_kv, d, o, lse, do, scale=scale, causal=causal,
         kv_lens=kv_lens, bias=bias, interpret=_backend.interpret_mode(),
         dropout_rate=dropout_rate, dropout_seed=dropout_seed)
@@ -815,6 +859,23 @@ def flash_attention(
     installation, not re-measured on this code. The full-step measurement
     (where the kernel competes with everything else for HBM) is the one
     that matters, not an isolated-kernel timing.
+
+    Where a call lands, by layout and head width (``impl='auto'``, bf16 or
+    fp32, sequences in whole 128-blocks at or past the crossover):
+
+    =====================  ==========================  =========================
+    entry                  heads of 128 (and 256)      heads of 64
+    =====================  ==========================  =========================
+    ``layout='bhsd'``      flat kernels                flat kernels
+    ``layout='bshd'``      seq-major kernels           one head: seq-major;
+                                                       several: XLA (explicit
+                                                       ``'pallas'`` raises)
+    ``fused_qkv_attention``  packed kernels            an even ``h == h_kv``:
+    (``GPTModel``, by                                  packed PAIR kernels, two
+    ``packed_kernel_ok``)                              heads a lane tile; else
+                                                       ``GPTModel`` keeps the
+                                                       flat kernels
+    =====================  ==========================  =========================
 
     ``layout='bshd'``: operands are (batch, seq, heads, head_dim) — the
     seq-major layout the QKV projection GEMMs naturally emit. The Pallas

@@ -284,10 +284,78 @@ def _band_walk(banded, n_own, b_own, b_other, n_other, shift, lo_reach, hi_reach
     return steps, lambda i, s: _least(first(i) + s, last(i))
 
 
+# --- heads of 64, two to a lane tile -------------------------------------------
+#
+# A folded (b, s, h·d) view takes d-wide column blocks, and 64 lanes are half
+# a tile. A packed-layout call whose heads are 64 wide (:func:`packed_pair`)
+# therefore takes column blocks of 128 lanes — two adjacent heads, the same
+# pair of q, k and v — and the kernels run each tile's mathematics once a head
+# of the pair. No lane is sliced: the SMALL operands of a head (q in the
+# forward; q, k and dO in the backward — selects over (rows, 128), never over
+# a score tile) have the other head's lanes zeroed, so a contraction over all
+# 128 lanes yields that head's scores and dP, and ``Pᵀ·dO``, ``dSᵀ·Q``,
+# ``dS·K`` land in that head's 64 lanes with zeros beside them: the shared
+# (rows, 128) accumulators take both heads by plain addition. A 128 x 128
+# MXU spends on a zeroed half what it would leave idle under a contraction
+# 64 deep. Everything else of a call is the call at half as many heads of 128.
+
+_PAIR_D, _PAIR_LANES = 64, 128
+
+
+def packed_pair(h, h_kv, d):
+    """Whether a packed-layout call rides pair blocks: heads of 64, an even
+    number of them, and ``group == 1`` — a q pair then reads the k and v
+    pair at its own index, lane for lane (under a group of q heads the two
+    heads of a q pair would share ONE kv head, on the wrong lanes for one
+    of them)."""
+    return d == _PAIR_D and h == h_kv and h % 2 == 0
+
+
+def _check_pair(bias):
+    if bias is not None:
+        raise ValueError("a packed call at heads of 64 takes no score bias "
+                         "(the bias kernels read one head a block)")
+
+
+def _pair_block(s):
+    """The block (``bq = bk``) a pair call's BACKWARD prefers, from the
+    sequence length alone: half the sequence, between 128 and 1,024. At 1,024
+    positions one 1,024-block is ONE tile a head, all of it crossed by the
+    diagonal; at 512 three of four tiles run and one of them takes the
+    branch with no mask (0.834 ms a layer against 0.904 at 8 x 16 heads;
+    256 loses, 1.249: a tile costs about a microsecond whatever its size).
+    From 2,048 positions on it is the 1,024 of the other forms. The forward
+    keeps 1,024 at every length: its body has no unmasked branch to reach,
+    and pays the cost a tile three times over at 512 (0.561 -> 0.831 ms;
+    PERF.md §6, PR 42)."""
+    return max(128, min(1024, s // 2))
+
+
+def _pair_own(shape, hd):
+    """(rows, 128) bool: the lanes of head ``hd`` of a pair."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return lane >= _PAIR_D if hd else lane < _PAIR_D
+
+
+def _pair_lanes(x, hd):
+    """``x`` (rows, 128) with the lanes of the pair's other head zeroed;
+    ``hd`` None (no pair): ``x`` itself."""
+    if hd is None:
+        return x
+    return jnp.where(_pair_own(x.shape, hd), x, jnp.zeros_like(x))
+
+
+def _pair_plane(hd):
+    """The index of head ``hd``'s plane of a per-head (2, rows, 1) scratch;
+    ``hd`` None (no pair): of the whole (rows, 1) scratch."""
+    return slice(None) if hd is None else hd
+
+
 # --- forward ------------------------------------------------------------------
 
 def _fwd_kernel(*refs, scale, causal, bq, bk, nk, off, varlen, bshd=False,
-                rate=0.0, has_bias=False, rel=None, window=None, second=False):
+                rate=0.0, has_bias=False, rel=None, window=None, second=False,
+                pair=False):
     """``varlen`` is a STATIC specialization flag: without kv lengths the
     kernel carries no length operand, no per-block length select, and no
     dynamic predicate conjunct — the common (non-padded) call pays nothing.
@@ -316,6 +384,12 @@ def _fwd_kernel(*refs, scale, causal, bq, bk, nk, off, varlen, bshd=False,
     k2 (b, h2, sk, d2), whose product is added to ``q·kᵀ`` before the scale
     (latent attention's rotary part: one key shared by all heads). The value
     block's width is the accumulator's and the output's; it need not be q's.
+    ``pair`` (static): the blocks hold two heads of 64, side by side in one
+    128-lane tile (the section on pairs above): a tile's mathematics runs
+    once a head on q with the other head's lanes zeroed, the running max and
+    sum are a plane a head of (2, bq, 1) scratches, each head's 64 lanes of
+    ``P·V`` are kept into the shared accumulator, and the lse block holds
+    the pair's two rows.
     """
     refs = list(refs)
     q_ref, k_ref, v_ref = refs[:3]
@@ -358,12 +432,13 @@ def _fwd_kernel(*refs, scale, causal, bq, bk, nk, off, varlen, bshd=False,
         kvlen = kvlen_ref[0, 0, 0]
         run = jnp.logical_and(run, j * bk < kvlen)
 
-    @pl.when(run)
-    def _step():
+    heads = (0, 1) if pair else (None,)
+
+    def _head_step(hd):
         # MXU operands stay in the input dtype (bf16 in mixed precision —
         # an fp32 pre-cast would run the matmul at the ~8x-slower fp32 MXU
         # rate); preferred_element_type pins fp32 accumulation either way.
-        q = q_ref[0]
+        q = _pair_lanes(q_ref[0], hd)
         k = k_ref[0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -384,39 +459,52 @@ def _fwd_kernel(*refs, scale, causal, bq, bk, nk, off, varlen, bshd=False,
             s = jnp.where(_visible(rows + off, cols, window), s, NEG_INF)
         if varlen:
             s = jnp.where(cols < kvlen, s, NEG_INF)
-        m_prev = m_scr[:]
+        own = _pair_plane(hd)
+        m_prev = m_scr[own]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        l_scr[own] = l_scr[own] * alpha + jnp.sum(p, axis=1, keepdims=True)
         if rate > 0.0:
-            pd = p * _mask_scale(seed_ref[0], t, i, j, bq, bk, rate)
+            # the dropout key is the q-head row: of a pair, 2·t and 2·t + 1
+            pd = p * _mask_scale(seed_ref[0], t if hd is None else 2 * t + hd,
+                                 i, j, bq, bk, rate)
         else:
             pd = p
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+        acc = acc_scr[:] * alpha + jax.lax.dot_general(
             pd.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        m_scr[:] = m_new
+        # of a pair, P·V is this head's in its own 64 lanes only
+        acc_scr[:] = acc if hd is None else jnp.where(
+            _pair_own(acc.shape, hd), acc, acc_scr[:])
+        m_scr[own] = m_new
+
+    @pl.when(run)
+    def _step():
+        for hd in heads:
+            _head_step(hd)
 
     @pl.when(jj == nk - 1)
     def _finish():
-        l = jnp.maximum(l_scr[:], 1e-30)
+        ls = [jnp.maximum(l_scr[_pair_plane(hd)], 1e-30) for hd in heads]
+        l = jnp.where(_pair_own(acc_scr.shape, 0), *ls) if pair else ls[0]
         o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse_val = m_scr[:] + jnp.log(l)
-        if varlen:
-            # fully-masked rows (kvlen == 0): lse would be NEG_INF+log(eps),
-            # and backward's exp(s - lse) with s == NEG_INF would overflow
-            # to exp(+huge); pin dead rows' lse to 0 so p == exp(NEG_INF).
-            lse_val = jnp.where(l_scr[:] > 0.0, lse_val, 0.0)
-        # lse rides an (sq, 8) layout: TPU blocks must tile (8, 128) or match
-        # the array dim, so a flat (1, bq) row block won't lower — broadcast
-        # the column across 8 lanes and let the caller slice lane 0.
-        lse_b = jnp.broadcast_to(lse_val, (l.shape[0], _LSE_LANES))
-        if bshd:  # (b, h, sq, LANES) carrier
-            lse_ref[0, 0] = lse_b
-        else:
-            lse_ref[0] = lse_b
+        for hd, l_hd in zip(heads, ls):
+            lse_val = m_scr[_pair_plane(hd)] + jnp.log(l_hd)
+            if varlen:
+                # fully-masked rows (kvlen == 0): lse would be NEG_INF+log(eps),
+                # and backward's exp(s - lse) with s == NEG_INF would overflow
+                # to exp(+huge); pin dead rows' lse to 0 so p == exp(NEG_INF).
+                lse_val = jnp.where(l_scr[_pair_plane(hd)] > 0.0, lse_val, 0.0)
+            # lse rides an (sq, 8) layout: TPU blocks must tile (8, 128) or match
+            # the array dim, so a flat (1, bq) row block won't lower — broadcast
+            # the column across 8 lanes and let the caller slice lane 0.
+            lse_b = jnp.broadcast_to(lse_val, (l_hd.shape[0], _LSE_LANES))
+            if bshd:  # (b, h, sq, LANES) carrier; a pair's block holds two heads
+                lse_ref[0, hd or 0] = lse_b
+            else:
+                lse_ref[0] = lse_b
 
 
 _LSE_LANES = 8
@@ -607,8 +695,19 @@ def flash_fwd_packed(qkv, h, h_kv, d, *, scale, causal, kv_lens=None,
 
     ``bias`` (hb, s, s) with hb | h: additive score bias, q-head row
     ``t = b·h + h_i`` reading bias row ``t % hb`` (i.e. per-head bias
-    shared over batch at hb == h; broadcast at hb == 1)."""
+    shared over batch at hb == h; broadcast at hb == 1).
+
+    Heads of 64 (:func:`packed_pair`: an even number of them, ``h == h_kv``)
+    ride two to a 128-lane block: the call is ``flash_fwd_packed_pair`` and
+    takes no ``bias``. Lengths and dropout ride it; the results have the same
+    shapes."""
     b, s, _ = qkv.shape
+    pair = packed_pair(h, h_kv, d)
+    if pair:
+        # the call at half as many heads of 128 (the section on pairs above)
+        _check_pair(bias)
+        h, h_kv, d = h // 2, h_kv // 2, _PAIR_LANES
+    per = 2 if pair else 1               # heads a block
     group = h // h_kv
     bq, bk = _bias_blocks(bias, bq, bk)
     bq, bk = _fit_block(s, bq), _fit_block(s, bk)
@@ -638,23 +737,23 @@ def flash_fwd_packed(qkv, h, h_kv, d, *, scale, causal, kv_lens=None,
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nk=nk, off=0, varlen=varlen,
                           bshd=True, rate=dropout_rate,
-                          has_bias=bias is not None),
-        name="flash_fwd_packed",
+                          has_bias=bias is not None, pair=pair),
+        name="flash_fwd_packed_pair" if pair else "flash_fwd_packed",
         grid=(b * h, nq, nk),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, bq, d),
                          lambda t, i, j, h=h: (t // h, i, t % h)),
-            pl.BlockSpec((1, 1, bq, _LSE_LANES),
+            pl.BlockSpec((1, per, bq, _LSE_LANES),
                          lambda t, i, j, h=h: (t // h, t % h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, s, h * d), qkv.dtype),
-            jax.ShapeDtypeStruct((b, h, s, _LSE_LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b, per * h, s, _LSE_LANES), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((2, bq, 1) if pair else (bq, 1), jnp.float32),
+            pltpu.VMEM((2, bq, 1) if pair else (bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
@@ -675,7 +774,7 @@ def _vmem_limit(nbytes):
     return min(_VMEM_CAP, max(_VMEM_FLOOR, nbytes))
 
 
-def _fused_bwd_vmem_bytes(s, d, bq, bk, itemsize, dv=None, d2=0):
+def _fused_bwd_vmem_bytes(s, d, bq, bk, itemsize, dv=None, d2=0, heads=1):
     """VMEM the one-pass packed backward holds at once: the two whole-
     sequence fp32 dk/dv accumulators, their (double-buffered) output blocks
     at the kv dtype, the q/do/o/k/v/dq blocks, and the score-tile
@@ -683,14 +782,15 @@ def _fused_bwd_vmem_bytes(s, d, bq, bk, itemsize, dv=None, d2=0):
     a dropout multiplier: under eight (bq, bk) fp32 tiles). ``dv``: the
     value head's width where it is not ``d``; ``d2``: the second score
     term's, whose q2/k2/dq2 blocks, dk2 accumulator and output ride at
-    whole 128-lane tiles."""
+    whole 128-lane tiles. ``heads``: the heads a block holds (2 in a pair
+    call), each with a step's tile temporaries of its own."""
     dv = d if dv is None else dv
     w = d + dv + -(-d2 // 128) * 128             # lanes resident per position
     accumulators = s * w * 4
     outputs = 2 * s * w * itemsize
     blocks = (2 * (2 * bq + bk) * w * itemsize
               + bq * (w - dv) * 4)
-    tiles = 8 * bq * bk * 4
+    tiles = heads * 8 * bq * bk * 4
     return accumulators + outputs + blocks + tiles
 
 
@@ -707,7 +807,7 @@ def _split_bwd_vmem_limit(d, bq, bk, itemsize, out_itemsize):
 
 
 def _bwd_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h, h_kv,
-                      varlen, rate=0.0, window=None, second=None):
+                      varlen, rate=0.0, window=None, second=None, pair=False):
     """One-pass backward of the seq-major layouts (the packed q|k|v buffer
     and separate bshd arrays: the two differ in index maps only): grid
     (b·h_kv, group, nq, nk), kv blocks innermost. Every (q block, kv block)
@@ -747,7 +847,14 @@ def _bwd_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h, h_kv,
     over ALL the q heads of its k2 head: they are consecutive rows of the
     grid's first axis, which such a call therefore walks in order
     (``"arbitrary"``), and the accumulator is zeroed at the first and
-    written at the last of them."""
+    written at the last of them.
+
+    ``pair`` (static): the blocks hold two heads of 64 side by side (the
+    section on pairs above; ``h``, ``h_kv`` count pairs). A tile runs once a
+    head on q, k and dO with the other head's lanes zeroed, so every product
+    is that head's own and the (rows, 128) accumulators take both heads by
+    addition; lse comes as the pair's two lane rows and D is a plane a head
+    of a (2, 1, bq) scratch."""
     refs = list(refs)
     q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref = refs[:6]
     n = 6
@@ -784,7 +891,12 @@ def _bwd_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h, h_kv,
     def _visit():
         dq_scr[...] = jnp.zeros_like(dq_scr)
         prod = do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32)
-        delta_scr[...] = jnp.sum(prod.T, axis=0, keepdims=True)  # (1, bq)
+        if pair:  # a head's D from its own 64 of the transposed 128 rows
+            prod_t = prod.T
+            delta_scr[0] = jnp.sum(prod_t[:_PAIR_D], axis=0, keepdims=True)
+            delta_scr[1] = jnp.sum(prod_t[_PAIR_D:], axis=0, keepdims=True)
+        else:
+            delta_scr[...] = jnp.sum(prod.T, axis=0, keepdims=True)  # (1, bq)
 
     if second:
         # this q head's place among the q heads of its k2 head
@@ -813,12 +925,12 @@ def _bwd_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h, h_kv,
         run = jnp.logical_and(run, j * bk < kvlen)
         inner = jnp.logical_and(inner, (j + 1) * bk <= kvlen)
 
-    def _tile(masked):
+    def _tile(masked, hd=None):
         # bf16 MXU operands, fp32 accumulation (see _fwd_kernel)
-        q = q_ref[0]
-        k = k_ref[0]
+        q = _pair_lanes(q_ref[0], hd)
+        k = _pair_lanes(k_ref[0], hd)
         v = v_ref[0]
-        do = do_ref[0]
+        do = _pair_lanes(do_ref[0], hd)
         st = jax.lax.dot_general(
             k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -837,9 +949,11 @@ def _bwd_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h, h_kv,
                 st = jnp.where(_visible(rows, cols, window), st, NEG_INF)
             if varlen:
                 st = jnp.where(cols < kvlen, st, NEG_INF)
-        pt = jnp.exp(st - lse_ref[0, 0])
+        pt = jnp.exp(st - lse_ref[0, hd or 0])
         if rate > 0.0:
             t = (r // h_kv) * h + (r % h_kv) * group + g  # the forward's row
+            if hd is not None:
+                t = 2 * t + hd
             ms = _mask_scale(seed_ref[0], t, i, j, bq, bk, rate,
                              transposed=True)
             pd = pt * ms  # dropped+rescaled probs: dV = Pdᵀ dO
@@ -853,7 +967,8 @@ def _bwd_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h, h_kv,
             v, do, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
         if rate > 0.0:
             dpt = dpt * ms
-        dst = (pt * (dpt - delta_scr[...])).astype(q.dtype)  # dSᵀ / scale
+        delta = delta_scr[...] if hd is None else delta_scr[hd]
+        dst = (pt * (dpt - delta)).astype(q.dtype)  # dSᵀ / scale
         dk_scr[kv_rows, :] += jax.lax.dot_general(
             dst, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -868,12 +983,16 @@ def _bwd_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h, h_kv,
                 dst, k2, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
+    def _tiles(masked):
+        for hd in (0, 1) if pair else (None,):
+            _tile(masked, hd)
+
     if not causal and not varlen:
-        _tile(False)
+        _tiles(False)
     else:
-        pl.when(jnp.logical_and(run, inner))(lambda: _tile(False))
+        pl.when(jnp.logical_and(run, inner))(lambda: _tiles(False))
         pl.when(jnp.logical_and(run, jnp.logical_not(inner)))(
-            lambda: _tile(True))
+            lambda: _tiles(True))
 
     @pl.when(jj == nk - 1)
     def _write_dq():
@@ -903,14 +1022,22 @@ def _bwd_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h, h_kv,
             jax.lax.fori_loop(0, dk2_scr.shape[0] // bk, block, 0)
 
 
-def _fused_bwd_fits(bias, rel_bias, sq, sk, d, bq, bk, itemsize, dv=None, d2=0):
+def _fused_bwd_fits(bias, rel_bias, sq, sk, d, bq, bk, itemsize, dv=None, d2=0, heads=1):
     """The rule that picks the one-pass backward, read from the operands:
     no score bias of either kind (the dbias / dtable kernels take D as an
     operand), one sequence length (``sq == sk``: the tile walk assumes the
     diagonal starts at the origin), and accumulators, blocks and tile
     temporaries inside the VMEM a kernel may ask for."""
     return (bias is None and rel_bias is None and sq == sk
-            and _fused_bwd_vmem_bytes(sq, d, bq, bk, itemsize, dv, d2) <= _VMEM_CAP)
+            and _fused_bwd_vmem_bytes(sq, d, bq, bk, itemsize, dv, d2, heads) <= _VMEM_CAP)
+
+
+def packed_pair_fits(s, itemsize):
+    """Whether a pair call (:func:`packed_pair`) of ``s`` positions has its
+    backward: the one-pass kernel only, so the (s, 128) accumulators of a kv
+    pair must pass :func:`_fused_bwd_fits` at the blocks the call takes."""
+    blk = _fit_block(s, _pair_block(s))
+    return _fused_bwd_fits(None, None, s, s, _PAIR_LANES, blk, blk, itemsize, heads=2)
 
 
 def bshd_two_width_fits(sq, sk, d, dv, d2, itemsize):
@@ -933,8 +1060,14 @@ def _flash_bwd_fused(q3, k3, v3, o3, lse, do3, *, h, h_kv, d, packed, scale,
     ``second = (q2 (b, h, s, d2), k2 (b, h2, s, d2))``, head-major: the second
     score term; the call is then ``flash_bwd_bshd_mla_fused`` and returns
     dq2 and dk2 in those shapes after dv. v, o and do may be narrower or
-    wider than q and k (``dv`` from v3's width)."""
+    wider than q and k (``dv`` from v3's width). A packed buffer of heads of
+    64 (:func:`packed_pair`) is walked as half as many heads of 128, two lse
+    rows a block: ``flash_bwd_packed_pair_fused``."""
     b, s, _ = q3.shape
+    pair = packed and packed_pair(h, h_kv, d)
+    if pair:
+        h, h_kv, d = h // 2, h_kv // 2, _PAIR_LANES
+    per = 2 if pair else 1               # heads a block
     group = h // h_kv
     dv = d if packed else v3.shape[-1] // h_kv
     d2 = 0 if second is None else second[0].shape[-1]
@@ -968,7 +1101,7 @@ def _flash_bwd_fused(q3, k3, v3, o3, lse, do3, *, h, h_kv, d, packed, scale,
                 pl.BlockSpec((1, bk, dv), vm),
                 pl.BlockSpec((1, bq, dv), qm),
                 pl.BlockSpec((1, bq, dv), qm),
-                pl.BlockSpec((1, 1, 1, bq),
+                pl.BlockSpec((1, per, 1, bq),
                              lambda r, g, i, j: (r // h_kv, head(r, g), 0, i))]
     out_specs = [pl.BlockSpec((1, bq, d), qm),
                  pl.BlockSpec((1, s, d), dkm),
@@ -979,7 +1112,7 @@ def _flash_bwd_fused(q3, k3, v3, o3, lse, do3, *, h, h_kv, d, packed, scale,
     scratch_shapes = [pltpu.VMEM((bq, d), jnp.float32),
                       pltpu.VMEM((s, d), jnp.float32),
                       pltpu.VMEM((s, dv), jnp.float32),
-                      pltpu.VMEM((1, bq), jnp.float32)]
+                      pltpu.VMEM((2, 1, bq) if pair else (1, bq), jnp.float32)]
     # dk/dv accumulate across the group, the q blocks and the kv blocks of
     # one (batch, kv head) row: all three stay sequential
     semantics = ("parallel", "arbitrary", "arbitrary", "arbitrary")
@@ -1005,11 +1138,12 @@ def _flash_bwd_fused(q3, k3, v3, o3, lse, do3, *, h, h_kv, d, packed, scale,
                           causal=causal, bq=bq, bk=bk, nq=nq, nk=steps,
                           group=group, h=h, h_kv=h_kv,
                           varlen=kv_lens is not None, rate=dropout_rate,
-                          window=window, second=share),
-        name="flash_bwd_packed_fused" if packed else (
-            "flash_bwd_bshd_mla_fused" if second is not None else (
-                "flash_bwd_bshd_fused" if window is None
-                else "flash_bwd_bshd_win_fused")),
+                          window=window, second=share, pair=pair),
+        name="flash_bwd_packed_pair_fused" if pair else (
+            "flash_bwd_packed_fused" if packed else (
+                "flash_bwd_bshd_mla_fused" if second is not None else (
+                    "flash_bwd_bshd_fused" if window is None
+                    else "flash_bwd_bshd_win_fused"))),
         grid=(b * h_kv, group, nq, steps),
         in_specs=in_specs + tail_specs,
         out_specs=out_specs,
@@ -1018,13 +1152,13 @@ def _flash_bwd_fused(q3, k3, v3, o3, lse, do3, *, h, h_kv, d, packed, scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics,
             vmem_limit_bytes=_vmem_limit(_fused_bwd_vmem_bytes(
-                s, d, bq, bk, q3.dtype.itemsize, dv, d2))),
+                s, d, bq, bk, q3.dtype.itemsize, dv, d2, heads=per))),
         interpret=interpret,
     )(q3, k3, v3, do3, o3, lse_rows, *(second or ()), *tail_args)
 
 
 def flash_bwd_packed(qkv, h, h_kv, d, o, lse, do, *, scale, causal,
-                     kv_lens=None, bias=None, bq=1024, bk=1024,
+                     kv_lens=None, bias=None, bq=None, bk=None,
                      interpret=False, dropout_rate=0.0, dropout_seed=None):
     """Backward of :func:`flash_fwd_packed`: returns SEPARATE folded grads
     (dq (b, s, h·d), dk/dv (b, s, h_kv·d)) — the caller contracts each
@@ -1048,16 +1182,29 @@ def flash_bwd_packed(qkv, h, h_kv, d, o, lse, do, *, scale, causal,
     it.
 
     ``bias`` (hb, s, s), hb | h: adds a fourth output dbias (hb, s, s)
-    fp32 (see :func:`flash_bwd`)."""
+    fp32 (see :func:`flash_bwd`).
+
+    Heads of 64 in pairs (:func:`packed_pair`) take the one kernel too, as
+    ``flash_bwd_packed_pair_fused``; they have no split, so no ``bias`` and
+    no sequence past :func:`packed_pair_fits`. ``bq`` / ``bk`` None: 1,024,
+    of a pair call :func:`_pair_block` of the sequence length."""
     b, s, _ = qkv.shape
     group = h // h_kv
+    pair = packed_pair(h, h_kv, d)
+    if pair:  # the one-pass kernel only
+        _check_pair(bias)
+    bq, bk = (blk or (_pair_block(s) if pair else 1024) for blk in (bq, bk))
     bq, bk = _bias_blocks(bias, bq, bk)
     bq, bk = _fit_block(s, bq), _fit_block(s, bk)
     nq, nk = _blocks(s, bq), _blocks(s, bk)
     varlen = kv_lens is not None
     hb = 0 if bias is None else bias.shape[0]
 
-    if _fused_bwd_fits(bias, None, s, s, d, bq, bk, qkv.dtype.itemsize):
+    if pair and not packed_pair_fits(s, qkv.dtype.itemsize):
+        raise NotImplementedError(
+            f"heads of 64 in pairs have the one-pass backward only, and {s} "
+            f"positions do not fit it (packed_pair_fits)")
+    if pair or _fused_bwd_fits(bias, None, s, s, d, bq, bk, qkv.dtype.itemsize):
         return _flash_bwd_fused(
             qkv, qkv, qkv, o, lse, do, h=h, h_kv=h_kv, d=d, packed=True,
             scale=scale, causal=causal, kv_lens=kv_lens, bq=bq, bk=bk,

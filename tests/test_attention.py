@@ -321,17 +321,20 @@ class TestGroupedQueryAttention:
 
     @pytest.mark.pallas
     @pytest.mark.parametrize("causal", [True, False])
-    @pytest.mark.parametrize("kv_heads", [4, 2])
-    def test_fused_qkv_attention_matches_composition(self, kv_heads, causal,
+    @pytest.mark.parametrize("kv_heads, d", [(4, 16), (2, 16), (4, 64)])
+    def test_fused_qkv_attention_matches_composition(self, kv_heads, d, causal,
                                                      monkeypatch):
         """The flagship's zero-layout-copy block (packed projection →
         window-reading kernels → output GEMM, hand-written VJP): forward
         and EVERY cotangent (x, packed weight, packed bias, out weight)
-        against the composed einsum+dense formulation."""
+        against the composed einsum+dense formulation. Four heads of 64 ride
+        the pair kernels, two heads to a 128-lane block, at blocks of 128 of
+        256 positions: causal, the four tiles of a pair are one skipped, one
+        fully visible and two crossed by the diagonal."""
         monkeypatch.setenv("APEX_TPU_PALLAS", "interpret")
         from apex_tpu.ops.attention import fused_qkv_attention
 
-        b, s, H, h, d = 2, 256, 64, 4, 16
+        b, s, H, h = 2, 256, 64, 4
         hkv = kv_heads
         G = h + 2 * hkv
         key = jr.fold_in(K, 31)
@@ -371,6 +374,35 @@ class TestGroupedQueryAttention:
         for a, e, name in zip(g1, g2, ("dx", "dw_qkv", "db_qkv", "dw_out")):
             np.testing.assert_allclose(a, e, rtol=3e-4, atol=3e-4,
                                        err_msg=name)
+        names = str(jax.make_jaxpr(jax.grad(loss1))(x, w_qkv, b_qkv, w_out))
+        assert ("flash_bwd_packed_pair_fused" in names) == (d == 64)
+
+    def test_packed_eligibility_rule(self):
+        """``fused_qkv_attention``'s gate: the folded rule, or heads of 64 in
+        pairs — an even number of them, no grouped kv, a sequence of whole
+        128-blocks that fits the one-pass backward, never float16."""
+        from apex_tpu.ops.attention import packed_kernel_ok
+        from apex_tpu.ops.pallas import attention as pk
+
+        assert packed_kernel_ok(1024, 16, 16, 64, jnp.bfloat16)
+        assert packed_kernel_ok(1024, 16, 16, 64, jnp.float32)
+        assert not packed_kernel_ok(1024, 15, 15, 64, jnp.bfloat16)    # an odd count (tp)
+        assert not packed_kernel_ok(1024, 16, 8, 64, jnp.bfloat16)     # grouped kv
+        assert not packed_kernel_ok(1024, 16, 16, 64, jnp.float16)
+        assert not packed_kernel_ok(1000, 16, 16, 64, jnp.bfloat16)
+        assert not packed_kernel_ok(65536, 16, 16, 64, jnp.bfloat16)   # past the accumulators
+        assert not packed_kernel_ok(1024, 16, 16, 32, jnp.bfloat16)
+        # heads of 128 and the single head of 64: as bshd_kernel_ok has them
+        assert packed_kernel_ok(8192, 16, 2, 128, jnp.bfloat16)
+        assert packed_kernel_ok(1024, 1, 1, 64, jnp.bfloat16)
+        assert not packed_kernel_ok(1024, 8, 8, 128, jnp.float16)
+        assert not packed_kernel_ok(1000, 8, 8, 128, jnp.bfloat16)
+        # the blocks of a pair call, from the sequence length alone
+        assert [pk._pair_block(s) for s in (128, 256, 1024, 2048, 8192)] == [
+            128, 128, 512, 1024, 1024]
+        with pytest.raises(ValueError, match="no score bias"):
+            pk.flash_fwd_packed(jnp.zeros((1, 128, 6 * 64)), 2, 2, 64, scale=1.0,
+                                causal=True, bias=jnp.zeros((1, 128, 128)))
 
     def test_causal_sq_gt_sk_raises(self):
         """ADVICE r2: bottom-right causal with sq > sk has rows attending

@@ -39,7 +39,10 @@ EXPECTED = {
         "flash_bwd_bshd_win_dq", "flash_bwd_bshd_win_dkv",
         # two head widths and a second score term (latent attention): the same
         # two bodies, still flash_fwd* / flash_bwd*
-        "flash_fwd_bshd_mla", "flash_bwd_bshd_mla_fused"},
+        "flash_fwd_bshd_mla", "flash_bwd_bshd_mla_fused",
+        # the packed pair at heads of 64, two to a lane tile: the same two
+        # bodies once a head, still flash_fwd* / flash_bwd*
+        "flash_fwd_packed_pair", "flash_bwd_packed_pair_fused"},
     "xentropy.py": {"xentropy_stats"},
     "decode_attention.py": {"decode_attn", "decode_attn_paged"},
     "layer_norm.py": {"ln_fwd", "ln_bwd"},
@@ -96,7 +99,7 @@ def test_every_pallas_call_has_a_literal_name(filename):
 def test_kernel_names_are_distinct_across_the_package():
     assert set(KERNEL_FILES) == set(EXPECTED)
     names = [n for f in KERNEL_FILES for n in literal_names(f)]
-    assert len(names) == 47 and len(set(names)) == 47
+    assert len(names) == 49 and len(set(names)) == 49
 
 
 def all_eqns(jaxpr):
@@ -302,6 +305,33 @@ def test_packed_flash_equations_carry_their_names(seq, biased, names):
         jnp.ones((1, seq, H)), jnp.ones(((h + 2 * h_kv) * d, H)),
         jnp.ones(((h + 2 * h_kv) * d,)), jnp.ones((H, h * d)))
     assert kernel_names(jaxpr.jaxpr) == names
+
+
+@pytest.mark.parametrize("heads,kv_heads,names", [
+    # gpt2-medium's kind: heads of 64, an even number — two to a lane tile
+    (4, 4, ["flash_fwd_packed_pair", "flash_bwd_packed_pair_fused"]),
+    # what the pair rule leaves on the head-batched route and the flat kernels
+    (3, 3, ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]),      # an odd (local) count
+    (4, 2, ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"]),      # grouped kv
+])
+def test_gpt_at_heads_of_64_takes_the_pair_kernels(heads, kv_heads, names, monkeypatch):
+    """``GPTModel._attention`` at heads of 64 chooses by ``packed_kernel_ok``
+    from shapes alone: the pair kernels (every one of them holding the
+    readers' ``flash_fwd`` / ``flash_bwd``) and never the dq | dkv split,
+    or the route it had."""
+    monkeypatch.setenv("APEX_TPU_PALLAS", "interpret")
+    from apex_tpu.models import GPTConfig, GPTModel
+
+    model = GPTModel(GPTConfig(vocab_size=128, max_seq_len=128, hidden_size=64 * heads,
+                               ffn_hidden_size=128, num_layers=1, num_heads=heads,
+                               num_kv_heads=kv_heads, tp_size=1, scan_layers=False,
+                               attention_impl="flash", remat=False))
+    params = model.init(jax.random.PRNGKey(0))
+    tokens = jnp.zeros((1, 128), jnp.int32)
+    jaxpr = jax.make_jaxpr(jax.grad(model.loss_fn))(params, tokens, tokens)
+    got = [n for n in kernel_names(jaxpr.jaxpr) if n.startswith("flash")]
+    assert got == names
+    assert all(any(part in n for part in READER_PARTS[:2]) for n in got)
 
 
 @pytest.mark.parametrize("seq,names", [

@@ -358,6 +358,97 @@ def _flash_cell_family(h, h_kv, d, window):
     return build
 
 
+# ``gpt2m-train-1k-dp4``'s attention a chip: 8 rows of 1,024, 16 heads of 64
+PAIR_CELL = dict(b=8, s=1024, h=16, d=64)
+
+
+def _pair_cell_family():
+    """``fused_qkv_attention`` at ``gpt2m-train-1k-dp4``'s shape (hidden
+    1,024 = 16 heads of 64, two to a lane tile: ``flash_fwd_packed_pair`` /
+    ``flash_bwd_packed_pair_fused``), the value and every gradient against
+    separate projections around XLA's attention."""
+    def build():
+        b, s, h, d = (PAIR_CELL[k] for k in "bshd")
+        hid = h * d
+        x = jr.normal(_key(5), (b, s, hid), jnp.bfloat16)
+        w_qkv = jr.normal(_key(6), (3 * hid, hid), jnp.bfloat16) * 0.02
+        b_qkv = jr.normal(_key(7), (3 * hid,), jnp.bfloat16) * 0.02
+        w_out = jr.normal(_key(8), (hid, hid), jnp.bfloat16) * 0.02
+
+        def kernel(x, w_qkv, b_qkv, w_out):
+            return fused_qkv_attention(x, w_qkv, b_qkv, w_out, None, None, None,
+                                       h, h, d, d ** -0.5, True, 0.0)
+
+        def reference(x, w_qkv, b_qkv, w_out):
+            q, k, v = bshd_qkv_projection(x, w_qkv, b_qkv, h, h, d)
+            ctx = flash_attention(q, k, v, causal=True, layout="bshd", impl="xla")
+            return bshd_output_projection(ctx, w_out, h, d)
+
+        return (_fwd_and_grads(kernel, (0, 1, 2, 3)),
+                _fwd_and_grads(reference, (0, 1, 2, 3)), (x, w_qkv, b_qkv, w_out))
+    return build
+
+
+def _device_ms(fn, *args, calls=5):
+    """``(total, by name)``: device milliseconds a call of jitted ``fn``, from
+    a profiler trace of ``calls`` calls — every operation's, and each
+    operation's by its instruction name. Not the host clock, which under
+    0.2 ms reads the dispatch floor."""
+    import tempfile
+    from apex_tpu.prof.scopes import read_xplane
+    fn = jax.jit(fn)
+    jax.block_until_ready(fn(*args))
+    with tempfile.TemporaryDirectory() as logdir:
+        jax.profiler.start_trace(logdir)
+        out = [fn(*args) for _ in range(calls)]
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        ops_s, _, _ = read_xplane(logdir)
+    by_name = {name: 1e3 * took / calls for name, took in ops_s.items()}
+    return sum(by_name.values()), by_name
+
+
+def _pair_cell_times():
+    """The attention of one layer at the cell's shape, forward + backward from
+    q, k, v (or the packed buffer) and a cotangent, by device time: XLA's
+    composition, the flat kernels, the pair kernels at three blocks."""
+    def report():
+        from apex_tpu.ops.pallas import attention as pk
+        b, s, h, d = (PAIR_CELL[k] for k in "bshd")
+        q, k, v = (jr.normal(_key(i), (b, h, s, d), jnp.bfloat16) for i in (1, 2, 3))
+        qkv = jr.normal(_key(4), (b, s, 3 * h * d), jnp.bfloat16)
+        do = jr.normal(_key(9), (b, s, h * d), jnp.bfloat16)
+
+        def flat(impl):
+            return _fwd_and_grads(lambda q, k, v: flash_attention(
+                q, k, v, causal=True, impl=impl), (0, 1, 2))
+
+        def pair(block):
+            kw = dict(scale=d ** -0.5, causal=True, bq=block, bk=block,
+                      interpret=_backend.interpret_mode())
+
+            def run(qkv, do):
+                o, lse = pk.flash_fwd_packed(qkv, h, h, d, full_lse=True, **kw)
+                return o, pk.flash_bwd_packed(qkv, h, h, d, o, lse, do, **kw)
+            return run
+
+        def parts(by_name):
+            fwd = sum(t for n, t in by_name.items() if "flash_fwd" in n)
+            bwd = sum(t for n, t in by_name.items() if "flash_bwd" in n)
+            return f"flash_fwd* {fwd:.3f} + flash_bwd* {bwd:.3f}" if fwd else "no kernel"
+
+        lines = []
+        for name, fn, args in (
+                ("XLA composition (b, h, s, d)", flat("xla"), (q, k, v)),
+                ("flat kernels flash_fwd + flash_bwd_dq/dkv", flat("pallas"), (q, k, v)),
+                *((f"pair kernels at blocks of {blk}", pair(blk), (qkv, do))
+                  for blk in (1024, 512, 256))):
+            total, by_name = _device_ms(fn, *args)
+            lines.append(f"{name}: {total:.3f} ms a layer on the device ({parts(by_name)})")
+        return lines
+    return report
+
+
 def _latent_cell_family(s=8192):
     """The two-width kernels at ``dsv2lite-train-8k``'s attention shape, 2
     rows of 8,192, 16 heads of 128 (+ 64 rotary features against ONE shared
@@ -769,6 +860,8 @@ FAMILIES = (
            _flash_cell_family(16, 2, 256, None)),
     Family("flash bshd latent 8,192, 16 heads of 128 + 64 shared / 128, fwd + one-pass bwd",
            _latent_cell_family()),
+    Family("flash packed pair 8 x 1,024, 16 heads of 64 two to a lane tile, fwd + one-pass bwd",
+           _pair_cell_family(), timings=_pair_cell_times()),
     Family("gated delta rule gdn_fwd/gdn_bwd", _delta_rule_family()),
     Family("gated delta rule on drifted keys, against the recurrence",
            _delta_rule_drifted_family()),
