@@ -20,6 +20,7 @@ from apex_tpu.optimizers import fused_adam  # noqa: E402
 from apex_tpu.transformer.moe import router_bias_update  # noqa: E402
 from benchmarks.adapters import afmoe_tree  # noqa: E402
 from benchmarks.reference import afmoe_ref as R  # noqa: E402
+from comparisons import batch, close  # noqa: E402
 
 PERIOD = ["sliding_attention"] * 3 + ["full_attention"]
 # the published order cut as the cell cuts it: layer 0 (dense) and one period;
@@ -37,17 +38,6 @@ def build(**settings):
     d = R.dims(TOY)
     model = HybridDecoderModel(HybridDecoderConfig(**afmoe_tree.config_kwargs(d, **settings)))
     return d, model, R.make_weights(d, R.seed_key(3))
-
-
-def batch(rows=2, seq=96, seed=0):
-    rng = np.random.default_rng(seed)
-    return (jnp.asarray(rng.integers(0, 256, (rows, seq)), jnp.int32),
-            jnp.asarray(rng.integers(0, 256, (rows, seq)), jnp.int32))
-
-
-def close(got, want, tol, name=""):
-    np.testing.assert_allclose(got, want, err_msg=name,
-                               atol=tol * float(jnp.max(jnp.abs(want))) + 1e-9)
 
 
 def test_dims_cut_the_published_order_as_the_cell_does():
@@ -69,33 +59,43 @@ def test_attention_mixers_match_the_reference(kind):
     p = jax.tree.map(lambda a: a[i], afmoe_tree.to_program(w)["layers"]["attn"])
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 80, 128))
     with jax.default_matmul_precision("highest"):
-        want = jax.vmap(lambda s: R.attention_mixer(lw, d, s, kind, "float32", 16))(x)
-        close(model._attention_mixer(p, x, kind), want, 2e-5)
+        want = jax.jit(jax.vmap(lambda s: R.attention_mixer(lw, d, s, kind, "float32", 16)))(x)
+        mixer = jax.jit(model._attention_mixer, static_argnums=2)
+        close(mixer(p, x, kind), want, 2e-5)
         other = "full" if kind == "window" else "window"
-        assert float(jnp.max(jnp.abs(model._attention_mixer(p, x, other) - want))) > 1e-3
+        assert float(jnp.max(jnp.abs(mixer(p, x, other) - want))) > 1e-3
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_loss_and_every_gradient_match_the_reference(impl):
-    """Rows of 128 against a window of 24: the banded kernels in interpret
-    mode on three layers, the unbanded on the fourth; the bias off zero."""
-    d, model, w = build(attention_impl=impl, experts_impl=impl)
-    p = afmoe_tree.to_program(w)
-    assert jax.tree.structure(p) == jax.tree.structure(model.init(jax.random.PRNGKey(0)))
+@pytest.fixture(scope="module")
+def reference():
+    d, model, w = build()
     tokens, targets = batch(2, 128)
     bias = 0.02 * jax.random.normal(jax.random.PRNGKey(1), model.init_router_bias().shape)
     with jax.default_matmul_precision("highest"):
-        (loss, aux), g = jax.value_and_grad(
+        (want, counts), gr = jax.jit(jax.value_and_grad(
+            lambda w: R.loss(w, bias, d, tokens, targets), has_aux=True))(w)
+    return bias, float(want), np.asarray(counts), afmoe_tree.to_program(gr)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_loss_and_every_gradient_match_the_reference(impl, reference):
+    """Rows of 128 against a window of 24: the banded kernels in interpret
+    mode on three layers, the unbanded on the fourth; the bias off zero."""
+    bias, want, counts, want_g = reference
+    d, model, w = build(attention_impl=impl, experts_impl=impl)
+    p = afmoe_tree.to_program(w)
+    assert jax.tree.structure(p) == jax.tree.structure(model.init(jax.random.PRNGKey(0)))
+    assert bias.shape == model.init_router_bias().shape
+    tokens, targets = batch(2, 128)
+    with jax.default_matmul_precision("highest"):
+        (loss, aux), g = jax.jit(jax.value_and_grad(
             lambda p: model.loss_fn(p, tokens, targets, return_aux=True, router_bias=bias),
-            has_aux=True)(p)
-        (want, counts), gr = jax.value_and_grad(
-            lambda w: R.loss(w, bias, d, tokens, targets), has_aux=True)(w)
-        unbiased = model.loss_fn(p, tokens, targets)
-    assert abs(float(loss) - float(want)) < 2e-5 and abs(float(unbiased) - float(want)) > 1e-5
+            has_aux=True))(p)
+        unbiased = jax.jit(model.loss_fn)(p, tokens, targets)
+    assert abs(float(loss) - want) < 2e-5 and abs(float(unbiased) - want) > 1e-5
     np.testing.assert_array_equal(aux["router_counts"], counts)
     np.testing.assert_array_equal(aux["expert_load"], counts[:, 4:12])
     assert int(aux["dropped"]) == 0 and float(aux["load_balance_loss"]) == 0.0
-    want_g = afmoe_tree.to_program(gr)
     for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(g)[0], jax.tree.leaves(want_g)):
         close(a, b, 2e-4 if impl == "pallas" else 2e-5, jax.tree_util.keystr(path))
 
@@ -144,11 +144,12 @@ def test_remat_and_spans_leave_the_loss_alone():
     _, again, _ = build(attention_impl="xla", experts_impl="xla", remat=True)
     p = afmoe_tree.to_program(w)
     tokens, targets = batch(1, 64)
-    g = jax.grad(model.loss_fn)(p, tokens, targets)
-    gr = jax.grad(again.loss_fn)(p, tokens, targets)
+    grad = jax.jit(jax.grad(model.loss_fn))
+    g = grad(p, tokens, targets)
+    gr = jax.jit(jax.grad(again.loss_fn))(p, tokens, targets)
     for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(gr)):
         close(a, b, 1e-5)
-    text = jax.jit(jax.grad(model.loss_fn)).lower(p, tokens, targets).as_text(debug_info=True)
+    text = grad.lower(p, tokens, targets).as_text(debug_info=True)
     for scope in ("hybrid/attn_win", "hybrid/attn", "hybrid/dense", "hybrid/moe", "moe/route",
                   "moe/experts", "moe/shared"):
         assert scope in text, scope

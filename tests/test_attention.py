@@ -57,8 +57,8 @@ class TestFlashAttention:
         v = jr.normal(jr.fold_in(K, 4), (3, 32, 16))
         f1 = lambda q, k, v: jnp.sum(jnp.sin(flash_attention(q, k, v, causal=causal)))
         f2 = lambda q, k, v: jnp.sum(jnp.sin(dense_ref(q, k, v, causal)))
-        g1 = jax.grad(f1, argnums=(0, 1, 2))(q, k, v)
-        g2 = jax.grad(f2, argnums=(0, 1, 2))(q, k, v)
+        g1 = jax.jit(jax.grad(f1, argnums=(0, 1, 2)))(q, k, v)
+        g2 = jax.jit(jax.grad(f2, argnums=(0, 1, 2)))(q, k, v)
         for a, e in zip(g1, g2):
             np.testing.assert_allclose(a, e, rtol=G_RTOL, atol=G_ATOL)
 
@@ -86,8 +86,8 @@ class TestFlashAttention:
                                        rtol=2e-5, atol=2e-5)
             f1 = lambda q, k, v: jnp.sum(jnp.cos(flash_attention(q, k, v, causal=causal, impl="pallas")))
             f2 = lambda q, k, v: jnp.sum(jnp.cos(dense_ref(q, k, v, causal)))
-            g1 = jax.grad(f1, argnums=(0, 1, 2))(q, k, v)
-            g2 = jax.grad(f2, argnums=(0, 1, 2))(q, k, v)
+            g1 = jax.jit(jax.grad(f1, argnums=(0, 1, 2)))(q, k, v)
+            g2 = jax.jit(jax.grad(f2, argnums=(0, 1, 2)))(q, k, v)
         for a, e in zip(g1, g2):
             np.testing.assert_allclose(a, e, rtol=2e-4, atol=2e-4)
 
@@ -174,8 +174,8 @@ class TestGroupedQueryAttention:
             o = dense_ref(q, jnp.repeat(k, rep, 1), jnp.repeat(v, rep, 1), causal)
             return jnp.sum(jnp.sin(o))
 
-        g1 = jax.grad(f1, argnums=(0, 1, 2))(q, k, v)
-        g2 = jax.grad(f2, argnums=(0, 1, 2))(q, k, v)
+        g1 = jax.jit(jax.grad(f1, argnums=(0, 1, 2)))(q, k, v)
+        g2 = jax.jit(jax.grad(f2, argnums=(0, 1, 2)))(q, k, v)
         for a, e in zip(g1, g2):
             np.testing.assert_allclose(a, e, rtol=G_RTOL, atol=G_ATOL)
 
@@ -269,8 +269,8 @@ class TestGroupedQueryAttention:
             f1 = lambda q, k, v: jnp.sum(jnp.cos(flash_attention(
                 q, k, v, causal=causal, layout="bshd", impl="pallas")))
             f2 = lambda q, k, v: jnp.sum(jnp.cos(dense(q, k, v)))
-            g1 = jax.grad(f1, argnums=(0, 1, 2))(q, k, v)
-            g2 = jax.grad(f2, argnums=(0, 1, 2))(q, k, v)
+            g1 = jax.jit(jax.grad(f1, argnums=(0, 1, 2)))(q, k, v)
+            g2 = jax.jit(jax.grad(f2, argnums=(0, 1, 2)))(q, k, v)
         for a, e in zip(g1, g2):
             np.testing.assert_allclose(a, e, rtol=2e-4, atol=2e-4)
 
@@ -286,11 +286,11 @@ class TestGroupedQueryAttention:
         ref = t(dense_ref(t(q), jnp.repeat(t(k), 2, 1),
                           jnp.repeat(t(v), 2, 1), causal))
         np.testing.assert_allclose(o, ref, rtol=RTOL, atol=ATOL)
-        g = jax.grad(lambda q: jnp.sum(flash_attention(
-            q, k, v, causal=causal, layout="bshd") ** 2))(q)
-        gref = jax.grad(lambda q: jnp.sum(t(dense_ref(
+        g = jax.jit(jax.grad(lambda q: jnp.sum(flash_attention(
+            q, k, v, causal=causal, layout="bshd") ** 2)))(q)
+        gref = jax.jit(jax.grad(lambda q: jnp.sum(t(dense_ref(
             t(q), jnp.repeat(t(k), 2, 1), jnp.repeat(t(v), 2, 1),
-            causal)) ** 2))(q)
+            causal)) ** 2)))(q)
         np.testing.assert_allclose(g, gref, rtol=G_RTOL, atol=G_ATOL)
 
     def test_bshd_rejects_bad_lens_shape_and_bad_rank(self):
@@ -367,9 +367,9 @@ class TestGroupedQueryAttention:
 
             loss1 = lambda *a: jnp.sum(jnp.sin(fused(*a)))
             loss2 = lambda *a: jnp.sum(jnp.sin(composed(*a)))
-            g1 = jax.grad(loss1, argnums=(0, 1, 2, 3))(
+            g1 = jax.jit(jax.grad(loss1, argnums=(0, 1, 2, 3)))(
                 x, w_qkv, b_qkv, w_out)
-            g2 = jax.grad(loss2, argnums=(0, 1, 2, 3))(
+            g2 = jax.jit(jax.grad(loss2, argnums=(0, 1, 2, 3)))(
                 x, w_qkv, b_qkv, w_out)
         for a, e, name in zip(g1, g2, ("dx", "dw_qkv", "db_qkv", "dw_out")):
             np.testing.assert_allclose(a, e, rtol=3e-4, atol=3e-4,
@@ -460,8 +460,8 @@ class TestVarlenAttention:
         f1 = lambda q, k, v: jnp.sum(jnp.sin(
             flash_attention(q, k, v, causal=True, kv_lens=lens)))
         f2 = lambda q, k, v: jnp.sum(jnp.sin(self._oracle(q, k, v, lens, True)))
-        g1 = jax.grad(f1, argnums=(0, 1, 2))(q, k, v)
-        g2 = jax.grad(f2, argnums=(0, 1, 2))(q, k, v)
+        g1 = jax.jit(jax.grad(f1, argnums=(0, 1, 2)))(q, k, v)
+        g2 = jax.jit(jax.grad(f2, argnums=(0, 1, 2)))(q, k, v)
         for a, e in zip(g1, g2):
             np.testing.assert_allclose(a, e, rtol=G_RTOL, atol=G_ATOL)
 
@@ -484,8 +484,8 @@ class TestVarlenAttention:
                 q, k, v, causal=True, kv_lens=lens, impl="pallas")))
             f2 = lambda q, k, v: jnp.sum(jnp.cos(
                 self._oracle(q, k, v, lens, True)))
-            g1 = jax.grad(f1, argnums=(0, 1, 2))(q, k, v)
-            g2 = jax.grad(f2, argnums=(0, 1, 2))(q, k, v)
+            g1 = jax.jit(jax.grad(f1, argnums=(0, 1, 2)))(q, k, v)
+            g2 = jax.jit(jax.grad(f2, argnums=(0, 1, 2)))(q, k, v)
         for a, e in zip(g1, g2):
             np.testing.assert_allclose(a, e, rtol=2e-4, atol=2e-4)
 
@@ -578,18 +578,18 @@ class TestRingAttention:
 
         qs, ks, vs = ((zigzag_shard(x, cp, 1) for x in (q, k, v))
                       if causal else (q, k, v))
-        g = mesh_lib.shard_map(
+        g = jax.jit(mesh_lib.shard_map(
             lambda q, k, v: jax.grad(local_loss, argnums=(0, 1, 2))(q, k, v),
             mesh=mesh,
             in_specs=(P(None, "cp"),) * 3,
             out_specs=(P(None, "cp"),) * 3,
-        )(qs, ks, vs)
+        ))(qs, ks, vs)
         if causal:
             g = tuple(zigzag_unshard(x, cp, 1) for x in g)
-        gref = jax.grad(
+        gref = jax.jit(jax.grad(
             lambda q, k, v: jnp.sum(dense_ref(q, k, v, causal) ** 2),
             argnums=(0, 1, 2),
-        )(q, k, v)
+        ))(q, k, v)
         for a, e in zip(g, gref):
             np.testing.assert_allclose(a, e, rtol=G_RTOL, atol=G_ATOL)
 
@@ -607,12 +607,12 @@ class TestRingAttention:
             return jnp.sum(ring_attention(q, k, v, causal=True) ** 2)
 
         qs, ks, vs = (zigzag_shard(x, cp, 1) for x in (q, k, v))
-        g = mesh_lib.shard_map(
+        g = jax.jit(mesh_lib.shard_map(
             lambda q, k, v: jax.grad(local_loss, argnums=(0, 1, 2))(q, k, v),
             mesh=mesh,
             in_specs=(P(None, "cp"),) * 3,
             out_specs=(P(None, "cp"),) * 3,
-        )(qs, ks, vs)
+        ))(qs, ks, vs)
         g = tuple(zigzag_unshard(x, cp, 1) for x in g)
         rep = hq // kvh
 
@@ -620,7 +620,7 @@ class TestRingAttention:
             return jnp.sum(dense_ref(q, jnp.repeat(k, rep, 0),
                                      jnp.repeat(v, rep, 0), True) ** 2)
 
-        gref = jax.grad(dense_loss, argnums=(0, 1, 2))(q, k, v)
+        gref = jax.jit(jax.grad(dense_loss, argnums=(0, 1, 2)))(q, k, v)
         for a, e in zip(g, gref):
             np.testing.assert_allclose(a, e, rtol=G_RTOL, atol=G_ATOL)
 
@@ -751,17 +751,17 @@ class TestUlyssesAttention:
             o = ulysses_attention(q, k, v, causal=True)
             return jnp.sum(o * o)
 
-        g = mesh_lib.shard_map(
+        g = jax.jit(mesh_lib.shard_map(
             lambda q, k, v: jax.grad(local_loss, argnums=(0, 1, 2))(q, k, v),
             mesh=mesh,
             in_specs=(P(None, "cp"),) * 3,
             out_specs=(P(None, "cp"),) * 3,
-        )(q, k, v)
+        ))(q, k, v)
         def ref_loss(q, k, v):
             o = dense_ref(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                           v.transpose(0, 2, 1, 3), True)
             return jnp.sum(o * o)
-        gref = jax.grad(ref_loss, argnums=(0, 1, 2))(q, k, v)
+        gref = jax.jit(jax.grad(ref_loss, argnums=(0, 1, 2)))(q, k, v)
         for a, e in zip(g, gref):
             np.testing.assert_allclose(a, e, rtol=G_RTOL, atol=G_ATOL)
 
@@ -933,8 +933,8 @@ class TestFlashDropout:
                                        rtol=2e-5, atol=2e-5)
             l1 = lambda *a: jnp.sum(jnp.sin(fused(*a)))
             l2 = lambda *a: jnp.sum(jnp.sin(composed(*a)))
-            g1 = jax.grad(l1, argnums=(0, 1, 2, 3))(x, w_qkv, b_qkv, w_out)
-            g2 = jax.grad(l2, argnums=(0, 1, 2, 3))(x, w_qkv, b_qkv, w_out)
+            g1 = jax.jit(jax.grad(l1, argnums=(0, 1, 2, 3)))(x, w_qkv, b_qkv, w_out)
+            g2 = jax.jit(jax.grad(l2, argnums=(0, 1, 2, 3)))(x, w_qkv, b_qkv, w_out)
         for a, e, n in zip(g1, g2, ("x", "w_qkv", "b_qkv", "w_out")):
             np.testing.assert_allclose(a, e, rtol=3e-4, atol=3e-5,
                                        err_msg=n)
@@ -995,7 +995,7 @@ class TestGPTFlashDropout:
         tgts = jr.randint(jr.fold_in(K, 72), (2, 128), 0, 64)
 
         loss_fn = lambda p, kk: m.loss_fn(p, toks, tgts, key=kk)
-        l1, g = jax.value_and_grad(loss_fn)(p, jr.PRNGKey(1))
+        l1, g = jax.jit(jax.value_and_grad(loss_fn))(p, jr.PRNGKey(1))
         l1b = loss_fn(p, jr.PRNGKey(1))
         l2 = loss_fn(p, jr.PRNGKey(2))
         l0 = m.loss_fn(p, toks, tgts)  # eval mode: no dropout
@@ -1040,8 +1040,8 @@ class TestVarlenFastPath:
                 scale)))
             np.testing.assert_allclose(float(f1(q, k, v)),
                                        float(ref(q, k, v)), rtol=1e-5)
-            g1 = jax.grad(f1, argnums=(0, 1, 2))(q, k, v)
-            g2 = jax.grad(ref, argnums=(0, 1, 2))(q, k, v)
+            g1 = jax.jit(jax.grad(f1, argnums=(0, 1, 2)))(q, k, v)
+            g2 = jax.jit(jax.grad(ref, argnums=(0, 1, 2)))(q, k, v)
         for a, e, n in zip(g1, g2, "qkv"):
             np.testing.assert_allclose(a, e, rtol=2e-4, atol=2e-5,
                                        err_msg=n)
@@ -1102,8 +1102,8 @@ class TestVarlenFastPath:
                                        rtol=2e-5, atol=2e-5)
             l1 = lambda *a: jnp.sum(jnp.sin(fused(*a)))
             l2 = lambda *a: jnp.sum(jnp.sin(composed(*a)))
-            g1 = jax.grad(l1, argnums=(0, 1, 2, 3))(x, w_qkv, b_qkv, w_out)
-            g2 = jax.grad(l2, argnums=(0, 1, 2, 3))(x, w_qkv, b_qkv, w_out)
+            g1 = jax.jit(jax.grad(l1, argnums=(0, 1, 2, 3)))(x, w_qkv, b_qkv, w_out)
+            g2 = jax.jit(jax.grad(l2, argnums=(0, 1, 2, 3)))(x, w_qkv, b_qkv, w_out)
         for a, e, n in zip(g1, g2, ("x", "w_qkv", "b_qkv", "w_out")):
             np.testing.assert_allclose(a, e, rtol=3e-4, atol=3e-5,
                                        err_msg=n)
@@ -1464,8 +1464,8 @@ class TestFlashBias:
             np.testing.assert_allclose(
                 o, self._dense_bias(q, k, v, bias, causal),
                 rtol=1e-4, atol=1e-4)
-            g1 = jax.grad(f, (0, 1, 2, 3))(q, k, v, bias)
-            g2 = jax.grad(ref, (0, 1, 2, 3))(q, k, v, bias)
+            g1 = jax.jit(jax.grad(f, (0, 1, 2, 3)))(q, k, v, bias)
+            g2 = jax.jit(jax.grad(ref, (0, 1, 2, 3)))(q, k, v, bias)
         for a, e, n in zip(g1, g2, ["dq", "dk", "dv", "dbias"]):
             np.testing.assert_allclose(a, e, rtol=5e-4, atol=5e-4,
                                        err_msg=n)
@@ -1500,8 +1500,8 @@ class TestFlashBias:
                                  kv_lens=lens, layout="bshd", impl="xla",
                                  dropout_rate=0.15, dropout_seed=7)
             np.testing.assert_allclose(o1, o2, rtol=5e-4, atol=5e-4)
-            g1 = jax.grad(make("pallas"), (0, 1, 2, 3))(q, k, v, bias)
-            g2 = jax.grad(make("xla"), (0, 1, 2, 3))(q, k, v, bias)
+            g1 = jax.jit(jax.grad(make("pallas"), (0, 1, 2, 3)))(q, k, v, bias)
+            g2 = jax.jit(jax.grad(make("xla"), (0, 1, 2, 3)))(q, k, v, bias)
         for a, e, n in zip(g1, g2, ["dq", "dk", "dv", "dbias"]):
             np.testing.assert_allclose(a, e, rtol=2e-3, atol=2e-3,
                                        err_msg=n)
@@ -1786,186 +1786,3 @@ class TestBucketedBias:
                 jnp.zeros((192,)), jnp.zeros((64, 64)),
                 BucketedBias(jnp.zeros((16, 1)), True, 64), None, None,
                 1, 1, 64, 0.125, True)
-
-
-class TestPackedOnePassBackward:
-    """``flash_bwd_packed`` without a bias is one kernel at any number of
-    blocks (``flash_bwd_packed_fused``): every score tile computed once,
-    dk/dv summed over the kv group in fp32 VMEM and written at kv width.
-    Checked against the gradients of the XLA composition (the same mask
-    hash, so dropout agrees bit for bit)."""
-
-    FULL, SHORT, DEAD = None, (512, 100), (0, 300)
-
-    @pytest.mark.pallas
-    @pytest.mark.parametrize(
-        "group,h_kv,causal,lens,rate,s,block,dtype",
-        [
-            # more than one block (4 x 4 tiles of 128), every group size
-            (1, 2, True, FULL, 0.0, 512, 128, jnp.float32),
-            (4, 2, True, FULL, 0.0, 512, 128, jnp.float32),
-            (16, 1, True, FULL, 0.0, 512, 128, jnp.float32),
-            (1, 2, False, FULL, 0.0, 512, 128, jnp.float32),
-            (4, 2, False, FULL, 0.0, 512, 128, jnp.float32),
-            (16, 1, False, FULL, 0.0, 512, 128, jnp.float32),
-            # kv_lens: one row shorter than a block, one of length 0
-            (4, 1, True, SHORT, 0.0, 512, 128, jnp.float32),
-            (4, 1, True, DEAD, 0.0, 512, 128, jnp.float32),
-            (4, 1, False, SHORT, 0.0, 512, 128, jnp.float32),
-            # dropout, same seed as the forward; with lengths too
-            (4, 1, True, FULL, 0.3, 512, 128, jnp.float32),
-            (2, 2, False, FULL, 0.3, 512, 128, jnp.float32),
-            (4, 1, True, DEAD, 0.3, 512, 128, jnp.float32),
-            # the sequence is one block, whatever block was asked for
-            (1, 2, True, FULL, 0.0, 128, 1024, jnp.float32),
-            (4, 1, True, FULL, 0.3, 128, 1024, jnp.float32),
-            (4, 1, False, (100, 128), 0.0, 128, 1024, jnp.float32),
-            # the training dtype: grads in bf16, the group summed in fp32
-            (16, 1, True, FULL, 0.0, 256, 128, jnp.bfloat16),
-        ])
-    def test_matches_xla_composition(self, group, h_kv, causal, lens, rate,
-                                     s, block, dtype):
-        from apex_tpu.ops.pallas import attention as pk
-
-        b, d = 2, 32
-        h = group * h_kv
-        key = jr.fold_in(K, 2500 + group)
-        qkv = jr.normal(key, (b, s, (h + 2 * h_kv) * d)).astype(dtype)
-        do = jr.normal(jr.fold_in(key, 1), (b, s, h * d)).astype(dtype)
-        kv_lens = None if lens is None else jnp.array(lens, jnp.int32)
-        seed = jnp.int32(77) if rate else None
-        scale = d ** -0.5
-        kw = dict(scale=scale, causal=causal, kv_lens=kv_lens, bq=block,
-                  bk=block, interpret=True, dropout_rate=rate,
-                  dropout_seed=seed)
-
-        def composition(q, k, v):
-            return flash_attention(
-                q, k, v, layout="bshd", impl="xla", causal=causal,
-                kv_lens=kv_lens, scale=scale, dropout_rate=rate,
-                dropout_seed=seed)
-
-        f32 = qkv.astype(jnp.float32)
-        q, k, v = (f32[..., :h * d].reshape(b, s, h, d),
-                   f32[..., h * d:(h + h_kv) * d].reshape(b, s, h_kv, d),
-                   f32[..., (h + h_kv) * d:].reshape(b, s, h_kv, d))
-        with jax.default_matmul_precision("highest"):
-            o, lse = pk.flash_fwd_packed(qkv, h, h_kv, d, full_lse=True, **kw)
-            got = pk.flash_bwd_packed(qkv, h, h_kv, d, o, lse, do, **kw)
-            _, vjp = jax.vjp(composition, q, k, v)
-            want = vjp(do.astype(jnp.float32).reshape(b, s, h, d))
-        assert len(got) == 3
-        tol = (dict(rtol=2e-4, atol=3e-5) if dtype == jnp.float32
-               else dict(rtol=3e-2, atol=6e-2))
-        for name, a, e, heads in zip(("dq", "dk", "dv"), got, want,
-                                     (h, h_kv, h_kv)):
-            assert a.shape == (b, s, heads * d) and a.dtype == dtype, name
-            np.testing.assert_allclose(
-                a.astype(jnp.float32), e.reshape(b, s, heads * d),
-                err_msg=name, **tol)
-
-
-class TestBshdOnePassBackward:
-    """``flash_bwd_bshd`` without a bias at one sequence length is the packed
-    layout's kernel over three arrays (``flash_bwd_bshd_fused``; on a window
-    ``flash_bwd_bshd_win_fused``, its kv axis the band's run of blocks).
-    Checked for dq, dk and dv against the dq/dkv split (the rule that picks
-    the form given no VMEM to ask for) and against the gradients of the XLA
-    composition (the same mask hash, so dropout agrees bit for bit)."""
-
-    FULL, SHORT, DEAD, LATE = None, (512, 100), (0, 300), (512, 450)
-
-    @pytest.mark.pallas
-    @pytest.mark.parametrize(
-        "group,h_kv,d,causal,window,lens,rate,s,block,dtype",
-        [
-            # 4 x 4 tiles of 128: groups of 1 and 8, causal and not
-            (1, 2, 128, True, None, FULL, 0.0, 512, 128, jnp.float32),
-            (8, 1, 128, True, None, FULL, 0.0, 512, 128, jnp.float32),
-            (1, 2, 128, False, None, FULL, 0.0, 512, 128, jnp.float32),
-            (8, 1, 128, False, None, FULL, 0.0, 512, 128, jnp.float32),
-            # heads of 256, 2 x 2 tiles
-            (8, 1, 256, True, None, FULL, 0.0, 256, 128, jnp.float32),
-            (1, 2, 256, False, None, FULL, 0.0, 256, 128, jnp.float32),
-            # the band: under a block, of a block, not a multiple of it, of
-            # the sequence and beyond it
-            (8, 1, 128, True, 5, FULL, 0.0, 512, 128, jnp.float32),
-            (8, 1, 128, True, 128, FULL, 0.0, 512, 128, jnp.float32),
-            (8, 1, 128, True, 200, FULL, 0.0, 512, 128, jnp.float32),
-            (1, 2, 128, True, 300, FULL, 0.0, 512, 128, jnp.float32),
-            (8, 1, 128, True, 512, FULL, 0.0, 512, 128, jnp.float32),
-            (8, 1, 128, True, 4096, FULL, 0.0, 512, 128, jnp.float32),
-            (8, 1, 256, True, 200, FULL, 0.0, 256, 128, jnp.float32),
-            # 2 x 2 tiles of 256 at heads of 128
-            (8, 1, 128, True, 300, FULL, 0.0, 512, 256, jnp.float32),
-            # the sequence is one block, whatever block was asked for
-            (1, 2, 128, True, None, FULL, 0.0, 128, 1024, jnp.float32),
-            (8, 1, 128, True, 100, FULL, 0.0, 128, 1024, jnp.float32),
-            (8, 1, 256, False, None, (100, 128), 0.0, 128, 1024, jnp.float32),
-            # kv_lens short of a block edge, one row of length 0; under a
-            # band a length that leaves every query a visible key
-            (8, 1, 128, True, None, SHORT, 0.0, 512, 128, jnp.float32),
-            (8, 1, 128, False, None, SHORT, 0.0, 512, 128, jnp.float32),
-            (8, 1, 128, True, None, DEAD, 0.0, 512, 128, jnp.float32),
-            (8, 1, 128, True, 200, LATE, 0.0, 512, 128, jnp.float32),
-            # dropout: the forward's mask regenerated from its seed
-            (8, 1, 128, True, None, FULL, 0.3, 512, 128, jnp.float32),
-            (2, 2, 128, False, None, FULL, 0.3, 512, 128, jnp.float32),
-            (8, 1, 128, True, 200, FULL, 0.3, 512, 128, jnp.float32),
-            (8, 1, 128, True, None, DEAD, 0.3, 512, 128, jnp.float32),
-            # the training dtype: grads in bf16, the group summed in fp32
-            (8, 1, 128, True, None, FULL, 0.0, 256, 128, jnp.bfloat16),
-            (8, 1, 128, True, 200, FULL, 0.0, 256, 128, jnp.bfloat16),
-            (8, 1, 256, True, None, FULL, 0.0, 256, 128, jnp.bfloat16),
-        ])
-    def test_matches_the_split_and_the_xla_composition(
-            self, group, h_kv, d, causal, window, lens, rate, s, block,
-            dtype, monkeypatch):
-        from apex_tpu.ops.pallas import attention as pk
-
-        b, h = 2, group * h_kv
-        key = jr.fold_in(K, 3100 + group + d)
-        q, k, v, do = (
-            jr.normal(jr.fold_in(key, i), (b, s, heads, d)).astype(dtype)
-            for i, heads in enumerate((h, h_kv, h_kv, h)))
-        kv_lens = None if lens is None else jnp.array(lens, jnp.int32)
-        seed = jnp.int32(77) if rate else None
-        scale = d ** -0.5
-        kw = dict(scale=scale, causal=causal, kv_lens=kv_lens, bq=block,
-                  bk=block, interpret=True, dropout_rate=rate,
-                  dropout_seed=seed, window=window)
-
-        def composition(q, k, v):
-            return flash_attention(
-                q, k, v, layout="bshd", impl="xla", causal=causal,
-                kv_lens=kv_lens, scale=scale, dropout_rate=rate,
-                dropout_seed=seed, window=window)
-
-        def names(*args):
-            return str(jax.make_jaxpr(
-                lambda *a: pk.flash_bwd_bshd(*a, **kw))(*args))
-
-        f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
-        with jax.default_matmul_precision("highest"):
-            o, lse = pk.flash_fwd_bshd(q, k, v, full_lse=True, **kw)
-            got = pk.flash_bwd_bshd(q, k, v, o, lse, do, **kw)
-            one_pass = names(q, k, v, o, lse, do)
-            monkeypatch.setattr(pk, "_VMEM_CAP", 0)
-            split = pk.flash_bwd_bshd(q, k, v, o, lse, do, **kw)
-            two_pass = names(q, k, v, o, lse, do)
-            _, vjp = jax.vjp(composition, f32(q), f32(k), f32(v))
-            want = vjp(f32(do))
-        fused_name = ("flash_bwd_bshd_fused" if window is None
-                      else "flash_bwd_bshd_win_fused")
-        assert fused_name in one_pass and "_dkv" not in one_pass
-        assert "_dkv" in two_pass and "fused" not in two_pass
-        assert len(got) == 3
-        tol = (dict(rtol=2e-4, atol=3e-5) if dtype == jnp.float32
-               else dict(rtol=3e-2, atol=6e-2))
-        for name, a, sp, e, heads in zip(("dq", "dk", "dv"), got, split,
-                                         want, (h, h_kv, h_kv)):
-            assert a.shape == (b, s, heads, d) and a.dtype == dtype, name
-            np.testing.assert_allclose(f32(a), f32(sp),
-                                       err_msg=f"{name} vs split", **tol)
-            np.testing.assert_allclose(f32(a), e, err_msg=f"{name} vs xla",
-                                       **tol)

@@ -49,9 +49,10 @@ def both(args, causal, impl, scale=None):
         return jnp.sum(o * w), o
 
     with jax.default_matmul_precision("highest"):
-        (_, o), g = jax.value_and_grad(loss, argnums=(0, 1, 2, 3), has_aux=True)(q, k, v, second)
-        (_, o_ref), g_ref = jax.value_and_grad(want, argnums=(0, 1, 2, 3), has_aux=True)(
+        (_, o), g = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3), has_aux=True))(
             q, k, v, second)
+        (_, o_ref), g_ref = jax.jit(jax.value_and_grad(
+            want, argnums=(0, 1, 2, 3), has_aux=True))(q, k, v, second)
     return o, o_ref, jax.tree.leaves(g), jax.tree.leaves(g_ref)
 
 

@@ -50,8 +50,11 @@ def test_banded_kernels_match_the_masked_oracle(window, backward, monkeypatch):
         if impl == "xla":
             f = lambda q, k, v: flash_attention(  # noqa: E731
                 q, k, v, causal=True, layout="bshd", impl="xla", window=window)
-            o, pull = jax.vjp(f, q, k, v)
-            return (o, *pull(do))
+
+            def both(q, k, v):
+                o, pull = jax.vjp(f, q, k, v)
+                return (o, *pull(do))
+            return jax.jit(both)(q, k, v)
         o, lse = fa.flash_fwd_bshd(q, k, v, scale=128 ** -0.5, causal=True, bq=bq, bk=bk,
                                    interpret=True, window=window)
         return (o, *fa.flash_bwd_bshd(q, k, v, o, lse, do, scale=128 ** -0.5, causal=True,
@@ -77,8 +80,8 @@ def test_public_entry_differentiates_through_the_banded_kernels():
             q, k, v, causal=True, layout="bshd", impl=impl, window=200).astype(jnp.float32)
             * do.astype(jnp.float32))
 
-    got = jax.grad(loss("pallas"), argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(loss("xla"), argnums=(0, 1, 2))(q, k, v)
+    got = jax.jit(jax.grad(loss("pallas"), argnums=(0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(loss("xla"), argnums=(0, 1, 2)))(q, k, v)
     for g, w in zip(got, want):
         np.testing.assert_allclose(g.astype(jnp.float32), w.astype(jnp.float32),
                                    atol=0.06, rtol=0.05)

@@ -45,8 +45,8 @@ def inputs(t, decay, b=2, hk=1, hv=2, d=128, seed=0, drift=0.0, sign=1.0):
 
 
 def value_and_grads(f, x, do):
-    return jax.value_and_grad(lambda *a: jnp.sum(f(*a).astype(jnp.float32) * do),
-                              argnums=range(5))(*x)
+    return jax.jit(jax.value_and_grad(lambda *a: jnp.sum(f(*a).astype(jnp.float32) * do),
+                                      argnums=range(5)))(*x)
 
 
 def check(impl, t, decay, tol=2e-5, chunk=64, **keys):
@@ -56,7 +56,7 @@ def check(impl, t, decay, tol=2e-5, chunk=64, **keys):
     with jax.default_matmul_precision("highest"):
         want, want_g = value_and_grads(recurrence, x, do)
         got, got_g = value_and_grads(rule, x, do)
-        o, o_ref = rule(*x), recurrence(*x)
+        o, o_ref = jax.jit(rule)(*x), jax.jit(recurrence)(*x)
     assert o.shape == o_ref.shape
     np.testing.assert_allclose(o, o_ref, atol=tol * float(jnp.max(jnp.abs(o_ref))))
     np.testing.assert_allclose(got, want, rtol=1e-4)
@@ -155,8 +155,8 @@ def test_unit_lower_inverse_and_its_gradient():
         t = _unit_lower_inverse(a)
         np.testing.assert_allclose(jnp.matmul(eye + a, t), jnp.broadcast_to(eye, a.shape), atol=2e-4)
         r = jax.random.normal(jax.random.PRNGKey(2), a.shape)
-        got = jax.grad(lambda a: jnp.sum(_unit_lower_inverse(a) * r))(a)
-        want = jax.grad(lambda a: jnp.sum(jnp.linalg.inv(eye + a) * r))(a)
+        got = jax.jit(jax.grad(lambda a: jnp.sum(_unit_lower_inverse(a) * r)))(a)
+        want = jax.jit(jax.grad(lambda a: jnp.sum(jnp.linalg.inv(eye + a) * r)))(a)
     np.testing.assert_allclose(got, want, atol=1e-3 * float(jnp.max(jnp.abs(want))))
 
 

@@ -18,6 +18,7 @@ from apex_tpu import amp  # noqa: E402
 from apex_tpu.models import HybridDecoderConfig, HybridDecoderModel  # noqa: E402
 from benchmarks.adapters import hybrid_tree  # noqa: E402
 from benchmarks.reference import hybrid_ref as R  # noqa: E402
+from comparisons import batch, close  # noqa: E402
 
 # two periods of (linear, full); 16 experts top-4, a share of 8 held
 TOY = dict(hidden_size=128, num_hidden_layers=4, full_attention_interval=2,
@@ -36,12 +37,6 @@ def build(**settings):
     return d, model, R.make_weights(d, R.seed_key(3))
 
 
-def batch(rows=2, seq=96):
-    rng = np.random.default_rng(0)
-    return (jnp.asarray(rng.integers(0, 256, (rows, seq)), jnp.int32),
-            jnp.asarray(rng.integers(0, 256, (rows, seq)), jnp.int32))
-
-
 def test_gated_attention_mixer_matches_the_reference():
     """q/k norm with a zero-centred weight, rotary on the first quarter of
     each head, grouped kv heads, the sigmoid gate."""
@@ -51,46 +46,55 @@ def test_gated_attention_mixer_matches_the_reference():
     r = jax.random.normal(jax.random.PRNGKey(6), (2, 80, 128))
     with jax.default_matmul_precision("highest"):
         ref = lambda lw, x: jax.vmap(lambda s: R.gated_attention_mixer(lw, d, s, "float32", 16))(x)  # noqa: E731
-        got, want = model._attention_mixer(lw, x), ref(lw, x)
+        got, want = jax.jit(model._attention_mixer)(lw, x), jax.jit(ref)(lw, x)
         np.testing.assert_allclose(got, want, atol=2e-5 * float(jnp.max(jnp.abs(want))))
-        g = jax.grad(lambda lw, x: jnp.sum(model._attention_mixer(lw, x) * r), argnums=(0, 1))(lw, x)
-        gr = jax.grad(lambda lw, x: jnp.sum(ref(lw, x) * r), argnums=(0, 1))(lw, x)
+        g = jax.jit(jax.grad(lambda lw, x: jnp.sum(model._attention_mixer(lw, x) * r),
+                             argnums=(0, 1)))(lw, x)
+        gr = jax.jit(jax.grad(lambda lw, x: jnp.sum(ref(lw, x) * r), argnums=(0, 1)))(lw, x)
     for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(gr)):
         np.testing.assert_allclose(a, b, atol=3e-5 * float(jnp.max(jnp.abs(b))))
 
 
-@pytest.mark.parametrize("impl,remat", [("xla", False), ("xla", True), ("pallas", True)])
-def test_loss_and_every_gradient_match_the_reference(impl, remat):
-    d, model, w = build(attention_impl="xla", delta_impl=impl, experts_impl=impl, remat=remat)
+@pytest.fixture(scope="module")
+def reference():
+    d, _, w = build()
     tokens, targets = batch()
     with jax.default_matmul_precision("highest"):
         (want, loads), g_ref = jax.jit(jax.value_and_grad(
             lambda w: R.loss(w, d, tokens, targets, row_block=2), has_aux=True))(w)
+    return float(want), np.asarray(loads), hybrid_tree.to_program(g_ref)
+
+
+@pytest.mark.parametrize("impl,remat", [("xla", False), ("xla", True), ("pallas", True)])
+def test_loss_and_every_gradient_match_the_reference(impl, remat, reference):
+    want, loads, want_g = reference
+    d, model, w = build(attention_impl="xla", delta_impl=impl, experts_impl=impl, remat=remat)
+    tokens, targets = batch()
+    with jax.default_matmul_precision("highest"):
         (got, aux), g = jax.jit(jax.value_and_grad(
             lambda p: model.loss_fn(p, tokens, targets, return_aux=True), has_aux=True))(
                 hybrid_tree.to_program(w))
-    assert abs(float(got) - float(want)) < 2e-6 * abs(float(want))
+    assert abs(float(got) - want) < 2e-6 * abs(want)
     np.testing.assert_array_equal(aux["expert_load"], loads)
     assert int(aux["dropped"]) == 0 and aux["expert_load"].shape == (4, 8)
-    want_g = hybrid_tree.to_program(g_ref)
     paths = jax.tree_util.tree_flatten_with_path(g)[0]
     assert len(paths) == 24
     for (path, a), b in zip(paths, jax.tree.leaves(want_g)):
-        np.testing.assert_allclose(a, b, err_msg=jax.tree_util.keystr(path),
-                                   atol=2e-4 * float(jnp.max(jnp.abs(b))) + 1e-9)
+        close(a, b, 2e-4, jax.tree_util.keystr(path))
 
 
 def test_loss_fn_has_the_trainers_signature_and_init_the_programs_tree():
     d, model, w = build(attention_impl="xla", delta_impl="xla", experts_impl="xla")
-    p = model.init(jax.random.PRNGKey(0))
+    p = jax.jit(model.init)(jax.random.PRNGKey(0))
     want = hybrid_tree.to_program(w)
     assert jax.tree.structure(p) == jax.tree.structure(want)
     assert jax.tree.map(jnp.shape, p) == jax.tree.map(jnp.shape, want)
     tokens, targets = batch(1, 64)
-    loss = model.loss_fn(p, tokens, targets)
-    masked = model.loss_fn(p, tokens, targets, loss_mask=jnp.ones_like(tokens).at[:, 32:].set(0))
+    loss = jax.jit(model.loss_fn)(p, tokens, targets)
+    masked = jax.jit(lambda p, mask: model.loss_fn(p, tokens, targets, loss_mask=mask))(
+        p, jnp.ones_like(tokens).at[:, 32:].set(0))
     assert loss.shape == () and np.isfinite(float(loss)) and float(masked) != float(loss)
-    assert model.logits(p, tokens).shape == (1, 64, 256)
+    assert jax.jit(model.logits)(p, tokens).shape == (1, 64, 256)
     with pytest.raises(ValueError, match="layer_types"):
         HybridDecoderConfig(layer_types=("linear", "sparse"))
     with pytest.raises(ValueError, match="window="):

@@ -5,7 +5,6 @@ backward pass holds each forward kernel once a layer, ``jax.ad_checkpoint``
 lists the half's arguments and the named values and nothing else, and the
 loss and every gradient are those of the model that recomputes nothing, bit
 for bit."""
-import collections
 import contextlib
 import io
 import os
@@ -24,6 +23,7 @@ from apex_tpu.models import HybridDecoderConfig, HybridDecoderModel, hybrid_deco
 from apex_tpu.ops.attention import FLASH_SAVED  # noqa: E402
 from apex_tpu.ops.gated_delta_rule import RULE_SAVED  # noqa: E402
 from apex_tpu.ops.ssd import SSD_SAVED  # noqa: E402
+from comparisons import kernel_calls  # noqa: E402
 
 LAYERS, ROWS, SEQ, HIDDEN = 2, 2, 256, 128
 # layer kind -> its forward kernel, the names its half holds, the values
@@ -48,17 +48,6 @@ def build(kind, remat):
         remat=remat, attention_impl="pallas", delta_impl="pallas", experts_impl="xla"))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (ROWS, SEQ), 0, 256)
     return model, model.init(jax.random.PRNGKey(0)), tokens
-
-
-def kernel_calls(jaxpr):
-    """Kernel name -> ``pallas_call`` equations, a call site at a time."""
-    calls = collections.Counter()
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            calls[eqn.params["name"]] += 1
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            calls.update(kernel_calls(sub))
-    return calls
 
 
 def grad_calls(kind, remat):

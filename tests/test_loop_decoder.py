@@ -7,7 +7,6 @@ size: the loss, every gradient leaf and the exits' readings with and without
 distribution; what the backward pass keeps of a looped stack and of its
 exits, whose hand-written rule is held to jax's own; and a stack walked once,
 which is the program it was before."""
-import collections
 import contextlib
 import dataclasses
 import hashlib
@@ -29,6 +28,7 @@ if ROOT not in sys.path:
 from apex_tpu.models import HybridDecoderConfig, HybridDecoderModel, hybrid_decoder  # noqa: E402
 from benchmarks.adapters import loop_tree  # noqa: E402
 from benchmarks.reference import loop_ref  # noqa: E402
+from comparisons import gap, kernel_calls  # noqa: E402
 
 # the cell's block at a small size: two heads of 64 rotated whole, a SwiGLU of
 # 2.75 x the hidden size, four walks, the published epsilon and theta
@@ -61,10 +61,6 @@ def batch(vocab=SMALL["vocab_size"]):
 # the stated tolerances: the loss and the exits' readings against the
 # reference's own size, a gradient leaf against its largest entry
 LOSS_TOL, GRAD_TOL = 2e-6, 3e-5
-
-
-def gap(a, b):
-    return float(jnp.max(jnp.abs(a - b))) / (float(jnp.max(jnp.abs(b))) + 1e-30)
 
 
 def saved_shapes(f, *args):
@@ -217,17 +213,6 @@ def test_four_walks_are_an_unshared_stack_of_copies_and_the_gradient_their_sum()
                            jax.tree.leaves(stack(g_copies[0]))):
         assert gap(a, b) <= 1e-5
         assert gap(first, a) > 1e-3              # no one walk's share is the whole
-
-
-def kernel_calls(jaxpr):
-    """Kernel name -> ``pallas_call`` equations, a call site at a time."""
-    calls = collections.Counter()
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            calls[eqn.params["name"]] += 1
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            calls.update(kernel_calls(sub))
-    return calls
 
 
 KERNEL_SIZE = dict(SMALL, num_attention_heads=1, num_key_value_heads=1, head_dim=128)

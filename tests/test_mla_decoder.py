@@ -23,6 +23,7 @@ from apex_tpu.ops import rotary  # noqa: E402
 from apex_tpu.transformer.moe import route_topk  # noqa: E402
 from benchmarks.adapters import mla_tree  # noqa: E402
 from benchmarks.reference import mla_ref as R  # noqa: E402
+from comparisons import batch, close  # noqa: E402
 
 YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 0.707,
         "mscale_all_dim": 0.707, "original_max_position_embeddings": 4096, "type": "yarn"}
@@ -45,17 +46,6 @@ def build(config=TOY, **settings):
     d = R.dims(config)
     model = HybridDecoderModel(HybridDecoderConfig(**mla_tree.config_kwargs(d, **settings)))
     return d, model, R.make_weights(d, R.seed_key(3))
-
-
-def batch(rows=2, seq=128, seed=0):
-    rng = np.random.default_rng(seed)
-    return (jnp.asarray(rng.integers(0, 256, (rows, seq)), jnp.int32),
-            jnp.asarray(rng.integers(0, 256, (rows, seq)), jnp.int32))
-
-
-def close(got, want, tol, name=""):
-    np.testing.assert_allclose(got, want, err_msg=name,
-                               atol=tol * float(jnp.max(jnp.abs(want))) + 1e-9)
 
 
 def test_dims_cut_the_published_model_as_the_cell_does():
@@ -105,11 +95,11 @@ def test_latent_mixer_matches_the_reference():
     p = jax.tree.map(lambda a: a[1], mla_tree.to_program(w)["layers"]["mla"])
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 96, 128))
     with jax.default_matmul_precision("highest"):
-        want = jax.vmap(lambda s: R.attention_mixer(lw, d, s, "float32", 32))(x)
-        close(model._latent_mixer(p, x), want, 2e-5)
+        want = jax.jit(jax.vmap(lambda s: R.attention_mixer(lw, d, s, "float32", 32)))(x)
+        close(jax.jit(model._latent_mixer)(p, x), want, 2e-5)
         plain = HybridDecoderModel(HybridDecoderConfig(**dict(
             mla_tree.config_kwargs(d, attention_impl="xla"), rope_scaling=None)))
-        assert float(jnp.max(jnp.abs(plain._latent_mixer(p, x) - want))) > 1e-4
+        assert float(jnp.max(jnp.abs(jax.jit(plain._latent_mixer)(p, x) - want))) > 1e-4
 
 
 def test_balance_term_per_sequence_by_hand():
@@ -134,28 +124,36 @@ def test_balance_term_per_sequence_by_hand():
         assert float(R.route(x, router, d, rows, "float32")[3]) == pytest.approx(want, rel=1e-5)
 
 
+@pytest.fixture(scope="module")
+def reference():
+    d, _, w = build()
+    tokens, targets = batch(2, 128)
+    with jax.default_matmul_precision("highest"):
+        (want, counts), gr = jax.jit(jax.value_and_grad(
+            lambda w: R.loss(w, d, tokens, targets), has_aux=True))(w)
+        _, _, balance = jax.jit(lambda w: R.hidden(w, d, tokens))(w)
+    return float(want), np.asarray(counts), float(balance), mla_tree.to_program(gr)
+
+
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_loss_and_every_gradient_match_the_reference(impl):
+def test_loss_and_every_gradient_match_the_reference(impl, reference):
     """Rows of 128: the two-width flash kernels in interpret mode on all
     three layers, the grouped expert products on two."""
+    want, counts, balance, want_g = reference
     d, model, w = build(attention_impl=impl, experts_impl=impl)
     p = mla_tree.to_program(w)
     assert jax.tree.structure(p) == jax.tree.structure(model.init(jax.random.PRNGKey(0)))
     tokens, targets = batch(2, 128)
     with jax.default_matmul_precision("highest"):
-        (loss, aux), g = jax.value_and_grad(
-            lambda p: model.loss_fn(p, tokens, targets, return_aux=True), has_aux=True)(p)
-        (want, counts), gr = jax.value_and_grad(
-            lambda w: R.loss(w, d, tokens, targets), has_aux=True)(w)
-        _, _, balance = R.hidden(w, d, tokens)
-    assert abs(float(loss) - float(want)) < 2e-5
+        (loss, aux), g = jax.jit(jax.value_and_grad(
+            lambda p: model.loss_fn(p, tokens, targets, return_aux=True), has_aux=True))(p)
+    assert abs(float(loss) - want) < 2e-5
     # the term is in the loss: summed over the expert layers at alpha
-    assert float(aux["load_balance_loss"]) * 2 == pytest.approx(float(balance), rel=1e-4)
-    assert 0.001 * float(balance) > 1e-3
+    assert float(aux["load_balance_loss"]) * 2 == pytest.approx(balance, rel=1e-4)
+    assert 0.001 * balance > 1e-3
     np.testing.assert_array_equal(aux["router_counts"], counts)
     np.testing.assert_array_equal(aux["expert_load"], counts[:, 6:8])
     assert int(aux["dropped"]) == 0
-    want_g = mla_tree.to_program(gr)
     for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(g)[0], jax.tree.leaves(want_g)):
         close(a, b, 2e-4 if impl == "pallas" else 2e-5, jax.tree_util.keystr(path))
 
@@ -202,11 +200,12 @@ def test_remat_and_spans_leave_the_loss_alone():
     _, again, _ = build(attention_impl="xla", experts_impl="xla", remat=True)
     p = mla_tree.to_program(w)
     tokens, targets = batch(1, 64)
-    g = jax.grad(model.loss_fn)(p, tokens, targets)
-    gr = jax.grad(again.loss_fn)(p, tokens, targets)
+    grad = jax.jit(jax.grad(model.loss_fn))
+    g = grad(p, tokens, targets)
+    gr = jax.jit(jax.grad(again.loss_fn))(p, tokens, targets)
     for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(gr)):
         close(a, b, 1e-5)
-    text = jax.jit(jax.grad(model.loss_fn)).lower(p, tokens, targets).as_text(debug_info=True)
+    text = grad.lower(p, tokens, targets).as_text(debug_info=True)
     for scope in ("hybrid/attn_mla", "mla/down", "mla/up", "hybrid/dense", "hybrid/moe",
                   "moe/route", "moe/experts", "moe/shared"):
         assert scope in text, scope
@@ -230,6 +229,6 @@ def test_a_latent_layer_sits_beside_the_other_kinds():
     assert p["layers"]["mla"]["w_kva"].shape == (1, 64, 16 + 16)
     assert p["layers"]["mla"]["w_kvb"].shape == (1, 16, 2 * 64)
     tokens = jnp.arange(32, dtype=jnp.int32).reshape(1, 32) % 64
-    assert np.isfinite(float(model.loss_fn(p, tokens, tokens)))
+    assert np.isfinite(float(jax.jit(model.loss_fn)(p, tokens, tokens)))
     with pytest.raises(ValueError, match="latent"):
         HybridDecoderConfig(layer_types=("mla",))

@@ -3,7 +3,6 @@ it stands for, token by token: value and every cotangent (``dA``, ``dD`` and
 ``ddt`` among them) over several chunks and a ragged last one, heads that
 share a lane tile and heads that fill one, groups that share ``B`` and ``C``,
 bf16 operands, the XLA twin, and the shape rule with its refusals."""
-import collections
 import os
 import sys
 
@@ -18,6 +17,7 @@ if ROOT not in sys.path:
 
 from apex_tpu.ops import ssd  # noqa: E402
 from apex_tpu.ops.pallas import ssd as kernels  # noqa: E402
+from comparisons import gap, kernel_calls  # noqa: E402
 
 NAMES = ("x", "dt", "A", "B", "C", "D")
 
@@ -55,14 +55,9 @@ def value_and_grads(fn, args, ct):
     def loss(*a):
         y = fn(*a)
         return jnp.sum(y.astype(jnp.float32) * ct), y
-    (_, y), grads = jax.value_and_grad(loss, argnums=tuple(range(6)), has_aux=True)(*args)
+    (_, y), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=tuple(range(6)), has_aux=True))(*args)
     return y, grads
-
-
-def close(got, want, tol, what):
-    scale = float(jnp.max(jnp.abs(want))) + 1e-30
-    err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))) / scale
-    assert err <= tol, f"{what}: {err:.3g} of scale {scale:.3g}"
 
 
 # (b, t, heads, P, groups, N): three chunks with a ragged last one, two heads
@@ -76,10 +71,10 @@ def test_value_and_every_cotangent_match_the_recurrence(shape, impl):
     args, ct = operands(*shape)
     want, g_want = value_and_grads(recurrence, args, ct)
     got, g_got = value_and_grads(lambda *a: ssd.ssd_scan(*a, impl=impl), args, ct)
-    close(got, want, 1e-4, "y")
+    assert gap(got, want) <= 1e-4, "y"
     for name, a, b in zip(NAMES, g_got, g_want):
         assert a.shape == b.shape and a.dtype == b.dtype, name
-        close(a, b, 1e-4, "d" + name)
+        assert gap(a, b) <= 1e-4, "d" + name
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
@@ -89,9 +84,9 @@ def test_bf16_operands_stay_near_the_float32_recurrence(impl):
     got, g_got = value_and_grads(lambda *a: ssd.ssd_scan(*a, impl=impl), args, ct)
     assert got.dtype == jnp.bfloat16 and g_got[0].dtype == jnp.bfloat16
     assert g_got[1].dtype == g_got[2].dtype == g_got[5].dtype == jnp.float32
-    close(got, want, 2e-2, "y")
+    assert gap(got, want) <= 2e-2, "y"
     for name, a, b in zip(NAMES, g_got, g_want):
-        close(a, b.astype(jnp.float32), 3e-2, "d" + name)
+        assert gap(a, b.astype(jnp.float32)) <= 3e-2, "d" + name
 
 
 def test_the_kernels_and_the_xla_twin_agree_in_bf16():
@@ -100,9 +95,9 @@ def test_the_kernels_and_the_xla_twin_agree_in_bf16():
     args, ct = operands(2, 256, 4, 64, 2, 128, jnp.bfloat16, seed=5)
     one, g_one = value_and_grads(lambda *a: ssd.ssd_scan(*a, impl="pallas"), args, ct)
     two, g_two = value_and_grads(lambda *a: ssd.ssd_scan(*a, impl="xla"), args, ct)
-    close(one, two.astype(jnp.float32), 1e-2, "y")
+    assert gap(one, two.astype(jnp.float32)) <= 1e-2, "y"
     for name, a, b in zip(NAMES, g_one, g_two):
-        close(a, b.astype(jnp.float32), 2e-2, "d" + name)
+        assert gap(a, b.astype(jnp.float32)) <= 2e-2, "d" + name
 
 
 def test_a_token_with_no_time_step_neither_decays_nor_writes():
@@ -112,7 +107,7 @@ def test_a_token_with_no_time_step_neither_decays_nor_writes():
     y = ssd.ssd_scan(x, dt, A, B, C, D, impl="pallas")
     keep = np.r_[0:100, 140:256]
     skipped = ssd.ssd_scan(x[:, keep], dt[:, keep], A, B[:, keep], C[:, keep], D, impl="pallas")
-    close(y[:, keep], skipped, 1e-5, "y around the still tokens")
+    assert gap(y[:, keep], skipped) <= 1e-5, "y around the still tokens"
 
 
 @pytest.mark.parametrize("case,ok", [
@@ -135,18 +130,7 @@ def test_pallas_refuses_what_the_rule_refuses_and_auto_falls_back():
     with pytest.raises(ValueError, match="impl"):
         ssd.ssd_scan(*args, chunk=16, impl="mosaic")
     y = ssd.ssd_scan(*args, chunk=16, impl="auto")          # the XLA form
-    close(y, recurrence(*args), 1e-4, "y")
-
-
-def kernel_calls(jaxpr):
-    """Kernel name -> ``pallas_call`` equations, a call site at a time."""
-    calls = collections.Counter()
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            calls[eqn.params["name"]] += 1
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            calls.update(kernel_calls(sub))
-    return calls
+    assert gap(y, recurrence(*args)) <= 1e-4, "y"
 
 
 def test_the_kernels_carry_their_names_and_the_states_are_the_steps():

@@ -5,7 +5,6 @@ small size with the published proportions: the mixer alone, the loss and every
 gradient leaf (XLA and the kernels in interpret mode), the sixteen shares of
 an expert layer that add up to the uncut layer, the block without a second
 half, the spans, and what ``remat`` keeps of a state-space half."""
-import collections
 import os
 import sys
 
@@ -23,6 +22,7 @@ from apex_tpu.ops.ssd import SSD_SAVED  # noqa: E402
 from apex_tpu.transformer import moe  # noqa: E402
 from benchmarks.adapters import ssm_tree  # noqa: E402
 from benchmarks.reference import ssm_ref  # noqa: E402
+from comparisons import gap, kernel_calls  # noqa: E402
 
 # the cell's cut at a small size: published layers 0-6 (MEMEM*E), heads of 64
 # two a lane tile, a state of 128 rows, 2 groups, chunk 128; 16 experts top-4
@@ -52,11 +52,6 @@ def batch():
     return tokens, jnp.roll(tokens, -1, axis=1)
 
 
-def close(a, b, tol):
-    scale = float(jnp.max(jnp.abs(b))) + 1e-30
-    assert float(jnp.max(jnp.abs(a - b))) <= tol * scale
-
-
 def test_dims_and_blocks_cut_the_published_model_as_the_cell_does():
     d = ssm_ref.dims(SMALL)
     assert d["kinds"] == ("ssm", "moe", "ssm", "moe", "ssm", "attn", "moe")
@@ -73,7 +68,8 @@ def test_dims_and_blocks_cut_the_published_model_as_the_cell_does():
     p = ssm_tree.to_program(w, d)
     count = lambda tree: sum(int(np.prod(a.shape)) for a in jax.tree.leaves(tree))  # noqa: E731
     assert count(p) == count(w)                          # a relabelling: nothing lost or doubled
-    assert jax.tree.map(jnp.shape, p) == jax.tree.map(jnp.shape, model.init(jax.random.PRNGKey(0)))
+    init = jax.jit(model.init)(jax.random.PRNGKey(0))
+    assert jax.tree.map(jnp.shape, p) == jax.tree.map(jnp.shape, init)
     assert p["layers"]["norm1"].shape == (4, 128) and p["layers"]["norm2"].shape == (3, 128)
 
 
@@ -84,7 +80,8 @@ def test_state_space_mixer_matches_the_reference(impl):
     lw = jax.tree.map(lambda a: a[1], w["ssm"])
     x = jax.random.normal(jax.random.PRNGKey(5), (ROWS, SEQ + 40, 128))     # a ragged last chunk
     with jax.default_matmul_precision("highest"):
-        close(model._ssm_mixer(p, x), ssm_ref.ssm_mixer(lw, d, x, "float32"), 2e-5)
+        want = jax.jit(lambda lw, x: ssm_ref.ssm_mixer(lw, d, x, "float32"))(lw, x)
+        assert gap(jax.jit(model._ssm_mixer)(p, x), want) <= 2e-5
 
 
 @pytest.fixture(scope="module")
@@ -93,8 +90,8 @@ def reference():
     bias = 0.01 * jax.random.normal(jax.random.PRNGKey(2), (3, 16))
     tokens, targets = batch()
     with jax.default_matmul_precision("highest"):
-        (loss, counts), g = jax.value_and_grad(
-            lambda w: ssm_ref.loss(w, bias, d, tokens, targets), has_aux=True)(w)
+        (loss, counts), g = jax.jit(jax.value_and_grad(
+            lambda w: ssm_ref.loss(w, bias, d, tokens, targets), has_aux=True))(w)
     return bias, float(loss), np.asarray(counts), ssm_tree.to_program(g, d)
 
 
@@ -105,8 +102,8 @@ def test_loss_and_every_gradient_match_the_reference(impl, reference):
                         remat=impl == "pallas")
     tokens, targets = batch()
     with jax.default_matmul_precision("highest"):
-        (loss, aux), g = jax.value_and_grad(lambda p: model.loss_fn(
-            p, tokens, targets, return_aux=True, router_bias=bias), has_aux=True)(
+        (loss, aux), g = jax.jit(jax.value_and_grad(lambda p: model.loss_fn(
+            p, tokens, targets, return_aux=True, router_bias=bias), has_aux=True))(
                 ssm_tree.to_program(w, d))
     assert abs(float(loss) - want) <= 2e-6 * want
     np.testing.assert_array_equal(aux["router_counts"], counts)
@@ -116,7 +113,7 @@ def test_loss_and_every_gradient_match_the_reference(impl, reference):
     assert len(leaves) == len(jax.tree.leaves(g_want)) == 22
     for (path, a), b in zip(leaves, jax.tree.leaves(g_want)):
         assert float(jnp.max(jnp.abs(b))) > 0, jax.tree_util.keystr(path)
-        close(a, b, 2e-5)
+        assert gap(a, b) <= 2e-5, jax.tree_util.keystr(path)
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
@@ -163,7 +160,7 @@ def test_a_block_without_a_second_half_is_its_mixer_alone():
                   attention_impl="xla", experts_impl="xla")
     model = HybridDecoderModel(HybridDecoderConfig(
         layer_types=("ssm", "full", "ssm"), ffn_types=("none", "moe", "none"), **config))
-    p = model.init(jax.random.PRNGKey(0))
+    p = jax.jit(model.init)(jax.random.PRNGKey(0))
     layers = p["layers"]
     assert layers["norm1"].shape == (3, 128) and layers["norm2"].shape == (1, 128)
     assert layers["moe"]["router"].shape[0] == 1 and "dense" not in layers
@@ -173,18 +170,24 @@ def test_a_block_without_a_second_half_is_its_mixer_alone():
     assert set(layers["ssm"]) == {"w_in", "conv_w", "conv_b", "A_log", "dt_bias", "D", "norm_w",
                                   "w_o"}
     tokens = jax.random.randint(jax.random.PRNGKey(1), (1, 128), 0, 64)
-    x, aux = model.hidden_states_with_aux(p, tokens)
+    x, aux = jax.jit(model.hidden_states_with_aux)(p, tokens)
     assert aux["expert_load"].shape == (1, 8)
-    # the stack by hand: the lone expert half reads norm2's ONE row, the
-    # blocks around it are their mixers alone
-    take = lambda group, j: jax.tree.map(lambda a: a[j], layers[group])  # noqa: E731
-    h = p["embedding"]["weight"][tokens]
-    h = model._mixer_half("ssm")(take("ssm", 0), layers["norm1"][0], None, h)
-    h = model._mixer_half("full")(take("attn", 0), layers["norm1"][1], None, h)
-    h, by_hand = model._expert_half(take("moe", 0), layers["norm2"][0], None, None, h)
-    h = model._mixer_half("ssm")(take("ssm", 1), layers["norm1"][2], None, h)
-    close(x, model._norm(h, p["norm_f"]), 1e-6)
-    np.testing.assert_array_equal(aux["expert_load"][0], by_hand["expert_load"])
+
+    def by_hand(p):
+        """The lone expert half reads norm2's ONE row, the blocks around it
+        are their mixers alone."""
+        layers = p["layers"]
+        take = lambda group, j: jax.tree.map(lambda a: a[j], layers[group])  # noqa: E731
+        h = p["embedding"]["weight"][tokens]
+        h = model._mixer_half("ssm")(take("ssm", 0), layers["norm1"][0], None, h)
+        h = model._mixer_half("full")(take("attn", 0), layers["norm1"][1], None, h)
+        h, aux = model._expert_half(take("moe", 0), layers["norm2"][0], None, None, h)
+        h = model._mixer_half("ssm")(take("ssm", 1), layers["norm1"][2], None, h)
+        return model._norm(h, p["norm_f"]), aux["expert_load"]
+
+    want, load = jax.jit(by_hand)(p)
+    assert gap(x, want) <= 1e-6
+    np.testing.assert_array_equal(aux["expert_load"][0], load)
     with pytest.raises(ValueError, match="'moe', 'dense' or 'none'"):
         HybridDecoderConfig(layer_types=("ssm",), ffn_types=("nothing",))
     with pytest.raises(ValueError, match="expert_activation"):
@@ -206,16 +209,6 @@ def test_float32_leaves_and_spans():
     assert "hybrid/gdn" not in text and "hybrid/dense" not in text
     from apex_tpu.prof import scopes
     assert "hybrid/ssm" in scopes.SPANS
-
-
-def kernel_calls(jaxpr):
-    calls = collections.Counter()
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            calls[eqn.params["name"]] += 1
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            calls.update(kernel_calls(sub))
-    return calls
 
 
 def grad_calls(remat):
