@@ -45,6 +45,8 @@ HALF_OPS = {
     # the chunked gated delta rule is matmuls over chunk operands; its
     # decays, norms and state are fp32 inside whatever the inputs
     "gated_delta_rule",
+    # the same rule with a decay a key channel
+    "kda_rule",
     # the chunked state-space scan likewise (decays and state fp32 inside)
     "ssd_scan",
 }

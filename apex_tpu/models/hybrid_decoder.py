@@ -1,5 +1,6 @@
 """A decoder whose layers take their kind from a pattern: gated delta-rule
-(linear-attention) mixers, state-space (Mamba-2) mixers, gated softmax-attention
+(linear-attention) mixers with a scalar decay a head or a decay a key channel,
+state-space (Mamba-2) mixers, gated softmax-attention
 mixers over all keys or over a sliding window, and latent-attention (MLA)
 mixers, in any order, each followed by a dropless
 sparse-expert layer with a shared expert, by a dense SwiGLU layer, or by
@@ -9,10 +10,10 @@ Pre-norm residual blocks (``x += mixer(norm(x)); x += experts(norm(x))``)
 with zero-centred RMSNorm (``x / rms(x) * (1 + w)``), no position table
 (the attention layers carry partial rotary embeddings, the delta-rule layers
 need none), no biases, an untied output head. ``layer_types`` names each
-layer ``"linear"``, ``"ssm"``, ``"full"``, ``"window"`` or ``"latent"``, ``ffn_types``
+layer ``"linear"``, ``"kda"``, ``"ssm"``, ``"full"``, ``"window"`` or ``"latent"``, ``ffn_types``
 its second half ``"moe"`` (the default everywhere), ``"dense"`` or ``"none"``
 (no second half: ``norm2`` has a row for each layer that has one); parameters
-of one kind are stacked on a leading axis under ``layers/gdn``, ``layers/ssm``,
+of one kind are stacked on a leading axis under ``layers/gdn``, ``layers/kda``, ``layers/ssm``,
 ``layers/attn`` (both gated attention kinds, in the order they come),
 ``layers/mla``, ``layers/moe`` and ``layers/dense``; a kind no layer has has
 no group. All linears are stored
@@ -24,12 +25,25 @@ each half's output before it is added (``sandwich_norms``:
 expert without its gate (``shared_gate=False``), ungated ``relu(x)^2`` experts
 with one ``up`` matrix (``expert_activation="relu2"``: leaves ``w_up``,
 ``shared_up``), and an attention mixer without its gate (``attn_gate=False``),
-its per-head norms (``qk_norm=False``) or rotary (``rotary_dim=0``).
+its per-head norms (``qk_norm=False``) or rotary (``rotary_dim=0``), a latent
+mixer with per-head q/k norms (``latent_qk_norm``) and a head-wise gate
+(``latent_gate``), and a group-limited choice of the experts
+(``router_groups``, ``router_groups_kept``).
 
 * ``"linear"`` — fused ``q|k|v|z`` and ``b|a`` projections, causal depthwise
   convolution + SiLU on ``q|k|v``, :func:`ops.gated_delta_rule.gated_delta_rule`
   (float32 decay, write strength, norms and state whatever the policy),
   head-wise RMSNorm gated by ``silu(z)``, output projection.
+* ``"kda"`` — the delta rule with a decay a key CHANNEL (Kimi Delta Attention):
+  a fused ``q|k|v`` projection (``kda_heads`` of ``kda_head_dim`` each: as many
+  key and value heads as query heads), causal depthwise convolution + SiLU on
+  it, the decay projection ``w_f`` at full width (float32 out), ``g =
+  kda_lower_bound * sigmoid(exp(A_log) * (x w_f + dt_bias))`` (``A_log`` a
+  head, ``dt_bias`` a channel: every step's log decay in (``kda_lower_bound``,
+  0), the bound :func:`ops.gated_delta_rule.kda_rule`'s kernels need), ``beta =
+  sigmoid(x w_b)`` a head, the rule (float32 decay, write strength, norms and
+  state whatever the policy), head-wise RMSNorm times ``sigmoid(x w_g)`` at
+  full width, output projection. The aux dict gains ``kda_log_decay_min``.
 * ``"ssm"`` — one fused projection ``xBC | z | dt`` (``ssm_heads x
   ssm_head_dim`` | 2 ``ssm_groups x ssm_state`` || the same inner width ||
   ``ssm_heads``; Mamba-2 publishes the columns as ``z | xBC | dt``: a
@@ -46,7 +60,7 @@ its per-head norms (``qk_norm=False``) or rotary (``rotary_dim=0``).
 * ``"window"`` — the same mixer, a query seeing its last ``window`` keys
   (``flash_attention(window=)``) and rotated over ``window_rotary_dim``
   features (``rotary_dim`` is the ``"full"`` layers'; 0 rotates nothing).
-* ``"latent"`` — multi-head latent attention without a gate or q/k norms:
+* ``"latent"`` — multi-head latent attention, by default without a gate or q/k norms:
   ``w_q`` (hidden, heads x ``qk_nope_dim`` | heads x ``qk_rope_dim``),
   ``w_kva`` (hidden, ``kv_lora_rank`` | ``qk_rope_dim``: the latent and ONE
   rotary key for all heads), ``kv_norm`` on the latent, ``w_kvb`` (latent,
@@ -54,13 +68,23 @@ its per-head norms (``qk_norm=False``) or rotary (``rotary_dim=0``).
   a published ``yarn`` entry) on the rotary features only, scores
   ``(q_nope . k_nope + q_pe . k_pe) * scale`` through
   ``flash_attention(second=)`` — the shared key is never repeated —, ``w_o``.
+  ``latent_qk_norm``: a per-head RMSNorm (``q_norm``, ``k_norm`` over all
+  ``qk_nope_dim + qk_rope_dim`` features) of the assembled query and key before
+  rotary — the shared rotary key then leaves a head's own, its norm being the
+  head's, and rides the kernel with as many heads as the query;
+  ``latent_gate``: ``w_gate`` (hidden, heads), ``sigmoid`` of it on each head's
+  context. Off (the defaults), the mixer is the one above, bit for bit.
 * experts — :func:`transformer.moe.dropless_moe_layer` over the experts held
   here (``experts_held``), router at its full width. With
   ``router_score="sigmoid"`` the step may carry a selection bias, state that
   is no parameter: ``loss_fn(..., router_bias=b)`` routes with it, the aux
   dict returns the step's ``router_counts`` and
   :func:`transformer.moe.router_bias_update` moves it
-  (:meth:`HybridDecoderModel.init_router_bias` starts it).
+  (:meth:`HybridDecoderModel.init_router_bias` starts it). ``router_groups`` >
+  1 limits the choice to the ``router_groups_kept`` best groups of consecutive
+  experts (:func:`transformer.moe.route_topk`'s ``groups``); the aux dict then
+  gains ``router_group_hit`` (expert layers,): the share of tokens whose kept
+  groups hold a group of the experts held here.
 
 ``loop_trips`` > 1 makes the stack a looped one: the same layers are walked
 that many times on the same weights (:meth:`HybridDecoderModel.walk`), the
@@ -83,12 +107,15 @@ except what the half's policy keeps by name (``MIXER_SAVED``,
 ``EXPERTS_SAVED``): a mixer half keeps the results of its kernels — the
 flash call's output and its log-sum-exp rows (``ops.attention.FLASH_SAVED``),
 the delta rule's output and its chunks' entry states
-(``ops.gated_delta_rule.RULE_SAVED``), the state-space scan's likewise
+(``ops.gated_delta_rule.RULE_SAVED``; ``KDA_SAVED`` for the per-channel rule), the
+state-space scan's likewise
 (``ops.ssd.SSD_SAVED``), which their backward rules read, so
 no forward kernel runs twice — and the outputs of its input projections
 (``"mix_proj"``: q, gate, k, v of an attention layer, ``q|k|v|z`` and ``b|a``
-of a delta-rule layer, ``xBC|z|dt`` of a state-space layer); an expert half
-keeps its routing plan. Norms, rotary, the convolution, the gates and
+of a delta-rule layer, ``xBC|z|dt`` of a state-space layer; a ``"kda"`` layer's
+five projections are named ``"kda_proj"`` and NOT kept: 1 GB a layer at 2 x
+8,192 tokens, which five such layers beside 14 B a parameter of state do not
+fit on a chip — they are computed again); an expert half keeps its routing plan. Norms, rotary, the convolution, the gates and
 ``w_o``'s operand are computed again. A looped stack holds ``loop_trips``
 passes of activations for every layer of state, so under ``remat`` it keeps
 the least that spares a kernel its second run (``LOOP_SAVED``): a block is
@@ -114,8 +141,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from apex_tpu.monitor import spans as monitor_spans
 from apex_tpu.ops.attention import FLASH_SAVED, flash_attention
-from apex_tpu.ops.gated_delta_rule import (RULE_SAVED, causal_conv_silu, gated_delta_rule,
-                                           gated_rms_norm)
+from apex_tpu.ops.gated_delta_rule import (KDA_SAVED, RULE_SAVED, causal_conv_silu,
+                                           gated_delta_rule, gated_rms_norm, kda_rule)
 from apex_tpu.ops.rotary import apply_partial_rotary, yarn_mscale
 from apex_tpu.ops.ssd import SSD_SAVED, ssd_scan
 from apex_tpu.transformer import tensor_parallel as tp_lib
@@ -124,15 +151,15 @@ from apex_tpu.transformer.moe import (ACTIVATIONS, FIRST_LEAVES, dropless_moe_la
 
 ATTENTION_KINDS = ("full", "window")
 GROUP_OF_KIND = {"linear": "gdn", "full": "attn", "window": "attn", "latent": "mla",
-                 "ssm": "ssm"}
+                 "ssm": "ssm", "kda": "kda"}
 MIXER_SCOPES = {"linear": "hybrid/gdn", "full": "hybrid/attn", "window": "hybrid/attn_win",
-                "latent": "hybrid/attn_mla", "ssm": "hybrid/ssm"}
+                "latent": "hybrid/attn_mla", "ssm": "hybrid/ssm", "kda": "hybrid/kda"}
 # What ``remat`` keeps of a half beside its arguments, by name. A mixer half:
 # the results of its kernels (the names their forward rules give them: which
 # of them exist in a half follows from the kernels its layer kind runs) and
 # the outputs of its input projections. An expert half: the routing plan
 # (``transformer.moe``).
-MIXER_SAVED = FLASH_SAVED + RULE_SAVED + SSD_SAVED + ("mix_proj",)
+MIXER_SAVED = FLASH_SAVED + RULE_SAVED + KDA_SAVED + SSD_SAVED + ("mix_proj",)
 EXPERTS_SAVED = ("moe_plan",)
 # A looped stack (``loop_trips`` > 1) holds ``loop_trips`` passes of activations
 # for every layer of state, so it keeps the least that spares a kernel its second
@@ -169,12 +196,22 @@ class HybridDecoderConfig:
     v_head_dim: int = 128
     kv_lora_rank: int = 512
     rope_scaling: Any = None
+    # per-head RMSNorm of the assembled (nope | rope) query and key before
+    # rotary (leaves ``q_norm``, ``k_norm``); one sigmoid gate a head on the
+    # context (leaf ``w_gate``). Off: the mixer above, bit for bit
+    latent_qk_norm: bool = False
+    latent_gate: bool = False
     # gated delta-rule layers
     linear_key_heads: int = 16
     linear_value_heads: int = 32
     linear_key_dim: int = 128
     linear_value_dim: int = 128
     conv_kernel: int = 4
+    # "kda" layers: as many key as value heads; the per-step log decay of a
+    # key channel is ``kda_lower_bound * sigmoid(.)``, in (kda_lower_bound, 0)
+    kda_heads: int = 32
+    kda_head_dim: int = 128
+    kda_lower_bound: float = -5.0
     # state-space (Mamba-2) layers: heads of ssm_head_dim, the state's rows,
     # groups that share B and C, tokens a chunk (their convolution has a bias)
     ssm_heads: int = 64
@@ -192,6 +229,12 @@ class HybridDecoderConfig:
     aux_coeff: float = 1e-3
     router_score: str = "softmax"
     route_scale: float = 1.0
+    # group-limited choice: the router's experts are ``router_groups`` groups
+    # of consecutive ones, of which a token keeps ``router_groups_kept`` (by
+    # the sum of each group's two best biased scores) and chooses inside
+    # them; 1 and 1: the choice over the whole row
+    router_groups: int = 1
+    router_groups_kept: int = 1
     shared_gate: bool = True
     # "silu_gate": SwiGLU experts over a fused gate|up; "relu2": ungated
     # relu(x W_up)^2 W_down (leaves w_up, shared_up)
@@ -225,7 +268,7 @@ class HybridDecoderConfig:
     def __post_init__(self):
         bad = set(self.layer_types) - set(GROUP_OF_KIND)
         if bad or not self.layer_types:
-            raise ValueError("layer_types holds 'linear', 'ssm', 'full', 'window' and "
+            raise ValueError("layer_types holds 'linear', 'kda', 'ssm', 'full', 'window' and "
                              f"'latent', got {self.layer_types!r}")
         if hasattr(self.rope_scaling, "items"):             # hashable, as the rest
             object.__setattr__(self, "rope_scaling", tuple(sorted(self.rope_scaling.items())))
@@ -239,6 +282,10 @@ class HybridDecoderConfig:
                              f"got {self.expert_activation!r}")
         if "ssm" in self.layer_types and self.ssm_heads % self.ssm_groups:
             raise ValueError("state-space heads must be a multiple of their groups")
+        if (self.router_experts % self.router_groups
+                or not 1 <= self.router_groups_kept <= self.router_groups):
+            raise ValueError("router_groups divides the router's width and keeps 1 to all of "
+                             f"them, got {self.router_groups} / {self.router_groups_kept}")
         if self.loop_trips < 1:
             raise ValueError(f"loop_trips counts the walks of the stack, got {self.loop_trips}")
         if self.router_score not in ("softmax", "sigmoid"):
@@ -343,8 +390,8 @@ class HybridDecoderModel:
         1/sqrt(2 L ``loop_trips``); decay ``A ~ U(1, 16)``, ``dt ~ logU(1e-3, 1e-1)``)."""
         c = self.config
         H, L = c.hidden_size, len(c.layer_types)
-        Lg, Ll, Ls = (c.layer_types.count(kind) for kind in ("linear", "latent", "ssm"))
-        La = L - Lg - Ll - Ls
+        Lg, Ll, Ls, Lk = (c.layer_types.count(kind) for kind in ("linear", "latent", "ssm", "kda"))
+        La = L - Lg - Ll - Ls - Lk
         Lm, Ld = c.ffn.count("moe"), c.ffn.count("dense")
         qk, vv = c.linear_key_heads * c.linear_key_dim, c.linear_value_heads * c.linear_value_dim
         keys = iter(jax.random.split(key, 32))
@@ -414,6 +461,15 @@ class HybridDecoderModel:
         }
         if Ls:                 # drawn last: the other groups keep the keys they had
             layers["ssm"] = self._init_ssm(Ls, n, keys, res)
+        # what later kinds and switches add draws from a stream of its own
+        keys = iter(jax.random.split(jax.random.fold_in(key, 1), 16))
+        if Lk:
+            layers["kda"] = self._init_kda(Lk, n, keys, res)
+        if Ll and c.latent_qk_norm:
+            layers["mla"].update(q_norm=unit((Ll, c.qk_nope_dim + c.qk_rope_dim)),
+                                 k_norm=unit((Ll, c.qk_nope_dim + c.qk_rope_dim)))
+        if Ll and c.latent_gate:
+            layers["mla"]["w_gate"] = n((Ll, H, c.num_heads))
         if c.loop_trips > 1:   # one Linear(hidden, 1) with bias, shared by the walks
             params["exit_gate"] = {"weight": n((H, 1)), "bias": zeros((1,))}
         return params
@@ -437,6 +493,24 @@ class HybridDecoderModel:
             "D": jnp.ones((Ls, nh), jnp.float32),
             "norm_w": jnp.ones((Ls, inner), c.dtype),
             "w_o": n((Ls, inner, H), res),
+        }
+
+    def _init_kda(self, Lk, n, keys, res):
+        """The per-channel delta-rule group: rates ``exp(A_log) ~ U(0.5, 2)`` a
+        head and ``dt_bias ~ U(-3, 3)`` a channel, so that the per-step
+        decays spread over (e^kda_lower_bound, 1) and not at one end."""
+        c = self.config
+        H, hd = c.hidden_size, c.kda_heads * c.kda_head_dim
+        return {
+            "w_qkv": n((Lk, H, 3 * hd)), "w_f": n((Lk, H, hd)), "w_g": n((Lk, H, hd)),
+            "w_b": n((Lk, H, c.kda_heads)),
+            "conv_w": jax.random.uniform(next(keys), (Lk, c.conv_kernel, 3 * hd),
+                                         jnp.float32, -0.5, 0.5).astype(c.dtype),
+            "A_log": jnp.log(jax.random.uniform(next(keys), (Lk, c.kda_heads), jnp.float32,
+                                                0.5, 2.0)),
+            "dt_bias": jax.random.uniform(next(keys), (Lk, hd), jnp.float32, -3.0, 3.0),
+            "norm_w": jnp.ones((Lk, c.kda_head_dim), c.dtype),
+            "w_o": n((Lk, hd, H), res),
         }
 
     def init_router_bias(self):
@@ -468,6 +542,37 @@ class HybridDecoderModel:
         o = gated_rms_norm(o, qkvz, p["norm_w"], c.rms_eps, impl=c.delta_impl)
         with monitor_spans.span("mix/proj_out"):
             return jnp.dot(o.reshape(b, s, hv * dv), p["w_o"])
+
+    def _kda_mixer(self, p, x):
+        """(what the mixer adds, the smallest per-step log decay it took)."""
+        c = self.config
+        b, s, _ = x.shape
+        h, d = c.kda_heads, c.kda_head_dim
+        f32 = jnp.float32
+        with monitor_spans.span("mix/proj_in"):
+            # named, and not among MIXER_SAVED: five such layers' projections (1 GB a
+            # layer at 2 x 8,192 tokens) beside 14 B a parameter of state do not fit a chip
+            qkv = checkpoint_name(jnp.dot(x, p["w_qkv"]), "kda_proj")
+            a = checkpoint_name(jnp.dot(x, p["w_f"], preferred_element_type=f32), "kda_proj")
+            gate = checkpoint_name(jnp.dot(x, p["w_g"]), "kda_proj")
+            b_logit = checkpoint_name(jnp.dot(x, p["w_b"], preferred_element_type=f32),
+                                      "kda_proj")
+        # q|k|v are read where the projection left them: no slice of qkv
+        q, k, v = causal_conv_silu(qkv, p["conv_w"], widths=(h * d,) * 3, impl=c.delta_impl)
+        with monitor_spans.span("mix/place"):
+            beta = jax.nn.sigmoid(b_logit)
+            rate = jnp.exp(p["A_log"].astype(f32))[:, None]
+            g = c.kda_lower_bound * jax.nn.sigmoid(
+                rate * (a + p["dt_bias"].astype(f32)).reshape(b, s, h, d))
+        heads = lambda z: z.reshape(b, s, h, d)  # noqa: E731
+        o = kda_rule(heads(q), heads(k), heads(v), g, beta, impl=c.delta_impl)
+        with monitor_spans.span("mix/place"):
+            o = o.astype(f32)
+            y = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + c.rms_eps)
+            y = (y * p["norm_w"].astype(f32) * jax.nn.sigmoid(heads(gate).astype(f32))
+                 ).astype(x.dtype)
+        with monitor_spans.span("mix/proj_out"):
+            return jnp.dot(y.reshape(b, s, h * d), p["w_o"]), jnp.min(g)
 
     def _ssm_mixer(self, p, x):
         c = self.config
@@ -536,6 +641,8 @@ class HybridDecoderModel:
             q_pe = jnp.dot(x, p["w_q"][:, nh * dn:]).reshape(b, s, nh, dr)
             latent = self._norm(jnp.dot(x, p["w_kva"][:, :rank]), p["kv_norm"])
             k_pe = jnp.dot(x, p["w_kva"][:, rank:]).reshape(b, s, 1, dr)
+            if c.latent_gate:
+                gate = jnp.dot(x, p["w_gate"], preferred_element_type=jnp.float32)
         with monitor_spans.span("mla/up"):
             k_nope = jnp.dot(latent, p["w_kvb"][:, :nh * dn]).reshape(b, s, nh, dn)
             v = jnp.dot(latent, p["w_kvb"][:, nh * dn:]).reshape(b, s, nh, dv)
@@ -544,12 +651,33 @@ class HybridDecoderModel:
             entry = dict(c.rope_scaling)
             scale *= yarn_mscale(entry["factor"], entry.get("mscale_all_dim", 0.0)) ** 2
         with monitor_spans.span("mix/place"):
+            if c.latent_qk_norm:
+                (q_nope, q_pe), (k_nope, k_pe) = (
+                    self._assembled_norm(nope, pe, w)
+                    for nope, pe, w in ((q_nope, q_pe, p["q_norm"]), (k_nope, k_pe, p["k_norm"])))
             q_pe, k_pe = (apply_partial_rotary(a, dr, c.rope_theta, scaling=c.rope_scaling)
                           for a in (q_pe, k_pe))
         ctx = flash_attention(q_nope, k_nope, v, causal=True, scale=scale, layout="bshd",
                               impl=c.attention_impl, second=(q_pe, k_pe))
+        if c.latent_gate:
+            with monitor_spans.span("mix/place"):
+                ctx = ctx * jax.nn.sigmoid(gate)[..., None].astype(ctx.dtype)
         with monitor_spans.span("mix/proj_out"):
             return jnp.dot(ctx.reshape(b, s, nh * dv), p["w_o"])
+
+    def _assembled_norm(self, nope, pe, w):
+        """The RMSNorm of a latent head's assembled ``nope | pe`` features
+        (``w`` over all of them), returned apart as they came: ``nope`` (b, s,
+        h, dn); ``pe`` (b, s, h or 1, dr) — a rotary key shared by the heads
+        leaves a head's own, its norm being the head's."""
+        c = self.config
+        dn, f32 = nope.shape[-1], jnp.float32
+        n32, p32, w = nope.astype(f32), pe.astype(f32), w.astype(f32)
+        w = 1.0 + w if c.zero_centered_norm else w
+        r = jax.lax.rsqrt((jnp.sum(n32 * n32, -1, keepdims=True)
+                           + jnp.sum(p32 * p32, -1, keepdims=True)) / (dn + pe.shape[-1])
+                          + c.rms_eps)
+        return (n32 * r * w[:dn]).astype(nope.dtype), (p32 * r * w[dn:]).astype(pe.dtype)
 
     def _experts(self, p, x, router_bias=None):
         c = self.config
@@ -557,7 +685,8 @@ class HybridDecoderModel:
             p, x, top_k=c.top_k, experts_held=c.held, normalize_weights=c.normalize_topk,
             impl=c.experts_impl, score=c.router_score, route_scale=c.route_scale,
             router_bias=router_bias, shared_gate=c.shared_gate,
-            sequence_balance=c.seq_aux, activation=c.expert_activation)
+            sequence_balance=c.seq_aux, activation=c.expert_activation,
+            groups=c.router_groups, groups_kept=c.router_groups_kept)
 
     @staticmethod
     def _dense(p, x):
@@ -578,6 +707,9 @@ class HybridDecoderModel:
         def half(p, w, post, x):
             with monitor_spans.span(MIXER_SCOPES[kind]):
                 h = self._norm(x, w)
+                if kind == "kda":          # hands its smallest log decay out beside the stream
+                    y, low = self._kda_mixer(p, h)
+                    return x + self._added(y, post), low
                 y = mixers[kind](p, h) if kind in mixers else self._attention_mixer(p, h, kind)
                 return x + self._added(y, post)
 
@@ -609,7 +741,9 @@ class HybridDecoderModel:
         """One walk of the stack from the stream ``x`` through the final norm:
         (the normed output, aux): ``load_balance_loss`` (mean over the expert
         layers), ``expert_load`` (expert layers, held) and ``router_counts``
-        (expert layers, router width) int32, ``dropped`` (). ``router_bias``
+        (expert layers, router width) int32, ``dropped`` (); with a group-limited
+        router ``router_group_hit`` (expert layers,), with ``"kda"`` layers
+        ``kda_log_decay_min`` (). ``router_bias``
         (expert layers, router width): the routers' selection bias, where the
         step carries one. Under ``remat`` each half of a block is recomputed
         by itself (``MIXER_SAVED``, ``EXPERTS_SAVED``); in a looped stack the
@@ -620,8 +754,8 @@ class HybridDecoderModel:
         expert_half = self._recomputed(self._expert_half, EXPERTS_SAVED)
         dense_half = self._recomputed(self._dense_half)
         post1, post2 = layers.get("norm1_post"), layers.get("norm2_post")
-        seen = {"gdn": 0, "ssm": 0, "attn": 0, "mla": 0, "moe": 0, "dense": 0}
-        lb, loads, counts, dropped = 0.0, [], [], 0
+        seen = {"gdn": 0, "ssm": 0, "attn": 0, "mla": 0, "kda": 0, "moe": 0, "dense": 0}
+        lb, loads, counts, dropped, hits, lows = 0.0, [], [], 0, [], []
 
         def take(group):
             j = seen[group]
@@ -636,18 +770,23 @@ class HybridDecoderModel:
             def block(p_mix, p_ffn, x):            # layer i, run before the loop moves on
                 x = self._mixer_half(kind)(p_mix, layers["norm1"][i],
                                            None if post1 is None else post1[i], x)
+                x, low = x if kind == "kda" else (x, None)
                 if ffn == "none":
-                    return x, None
+                    return x, None, low
                 post = None if post2 is None else post2[second]
                 if ffn == "dense":
-                    return dense_half(p_ffn, layers["norm2"][second], post, x), None
+                    return dense_half(p_ffn, layers["norm2"][second], post, x), None, low
                 bias = None if router_bias is None else router_bias[j]
-                return expert_half(p_ffn, layers["norm2"][second], post, bias, x)
+                return (*expert_half(p_ffn, layers["norm2"][second], post, bias, x), low)
 
-            x, aux = self._recomputed(block, LOOP_SAVED, block=True)(p_mix, p_ffn, x)
+            x, aux, low = self._recomputed(block, LOOP_SAVED, block=True)(p_mix, p_ffn, x)
             second += ffn != "none"
+            if low is not None:
+                lows.append(low)
             if aux is None:
                 continue
+            if "router_group_hit" in aux:
+                hits.append(aux["router_group_hit"])
             lb = lb + aux["load_balance_loss"]
             loads.append(aux["expert_load"])
             counts.append(aux["router_counts"])
@@ -657,6 +796,10 @@ class HybridDecoderModel:
         aux = {"load_balance_loss": lb / max(len(loads), 1),
                "expert_load": stacked(loads, c.held[1]),
                "router_counts": stacked(counts, c.router_experts), "dropped": dropped}
+        if hits:               # a group-limited router's: the share of tokens a layer whose
+            aux["router_group_hit"] = jnp.stack(hits)      # kept groups hold the held experts'
+        if lows:               # the smallest per-step log decay the "kda" layers took
+            aux["kda_log_decay_min"] = jnp.min(jnp.stack(lows))
         return self._recomputed(self._norm, block=True)(x, params["norm_f"]), aux
 
     def trip_states(self, params, tokens, router_bias=None):

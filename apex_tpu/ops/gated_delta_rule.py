@@ -19,6 +19,17 @@ over them — the same mathematics, the oracle the kernels are tested
 against, as every kernel family here has one, and the path for shapes the
 kernels refuse.
 
+:func:`kda_rule` is the same recurrence with the decay a vector a key channel
+(``g_t`` in R^dk a head: ``S <- Diag(exp(g_t)) S``; Kimi Delta Attention). The
+in-chunk matrix is then ``beta_i sum_c k_ic k_jc e^{G_ic - G_jc}``, which no
+longer factors into a matmul and a (C, C) mask: the kernel pair ``kda_fwd`` /
+``kda_bwd`` (``ops/pallas/kda.py``) makes it a sub-chunk of 16 rows at a time
+from float32 factors that stay inside float32's range as long as every
+step's log decay is at least ``KDA_LOG_DECAY_MIN`` (-5: what a bounded gate
+gives), and everything after it — the inverse, the walk, the saved results —
+is the scalar rule's; its ``impl="xla"`` oracle takes the explicit
+differences chunk by chunk in a ``lax.scan``.
+
 Also here: :func:`causal_conv_silu` (the depthwise causal convolution that
 precedes the rule) and :func:`gated_rms_norm` (the head-wise RMSNorm times
 ``silu(gate)`` that follows it). Both take ``impl`` as the rule does and
@@ -45,6 +56,7 @@ from apex_tpu.amp.lists import apply_op_rules
 from apex_tpu.ops import _backend
 from apex_tpu.ops.pallas import delta_mixer as _m
 from apex_tpu.ops.pallas import gated_delta_rule as _k
+from apex_tpu.ops.pallas import kda as _kda
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -377,6 +389,27 @@ def _rule_bwd(heads, interpret, res, do):
 _rule_pallas.defvjp(_rule_fwd, _rule_bwd)
 
 
+def _chunked(t, chunk, whole_steps):
+    """A sequence of ``t`` tokens in chunks: (their number — whole grid steps
+    of the kernels' ``CHUNKS`` where ``whole_steps`` and there are more than
+    one step's —, ``padded``: (b, t, ...) with the tail filled with tokens that
+    neither decay nor write, ``chunks``: (b, t, heads, ...) -> (b, heads, n,
+    C, ...))."""
+    n = -(-t // chunk)
+    if whole_steps and n > _k.CHUNKS:
+        n = -(-n // _k.CHUNKS) * _k.CHUNKS
+    pad = n * chunk - t
+
+    def padded(x):
+        return jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2)) if pad else x
+
+    def chunks(x):
+        x = jnp.moveaxis(padded(x), 1, 2)
+        return x.reshape(x.shape[:2] + (n, chunk) + x.shape[3:])
+
+    return n, padded, chunks
+
+
 def shapes_ok(dk: int, dv: int, chunk: int) -> bool:
     """What the kernels' blocks need: features in whole lanes (a head is a
     lane block of the projections' arrays), chunks in whole sublane tiles of
@@ -405,19 +438,8 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, impl: str = "auto"):
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
     use_kernel = _backend.choose_impl(impl, shapes_ok(dk, dv, chunk)) == "pallas"
-    n = -(-t // chunk)
-    if use_kernel and n > _k.CHUNKS:
-        n = -(-n // _k.CHUNKS) * _k.CHUNKS         # whole grid steps
-    pad = n * chunk - t
+    n, padded, chunks = _chunked(t, chunk, use_kernel)
     g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
-
-    def padded(x):
-        return jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2)) if pad else x
-
-    def chunks(x):                         # (b, t, heads, ...) -> (b, heads, n, C, ...)
-        x = jnp.moveaxis(padded(x), 1, 2)
-        return x.reshape(x.shape[:2] + (n, chunk) + x.shape[3:])
-
     if use_kernel:
         # only the small float32 g and beta are re-laid (a chunk a row); q, k, v
         # and o keep the layout of the projections, a head a lane block
@@ -433,3 +455,117 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64, impl: str = "auto"):
                           v.dtype)
     o = _recurrence_xla(*ops).reshape(b, hv, n * chunk, dv)
     return jnp.moveaxis(o, 1, 2)[:, :t]
+
+
+# --- the rule with a decay a key channel (KDA) --------------------------------
+
+KDA_LOG_DECAY_MIN = _kda.LOG_DECAY_MIN
+# ``kda_fwd``'s results by the names a ``jax.checkpoint`` policy keeps them
+# under, as ``RULE_SAVED`` are ``gdn_fwd``'s
+KDA_SAVED = ("kda_o", "kda_s0")
+
+_kda_fwd = jax.jit(_kda.kda_fwd, static_argnames=("interpret",))
+_kda_bwd = jax.jit(_kda.kda_bwd, static_argnames=("interpret",))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _kda_pallas(q, k, v, g, beta, interpret):
+    """The rule on the kernels: ``q, k, v`` (b, T, h d) as the convolution
+    leaves them, ``g`` (b, T, h dk) float32 the per-step log decay, ``beta``
+    (b, h, n, C) float32."""
+    return _kda_fwd(q, k, v, g, beta, interpret=interpret)[0]
+
+
+def _kda_rule_fwd(q, k, v, g, beta, interpret):
+    o, s0 = map(checkpoint_name, _kda_fwd(q, k, v, g, beta, interpret=interpret), KDA_SAVED)
+    return o, (q, k, v, g, beta, s0)
+
+
+def _kda_rule_bwd(interpret, res, do):
+    return tuple(_kda_bwd(*res, do, interpret=interpret))
+
+
+_kda_pallas.defvjp(_kda_rule_fwd, _kda_rule_bwd)
+
+
+def _kda_chunk(q, k, v, g, beta, dtype):
+    """One chunk's operands by the explicit differences: q, k (b, h, C, dk)
+    float32 (normalised, q scaled), v (b, h, C, dv), g (b, h, C, dk), beta
+    (b, h, C) -> w, u, qg, kg, p in ``dtype`` and gam (b, h, dk)."""
+    hi = jax.lax.Precision.DEFAULT if dtype == jnp.bfloat16 else _HI
+    C = q.shape[-2]
+    G = jnp.cumsum(g, axis=-2)
+    tri = jnp.tril(jnp.ones((C, C), bool))
+    decay = jnp.exp(jnp.where(tri[..., None], G[..., :, None, :] - G[..., None, :, :], -jnp.inf))
+    kk = jnp.einsum("...ic,...jc,...ijc->...ij", k, k, decay, precision=_HI)
+    qk = jnp.einsum("...ic,...jc,...ijc->...ij", q, k, decay, precision=_HI)
+    a = jnp.where(jnp.tril(tri, -1), beta[..., None] * kk, 0.0)
+    t = _unit_lower_inverse(a, hi)
+    eg = jnp.exp(G)
+    w = jnp.einsum("...ij,...jk->...ik", t, beta[..., None] * eg * k, precision=hi)
+    u = jnp.einsum("...ij,...jk->...ik", t, beta[..., None] * v.astype(jnp.float32),
+                   precision=hi)
+    cast = lambda x: x.astype(dtype)  # noqa: E731
+    return (cast(w), cast(u), cast(q * eg), cast(k * jnp.exp(G[..., -1:, :] - G)),
+            cast(jnp.where(tri, qk, 0.0)), eg[..., -1, :])
+
+
+def _kda_xla(q, k, v, g, beta, dtype):
+    """(b, h, n, C, .) inputs: a scan over the chunks, each preparing its
+    operands (recomputed in the backward pass) and moving the state."""
+    f32 = jnp.float32
+    mm = functools.partial(jnp.einsum, preferred_element_type=f32, precision=_HI)
+    prepare = jax.checkpoint(functools.partial(_kda_chunk, dtype=dtype))
+
+    def chunk(state, x):
+        w, u, qg, kg, p, gam = prepare(*x)
+        s = state.astype(dtype)
+        v_new = u.astype(f32) - mm("bhck,bhkv->bhcv", w, s)
+        o = mm("bhck,bhkv->bhcv", qg, s) + mm("bhcj,bhjv->bhcv", p, v_new.astype(dtype))
+        state = state * gam[..., None] + mm("bhck,bhcv->bhkv", kg, v_new.astype(dtype))
+        return state, o.astype(dtype)
+
+    b, h, n, C, dk = q.shape
+    xs = jax.tree.map(lambda a: jnp.moveaxis(a, 2, 0), (q, k, v, g, beta))
+    _, o = jax.lax.scan(chunk, jnp.zeros((b, h, dk, v.shape[-1]), f32), xs)
+    return jnp.moveaxis(o, 0, 2)
+
+
+def kda_shapes_ok(dk: int, dv: int, chunk: int) -> bool:
+    """What the KDA kernels' blocks need: :func:`shapes_ok`, and chunks in
+    whole sub-chunks of the scores."""
+    return shapes_ok(dk, dv, chunk) and chunk % _kda.SUB == 0
+
+
+def kda_rule(q, k, v, g, beta, *, chunk: int = 64, impl: str = "auto"):
+    """The delta rule with a decay a key channel over whole sequences from a
+    zero state: ``S <- Diag(exp(g_t)) S; u_t = beta_t (v_t - S^T k_t); S <- S
+    + k_t u_t^T; o_t = S^T q_t``.
+
+    ``q``, ``k`` (b, t, h, dk) as they leave the convolution — L2-normalised
+    here, ``q`` scaled by ``dk ** -0.5``; ``v`` (b, t, h, dv), as many value
+    as key heads; ``g`` (b, t, h, dk) the log decay of every key channel and
+    ``beta`` (b, t, h), float32 whatever the inputs. Returns ``o`` (b, t, h,
+    dv) in ``v``'s dtype. The tail of a ``t`` that is no multiple of
+    ``chunk`` is padded with tokens that neither decay nor write. HALF-class
+    under O1, as :func:`gated_delta_rule`.
+
+    ``impl``: ``auto`` | ``pallas`` | ``xla`` — the ``kda_fwd`` / ``kda_bwd``
+    kernels, which need every ``g >= KDA_LOG_DECAY_MIN`` (their float32
+    factors reach ``e^{15 |g|}``: a gate bounded below, ``-5 sigmoid(.)``,
+    gives it; nothing checks it), or the explicit differences chunk by chunk
+    in a ``lax.scan``, which take any ``g <= 0``."""
+    q, k, v = apply_op_rules("kda_rule", q, k, v)
+    b, t, h, dk = q.shape
+    dv = v.shape[3]
+    use_kernel = _backend.choose_impl(impl, kda_shapes_ok(dk, dv, chunk)) == "pallas"
+    n, padded, chunks = _chunked(t, chunk, use_kernel)
+    g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
+    if use_kernel:
+        flat = lambda x: padded(x).reshape(b, n * chunk, -1)  # noqa: E731
+        o = _kda_pallas(flat(q), flat(k), flat(v), flat(g), chunks(beta),
+                        _backend.interpret_mode())
+        return o.reshape(b, n * chunk, h, dv)[:, :t]
+    qn = l2_normalize(q) * dk ** -0.5
+    o = _kda_xla(chunks(qn), chunks(l2_normalize(k)), chunks(v), chunks(g), chunks(beta), v.dtype)
+    return jnp.moveaxis(o.reshape(b, h, n * chunk, dv), 1, 2)[:, :t]

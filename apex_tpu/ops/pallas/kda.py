@@ -1,0 +1,341 @@
+"""Pallas kernels of the chunked delta rule whose decay is a vector a key
+channel (KDA): ``ops/pallas/gated_delta_rule.py``'s scheme — a chunk's
+operands built in VMEM, the chunks walked with the state there too, only
+``q, k, v, g, beta`` and ``o`` across HBM — with the one thing a per-channel
+decay changes: the in-chunk scores no longer factor into a matmul and a
+(C, C) mask.
+
+Per chunk of ``C`` tokens and head, with ``g`` (C, dk) the per-step log decay
+of every key channel, ``G = cumsum(g)`` inside the chunk (here, by a
+triangular matmul), ``qn``/``kn`` the L2-normalised queries and keys and
+float32 state ``S (dk, dv)``:
+
+    kk_ij = sum_c kn_ic kn_jc e^{G_ic - G_jc},  qk_ij likewise   (i >= j)
+    a = tril(beta kk, -1);   T = (I + a)^-1;   p = tril(qk)
+    w = T (beta e^G kn);   u = T (beta v);   qg = qn e^G;   kg = kn e^{G_C - G}
+    v' = u - w S;   o = qg S + p v';   S <- Diag(e^{G_C}) S + kg^T v'
+
+Only differences ``G_i - G_j``, ``i >= j``, occur, all <= 0. The scores are
+made a sub-chunk of ``SUB`` rows at a time (:func:`_scores`): with ``r`` the
+first row of the sub-chunk, rows ``i`` of it against every column ``j`` up to
+its own last are ONE float32 matmul of ``kn e^{G - G_r}`` with ``kn e^{G_r -
+G}``. For ``j`` before the sub-chunk both factors are <= 1; for ``j`` inside
+it the second is ``e^{-(G_j - G_r)}``, at most ``e^{(SUB - 1) |g|_max}`` —
+``e^75`` at ``LOG_DECAY_MIN`` = -5 a step, inside float32 (``e^88``), which is
+the bound the kernels need of ``g`` and why a model that runs them bounds its
+gate. Those factors are float32 operands at ``HIGHEST``, never bf16.
+
+Everything after the scores is ``gdn_fwd`` / ``gdn_bwd``'s: the blocked
+inverse (:func:`gated_delta_rule._inverse`, imported), the operands in the
+inputs' dtype, the walk, the states a block of chunks started from, the
+backward's order. The state is held transposed, ``S^T (dv, dk)``, so that its
+per-channel decay ``e^{G_C}`` is a row over the lanes.
+
+Layout: ``q, k, v, o`` (b, T, h d) as the projections leave them, ``g`` (b, T,
+h dk) float32 in the same layout, one head a lane block picked by the index
+map (as many key as value heads); ``beta`` (b, h, n, C) float32, a chunk a
+row.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from apex_tpu.ops.pallas.gated_delta_rule import (_OPERANDS, CHUNKS, NN, NT, TN, UNROLL, _column,
+                                                  _inverse, _masks, _mm, _normalized,
+                                                  _normalized_bwd, _precision, _row)
+
+SUB = 16                  # rows of a sub-chunk of the scores
+LOG_DECAY_MIN = -5.0      # the smallest per-step log decay the kernels take: (SUB - 1) * 5 < 88
+_F32 = jnp.float32
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _cumulated(g, dims=NN):
+    """``G = cumsum(g)`` over the chunk's rows, float32: a lower-triangular
+    matmul of ones (exact factors, float32 passes). ``dims=TN``: its
+    transpose, the sums from each row on (the cumsum's cotangent)."""
+    row, col = _masks(g.shape[0])
+    return _mm(jnp.where(row >= col, 1.0, 0.0).astype(_F32), g, dims, _HI)
+
+
+def _sub_chunks(kn, qn, G):
+    """The factors of every sub-chunk's scores: ``[(ea (SUB, dk), fa (C, dk),
+    ra (2 SUB, dk), ca (C, dk))]`` with ``ea = e^{G_i - G_r}`` over the
+    sub-chunk's rows, ``fa = e^{G_r - G_j}`` over the columns up to its last
+    (0 after), ``ra = [kn ea; qn ea]`` and ``ca = kn fa``."""
+    C = kn.shape[0]
+    j = jax.lax.broadcasted_iota(jnp.int32, G.shape, 0)
+    out = []
+    for a in range(C // SUB):
+        lo, hi = a * SUB, (a + 1) * SUB
+        gr = G[lo:lo + 1]
+        ea = jnp.exp(G[lo:hi] - gr)
+        fa = jnp.exp(jnp.where(j < hi, gr - G, -jnp.inf))
+        out.append((ea, fa, jnp.concatenate([kn[lo:hi] * ea, qn[lo:hi] * ea], axis=0), kn * fa))
+    return out
+
+
+def _scores(subs):
+    """``kk, qk`` (C, C): exact at ``i >= j``; above the diagonal finite and
+    never read."""
+    blocks = [_mm(ra, ca, NT, _HI) for _, _, ra, ca in subs]
+    return (jnp.concatenate([s[:SUB] for s in blocks], axis=0),
+            jnp.concatenate([s[SUB:] for s in blocks], axis=0))
+
+
+def _chunk(q, k, v, g, b_row, row, col):
+    """One chunk's elementwise operands, float32: everything but ``T``'s
+    products."""
+    C, dk = q.shape
+    qn, rq = _normalized(q, dk ** -0.5)
+    kn, rk = _normalized(k, 1.0)
+    G = _cumulated(g)
+    subs = _sub_chunks(kn, qn, G)
+    kk, qk = _scores(subs)
+    b_col = _column(b_row, row == col)
+    eg = jnp.exp(G)
+    tail = jnp.exp(G[C - 1:C] - G)
+    return dict(qn=qn, kn=kn, rq=rq, rk=rk, subs=subs, kk=kk, b_col=b_col, eg=eg,
+                tail=tail, a=jnp.where(row > col, b_col * kk, 0.0),
+                p=jnp.where(row >= col, qk, 0.0), bk=b_col * eg * kn,
+                bv=b_col * v.astype(_F32), qg=qn * eg, kg=kn * tail, gam=eg[C - 1:C])
+
+
+def _prepare_group(chunks, refs, scr, *, C, keep_t):
+    """The operands of the chunks ``chunks``, written to the scratch: side by
+    side, their triangular systems inverted as one batch."""
+    q_ref, k_ref, v_ref, g_ref, b_ref = refs
+    dt = v_ref.dtype
+    pr = _precision(dt)
+    row, col = _masks(C)
+    made = []
+    for c in chunks:
+        rows = pl.ds(pl.multiple_of(c * C, C), C)
+        made.append((c, rows, _chunk(q_ref[rows, :], k_ref[rows, :], v_ref[rows, :],
+                                     g_ref[rows, :], b_ref[pl.ds(c, 1), :], row, col)))
+    inverses = _inverse(jnp.stack([x["a"] for _, _, x in made])).astype(dt)
+    for (c, rows, x), t in zip(made, inverses):
+        scr["w"][rows, :] = _mm(t, x["bk"].astype(dt), NN, pr).astype(dt)
+        scr["u"][rows, :] = _mm(t, x["bv"].astype(dt), NN, pr).astype(dt)
+        scr["qg"][rows, :] = x["qg"].astype(dt)
+        scr["kg"][rows, :] = x["kg"].astype(dt)
+        scr["p"][rows, :] = x["p"].astype(dt)
+        scr["gam"][pl.ds(c, 1), :] = x["gam"]
+        if keep_t:
+            scr["t"][rows, :] = t
+
+
+def _prepare(chunks, refs, scr, **kw):
+    group = UNROLL if chunks % UNROLL == 0 else 1
+
+    def step(i, carry):
+        _prepare_group([i * group + j for j in range(group)], refs, scr, **kw)
+        return carry
+
+    jax.lax.fori_loop(0, chunks // group, step, 0)
+
+
+def _recur(st, scr, rows, gam_row, dt):
+    """One chunk of the recurrence on prepared operands, the state transposed
+    (dv, dk): (new state, v', o), all float32."""
+    pr = _precision(dt)
+    s = st.astype(dt)
+    v_new = scr["u"][rows, :].astype(_F32) - _mm(scr["w"][rows, :], s, NT, pr)
+    v_lo = v_new.astype(dt)
+    o = _mm(scr["qg"][rows, :], s, NT, pr) + _mm(scr["p"][rows, :], v_lo, NN, pr)
+    st = st * gam_row + _mm(v_lo, scr["kg"][rows, :], TN, pr)
+    return st, v_new, o
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s0_ref, s_scr, gam_scr, *operands,
+                chunks, C):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_scr[...] = jnp.zeros_like(s_scr)
+
+    s0_ref[...] = s_scr[...]
+    dt = v_ref.dtype
+    scr = dict(zip(_OPERANDS, operands), gam=gam_scr)
+    _prepare(chunks, (q_ref, k_ref, v_ref, g_ref, b_ref), scr, C=C, keep_t=False)
+
+    def walk(c, carry):
+        rows = pl.ds(pl.multiple_of(c * C, C), C)
+        st, _, o = _recur(s_scr[...], scr, rows, gam_scr[pl.ds(c, 1), :], dt)
+        s_scr[...] = st
+        o_ref[rows, :] = o.astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, chunks, walk, 0)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, db_ref,
+                ds_scr, states_scr, vnew_scr, du_scr, dkg_scr, dgc_scr, gam_scr, *operands,
+                chunks, C):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_scr[...] = jnp.zeros_like(ds_scr)
+
+    dt = v_ref.dtype
+    pr = _precision(dt)
+    dk_ = q_ref.shape[-1]
+    scr = dict(zip(_OPERANDS + ("t",), operands), gam=gam_scr)
+    lo = lambda z: z.astype(dt)  # noqa: E731
+
+    _prepare(chunks, (q_ref, k_ref, v_ref, g_ref, b_ref), scr, C=C, keep_t=True)
+
+    def rebuild(c, st):                    # the block's states and v', in order
+        rows = pl.ds(pl.multiple_of(c * C, C), C)
+        states_scr[c] = st
+        st, v_new, _ = _recur(st, scr, rows, gam_scr[pl.ds(c, 1), :], dt)
+        vnew_scr[rows, :] = v_new.astype(dt)
+        return st
+
+    jax.lax.fori_loop(0, chunks, rebuild, s0_ref[...])
+
+    def carry_back(i, carry):              # dS through the chunks, last to first
+        c = chunks - 1 - i
+        rows = pl.ds(pl.multiple_of(c * C, C), C)
+        one = pl.ds(c, 1)
+        st, ds = states_scr[c], ds_scr[...]
+        ds_lo = lo(ds)
+        do = do_ref[rows, :]
+        gam = gam_scr[one, :]
+        du = lo(_mm(scr["p"][rows, :], do, TN, pr) + _mm(scr["kg"][rows, :], ds_lo, NT, pr))
+        du_scr[rows, :] = du                                                   # = dv'
+        dkg_scr[rows, :] = _mm(vnew_scr[rows, :], ds_lo, NN, pr)
+        dgc_scr[one, :] = jnp.sum(st * ds, axis=0, keepdims=True) * gam
+        ds_scr[...] = (ds * gam + _mm(do, scr["qg"][rows, :], TN, pr)
+                       - _mm(du, scr["w"][rows, :], TN, pr))
+        return carry
+
+    jax.lax.fori_loop(0, chunks, carry_back, 0)
+
+    def inputs(c):                         # the operands' cotangents -> q, k, v, g, beta's
+        rows = pl.ds(pl.multiple_of(c * C, C), C)
+        one = pl.ds(c, 1)
+        row, col = _masks(C)
+        eye = row == col
+        lanes = lambda z: jnp.sum(z, axis=1, keepdims=True)  # noqa: E731
+        v = v_ref[rows, :]
+        x = _chunk(q_ref[rows, :], k_ref[rows, :], v, g_ref[rows, :], b_ref[one, :], row, col)
+        qn, kn = x["qn"], x["kn"]
+        t, v_new, du = scr["t"][rows, :], vnew_scr[rows, :], du_scr[rows, :]
+        s = lo(states_scr[c])
+        do = do_ref[rows, :]
+        dkg = dkg_scr[rows, :]
+        # the recurrence's operands
+        dp = jnp.where(row >= col, _mm(do, v_new, NT, pr), 0.0)
+        dqg = _mm(do, s, NN, pr)
+        dw = lo(-_mm(du, s, NN, pr))
+        # w = T bk, u = T bv, T = (I + a)^-1: da = -T^T dT T^T with dT = dw bk^T + du bv^T
+        dbk = _mm(t, dw, TN, pr)
+        dbv = _mm(t, du, TN, pr)
+        da = jnp.where(row > col, -(_mm(lo(dbk), scr["w"][rows, :], NT, pr)
+                                    + _mm(lo(dbv), scr["u"][rows, :], NT, pr)), 0.0)
+        # a = beta kk, p = qk; kk, qk a sub-chunk at a time = ra ca^T: the factors'
+        # cotangents go to kn, qn and (each factor its own exponent's) to G
+        dkk = da * x["b_col"]
+        row_k, row_q, row_g = [], [], []
+        col_k = jnp.zeros((C, dk_), _F32)
+        col_g = jnp.zeros((C, dk_), _F32)
+        for a, (ea, fa, ra, ca) in enumerate(x["subs"]):
+            ds_a = jnp.concatenate([dkk[a * SUB:(a + 1) * SUB], dp[a * SUB:(a + 1) * SUB]], axis=0)
+            dra = _mm(ds_a, ca, NN, _HI)
+            dca = _mm(ds_a, ra, TN, _HI)
+            row_k.append(dra[:SUB] * ea)
+            row_q.append(dra[SUB:] * ea)
+            row_g.append(dra[:SUB] * ra[:SUB] + dra[SUB:] * ra[SUB:])
+            col_k = col_k + dca * fa
+            col_g = col_g + dca * ca
+        tails = dkg * x["kg"]
+        dG = (jnp.concatenate(row_g, axis=0) - col_g + dbk * x["bk"] + dqg * x["qg"] - tails)
+        # G_C's: the state's decay (carry_back) and the keys' tails, on the chunk's last row
+        last = jax.lax.broadcasted_iota(jnp.int32, dG.shape, 0) == C - 1
+        dG = dG + jnp.where(last, dgc_scr[one, :] + jnp.sum(tails, axis=0, keepdims=True), 0.0)
+        dg_ref[rows, :] = _cumulated(dG, TN)
+        db_col = lanes(da * x["kk"]) + lanes(dbk * (kn * x["eg"]) + dbv * v.astype(_F32))
+        db_ref[one, :] = _row(db_col, eye)
+        dkn = jnp.concatenate(row_k, axis=0) + col_k + dbk * (x["b_col"] * x["eg"]) + dkg * x["tail"]
+        dqn = jnp.concatenate(row_q, axis=0) + dqg * x["eg"]
+        dv_ref[rows, :] = (dbv * x["b_col"]).astype(dv_ref.dtype)
+        dq_ref[rows, :] = _normalized_bwd(qn, x["rq"], dqn, dk_ ** -0.5).astype(dq_ref.dtype)
+        dk_ref[rows, :] = _normalized_bwd(kn, x["rk"], dkn, 1.0).astype(dk_ref.dtype)
+
+    jax.lax.fori_loop(0, chunks, lambda c, carry: (inputs(c), carry)[1], 0)
+
+
+def _operand_scratch(rows, C, dk, dv, dtype, keep_t):
+    """w, u, qg, kg, p (and T) of a block's chunks, in the operands' dtype."""
+    widths = (dk, dv, dk, dk, C) + ((C,) if keep_t else ())
+    return [pltpu.VMEM((rows, width), dtype) for width in widths]
+
+
+def _specs(chunks, C, dk, dv, order):
+    """Block specs over the grid (batch, head, block of chunks)."""
+    qk = pl.BlockSpec((None, chunks * C, dk), lambda b, j, t: (b, order(t), j))
+    v = pl.BlockSpec((None, chunks * C, dv), lambda b, j, t: (b, order(t), j))
+    beta = pl.BlockSpec((None, None, chunks, C), lambda b, j, t: (b, j, order(t), 0))
+    s0 = pl.BlockSpec((None, None, None, dv, dk), lambda b, j, t: (b, j, order(t), 0, 0))
+    return qk, v, beta, s0
+
+
+def _plan(q, v, beta):
+    """(b, heads, C, dk, dv, chunks a grid step — all of a short row's, the
+    caller pads a longer one to whole steps —, steps)."""
+    b, h, n, C = beta.shape
+    chunks = min(n, CHUNKS)
+    return b, h, C, q.shape[-1] // h, v.shape[-1] // h, chunks, n // chunks
+
+
+def kda_fwd(q, k, v, g, beta, *, interpret=False):
+    """``o`` (b, T, h dv) and the transposed states every block of chunks
+    started from (b, h, blocks, dv, dk) float32."""
+    b, h, C, dk, dv, chunks, nt = _plan(q, v, beta)
+    qk, vs, bs, s0 = _specs(chunks, C, dk, dv, lambda t: t)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, chunks=chunks, C=C),
+        name="kda_fwd",
+        grid=(b, h, nt),
+        in_specs=[qk, qk, vs, qk, bs],
+        out_specs=[vs, s0],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct((b, h, nt, dv, dk), _F32)],
+        scratch_shapes=[pltpu.VMEM((dv, dk), _F32), pltpu.VMEM((chunks, dk), _F32)]
+        + _operand_scratch(chunks * C, C, dk, dv, v.dtype, keep_t=False),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(q, k, v, g, beta)
+
+
+def kda_bwd(q, k, v, g, beta, s0, do, *, interpret=False):
+    """Cotangents of (q, k, v, g, beta) in their shapes and dtypes."""
+    b, h, C, dk, dv, chunks, nt = _plan(q, v, beta)
+    qk, vs, bs, s0_spec = _specs(chunks, C, dk, dv, lambda t: nt - 1 - t)
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, chunks=chunks, C=C),
+        name="kda_bwd",
+        grid=(b, h, nt),
+        in_specs=[qk, qk, vs, qk, bs, s0_spec, vs],
+        out_specs=[qk, qk, vs, qk, bs],
+        out_shape=[like(q), like(k), like(v), like(g), like(beta)],
+        scratch_shapes=[pltpu.VMEM((dv, dk), _F32),                    # dS^T
+                        pltpu.VMEM((chunks, dv, dk), _F32),            # every chunk's state
+                        pltpu.VMEM((chunks * C, dv), v.dtype),         # v'
+                        pltpu.VMEM((chunks * C, dv), v.dtype),         # dv' = du
+                        pltpu.VMEM((chunks * C, dk), _F32),            # dkg
+                        pltpu.VMEM((chunks, dk), _F32),                # dG_C from the state's decay
+                        pltpu.VMEM((chunks, dk), _F32)]                # e^{G_C}
+        + _operand_scratch(chunks * C, C, dk, dv, v.dtype, keep_t=True),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(q, k, v, g, beta, s0, do)

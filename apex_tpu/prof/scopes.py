@@ -48,6 +48,7 @@ SPANS: Dict[str, str] = {
     "gpt/mlp": "models/gpt.py _block", "gpt/unembed_xent": "models/gpt.py loss_fn",
     "hybrid/embed": "models/hybrid_decoder.py",
     "hybrid/gdn": "a delta-rule mixer half", "hybrid/attn": "a full-attention mixer half",
+    "hybrid/kda": "a delta-rule mixer half whose decay is a vector a key channel",
     "hybrid/attn_win": "a windowed-attention mixer half",
     "hybrid/attn_mla": "a latent-attention mixer half",
     "hybrid/ssm": "a state-space (Mamba-2) mixer half",
@@ -58,7 +59,8 @@ SPANS: Dict[str, str] = {
     "moe/experts": "the grouped products", "moe/shared": "the shared experts",
     "mla/down": "_latent_mixer's proj_in: the query and down projections, the latent's norm",
     "mla/up": "_latent_mixer's proj_in: W_kvb",
-    "mix/proj_in": "a mixer's input projections (w_q, w_k, w_v; w_qkvz, w_ba; w_in)",
+    "mix/proj_in": "a mixer's input projections (w_q, w_k, w_v; w_qkvz, w_ba; w_in; "
+                   "w_qkv, w_f, w_g, w_b)",
     "mix/place": "what stands between the projections and the kernel and after it: "
                  "per-head norms, rotary, the gates, beta / g",
     "mix/proj_out": "a mixer's output projection (w_o)",

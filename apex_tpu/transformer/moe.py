@@ -457,8 +457,21 @@ def _scores_at_bwd(res, g):
 _scores_at.defvjp(_scores_at_fwd, _scores_at_bwd)
 
 
+def kept_groups(biased, groups, kept):
+    """Which of a row's ``groups`` groups of consecutive experts stay, (T,
+    groups) bool: a group's score is the sum of its two largest entries of
+    ``biased`` (T, E), the ``kept`` best groups stay, the lowest index among
+    equals (:func:`top_rounds.top_rounds` both times)."""
+    T, E = biased.shape
+    per = biased.reshape(T * groups, E // groups)
+    best = jnp.sum(jnp.where(tr.top_rounds(per, 2)[..., None] == jnp.arange(E // groups),
+                             per[:, None, :], 0.0), axis=(1, 2)).reshape(T, groups)
+    ids = tr.top_rounds(best, kept)
+    return jnp.any(ids[..., None] == jnp.arange(groups), axis=1)
+
+
 def route_topk(x, router, k, *, normalize=True, score="softmax", bias=None, scale=1.0,
-               sequences=None, impl="auto"):
+               sequences=None, impl="auto", groups=1, groups_kept=1, with_kept=False):
     """Router at its full width: ``p = softmax_fp32(x @ router)``, the top
     ``k`` (ids (T, k) int32, weights (T, k) float32, renormalised to sum 1
     when ``normalize``), the Switch load-balance term ``E sum_e f_e P_e``
@@ -478,6 +491,13 @@ def route_topk(x, router, k, *, normalize=True, score="softmax", bias=None, scal
     carries no gradient (:func:`router_bias_update` moves it). ``scale``
     multiplies the weights last.
 
+    ``groups`` > 1: the choice is group-limited (:func:`kept_groups`). The E
+    experts are ``groups`` groups of consecutive ones; on ``p + bias`` — the
+    ``bias`` enters the groups' scores as it enters the choice — a token keeps
+    its ``groups_kept`` best groups and its top ``k`` are taken inside them.
+    ``with_kept`` also returns which groups every token kept, (T, groups)
+    bool. 1 and 1: the choice over the whole row, as it was.
+
     ``sequences`` (int; the T tokens are that many rows of T / sequences):
     the balance term taken per sequence and averaged over them — for each
     row ``E sum_e f_e P_e`` with ``f`` the share of the row's assignments
@@ -490,6 +510,11 @@ def route_topk(x, router, k, *, normalize=True, score="softmax", bias=None, scal
     chosen_by = jax.lax.stop_gradient(p)
     ok = tr.shapes_ok(x.shape[0], E)
     offset = jnp.zeros((E,), jnp.float32) if bias is None else bias.astype(jnp.float32)
+    kept = None
+    if groups > 1:             # the other groups' experts leave the choice
+        kept = kept_groups(chosen_by + offset, groups, groups_kept)
+        chosen_by = jnp.where(jnp.repeat(kept, E // groups, axis=1), chosen_by + offset, -jnp.inf)
+        offset = jnp.zeros((E,), jnp.float32)
     if _backend.choose_impl(impl if ok else "xla", ok) == "pallas":
         top_e = tr.moe_top_rounds(chosen_by.T, offset, k=k, interpret=_backend.interpret_mode()).T
     else:
@@ -517,7 +542,7 @@ def route_topk(x, router, k, *, normalize=True, score="softmax", bias=None, scal
         if score == "softmax":
             share = row_counts.astype(jnp.float32) / (x.shape[0] // sequences * k)
             aux = E * jnp.mean(jnp.sum(share * jnp.mean(by_row(p), axis=1), axis=-1))
-    return top_e, top_p, aux, counts
+    return (top_e, top_p, aux, counts, kept) if with_kept else (top_e, top_p, aux, counts)
 
 
 def router_bias_update(bias, counts, rate):
@@ -871,7 +896,8 @@ def dropless_block_rows(tokens, top_k, held, width):
 def dropless_moe_layer(params, x, *, top_k, experts_held=None,
                        normalize_weights=True, impl="auto", score="softmax",
                        route_scale=1.0, router_bias=None, shared_gate=True,
-                       sequence_balance=False, activation="silu_gate"):
+                       sequence_balance=False, activation="silu_gate", groups=1,
+                       groups_kept=1):
     """Sparse SwiGLU experts without token dropping, plus a shared expert,
     over ``x`` (..., hidden).
 
@@ -885,7 +911,8 @@ def dropless_moe_layer(params, x, *, top_k, experts_held=None,
     (Fs, hidden) and ``shared_mix`` (hidden,): the shared expert is gated by
     ``sigmoid(x . shared_mix)`` unless ``shared_gate`` is False, when it is
     added as it is and the leaf is not read. ``score``, ``route_scale`` and
-    ``router_bias`` (E,) are :func:`route_topk`'s. ``experts_held = (first, count)``
+    ``router_bias`` (E,), ``groups`` and ``groups_kept`` are :func:`route_topk`'s.
+    ``experts_held = (first, count)``
     says which of the router's experts these are (default: all).
     ``sequence_balance``: the balance term per sequence, ``x``'s leading dims
     but the last being the sequences (:func:`route_topk`'s ``sequences``);
@@ -900,7 +927,9 @@ def dropless_moe_layer(params, x, *, top_k, experts_held=None,
     held, ``aux["router_counts"]`` (E,) int32 assignments to every expert of
     the router's width (what :func:`router_bias_update` balances),
     ``aux["dropped"]`` () int32 — local assignments that no row computed,
-    which this layer keeps at 0 by construction.
+    which this layer keeps at 0 by construction; with ``groups`` > 1 also
+    ``aux["router_group_hit"]`` () float32, the share of tokens whose kept
+    groups hold a group of the experts held here.
     """
     lead, H = x.shape[:-1], x.shape[-1]
     xt = x.reshape(-1, H)
@@ -915,10 +944,11 @@ def dropless_moe_layer(params, x, *, top_k, experts_held=None,
             f"experts_held={held} does not match the {params[first].shape[0]} "
             f"expert matrices given and a router of width {E}")
     with monitor_spans.span("moe/route"):
-        top_e, top_p, aux_loss, counts = route_topk(
+        top_e, top_p, aux_loss, counts, kept = route_topk(
             xt, params["router"], top_k, normalize=normalize_weights, score=score,
             bias=router_bias, scale=route_scale, impl=impl,
-            sequences=max(1, T // lead[-1]) if sequence_balance and lead else None)
+            sequences=max(1, T // lead[-1]) if sequence_balance and lead else None,
+            groups=groups, groups_kept=groups_kept, with_kept=True)
         rows = -(-dropless_block_rows(T, top_k, held[1], E) // gk.TM) * gk.TM
         # under jax.checkpoint a policy may keep the plan by this name, so
         # that the backward pass does not make it again
@@ -939,4 +969,8 @@ def dropless_moe_layer(params, x, *, top_k, experts_held=None,
     computed = jnp.sum(plan["row_valid"], dtype=jnp.int32)
     aux = {"load_balance_loss": aux_loss, "expert_load": load, "router_counts": counts,
            "dropped": jnp.sum(load) - computed}
+    if kept is not None:       # tokens whose kept groups hold a group of the experts held
+        size = E // groups
+        mine = kept[:, held[0] // size:(held[0] + held[1] - 1) // size + 1]
+        aux["router_group_hit"] = jnp.mean(jnp.any(mine, axis=1).astype(jnp.float32))
     return y.reshape(*lead, H), aux

@@ -22,10 +22,10 @@ PALLAS_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file
 KERNEL_FILES = sorted(os.path.basename(p) for p in glob.glob(os.path.join(PALLAS_DIR, "*.py"))
                       if os.path.basename(p) != "__init__.py")
 # readers match by prefix: flash_fwd*, flash_bwd*, xentropy*, gdn_fwd*, gdn_bwd*,
-# ssd_fwd*, ssd_bwd*, moe_gmm*, moe_rows*; the rest by name (conv_silu_*, gated_norm_*: the
+# ssd_fwd*, ssd_bwd*, kda_fwd*, kda_bwd*, moe_gmm*, moe_rows*; the rest by name (conv_silu_*, gated_norm_*: the
 # stages around the rule and the scan, which no reader's part may match)
 READER_PARTS = ("flash_fwd", "flash_bwd", "xentropy", "gdn_", "moe_gmm", "moe_rows", "conv_silu",
-                "gated_norm", "ssd_")
+                "gated_norm", "ssd_", "kda_")
 EXPECTED = {
     "attention.py": {
         "flash_fwd", "flash_fwd_packed", "flash_fwd_bshd",
@@ -53,6 +53,8 @@ EXPECTED = {
     "gated_delta_rule.py": {"gdn_fwd", "gdn_bwd"},
     # the Mamba-2 state-space scan: ssd_*, which no older reader's part matches
     "ssd.py": {"ssd_fwd", "ssd_bwd"},
+    # the delta rule with a decay a key channel: kda_*, never gdn_*
+    "kda.py": {"kda_fwd", "kda_bwd"},
     "delta_mixer.py": {"conv_silu_fwd", "conv_silu_bwd", "gated_norm_fwd", "gated_norm_bwd"},
     "grouped_matmul.py": {"moe_gmm", "moe_gmm_dx", "moe_gmm_dw"},
     # the movements between tokens and expert rows: moe_rows*, never moe_gmm*
@@ -99,7 +101,7 @@ def test_every_pallas_call_has_a_literal_name(filename):
 def test_kernel_names_are_distinct_across_the_package():
     assert set(KERNEL_FILES) == set(EXPECTED)
     names = [n for f in KERNEL_FILES for n in literal_names(f)]
-    assert len(names) == 49 and len(set(names)) == 49
+    assert len(names) == 51 and len(set(names)) == 51
 
 
 def all_eqns(jaxpr):

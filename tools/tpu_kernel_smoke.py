@@ -509,6 +509,44 @@ def _delta_rule_family():
     return build
 
 
+def _kda_operands(t=8192, heads=32):
+    """``ling3-train-8k``'s rule: 2 rows of 8,192, 32 heads of 128, a log decay
+    a key channel drawn over the whole of (-5, 0) — a fifth of the heads AT
+    the bound every step, so that whole sub-chunks reach the kernels' largest
+    factors."""
+    q, k, v = (jr.normal(_key(i), (B, t, heads, D), jnp.bfloat16) for i in (120, 121, 122))
+    g = -5.0 * jax.nn.sigmoid(4.0 * jr.normal(_key(123), (B, t, heads, D)))
+    g = g.at[:, :, ::5].set(-5.0)
+    beta = jax.nn.sigmoid(jr.normal(_key(124), (B, t, heads)))
+    return q, k, v, g, beta
+
+
+def _kda_family():
+    """``kda_fwd`` / ``kda_bwd`` (the in-chunk scores a sub-chunk of 16 rows at
+    a time from float32 factors, then ``gdn_*``'s scheme) against the XLA form
+    (explicit differences chunk by chunk in a ``lax.scan``) at the cell's
+    shape, value and all five gradients."""
+    def build():
+        from apex_tpu.ops.gated_delta_rule import kda_rule
+
+        def make(impl):
+            return _fwd_and_grads(lambda *a: kda_rule(*a, impl=impl), (0, 1, 2, 3, 4))
+        return make("pallas"), make("xla"), _kda_operands()
+    return build
+
+
+def _kda_times():
+    def report():
+        from apex_tpu.ops.gated_delta_rule import kda_rule
+        args = _kda_operands()
+        both = _fwd_and_grads(lambda *a: kda_rule(*a, impl="pallas"), (0, 1, 2, 3, 4))
+        _, by_name = _device_ms(both, *args)
+        kernels = {n: ms for n, ms in by_name.items() if "kda_" in n}
+        return ["kda pallas: " + ", ".join(f"{n} {ms:.2f} ms" for n, ms in sorted(kernels.items()))
+                + " a layer by device time (2 x 8,192 tokens, 32 heads of 128)"]
+    return report
+
+
 def _delta_mixer_stages_family():
     """The two stages around the rule at ``q3next-train-8k``'s shape:
     ``conv_silu_fwd`` / ``conv_silu_bwd`` over ``q|k|v`` and ``gated_norm_fwd``
@@ -865,6 +903,8 @@ FAMILIES = (
     Family("gated delta rule gdn_fwd/gdn_bwd", _delta_rule_family()),
     Family("gated delta rule on drifted keys, against the recurrence",
            _delta_rule_drifted_family()),
+    Family("kda rule kda_fwd/kda_bwd at ling3-train-8k's 32 heads of 128, decays down to the bound",
+           _kda_family(), timings=_kda_times()),
     Family("delta mixer stages conv_silu/gated_norm fwd/bwd", _delta_mixer_stages_family()),
     Family("ssd scan ssd_fwd/ssd_bwd at nemotron3-train-8k's 64 heads of 64, N 128, 8 groups",
            _ssd_family(), timings=_ssd_times()),
