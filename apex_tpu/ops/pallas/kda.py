@@ -25,11 +25,44 @@ it the second is ``e^{-(G_j - G_r)}``, at most ``e^{(SUB - 1) |g|_max}`` —
 the bound the kernels need of ``g`` and why a model that runs them bounds its
 gate. Those factors are float32 operands at ``HIGHEST``, never bf16.
 
-Everything after the scores is ``gdn_fwd`` / ``gdn_bwd``'s: the blocked
-inverse (:func:`gated_delta_rule._inverse`, imported), the operands in the
-inputs' dtype, the walk, the states a block of chunks started from, the
-backward's order. The state is held transposed, ``S^T (dv, dk)``, so that its
-per-channel decay ``e^{G_C}`` is a row over the lanes.
+The blocked inverse (:func:`gated_delta_rule._inverse`), the operands in the
+inputs' dtype, the recurrence and the states a block of chunks started from
+are ``gdn_fwd`` / ``gdn_bwd``'s; the state is held transposed, ``S^T (dv,
+dk)``, so that its per-channel decay ``e^{G_C}`` is a row over the lanes. The
+order of a grid step (``CHUNKS`` chunks of one (row, head)) is this file's own.
+Mosaic schedules a basic block at a time and, inside one, packs what stands
+close together in the text: two independent streams one after the other run
+one after the other, and a loop's every trip pays its fill and drain. So a grid
+step is a few long blocks, and nothing is made twice:
+
+- ``kda_fwd``: ONE block. :func:`_prepare` makes the operands of all the grid
+  step's chunks side by side — per chunk the cumulated decay, the sub-chunks'
+  factors, the scores; then all their triangular systems inverted as one batch
+  (the substitution's 31 dependent steps carry eight systems where they carried
+  four), then ``T``'s products — and the walk over the chunks follows unrolled.
+- ``kda_bwd``: the same preparation, which also KEEPS, in VMEM, what the second
+  half reads again: ``T`` and, float32, each chunk's ``G`` (32 KB) and ``kk``
+  (16 KB); the rebuild of the block's states and ``v'`` follows unrolled in the
+  same block. Then one loop over the chunks, last to first, ``BACK`` chunks a
+  body — all eight of a full grid step, so the loop has one trip, the compiler
+  inlines it and ``kda_bwd`` too is one block (a short row's 2, 4 or 6 chunks
+  go two a body, an odd count one): a chunk's ``du``, ``dkg`` and ``dG_C`` are
+  made where ``dS`` is carried through it and turned into the cotangents of
+  ``q, k, v, g, beta`` at once, as values of the body (no scratch carries them
+  from one loop to another); the
+  sub-chunks' factors come again from the kept ``G`` (exponentials, no product)
+  and ``kk`` is read for ``dbeta``, so no score and no cumulated decay is made
+  a second time. ``dS``'s short chain stands between the chunks' independent
+  products, which is where the scheduler covers it.
+
+VMEM a grid step, bf16 inputs at ``dk = dv = 128``: the pipelined blocks 1.7 MB
+forward, 3.0 MB backward (two buffers each); scratch 0.8 / 2.0 MB (a (rows, 64)
+array takes its tiles' 128 lanes: the kept ``G`` and ``kk`` 0.5 MB, where
+``du``, ``dkg``, ``dG_C`` took 0.4 MB). The long blocks spill more than the
+loops did: the compiler scopes 6.2 MB forward and 8.7 MB backward in all (4.1
+and 6.0 MB before; 10.4 at four chunks a body), inside the 16 MiB a kernel may
+scope, so no ``vmem_limit_bytes``; a second (row, head) a grid step would pass
+it (17.1 MB).
 
 Layout: ``q, k, v, o`` (b, T, h d) as the projections leave them, ``g`` (b, T,
 h dk) float32 in the same layout, one head a lane block picked by the index
@@ -46,11 +79,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from apex_tpu.ops.pallas.gated_delta_rule import (_OPERANDS, CHUNKS, NN, NT, TN, UNROLL, _column,
-                                                  _inverse, _masks, _mm, _normalized,
-                                                  _normalized_bwd, _precision, _row)
+from apex_tpu.ops.pallas.gated_delta_rule import (_OPERANDS, CHUNKS, NN, NT, TN, _column, _inverse,
+                                                  _masks, _mm, _normalized, _normalized_bwd,
+                                                  _precision, _row)
 
 SUB = 16                  # rows of a sub-chunk of the scores
+BACK = 8                  # chunks a body of the backward's loop over the chunks, last to first
+_KEPT = ("t", "G", "kk")  # what the backward keeps of its preparation beside the operands
 LOG_DECAY_MIN = -5.0      # the smallest per-step log decay the kernels take: (SUB - 1) * 5 < 88
 _F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
@@ -89,36 +124,43 @@ def _scores(subs):
             jnp.concatenate([s[SUB:] for s in blocks], axis=0))
 
 
-def _chunk(q, k, v, g, b_row, row, col):
-    """One chunk's elementwise operands, float32: everything but ``T``'s
-    products."""
+def _elementwise(q, k, v, G, b_row, row, col):
+    """One chunk's operands that no matmul makes, float32, from its cumulated
+    log decay ``G``: the sub-chunks' factors among them."""
     C, dk = q.shape
     qn, rq = _normalized(q, dk ** -0.5)
     kn, rk = _normalized(k, 1.0)
-    G = _cumulated(g)
-    subs = _sub_chunks(kn, qn, G)
-    kk, qk = _scores(subs)
     b_col = _column(b_row, row == col)
     eg = jnp.exp(G)
     tail = jnp.exp(G[C - 1:C] - G)
-    return dict(qn=qn, kn=kn, rq=rq, rk=rk, subs=subs, kk=kk, b_col=b_col, eg=eg,
-                tail=tail, a=jnp.where(row > col, b_col * kk, 0.0),
-                p=jnp.where(row >= col, qk, 0.0), bk=b_col * eg * kn,
-                bv=b_col * v.astype(_F32), qg=qn * eg, kg=kn * tail, gam=eg[C - 1:C])
+    return dict(qn=qn, kn=kn, rq=rq, rk=rk, subs=_sub_chunks(kn, qn, G), b_col=b_col, eg=eg,
+                tail=tail, bk=b_col * eg * kn, bv=b_col * v.astype(_F32), qg=qn * eg,
+                kg=kn * tail, gam=eg[C - 1:C])
 
 
-def _prepare_group(chunks, refs, scr, *, C, keep_t):
-    """The operands of the chunks ``chunks``, written to the scratch: side by
-    side, their triangular systems inverted as one batch."""
+def _rows(c, C):
+    """The rows of chunk ``c``, a Python int or a loop's index."""
+    return pl.ds(c * C if isinstance(c, int) else pl.multiple_of(c * C, C), C)
+
+
+def _prepare(chunks, refs, scr, *, C, keep):
+    """The operands of all ``chunks`` chunks of the grid step, written to the
+    scratch: side by side in one basic block, their triangular systems
+    inverted as one batch. ``keep``: also what the backward reads again —
+    ``T`` and, float32, ``G`` and ``kk``."""
     q_ref, k_ref, v_ref, g_ref, b_ref = refs
     dt = v_ref.dtype
     pr = _precision(dt)
     row, col = _masks(C)
     made = []
-    for c in chunks:
-        rows = pl.ds(pl.multiple_of(c * C, C), C)
-        made.append((c, rows, _chunk(q_ref[rows, :], k_ref[rows, :], v_ref[rows, :],
-                                     g_ref[rows, :], b_ref[pl.ds(c, 1), :], row, col)))
+    for c in range(chunks):
+        rows = _rows(c, C)
+        G = _cumulated(g_ref[rows, :])
+        x = _elementwise(q_ref[rows, :], k_ref[rows, :], v_ref[rows, :], G, b_ref[pl.ds(c, 1), :],
+                         row, col)
+        kk, qk = _scores(x["subs"])
+        made.append((c, rows, dict(x, G=G, kk=kk, a=jnp.where(row > col, x["b_col"] * kk, 0.0),
+                                   p=jnp.where(row >= col, qk, 0.0))))
     inverses = _inverse(jnp.stack([x["a"] for _, _, x in made])).astype(dt)
     for (c, rows, x), t in zip(made, inverses):
         scr["w"][rows, :] = _mm(t, x["bk"].astype(dt), NN, pr).astype(dt)
@@ -127,18 +169,10 @@ def _prepare_group(chunks, refs, scr, *, C, keep_t):
         scr["kg"][rows, :] = x["kg"].astype(dt)
         scr["p"][rows, :] = x["p"].astype(dt)
         scr["gam"][pl.ds(c, 1), :] = x["gam"]
-        if keep_t:
+        if keep:
             scr["t"][rows, :] = t
-
-
-def _prepare(chunks, refs, scr, **kw):
-    group = UNROLL if chunks % UNROLL == 0 else 1
-
-    def step(i, carry):
-        _prepare_group([i * group + j for j in range(group)], refs, scr, **kw)
-        return carry
-
-    jax.lax.fori_loop(0, chunks // group, step, 0)
+            scr["G"][rows, :] = x["G"]
+            scr["kk"][rows, :] = x["kk"]
 
 
 def _recur(st, scr, rows, gam_row, dt):
@@ -159,25 +193,21 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s0_ref, s_scr, gam_scr
     def _():
         s_scr[...] = jnp.zeros_like(s_scr)
 
-    s0_ref[...] = s_scr[...]
+    st = s_scr[...]
+    s0_ref[...] = st
     dt = v_ref.dtype
     scr = dict(zip(_OPERANDS, operands), gam=gam_scr)
-    _prepare(chunks, (q_ref, k_ref, v_ref, g_ref, b_ref), scr, C=C, keep_t=False)
-
-    def walk(c, carry):
-        rows = pl.ds(pl.multiple_of(c * C, C), C)
-        st, _, o = _recur(s_scr[...], scr, rows, gam_scr[pl.ds(c, 1), :], dt)
-        s_scr[...] = st
+    _prepare(chunks, (q_ref, k_ref, v_ref, g_ref, b_ref), scr, C=C, keep=False)
+    for c in range(chunks):                # the walk, in the same block
+        rows = _rows(c, C)
+        st, _, o = _recur(st, scr, rows, gam_scr[pl.ds(c, 1), :], dt)
         o_ref[rows, :] = o.astype(o_ref.dtype)
-        return carry
-
-    jax.lax.fori_loop(0, chunks, walk, 0)
+    s_scr[...] = st
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, do_ref,
                 dq_ref, dk_ref, dv_ref, dg_ref, db_ref,
-                ds_scr, states_scr, vnew_scr, du_scr, dkg_scr, dgc_scr, gam_scr, *operands,
-                chunks, C):
+                ds_scr, states_scr, vnew_scr, gam_scr, *operands, chunks, C):
     @pl.when(pl.program_id(2) == 0)
     def _():
         ds_scr[...] = jnp.zeros_like(ds_scr)
@@ -185,51 +215,41 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, do_ref,
     dt = v_ref.dtype
     pr = _precision(dt)
     dk_ = q_ref.shape[-1]
-    scr = dict(zip(_OPERANDS + ("t",), operands), gam=gam_scr)
+    scr = dict(zip(_OPERANDS + _KEPT, operands), gam=gam_scr)
     lo = lambda z: z.astype(dt)  # noqa: E731
 
-    _prepare(chunks, (q_ref, k_ref, v_ref, g_ref, b_ref), scr, C=C, keep_t=True)
-
-    def rebuild(c, st):                    # the block's states and v', in order
-        rows = pl.ds(pl.multiple_of(c * C, C), C)
+    _prepare(chunks, (q_ref, k_ref, v_ref, g_ref, b_ref), scr, C=C, keep=True)
+    st = s0_ref[...]
+    for c in range(chunks):                # the block's states and v', in order, in the same block
+        rows = _rows(c, C)
         states_scr[c] = st
         st, v_new, _ = _recur(st, scr, rows, gam_scr[pl.ds(c, 1), :], dt)
         vnew_scr[rows, :] = v_new.astype(dt)
-        return st
 
-    jax.lax.fori_loop(0, chunks, rebuild, s0_ref[...])
-
-    def carry_back(i, carry):              # dS through the chunks, last to first
-        c = chunks - 1 - i
-        rows = pl.ds(pl.multiple_of(c * C, C), C)
-        one = pl.ds(c, 1)
-        st, ds = states_scr[c], ds_scr[...]
+    def carry_back(c, ds):                 # dS through chunk c: (dS before it, du, dkg, dG_C's)
+        rows = _rows(c, C)
         ds_lo = lo(ds)
         do = do_ref[rows, :]
-        gam = gam_scr[one, :]
-        du = lo(_mm(scr["p"][rows, :], do, TN, pr) + _mm(scr["kg"][rows, :], ds_lo, NT, pr))
-        du_scr[rows, :] = du                                                   # = dv'
-        dkg_scr[rows, :] = _mm(vnew_scr[rows, :], ds_lo, NN, pr)
-        dgc_scr[one, :] = jnp.sum(st * ds, axis=0, keepdims=True) * gam
-        ds_scr[...] = (ds * gam + _mm(do, scr["qg"][rows, :], TN, pr)
-                       - _mm(du, scr["w"][rows, :], TN, pr))
-        return carry
+        gam = gam_scr[pl.ds(c, 1), :]
+        du = lo(_mm(scr["p"][rows, :], do, TN, pr) + _mm(scr["kg"][rows, :], ds_lo, NT, pr))  # dv'
+        dkg = _mm(vnew_scr[rows, :], ds_lo, NN, pr)
+        dgc = jnp.sum(states_scr[c] * ds, axis=0, keepdims=True) * gam
+        ds = ds * gam + _mm(do, scr["qg"][rows, :], TN, pr) - _mm(du, scr["w"][rows, :], TN, pr)
+        return ds, du, dkg, dgc
 
-    jax.lax.fori_loop(0, chunks, carry_back, 0)
-
-    def inputs(c):                         # the operands' cotangents -> q, k, v, g, beta's
-        rows = pl.ds(pl.multiple_of(c * C, C), C)
+    def inputs(c, du, dkg, dgc):           # the operands' cotangents -> q, k, v, g, beta's
+        rows = _rows(c, C)
         one = pl.ds(c, 1)
         row, col = _masks(C)
         eye = row == col
         lanes = lambda z: jnp.sum(z, axis=1, keepdims=True)  # noqa: E731
         v = v_ref[rows, :]
-        x = _chunk(q_ref[rows, :], k_ref[rows, :], v, g_ref[rows, :], b_ref[one, :], row, col)
+        x = _elementwise(q_ref[rows, :], k_ref[rows, :], v, scr["G"][rows, :], b_ref[one, :],
+                         row, col)
         qn, kn = x["qn"], x["kn"]
-        t, v_new, du = scr["t"][rows, :], vnew_scr[rows, :], du_scr[rows, :]
+        t, v_new = scr["t"][rows, :], vnew_scr[rows, :]
         s = lo(states_scr[c])
         do = do_ref[rows, :]
-        dkg = dkg_scr[rows, :]
         # the recurrence's operands
         dp = jnp.where(row >= col, _mm(do, v_new, NT, pr), 0.0)
         dqg = _mm(do, s, NN, pr)
@@ -258,9 +278,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, do_ref,
         dG = (jnp.concatenate(row_g, axis=0) - col_g + dbk * x["bk"] + dqg * x["qg"] - tails)
         # G_C's: the state's decay (carry_back) and the keys' tails, on the chunk's last row
         last = jax.lax.broadcasted_iota(jnp.int32, dG.shape, 0) == C - 1
-        dG = dG + jnp.where(last, dgc_scr[one, :] + jnp.sum(tails, axis=0, keepdims=True), 0.0)
+        dG = dG + jnp.where(last, dgc + jnp.sum(tails, axis=0, keepdims=True), 0.0)
         dg_ref[rows, :] = _cumulated(dG, TN)
-        db_col = lanes(da * x["kk"]) + lanes(dbk * (kn * x["eg"]) + dbv * v.astype(_F32))
+        db_col = lanes(da * scr["kk"][rows, :]) + lanes(dbk * (kn * x["eg"]) + dbv * v.astype(_F32))
         db_ref[one, :] = _row(db_col, eye)
         dkn = jnp.concatenate(row_k, axis=0) + col_k + dbk * (x["b_col"] * x["eg"]) + dkg * x["tail"]
         dqn = jnp.concatenate(row_q, axis=0) + dqg * x["eg"]
@@ -268,13 +288,24 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, do_ref,
         dq_ref[rows, :] = _normalized_bwd(qn, x["rq"], dqn, dk_ ** -0.5).astype(dq_ref.dtype)
         dk_ref[rows, :] = _normalized_bwd(kn, x["rk"], dkn, 1.0).astype(dk_ref.dtype)
 
-    jax.lax.fori_loop(0, chunks, lambda c, carry: (inputs(c), carry)[1], 0)
+    unroll = next(u for u in (BACK, 2, 1) if chunks % u == 0)
+
+    def back(i, ds):                       # last chunk to first, ``unroll`` chunks a basic block
+        for j in range(unroll):
+            c = chunks - 1 - (i * unroll + j)
+            ds, du, dkg, dgc = carry_back(c, ds)
+            inputs(c, du, dkg, dgc)
+        return ds
+
+    ds_scr[...] = jax.lax.fori_loop(0, chunks // unroll, back, ds_scr[...])
 
 
-def _operand_scratch(rows, C, dk, dv, dtype, keep_t):
-    """w, u, qg, kg, p (and T) of a block's chunks, in the operands' dtype."""
-    widths = (dk, dv, dk, dk, C) + ((C,) if keep_t else ())
-    return [pltpu.VMEM((rows, width), dtype) for width in widths]
+def _operand_scratch(rows, C, dk, dv, dtype, keep):
+    """w, u, qg, kg, p of a block's chunks in the operands' dtype and, for the
+    backward, T in it and G, kk float32."""
+    widths = (dk, dv, dk, dk, C) + ((C,) if keep else ())
+    kept = [pltpu.VMEM((rows, dk), _F32), pltpu.VMEM((rows, C), _F32)] if keep else []
+    return [pltpu.VMEM((rows, width), dtype) for width in widths] + kept
 
 
 def _specs(chunks, C, dk, dv, order):
@@ -308,7 +339,7 @@ def kda_fwd(q, k, v, g, beta, *, interpret=False):
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct((b, h, nt, dv, dk), _F32)],
         scratch_shapes=[pltpu.VMEM((dv, dk), _F32), pltpu.VMEM((chunks, dk), _F32)]
-        + _operand_scratch(chunks * C, C, dk, dv, v.dtype, keep_t=False),
+        + _operand_scratch(chunks * C, C, dk, dv, v.dtype, keep=False),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -330,11 +361,8 @@ def kda_bwd(q, k, v, g, beta, s0, do, *, interpret=False):
         scratch_shapes=[pltpu.VMEM((dv, dk), _F32),                    # dS^T
                         pltpu.VMEM((chunks, dv, dk), _F32),            # every chunk's state
                         pltpu.VMEM((chunks * C, dv), v.dtype),         # v'
-                        pltpu.VMEM((chunks * C, dv), v.dtype),         # dv' = du
-                        pltpu.VMEM((chunks * C, dk), _F32),            # dkg
-                        pltpu.VMEM((chunks, dk), _F32),                # dG_C from the state's decay
                         pltpu.VMEM((chunks, dk), _F32)]                # e^{G_C}
-        + _operand_scratch(chunks * C, C, dk, dv, v.dtype, keep_t=True),
+        + _operand_scratch(chunks * C, C, dk, dv, v.dtype, keep=True),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,

@@ -67,14 +67,18 @@ def rule(impl):
     return lambda *a: gdr.kda_rule(*a, impl=impl)
 
 
-# (b, t, heads, d): four chunks with a ragged last one in one grid step; eighteen
-# chunks padded to three grid steps of eight, the state carried between them
-SHAPES = [(1, 200, 2, 128), (2, 1100, 1, 128)]
+# (b, t, heads, d): four chunks with a ragged last one in one grid step (the backward's
+# loop over the chunks two bodies of two); eighteen chunks padded to three grid steps of
+# eight, the state carried between them (``BACK`` = 8: one body a step); six chunks:
+# three bodies of two; five: five bodies of one
+SHAPES = [(1, 200, 2, 128), (2, 1100, 1, 128), (1, 384, 2, 128), (1, 320, 1, 128)]
 
 
 @pytest.mark.parametrize("shape,impl", [(SHAPES[0], "xla"), (SHAPES[0], "pallas"),
-                                        (SHAPES[1], "pallas")],
-                         ids=["ragged-one-step-xla", "ragged-one-step-pallas", "three-steps-pallas"])
+                                        (SHAPES[1], "pallas"), (SHAPES[2], "pallas"),
+                                        (SHAPES[3], "pallas")],
+                         ids=["ragged-one-step-xla", "ragged-one-step-pallas", "three-steps-pallas",
+                              "six-chunks-pallas", "five-chunks-pallas"])
 def test_value_and_every_cotangent_match_the_recurrence(shape, impl):
     args, ct = operands(*shape)
     want_y, want = value_and_grads(recurrence, args, ct)
