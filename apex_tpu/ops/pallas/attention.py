@@ -18,7 +18,10 @@ Block sizes default to 1024 (measured best on v5e at seq>=1024 — small
 blocks leave the head_dim-64 MXU contraction starved and grid overhead
 dominant); d must equal the full head dim
 (trailing-dim tiling rule). Causal masking skips whole KV blocks above the
-diagonal — the work saving that makes causal flash ~2x dense.
+diagonal — the work saving that makes causal flash ~2x dense — and fetches
+none of them (:func:`_kv_walk`); a tile wholly under the diagonal runs
+without a mask in the forward and the one-pass backward alike
+(:func:`_tile_kind`).
 """
 
 from __future__ import annotations
@@ -206,8 +209,10 @@ def _fit_block(n, pref):
 # ``jj`` of q block ``i`` is kv block ``_band_first(i) + jj``, and the index
 # maps clamp at the band's last block, so a step past it fetches nothing
 # (an unchanged block index issues no DMA) and computes nothing. The dkv
-# kernel walks the q blocks of each kv block the same way. ``window=None``
-# leaves every kernel and index map as it was.
+# kernel walks the q blocks of each kv block the same way. ``window=None``:
+# the forwards and the one-pass backward walk every kv block and, causal, hold
+# the q block's last needed one the same way (``_kv_walk``); the split
+# backward's maps pass the step through.
 
 def _visible(q_pos, k_pos, window):
     """Whether key ``k_pos`` is visible to query ``q_pos`` (global
@@ -284,6 +289,86 @@ def _band_walk(banded, n_own, b_own, b_other, n_other, shift, lo_reach, hi_reach
     return steps, lambda i, s: _least(first(i) + s, last(i))
 
 
+def _shifted(x, off):
+    """``x + off``; a zero ``off`` (one sequence length, static) adds no
+    operation, so the one-pass backward's program stays the text it was."""
+    return x + off if off else x
+
+
+def _both(a, b):
+    """``a and b``: Python's on bools (a count on the host),
+    ``jnp.logical_and`` on traced ones (a kernel's)."""
+    return (a and b) if isinstance(a, bool) and isinstance(b, bool) else jnp.logical_and(a, b)
+
+
+def _kv_walk(causal, window, nq, bq, bk, nk, off):
+    """``(steps, block)`` of a q block's walk along the kv blocks — the ONE
+    rule of the forward wrappers and the one-pass backward. Banded: the
+    band's walk (:func:`_band_walk`). Causal: every block in order and HELD
+    at the last one the q block needs, the block of its last row's diagonal
+    — a step past it is a tile above the diagonal, which computes nothing
+    and, its block index unchanged, fetches nothing. Else every block in
+    order. Kv lengths are a run-time operand and stay out of the index map:
+    a step past a row's length computes nothing and still fetches."""
+    if window is not None:
+        return _band_walk(True, nq, bq, bk, nk, off, window - 1, 0)
+    if not causal:
+        return nk, lambda i, j: j
+
+    def last(i):
+        block = _shifted((i + 1) * bq - 1, off) // bk
+        # with more queries than keys the first q blocks see no key at all
+        return _most(block, 0) if off < 0 else block
+    return nk, lambda i, j: _least(j, last(i))
+
+
+def _tile_kind(i, j, bq, bk, off, causal, window):
+    """``(run, inner)`` of score tile (q block ``i``, kv block ``j``), from
+    block indices alone (Python ints on the host, traced scalars in a kernel
+    — the ONE classification of :func:`_fwd_kernel`, :func:`_bwd_fused_kernel`
+    and :func:`forward_tiles`). ``run``: the tile holds a visible score, it is
+    not above the diagonal (the band ends at the diagonal's block, so a step
+    past it is a tile above the diagonal: one test skips both). ``inner``:
+    every score of it is visible — the tile's last column at or left of its
+    first row's diagonal / its first column inside its last row's window."""
+    run = inner = True
+    if causal:
+        run = j * bk <= _shifted((i + 1) * bq - 1, off)
+        inner = (j + 1) * bk - 1 <= _shifted(i * bq, off)
+    if window is not None:
+        inner = _both(inner, j * bk > _shifted((i + 1) * bq - 1, off) - window)
+    return run, inner
+
+
+def _tile_in_length(run, inner, j, bk, kvlen):
+    """:func:`_tile_kind` under a row's kv length (a run-time scalar): a tile
+    past it does not run, one it ends in is not fully visible."""
+    return _both(run, j * bk < kvlen), _both(inner, (j + 1) * bk <= kvlen)
+
+
+def forward_tiles(sq, sk, bq, bk, causal, window=None):
+    """``(running, fully_visible, skipped)``: the grid steps a head of a
+    forward call takes, by what :func:`_fwd_kernel` does at each — static by
+    shape, the kernel's three-way branch counted on the host. ``running``
+    tiles are computed, ``fully_visible`` of them without a mask (no iota,
+    compare or select), the rest crossed by the diagonal or the band's lower
+    edge; ``skipped`` steps compute nothing and fetch nothing (above the
+    diagonal, or past the band's last block). Kv lengths, a run-time operand,
+    are not in it. 8,192 positions in blocks of 1,024: 36, 28, 28; under a
+    window of 2,048: 21, 7, 3; 1,024 in blocks of 512: 3, 1, 1."""
+    bq, bk = _fit_block(sq, bq), _fit_block(sk, bk)
+    nq, nk, off = _blocks(sq, bq), _blocks(sk, bk), sk - sq
+    steps, _ = _kv_walk(causal, window, nq, bq, bk, nk, off)
+    running = visible = 0
+    for i in range(nq):
+        first = 0 if window is None else _band_first(i, bq, bk, off, window - 1)
+        for j in range(first, min(first + steps, nk)):
+            run, inner = _tile_kind(i, j, bq, bk, off, causal, window)
+            running += run
+            visible += _both(run, inner)
+    return running, visible, nq * steps - running
+
+
 # --- heads of 64, two to a lane tile -------------------------------------------
 #
 # A folded (b, s, h·d) view takes d-wide column blocks, and 64 lanes are half
@@ -325,9 +410,9 @@ def _pair_block(s):
     branch with no mask (0.834 ms a layer against 0.904 at 8 x 16 heads;
     256 loses, 1.249: a tile costs about a microsecond whatever its size).
     From 2,048 positions on it is the 1,024 of the other forms. The forward
-    keeps 1,024 at every length: its body has no unmasked branch to reach,
-    and pays the cost a tile three times over at 512 (0.561 -> 0.831 ms;
-    PERF.md §6, PR 42)."""
+    keeps 1,024 at every length: measured when its body had no unmasked
+    branch to reach, it paid the cost a tile three times over at 512 (0.561
+    -> 0.831 ms; PERF.md §6, PR 42)."""
     return max(128, min(1024, s // 2))
 
 
@@ -356,7 +441,27 @@ def _pair_plane(hd):
 def _fwd_kernel(*refs, scale, causal, bq, bk, nk, off, varlen, bshd=False,
                 rate=0.0, has_bias=False, rel=None, window=None, second=False,
                 pair=False):
-    """``varlen`` is a STATIC specialization flag: without kv lengths the
+    """Grid (q-head rows, q blocks, kv steps), the kv steps innermost: the
+    online-softmax recurrence over the score tiles of one q block.
+
+    A tile is skipped (above the causal diagonal / past the row's kv
+    length), fully visible (no iota, compare or select), or crossed by the
+    diagonal, the band's lower edge or the length — branched on block
+    indices with ``pl.when`` (:func:`_tile_kind` and, under a length,
+    :func:`_tile_in_length`: the one-pass backward's classification with
+    this call's ``off``). A skipped step costs the grid
+    step alone: the wrappers' kv index maps hold the q block's last needed
+    block (:func:`_kv_walk`), so it fetches nothing — except a step past a
+    kv LENGTH, a run-time operand no index map can read. A fully visible
+    tile costs its two matmuls, the exp and the row reductions; a crossed
+    one two (bq, bk) iotas, the compares and a select more. A select whose
+    predicate is all true returns its operand, so both branches give a
+    fully visible tile the same bits. Not causal and no lengths: one body
+    without a mask, no branch. How many steps of a head are which is static
+    by shape: :func:`forward_tiles` (8,192 positions in blocks of 1,024:
+    36 running, 28 of them fully visible, 28 skipped).
+
+    ``varlen`` is a STATIC specialization flag: without kv lengths the
     kernel carries no length operand, no per-block length select, and no
     dynamic predicate conjunct — the common (non-padded) call pays nothing.
     ``bshd``: the seq-major layout — q/k/v/o ride (b, s, h·d) folded views
@@ -423,18 +528,21 @@ def _fwd_kernel(*refs, scale, causal, bq, bk, nk, off, varlen, bshd=False,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     # causal: process only blocks intersecting the (bottom-right aligned)
-    # lower triangle — row r attends cols <= r + off, off = sk - sq.
+    # lower triangle — row r attends cols <= r + off, off = sk - sq; the
+    # wrappers' kv index maps hold the last such block (:func:`_kv_walk`), so
+    # a skipped step issues no DMA either.
     # varlen: additionally skip KV blocks entirely past this row's valid
-    # length (a *dynamic* predicate — pl.when predicates the block; note the
-    # block's DMA is issued regardless, only the compute is skipped).
-    run = (not causal) or (j * bk <= (i + 1) * bq - 1 + off)
+    # length (a *dynamic* predicate — pl.when predicates the block; the
+    # length is no part of an index map, so THAT block's DMA is issued
+    # regardless, only the compute is skipped).
+    run, inner = _tile_kind(i, j, bq, bk, off, causal, window)
     if varlen:
         kvlen = kvlen_ref[0, 0, 0]
-        run = jnp.logical_and(run, j * bk < kvlen)
+        run, inner = _tile_in_length(run, inner, j, bk, kvlen)
 
     heads = (0, 1) if pair else (None,)
 
-    def _head_step(hd):
+    def _head_step(hd, masked):
         # MXU operands stay in the input dtype (bf16 in mixed precision —
         # an fp32 pre-cast would run the matmul at the ~8x-slower fp32 MXU
         # rate); preferred_element_type pins fp32 accumulation either way.
@@ -452,13 +560,13 @@ def _fwd_kernel(*refs, scale, causal, bq, bk, nk, off, varlen, bshd=False,
             s = s + bias_ref[0].astype(jnp.float32)
         if rel is not None:
             s = s + _rel_bias_block(rtab_ref, roff_ref, i, j, bq, bk, rel)
-        if causal or varlen:
+        if masked:
             cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        if causal:
-            rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            s = jnp.where(_visible(rows + off, cols, window), s, NEG_INF)
-        if varlen:
-            s = jnp.where(cols < kvlen, s, NEG_INF)
+            if causal:
+                rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+                s = jnp.where(_visible(rows + off, cols, window), s, NEG_INF)
+            if varlen:
+                s = jnp.where(cols < kvlen, s, NEG_INF)
         own = _pair_plane(hd)
         m_prev = m_scr[own]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -480,10 +588,17 @@ def _fwd_kernel(*refs, scale, causal, bq, bk, nk, off, varlen, bshd=False,
             _pair_own(acc.shape, hd), acc, acc_scr[:])
         m_scr[own] = m_new
 
-    @pl.when(run)
-    def _step():
+    def _step(masked):
         for hd in heads:
-            _head_step(hd)
+            _head_step(hd, masked)
+
+    # a select whose predicate is all true returns its operand: the branch
+    # with no mask leaves a fully visible tile's scores bit for bit
+    if not causal and not varlen:
+        _step(False)
+    else:
+        pl.when(jnp.logical_and(run, inner))(lambda: _step(False))
+        pl.when(jnp.logical_and(run, jnp.logical_not(inner)))(lambda: _step(True))
 
     @pl.when(jj == nk - 1)
     def _finish():
@@ -609,11 +724,16 @@ def flash_fwd(q, k, v, *, scale, causal, kv_lens=None, bias=None,
     reads kv row ``b // group``), zero-copy: kv shards are never repeated
     in HBM. ``kv_lens`` (bh,) int32 masks each row's kv positions >= its
     length (padded batches); the MXU/VPU work of KV blocks entirely past
-    the length is skipped dynamically (their DMA still runs — BlockSpec
-    copies are unconditional). ``kv_lens=None`` compiles a kernel with no
-    varlen operand or masking at all. ``full_lse`` returns the raw
-    (bh, sq, LANES) lane carrier, which :func:`flash_bwd` accepts directly
-    (saves the slice + re-broadcast pair when lse only rides residuals).
+    the length is skipped dynamically (their DMA still runs — a length is
+    no part of an index map). ``kv_lens=None`` compiles a kernel with no
+    varlen operand or masking at all. Causal: a step above the diagonal is
+    skipped and fetches nothing (the k, v and bias maps hold the q block's
+    last needed block, :func:`_kv_walk`), a tile wholly under it runs
+    without a mask, one the diagonal or a length crosses under the mask
+    (:func:`_fwd_kernel`; counted by :func:`forward_tiles`). ``full_lse``
+    returns the raw (bh, sq, LANES) lane carrier, which :func:`flash_bwd`
+    accepts directly (saves the slice + re-broadcast pair when lse only
+    rides residuals).
 
     ``bias`` (hb, sq, sk) with hb | bh: an additive score bias, row ``r``
     reading bias row ``r % hb`` — (h, sq, sk) shared over batch under the
@@ -638,15 +758,17 @@ def flash_fwd(q, k, v, *, scale, causal, kv_lens=None, bias=None,
         rel_bias[:2], rel_bias[2])
     rhb = 0 if rel is None else rel[0].shape[0]
 
+    _, kv_block = _kv_walk(causal, None, nq, bq, bk, nk, sk - sq)
+
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, bk, d), lambda b, i, j, g=group: (b // g, j, 0)),
-        pl.BlockSpec((1, bk, d), lambda b, i, j, g=group: (b // g, j, 0)),
+        pl.BlockSpec((1, bk, d), lambda b, i, j, g=group: (b // g, kv_block(i, j), 0)),
+        pl.BlockSpec((1, bk, d), lambda b, i, j, g=group: (b // g, kv_block(i, j), 0)),
     ]
     args = [q, k, v]
     tail_specs, tail_args = _tail_operands(
         kv_lens, bh, dropout_rate, dropout_seed, lambda b, i, j: (b, 0, 0),
-        bias, lambda b, i, j, hb=hb: (b % hb, i, j), (1, bq, bk),
+        bias, lambda b, i, j, hb=hb: (b % hb, i, kv_block(i, j)), (1, bq, bk),
         rel, lambda b, i, j, rhb=rhb: b % rhb)
     in_specs += tail_specs
     args += tail_args
@@ -693,6 +815,13 @@ def flash_fwd_packed(qkv, h, h_kv, d, *, scale, causal, kv_lens=None,
     :func:`flash_bwd_packed` accepts directly: round-tripping through the
     sliced form costs a slice + re-broadcast pair per layer for nothing.
 
+    Causal, a grid step is a tile skipped (above the diagonal: nothing
+    computed and, the k and v windows held at the q block's last needed
+    block by :func:`_kv_walk`, nothing fetched), fully visible (no mask) or
+    crossed by the diagonal or a length (the mask): :func:`_fwd_kernel`,
+    counted by :func:`forward_tiles` — at 8,192 positions 36 of a head's 64
+    steps run, 28 of them without a mask.
+
     ``bias`` (hb, s, s) with hb | h: additive score bias, q-head row
     ``t = b·h + h_i`` reading bias row ``t % hb`` (i.e. per-head bias
     shared over batch at hb == h; broadcast at hb == 1).
@@ -715,21 +844,23 @@ def flash_fwd_packed(qkv, h, h_kv, d, *, scale, causal, kv_lens=None,
     varlen = kv_lens is not None
     hb = 0 if bias is None else bias.shape[0]
 
+    _, kv_block = _kv_walk(causal, None, nq, bq, bk, nk, 0)
+
     args = [qkv, qkv, qkv]
     in_specs = [
         pl.BlockSpec((1, bq, d),
                      lambda t, i, j, h=h: (t // h, i, t % h)),
         pl.BlockSpec((1, bk, d),
                      lambda t, i, j, h=h, g=group:
-                     (t // h, j, h + (t % h) // g)),
+                     (t // h, kv_block(i, j), h + (t % h) // g)),
         pl.BlockSpec((1, bk, d),
                      lambda t, i, j, h=h, hk=h_kv, g=group:
-                     (t // h, j, h + hk + (t % h) // g)),
+                     (t // h, kv_block(i, j), h + hk + (t % h) // g)),
     ]
     tail_specs, tail_args = _tail_operands(
         kv_lens, b, dropout_rate, dropout_seed,
         lambda t, i, j, h=h: (t // h, 0, 0),
-        bias, lambda t, i, j, hb=hb: (t % hb, i, j), (1, bq, bk))
+        bias, lambda t, i, j, hb=hb: (t % hb, i, kv_block(i, j)), (1, bq, bk))
     in_specs += tail_specs
     args += tail_args
 
@@ -833,7 +964,8 @@ def _bwd_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h, h_kv,
     A tile is skipped (above the causal diagonal / past the row's kv
     length, as in the split), fully visible (no iota, compare or select),
     or crossed by the diagonal, the band's lower edge or the length —
-    branched on block indices with ``pl.when``. ``window`` (static): the kv
+    branched on block indices with ``pl.when`` (:func:`_tile_kind`, the
+    forward's too). ``window`` (static): the kv
     axis is the band's run of blocks (``nk`` steps, counted from the band's
     first block; the section on the band above), the mask is
     :func:`_visible`. Dropout regenerates the forward's mask: the same hash
@@ -911,19 +1043,10 @@ def _bwd_fused_kernel(*refs, scale, causal, bq, bk, nq, nk, group, h, h_kv,
         def _visit2():
             dq2_scr[...] = jnp.zeros_like(dq2_scr)
 
-    # the band ends at the diagonal's block, so a step past it is a tile
-    # above the diagonal: one test skips both
-    run = (not causal) or (j * bk <= (i + 1) * bq - 1)
-    # fully visible: the tile's last column at or left of its first row's
-    # diagonal / its first column inside its last row's window / inside the
-    # kv length
-    inner = (not causal) or ((j + 1) * bk - 1 <= i * bq)
-    if window is not None:
-        inner = jnp.logical_and(inner, j * bk > (i + 1) * bq - 1 - window)
+    run, inner = _tile_kind(i, j, bq, bk, 0, causal, window)
     if varlen:
         kvlen = kvlen_ref[0, 0, 0]
-        run = jnp.logical_and(run, j * bk < kvlen)
-        inner = jnp.logical_and(inner, (j + 1) * bk <= kvlen)
+        run, inner = _tile_in_length(run, inner, j, bk, kvlen)
 
     def _tile(masked, hd=None):
         # bf16 MXU operands, fp32 accumulation (see _fwd_kernel)
@@ -1081,15 +1204,7 @@ def _flash_bwd_fused(q3, k3, v3, o3, lse, do3, *, h, h_kv, d, packed, scale,
     def head(r, g):  # q-head index within the batch row
         return (r % h_kv) * group + g
 
-    if window is None:
-        steps = nk
-
-        def kv_block(i, j):
-            # causal: tiles above the diagonal are skipped — hold the last
-            # needed kv block so the skipped steps fetch nothing
-            return jnp.minimum(j, ((i + 1) * bq - 1) // bk) if causal else j
-    else:
-        steps, kv_block = _band_walk(True, nq, bq, bk, nk, 0, window - 1, 0)
+    steps, kv_block = _kv_walk(causal, window, nq, bq, bk, nk, 0)
 
     qm = lambda r, g, i, j: (r // h_kv, i, head(r, g))  # noqa: E731
     km = lambda r, g, i, j: (r // h_kv, kv_block(i, j), k_col + r % h_kv)  # noqa: E731
@@ -1350,6 +1465,13 @@ def flash_fwd_bshd(q, k, v, *, scale, causal, kv_lens=None, bias=None,
     row's length — the padded-batch case); same masking/skip semantics as
     :func:`flash_fwd`.
 
+    Causal, a grid step is a tile skipped (above the diagonal or past the
+    band: nothing computed and, the k, v, k2 and bias maps held at the q
+    block's last needed block by :func:`_kv_walk`, nothing fetched), fully
+    visible (no mask) or crossed by the diagonal, the band's lower edge or
+    a length (the mask): :func:`_fwd_kernel`, counted by
+    :func:`forward_tiles`.
+
     ``bias`` (hb, sq, sk) with hb | h: additive score bias, q-head row
     ``t = b·h + h_i`` reading bias row ``t % hb``. ``rel_bias``: the
     bucketed triple (see :func:`flash_fwd`), table row ``t % hb``.
@@ -1375,8 +1497,7 @@ def flash_fwd_bshd(q, k, v, *, scale, causal, kv_lens=None, bias=None,
     off = sk - sq
     _check_window(window, causal, bias, rel_bias)
     _check_second(second, window, bias, rel_bias, kv_lens, dropout_rate)
-    steps, kv_block = _band_walk(window is not None, nq, bq, bk, nk, off,
-                                 (window or 1) - 1, 0)
+    steps, kv_block = _kv_walk(causal, window, nq, bq, bk, nk, off)
     varlen = kv_lens is not None
     hb = 0 if bias is None else bias.shape[0]
     rel, rel_static = (None, None) if rel_bias is None else (
@@ -1408,7 +1529,7 @@ def flash_fwd_bshd(q, k, v, *, scale, causal, kv_lens=None, bias=None,
     tail_specs, tail_args = _tail_operands(
         kv_lens, b, dropout_rate, dropout_seed,
         lambda t, i, j, h=h: (t // h, 0, 0),
-        bias, lambda t, i, j, hb=hb: (t % hb, i, j), (1, bq, bk),
+        bias, lambda t, i, j, hb=hb: (t % hb, i, kv_block(i, j)), (1, bq, bk),
         rel, lambda t, i, j, rhb=rhb: t % rhb)
     in_specs += tail_specs
     args += tail_args

@@ -1786,3 +1786,177 @@ class TestBucketedBias:
                 jnp.zeros((192,)), jnp.zeros((64, 64)),
                 BucketedBias(jnp.zeros((16, 1)), True, 64), None, None,
                 1, 1, 64, 0.125, True)
+
+
+# --- the forward's three kinds of tile ----------------------------------------
+#
+# ``_fwd_kernel`` classifies a grid step from block indices: skipped (nothing
+# computed, nothing fetched), fully visible (no mask) or crossed (the mask).
+# Every case below makes all the kinds its mask can make in ONE call, at blocks
+# of 128, and checks value and lse against materialised scores — and against
+# the same kernel with every running tile sent through the mask.
+
+def _forward_reference(q, k, v, *, scale, causal, window=None, kv_lens=None,
+                       bias=None, second=None, rate=0.0, seed=None):
+    """Head-major materialised scores in float32: q (b, h, sq, d), k / v
+    (b, h, sk, ·) with kv heads repeated, ``kv_lens`` (b, h), ``bias``
+    broadcastable to (b, h, sq, sk), ``second`` = (q2, k2) head-major with
+    k2's heads repeated. Returns (o (b, h, sq, dv), lse (b, h, sq))."""
+    from apex_tpu.ops.pallas.attention import NEG_INF, dropout_keep
+
+    b, h, sq, _ = q.shape
+    sk = k.shape[2]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k)
+    if second is not None:
+        s = s + jnp.einsum("bhqd,bhkd->bhqk", *second)
+    s = s * scale
+    if bias is not None:
+        s = s + bias
+    rows = jnp.arange(sq)[:, None] + (sk - sq)
+    cols = jnp.arange(sk)[None, :]
+    keep = jnp.ones((sq, sk), bool)
+    if causal:
+        keep = keep & (cols <= rows)
+    if window is not None:
+        keep = keep & (cols > rows - window)
+    keep = jnp.broadcast_to(keep, s.shape)
+    if kv_lens is not None:
+        keep = keep & (cols < kv_lens[:, :, None, None])
+    s = jnp.where(keep, s, NEG_INF)
+    m = jnp.max(s, -1, keepdims=True)
+    p = jnp.exp(s - m)
+    l = jnp.sum(p, -1, keepdims=True)
+    prob = p / l
+    if rate:
+        t = (jnp.arange(b)[:, None] * h + jnp.arange(h)[None, :])[:, :, None, None]
+        kept = dropout_keep(seed, t, jnp.arange(sq)[:, None], cols, rate)
+        prob = prob * jnp.where(kept, 1.0 / (1.0 - rate), 0.0)
+    return jnp.einsum("bhqk,bhkd->bhqd", prob, v), (m + jnp.log(l))[..., 0]
+
+
+# wrapper, sq, sk, heads (h, h_kv), d, and what rides the call
+_TILE_CASES = {
+    # the flat layout: more keys than queries (off = 256: a q block's first two
+    # tiles fully visible, one crossed, one skipped)
+    "flat_causal_longer_keys": dict(form="flat", sq=256, sk=512, h=2, h_kv=2, d=32),
+    "flat_causal_grouped": dict(form="flat", sq=512, sk=512, h=4, h_kv=2, d=32),
+    # a length that ends INSIDE a tile the diagonal leaves fully visible
+    # (row 0: 200 of 512, tile (3, 1) holds columns 128-255): the mask branch
+    "flat_causal_lengths": dict(form="flat", sq=512, sk=512, h=2, h_kv=2, d=32,
+                                lens=(200, 512, 129, 384)),
+    "flat_full_lengths": dict(form="flat", sq=256, sk=512, h=2, h_kv=1, d=32, causal=False,
+                              lens=(200, 512, 129, 384)),
+    "flat_causal_bias": dict(form="flat", sq=256, sk=512, h=2, h_kv=2, d=32, bias=2),
+    "flat_causal_bucketed_bias": dict(form="flat", sq=512, sk=512, h=2, h_kv=2, d=32, rel=True),
+    "flat_causal_dropout": dict(form="flat", sq=256, sk=512, h=2, h_kv=2, d=32, rate=0.3),
+    "flat_full": dict(form="flat", sq=256, sk=384, h=2, h_kv=2, d=32, causal=False),
+    # the packed projection buffer
+    "packed_causal": dict(form="packed", sq=512, sk=512, h=4, h_kv=1, d=128),
+    "packed_causal_lengths": dict(form="packed", sq=512, sk=512, h=4, h_kv=2, d=32,
+                                  lens=(200, 385)),
+    "packed_causal_bias_dropout": dict(form="packed", sq=512, sk=512, h=2, h_kv=2, d=32,
+                                       bias=2, rate=0.2),
+    # heads of 64 two to a lane tile
+    "pair_causal": dict(form="packed", sq=512, sk=512, h=4, h_kv=4, d=64),
+    "pair_causal_lengths_dropout": dict(form="packed", sq=512, sk=512, h=2, h_kv=2, d=64,
+                                        lens=(200, 385), rate=0.2),
+    # separate seq-major arrays
+    "bshd_causal_longer_keys": dict(form="bshd", sq=256, sk=512, h=4, h_kv=2, d=128),
+    "bshd_causal_lengths": dict(form="bshd", sq=512, sk=512, h=2, h_kv=1, d=128,
+                                lens=(200, 385)),
+    "bshd_causal_bias": dict(form="bshd", sq=256, sk=512, h=2, h_kv=2, d=128, bias=1),
+    "bshd_causal_dropout": dict(form="bshd", sq=512, sk=512, h=2, h_kv=2, d=128, rate=0.3),
+    # a window whose lower edge crosses a tile (300 of blocks of 128), longer
+    # keys; one that is a whole number of tiles; one with a length
+    "bshd_window_edge": dict(form="bshd", sq=384, sk=512, h=2, h_kv=1, d=128, window=300),
+    "bshd_window_of_tiles": dict(form="bshd", sq=512, sk=512, h=2, h_kv=1, d=128, window=256),
+    "bshd_window_lengths": dict(form="bshd", sq=512, sk=512, h=2, h_kv=1, d=128, window=300,
+                                lens=(450, 512)),
+    # two head widths and a second score term on one shared key
+    "bshd_second": dict(form="bshd", sq=512, sk=512, h=4, h_kv=4, d=128, dv=128, d2=64),
+    "bshd_second_wider_values": dict(form="bshd", sq=384, sk=384, h=2, h_kv=1, d=128, dv=256,
+                                     d2=64),
+}
+
+
+class TestForwardTileKinds:
+    @pytest.mark.pallas
+    @pytest.mark.parametrize("case", sorted(_TILE_CASES))
+    def test_value_and_lse_match_materialised_scores(self, case, monkeypatch):
+        from apex_tpu.ops.pallas import attention as pk
+
+        c = dict(_TILE_CASES[case])
+        form, sq, sk, h, h_kv, d = (c[n] for n in ("form", "sq", "sk", "h", "h_kv", "d"))
+        causal, window, rate = c.get("causal", True), c.get("window"), c.get("rate", 0.0)
+        dv, d2, b, blk = c.get("dv", d), c.get("d2", 0), 2, 128
+        key = jr.fold_in(K, 4600 + sorted(_TILE_CASES).index(case))
+        n = lambda i, *shape: jr.normal(jr.fold_in(key, i), shape)  # noqa: E731
+        q, k, v = n(0, b, h, sq, d), n(1, b, h_kv, sk, d), n(2, b, h_kv, sk, dv)
+        second = (n(3, b, h, sq, d2), n(4, b, 1, sk, d2)) if d2 else None
+        bias = n(5, c["bias"], sq, sk) if c.get("bias") else None
+        seed = jnp.int32(91) if rate else None
+        scale = (d + d2) ** -0.5
+        rep = lambda x: jnp.repeat(x, h // x.shape[1], 1)  # noqa: E731
+        # lengths a batch row (seq-major) or a (batch, head) row (flat)
+        lens = None if "lens" not in c else jnp.array(c["lens"], jnp.int32)
+        lens_bh = None if lens is None else (
+            lens.reshape(b, h) if form == "flat" else jnp.repeat(lens[:, None], h, 1))
+        kw = dict(scale=scale, causal=causal, kv_lens=lens, bq=blk, bk=blk, interpret=True,
+                  dropout_rate=rate, dropout_seed=seed)
+        rel = None
+        if c.get("rel"):
+            from apex_tpu.ops.attention import BucketedBias
+            table = BucketedBias(n(6, 8, h) * 0.5, False, 64)
+            bias_ref, rel = table.materialize(sq, sk)[None], table.kernel_operands()
+        else:
+            bias_ref = None if bias is None else jnp.tile(bias, (b * h // bias.shape[0], 1, 1)
+                                                          ).reshape(b, h, sq, sk)
+        seq_major = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+
+        def run():
+            if form == "flat":
+                o, lse = pk.flash_fwd(q.reshape(b * h, sq, d), k.reshape(b * h_kv, sk, d),
+                                      v.reshape(b * h_kv, sk, d), bias=bias, rel_bias=rel, **kw)
+                return o.reshape(b, h, sq, d), lse.reshape(b, h, sq)
+            if form == "packed":
+                qkv = jnp.concatenate([seq_major(x).reshape(b, sq, -1) for x in (q, k, v)], -1)
+                o, lse = pk.flash_fwd_packed(qkv, h, h_kv, d, bias=bias, **kw)
+                return seq_major(o.reshape(b, sq, h, d)), lse
+            o, lse = pk.flash_fwd_bshd(seq_major(q), seq_major(k), seq_major(v), bias=bias,
+                                       window=window, second=second, **kw)
+            return seq_major(o), lse
+
+        # the call holds every kind of step its mask can make
+        running, visible, skipped = pk.forward_tiles(sq, sk, blk, blk, causal, window)
+        if causal:
+            assert visible > 0 and running > visible and skipped > 0, (running, visible, skipped)
+        with jax.default_matmul_precision("highest"):
+            o, lse = run()
+            o_ref, lse_ref = _forward_reference(
+                q, rep(k), rep(v), scale=scale, causal=causal, window=window, kv_lens=lens_bh,
+                bias=bias_ref, second=None if second is None else (second[0], rep(second[1])),
+                rate=rate, seed=seed)
+            # every running tile through the mask, as before there was a branch
+            kind = pk._tile_kind
+            monkeypatch.setattr(pk, "_tile_kind", lambda *a: (kind(*a)[0], False))
+            o_masked, lse_masked = run()
+        np.testing.assert_allclose(o, o_ref, atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(lse, lse_ref, atol=2e-5, rtol=2e-5)
+        # to a rounding: interpreted on the CPU the two branches are two XLA
+        # fusions (one contracts scale and maximum into an fma); on the chip
+        # the forms are held bit-equal to the parent's by tools/tpu_kernel_smoke.py
+        np.testing.assert_allclose(o, o_masked, atol=1e-6, rtol=0)
+        np.testing.assert_allclose(lse, lse_masked, atol=1e-6, rtol=0)
+
+    @pytest.mark.pallas
+    def test_a_call_with_nothing_to_mask_has_one_body_and_no_branch(self):
+        """Not causal, no lengths: one unmasked body — no ``cond``, no iota."""
+        from apex_tpu.ops.pallas import attention as pk
+
+        q = jnp.zeros((2, 256, 32))
+        text = lambda **kw: str(jax.make_jaxpr(lambda q: pk.flash_fwd(  # noqa: E731
+            q, q, q, scale=1.0, bq=128, bk=128, interpret=True, **kw))(q))
+        full, causal = text(causal=False), text(causal=True)
+        assert "iota" not in full and "iota" in causal
+        # init, finish / init, finish and the two kinds of running tile
+        assert full.count("cond[") == 2 and causal.count("cond[") == 4

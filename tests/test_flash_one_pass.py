@@ -21,7 +21,7 @@ class TestPackedOnePassBackward:
     Checked against the gradients of the XLA composition (the same mask
     hash, so dropout agrees bit for bit)."""
 
-    FULL, SHORT, DEAD = None, (512, 100), (0, 300)
+    FULL, SHORT, DEAD, INSIDE = None, (512, 100), (0, 300), (512, 200)
 
     @pytest.mark.pallas
     @pytest.mark.parametrize(
@@ -38,6 +38,9 @@ class TestPackedOnePassBackward:
             (4, 1, True, SHORT, 0.0, 512, 128, jnp.float32),
             (4, 1, True, DEAD, 0.0, 512, 128, jnp.float32),
             (4, 1, False, SHORT, 0.0, 512, 128, jnp.float32),
+            # a length that ends inside a tile the diagonal leaves fully
+            # visible (200 of 512: tile (3, 1)): both bodies' mask branch
+            (4, 1, True, INSIDE, 0.0, 512, 128, jnp.float32),
             # dropout, same seed as the forward; with lengths too
             (4, 1, True, FULL, 0.3, 512, 128, jnp.float32),
             (2, 2, False, FULL, 0.3, 512, 128, jnp.float32),
@@ -99,7 +102,7 @@ class TestBshdOnePassBackward:
     the form given no VMEM to ask for) and against the gradients of the XLA
     composition (the same mask hash, so dropout agrees bit for bit)."""
 
-    FULL, SHORT, DEAD, LATE = None, (512, 100), (0, 300), (512, 450)
+    FULL, SHORT, DEAD, LATE, INSIDE = None, (512, 100), (0, 300), (512, 450), (512, 200)
 
     @pytest.mark.pallas
     @pytest.mark.parametrize(
@@ -134,6 +137,10 @@ class TestBshdOnePassBackward:
             (8, 1, 128, False, None, SHORT, 0.0, 512, 128, jnp.float32),
             (8, 1, 128, True, None, DEAD, 0.0, 512, 128, jnp.float32),
             (8, 1, 128, True, 200, LATE, 0.0, 512, 128, jnp.float32),
+            # a length that ends inside a tile the diagonal (and the band)
+            # leave fully visible
+            (8, 1, 128, True, None, INSIDE, 0.0, 512, 128, jnp.float32),
+            (8, 1, 128, True, 300, LATE, 0.0, 512, 128, jnp.float32),
             # dropout: the forward's mask regenerated from its seed
             (8, 1, 128, True, None, FULL, 0.3, 512, 128, jnp.float32),
             (2, 2, 128, False, None, FULL, 0.3, 512, 128, jnp.float32),

@@ -59,6 +59,8 @@ def both(args, causal, impl, scale=None):
 CASES = {
     # DeepSeek-V2-Lite's form: heads of 128 + 64 rotary against values of 128, ONE rotary key
     "mla": dict(b=2, s=384, h=4, h_kv=4, h2=1, d=128, dv=128, d2=64),
+    # 4 x 4 tiles: fully visible ones beside the skipped and the crossed
+    "mla_four_blocks": dict(b=1, s=512, h=2, h_kv=2, h2=1, d=128, dv=128, d2=64),
     "mla_grouped": dict(b=1, s=256, h=8, h_kv=4, h2=2, d=128, dv=128, d2=64),
     "wider_values": dict(b=1, s=256, h=4, h_kv=2, h2=1, d=128, dv=256, d2=64),
     "narrower_values_no_second": dict(b=1, s=256, h=4, h_kv=2, h2=2, d=256, dv=128, d2=0),

@@ -153,3 +153,99 @@ def test_window_refuses_what_it_cannot_mask(kw):
     args.update(kw)
     with pytest.raises(ValueError, match="window"):
         flash_attention(q, q, q, **args)
+
+
+# --- what a forward grid step is, counted from the mask ------------------------
+
+def _mask(sq, sk, causal, window):
+    rows = np.arange(sq)[:, None] + (sk - sq)
+    cols = np.arange(sk)[None, :]
+    keep = np.ones((sq, sk), bool)
+    if causal:
+        keep &= cols <= rows
+    if window is not None:
+        keep &= cols > rows - window
+    return keep
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk,causal,window", [
+    (8192, 8192, 1024, 1024, True, None),       # the 8k cells' full layers
+    (8192, 8192, 1024, 1024, True, 2048),       # trinity-train-8k's banded layers
+    (1024, 1024, 512, 512, True, None),         # the pair backward's blocks
+    (1024, 1024, 1024, 1024, True, None),       # the pair forward's one tile
+    (8192, 8192, 1024, 1024, False, None),
+    (512, 1024, 128, 128, True, None),          # more keys than queries
+    (512, 1024, 128, 256, True, None),
+    (1024, 1024, 256, 128, True, None),
+    (384, 512, 128, 128, True, 300),
+    (512, 512, 128, 128, True, 1),
+    (512, 512, 128, 128, True, 129),
+    (512, 512, 128, 256, True, 256),
+    (512, 512, 128, 128, True, 4096),
+    (256, 1024, 128, 128, False, None),
+])
+def test_forward_tiles_counts_the_mask(sq, sk, bq, bk, causal, window):
+    """``forward_tiles`` against the mask itself: a tile runs iff it holds a
+    visible score, is fully visible iff it holds no hidden one, and the grid
+    is as long as the widest run of running tiles a q block has."""
+    keep = _mask(sq, sk, causal, window)
+    tiles = keep.reshape(sq // bq, bq, sk // bk, bk).transpose(0, 2, 1, 3)
+    runs, full = tiles.any((2, 3)), tiles.all((2, 3))
+    steps = runs.sum(1).max()
+    want = (int(runs.sum()), int(full.sum()), int(runs.shape[0] * steps - runs.sum()))
+    assert fa.forward_tiles(sq, sk, bq, bk, causal, window) == want
+    # the kernel's own predicates, tile by tile
+    for i in range(sq // bq):
+        for j in range(sk // bk):
+            run, inner = fa._tile_kind(i, j, bq, bk, sk - sq, causal, window)
+            # a band's tile past the diagonal never reaches the kernel as a
+            # tile under its lower edge: `run` is the diagonal's test alone
+            if window is None or runs[i, j] or j * bk > (i + 1) * bq - 1 + sk - sq:
+                assert bool(run) == bool(runs[i, j]), (i, j)
+            if runs[i, j]:
+                assert bool(inner) == bool(full[i, j]), (i, j)
+
+
+def test_forward_tiles_at_the_cells_shapes():
+    assert fa.forward_tiles(8192, 8192, 1024, 1024, True) == (36, 28, 28)
+    assert fa.forward_tiles(8192, 8192, 1024, 1024, True, 2048) == (21, 7, 3)
+    assert fa.forward_tiles(1024, 1024, 512, 512, True) == (3, 1, 1)
+    assert fa.forward_tiles(1024, 1024, 1024, 1024, True) == (1, 0, 0)
+    assert fa.forward_tiles(8192, 8192, 1024, 1024, False) == (64, 64, 0)
+    # a length is a run-time operand: the kernel sends a tile it ends in
+    # through the mask, and skips one past it
+    kind = lambda i, j, causal, kvlen: fa._tile_in_length(  # noqa: E731
+        *fa._tile_kind(i, j, 128, 128, 0, causal, None), j, 128, kvlen)
+    assert kind(3, 1, True, 200) == (True, False)
+    assert kind(3, 0, True, 200) == (True, True)
+    assert kind(3, 2, True, 200) == (False, False)
+    assert kind(0, 1, False, 256) == (True, True)
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk", [
+    (8192, 8192, 1024, 1024), (1024, 1024, 512, 512), (512, 1024, 128, 128),
+    (512, 1024, 128, 256), (1024, 1024, 256, 128), (1024, 1024, 128, 256),
+    (1024, 512, 128, 128),                      # more queries than keys: rows that see nothing
+])
+def test_the_causal_walk_holds_the_last_needed_block(sq, sk, bq, bk):
+    """The one kv index rule of the forwards and the one-pass backward, over
+    every (i, j): the step itself where the tile runs, held at the q block's
+    last needed block where it is skipped, never past it and never below 0."""
+    nq, nk, off = sq // bq, sk // bk, sk - sq
+    steps, block = fa._kv_walk(True, None, nq, bq, bk, nk, off)
+    assert steps == nk
+    keep = _mask(sq, sk, True, None)
+    runs = keep.reshape(nq, bq, nk, bk).any((1, 3))
+    for i in range(nq):
+        needed = max(int(runs[i].sum()) - 1, 0)          # the running tiles are 0 .. needed
+        held = [int(block(jnp.int32(i), jnp.int32(j))) for j in range(nk)]
+        assert held == [int(block(i, j)) for j in range(nk)]      # traced and static agree
+        for j in range(nk):
+            assert held[j] == (j if runs[i, j] else needed), (i, j)
+            assert 0 <= held[j] <= needed
+    # not causal: every block in order; banded: the band's own walk
+    assert [fa._kv_walk(False, None, nq, bq, bk, nk, off)[1](0, j) for j in range(nk)] == list(
+        range(nk))
+    if off >= 0:
+        assert fa._kv_walk(True, 2 * bk, nq, bq, bk, nk, off)[0] == fa._band_walk(
+            True, nq, bq, bk, nk, off, 2 * bk - 1, 0)[0]

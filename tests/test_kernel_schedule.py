@@ -1,6 +1,7 @@
 """``tools/kernel_schedule.py``'s two readers on a made-up dump: the bundles a
 grid step walks (a loop's body times its trips, hoisted outer work inside a
-body counted with it) and the units' mean use a window."""
+body counted with it), the units' mean use a window or a stretch, and the
+stretches between control marks (a ``pl.when`` body's end)."""
 import os
 import sys
 
@@ -48,8 +49,24 @@ def test_unit_use_is_a_share_of_each_units_capacity():
             "    4     3     4     1     3     3     1     1     2", "== UTILIZATION:"]
     rows += ["4 0 2 0 0 0 1 1 0"] * 2 + ["0 3 2 1 3 0 0 0 2"] * 2 + ["2 0 0 0 0 0 0 0 0"]
     windows = ks.unit_use("\n".join(rows), 4)
-    assert [first for first, _ in windows] == [0, 4]
-    use = windows[0][1]
+    assert [(first, n) for first, n, _ in windows] == [(0, 4), (4, 1)]
+    use = windows[0][2]
     assert use["MXU"] == 0.5 and use["XLU"] == 0.5 and use["VALU"] == 0.5 and use["EUP"] == 0.5
     assert use["VSTORE"] == 0.5 and use["SPILL"] == 0.5 and use["SALU"] == 0.5
-    assert windows[1][1]["MXU"] == 0.5 and windows[1][1]["VALU"] == 0.0
+    assert windows[1][2]["MXU"] == 0.5 and windows[1][2]["VALU"] == 0.0
+    # by stretch: from each given first bundle to the next
+    stretches = ks.unit_use("\n".join(rows), firsts=[0, 2])
+    assert [(first, n) for first, n, _ in stretches] == [(0, 2), (2, 3)]
+    assert stretches[0][2]["MXU"] == 1.0 and stretches[1][2]["XLU"] == 2 / 3
+
+
+def test_a_predicated_region_ends_at_its_fallthrough_mark():
+    """A ``pl.when`` body is a predicated region: the flash forward's text is
+    prologue, init, the tile with no mask, the tile under the mask, finish."""
+    text = bundles([("", 0, "")] * 2 + [("LB", 1, "")] + [("", 1, "")] * 3 + [("PF", 1, "")]
+                   + [("", 1, "")] * 7 + [("PF", 1, "")] + [("", 1, "")] * 9 + [("PF", 1, "")]
+                   + [("", 1, exit_test(2050))])
+    assert ks.regions(text) == [0, 2, 6, 14, 24]
+    # the parent process's labels are the families' own
+    assert ks.LABELS == {family: tuple(label for label, _, _ in kernels)
+                         for family, kernels in ks.families().items()}

@@ -4,6 +4,13 @@ of 128, seq 1024, vocab 32768, 8 serving slots, 128-row cache blocks).
 
     python tools/tpu_kernel_smoke.py          # exits non-zero on any failure
     python tools/tpu_kernel_smoke.py gdn moe  # only the families so named
+    python tools/tpu_kernel_smoke.py "flash bshd" "flash packed pair" --checkout .scratch/parent
+
+``--checkout DIR`` (any number of them): the flash families at a cell's shape
+also run their FORWARD from ``DIR``'s ``ops/pallas/attention.py`` on the same
+operands, print its device time beside the tree's and hold ``o`` and ``lse`` to
+it bit for bit (max |diff| must read 0.0: a forward that only stops masking
+what needs no mask and fetching what it skips changes no result).
 
 A family is ``(kernel_fn, reference_fn, args)``: both run under ``jax.jit``
 on the same operands and every output leaf (forward values AND gradients)
@@ -340,6 +347,82 @@ def _sliced_attention_reference(fn):
     return run
 
 
+# trees whose forward kernels the flash cell families compare with (``--checkout``)
+CHECKOUTS = []
+
+
+def _attention_at(checkout):
+    """``ops/pallas/attention.py`` of another tree as a module of its own (its
+    imports resolve to this tree's package, which it shares)."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "attention_at_" + "".join(c if c.isalnum() else "_" for c in checkout),
+        os.path.join(checkout, "apex_tpu", "ops", "pallas", "attention.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def _forward_report(forms):
+    """A flash cell family's lines: for each of ``forms`` — ``(what, (sq, sk,
+    block, window), call, operands)``, ``call(pk, *operands)`` the raw forward
+    wrapper of attention module ``pk`` returning ``(o, lse)`` — the grid steps
+    a head takes by kind (``forward_tiles``, static by shape), the forward's
+    device time, and for every ``--checkout`` that tree's time and the largest
+    difference of ``o`` and ``lse`` (it must read 0.0)."""
+    def diff(a, b):
+        return float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))))
+
+    def report():
+        from apex_tpu.ops.pallas import attention as here
+        lines = []
+        for what, (sq, sk, block, window), call, operands in forms:
+            args = operands()
+
+            def run(pk):
+                _, by_name, out = _device_ms(functools.partial(call, pk), *args, with_output=True)
+                return sum(t for n, t in by_name.items() if "flash_fwd" in n), out
+
+            ms, (o, lse) = run(here)
+            running, visible, skipped = here.forward_tiles(sq, sk, block, block, True, window)
+            lines.append(f"{what}: {ms:.3f} ms a call on the device; a head's grid steps: "
+                         f"{running} running tiles, {visible} of them fully visible (no mask), "
+                         f"{skipped} skipped (nothing fetched)")
+            for checkout in CHECKOUTS:
+                ms_c, (o_c, lse_c) = run(_attention_at(checkout))
+                d_o, d_lse = diff(o, o_c), diff(lse, lse_c)
+                lines.append(f"{'FAIL' if d_o or d_lse else '    '} {checkout}: {ms_c:.3f} ms a call; "
+                             f"max |diff| of o {d_o}, of lse {d_lse}")
+        return lines
+    return report
+
+
+def _bshd_forward_forms(h, h_kv, d, window, packed=False):
+    """The forward wrappers a cell family's shape rides: ``flash_fwd_bshd``
+    (``_win`` under a window) and, ``packed``, ``flash_fwd_packed`` on a q|k|v
+    buffer of the same heads."""
+    def bshd(pk, q, k, v):
+        return pk.flash_fwd_bshd(q, k, v, scale=d ** -0.5, causal=True, window=window,
+                                 full_lse=True, interpret=_backend.interpret_mode())
+
+    def of_buffer(pk, qkv):
+        return pk.flash_fwd_packed(qkv, h, h_kv, d, scale=d ** -0.5, causal=True, full_lse=True,
+                                   interpret=_backend.interpret_mode())
+
+    qkv = lambda: (jr.normal(_key(54), (2, 8192, (h + 2 * h_kv) * d), jnp.bfloat16),)  # noqa: E731
+    name = "flash_fwd_bshd" + ("" if window is None else "_win")
+    forms = [(name, (8192, 8192, 1024, window), bshd, lambda: _cell_qkv(h, h_kv, d))]
+    if packed:
+        forms.append(("flash_fwd_packed", (8192, 8192, 1024, None), of_buffer, qkv))
+    return forms
+
+
+def _cell_qkv(h, h_kv, d):
+    q = jr.normal(_key(51), (2, 8192, h, d), jnp.bfloat16)
+    k, v = (jr.normal(_key(i), (2, 8192, h_kv, d), jnp.bfloat16) for i in (52, 53))
+    return q, k, v
+
+
 def _flash_cell_family(h, h_kv, d, window):
     """The seq-major kernels at a training cell's attention shape, 2 rows of
     8,192: the forward (``flash_fwd_bshd`` / ``_win``) and the one-pass
@@ -347,14 +430,11 @@ def _flash_cell_family(h, h_kv, d, window):
     and dv from one walk of the tiles, on a window 3 of the 8 kv blocks a q
     block) against XLA's masked scores, a slice at a time."""
     def build():
-        q = jr.normal(_key(51), (2, 8192, h, d), jnp.bfloat16)
-        k, v = (jr.normal(_key(i), (2, 8192, h_kv, d), jnp.bfloat16) for i in (52, 53))
-
         def attention(impl):
             return lambda q, k, v: flash_attention(
                 q, k, v, causal=True, layout="bshd", window=window, impl=impl)
         return (_fwd_and_grads(attention("pallas"), (0, 1, 2)),
-                _sliced_attention_reference(attention("xla")), (q, k, v))
+                _sliced_attention_reference(attention("xla")), _cell_qkv(h, h_kv, d))
     return build
 
 
@@ -389,11 +469,21 @@ def _pair_cell_family():
     return build
 
 
-def _device_ms(fn, *args, calls=5):
+def _pair_forward_forms():
+    b, s, h, d = (PAIR_CELL[k] for k in "bshd")
+
+    def call(pk, qkv):
+        return pk.flash_fwd_packed(qkv, h, h, d, scale=d ** -0.5, causal=True, full_lse=True,
+                                   interpret=_backend.interpret_mode())
+    return [("flash_fwd_packed_pair", (s, s, 1024, None), call,
+             lambda: (jr.normal(_key(4), (b, s, 3 * h * d), jnp.bfloat16),))]
+
+
+def _device_ms(fn, *args, calls=5, with_output=False):
     """``(total, by name)``: device milliseconds a call of jitted ``fn``, from
     a profiler trace of ``calls`` calls — every operation's, and each
     operation's by its instruction name. Not the host clock, which under
-    0.2 ms reads the dispatch floor."""
+    0.2 ms reads the dispatch floor. ``with_output``: a call's result third."""
     import tempfile
     from apex_tpu.prof.scopes import read_xplane
     fn = jax.jit(fn)
@@ -405,7 +495,8 @@ def _device_ms(fn, *args, calls=5):
         jax.profiler.stop_trace()
         ops_s, _, _ = read_xplane(logdir)
     by_name = {name: 1e3 * took / calls for name, took in ops_s.items()}
-    return sum(by_name.values()), by_name
+    total = sum(by_name.values())
+    return (total, by_name, out[0]) if with_output else (total, by_name)
 
 
 def _pair_cell_times():
@@ -488,6 +579,16 @@ def _latent_cell_family(s=8192):
 
         return _fwd_and_grads(attention("pallas"), (0, 1, 2, 3, 4)), sliced, args
     return build
+
+
+def _latent_forward_forms(s=8192, heads=16):
+    def call(pk, q, k, v, q2, k2):
+        return pk.flash_fwd_bshd(q, k, v, scale=192 ** -0.5 * 1.5896, causal=True, full_lse=True,
+                                 second=(q2, k2), interpret=_backend.interpret_mode())
+    shapes = ((2, s, heads, 128),) * 3 + ((2, heads, s, 64), (2, 1, s, 64))    # the second term head-major
+    return [("flash_fwd_bshd_mla", (s, s, 1024, None), call,
+             lambda: tuple(jr.normal(_key(61 + i), shape, jnp.bfloat16)
+                           for i, shape in enumerate(shapes)))]
 
 
 def _delta_rule_family():
@@ -893,13 +994,22 @@ FAMILIES = (
     Family("xentropy stats", _xent_family, tol=F32_TOL),
     Family("flash bshd heads of 256, group 8", _flash_wide_head_family()),
     Family("flash bshd window 2,048 of 8,192, 32 / 4 heads of 128, fwd + one-pass bwd",
-           _flash_cell_family(32, 4, 128, 2048)),
+           _flash_cell_family(32, 4, 128, 2048),
+           timings=_forward_report(_bshd_forward_forms(32, 4, 128, 2048))),
+    Family("flash bshd 8,192, 32 / 4 heads of 128, fwd + one-pass bwd",
+           _flash_cell_family(32, 4, 128, None),
+           timings=_forward_report(_bshd_forward_forms(32, 4, 128, None))),
+    Family("flash bshd 8,192, 16 / 1 heads of 128 (sc1b-train-8k's, whose buffer is packed), "
+           "fwd + one-pass bwd", _flash_cell_family(16, 1, 128, None),
+           timings=_forward_report(_bshd_forward_forms(16, 1, 128, None, packed=True))),
     Family("flash bshd 8,192, 16 / 2 heads of 256, fwd + one-pass bwd",
-           _flash_cell_family(16, 2, 256, None)),
+           _flash_cell_family(16, 2, 256, None),
+           timings=_forward_report(_bshd_forward_forms(16, 2, 256, None))),
     Family("flash bshd latent 8,192, 16 heads of 128 + 64 shared / 128, fwd + one-pass bwd",
-           _latent_cell_family()),
+           _latent_cell_family(), timings=_forward_report(_latent_forward_forms())),
     Family("flash packed pair 8 x 1,024, 16 heads of 64 two to a lane tile, fwd + one-pass bwd",
-           _pair_cell_family(), timings=_pair_cell_times()),
+           _pair_cell_family(),
+           timings=lambda: _pair_cell_times()() + _forward_report(_pair_forward_forms())()),
     Family("gated delta rule gdn_fwd/gdn_bwd", _delta_rule_family()),
     Family("gated delta rule on drifted keys, against the recurrence",
            _delta_rule_drifted_family()),
@@ -1006,8 +1116,11 @@ def main(families=FAMILIES) -> list:
         print(f"{'PASS' if ok else 'FAIL'} {fam.name}: max err {err:.2e} "
               f"({apart}tol {fam.tol:.0e}, {'auto' if fam.auto else 'explicit'}, "
               f"compile {c_s:.1f} s, run {r_s:.2f} s)", flush=True)
-        for line in (fam.timings() if fam.timings else ()):
+        lines = fam.timings() if fam.timings else ()
+        for line in lines:
             print(f"     {line}", flush=True)
+        if ok and any(line.startswith("FAIL") for line in lines):
+            failed.append(fam.name)
     print(f"{len(families) - len(failed)}/{len(families)} kernel families "
           f"match their XLA composition on {jax.devices()[0].device_kind}")
     print(f"kernels: compile {compile_s:.1f} s, run {run_s:.1f} s "
@@ -1017,6 +1130,11 @@ def main(families=FAMILIES) -> list:
 
 if __name__ == "__main__":
     # any arguments choose the families whose name holds one of them
-    chosen = tuple(f for f in FAMILIES if not sys.argv[1:]
-                   or any(part in f.name for part in sys.argv[1:]))
+    parts = sys.argv[1:]
+    while "--checkout" in parts:
+        at = parts.index("--checkout")
+        CHECKOUTS.append(parts[at + 1])
+        del parts[at:at + 2]
+    chosen = tuple(f for f in FAMILIES if not parts
+                   or any(part in f.name for part in parts))
     sys.exit(1 if main(chosen) else 0)
