@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from benchmarks import afmoe_work
+from benchmarks import afmoe_work, hybrid_work
 from benchmarks.adapters import afmoe_tree, gpt_tree, train_o2_dp, train_o2_hybrid
 from benchmarks.adapters.train_o2_dp import ALL_NUMBERS, B1, compare, leaf_gaps  # noqa: F401
 from benchmarks.adapters.train_o2_hybrid import load_gap  # noqa: F401
@@ -135,13 +135,15 @@ def setup(ctx):
 
 
 def measure(t, ctx, tracer):
-    """``train_o2_dp.measure``'s window, with the counters of its steps
+    """``train_o2_dp.window``, with the counters of its steps
     beside it and the operations a token required at those loads."""
-    run = train_o2_dp.measure(t, ctx, tracer)
+    run = train_o2_dp.window(t, ctx, tracer)
     run["expert_load"] = _take_counters(t)
     run["dropped"] = t.dropped
     run["bias_spread"] = np.stack(t.bias_spread)
     run["train_flops_per_token"] = afmoe_work.window_flops_per_token(run)
+    run["expert_matmul_work"] = hybrid_work.window_expert_matmul_work(
+        run, view=afmoe_work.expert_view)
     ctx["log"](f"window: selection bias spread at its end {run['bias_spread'][-1].max():.4g}; "
                f"{run['dropped']} local assignments dropped")
     return run

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from benchmarks import bailing_work
+from benchmarks import bailing_work, hybrid_work, mla_work
 from benchmarks.adapters import bailing_tree, gpt_tree, train_o2_dp, train_o2_hybrid
 from benchmarks.adapters.train_o2_afmoe import bias_gap
 from benchmarks.adapters.train_o2_dp import ALL_NUMBERS, B1, compare, leaf_gaps  # noqa: F401
@@ -147,15 +147,17 @@ def setup(ctx):
 
 
 def measure(t, ctx, tracer):
-    """``train_o2_dp.measure``'s window, with the counters of its steps
+    """``train_o2_dp.window``, with the counters of its steps
     beside it and the operations a token required at those loads."""
-    run = train_o2_dp.measure(t, ctx, tracer)
+    run = train_o2_dp.window(t, ctx, tracer)
     run["expert_load"] = _take_counters(t)
     run["dropped"] = t.dropped
     run["bias_spread"] = np.stack(t.bias_spread)
     run["router_group_hit"] = np.stack(t.group_hit)
     run["kda_log_decay_min"] = min(t.log_decay_min)
     run["train_flops_per_token"] = bailing_work.window_flops_per_token(run)
+    run["expert_matmul_work"] = hybrid_work.window_expert_matmul_work(
+        run, view=mla_work.expert_view)
     ctx["log"](f"window: selection bias spread at its end {run['bias_spread'][-1].max():.4g}; "
                f"{run['dropped']} local assignments dropped; kept groups held the held "
                f"experts' for {100 * run['router_group_hit'].mean():.1f} % of the tokens; "

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from benchmarks import flops
 from benchmarks.adapters import gpt_tree
 from benchmarks.reference import gpt_ref
 
@@ -167,7 +168,7 @@ def setup(ctx):
     return t
 
 
-def measure(t, ctx, tracer):
+def window(t, ctx, tracer):
     """Steps for ``seconds``, every one counted, over the time from the
     first dispatch to the last loss fetched. The host fetches the loss of the
     step ``IN_FLIGHT`` behind the one it dispatched last, as a training loop
@@ -217,6 +218,15 @@ def measure(t, ctx, tracer):
         "window_s": elapsed, "steps": len(losses), "step_s": step_s,
         "tokens": tokens, "chips": t.n, "seq": t.seq, "dims": t.d,
     }
+
+
+def measure(t, ctx, tracer):
+    """``window`` with the GPT block's required operations a token, which the
+    MFU reader takes. An adapter of another block calls ``window`` and hands
+    its own count: one that hands none reports no ``mfu_pct``."""
+    run = window(t, ctx, tracer)
+    run["train_flops_per_token"] = flops.train_flops_per_token(t.d, t.seq)
+    return run
 
 
 def leaf_gaps(got, want, scale=None):

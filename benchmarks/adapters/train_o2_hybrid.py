@@ -124,12 +124,13 @@ def _loads(t):
 
 
 def measure(t, ctx, tracer):
-    """``train_o2_dp.measure``'s window, with the load counters of its steps
+    """``train_o2_dp.window``, with the load counters of its steps
     beside it and the operations a token required at those loads."""
-    run = train_o2_dp.measure(t, ctx, tracer)
+    run = train_o2_dp.window(t, ctx, tracer)
     run["expert_load"] = _loads(t)
     run["dropped"] = t.dropped
     run["train_flops_per_token"] = hybrid_work.window_flops_per_token(run)
+    run["expert_matmul_work"] = hybrid_work.window_expert_matmul_work(run)
     return run
 
 

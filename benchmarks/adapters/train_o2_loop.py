@@ -118,9 +118,9 @@ def setup(ctx):
 
 
 def measure(t, ctx, tracer):
-    """``train_o2_dp.measure``'s window, with the exits' readings of its steps
+    """``train_o2_dp.window``, with the exits' readings of its steps
     beside it and the operations a token required."""
-    run = train_o2_dp.measure(t, ctx, tracer)
+    run = train_o2_dp.window(t, ctx, tracer)
     run.update(_take_counters(t))
     run["train_flops_per_token"] = loop_work.window_flops_per_token(run)
     ctx["log"]("window: exits' share of the tokens at its end "

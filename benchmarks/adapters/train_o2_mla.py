@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from benchmarks import mla_work
+from benchmarks import hybrid_work, mla_work
 from benchmarks.adapters import gpt_tree, mla_tree, train_o2_dp, train_o2_hybrid
 from benchmarks.adapters.train_o2_dp import ALL_NUMBERS, B1, compare, leaf_gaps  # noqa: F401
 from benchmarks.adapters.train_o2_hybrid import first_steps, load_gap  # noqa: F401
@@ -107,13 +107,15 @@ def setup(ctx):
 
 
 def measure(t, ctx, tracer):
-    """``train_o2_dp.measure``'s window, with ``train_o2_hybrid``'s load
+    """``train_o2_dp.window``, with ``train_o2_hybrid``'s load
     counters of its steps beside it and the operations a token required of
     THIS block at those loads."""
-    run = train_o2_dp.measure(t, ctx, tracer)
+    run = train_o2_dp.window(t, ctx, tracer)
     run["expert_load"] = train_o2_hybrid._loads(t)
     run["dropped"] = t.dropped
     run["train_flops_per_token"] = mla_work.window_flops_per_token(run)
+    run["expert_matmul_work"] = hybrid_work.window_expert_matmul_work(
+        run, view=mla_work.expert_view)
     ctx["log"](f"window: {run['dropped']} local assignments dropped; largest held load a "
                f"layer and step {int(run['expert_load'].sum(-1).max())} rows")
     return run

@@ -119,3 +119,15 @@ def window_flops_per_token(run):
     if assignments is None:
         return None
     return train_flops_per_token(run["dims"], run["seq"], assignments / step_tokens(run))
+
+
+def window_expert_matmul_work(run, view=None, work=expert_matmul_work):
+    """The grouped products' required (operations, bytes) of one step at the
+    window's counted local assignments: what the adapter hands the roofline
+    reader under ``run["expert_matmul_work"]``. The adapter of another block
+    passes its ``expert_view`` of the dims, or its own count as ``work``."""
+    assignments = assignments_per_step(run)
+    if assignments is None:
+        return None
+    d = run["dims"] if view is None else view(run["dims"])
+    return work(d, assignments, passes=3)

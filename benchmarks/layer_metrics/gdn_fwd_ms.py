@@ -1,7 +1,8 @@
 """Device time of the forward gated delta-rule kernels (Mosaic calls whose
-name holds ``gdn_fwd``: the recurrence over chunks), per traced step, mean
-over chips. The chunk operands are prepared by XLA fusions under the
-``hybrid/gdn`` scope and are not in it."""
+name holds ``gdn_fwd``; since PR 27 the whole rule from ``q, k, v, g, beta``:
+the norms, the chunk operands, the 64 x 64 inverse and the recurrence), per
+traced step, mean over chips. The convolution and the gated norm around it
+are ``delta_mixer_ms``'s."""
 from benchmarks import hybrid_work, kernel_work
 
 LAYER = "kernels"

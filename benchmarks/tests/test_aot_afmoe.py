@@ -20,8 +20,8 @@ import pytest
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM = 15.75 * 2 ** 30   # what the compiler allows a program on one v5e chip
-KERNELS = {"flash_fwd_bshd", "flash_bwd_bshd_dq", "flash_bwd_bshd_dkv",
-           "flash_fwd_bshd_win", "flash_bwd_bshd_win_dq", "flash_bwd_bshd_win_dkv",
+# the one-pass backwards since PR 31 (``flash_bwd_bshd[_win]_dq`` / ``_dkv`` before it)
+KERNELS = {"flash_fwd_bshd", "flash_bwd_bshd_fused", "flash_fwd_bshd_win", "flash_bwd_bshd_win_fused",
            "moe_gmm", "moe_gmm_dx", "moe_gmm_dw", "xentropy_stats"}
 
 

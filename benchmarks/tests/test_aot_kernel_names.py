@@ -18,12 +18,13 @@ CUSTOM_CALL = re.compile(r"%?([\w.\-]+) = [^\n]*? custom-call\([^\n]*custom_call
 
 
 @pytest.mark.parametrize("name,seq,chips,kernels,reached", [
-    # 3 flash kernels a layer + CE. Heads of 128 take the fused projection +
-    # attention block (packed q|k|v), heads of 64 the (b, h, s, d) kernels
-    ("starcoderbase-1b-train1", 8192, 1, 25,
-     {"flash_fwd_packed", "flash_bwd_packed_dq", "flash_bwd_packed_dkv", "xentropy_stats"}),
-    ("gpt2-medium", 1024, 4, 73,
-     {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "xentropy_stats"})])
+    # 2 flash kernels a layer (the forward, the one-pass backward since PR 25) + CE. Heads
+    # of 128 take the fused projection + attention block (packed q|k|v), heads of 64 the
+    # same block two heads to a lane tile (PR 42)
+    ("starcoderbase-1b-train1", 8192, 1, 17,
+     {"flash_fwd_packed", "flash_bwd_packed_fused", "xentropy_stats"}),
+    ("gpt2-medium", 1024, 4, 49,
+     {"flash_fwd_packed_pair", "flash_bwd_packed_pair_fused", "xentropy_stats"})])
 def test_compiled_step_names_its_kernels(topo, as_on_tpu, name, seq, chips,  # noqa: F811
                                          kernels, reached):
     from apex_tpu.parallel import mesh as mesh_lib

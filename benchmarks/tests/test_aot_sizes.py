@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import pytest
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-HBM = 15.75e9   # what the compiler allows a program on one v5e chip
+HBM = 15.75 * 2 ** 30   # what the compiler allows a program on one v5e chip: GiB (16.91 GB)
 
 
 @pytest.fixture(scope="module")
@@ -47,8 +47,8 @@ def total_bytes(compiled):
 
 
 @pytest.mark.parametrize("name,seq,chips,kernels", [
-    ("starcoderbase-1b-train1", 8192, 1, 25),    # 3 flash kernels a layer + CE
-    ("gpt2-medium", 1024, 4, 73)])
+    ("starcoderbase-1b-train1", 8192, 1, 17),    # 2 flash kernels a layer + CE
+    ("gpt2-medium", 1024, 4, 49)])
 def test_train_step_fits(topo, as_on_tpu, name, seq, chips, kernels):
     from apex_tpu.parallel import mesh as mesh_lib
     from benchmarks.adapters import train_o2_dp
@@ -73,5 +73,5 @@ def test_train_step_fits(topo, as_on_tpu, name, seq, chips, kernels):
           f"{text.count('all-reduce')} mentions of all-reduce")
     assert total < HBM
     assert m.alias_size_in_bytes >= 0.99 * m.output_size_in_bytes   # state donated
-    assert text.count("tpu_custom_call") >= kernels
+    assert text.count("tpu_custom_call") == kernels
     assert (text.count("all-reduce") > 0) == (chips > 1)

@@ -172,8 +172,8 @@ def test_an_expert_layer_compiles_at_half_lane_tiles_and_widened_rows(
     fallback: a relu2 expert layer, forward and every gradient, compiles for
     the chip on the ``moe_gmm*`` and ``moe_rows_*`` kernels at widths other
     than the cell's, the rows at ``moves_at``. (The numbers at such widths:
-    ``tests/test_moe_dropless.py``, interpreted; on the chip the cell's own
-    alone, PERF.md section 7.)"""
+    ``tests/test_grouped_matmul.py`` and ``tests/test_expert_rows.py``,
+    interpreted; on the chip the cell's own alone, PERF.md section 7.)"""
     from jax.sharding import SingleDeviceSharding
     from apex_tpu.transformer import moe
 

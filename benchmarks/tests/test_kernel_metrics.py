@@ -4,7 +4,6 @@ events are spelt as the chip spells them, on a trace of a program that names
 nothing, and on one step of ``sc1b-train-8k`` recorded on the chip after the
 kernels were named (``fixtures/named/``, stats stripped)."""
 import glob
-import importlib.util
 import os
 import re
 
@@ -142,46 +141,3 @@ def test_recorded_named_trace_from_the_chip():
     assert 1e3 * sum(kernels.values()) == pytest.approx(fwd + bwd + xent)
     # the breakdown the ledger prints names kernels now
     assert trace["device_ops"][3][0].startswith("flash_bwd_packed_dkv.")
-
-
-def test_scope_times_by_hand():
-    """``scope_times.py``, the reader behind PERF.md section 5's block
-    columns, on a hand-made trace: two chips, two traced steps, the scope
-    path held by the stat itself and interned."""
-    from benchmarks.tests import scope_times as st
-
-    if importlib.util.find_spec("tensorflow") is None:
-        pytest.skip("no tensorflow to take the .xplane.pb schema from")
-    pb = st.xplane_schema()
-    ops = [  # name, tf_op, picoseconds
-        (FWD, "jit(call)/amp/fwd_bwd/jvp(gpt/attn)/flash_fwd_packed/pallas_call:", 40e9),
-        (DQ, "jit(call)/amp/fwd_bwd/transpose(jvp(gpt/attn))/flash_bwd_packed_dq/pallas_call:", 60e9),
-        (FUSION, "jit(call)/amp/fwd_bwd/transpose(jvp(gpt/unembed_xent))/dot_general:", 20e9),
-        ("%fusion.9 = f32[8]{0} fusion(f32[8]{0} %p), kind=kLoop",
-         "jit(call)/amp/apply_master/fused_adam/update/mul:", 10e9),
-        ("%fusion.10 = f32[8]{0} fusion(f32[8]{0} %p), kind=kLoop",
-         "jit(call)/amp/apply_master/add:", 6e9),
-        ("%copy.3 = bf16[8]{0} copy(bf16[8]{0} %p)", None, 4e9)]
-    space = pb.XSpace()
-    for chip in range(2):
-        p = space.planes.add(name=f"/device:TPU:{chip}")
-        p.stat_metadata[1].name = "tf_op"
-        line = p.lines.add(name="XLA Ops")
-        for i, (name, path, ps) in enumerate(ops, start=10):
-            p.event_metadata[i].name = name
-            if path and chip:
-                p.stat_metadata[100 + i].name = path
-                p.event_metadata[i].stats.add(metadata_id=1, ref_value=100 + i)
-            elif path:
-                p.event_metadata[i].stats.add(metadata_id=1, str_value=path)
-            for step in range(2):
-                line.events.add(metadata_id=i, offset_ps=int(step * 1e12), duration_ps=int(ps))
-    space.planes.add(name="/host:CPU").lines.add(name="main")
-    out = st.scope_times(space, steps=2)
-    assert out["chips"] == 2
-    assert out["blocks"] == pytest.approx({
-        "gpt/attn bwd": 60.0, "gpt/attn fwd": 40.0, "gpt/unembed_xent bwd": 20.0,
-        "fused_adam/update": 10.0, "amp/apply_master": 6.0, "(no scope)": 4.0})
-    assert out["kernels"] == pytest.approx({"gpt/attn bwd :: flash_bwd_packed_dq": 60.0,
-                                            "gpt/attn fwd :: flash_fwd_packed": 40.0})
-    assert out["no_scope"] == pytest.approx({"copy": 4.0})

@@ -46,9 +46,13 @@ def test_train_rehearsal_is_correct_and_reports_the_contract_keys(here):
     json.dumps(line)
 
 
-def test_traced_train_rehearsal_reports_layer_metrics(here):
+@pytest.mark.parametrize("entry,mfu", [("measure", True), ("window", False)])
+def test_traced_train_rehearsal_reports_layer_metrics(here, monkeypatch, entry, mfu):
+    """The shared ``window`` hands no ``train_flops_per_token``: an adapter of
+    another block that forgets its own reads nothing, never the GPT count."""
+    monkeypatch.setattr(train_o2_dp, "measure", getattr(train_o2_dp, entry))
     line = execute(here, seed=8, trace=1)
-    assert {"step_ms.train", "mfu_pct"} <= set(line["metrics"])
+    assert "step_ms.train" in line["metrics"] and ("mfu_pct" in line["metrics"]) is mfu
     assert "device_idle_pct.train" not in line["metrics"]   # no device in a CPU trace
     assert {"busy_s", "window_s"} <= set(line["device"])
     assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}

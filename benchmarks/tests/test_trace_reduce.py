@@ -59,6 +59,23 @@ def test_busy_idle_and_exposed_collective():
     assert tr.op_name(psum) == "psum.3"
 
 
+def test_an_idle_gap_is_named_by_the_innermost_host_event():
+    """``bench_step`` wraps every step and overlaps every gap longest: the gap
+    takes the name of the event nested deepest inside it that overlaps the gap,
+    the longest such overlap first; a gap no host event overlaps is not traced."""
+    fus = "%fusion.1 = bf16[8,8]{1,0} fusion(bf16[8,8]{1,0} %p0), kind=kLoop"
+    chip = [(0, 100, fus), (400, 500, fus), (600, 700, fus), (900, 1000, fus), (2000, 2100, fus)]
+    host = [(0, 1000, "bench_step"), (90, 450, "fetch_loss"), (120, 380, "device_get"),
+            (480, 560, "dispatch"), (560, 650, "feed.get"), (700, 1000, "fetch_loss")]
+    text = (plane("/device:TPU:0", "XLA Ops", chip, 1) + plane("/host:CPU", "main", host, 2))
+    r = tr.reduce(ProfileData.from_text_proto(text))
+    assert r["idle_gaps"] == [["host not traced", pytest.approx(1000e-9)],
+                              ["device_get", pytest.approx(300e-9)],      # inside fetch_loss
+                              ["fetch_loss", pytest.approx(200e-9)],      # nothing inside it
+                              ["dispatch", pytest.approx(100e-9)]]        # 60 ns against 40
+    assert tr.innermost(0, 10, [(0, 1000, "bench_step")]) == "bench_step"   # alone, it names it
+
+
 def test_a_trace_without_a_device_reads_as_nothing():
     r = tr.reduce(ProfileData.from_text_proto(plane("/host:CPU", "main", [(0, 5, "x")], 1)))
     assert r["chips"] == 0 and r["busy_s"] == 0.0

@@ -104,6 +104,18 @@ def host_events(profile):
     return out
 
 
+def innermost(a, b, host):
+    """What the host was doing in the gap ``[a, b)``: of the host events that
+    overlap it, one that encloses no other of them — a span around the whole
+    step (``bench_step``) overlaps every gap and names none — and of those
+    the one that overlaps it longest."""
+    over = [(min(b, hb) - max(a, ha), ha, hb, name) for ha, hb, name in host
+            if min(b, hb) > max(a, ha)]
+    leaves = [e for e in over if not any(
+        e[1] <= ha and hb <= e[2] and hb - ha < e[2] - e[1] for _, ha, hb, _ in over)]
+    return max(leaves)[3] if leaves else "host not traced"
+
+
 def reduce(profile, top=10):
     """Seconds, averaged over the chips in the trace."""
     per_chip = device_ops(profile)
@@ -128,14 +140,7 @@ def reduce(profile, top=10):
                  for i in range(len(merged) - 1)]
     ops_s = {name: ns / n / 1e9 for name, ns in by_name.items()}
     host = host_events(profile)
-    named = []
-    for size, a, b in sorted(gaps, reverse=True)[:top]:
-        best, overlap = "host not traced", 0
-        for ha, hb, name in host:
-            o = min(b, hb) - max(a, ha)
-            if o > overlap:
-                best, overlap = name, o
-        named.append([best, size / 1e9])
+    named = [[innermost(a, b, host), size / 1e9] for size, a, b in sorted(gaps, reverse=True)[:top]]
     return {
         "chips": n, "busy_s": busy / n / 1e9, "ops_s": ops_s,
         "collective_s": collective / n / 1e9,

@@ -17,18 +17,19 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmarks import afmoe_work, run, trace_reduce as tr  # noqa: E402
+from benchmarks import afmoe_work, hybrid_work, run, trace_reduce as tr  # noqa: E402
 from benchmarks.adapters import afmoe_tree, train_o2_afmoe  # noqa: E402
 from benchmarks.reference import afmoe_ref  # noqa: E402
-from benchmarks.tests import toy  # noqa: E402
+from benchmarks.tests import test_harness, toy  # noqa: E402
 from benchmarks.tests.test_trace_reduce import plane  # noqa: E402
 
 HERE = os.path.join(ROOT, "benchmarks")
 PEAKS = run.load_json(os.path.join(HERE, "peaks.json"))["TPU v5 lite"]
 CELL = "trinity-train-8k"
 NEW_METRICS = ("flash_win_fwd_ms", "flash_win_bwd_ms", "flash_win_fwd_roofline_pct",
-               "flash_win_bwd_roofline_pct", "mfu_pct.afmoe", "moe_gmm_ms.afmoe",
-               "moe_gmm_roofline_pct.afmoe", "moe_load_max_over_mean.afmoe")
+               "flash_win_bwd_roofline_pct")
+# what the cell reports under names it shares with other cells: their lists hold it
+SHARED_METRICS = ("mfu_pct", "moe_gmm_ms", "moe_gmm_roofline_pct", "moe_load_max_over_mean")
 PERIOD = ["sliding_attention"] * 3 + ["full_attention"]
 # the cell's cut at a toy size: published layer 0 (dense) and one period, a
 # window shorter than the rows, 16 experts top-4 with a share of 8 held
@@ -55,9 +56,7 @@ def manifest():
     m["workloads"] = [{"name": "toy-afmoe-cell", "config": "toy-afmoe",
                        "traffic": "toy-docs", "chips": 1}]
     m["per_layer"] += [{"name": n, "unit": "x", "moves": "train_tokens_per_s"}
-                       for n in ("moe_load_max_over_mean.afmoe", "mfu_pct.afmoe",
-                                 "flash_win_fwd_ms")]
-    m["per_layer"] = [p for p in m["per_layer"] if p["name"] != "mfu_pct"]
+                       for n in ("moe_load_max_over_mean", "flash_win_fwd_ms")]
     return m
 
 
@@ -77,8 +76,8 @@ def test_traced_rehearsal_is_correct_and_hands_back_the_counters(here):
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] >= 2
     # at most the 8 held experts' whole load on one; how far a toy router
     # drifts at lr 3e-4 depends on how many steps the host fits in the window
-    assert 1.0 <= line["metrics"]["moe_load_max_over_mean.afmoe"]["value"] <= 8.0
-    assert 0.0 < line["metrics"]["mfu_pct.afmoe"]["value"] < 100.0
+    assert 1.0 <= line["metrics"]["moe_load_max_over_mean"]["value"] <= 8.0
+    assert 0.0 < line["metrics"]["mfu_pct"]["value"] < 100.0
     assert "flash_win_fwd_ms" not in line["metrics"]          # no device in a CPU trace
     json.dumps(line)
 
@@ -208,7 +207,9 @@ def cell_run(events, steps, loads):
     r = {"trace": trace, "step_s": [0.5] * steps, "steps": 40, "tokens": 40 * 16384,
          "window_s": 20.0, "chips": 1, "seq": 8192, "dims": cell_dims(), "peaks": PEAKS,
          "expert_load": loads}
-    return dict(r, train_flops_per_token=afmoe_work.window_flops_per_token(r))   # as the adapter
+    return dict(r, train_flops_per_token=afmoe_work.window_flops_per_token(r),   # as the adapter
+                expert_matmul_work=hybrid_work.window_expert_matmul_work(
+                    r, view=afmoe_work.expert_view))
 
 
 def read(name, r):
@@ -230,17 +231,25 @@ def test_new_readers_on_names_as_the_chip_spells_them():
     r = cell_run(events, steps=2, loads=loads)
     assert read("flash_win_fwd_ms", r) == pytest.approx(24.0)
     assert read("flash_win_bwd_ms", r) == pytest.approx(36.0)
-    assert read("moe_gmm_ms.afmoe", r) == pytest.approx(10.0)
+    assert read("moe_gmm_ms", r) == pytest.approx(10.0)
     # four banded layers x 16,384 tokens x 4 x 4,096 x 1,792.125 keys = 1.924 TFLOP: 9.768 ms
     # at 197 TFLOP/s (their bytes take 1.5 ms); backward twice that
     assert read("flash_win_fwd_roofline_pct", r) == pytest.approx(100 * 9.768 / 24.0, rel=1e-3)
     assert read("flash_win_bwd_roofline_pct", r) == pytest.approx(100 * 19.536 / 36.0, rel=1e-3)
     n = loads[0].sum()
     ops, nbytes = afmoe_hand_expert_work(n)
-    assert read("moe_gmm_roofline_pct.afmoe", r) == pytest.approx(
+    assert read("moe_gmm_roofline_pct", r) == pytest.approx(
         100 * 1e3 * max(ops / 197e12, nbytes / 819e9) / 10.0)
-    assert read("moe_load_max_over_mean.afmoe", r) == pytest.approx(1536 / 1056.0)
-    assert 0 < read("mfu_pct.afmoe", r) < 100
+    assert read("moe_load_max_over_mean", r) == pytest.approx(1536 / 1056.0)
+    # the whole step's share, by hand: required operations a token at the counted local
+    # assignments, times 40 steps of 16,384 tokens in 20 s, over the bf16 peak
+    assert read("mfu_pct", r) == pytest.approx(
+        100 * afmoe_work.train_flops_per_token(r["dims"], 8192, n / 16384) * 40 * 16384 / 20.0
+        / 197e12)
+    assert 0 < read("mfu_pct", r) < 100
+    # a run whose adapter hands no count, or no work, reads as nothing
+    bare = {k: v for k, v in r.items() if k not in ("train_flops_per_token", "expert_matmul_work")}
+    assert read("mfu_pct", bare) is None and read("moe_gmm_roofline_pct", bare) is None
     # the accepted flash shares list no cells: they read this cell through the
     # attention view, banded and unbanded kernels together against the
     # required work of 2.75 full causal layers (9.768 + 5.582 = 15.35 ms forward)
@@ -248,8 +257,8 @@ def test_new_readers_on_names_as_the_chip_spells_them():
     assert read("flash_bwd_ms", r) == pytest.approx(51.0)
     assert read("flash_fwd_roofline_pct", r) == pytest.approx(100 * 15.350 / 36.0, rel=1e-3)
     assert read("flash_bwd_roofline_pct", r) == pytest.approx(100 * 30.700 / 51.0, rel=1e-3)
-    for name in NEW_METRICS + ("flash_fwd_roofline_pct", "flash_bwd_roofline_pct"):
-        if name.endswith("_pct") or name.startswith("mfu"):
+    for name in NEW_METRICS + ("mfu_pct", "flash_fwd_roofline_pct", "flash_bwd_roofline_pct"):
+        if name.endswith("_pct"):
             assert 0 <= read(name, r) <= 100, name   # a share over 100 % is a miscount
 
 
@@ -277,8 +286,9 @@ def test_new_readers_find_nothing_in_a_program_that_lacks_the_names():
     sc1b = gpt_ref.dims(run.load_json(os.path.join(HERE, "configs", "starcoderbase-1b-train1.json")))
     r = cell_run([(0, 5, FULL_FWD), (5, 9, FUSION)], steps=1, loads=None)
     r = {k: v for k, v in dict(r, dims=sc1b).items() if k != "expert_load"}
-    assert [read(name, r) for name in NEW_METRICS] == [None] * len(NEW_METRICS)
-    assert [read(name, dict(r, trace=None)) for name in NEW_METRICS] == [None] * len(NEW_METRICS)
+    names = NEW_METRICS + SHARED_METRICS
+    assert [read(name, r) for name in names] == [None] * len(names)
+    assert [read(name, dict(r, trace=None)) for name in names] == [None] * len(names)
 
 
 def test_required_work_by_hand():
@@ -301,22 +311,18 @@ def test_required_work_by_hand():
     assert afmoe_work.expert_view(d)["num_hidden_layers"] == 4
 
 
+def check_manifest(m):
+    """The cell's entries as members of the manifest's lists (``test_harness.check_cell``),
+    and what is this cell's alone."""
+    _, config, _, reported = test_harness.check_cell(
+        m, CELL, "trinity-mini-train1", NEW_METRICS + SHARED_METRICS)
+    assert "gdn_fwd_ms" not in reported
+    return config
+
+
+
 def test_manifest_holds_the_new_cell_and_its_metrics():
-    m = run.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    cell, config = run.find_cell(m, CELL)
-    assert (cell["chips"], cell["traffic"], cell["config"]) == (1, "packed-code-8k",
-                                                               "trinity-mini-train1")
-    reported = {p["name"] for p in run.metrics_of(m, "per_layer", cell)}
-    assert set(NEW_METRICS) <= reported and not {"mfu_pct", "mfu_pct.hybrid", "gdn_fwd_ms",
-                                                 "moe_gmm_ms"} & reported
-    assert {"flash_fwd_roofline_pct", "flash_bwd_roofline_pct", "flash_fwd_ms", "flash_bwd_ms",
-            "step_ms.train", "device_idle_pct.train", "peak_hbm_gb.train",
-            "xentropy_ms"} <= reported
-    listed = {p["name"]: p for p in m["per_layer"]}
-    for name in NEW_METRICS:
-        assert listed[name]["workloads"] == [CELL]
-    # nothing the benchmark had lists the new cell
-    assert listed["mfu_pct.hybrid"]["workloads"] == ["q3next-train-8k"]
+    config = check_manifest(run.load_json(os.path.join(ROOT, "BENCHMARK.json")))
     published = {"num_hidden_layers": 32, "num_dense_layers": 2, "num_experts": 128,
                  "vocab_size": 200192}
     assert config["published"] == published and config["reduced"] == list(published)

@@ -23,19 +23,18 @@ if ROOT not in sys.path:
 from benchmarks import bailing_work, hybrid_work, mla_work, run, trace_reduce as tr  # noqa: E402
 from benchmarks.adapters import bailing_tree, train_o2_bailing  # noqa: E402
 from benchmarks.reference import bailing_ref  # noqa: E402
-from benchmarks.tests import toy  # noqa: E402
+from benchmarks.tests import test_harness, toy  # noqa: E402
 from benchmarks.tests.test_trace_reduce import plane  # noqa: E402
 
 HERE = os.path.join(ROOT, "benchmarks")
 PEAKS = run.load_json(os.path.join(HERE, "peaks.json"))["TPU v5 lite"]
 CELL, CONFIG = "ling3-train-8k", "ling-3.0-flash-train1"
-NEW_METRICS = ("mfu_pct.bailing", "kda_fwd_ms", "kda_bwd_ms", "kda_fwd_roofline_pct",
-               "kda_bwd_roofline_pct", "kda_block_ms", "kda_outside_kernels_ms",
-               "attn_block_ms.bailing", "mlp_block_ms.bailing", "moe_block_ms.bailing",
-               "moe_route_ms.bailing", "moe_gmm_ms.bailing", "moe_gmm_roofline_pct.bailing",
-               "moe_load_max_over_mean.bailing", "unembed_xent_ms.bailing",
-               "optimizer_ms.bailing", "recompute_ms.bailing", "unscoped_ms.bailing",
-               "route_group_hit_share")
+NEW_METRICS = ("kda_fwd_ms", "kda_bwd_ms", "kda_fwd_roofline_pct", "kda_bwd_roofline_pct",
+               "kda_block_ms", "kda_outside_kernels_ms", "route_group_hit_share")
+# what the cell reports under names it shares with other cells: their lists hold it
+SHARED_METRICS = ("mfu_pct", "attn_block_ms", "mlp_block_ms", "moe_block_ms", "moe_route_ms",
+                  "moe_gmm_ms", "moe_gmm_roofline_pct", "moe_load_max_over_mean",
+                  "unembed_xent_ms", "optimizer_ms", "recompute_ms", "unscoped_ms")
 # the cell's cut at a toy size: published layers 1 (delta rule, dense) and 5 (latent,
 # experts), heads of 16 (the XLA forms), 16 experts in 4 groups of which 2 stay, group 1 held
 TOY_BAILING = {
@@ -63,9 +62,8 @@ def manifest():
     m["workloads"] = [{"name": "toy-bailing-cell", "config": "toy-bailing",
                        "traffic": "toy-docs", "chips": 1}]
     m["per_layer"] += [{"name": n, "unit": "x", "moves": "train_tokens_per_s"}
-                       for n in ("moe_load_max_over_mean.bailing", "mfu_pct.bailing",
-                                 "route_group_hit_share", "kda_fwd_ms", "moe_gmm_ms.bailing")]
-    m["per_layer"] = [p for p in m["per_layer"] if p["name"] != "mfu_pct"]
+                       for n in ("moe_load_max_over_mean", "route_group_hit_share", "kda_fwd_ms",
+                                 "moe_gmm_ms")]
     return m
 
 
@@ -97,11 +95,11 @@ def test_traced_rehearsal_is_correct_and_the_float8_control_is_not(here, monkeyp
     line = run.execute(m, m["workloads"][0], TOY_BAILING, toy.args(seed=2**31 + 11, trace=1),
                        jax.devices()[:1], PEAKS, here=here)
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] >= 2
-    assert 1.0 <= line["metrics"]["moe_load_max_over_mean.bailing"]["value"] <= 4.0
-    assert 0.0 < line["metrics"]["mfu_pct.bailing"]["value"] < 100.0
+    assert 1.0 <= line["metrics"]["moe_load_max_over_mean"]["value"] <= 4.0
+    assert 0.0 < line["metrics"]["mfu_pct"]["value"] < 100.0
     # the held experts are one group of four, of which a token keeps two
     assert 10.0 < line["metrics"]["route_group_hit_share"]["value"] < 90.0
-    assert not {"kda_fwd_ms", "moe_gmm_ms.bailing"} & set(line["metrics"])   # no device in a CPU trace
+    assert not {"kda_fwd_ms", "moe_gmm_ms"} & set(line["metrics"])   # no device in a CPU trace
     checked = [r.split()[1] for r in rows if r.startswith("check:") and "limit" in r]
     assert {"dropped_assignments", "held_load_gap", "compilations_inside_window",
             "first_gradient_projection_gap"} <= set(checked)
@@ -186,7 +184,9 @@ def cell_run(events, steps, loads, table=None):
          "expert_load": loads, "router_group_hit": np.full((32, 5), 0.5)}
     if table is not None:
         r["scope_table"] = table
-    return dict(r, train_flops_per_token=bailing_work.window_flops_per_token(r))   # as the adapter
+    return dict(r, train_flops_per_token=bailing_work.window_flops_per_token(r),   # as the adapter
+                expert_matmul_work=hybrid_work.window_expert_matmul_work(
+                    r, view=mla_work.expert_view))
 
 
 def read(name, r):
@@ -228,40 +228,41 @@ def test_new_readers_on_names_as_the_chip_spells_them():
     ops_b, bytes_b = bailing_work.rule_work(r["dims"], tokens, backward=True)
     assert read("kda_bwd_roofline_pct", r) == pytest.approx(
         100 * 1e3 * max(ops_b / 197e12, bytes_b / 819e9) / 125.0)
-    assert read("moe_gmm_ms.bailing", r) == pytest.approx(5.0)
+    assert read("moe_gmm_ms", r) == pytest.approx(5.0)
     n = loads[0].sum()
     want = hybrid_work.expert_matmul_work(dict(r["dims"], num_hidden_layers=5), n, passes=3)
     assert want[0] == 3 * 6 * 2560 * 768 * n
-    assert read("moe_gmm_roofline_pct.bailing", r) == pytest.approx(
+    assert read("moe_gmm_roofline_pct", r) == pytest.approx(
         100 * 1e3 * max(want[0] / 197e12, want[1] / 819e9) / 5.0)
-    assert read("moe_load_max_over_mean.bailing", r) == pytest.approx(384 / 272.0)
+    assert read("moe_load_max_over_mean", r) == pytest.approx(384 / 272.0)
     assert read("route_group_hit_share", r) == pytest.approx(50.0)
-    assert read("mfu_pct.bailing", r) == pytest.approx(
+    assert read("mfu_pct", r) == pytest.approx(
         100 * bailing_work.train_flops_per_token(r["dims"], 8192, n / tokens) * 32 * tokens / 26.0
         / 197e12)
-    assert 30 < read("mfu_pct.bailing", r) < 40
+    assert 30 < read("mfu_pct", r) < 40
     # everything traced under hybrid/kda, forward, backward and recomputed: both rule
     # kernels, the convolution, the projection's fusion and its second run
     assert read("kda_block_ms", r) == pytest.approx(40.0 + 125.0 + 5.0 + 50.0 + 3.0)
     assert read("kda_outside_kernels_ms", r) == pytest.approx(53.0)
-    assert read("attn_block_ms.bailing", r) == pytest.approx(20.0)
-    assert read("mlp_block_ms.bailing", r) == pytest.approx(6.0)
-    assert read("moe_block_ms.bailing", r) == pytest.approx(5.0 + 4.0)
-    assert read("moe_route_ms.bailing", r) == pytest.approx(4.0)
-    assert read("unembed_xent_ms.bailing", r) == pytest.approx(3.0)
-    assert read("optimizer_ms.bailing", r) == pytest.approx(2.0)
-    assert read("recompute_ms.bailing", r) == pytest.approx(3.0)
-    assert read("unscoped_ms.bailing", r) == pytest.approx(1.0)
-    for twin in ("moe_block_ms", "moe_route_ms", "unembed_xent_ms", "optimizer_ms"):
-        assert read(twin + ".bailing", r) == read(twin, r)      # what the accepted reader reads
+    assert read("attn_block_ms", r) == pytest.approx(20.0)
+    assert read("mlp_block_ms", r) == pytest.approx(6.0)
+    assert read("moe_block_ms", r) == pytest.approx(5.0 + 4.0)
+    assert read("moe_route_ms", r) == pytest.approx(4.0)
+    assert read("unembed_xent_ms", r) == pytest.approx(3.0)
+    assert read("optimizer_ms", r) == pytest.approx(2.0)
+    assert read("recompute_ms", r) == pytest.approx(3.0)
+    assert read("unscoped_ms", r) == pytest.approx(1.0)
     # the accepted flash times and shares list no cells: they read this cell's ONE latent
     # layer through the attention view, at 640 operations a score pair and head
     assert read("flash_fwd_ms", r) == pytest.approx(20.0)
     want = 16384 * 4 * 32 * 160 * 4096.5 / 197e12 * 1e3
     assert read("flash_fwd_roofline_pct", r) == pytest.approx(100 * want / 20.0, rel=1e-3)
-    for name in NEW_METRICS + ("flash_fwd_roofline_pct",):
-        if name.endswith("_pct") or name.startswith("mfu") or "_pct." in name:
+    for name in NEW_METRICS + SHARED_METRICS + ("flash_fwd_roofline_pct",):
+        if name.endswith("_pct"):
             assert 0 <= read(name, r) <= 100, name   # a share over 100 % is a miscount
+    # a run whose adapter hands no count, or no work, reads as nothing
+    bare = {k: v for k, v in r.items() if k not in ("train_flops_per_token", "expert_matmul_work")}
+    assert read("mfu_pct", bare) is None and read("moe_gmm_roofline_pct", bare) is None
     # the other blocks' readers find nothing here
     for name in ("flash_win_fwd_ms", "gdn_fwd_ms", "ssd_fwd_ms", "flash_bwd_ms"):
         assert read(name, r) is None
@@ -294,8 +295,9 @@ def test_new_readers_find_nothing_in_a_program_that_lacks_the_names():
     r = cell_run([(0, 5, FLASH), (5, 9, FUSION)], steps=1, loads=None, table={})
     r = {k: v for k, v in dict(r, dims=sc1b).items()
          if k not in ("expert_load", "router_group_hit", "train_flops_per_token")}
-    assert [read(name, r) for name in NEW_METRICS] == [None] * len(NEW_METRICS)
-    assert [read(name, dict(r, trace=None)) for name in NEW_METRICS] == [None] * len(NEW_METRICS)
+    names = NEW_METRICS + SHARED_METRICS
+    assert [read(name, r) for name in names] == [None] * len(names)
+    assert [read(name, dict(r, trace=None)) for name in names] == [None] * len(names)
 
 
 def test_required_work_by_hand():
@@ -341,55 +343,25 @@ def test_required_work_by_hand():
     assert p["layers"]["norm1"].shape == p["layers"]["norm2"].shape == (6, 2560)
 
 
+def check_manifest(m):
+    """The cell's entries as members of the manifest's lists (``test_harness.check_cell``),
+    and what is this cell's alone."""
+    cell, config, entry, reported = test_harness.check_cell(
+        m, CELL, CONFIG, NEW_METRICS + SHARED_METRICS)
+    assert "1/64" in cell["why"] and "group-limited" in cell["why"]
+    assert not {"gdn_fwd_ms", "ssd_fwd_ms", "flash_win_fwd_ms", "moe_rows_ms",
+                "exit_gate_ms"} & reported
+    return config, entry
+
+
+
 def test_the_cells_entries_are_members_of_the_manifest_and_keep_to_the_contract():
-    """The cell, its configuration and its metrics are IN their lists, after
-    the seven accepted cells' entries and in their own order; what comes after
-    them is a later PR's and is not looked at."""
-    from benchmarks.tests.test_harness import NAME
-    m = run.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    cells = [w["name"] for w in m["workloads"]]
-    assert cells[:7] == ["sc1b-train-8k", "gpt2m-train-1k-dp4", "q3next-train-8k",
-                         "trinity-train-8k", "dsv2lite-train-8k", "nemotron3-train-8k",
-                         "ouro-train-8k"]
-    assert cells.index(CELL) >= 7 and [c["name"] for c in m["configs"]].index(CONFIG) >= 7
-    names = [p["name"] for p in m["per_layer"]]
-    at = names.index(NEW_METRICS[0])
-    assert tuple(names[at:at + len(NEW_METRICS)]) == NEW_METRICS
-    assert all(CELL not in p.get("workloads", ()) for p in m["per_layer"][:at])
-    assert (m["run_seconds"], [e["bound"] for e in m["end_to_end"]]) == (20, [0.01, 0.1])
-    assert len(json.dumps(m, indent=1)) < 64 * 1024
-    entry = next(c for c in m["configs"] if c["name"] == CONFIG)
-    cell = next(w for w in m["workloads"] if w["name"] == CELL)
-    assert set(entry) == {"name", "source", "file", "reduced", "why"}
-    assert set(cell) == {"name", "config", "traffic", "chips", "why"}
-    assert len(entry["why"]) <= 200 and len(cell["why"]) <= 200
-    for e in m["per_layer"][at:at + len(NEW_METRICS)]:
-        assert set(e) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
-        assert e["workloads"] == [CELL]
-        reader = run.load_reader(e["name"])
-        assert (reader.LAYER, reader.UNIT, reader.MOVES) == (e["layer"], e["unit"], e["moves"])
-    every = [x["name"] for k in ("configs", "workloads", "end_to_end", "per_layer") for x in m[k]]
-    assert all(NAME.match(n) for n in every) and len(set(every)) == len(every)
-    assert sum(w["chips"] == 4 for w in m["workloads"]) <= max(1, len(m["workloads"]) // 4)
-    n = len(m["workloads"])
-    assert (2 + 14 * n) * (m["run_seconds"] + 60) + 2 * 90 * n + 1200 <= 43200
+    check_manifest(run.load_json(os.path.join(ROOT, "BENCHMARK.json")))
 
 
 def test_manifest_holds_the_new_cell_and_its_configuration():
-    m = run.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    cell, config = run.find_cell(m, CELL)
-    assert (cell["chips"], cell["traffic"], cell["config"]) == (1, "packed-code-8k", CONFIG)
-    assert "1/64" in cell["why"] and "group-limited" in cell["why"]
-    reported = {p["name"] for p in run.metrics_of(m, "per_layer", cell)}
-    assert set(NEW_METRICS) <= reported and not {
-        "mfu_pct", "mfu_pct.hybrid", "mfu_pct.mla", "gdn_fwd_ms", "moe_gmm_ms", "moe_gmm_ms.mla",
-        "ssd_fwd_ms", "moe_rows_ms", "attn_block_ms"} & reported
-    assert {"flash_fwd_roofline_pct", "flash_bwd_roofline_pct", "flash_fwd_ms", "flash_bwd_ms",
-            "step_ms.train", "device_idle_pct.train", "peak_hbm_gb.train",
-            "xentropy_ms"} <= reported
-    assert {e["name"] for e in run.metrics_of(m, "end_to_end", cell)} == {
-        "train_tokens_per_s", "setup_s"}
-    entry = next(c for c in m["configs"] if c["name"] == CONFIG)
+    """The configuration behind the cell's entries, on file as the entry says."""
+    config, entry = check_manifest(run.load_json(os.path.join(ROOT, "BENCHMARK.json")))
     published = {"num_hidden_layers": 42, "num_experts": 512, "vocab_size": 157184}
     assert config["published"] == published and config["reduced"] == list(published)
     assert entry["reduced"] == list(published)

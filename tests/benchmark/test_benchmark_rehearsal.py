@@ -10,6 +10,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmarks.tests.test_harness import (  # noqa: E402,F401
+    test_every_cell_reports_the_names_it_reported_before_the_fold,
     test_manifest_keeps_to_the_contract,
     test_new_files_and_entries_alone_add_a_cell,
 )

@@ -19,14 +19,16 @@ if ROOT not in sys.path:
 from benchmarks import hybrid_work, run, trace_reduce as tr  # noqa: E402
 from benchmarks.adapters import train_o2_hybrid  # noqa: E402
 from benchmarks.reference import hybrid_ref  # noqa: E402
-from benchmarks.tests import toy  # noqa: E402
+from benchmarks.tests import test_harness, toy  # noqa: E402
 from benchmarks.tests.test_trace_reduce import plane  # noqa: E402
 
 HERE = os.path.join(ROOT, "benchmarks")
 PEAKS = run.load_json(os.path.join(HERE, "peaks.json"))["TPU v5 lite"]
 CELL = "q3next-train-8k"
 NEW_METRICS = ("gdn_fwd_ms", "gdn_bwd_ms", "gdn_fwd_roofline_pct", "gdn_bwd_roofline_pct",
-               "moe_gmm_ms", "moe_gmm_roofline_pct", "moe_load_max_over_mean", "mfu_pct.hybrid")
+               "moe_gmm_ms", "moe_gmm_roofline_pct", "moe_load_max_over_mean")
+# what the cell reports under names it shares with other cells: their lists hold it
+SHARED_METRICS = ("mfu_pct",)
 # two periods of (linear, full), 16 experts top-4 with a share of 8 held
 TOY_HYBRID = {
     "name": "toy-hybrid", "adapter": "train_o2_hybrid",
@@ -51,8 +53,7 @@ def manifest():
     m["workloads"] = [{"name": "toy-hybrid-cell", "config": "toy-hybrid",
                        "traffic": "toy-docs", "chips": 1}]
     m["per_layer"] += [{"name": n, "unit": "x", "moves": "train_tokens_per_s"}
-                       for n in ("moe_load_max_over_mean", "mfu_pct.hybrid", "gdn_fwd_ms")]
-    m["per_layer"] = [p for p in m["per_layer"] if p["name"] != "mfu_pct"]
+                       for n in ("moe_load_max_over_mean", "gdn_fwd_ms")]
     return m
 
 
@@ -71,7 +72,7 @@ def test_traced_rehearsal_is_correct_and_hands_back_the_counters(here):
                        jax.devices()[:1], PEAKS, here=here)
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] >= 2
     assert 1.0 <= line["metrics"]["moe_load_max_over_mean"]["value"] < 3.0
-    assert 0.0 < line["metrics"]["mfu_pct.hybrid"]["value"] < 100.0
+    assert 0.0 < line["metrics"]["mfu_pct"]["value"] < 100.0
     assert "gdn_fwd_ms" not in line["metrics"]          # no device in a CPU trace
     json.dumps(line)
 
@@ -137,7 +138,8 @@ def cell_run(events, steps, loads):
     r = {"trace": trace, "step_s": [0.6] * steps, "steps": 30, "tokens": 30 * 16384,
          "window_s": 18.0, "chips": 1, "seq": 8192, "dims": cell_dims(), "peaks": PEAKS,
          "expert_load": loads}
-    return dict(r, train_flops_per_token=hybrid_work.window_flops_per_token(r))   # as the adapter
+    return dict(r, train_flops_per_token=hybrid_work.window_flops_per_token(r),   # as the adapter
+                expert_matmul_work=hybrid_work.window_expert_matmul_work(r))
 
 
 def read(name, r):
@@ -168,12 +170,20 @@ def test_new_readers_on_names_as_the_chip_spells_them():
     assert read("moe_gmm_roofline_pct", r) == pytest.approx(
         100 * 1e3 * max(ops / 197e12, nbytes / 819e9) / 6.0)
     assert read("moe_load_max_over_mean", r) == pytest.approx(480 / 325.0)
-    assert 0 < read("mfu_pct.hybrid", r) < 100
+    # the whole step's share, by hand: required operations a token at the counted 2.54 local
+    # assignments a token, times 30 steps of 16,384 tokens in 18 s, over the bf16 peak
+    assert read("mfu_pct", r) == pytest.approx(
+        100 * hybrid_work.train_flops_per_token(r["dims"], 8192, n / 16384) * 30 * 16384 / 18.0
+        / 197e12)
+    assert 0 < read("mfu_pct", r) < 100
+    # a run whose adapter hands no count, or no work, reads as nothing
+    bare = {k: v for k, v in r.items() if k not in ("train_flops_per_token", "expert_matmul_work")}
+    assert read("mfu_pct", bare) is None and read("moe_gmm_roofline_pct", bare) is None
     # the accepted flash shares list no cells: they read this cell through the
     # attention layers' view of its dims (one layer of 16 heads of 256, 2 kv heads)
     assert read("flash_fwd_ms", r) == pytest.approx(10.0)
     assert read("flash_fwd_roofline_pct", r) == pytest.approx(100 * 5.582 / 10.0, rel=1e-3)
-    for name in NEW_METRICS + ("flash_fwd_roofline_pct",):
+    for name in NEW_METRICS + SHARED_METRICS + ("flash_fwd_roofline_pct",):
         if name.endswith("_pct") or name.startswith("mfu"):
             assert 0 <= read(name, r) <= 100, name   # a share over 100 % is a miscount
 
@@ -185,8 +195,9 @@ def test_new_readers_find_nothing_in_a_program_that_lacks_the_names():
     sc1b = gpt_ref.dims(run.load_json(os.path.join(HERE, "configs", "starcoderbase-1b-train1.json")))
     r = cell_run([(0, 5, FLASH), (5, 9, FUSION)], steps=1, loads=None)
     r = {k: v for k, v in dict(r, dims=sc1b).items() if k != "expert_load"}
-    assert [read(name, r) for name in NEW_METRICS] == [None] * len(NEW_METRICS)
-    assert [read(name, dict(r, trace=None)) for name in NEW_METRICS] == [None] * len(NEW_METRICS)
+    names = NEW_METRICS + SHARED_METRICS
+    assert [read(name, r) for name in names] == [None] * len(names)
+    assert [read(name, dict(r, trace=None)) for name in names] == [None] * len(names)
 
 
 def test_required_work_by_hand():
@@ -208,19 +219,18 @@ def test_required_work_by_hand():
     assert nbytes == 3 * 4 * 32 * 3 * 2048 * 512 * 2 + 3 * 40960 * 2 * 2048 * 2
 
 
+def check_manifest(m):
+    """The cell's entries as members of the manifest's lists (``test_harness.check_cell``),
+    and what is this cell's alone."""
+    _, config, _, reported = test_harness.check_cell(
+        m, CELL, "qwen3-next-80b-a3b-train1", NEW_METRICS + SHARED_METRICS)
+    assert "flash_win_fwd_ms" not in reported
+    return config
+
+
+
 def test_manifest_holds_the_new_cell_and_its_metrics():
-    m = run.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    cell, config = run.find_cell(m, CELL)
-    assert (cell["chips"], cell["traffic"], cell["config"]) == (1, "packed-code-8k",
-                                                               "qwen3-next-80b-a3b-train1")
-    reported = {p["name"] for p in run.metrics_of(m, "per_layer", cell)}
-    assert set(NEW_METRICS) <= reported and "mfu_pct" not in reported
-    assert {"flash_fwd_roofline_pct", "flash_bwd_roofline_pct", "step_ms.train",
-            "device_idle_pct.train", "xentropy_ms"} <= reported
-    listed = {p["name"]: p for p in m["per_layer"]}
-    for name in NEW_METRICS:
-        assert listed[name]["workloads"] == [CELL]
-    assert listed["mfu_pct"]["workloads"] == ["sc1b-train-8k", "gpt2m-train-1k-dp4"]
+    config = check_manifest(run.load_json(os.path.join(ROOT, "BENCHMARK.json")))
     published = {"num_hidden_layers": 48, "num_experts": 512, "vocab_size": 151936}
     assert config["published"] == published and config["reduced"] == list(published)
     assert (config["num_hidden_layers"], config["num_experts"], config["vocab_size"]) == (4, 32, 18992)

@@ -14,5 +14,7 @@ from benchmarks.tests.test_kernel_metrics import (  # noqa: E402,F401
     test_readers_on_names_as_the_chip_spells_them,
     test_recorded_named_trace_from_the_chip,
     test_required_work_by_hand,
-    test_scope_times_by_hand,
+)
+from benchmarks.tests.test_trace_reduce import (  # noqa: E402,F401
+    test_an_idle_gap_is_named_by_the_innermost_host_event,
 )

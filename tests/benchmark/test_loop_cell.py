@@ -4,8 +4,8 @@ hands back the exits' readings; the float8 control fails the comparison), a
 program whose stack cannot loop refuses the cell at once, the new readers on
 a hand-made trace spelt as the chip spells it (the walks counted twice read a
 share over 100 %, which fails here), the required work by hand, and the
-cell's entries of ``BENCHMARK.json`` (after every accepted entry, nothing
-before them touched)."""
+cell's entries of ``BENCHMARK.json`` as MEMBERS of their lists: what a later
+PR puts after them, or adds to a list that holds this cell, breaks nothing here."""
 import importlib
 import json
 import os
@@ -23,17 +23,16 @@ if ROOT not in sys.path:
 from benchmarks import kernel_work, loop_work, run, trace_reduce as tr  # noqa: E402
 from benchmarks.adapters import loop_tree, train_o2_loop  # noqa: E402
 from benchmarks.reference import loop_ref  # noqa: E402
-from benchmarks.tests import toy  # noqa: E402
+from benchmarks.tests import test_harness, toy  # noqa: E402
 from benchmarks.tests.test_trace_reduce import plane  # noqa: E402
 
 HERE = os.path.join(ROOT, "benchmarks")
 PEAKS = run.load_json(os.path.join(HERE, "peaks.json"))["TPU v5 lite"]
 CELL, CONFIG = "ouro-train-8k", "ouro-2.6b-train1"
-ACCEPTED_CELLS = ["sc1b-train-8k", "gpt2m-train-1k-dp4", "q3next-train-8k", "trinity-train-8k",
-                  "dsv2lite-train-8k", "nemotron3-train-8k"]
-NEW_METRICS = ("mfu_pct.loop", "attn_block_ms.loop", "attn_outside_kernels_ms.loop",
-               "mlp_block_ms.loop", "unembed_xent_ms.loop", "optimizer_ms.loop",
-               "recompute_ms.loop", "unscoped_ms.loop", "exit_gate_ms", "exit_mass_last")
+NEW_METRICS = ("exit_gate_ms", "exit_mass_last")
+# what the cell reports under names it shares with other cells: their lists hold it
+SHARED_METRICS = ("mfu_pct", "attn_block_ms", "attn_outside_kernels_ms", "mlp_block_ms",
+                  "unembed_xent_ms", "optimizer_ms", "recompute_ms", "unscoped_ms")
 # the cell's block at a toy size: four heads of 16, a SwiGLU of 2.75 x the
 # hidden size, four walks of two layers
 TOY_LOOP = {
@@ -57,9 +56,7 @@ def manifest():
     m["workloads"] = [{"name": "toy-loop-cell", "config": "toy-loop",
                        "traffic": "toy-docs", "chips": 1}]
     m["per_layer"] += [{"name": n, "unit": "x", "moves": "train_tokens_per_s"}
-                       for n in ("mfu_pct.loop", "exit_mass_last", "exit_gate_ms",
-                                 "attn_block_ms.loop")]
-    m["per_layer"] = [p for p in m["per_layer"] if p["name"] != "mfu_pct"]
+                       for n in ("exit_mass_last", "exit_gate_ms", "attn_block_ms")]
     return m
 
 
@@ -80,8 +77,8 @@ def test_traced_rehearsal_is_correct_and_hands_back_the_exits(here, monkeypatch)
                        jax.devices()[:1], PEAKS, here=here)
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] >= 2
     assert 0.0 < line["metrics"]["exit_mass_last"]["value"] < 1.0
-    assert 0.0 < line["metrics"]["mfu_pct.loop"]["value"] < 100.0
-    assert not {"exit_gate_ms", "attn_block_ms.loop"} & set(line["metrics"])   # a CPU trace
+    assert 0.0 < line["metrics"]["mfu_pct"]["value"] < 100.0
+    assert not {"exit_gate_ms", "attn_block_ms"} & set(line["metrics"])   # a CPU trace
     checked = [r.split()[1].split("@")[0] for r in rows if r.startswith("check:") and "limit" in r]
     assert {"exit_losses_gap", "exit_mass_gap", "compilations_inside_window",
             "first_gradient_projection_gap", "moved_norm_gap",
@@ -210,22 +207,20 @@ def test_new_readers_on_names_as_the_chip_spells_them():
     mass = np.tile([0.4, 0.3, 0.2, 0.1], (8, 1))
     mass[:, -1] += np.linspace(0, 0.07, 8)
     r = cell_run(events, steps=2, mass=mass, table=table)
-    assert read("attn_block_ms.loop", r) == pytest.approx(100.0 + 200.0 + 50.0 + 30.0)
-    assert read("attn_outside_kernels_ms.loop", r) == pytest.approx(50.0 + 30.0)
-    assert read("mlp_block_ms.loop", r) == pytest.approx(120.0)
-    assert read("unembed_xent_ms.loop", r) == pytest.approx(50.0 + 10.0)
+    assert read("attn_block_ms", r) == pytest.approx(100.0 + 200.0 + 50.0 + 30.0)
+    assert read("attn_outside_kernels_ms", r) == pytest.approx(50.0 + 30.0)
+    assert read("mlp_block_ms", r) == pytest.approx(120.0)
+    assert read("unembed_xent_ms", r) == pytest.approx(50.0 + 10.0)
     assert read("exit_gate_ms", r) == pytest.approx(2.0)
-    assert read("optimizer_ms.loop", r) == pytest.approx(13.0)
-    assert read("recompute_ms.loop", r) == pytest.approx(30.0)
-    assert read("unscoped_ms.loop", r) == pytest.approx(5.0)
+    assert read("optimizer_ms", r) == pytest.approx(13.0)
+    assert read("recompute_ms", r) == pytest.approx(30.0)
+    assert read("unscoped_ms", r) == pytest.approx(5.0)
     assert read("exit_mass_last", r) == pytest.approx(0.135)
-    assert read("mfu_pct.loop", r) == pytest.approx(
+    assert read("mfu_pct", r) == pytest.approx(
         100 * loop_work.train_flops_per_token(r["dims"], 8192) * 8 * 16384 / 19.2 / 197e12)
-    assert 50 < read("mfu_pct.loop", r) < 60
-    # the twins read what the accepted readers read on the spans the cell shares
-    for twin in ("attn_block_ms", "attn_outside_kernels_ms", "mlp_block_ms", "unembed_xent_ms",
-                 "optimizer_ms", "recompute_ms", "unscoped_ms"):
-        assert read(twin + ".loop", r) == read(twin, r)
+    assert 50 < read("mfu_pct", r) < 60
+    # a run whose adapter hands no count reads as nothing
+    assert read("mfu_pct", {k: v for k, v in r.items() if k != "train_flops_per_token"}) is None
     # the accepted flash times and shares list no cells: they read this cell's
     # 8 layers x 4 walks of flash calls through the attention view
     assert read("flash_fwd_ms", r) == pytest.approx(100.0)
@@ -234,11 +229,11 @@ def test_new_readers_on_names_as_the_chip_spells_them():
     want = 32 * 16384 * 4 * 16 * 128 * 4096.5 / 197e12 * 1e3
     assert read("flash_fwd_roofline_pct", r) == pytest.approx(100 * want / 100.0, rel=1e-3)
     assert read("flash_bwd_roofline_pct", r) == pytest.approx(100 * 2 * want / 200.0, rel=1e-3)
-    for name in ("mfu_pct.loop", "flash_fwd_roofline_pct", "flash_bwd_roofline_pct"):
+    for name in ("mfu_pct", "flash_fwd_roofline_pct", "flash_bwd_roofline_pct"):
         assert 0 <= read(name, r) <= 100, name       # a share over 100 % is a miscount
     # the other blocks' readers find nothing here
-    for name in ("flash_win_fwd_ms", "gdn_fwd_ms", "ssd_fwd_ms", "moe_gmm_ms.ssm",
-                 "moe_load_max_over_mean.ssm"):
+    for name in ("flash_win_fwd_ms", "gdn_fwd_ms", "ssd_fwd_ms", "moe_gmm_ms",
+                 "moe_gmm_roofline_pct", "moe_load_max_over_mean"):
         assert read(name, r) is None
 
 
@@ -266,8 +261,8 @@ def test_the_walks_are_counted_once_in_the_attention_view_and_nowhere_else():
     need = loop_work.train_flops_per_token(d, 8192)
     at_peak = dict(r, tokens=16384, window_s=16384 * need / 197e12 * 1.02,
                    train_flops_per_token=need)
-    assert 95 < read("mfu_pct.loop", at_peak) < 100
-    assert read("mfu_pct.loop", dict(at_peak, train_flops_per_token=T * need)) > 100
+    assert 95 < read("mfu_pct", at_peak) < 100
+    assert read("mfu_pct", dict(at_peak, train_flops_per_token=T * need)) > 100
 
 
 def test_new_readers_find_nothing_in_a_program_that_lacks_the_names():
@@ -278,8 +273,9 @@ def test_new_readers_find_nothing_in_a_program_that_lacks_the_names():
     sc1b = gpt_ref.dims(run.load_json(os.path.join(HERE, "configs", "starcoderbase-1b-train1.json")))
     r = cell_run([(0, 5, FLASH), (5, 9, FUSION)], steps=1, table={})
     r = {k: v for k, v in dict(r, dims=sc1b).items() if k != "train_flops_per_token"}
-    assert [read(name, r) for name in NEW_METRICS] == [None] * len(NEW_METRICS)
-    assert [read(name, dict(r, trace=None)) for name in NEW_METRICS] == [None] * len(NEW_METRICS)
+    names = NEW_METRICS + SHARED_METRICS
+    assert [read(name, r) for name in names] == [None] * len(names)
+    assert [read(name, dict(r, trace=None)) for name in names] == [None] * len(names)
 
 
 def test_required_work_by_hand():
@@ -310,60 +306,28 @@ def test_required_work_by_hand():
     assert p["layers"]["norm1_post"].shape == p["layers"]["norm2_post"].shape == (L, 2048)
 
 
+def check_manifest(m):
+    """The cell's entries as members of the manifest's lists (``test_harness.check_cell``),
+    and what is this cell's alone."""
+    cell, config, entry, reported = test_harness.check_cell(
+        m, CELL, CONFIG, NEW_METRICS + SHARED_METRICS)
+    assert "4 walks" in cell["why"]
+    assert next(p for p in m["per_layer"] if p["name"] == "exit_mass_last")["source"] == (
+        "program_counter")
+    assert not {"gdn_fwd_ms", "moe_gmm_ms", "flash_win_fwd_ms", "ssd_fwd_ms",
+                "moe_block_ms"} & reported
+    return config, entry
+
+
+
 def test_the_cell_comes_after_every_accepted_entry_and_keeps_to_the_contract():
-    """The cell's entries follow the six accepted cells' (whose entries are
-    the ones ``test_ssm_cell.py`` pins, in their order), as one block; a later
-    cell may follow them."""
-    from benchmarks.tests.test_harness import NAME
-    m = run.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    assert [w["name"] for w in m["workloads"]][:7] == ACCEPTED_CELLS + [CELL]
-    assert [c["name"] for c in m["configs"]][5:7] == ["nemotron-3-nano-30b-a3b-train1", CONFIG]
-    names = [p["name"] for p in m["per_layer"]]
-    first = names.index(NEW_METRICS[0])
-    assert names[first - 1] == "optimizer_ms.ssm"            # the last accepted entry
-    assert tuple(names[first:first + len(NEW_METRICS)]) == NEW_METRICS
-    assert all(CELL not in p.get("workloads", ()) for p in m["per_layer"][:first])
-    assert (m["run_seconds"], [e["bound"] for e in m["end_to_end"]]) == (20, [0.01, 0.1])
-    assert [e["name"] for e in m["end_to_end"]] == ["train_tokens_per_s", "setup_s"]
-    assert len(json.dumps(m, indent=1)) < 64 * 1024
-    assert set(m["configs"][6]) == {"name", "source", "file", "reduced", "why"}
-    assert set(m["workloads"][6]) == {"name", "config", "traffic", "chips", "why"}
-    for entry in m["per_layer"][first:first + len(NEW_METRICS)]:
-        assert set(entry) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
-        reader = run.load_reader(entry["name"])
-        assert (reader.LAYER, reader.UNIT, reader.MOVES) == (
-            entry["layer"], entry["unit"], entry["moves"])
-    every = [x["name"] for k in ("configs", "workloads", "end_to_end", "per_layer") for x in m[k]]
-    assert all(NAME.match(n) for n in every) and len(set(every)) == len(every)
-    assert sum(w["chips"] == 4 for w in m["workloads"]) <= max(1, len(m["workloads"]) // 4)
-    cells = len(m["workloads"])
-    assert (2 + 14 * cells) * (m["run_seconds"] + 60) + 2 * 90 * cells + 1200 <= 43200
+    """Entered once, a member ever after: ``check_manifest`` on the file as it is."""
+    check_manifest(run.load_json(os.path.join(ROOT, "BENCHMARK.json")))
 
 
 def test_manifest_holds_the_new_cell_and_its_metrics():
-    m = run.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    cell, config = run.find_cell(m, CELL)
-    assert (cell["chips"], cell["traffic"], cell["config"]) == (1, "packed-code-8k", CONFIG)
-    assert len(cell["why"]) <= 200 and "4 walks" in cell["why"]
-    reported = {p["name"] for p in run.metrics_of(m, "per_layer", cell)}
-    assert set(NEW_METRICS) <= reported and not {
-        "mfu_pct", "mfu_pct.hybrid", "mfu_pct.afmoe", "mfu_pct.mla", "mfu_pct.ssm",
-        "gdn_fwd_ms", "moe_gmm_ms", "flash_win_fwd_ms", "ssd_fwd_ms", "attn_block_ms",
-        "attn_block_ms.ssm", "mlp_block_ms", "recompute_ms", "unscoped_ms"} & reported
-    assert {"flash_fwd_roofline_pct", "flash_bwd_roofline_pct", "flash_fwd_ms", "flash_bwd_ms",
-            "step_ms.train", "device_idle_pct.train", "peak_hbm_gb.train",
-            "xentropy_ms"} <= reported
-    assert {e["name"] for e in run.metrics_of(m, "end_to_end", cell)} == {
-        "train_tokens_per_s", "setup_s"}
-    listed = {p["name"]: p for p in m["per_layer"]}
-    for name in NEW_METRICS:
-        assert listed[name]["workloads"] == [CELL]
-        assert os.path.exists(os.path.join(HERE, "layer_metrics", name + ".py"))
-    assert listed["exit_mass_last"]["source"] == "program_counter"
-    # nothing the benchmark had lists the new cell
-    assert listed["mfu_pct.ssm"]["workloads"] == ["nemotron3-train-8k"]
-    assert CELL not in listed["attn_block_ms"]["workloads"]
-    entry = next(c for c in m["configs"] if c["name"] == cell["config"])
+    """The configuration behind the cell's entries, on file as the entry says."""
+    config, entry = check_manifest(run.load_json(os.path.join(ROOT, "BENCHMARK.json")))
     assert config["published"] == {"num_hidden_layers": 48}
     assert config["reduced"] == entry["reduced"] == ["num_hidden_layers"]
     assert 4 <= config["num_hidden_layers"] <= 8

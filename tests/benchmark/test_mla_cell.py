@@ -17,17 +17,18 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmarks import mla_work, run, trace_reduce as tr  # noqa: E402
+from benchmarks import hybrid_work, mla_work, run, trace_reduce as tr  # noqa: E402
 from benchmarks.adapters import mla_tree, train_o2_mla  # noqa: E402
 from benchmarks.reference import mla_ref  # noqa: E402
-from benchmarks.tests import toy  # noqa: E402
+from benchmarks.tests import test_harness, toy  # noqa: E402
 from benchmarks.tests.test_trace_reduce import plane  # noqa: E402
 
 HERE = os.path.join(ROOT, "benchmarks")
 PEAKS = run.load_json(os.path.join(HERE, "peaks.json"))["TPU v5 lite"]
 CELL = "dsv2lite-train-8k"
-NEW_METRICS = ("mfu_pct.mla", "moe_gmm_ms.mla", "moe_gmm_roofline_pct.mla",
-               "moe_load_max_over_mean.mla")
+# the cell brought no reader of its own: what it reports stands under names it shares
+# with other cells, whose lists hold it
+SHARED_METRICS = ("mfu_pct", "moe_gmm_ms", "moe_gmm_roofline_pct", "moe_load_max_over_mean")
 # the cell's cut at a toy size: the leading dense layer and two expert layers,
 # 16 experts top-4 with a share of 4 held, a yarn ramp inside rows of 64
 TOY_MLA = {
@@ -59,8 +60,7 @@ def manifest():
     m["workloads"] = [{"name": "toy-mla-cell", "config": "toy-mla",
                        "traffic": "toy-docs", "chips": 1}]
     m["per_layer"] += [{"name": n, "unit": "x", "moves": "train_tokens_per_s"}
-                       for n in ("moe_load_max_over_mean.mla", "mfu_pct.mla", "moe_gmm_ms.mla")]
-    m["per_layer"] = [p for p in m["per_layer"] if p["name"] != "mfu_pct"]
+                       for n in ("moe_load_max_over_mean", "moe_gmm_ms")]
     return m
 
 
@@ -81,9 +81,9 @@ def test_traced_rehearsal_is_correct_and_hands_back_the_counters(here, monkeypat
                        jax.devices()[:1], PEAKS, here=here)
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] >= 2
     # at most the 4 held experts' whole load on one
-    assert 1.0 <= line["metrics"]["moe_load_max_over_mean.mla"]["value"] <= 4.0
-    assert 0.0 < line["metrics"]["mfu_pct.mla"]["value"] < 100.0
-    assert "moe_gmm_ms.mla" not in line["metrics"]            # no device in a CPU trace
+    assert 1.0 <= line["metrics"]["moe_load_max_over_mean"]["value"] <= 4.0
+    assert 0.0 < line["metrics"]["mfu_pct"]["value"] < 100.0
+    assert "moe_gmm_ms" not in line["metrics"]            # no device in a CPU trace
     checked = [r.split()[1] for r in rows if r.startswith("check:") and "limit" in r]
     assert {"dropped_assignments", "held_load_gap", "compilations_inside_window",
             "first_gradient_projection_gap"} <= set(checked)
@@ -175,7 +175,9 @@ def cell_run(events, steps, loads):
     r = {"trace": trace, "step_s": [0.6] * steps, "steps": 32, "tokens": 32 * 16384,
          "window_s": 20.0, "chips": 1, "seq": 8192, "dims": cell_dims(), "peaks": PEAKS,
          "expert_load": loads}
-    return dict(r, train_flops_per_token=mla_work.window_flops_per_token(r))   # as the adapter
+    return dict(r, train_flops_per_token=mla_work.window_flops_per_token(r),   # as the adapter
+                expert_matmul_work=hybrid_work.window_expert_matmul_work(
+                    r, view=mla_work.expert_view))
 
 
 def read(name, r):
@@ -199,18 +201,18 @@ def test_new_readers_on_names_as_the_chip_spells_them():
     loads = even_loads()
     loads[:, :, 0] = 2304                     # one expert half as full again
     r = cell_run(events, steps=2, loads=loads)
-    assert read("moe_gmm_ms.mla", r) == pytest.approx(20.0)
+    assert read("moe_gmm_ms", r) == pytest.approx(20.0)
     n = loads[0].sum()
     ops, nbytes = hand_expert_work(n)
-    assert read("moe_gmm_roofline_pct.mla", r) == pytest.approx(
+    assert read("moe_gmm_roofline_pct", r) == pytest.approx(
         100 * 1e3 * max(ops / 197e12, nbytes / 819e9) / 20.0)
-    assert read("moe_load_max_over_mean.mla", r) == pytest.approx(2304 / 1632.0)
+    assert read("moe_load_max_over_mean", r) == pytest.approx(2304 / 1632.0)
     # 32 steps of 16,384 tokens in 20 s at 2.5345 GFLOP a token (0.7969 local
     # assignments a token and layer) over 197 TFLOP/s
-    assert read("mfu_pct.mla", r) == pytest.approx(
+    assert read("mfu_pct", r) == pytest.approx(
         100 * mla_work.train_flops_per_token(r["dims"], 8192, n / 16384) * 32 * 16384 / 20.0
         / 197e12)
-    assert 30 < read("mfu_pct.mla", r) < 40
+    assert 30 < read("mfu_pct", r) < 40
     # the accepted flash times and shares list no cells: they read this cell's
     # two-width kernels through the attention view. Six layers x 16,384 tokens
     # x 16 heads x 640 x 4,096.5 keys = 4.124 TFLOP forward: 20.93 ms at 197
@@ -219,9 +221,12 @@ def test_new_readers_on_names_as_the_chip_spells_them():
     assert read("flash_bwd_ms", r) == pytest.approx(80.0)
     assert read("flash_fwd_roofline_pct", r) == pytest.approx(100 * 20.932 / 40.0, rel=1e-3)
     assert read("flash_bwd_roofline_pct", r) == pytest.approx(100 * 41.864 / 80.0, rel=1e-3)
-    for name in NEW_METRICS + ("flash_fwd_roofline_pct", "flash_bwd_roofline_pct"):
-        if name.endswith("_pct") or name.startswith("mfu") or "_pct." in name:
+    for name in SHARED_METRICS + ("flash_fwd_roofline_pct", "flash_bwd_roofline_pct"):
+        if name.endswith("_pct"):
             assert 0 <= read(name, r) <= 100, name   # a share over 100 % is a miscount
+    # a run whose adapter hands no count, or no work, reads as nothing
+    bare = {k: v for k, v in r.items() if k not in ("train_flops_per_token", "expert_matmul_work")}
+    assert read("mfu_pct", bare) is None and read("moe_gmm_roofline_pct", bare) is None
     # the banded readers and the other blocks' twins find nothing here
     for name in ("flash_win_fwd_ms", "flash_win_bwd_roofline_pct", "gdn_fwd_ms"):
         assert read(name, r) is None
@@ -248,8 +253,9 @@ def test_new_readers_find_nothing_in_a_program_that_lacks_the_names():
     sc1b = gpt_ref.dims(run.load_json(os.path.join(HERE, "configs", "starcoderbase-1b-train1.json")))
     r = cell_run([(0, 5, MLA_FWD), (5, 9, FUSION)], steps=1, loads=None)
     r = {k: v for k, v in dict(r, dims=sc1b).items() if k != "expert_load"}
-    assert [read(name, r) for name in NEW_METRICS] == [None] * len(NEW_METRICS)
-    assert [read(name, dict(r, trace=None)) for name in NEW_METRICS] == [None] * len(NEW_METRICS)
+    names = SHARED_METRICS
+    assert [read(name, r) for name in names] == [None] * len(names)
+    assert [read(name, dict(r, trace=None)) for name in names] == [None] * len(names)
 
 
 def test_required_work_by_hand():
@@ -269,7 +275,6 @@ def test_required_work_by_hand():
     view = mla_work.expert_view(d)
     assert (view["num_hidden_layers"], view["moe_intermediate_size"], view["experts_held"]) == (
         5, 1408, (0, 8))
-    from benchmarks import hybrid_work
     assert hybrid_work.expert_matmul_work(view, 61440, passes=3) == hand_expert_work(61440)
     # the tree map is a relabelling: nothing is lost or doubled
     w = jax.eval_shape(lambda k: mla_ref.make_weights(d, k), jax.ShapeDtypeStruct((2,), np.uint32))
@@ -280,25 +285,18 @@ def test_required_work_by_hand():
     assert p["layers"]["moe"]["w_gate_up"].shape == (5, 8, 2048, 2816)
 
 
+def check_manifest(m):
+    """The cell's entries as members of the manifest's lists (``test_harness.check_cell``),
+    and what is this cell's alone."""
+    cell, config, entry, reported = test_harness.check_cell(
+        m, CELL, "deepseek-v2-lite-train1", SHARED_METRICS)
+    assert "1/8" in cell["why"] and not {"gdn_fwd_ms", "flash_win_fwd_ms"} & reported
+    return config, entry
+
+
+
 def test_manifest_holds_the_new_cell_and_its_metrics():
-    m = run.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    cell, config = run.find_cell(m, CELL)
-    assert (cell["chips"], cell["traffic"], cell["config"]) == (1, "packed-code-8k",
-                                                               "deepseek-v2-lite-train1")
-    assert len(cell["why"]) <= 200 and "1/8" in cell["why"]
-    reported = {p["name"] for p in run.metrics_of(m, "per_layer", cell)}
-    assert set(NEW_METRICS) <= reported and not {
-        "mfu_pct", "mfu_pct.hybrid", "mfu_pct.afmoe", "gdn_fwd_ms", "moe_gmm_ms",
-        "moe_gmm_ms.afmoe", "flash_win_fwd_ms"} & reported
-    assert {"flash_fwd_roofline_pct", "flash_bwd_roofline_pct", "flash_fwd_ms", "flash_bwd_ms",
-            "step_ms.train", "device_idle_pct.train", "peak_hbm_gb.train",
-            "xentropy_ms"} <= reported
-    listed = {p["name"]: p for p in m["per_layer"]}
-    for name in NEW_METRICS:
-        assert listed[name]["workloads"] == [CELL]
-    # nothing the benchmark had lists the new cell
-    assert listed["mfu_pct.afmoe"]["workloads"] == ["trinity-train-8k"]
-    entry = next(c for c in m["configs"] if c["name"] == cell["config"])
+    config, entry = check_manifest(run.load_json(os.path.join(ROOT, "BENCHMARK.json")))
     published = {"num_hidden_layers": 27, "n_routed_experts": 64, "vocab_size": 102400}
     assert config["published"] == published and config["reduced"] == list(published)
     assert entry["reduced"] == list(published)

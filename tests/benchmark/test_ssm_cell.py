@@ -23,18 +23,18 @@ if ROOT not in sys.path:
 from benchmarks import hybrid_work, run, ssm_work, trace_reduce as tr  # noqa: E402
 from benchmarks.adapters import ssm_tree, train_o2_ssm  # noqa: E402
 from benchmarks.reference import ssm_ref  # noqa: E402
-from benchmarks.tests import toy  # noqa: E402
+from benchmarks.tests import test_harness, toy  # noqa: E402
 from benchmarks.tests.test_trace_reduce import plane  # noqa: E402
 
 HERE = os.path.join(ROOT, "benchmarks")
 PEAKS = run.load_json(os.path.join(HERE, "peaks.json"))["TPU v5 lite"]
 CELL = "nemotron3-train-8k"
-NEW_METRICS = ("mfu_pct.ssm", "ssd_fwd_ms", "ssd_bwd_ms", "ssd_fwd_roofline_pct",
-               "ssd_bwd_roofline_pct", "ssm_block_ms", "ssm_outside_kernels_ms",
-               "moe_gmm_ms.ssm", "moe_gmm_roofline_pct.ssm", "moe_load_max_over_mean.ssm",
-               # twins of accepted list-bearing metrics, whose lists cannot take the cell
-               "moe_rows_ms.ssm", "moe_block_ms.ssm", "moe_route_ms.ssm", "attn_block_ms.ssm",
-               "unembed_xent_ms.ssm", "optimizer_ms.ssm")
+NEW_METRICS = ("ssd_fwd_ms", "ssd_bwd_ms", "ssd_fwd_roofline_pct", "ssd_bwd_roofline_pct",
+               "ssm_block_ms", "ssm_outside_kernels_ms")
+# what the cell reports under names it shares with other cells: their lists hold it
+SHARED_METRICS = ("mfu_pct", "moe_gmm_ms", "moe_gmm_roofline_pct", "moe_load_max_over_mean",
+                  "moe_rows_ms", "moe_block_ms", "moe_route_ms", "attn_block_ms",
+                  "unembed_xent_ms", "optimizer_ms")
 # the cell's cut at a toy size: one period of the pattern, 16 experts top-4
 # with a share of 4 held, chunks of 16 inside rows of 64
 TOY_SSM = {
@@ -63,9 +63,7 @@ def manifest():
     m["workloads"] = [{"name": "toy-ssm-cell", "config": "toy-ssm",
                        "traffic": "toy-docs", "chips": 1}]
     m["per_layer"] += [{"name": n, "unit": "x", "moves": "train_tokens_per_s"}
-                       for n in ("moe_load_max_over_mean.ssm", "mfu_pct.ssm", "ssd_fwd_ms",
-                                 "moe_gmm_ms.ssm")]
-    m["per_layer"] = [p for p in m["per_layer"] if p["name"] != "mfu_pct"]
+                       for n in ("moe_load_max_over_mean", "ssd_fwd_ms", "moe_gmm_ms")]
     return m
 
 
@@ -86,9 +84,9 @@ def test_traced_rehearsal_is_correct_and_hands_back_the_counters(here, monkeypat
                        jax.devices()[:1], PEAKS, here=here)
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] >= 2
     # at most the 4 held experts' whole load on one
-    assert 1.0 <= line["metrics"]["moe_load_max_over_mean.ssm"]["value"] <= 4.0
-    assert 0.0 < line["metrics"]["mfu_pct.ssm"]["value"] < 100.0
-    assert not {"ssd_fwd_ms", "moe_gmm_ms.ssm"} & set(line["metrics"])   # no device in a CPU trace
+    assert 1.0 <= line["metrics"]["moe_load_max_over_mean"]["value"] <= 4.0
+    assert 0.0 < line["metrics"]["mfu_pct"]["value"] < 100.0
+    assert not {"ssd_fwd_ms", "moe_gmm_ms"} & set(line["metrics"])   # no device in a CPU trace
     checked = [r.split()[1] for r in rows if r.startswith("check:") and "limit" in r]
     assert {"dropped_assignments", "held_load_gap", "router_bias_gap",
             "compilations_inside_window", "first_gradient_projection_gap"} <= set(checked)
@@ -234,7 +232,13 @@ def cell_run(events, steps, loads, table=None):
          "expert_load": loads}
     if table is not None:
         r["scope_table"] = table
-    return dict(r, train_flops_per_token=ssm_work.window_flops_per_token(r))   # as the adapter
+    return as_the_adapter(r)
+
+
+def as_the_adapter(r):
+    return dict(r, train_flops_per_token=ssm_work.window_flops_per_token(r),
+                expert_matmul_work=hybrid_work.window_expert_matmul_work(
+                    r, work=ssm_work.expert_matmul_work))
 
 
 def read(name, r):
@@ -279,38 +283,39 @@ def test_new_readers_on_names_as_the_chip_spells_them():
     ops_b, bytes_b = ssm_work.scan_work(r["dims"], tokens, backward=True)
     assert read("ssd_bwd_roofline_pct", r) == pytest.approx(
         100 * 1e3 * max(ops_b / 197e12, bytes_b / 819e9) / 50.0)
-    assert read("moe_gmm_ms.ssm", r) == pytest.approx(20.0)
+    assert read("moe_gmm_ms", r) == pytest.approx(20.0)
     n = loads[0].sum()
     ops, nbytes = hand_expert_work(n)
-    assert read("moe_gmm_roofline_pct.ssm", r) == pytest.approx(
+    assert read("moe_gmm_roofline_pct", r) == pytest.approx(
         100 * 1e3 * max(ops / 197e12, nbytes / 819e9) / 20.0)
-    assert read("moe_load_max_over_mean.ssm", r) == pytest.approx(1152 / 816.0)
-    assert read("mfu_pct.ssm", r) == pytest.approx(
+    assert read("moe_load_max_over_mean", r) == pytest.approx(1152 / 816.0)
+    assert read("mfu_pct", r) == pytest.approx(
         100 * ssm_work.train_flops_per_token(r["dims"], 8192, n / tokens) * 32 * tokens / 16.0
         / 197e12)
-    assert 25 < read("mfu_pct.ssm", r) < 35
+    assert 25 < read("mfu_pct", r) < 35
     # everything traced under hybrid/ssm: both scans, the convolution and the
     # projection's fusion; outside the kernels the fusion alone
     assert read("ssm_block_ms", r) == pytest.approx(15.0 + 50.0 + 5.0 + 50.0)
     assert read("ssm_outside_kernels_ms", r) == pytest.approx(50.0)
-    # the twins of the accepted list-bearing metrics, on the spans the cell shares
-    assert read("moe_rows_ms.ssm", r) == pytest.approx(6.0)
-    assert read("moe_block_ms.ssm", r) == pytest.approx(10.0 + 6.0 + 4.0)   # gmm, rows, route
-    assert read("moe_route_ms.ssm", r) == pytest.approx(4.0)
-    assert read("attn_block_ms.ssm", r) == pytest.approx(10.0)
-    assert read("unembed_xent_ms.ssm", r) == pytest.approx(3.0)
-    assert read("optimizer_ms.ssm", r) == pytest.approx(2.0)
-    for twin in ("moe_rows_ms", "moe_block_ms", "moe_route_ms", "attn_block_ms",
-                 "unembed_xent_ms", "optimizer_ms"):
-        assert read(twin + ".ssm", r) == read(twin, r)      # what the accepted reader reads
+    # the shared block readers, on the spans this cell's program holds (``hybrid/attn``
+    # alone of the attention spans; nothing under ``gpt/*``): what its twins read
+    assert read("moe_rows_ms", r) == pytest.approx(6.0)
+    assert read("moe_block_ms", r) == pytest.approx(10.0 + 6.0 + 4.0)   # gmm, rows, route
+    assert read("moe_route_ms", r) == pytest.approx(4.0)
+    assert read("attn_block_ms", r) == pytest.approx(10.0)
+    assert read("unembed_xent_ms", r) == pytest.approx(3.0)
+    assert read("optimizer_ms", r) == pytest.approx(2.0)
     # the accepted flash times and shares list no cells: they read this cell's
     # ONE attention layer through the attention view
     assert read("flash_fwd_ms", r) == pytest.approx(10.0)
     want = 16384 * 4 * 32 * 128 * 4096.5 / 197e12 * 1e3
     assert read("flash_fwd_roofline_pct", r) == pytest.approx(100 * want / 10.0, rel=1e-3)
-    for name in NEW_METRICS + ("flash_fwd_roofline_pct",):
-        if name.endswith("_pct") or name.startswith("mfu") or "_pct." in name:
+    for name in NEW_METRICS + SHARED_METRICS + ("flash_fwd_roofline_pct",):
+        if name.endswith("_pct"):
             assert 0 <= read(name, r) <= 100, name   # a share over 100 % is a miscount
+    # a run whose adapter hands no count, or no work, reads as nothing
+    bare = {k: v for k, v in r.items() if k not in ("train_flops_per_token", "expert_matmul_work")}
+    assert read("mfu_pct", bare) is None and read("moe_gmm_roofline_pct", bare) is None
     # the other blocks' twins and readers find nothing here
     for name in ("flash_win_fwd_ms", "gdn_fwd_ms", "flash_bwd_ms"):
         assert read(name, r) is None
@@ -328,10 +333,10 @@ def test_required_work_is_never_counted_at_a_padded_width():
     least_ms = 1e3 * max(ops / 197e12, nbytes / 819e9)
     ns = int(2 * 1.02 * least_ms * 1e6)                   # two traced steps, 2 % over the least
     r = cell_run([(0, ns, GMM)], steps=2, loads=loads)
-    assert 95 < read("moe_gmm_roofline_pct.ssm", r) < 100
+    assert 95 < read("moe_gmm_roofline_pct", r) < 100
     for padded in (1920, 2048):
-        wide = dict(r, dims=dict(r["dims"], moe_intermediate_size=padded))
-        assert read("moe_gmm_roofline_pct.ssm", wide) > 100
+        wide = as_the_adapter(dict(r, dims=dict(r["dims"], moe_intermediate_size=padded)))
+        assert read("moe_gmm_roofline_pct", wide) > 100
     assert ssm_work.expert_matmul_work(d, n) == hand_expert_work(n)
     assert ssm_work.expert_matmul_work(d, n)[0] < hybrid_work.expert_matmul_work(
         dict(d, num_hidden_layers=3), n, passes=3)[0]      # two matrices, not a SwiGLU's three
@@ -345,8 +350,9 @@ def test_new_readers_find_nothing_in_a_program_that_lacks_the_names():
     sc1b = gpt_ref.dims(run.load_json(os.path.join(HERE, "configs", "starcoderbase-1b-train1.json")))
     r = cell_run([(0, 5, FLASH), (5, 9, FUSION)], steps=1, loads=None, table={})
     r = {k: v for k, v in dict(r, dims=sc1b).items() if k != "expert_load"}
-    assert [read(name, r) for name in NEW_METRICS] == [None] * len(NEW_METRICS)
-    assert [read(name, dict(r, trace=None)) for name in NEW_METRICS] == [None] * len(NEW_METRICS)
+    names = NEW_METRICS + SHARED_METRICS
+    assert [read(name, r) for name in names] == [None] * len(names)
+    assert [read(name, dict(r, trace=None)) for name in names] == [None] * len(names)
 
 
 def test_required_work_by_hand():
@@ -384,60 +390,25 @@ def test_required_work_by_hand():
     assert p["layers"]["norm1"].shape == (4, 2688) and p["layers"]["norm2"].shape == (3, 2688)
 
 
+def check_manifest(m):
+    """The cell's entries as members of the manifest's lists (``test_harness.check_cell``),
+    and what is this cell's alone."""
+    cell, config, entry, reported = test_harness.check_cell(
+        m, CELL, "nemotron-3-nano-30b-a3b-train1", NEW_METRICS + SHARED_METRICS)
+    assert "1/16" in cell["why"] and "41 %" in cell["why"]
+    assert not {"gdn_fwd_ms", "flash_win_fwd_ms", "kda_fwd_ms", "mlp_block_ms"} & reported
+    return config, entry
+
+
+
 def test_the_cell_is_appended_to_the_manifest_and_its_entries_keep_to_the_contract():
-    """The cell's entries are the last of their lists, and every entry before
-    them is the one ``test_scope_metrics.py``'s pin of the five accepted cells
-    describes (that pin's count of cells is the one assertion a sixth cell
-    breaks: PERF.md section 7)."""
-    from benchmarks.tests.test_harness import NAME
-    m = run.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    assert [w["name"] for w in m["workloads"]] == [
-        "sc1b-train-8k", "gpt2m-train-1k-dp4", "q3next-train-8k", "trinity-train-8k",
-        "dsv2lite-train-8k", CELL]
-    assert m["configs"][-1]["name"] == "nemotron-3-nano-30b-a3b-train1" and len(m["configs"]) == 6
-    assert tuple(p["name"] for p in m["per_layer"][-len(NEW_METRICS):]) == NEW_METRICS
-    assert all(CELL not in p.get("workloads", ()) for p in m["per_layer"][:-len(NEW_METRICS)])
-    assert (m["run_seconds"], [e["bound"] for e in m["end_to_end"]]) == (20, [0.01, 0.1])
-    assert len(json.dumps(m, indent=1)) < 64 * 1024
-    assert set(m["configs"][-1]) == {"name", "source", "file", "reduced", "why"}
-    assert set(m["workloads"][-1]) == {"name", "config", "traffic", "chips", "why"}
-    for entry in m["per_layer"][-len(NEW_METRICS):]:
-        assert set(entry) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
-        reader = run.load_reader(entry["name"])
-        assert (reader.LAYER, reader.UNIT, reader.MOVES) == (
-            entry["layer"], entry["unit"], entry["moves"])
-    names = [x["name"] for k in ("configs", "workloads", "end_to_end", "per_layer") for x in m[k]]
-    assert all(NAME.match(n) for n in names) and len(set(names)) == len(names)
-    assert sum(w["chips"] == 4 for w in m["workloads"]) <= max(1, len(m["workloads"]) // 4)
-    # the check's time rule at this run_seconds, with the sixth cell
-    cells = len(m["workloads"])
-    assert (2 + 14 * cells) * (m["run_seconds"] + 60) + 2 * 90 * cells + 1200 <= 43200
+    """Appended once, a member ever after: ``check_manifest`` on the file as it is."""
+    check_manifest(run.load_json(os.path.join(ROOT, "BENCHMARK.json")))
 
 
 def test_manifest_holds_the_new_cell_and_its_metrics():
-    m = run.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    cell, config = run.find_cell(m, CELL)
-    assert (cell["chips"], cell["traffic"], cell["config"]) == (
-        1, "packed-code-8k", "nemotron-3-nano-30b-a3b-train1")
-    assert len(cell["why"]) <= 200 and "1/16" in cell["why"] and "41 %" in cell["why"]
-    reported = {p["name"] for p in run.metrics_of(m, "per_layer", cell)}
-    assert set(NEW_METRICS) <= reported and not {
-        "mfu_pct", "mfu_pct.hybrid", "mfu_pct.afmoe", "mfu_pct.mla", "gdn_fwd_ms", "moe_gmm_ms",
-        "moe_gmm_ms.afmoe", "moe_gmm_ms.mla", "flash_win_fwd_ms", "moe_rows_ms",
-        "attn_block_ms"} & reported
-    assert {"flash_fwd_roofline_pct", "flash_bwd_roofline_pct", "flash_fwd_ms", "flash_bwd_ms",
-            "step_ms.train", "device_idle_pct.train", "peak_hbm_gb.train",
-            "xentropy_ms"} <= reported
-    assert {e["name"] for e in run.metrics_of(m, "end_to_end", cell)} == {
-        "train_tokens_per_s", "setup_s"}
-    listed = {p["name"]: p for p in m["per_layer"]}
-    for name in NEW_METRICS:
-        assert listed[name]["workloads"] == [CELL]
-        assert os.path.exists(os.path.join(HERE, "layer_metrics", name + ".py"))
-    # nothing the benchmark had lists the new cell
-    assert listed["mfu_pct.mla"]["workloads"] == ["dsv2lite-train-8k"]
-    assert CELL not in listed["moe_rows_ms"]["workloads"]
-    entry = next(c for c in m["configs"] if c["name"] == cell["config"])
+    """The configuration behind the cell's entries, on file as the entry says."""
+    config, entry = check_manifest(run.load_json(os.path.join(ROOT, "BENCHMARK.json")))
     published = {"num_hidden_layers": 52, "n_routed_experts": 128, "vocab_size": 131072}
     assert config["published"] == published and config["reduced"] == list(published)
     assert entry["reduced"] == list(published)
