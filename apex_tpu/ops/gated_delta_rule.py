@@ -11,13 +11,18 @@ UT form): inside a chunk the ``u`` solve a unit lower-triangular system
 ``(I + A) U = beta V - (beta e^G K) S_0``, so ``T = (I + A)^-1`` turns each
 chunk into operands of a recurrence over *chunks* — matmuls instead of
 ``chunk`` rank-one updates (float32 decays, cumulative inside the chunk
-only, so nothing is ever divided by a decay). The Pallas kernel pair
-``gdn_fwd`` / ``gdn_bwd`` (``ops/pallas/gated_delta_rule.py``) builds every
-chunk's operands in VMEM and walks the chunks with the state there too;
-``impl="xla"`` prepares all chunks at once in XLA and runs a ``lax.scan``
-over them — the same mathematics, the oracle the kernels are tested
-against, as every kernel family here has one, and the path for shapes the
-kernels refuse.
+only, so nothing is ever divided by a decay). The two ``impl``s are the
+same mathematics in two orders. The Pallas kernel pair ``gdn_fwd`` /
+``gdn_bwd`` (``ops/pallas/gated_delta_rule.py``) takes eight chunks of one key
+head, of the value heads it serves and of two batch rows (one of an odd
+batch) a grid step: it builds all their operands in VMEM side by side, their
+triangular systems inverted as one batch, and walks the chunks unrolled in
+the same basic block with the states as values — the rows' chains side by
+side; the backward keeps ``T`` and the scores of its preparation and turns a
+chunk's cotangents into its inputs' where ``dS`` is carried through it.
+``impl="xla"`` prepares all chunks of the sequence at once in XLA and runs a
+``lax.scan`` over them: the oracle the kernels are tested against, as every
+kernel family here has one, and the path for shapes the kernels refuse.
 
 :func:`kda_rule` is the same recurrence with the decay a vector a key channel
 (``g_t`` in R^dk a head: ``S <- Diag(exp(g_t)) S``; Kimi Delta Attention). The
@@ -26,8 +31,9 @@ longer factors into a matmul and a (C, C) mask: the kernel pair ``kda_fwd`` /
 ``kda_bwd`` (``ops/pallas/kda.py``) makes it a sub-chunk of 16 rows at a time
 from float32 factors that stay inside float32's range as long as every
 step's log decay is at least ``KDA_LOG_DECAY_MIN`` (-5: what a bounded gate
-gives), and everything after it — the inverse, the walk, the saved results —
-is the scalar rule's; its ``impl="xla"`` oracle takes the explicit
+gives), and everything after it — the inverse, the walk, the saved results,
+the shape of a grid step (two batch rows, a few long blocks that make nothing
+twice) — is the scalar rule's; its ``impl="xla"`` oracle takes the explicit
 differences chunk by chunk in a ``lax.scan``.
 
 Also here: :func:`causal_conv_silu` (the depthwise causal convolution that

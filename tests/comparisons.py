@@ -19,6 +19,25 @@ def gap(got, want):
     return float(jnp.max(jnp.abs(got - want))) / (float(jnp.max(jnp.abs(want))) + 1e-30)
 
 
+# the delta rules' kernels on a batch against the same kernels a row at a time: every batch at
+# a short row; the cells' batch of two at every count of chunks — 3 an odd count, 6 short of
+# a grid step, 8 a whole grid step, 16 two grid steps with the state and dS carried between
+BATCH_AND_CHUNKS = [(1, 2), (2, 2), (3, 2), (4, 2), (2, 3), (2, 6), (2, 8), (2, 16)]
+
+
+def batch_equals_its_rows(kernel_pair, args, do):
+    """``kernel_pair(*args, do)`` (every result of a forward and a backward
+    kernel, the batch leading) on the whole batch equals, bit for bit, the
+    same call a row at a time: two rows a grid step (an even batch) and one
+    (an odd batch, a batch of one) are the same arithmetic."""
+    whole = kernel_pair(*args, do)
+    for r in range(do.shape[0]):
+        one = kernel_pair(*(a[r:r + 1] for a in args), do[r:r + 1])
+        for x, y in zip(whole, one):
+            np.testing.assert_array_equal(np.asarray(x[r:r + 1], np.float32),
+                                          np.asarray(y, np.float32))
+
+
 def batch(rows=2, seq=96, seed=0):
     """Tokens and targets below 256, drawn apart."""
     rng = np.random.default_rng(seed)

@@ -1,7 +1,9 @@
 """The chunked gated delta rule (XLA form and the ``gdn_fwd`` / ``gdn_bwd``
 kernels, which build every chunk's operands in VMEM) against the
 token-by-token recurrence of the plain reference and against each other,
-value and all five gradients; and the small ops around it."""
+value and all five gradients; a batch on the kernels (two rows a grid step)
+against the same kernels a row at a time, bit for bit; and the small ops
+around it."""
 import os
 import sys
 
@@ -16,8 +18,10 @@ if ROOT not in sys.path:
 
 from apex_tpu.ops.gated_delta_rule import (causal_conv_silu, gated_delta_rule,  # noqa: E402
                                            gated_rms_norm, _unit_lower_inverse)
+from apex_tpu.ops.pallas import gated_delta_rule as kernels  # noqa: E402
 from apex_tpu.ops.rotary import apply_partial_rotary  # noqa: E402
 from benchmarks.reference import hybrid_ref as R  # noqa: E402
+from comparisons import BATCH_AND_CHUNKS, batch_equals_its_rows  # noqa: E402
 
 
 def recurrence(q, k, v, g, beta):
@@ -98,6 +102,28 @@ def test_kernels_agree_with_the_xla_form(t, decay, chunk):
     np.testing.assert_allclose(a, b, rtol=1e-5)
     for m, n in zip(ga, gb):
         np.testing.assert_allclose(m, n, atol=1e-5 * float(jnp.max(jnp.abs(n))) + 1e-7)
+
+
+def kernel_operands(b, n, C=16, d=128):
+    """What ``gdn_fwd`` / ``gdn_bwd`` take, bf16 as the cells run them: ``b`` rows of
+    ``n`` chunks of ``C`` tokens, one key head and its value head, and ``do``."""
+    ks = jax.random.split(jax.random.PRNGKey(b * 100 + n), 6)
+    q, k, v, do = (jax.random.normal(ks[i], (b, n * C, d), jnp.bfloat16) for i in range(4))
+    G = jnp.cumsum(-jnp.exp(jax.random.uniform(ks[4], (b, 1, n, C), minval=-7.0, maxval=0.5)), -1)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[5], (b, 1, n, C)))
+    return (q, k, v, G, beta, jnp.broadcast_to(G[..., -1:], G.shape[:-1] + (d,))), do
+
+
+@jax.jit
+def kernel_pair(q, k, v, G, beta, gl, do):
+    o, s0 = kernels.gdn_fwd(q, k, v, G, beta, gl, heads=1, interpret=True)
+    return (o, s0) + tuple(kernels.gdn_bwd(q, k, v, G, beta, gl, s0, do, heads=1, interpret=True))
+
+
+@pytest.mark.parametrize("b,n", BATCH_AND_CHUNKS)
+def test_a_batch_on_the_kernels_equals_its_rows_bit_for_bit(b, n):
+    """``o``, the states the blocks started from and all six cotangents."""
+    batch_equals_its_rows(kernel_pair, *kernel_operands(b, n))
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])

@@ -4,7 +4,8 @@ every cotangent (the per-channel log decay's among them) over several chunks,
 a ragged last one and more than one grid step, decays AT the kernels' bound
 for a whole chunk and near 0, a decay that is constant over a head's channels
 equal to ``gated_delta_rule`` on the same inputs, bf16 operands, the XLA twin,
-the names ``remat`` keeps and the shape rule."""
+a batch on the kernels (two rows a grid step) against the same kernels a row at
+a time, the names ``remat`` keeps and the shape rule."""
 import os
 import sys
 
@@ -18,7 +19,8 @@ if ROOT not in sys.path:
 
 from apex_tpu.ops import gated_delta_rule as gdr  # noqa: E402
 from apex_tpu.ops.pallas import kda as kernels  # noqa: E402
-from comparisons import gap, kernel_calls  # noqa: E402
+from comparisons import (BATCH_AND_CHUNKS, batch_equals_its_rows, gap,  # noqa: E402
+                         kernel_calls)
 
 NAMES = ("q", "k", "v", "g", "beta")
 
@@ -132,6 +134,27 @@ def test_bf16_operands_keep_decay_and_state_float32(impl):
     assert gap(got_y.astype(jnp.float32), want_y) < 3e-2
     for name, a, b in zip(NAMES, got, want):
         assert gap(a.astype(jnp.float32), b) < 5e-2, name
+
+
+def kernel_operands(b, n, C=16, d=128):
+    """What ``kda_fwd`` / ``kda_bwd`` take, bf16 as the cell runs them: ``b`` rows of
+    ``n`` chunks of ``C`` tokens, one head, decays over the whole of (-5, 0), and ``do``."""
+    ks = jax.random.split(jax.random.PRNGKey(b * 100 + n), 6)
+    q, k, v, do = (jax.random.normal(ks[i], (b, n * C, d), jnp.bfloat16) for i in range(4))
+    g = kernels.LOG_DECAY_MIN * jax.nn.sigmoid(4.0 * jax.random.normal(ks[4], (b, n * C, d)))
+    return (q, k, v, g, jax.nn.sigmoid(jax.random.normal(ks[5], (b, 1, n, C)))), do
+
+
+@jax.jit
+def kernel_pair(q, k, v, g, beta, do):
+    o, s0 = kernels.kda_fwd(q, k, v, g, beta, interpret=True)
+    return (o, s0) + tuple(kernels.kda_bwd(q, k, v, g, beta, s0, do, interpret=True))
+
+
+@pytest.mark.parametrize("b,n", BATCH_AND_CHUNKS)
+def test_a_batch_on_the_kernels_equals_its_rows_bit_for_bit(b, n):
+    """``o``, the states the blocks started from and all five cotangents."""
+    batch_equals_its_rows(kernel_pair, *kernel_operands(b, n))
 
 
 def test_remat_keeps_the_kernels_results_by_name():

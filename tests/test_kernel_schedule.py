@@ -1,7 +1,8 @@
 """``tools/kernel_schedule.py``'s two readers on a made-up dump: the bundles a
 grid step walks (a loop's body times its trips, hoisted outer work inside a
-body counted with it), the units' mean use a window or a stretch, and the
-stretches between control marks (a ``pl.when`` body's end)."""
+body counted with it) and the grid's steps (which say how many batch rows a
+grid step of the delta rules holds), the units' mean use a window or a
+stretch, and the stretches between control marks (a ``pl.when`` body's end)."""
 import os
 import sys
 
@@ -32,16 +33,22 @@ def test_a_grid_step_is_the_loops_bodies_times_their_trips(hoisted):
     second = [("LB", 2, "")] + [("", 2, "")] * 2 + [("", 2, exit_test(8))]
     text = bundles([("", 0, "")] * 2 + [("LB", 1, "")] + [("", 1, "")] * 9 + first + second
                    + [("", 1, exit_test(1026))] * 1 + [("", 0, "")])
-    step, inner, total = ks.loops(text)
+    step, inner, total, grid = ks.loops(text)
+    assert grid == 1024                 # the grid loop's exit test less the pipeline's two
     assert inner == [(6 + hoisted, 2), (4, 8)]
     assert total == 14 + 6 + hoisted + 4
     assert step == 14 + 2 * (6 + hoisted) + 8 * 4
 
 
-def test_a_kernel_without_inner_loops_is_its_text():
-    step, inner, total = ks.loops(bundles([("", 0, "")] + [("LB", 1, "")] + [("", 1, "")] * 20
-                                          + [("", 1, exit_test(1026))]))
-    assert (step, inner, total) == (23, [], 23)
+@pytest.mark.parametrize("family,trips,held", [("kda", 1026, 1), ("kda", 514, 2), ("gdn", 258, 2)],
+                         ids=["a-row-a-step", "kda-two-rows", "gdn-two-rows"])
+def test_a_kernel_without_inner_loops_is_its_text_and_the_grid_says_its_rows(family, trips, held):
+    """One long block a grid step; the (row, head, block) triples of the cell's
+    shape over the grid's steps are the batch rows a grid step holds."""
+    step, inner, total, grid = ks.loops(bundles([("", 0, "")] + [("LB", 1, "")] + [("", 1, "")] * 20
+                                                + [("", 1, exit_test(trips))]))
+    assert (step, inner, total, grid) == (23, [], 23, trips - 2)
+    assert ks.TRIPLES[family] // grid == held
 
 
 def test_unit_use_is_a_share_of_each_units_capacity():

@@ -9,8 +9,10 @@ says what binds a stretch (MXU, XLU, VALU, EUP, load / store slots, spills).
     python tools/kernel_schedule.py kda [--checkout DIR] [--window 250]
     python tools/kernel_schedule.py flash [--checkout DIR]
 
-prints, a kernel: bundles a grid step, every inner loop as bundles x trips,
-and the mean unit use a window of bundles along the text. ``--checkout`` reads
+prints, a kernel: bundles a grid step AND a batch row (a grid step of the delta
+rules holds two rows of an even batch: the grid loop's trips say how many),
+every inner loop as bundles x trips, and the mean unit use a window of bundles
+along the text. ``--checkout`` reads
 the kernels of another tree (a copy of the parent). The ``flash`` family (the
 forward wrappers at the cells' shapes: packed, bshd at heads of 128 and 256,
 banded, two-width, the pair) prints the use a PREDICATED REGION instead: a
@@ -38,6 +40,14 @@ _BUNDLE = re.compile(r"^\s*(0x[0-9a-f]+|\d+)\s+([A-Z]{2})?:\s*(>*)\s*\{")
 _EXIT = re.compile(r"totalorder %\w+, (\d+) /\* loop exit test")
 
 
+# the delta rules' cells: 2 rows of 8,192 tokens a chip in chunks of 64, the heads a grid row each.
+# TRIPLES: the (batch row, head, block of eight chunks) a kernel walks; over the grid's steps they
+# say how many batch rows a grid step holds
+ROWS, SEQ, CHUNK = 2, 8192, 64
+HEADS = {"kda": 32, "gdn": 16}
+TRIPLES = {family: ROWS * heads * (SEQ // CHUNK // 8) for family, heads in HEADS.items()}
+
+
 def families():
     """{family: [(kernel name, function, argument shapes)]} at the cells' shapes."""
     import jax
@@ -45,14 +55,14 @@ def families():
     from apex_tpu.ops.pallas import gated_delta_rule as gdn
     from apex_tpu.ops.pallas import kda
     sd = jax.ShapeDtypeStruct
-    b, t, C, d = 2, 8192, 64, 128
+    b, t, C, d = ROWS, SEQ, CHUNK, 128
 
-    def kda_args(h=32):                    # ling3-train-8k: 32 heads of 128
+    def kda_args(h=HEADS["kda"]):          # ling3-train-8k: 32 heads of 128
         x, g = sd((b, t, h * d), jnp.bfloat16), sd((b, t, h * d), jnp.float32)
         beta, s0 = sd((b, h, t // C, C), jnp.float32), sd((b, h, t // C // kda.CHUNKS, d, d), jnp.float32)
         return (x, x, x, g, beta), (x, x, x, g, beta, s0, x)
 
-    hk, hv = 16, 32                        # q3next-train-8k: 16 key heads serve 32 value heads
+    hk, hv = HEADS["gdn"], 32              # q3next-train-8k: 16 key heads serve 32 value heads
 
     def gdn_args():
         qk, v = sd((b, t, hk * d), jnp.bfloat16), sd((b, t, hv * d), jnp.bfloat16)
@@ -88,6 +98,7 @@ def families():
             "flash": flash}
 
 
+
 # the kernels a family lists, by label (the first word the kernel's own name), for
 # the parent process, which stays off JAX
 LABELS = {"kda": ("kda_fwd", "kda_bwd"), "gdn": ("gdn_fwd", "gdn_bwd"),
@@ -112,11 +123,12 @@ def compile_kernel(family, kernel):
 
 def loops(text):
     """(bundles a grid step, [(bundles, trips)] of the inner loops, bundles of
-    text) from a ``final_bundles`` dump. A loop's body runs from its ``LB``
-    bundle to the last bundle of its depth before eight in a row of a lesser
+    text, grid steps) from a ``final_bundles`` dump. A loop's body runs from its
+    ``LB`` bundle to the last bundle of its depth before eight in a row of a lesser
     one or a sibling's ``LB`` (hoisted scalar work of the outer loop stands
     inside it, marked with the outer depth); the trips are the loops' exit
-    tests in order, the grid's own last."""
+    tests in order, the grid's own last (two more than its steps: the
+    pipeline's first fetch and last write)."""
     rows = [(m.group(2), len(m.group(3))) for m in map(_BUNDLE.match, text.splitlines()) if m]
     inner = []
     for i, (mark, depth) in enumerate(rows):
@@ -131,9 +143,10 @@ def loops(text):
                 if below >= 8 or rows[j][0] == "LB":
                     break
         inner.append(last - i + 1)
-    trips = [int(t) for t in _EXIT.findall(text)][:len(inner)]
+    exits = [int(t) for t in _EXIT.findall(text)]
+    trips = exits[:len(inner)]
     step = len(rows) - sum(inner) + sum(n * t for n, t in zip(inner, trips))
-    return step, list(zip(inner, trips)), len(rows)
+    return step, list(zip(inner, trips)), len(rows), (exits[-1] - 2 if exits else 1)
 
 
 def regions(text):
@@ -182,10 +195,13 @@ def main():
                 f for f in sorted(glob.glob(f"{dump}/*{kernel}*{part}*"))
                 if "schedule-analysis" not in f), errors="replace").read()
             bundles = read("final_bundles")
-            step, inner, text = loops(bundles)
+            step, inner, text, grid = loops(bundles)
             by_region = args.family == "flash"
+            held = max(1, TRIPLES.get(args.family, grid) // grid)   # batch rows a grid step
             print(f"{label}: " + (f"{text} bundles of text, a grid step walks ONE tile region"
-                                  if by_region else f"{step} bundles a grid step ({text} of text)")
+                                  if by_region else
+                                  f"{step} bundles a grid step of {held} batch row{'s'[:held - 1]}, "
+                                  f"{step // held} a row ({text} of text)")
                   + f"; inner loops (bundles x trips): {inner}")
             print("  from   bundles " + " ".join(f"{u:>6s}" for u in UNITS))
             for first, n, use in unit_use(read("per-bundle-utilization"), args.window,

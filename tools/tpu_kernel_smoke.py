@@ -10,7 +10,11 @@ of 128, seq 1024, vocab 32768, 8 serving slots, 128-row cache blocks).
 also run their FORWARD from ``DIR``'s ``ops/pallas/attention.py`` on the same
 operands, print its device time beside the tree's and hold ``o`` and ``lse`` to
 it bit for bit (max |diff| must read 0.0: a forward that only stops masking
-what needs no mask and fetching what it skips changes no result).
+what needs no mask and fetching what it skips changes no result). The delta
+rules' families (``gdn``, ``kda``) print their kernel pair ALONE at the cell's
+shape, 2 rows a chip, by device ms a call, and run ``DIR``'s
+``ops/pallas/gated_delta_rule.py`` / ``kda.py`` the same way: ``o``, the states
+the blocks started from and every cotangent bit for bit.
 
 A family is ``(kernel_fn, reference_fn, args)``: both run under ``jax.jit``
 on the same operands and every output leaf (forward values AND gradients)
@@ -347,17 +351,17 @@ def _sliced_attention_reference(fn):
     return run
 
 
-# trees whose forward kernels the flash cell families compare with (``--checkout``)
+# trees whose kernels the flash cell families and the delta rules' compare with (``--checkout``)
 CHECKOUTS = []
 
 
-def _attention_at(checkout):
-    """``ops/pallas/attention.py`` of another tree as a module of its own (its
+def _module_at(checkout, file):
+    """``ops/pallas/<file>.py`` of another tree as a module of its own (its
     imports resolve to this tree's package, which it shares)."""
     import importlib.util
     spec = importlib.util.spec_from_file_location(
-        "attention_at_" + "".join(c if c.isalnum() else "_" for c in checkout),
-        os.path.join(checkout, "apex_tpu", "ops", "pallas", "attention.py"))
+        file + "_at_" + "".join(c if c.isalnum() else "_" for c in checkout),
+        os.path.join(checkout, "apex_tpu", "ops", "pallas", file + ".py"))
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
@@ -389,7 +393,7 @@ def _forward_report(forms):
                          f"{running} running tiles, {visible} of them fully visible (no mask), "
                          f"{skipped} skipped (nothing fetched)")
             for checkout in CHECKOUTS:
-                ms_c, (o_c, lse_c) = run(_attention_at(checkout))
+                ms_c, (o_c, lse_c) = run(_module_at(checkout, "attention"))
                 d_o, d_lse = diff(o, o_c), diff(lse, lse_c)
                 lines.append(f"{'FAIL' if d_o or d_lse else '    '} {checkout}: {ms_c:.3f} ms a call; "
                              f"max |diff| of o {d_o}, of lse {d_lse}")
@@ -636,15 +640,63 @@ def _kda_family():
     return build
 
 
-def _kda_times():
+def _gdn_operands(t=8192, hk=16, hv=32, chunk=64):
+    """``q3next-train-8k``'s rule as the kernels take it: 2 rows of 8,192, 16
+    key heads serve 32 value heads of 128, decays from 0.2 to 0.999; ``G`` the
+    log decay cumulated inside each chunk of 64, ``gl`` its last over the lanes."""
+    q, k = (jr.normal(_key(i), (B, t, hk * D), jnp.bfloat16) for i in (130, 131))
+    v, do = (jr.normal(_key(i), (B, t, hv * D), jnp.bfloat16) for i in (132, 133))
+    g = -jnp.exp(jr.uniform(_key(134), (B, hv, t // chunk, chunk), minval=-7.0, maxval=0.5))
+    G = jnp.cumsum(g, axis=-1)
+    beta = jax.nn.sigmoid(jr.normal(_key(135), (B, hv, t // chunk, chunk)))
+    return (q, k, v, G, beta, jnp.broadcast_to(G[..., -1:], G.shape[:-1] + (D,))), do
+
+
+def _rule_times(family):
+    """The delta rule's kernel pair (``gdn`` or ``kda``) ALONE at its cell's
+    shape, 2 rows a chip as the cells run: device ms a call of the forward and
+    of the backward — and, from every ``--checkout``, the same pair on the same
+    operands beside it with max |diff| of every result (``o``, the states the
+    blocks started from, each cotangent), which must read 0.0: a grid step's
+    order is not its arithmetic."""
     def report():
-        from apex_tpu.ops.gated_delta_rule import kda_rule
-        args = _kda_operands()
-        both = _fwd_and_grads(lambda *a: kda_rule(*a, impl="pallas"), (0, 1, 2, 3, 4))
-        _, by_name = _device_ms(both, *args)
-        kernels = {n: ms for n, ms in by_name.items() if "kda_" in n}
-        return ["kda pallas: " + ", ".join(f"{n} {ms:.2f} ms" for n, ms in sorted(kernels.items()))
-                + " a layer by device time (2 x 8,192 tokens, 32 heads of 128)"]
+        from apex_tpu.ops.pallas import gated_delta_rule, kda
+        static = dict(interpret=_backend.interpret_mode())
+        if family == "gdn":
+            file, here, shape = "gated_delta_rule", gated_delta_rule, "16 key / 32 value heads of 128"
+            args, do = _gdn_operands()
+            static["heads"] = 16
+        else:
+            file, here, shape = "kda", kda, "32 heads of 128"
+            q, k, v, g, beta = _kda_operands()
+            flat = lambda x: x.reshape(x.shape[:2] + (-1,))  # noqa: E731
+            args = (flat(q), flat(k), flat(v), flat(g), jnp.moveaxis(beta, 1, 2).reshape(B, 32, -1, 64))
+            do = flat(jr.normal(_key(125), q.shape, jnp.bfloat16))
+
+        def both(module):
+            fwd, bwd = (functools.partial(getattr(module, f"{family}_{half}"), **static)
+                        for half in ("fwd", "bwd"))
+
+            def run(*a):
+                o, s0 = fwd(*a[:-1])
+                return (o, s0) + tuple(bwd(*a[:-1], s0, a[-1]))
+            _, by_name, out = _device_ms(run, *args, do, with_output=True)
+            return {n: ms for n, ms in by_name.items() if family + "_" in n}, out
+
+        def line(label, kernels):
+            return (f"{label}: " + ", ".join(f"{n} {ms:.3f} ms" for n, ms in sorted(kernels.items()))
+                    + f" a call by device time (2 x 8,192 tokens, {shape})")
+
+        kernels, out = both(here)
+        lines = [line(f"{family} kernels alone", kernels)]
+        for checkout in CHECKOUTS:
+            theirs, their_out = both(_module_at(checkout, file))
+            gaps = [float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))))
+                    for a, b in zip(out, their_out)]
+            same = all(gap == 0.0 for gap in gaps) and len(out) == len(their_out)
+            lines.append(("" if same else "FAIL ") + line(f"at {checkout}", theirs)
+                         + f"; max |diff| of o, s0 and the cotangents {gaps}")
+        return lines
     return report
 
 
@@ -1010,11 +1062,11 @@ FAMILIES = (
     Family("flash packed pair 8 x 1,024, 16 heads of 64 two to a lane tile, fwd + one-pass bwd",
            _pair_cell_family(),
            timings=lambda: _pair_cell_times()() + _forward_report(_pair_forward_forms())()),
-    Family("gated delta rule gdn_fwd/gdn_bwd", _delta_rule_family()),
+    Family("gated delta rule gdn_fwd/gdn_bwd", _delta_rule_family(), timings=_rule_times("gdn")),
     Family("gated delta rule on drifted keys, against the recurrence",
            _delta_rule_drifted_family()),
     Family("kda rule kda_fwd/kda_bwd at ling3-train-8k's 32 heads of 128, decays down to the bound",
-           _kda_family(), timings=_kda_times()),
+           _kda_family(), timings=_rule_times("kda")),
     Family("delta mixer stages conv_silu/gated_norm fwd/bwd", _delta_mixer_stages_family()),
     Family("ssd scan ssd_fwd/ssd_bwd at nemotron3-train-8k's 64 heads of 64, N 128, 8 groups",
            _ssd_family(), timings=_ssd_times()),
