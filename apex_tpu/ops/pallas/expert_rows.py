@@ -4,9 +4,15 @@ rows and back, by one DMA a row in use and none for the rest.
 A row of a (N, H) array in its tiled layout is no DMA's unit (eight or
 sixteen rows interleave in one tile), so the SOURCE of either movement
 arrives as its GROUPS: (N * G, 128) words of 32 bits, a row in G consecutive
-lines of 128 — whole tiles, contiguous in HBM. float32 rows are their own
-words (G = H / 128); a bf16 row packs column ``c`` and column ``c + H / 2``
-into one word (G = H / 256), so a row is as many bytes as it was. A kernel
+lines of 128, contiguous in HBM. float32 rows are their own words; a bf16 row
+packs column ``c`` and column ``c + 128 G`` into one word. G (``groups_of``)
+is the lines that hold the row and no more, whole tiles of eight or not (a
+DMA may start at any line of such a buffer and a strided load take any
+stride): 2,048 bf16 columns are 8 lines, 2,560 are 10, and a row is as many
+bytes as it was; 2,688 are 10.5 and ride in 11, the last line's high half
+zero. That padding lives in the groups and nowhere else: every tiled operand
+and result is (rows, H) at the row's own width, and a kernel's static loop
+over a group's column blocks leaves out the blocks at or past H. A kernel
 fetches a row's group with one DMA into VMEM and reads the staged groups back
 column block by column block with a strided load, which is the relayout to
 (rows, H). The RESULTS leave in the plain tiled layout. ``as_groups`` builds
@@ -41,6 +47,7 @@ from jax.experimental.pallas import tpu as pltpu
 from apex_tpu.ops.pallas.grouped_matmul import TM
 
 LANES = 128
+TILE = 8               # lines of a tile of 32-bit words
 TT = 128               # tokens a tile of the combine
 LC = 128               # rows the combine stages and sums at a time
 SUB = 16               # row DMAs started a loop step and waited for at once
@@ -53,37 +60,58 @@ def _packed(dtype):
 
 
 def groups_of(width, dtype):
-    """Lines of 128 words that one row's group takes."""
-    return width // (2 * LANES if _packed(dtype) else LANES)
+    """Lines of 128 words that one row's group takes: those that hold it."""
+    return -(-width // (2 * LANES if _packed(dtype) else LANES))
+
+
+def shapes_ok(width, dtype, interpret):
+    """Widths the kernels take, each at its own: whole lane tiles and,
+    compiled, a group of more than half a tile's eight lines (1,024 bf16
+    columns are four: XLA's movements, as such rows ran before the row
+    kernels and since)."""
+    return width % LANES == 0 and (interpret or 2 * groups_of(width, dtype) > TILE)
 
 
 def _bits(part):
     return jax.lax.bitcast_convert_type(part.astype(jnp.float32), jnp.uint32)
 
 
+def _low(part):
+    """bf16 (as float32 bits) in the lower half of a word each."""
+    return _bits(part) >> 16
+
+
 def _words(low, high):
-    """bf16 (as float32 bits) ``low`` and ``high`` in one word each pair."""
-    return (_bits(low) >> 16) | (_bits(high) & jnp.uint32(_HIGH))
+    """bf16 ``low`` and ``high`` in one word each pair."""
+    return _low(low) | (_bits(high) & jnp.uint32(_HIGH))
 
 
 def as_groups(a):
-    """(N, H) -> (N * G, 128) words: every row contiguous, in whole tiles —
-    the layout a row DMA can address."""
+    """(N, H) -> (N * G, 128) words: every row contiguous in its G lines —
+    the layout a row DMA can address. Where a bf16 row ends inside its last
+    line, the high half's zeros from column H on are written here, in the one
+    pass that makes the words (a ``pad`` that cuts the low half off and adds
+    them: one fusion with the shifts, nothing padded before it)."""
     if not _packed(a.dtype):
         return a.astype(jnp.float32).reshape(-1, LANES)
-    half = a.shape[1] // 2
-    return _words(a[:, :half], a[:, half:]).reshape(-1, LANES)
+    half = groups_of(a.shape[1], a.dtype) * LANES
+    low, past = a[:, :half], 2 * half - a.shape[1]
+    high = a[:, half:] if not past else jax.lax.pad(
+        a, jnp.zeros((), a.dtype), ((0, 0, 0), (-half, past, 0)))
+    return _words(low, high).reshape(-1, LANES)
 
 
 def _parts(staged, s, rows, groups, width):
     """Line ``s`` of each of ``rows`` staged groups as float32 column blocks
-    ``[(first column, (rows, 128))]``: one for float32 words, two for packed."""
+    ``[(first column, (rows, 128))]``: one for float32 words, two for packed
+    — the low block and, ``128 groups`` columns on, the high one, left out
+    where the row ends before it."""
     words = staged[pl.ds(s, rows, stride=groups), :]
     if words.dtype == jnp.float32:
         return [(s * LANES, words)]
     value = lambda w: jax.lax.bitcast_convert_type(w, jnp.float32)  # noqa: E731
-    return [(s * LANES, value(words << 16)),
-            (width // 2 + s * LANES, value(words & jnp.uint32(_HIGH)))]
+    low, high = (s * LANES, value(words << 16)), (groups + s) * LANES
+    return [low] if high >= width else [low, (high, value(words & jnp.uint32(_HIGH)))]
 
 
 def _last_used(i, n_used):
@@ -95,13 +123,15 @@ def _last_used(i, n_used):
 def _pack_kernel(n_used, a_ref, o_ref, *, groups):
     @pl.when(pl.program_id(0) < n_used[0])
     def _():
-        half = a_ref.shape[1] // 2
+        cols = lambda first: a_ref[:, first:first + LANES]  # noqa: E731
         for s in range(groups):
-            cols = slice(s * LANES, (s + 1) * LANES)
+            low, high = s * LANES, (groups + s) * LANES
             if o_ref.dtype == jnp.float32:
-                words = a_ref[:, cols].astype(jnp.float32)
-            else:
-                words = _words(a_ref[:, cols], a_ref[:, half + s * LANES:half + (s + 1) * LANES])
+                words = cols(low).astype(jnp.float32)
+            elif high < a_ref.shape[1]:
+                words = _words(cols(low), cols(high))
+            else:                           # the row ends before this line's high half
+                words = _low(cols(low))
             o_ref[pl.ds(s, TM, stride=groups), :] = words
 
 
@@ -125,19 +155,6 @@ def moe_rows_pack(a, n_used, *, interpret=False):
             dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM),
         interpret=interpret,
     )(n_used, a)
-
-
-def padded_width(width, dtype, interpret):
-    """The least width the kernels take that holds ``width``: the strided
-    read-back wants a row's group to be whole tiles of eight lines (compiled:
-    multiples of 1,024 float32, 2,048 bf16), any whole lines interpreted."""
-    quantum = (1 if interpret else 8) * (2 * LANES if _packed(dtype) else LANES)
-    return -(-width // quantum) * quantum
-
-
-def shapes_ok(width, dtype, interpret):
-    """Widths the kernels take as they are."""
-    return padded_width(width, dtype, interpret) == width
 
 
 def list_length(per_token):

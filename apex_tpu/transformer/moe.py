@@ -627,39 +627,21 @@ def _f0(a):
     return np.zeros(a.shape, jax.dtypes.float0)
 
 
-def _rows_width(a):
-    """The width the row kernels move ``a``'s rows at: its own where the row
-    DMA takes it (compiled: a multiple of 2,048 bf16), else the next it takes,
-    zeros in the columns added (2,688 moves at 4,096: the kernels' cost
-    follows the rows in use, XLA's gathers run over every row of a block, so
-    a row half as wide again is still the cheaper)."""
-    return rk.padded_width(a.shape[-1], a.dtype, _backend.interpret_mode())
-
-
 def _rows_impl(impl, a):
-    """The movements' implementation. A width that would move at twice its
-    own or more keeps XLA's movements under ``impl="pallas"`` too, as it ran
-    before the row kernels."""
-    ok = _rows_width(a) < 2 * a.shape[-1]
+    """The movements' implementation: the row kernels at every width they
+    take (``expert_rows.shapes_ok``), XLA's movements at the others under
+    ``impl="pallas"`` too."""
+    ok = rk.shapes_ok(a.shape[-1], a.dtype, _backend.interpret_mode())
     return _backend.choose_impl(impl if ok else "xla", ok)
-
-
-def _widened(a, wide):
-    return a if a.shape[-1] == wide else jnp.pad(a, ((0, 0), (0, wide - a.shape[-1])))
 
 
 def _gather_rows(x, move, scale, dot_with=None):
     """``moe_rows_gather`` over one block: row r takes ``scale[r]`` times the
     token ``row_token[r]`` (rows that ``row_valid`` excludes: a scale of 0)."""
-    H, wide = x.shape[-1], _rows_width(x)
-    out = rk.moe_rows_gather(
-        rk.as_groups(_widened(x, wide)), move["row_token"],
-        jnp.where(move["row_valid"], scale, 0.0), move["n_used"],
-        None if dot_with is None else _widened(dot_with, wide),
-        width=wide, dtype=x.dtype, interpret=_backend.interpret_mode())
-    if wide == H:
-        return out
-    return out[:, :H] if dot_with is None else (out[0][:, :H], out[1])
+    return rk.moe_rows_gather(
+        rk.as_groups(x), move["row_token"], jnp.where(move["row_valid"], scale, 0.0),
+        move["n_used"], dot_with, width=x.shape[-1], dtype=x.dtype,
+        interpret=_backend.interpret_mode())
 
 
 def _combine_rows(y, move, weights=None):
@@ -670,12 +652,10 @@ def _combine_rows(y, move, weights=None):
         weights = jnp.pad(weights.astype(jnp.float32),
                           ((0, move["rank"].shape[0] - tokens), (0, 0)))
     interpret = _backend.interpret_mode()
-    H, wide = y.shape[-1], _rows_width(y)
-    out = rk.moe_rows_combine(
-        rk.moe_rows_pack(_widened(y, wide), move["n_used"], interpret=interpret),
+    return rk.moe_rows_combine(
+        rk.moe_rows_pack(y, move["n_used"], interpret=interpret),
         move["tile_rows"], move["tile_count"], move["rank"], weights,
-        tokens=tokens, width=wide, dtype=y.dtype, interpret=interpret)
-    return out if wide == H else out[:, :H]
+        tokens=tokens, width=y.shape[-1], dtype=y.dtype, interpret=interpret)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))

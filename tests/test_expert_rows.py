@@ -1,8 +1,12 @@
 """The row movements of the dropless expert layer as kernels
 (``ops/pallas/expert_rows.py``: ``moe_rows_gather`` / ``moe_rows_combine``,
 interpreted) against XLA's gathers: the layer's output and every gradient at
-the cells' routings, bf16 rows, the widths the row DMA takes, and the plan's
-lists that the kernels read."""
+the cells' routings, bf16 rows, the widths the kernels take — each at its own,
+the group's padding inside the groups — and the plan's lists that the kernels
+read."""
+import collections
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,6 +17,10 @@ from apex_tpu.ops.pallas import grouped_matmul as gk
 from apex_tpu.transformer import moe
 from comparisons import close
 from moe_toy import F, H
+
+
+def _f32(a):
+    return np.asarray(a, np.float32)
 
 
 def _movement_operands(tokens, hidden, held, width, dtype, skew=None, seed=30):
@@ -160,50 +168,160 @@ def test_bf16_layer_on_the_kernels_matches_the_xla_composition(top_k=6, held=(0,
     want = _layer_and_grads("xla", p, x, top_k, held)
     got = _layer_and_grads("pallas", p, x, top_k, held)
     np.testing.assert_array_equal(got[1]["expert_load"], want[1]["expert_load"])
-    f32 = lambda a: np.asarray(a, np.float32)  # noqa: E731
-    close(f32(got[0]), f32(want[0]), 2e-2, "y")
-    close(f32(got[3]), f32(want[3]), 2e-2, "dx")
+    close(_f32(got[0]), _f32(want[0]), 2e-2, "y")
+    close(_f32(got[3]), _f32(want[3]), 2e-2, "dx")
     for name in want[2]:
-        close(f32(got[2][name]), f32(want[2][name]), 2e-2, name)
+        close(_f32(got[2][name]), _f32(want[2][name]), 2e-2, name)
 
 
-@pytest.mark.parametrize("width, dtype, takes, moves_at", [
-    (2048, jnp.bfloat16, "pallas", 2048), (1024, jnp.float32, "pallas", 1024),
-    (1024, jnp.bfloat16, "xla", 2048), (512, jnp.float32, "xla", 1024),
-    # nemotron3-train-8k's rows: 10.5 lines of 128 words move as 16
-    (2688, jnp.bfloat16, "pallas", 4096), (2688, jnp.float32, "pallas", 3072)])
-def test_compiled_movements_keep_xla_at_widths_the_row_dma_cannot_take(
-        width, dtype, takes, moves_at, monkeypatch):
-    """Compiled, a row's group is whole tiles of eight lines: a width between
-    two such moves at the next one, zeros in the columns added, where that is
-    under twice its own; narrower widths keep XLA's movements under
-    ``impl="pallas"`` too (the grouped products take them) instead of
-    raising."""
+@pytest.mark.parametrize("width, dtype, takes, lines", [
+    (2048, jnp.bfloat16, "pallas", 8), (1024, jnp.float32, "pallas", 8),
+    # ling3- and nemotron3-train-8k's rows: 10 and 10.5 lines of 128 words, in groups of 10 and 11
+    (2560, jnp.bfloat16, "pallas", 10), (2688, jnp.bfloat16, "pallas", 11),
+    (2688, jnp.float32, "pallas", 21),
+    # a width the parent moved at 2,048: six lines
+    (1536, jnp.bfloat16, "pallas", 6),
+    # four lines, half a tile of eight
+    (1024, jnp.bfloat16, "xla", 4), (512, jnp.float32, "xla", 4)])
+def test_compiled_movements_take_every_width_over_half_a_tile_of_lines_at_its_own(
+        width, dtype, takes, lines, monkeypatch):
+    """Compiled as interpreted, a row's group is the lines that hold it and
+    the arrays around the kernels keep the row's own width: a width of whole
+    lane tiles rides the kernels where its group is more than half a tile of
+    eight lines — the widths that rode them widened before; narrower ones keep
+    XLA's movements under ``impl="pallas"`` too (the grouped products take
+    them) instead of raising."""
     monkeypatch.setattr(moe._backend, "interpret_mode", lambda: False)
     a = jax.ShapeDtypeStruct((256, width), dtype)
-    assert moe._rows_width(a) == moves_at
+    assert rk.groups_of(width, dtype) == lines
     assert moe._rows_impl("pallas", a) == takes
     assert moe._rows_impl("xla", a) == "xla"
+    assert not hasattr(moe, "_rows_width") and not hasattr(moe, "_widened")
 
 
-def test_rows_of_a_width_between_two_the_kernels_take_move_widened(top_k=6, held=(0, 8)):
-    """bf16 rows of 384 (one and a half lines of packed words, as 2,688 is
-    10.5 compiled): the movements run on the kernels at 512 with zeros in the
-    added columns, forward and every gradient, and nothing of the padding
-    reaches a result."""
-    p, x = _movement_operands(256, 384, held, 64, jnp.bfloat16)
-    assert moe._rows_width(x) == 512 and moe._rows_impl("pallas", x) == "pallas"
-    names = lambda impl: str(jax.make_jaxpr(  # noqa: E731
-        lambda p, x: _layer_and_grads(impl, p, x, top_k, held)[0])(p, x))
-    assert "moe_rows_gather" in names("pallas") and "moe_rows_combine" in names("pallas")
-    assert "moe_rows" not in names("xla")
+# a row that ends inside its group's last line (384: 1.5 lines of packed words in 2, as
+# nemotron3-train-8k's 2,688 is 10.5 in 11) and ling3-train-8k's 10 whole lines, which are no
+# whole tiles: the groups the chip runs, walked here by the interpreter
+OWN_WIDTHS = [(384, 2), (2560, 10), (2688, 11)]
+
+
+@pytest.mark.parametrize("width, lines", OWN_WIDTHS)
+def test_rows_of_no_whole_tiles_move_at_their_own_width(width, lines):
+    """All four movements on the kernels against XLA's, operands and results
+    (rows, width) as they are: rows <- tokens and its cotangent ``x`` (the
+    unweighted sum) bit for bit, tokens <- rows within bf16's rounding of the
+    float32 sum, its cotangent ``y`` (one rounding of the same float32
+    product) bit for bit, the weights' (``dots``, float32 sums in another
+    order) to 1e-5 of the largest."""
+    move, top_p, rows = _one_block_move(192, 4, 8, 16)
+    k = jax.random.split(jax.random.PRNGKey(60), 3)
+    x, g = (jax.random.normal(k[i], (192, width)).astype(jnp.bfloat16) for i in (0, 1))
+    y = jax.random.normal(k[2], (rows, width)).astype(jnp.bfloat16)
+    assert rk.groups_of(width, x.dtype) == lines and moe._rows_impl("pallas", x) == "pallas"
+
+    @functools.partial(jax.jit, static_argnums=4)
+    def movements(x, y, w, g, impl):
+        xs, pull = jax.vjp(lambda x: moe._rows_from_tokens(x, move, impl), x)
+        out, pull_out = jax.vjp(lambda y, w: moe._tokens_from_rows(y, w, move, impl), y, w)
+        return (xs, pull(y)[0], out, *pull_out(g))
+    want = movements(x, y, top_p, g, "xla")
+    got = movements(x, y, top_p, g, "pallas")
+    for a, b, name in zip(got, want, ("rows", "dx", "tokens", "dy", "dweights")):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        if name == "tokens":
+            close(_f32(a), _f32(b), 2e-2, name)
+        elif name == "dweights":
+            close(a, b, 1e-5, name)
+        else:
+            np.testing.assert_array_equal(_f32(a), _f32(b), err_msg=name)
+    assert float(jnp.max(jnp.abs(got[4]))) > 0
+
+
+@pytest.mark.parametrize("hidden", [384, 2688])
+def test_layer_on_rows_that_end_inside_their_group_matches_the_xla_composition(
+        hidden, top_k=6, held=(0, 8)):
+    """The whole layer at such a width, forward and every gradient: the
+    movements run on the kernels at (rows, hidden) and nothing of a group's
+    padding reaches a result."""
+    p, x = _movement_operands(256, hidden, held, 64, jnp.bfloat16)
+    assert moe._rows_impl("pallas", x) == "pallas"
     want = _layer_and_grads("xla", p, x, top_k, held)
     got = _layer_and_grads("pallas", p, x, top_k, held)
     np.testing.assert_array_equal(got[1]["expert_load"], want[1]["expert_load"])
-    assert got[0].shape == want[0].shape == (256, 384) and got[3].shape == (256, 384)
-    f32 = lambda a: np.asarray(a, np.float32)  # noqa: E731
-    close(f32(got[0]), f32(want[0]), 2e-2, "y")
-    close(f32(got[3]), f32(want[3]), 2e-2, "dx")
+    assert got[0].shape == want[0].shape == (256, hidden) and got[3].shape == (256, hidden)
+    close(_f32(got[0]), _f32(want[0]), 2e-2, "y")
+    close(_f32(got[3]), _f32(want[3]), 2e-2, "dx")
     for name in want[2]:
-        close(f32(got[2][name]), f32(want[2][name]), 2e-2, name)
+        close(_f32(got[2][name]), _f32(want[2][name]), 2e-2, name)
 
+
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def test_no_pad_and_no_slice_stands_around_a_movement_at_2688(top_k=6, held=(0, 8)):
+    """The traced layer and its gradients at ``nemotron3-train-8k``'s width:
+    every ``moe_rows_*`` call takes and gives the row's own width (its groups
+    apart), no ``pad`` makes anything wider than the row, and no ``slice``
+    cuts anything wider down."""
+    hidden = 2688
+    p, x = _movement_operands(256, hidden, held, 64, jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda p, x: _layer_and_grads("pallas", p, x, top_k, held)[2:])(p, x)
+    called = collections.Counter()
+    for eqn in _equations(jaxpr.jaxpr):
+        shapes = lambda vs: [v.aval.shape for v in vs if getattr(v.aval, "ndim", 0) == 2]  # noqa: E731
+        name = eqn.primitive.name
+        if name == "pallas_call" and eqn.params["name"].startswith("moe_rows"):
+            called[eqn.params["name"]] += 1
+            assert {s[1] for s in shapes(eqn.invars + eqn.outvars)} <= {hidden, rk.LANES, top_k}
+        elif name == "pad":
+            assert all(s[1] <= hidden for s in shapes(eqn.outvars)), eqn
+        elif name in ("slice", "dynamic_slice"):
+            assert not [s for s in shapes(eqn.invars[:1]) if s[1] > hidden], eqn
+    assert set(called) == {"moe_rows_gather", "moe_rows_gather_dots", "moe_rows_pack",
+                           "moe_rows_combine", "moe_rows_combine_weighted"}
+
+
+@pytest.mark.parametrize("width, lines", [(1152, 5), (2688, 11)])
+def test_garbage_past_the_row_and_in_unused_tiles_never_reaches_a_result(
+        width, lines, dtype=jnp.bfloat16):
+    """Groups whose last line's high half holds NaN from column ``width`` on
+    and, for the combine, NaN in every group of the row tiles not in use
+    (``moe_rows_pack`` leaves those unwritten): both kernels give what they
+    give on zeros there."""
+    assert rk.groups_of(width, dtype) == lines
+    wide = 2 * lines * rk.LANES
+    move, top_p, rows = _one_block_move(192, 4, 8, 16)
+    x = jax.random.normal(jax.random.PRNGKey(70), (192, width)).astype(dtype)
+    y = jax.random.normal(jax.random.PRNGKey(71), (rows, width)).astype(dtype)
+
+    def spoiled(a):
+        """The groups of the row continued with NaN to the last line's end:
+        the same lines, the same pairing ``c`` / ``c + 128 lines``."""
+        low, high = a[:, :wide // 2], a[:, wide // 2:]
+        high = jnp.pad(high, ((0, 0), (0, wide - width)), constant_values=jnp.nan)
+        return rk._words(low, high).reshape(-1, rk.LANES)
+    clean = rk.as_groups(x)
+    assert clean.shape == spoiled(x).shape and not np.array_equal(clean, spoiled(x))
+    scale = jnp.where(move["row_valid"], 1.5, 0.0)
+    gather = lambda groups: rk.moe_rows_gather(  # noqa: E731
+        groups, move["row_token"], scale, move["n_used"], y, width=width, dtype=dtype,
+        interpret=True)
+    for got, want in zip(gather(spoiled(x)), gather(clean)):
+        np.testing.assert_array_equal(_f32(got), _f32(want))
+    assert np.isfinite(_f32(gather(spoiled(x))[0])).all()
+
+    in_use = jnp.repeat(jnp.arange(rows // gk.TM) < move["n_used"][0], gk.TM * lines)[:, None]
+    packed = rk.moe_rows_pack(y, move["n_used"], interpret=True)
+    combine = lambda groups: rk.moe_rows_combine(  # noqa: E731
+        groups, move["tile_rows"], move["tile_count"], move["rank"],
+        jnp.pad(top_p, ((0, move["rank"].shape[0] - 192), (0, 0))),
+        tokens=192, width=width, dtype=dtype, interpret=True)
+    want = combine(jnp.where(in_use, packed, 0))
+    got = combine(jnp.where(in_use, spoiled(y), jnp.uint32(0x7FC07FC0)))
+    np.testing.assert_array_equal(_f32(got), _f32(want))
+    assert np.isfinite(_f32(got)).all() and float(jnp.max(jnp.abs(got))) > 0
+    close(_f32(got), _f32(moe._tokens_from_rows(y, top_p, move, "xla")), 2e-2)

@@ -863,14 +863,17 @@ def _dropless_family():
 
 
 CELL_TOKENS, CELL_HIDDEN = 16384, 2048   # 2 rows of 8,192; the first three expert cells' width
-# top-k, experts held, router width, F: trinity-, dsv2lite-, q3next- and nemotron3-train-8k
+# top-k, experts held, router width, F: trinity-, dsv2lite-, q3next-, nemotron3- and ling3-train-8k
 EXPERT_CELLS = {"trinity": (8, 16, 128, 1024), "dsv2lite": (6, 8, 64, 1408),
                 "q3next": (10, 32, 512, 512), "nemotron3": (6, 8, 128, 1856),
-                # no cell's: a second width of whole HALF lane tiles (7.5) and a second
-                # row the DMA cannot take (3,072 moves at 4,096), which the rules let through
+                "ling3": (8, 8, 512, 768),
+                # no cell's: a second width of whole HALF lane tiles (7.5) and a third row
+                # of no whole tiles (3,072: 12 lines), which the rules let through
                 "halftile": (6, 8, 128, 960)}
-# experts that are not SwiGLU at 2,048: (hidden, activation)
-EXPERT_FORMS = {"nemotron3": (2688, "relu2"), "halftile": (3072, "relu2")}
+# experts that are not SwiGLU at 2,048: (hidden, activation); the rows of 2,688 and 2,560 are
+# 10.5 and 10 lines of 128 words, in groups of 11 and 10 (``expert_rows.groups_of``)
+EXPERT_FORMS = {"nemotron3": (2688, "relu2"), "ling3": (2560, "silu_gate"),
+                "halftile": (3072, "relu2")}
 NO_CELL = {"halftile"}
 
 
